@@ -18,9 +18,8 @@ Protocol: one frame per connection — {run_dir, actor_id, incarnation, env,
 log_base} → ("ok", child_pid). The requester (head or agent) monitors the
 child with a pid-probe Popen shim (children are reaped HERE, by their true
 parent). The zygote exits when its parent does (getppid watch), so cluster
-shutdown needs no extra plumbing. Only ``light`` actors route here; actors
-that need sitecustomize (jax/TPU plugin registration) still get a full
-interpreter start.
+shutdown needs no extra plumbing. Only ``light`` actors route here; the rest
+get a full interpreter start with site processing.
 """
 
 from __future__ import annotations

@@ -28,6 +28,7 @@ from typing import Optional, Sequence
 import flax.linen as nn
 import jax.numpy as jnp
 
+from raydp_tpu.ops.backend import on_tpu
 from raydp_tpu.ops.interaction import dot_interaction, dot_interaction_fused
 
 
@@ -93,12 +94,10 @@ class DLRM(nn.Module):
 
         use_pallas = self.use_pallas_interaction
         if use_pallas is None:
-            import jax
-
             # the fused kernel measures 1.46x the einsum on TPU; multi-device
             # meshes run it per-shard via shard_map (dot_interaction_fused) —
             # the dp×tp path keeps the kernel instead of falling back
-            use_pallas = jax.default_backend() == "tpu"
+            use_pallas = on_tpu()
         interact = dot_interaction_fused(t) if use_pallas else dot_interaction(t)
         z = jnp.concatenate([h, interact.astype(self.dtype)], axis=1)
 

@@ -249,8 +249,6 @@ def sequence_parallel_apply(model: TransformerLM, params, tokens, mesh):
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from raydp_tpu.parallel.sharding import shard_map_compat
-
     axis = model.seq_axis
 
     def body(p, tok):
@@ -260,10 +258,8 @@ def sequence_parallel_apply(model: TransformerLM, params, tokens, mesh):
     # *_flash: the pallas interpreter can't reconcile invariant grid
     # slices with varying operands; numerics are test-validated against full
     # attention
-    check_vma = (
-        False if model.attn_impl in ("ring_flash", "ulysses_flash") else None
-    )
-    return shard_map_compat(
+    check_vma = model.attn_impl not in ("ring_flash", "ulysses_flash")
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(), P(None, axis)),

@@ -55,9 +55,10 @@ _CPU_NOMINAL_PER_CORE = 3.0e9 * 16
 
 def device_peak_flops(device: Any = None) -> dict:
     """``{kind, peak, peak_source}`` for ``device`` (default: the first
-    jax device). ``peak`` is None when the device kind is unknown and no
-    override is set; ``peak_source`` is one of ``tpu-table`` / ``env`` /
-    ``nominal-cpu`` / ``unknown``."""
+    jax device). ``peak_source`` is one of ``tpu-table`` / ``env`` /
+    ``nominal-cpu`` / ``unknown`` (``peak`` None). A TPU whose kind the
+    table does not know is an error, not ``unknown``: an MFU gauge that
+    silently never moves on the chip it exists for hides the device."""
     override = os.environ.get(PEAK_FLOPS_ENV)
     if device is None:
         import jax
@@ -77,6 +78,11 @@ def device_peak_flops(device: Any = None) -> dict:
             "peak": cores * _CPU_NOMINAL_PER_CORE,
             "peak_source": "nominal-cpu",
         }
+    if getattr(device, "platform", None) == "tpu":
+        raise ValueError(
+            f"TPU device kind {kind!r} is not in TPU_PEAK_FLOPS; add its "
+            f"peak to the table or set {PEAK_FLOPS_ENV}"
+        )
     return {"kind": kind, "peak": None, "peak_source": "unknown"}
 
 

@@ -20,8 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from raydp_tpu.parallel.mesh import axis_env_size
-
 
 def embedding_lookup_vocab_sharded(
     table: jnp.ndarray, ids: jnp.ndarray, axis_name: str
@@ -29,7 +27,7 @@ def embedding_lookup_vocab_sharded(
     """Per-device body (call inside shard_map): ``table`` is the local vocab
     shard [V/N, D]; ``ids`` are global ids (replicated). Each device gathers
     the ids that fall in its shard and a psum assembles full rows."""
-    n = axis_env_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     local_v = table.shape[0]
     start = my * local_v
@@ -48,12 +46,7 @@ def sharded_embedding_lookup(
     ids replicated; returns replicated rows."""
     from jax.sharding import PartitionSpec as P
 
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-
-    return shard_map(
+    return jax.shard_map(
         partial(embedding_lookup_vocab_sharded, axis_name=axis),
         mesh=mesh,
         in_specs=(P(axis, None), P()),

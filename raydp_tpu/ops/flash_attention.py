@@ -19,8 +19,10 @@ One kernel family serves three surfaces:
   merges across devices (``use_flash=True``).
 - ``flash_decode``: incremental-decode attention of a few new query rows
   against a KV cache with per-sequence valid lengths (SMEM), sharing the
-  same online-softmax update — so decode-vs-prefill is bit-identical at a
-  fixed shape. Optional int8 K/V with on-the-fly per-row dequant.
+  same online-softmax update — so a decode step repeats the prefill's
+  per-row arithmetic: bit-identical compiled on a TPU v5e, within 3e-7
+  interpreted on XLA:CPU, whose matmul depends on the q-tile's row count
+  (docs/serving.md). Optional int8 K/V with on-the-fly per-row dequant.
 
 Two forward kernel bodies implement the same math: ``_flash_kernel`` (the
 r05 two-term update — reference) and ``_flash_kernel_onepass`` (default),
@@ -42,6 +44,8 @@ import os
 
 import jax
 import jax.numpy as jnp
+
+from raydp_tpu.ops.backend import pallas_interpret
 
 NEG_INF = -1e30
 
@@ -231,28 +235,17 @@ def _flash_kernel_onepass(
 
 
 def _union_vma(*arrays):
-    # jax.typeof (and the vma tracking it exposes) only exists on modern jax;
-    # on older releases (0.4.x) there is no varying-manual-axes machinery to
-    # reconcile, so "no vma anywhere" is the correct answer — not a crash
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:
-        return None
-    vmas = [getattr(typeof(a), "vma", None) for a in arrays]
-    if any(v is not None for v in vmas):
-        return frozenset().union(*[v for v in vmas if v is not None])
-    return None
+    """Union of the operands' varying manual axes (empty outside shard_map).
+    Under shard_map — the only way Mosaic kernels run multi-device — a
+    pallas_call's out_shape must carry it."""
+    return frozenset().union(*(jax.typeof(a).vma for a in arrays))
 
 
-def _pvary_scalar(x, axis_name):
-    from jax import lax
-
-    try:
-        return lax.pcast(x, (axis_name,), to="varying")
-    except (AttributeError, ValueError):
-        try:
-            return lax.pvary(x, (axis_name,))
-        except (AttributeError, ValueError):
-            return x
+def _vary_like(x, vma):
+    """SMEM scalars must vary over the same axes as the kernel's operands;
+    an offset derived from ``lax.axis_index`` already does."""
+    missing = tuple(vma - jax.typeof(x).vma)
+    return jax.lax.pcast(x, missing, to="varying") if missing else x
 
 
 def _flash_call(
@@ -262,8 +255,7 @@ def _flash_call(
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = pallas_interpret(interpret)
     if onepass is None:
         onepass = use_onepass_default()
     b, h, t, d = q.shape
@@ -285,21 +277,13 @@ def _flash_call(
         scale=d**-0.5, causal=causal, block_q=block_q, block_k=block_k,
         normalize=normalize,
     )
-    # under shard_map (manual partitioning — the only way Mosaic kernels run
-    # multi-device) out_shape must carry the UNION of the inputs' varying axes
     union = _union_vma(qf, kf, vf)
 
     def sds(shape, dtype):
-        if union is not None:
-            return jax.ShapeDtypeStruct(shape, dtype, vma=union)
-        return jax.ShapeDtypeStruct(shape, dtype)
+        return jax.ShapeDtypeStruct(shape, dtype, vma=union)
 
-    q_off = jnp.asarray([q_offset], jnp.int32)
-    k_off = jnp.asarray([k_offset], jnp.int32)
-    if union is not None:  # SMEM scalars must match the kernel vma too
-        for axis in union:
-            q_off = _pvary_scalar(q_off, axis)
-            k_off = _pvary_scalar(k_off, axis)
+    q_off = _vary_like(jnp.asarray([q_offset], jnp.int32), union)
+    k_off = _vary_like(jnp.asarray([k_offset], jnp.int32), union)
 
     out_dtype = q.dtype if normalize else jnp.float32
     o, m, l = pl.pallas_call(  # noqa: E741
@@ -497,8 +481,7 @@ def flash_backward_blocks(
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = pallas_interpret(interpret)
     b, h, t, d = q.shape
     tk = k.shape[2]
     auto_q, auto_k = pick_blocks(t, tk)
@@ -521,16 +504,10 @@ def flash_backward_blocks(
     union = _union_vma(qf, kf, vf, dof)
 
     def sds(shape, dtype):
-        if union is not None:
-            return jax.ShapeDtypeStruct(shape, dtype, vma=union)
-        return jax.ShapeDtypeStruct(shape, dtype)
+        return jax.ShapeDtypeStruct(shape, dtype, vma=union)
 
-    q_off = jnp.asarray([q_offset], jnp.int32).reshape(1)
-    k_off = jnp.asarray([k_offset], jnp.int32).reshape(1)
-    if union is not None:
-        for axis in union:
-            q_off = _pvary_scalar(q_off, axis)
-            k_off = _pvary_scalar(k_off, axis)
+    q_off = _vary_like(jnp.asarray([q_offset], jnp.int32).reshape(1), union)
+    k_off = _vary_like(jnp.asarray([k_offset], jnp.int32).reshape(1), union)
 
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     q_spec = pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0))
@@ -655,8 +632,8 @@ flash_attention.defvjp(_fwd, _bwd)
 # decode: a few new query rows against a KV cache. Same online-softmax
 # update as the prefill kernel (deferred rescale + deferred normalization),
 # same masking predicate (keep k_pos <= q_pos), same NEG_INF/p-zeroing
-# semantics — so a decode step at a fixed shape is bit-identical to the
-# matching rows of a prefill pass over the same (dequantized) cache when
+# semantics — so a decode step at a fixed shape repeats, row for row, the
+# arithmetic of a prefill pass over the same (dequantized) cache when
 # block_k agrees. Grid is (batch·head, k-block) with per-sequence valid
 # lengths in SMEM; k-blocks entirely past a sequence's length are skipped.
 # ---------------------------------------------------------------------------
@@ -789,8 +766,7 @@ def flash_decode(
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = pallas_interpret(interpret)
     int8_kv = k_scale is not None
     if int8_kv != (v_scale is not None):
         raise ValueError("k_scale and v_scale must be provided together")

@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from raydp_tpu.ops.backend import pallas_interpret
+
 
 def _tril_indices(f: int):
     rows, cols = np.tril_indices(f, k=-1)
@@ -77,23 +79,10 @@ dot_interaction_pallas.defvjp(_interaction_fwd, _interaction_bwd)
 
 
 def _active_mesh():
-    """The mesh governing the current trace: the new-style context
-    (``jax.set_mesh`` / ``use_abstract_mesh``) or the legacy ``with mesh:``
-    block. Returns None when no multi-device mesh is active."""
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        mesh = get_abstract()
-        if mesh is not None and mesh.shape:
-            return mesh
-    try:
-        from jax._src.mesh import thread_resources
-
-        physical = thread_resources.env.physical_mesh
-        if not physical.empty:
-            return physical
-    except Exception:  # raydp-lint: disable=swallowed-exceptions (optional fast path; caller falls back)
-        pass
-    return None
+    """The mesh governing the current trace (``jax.set_mesh``), or None when
+    no mesh context is active."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return mesh if mesh.shape else None
 
 
 def dot_interaction_fused(
@@ -123,10 +112,8 @@ def dot_interaction_fused(
         return dot_interaction_pallas(stacked, block_batch, interpret)
     from jax.sharding import PartitionSpec as P
 
-    from raydp_tpu.parallel.sharding import shard_map_compat
-
     present = tuple(a for a in batch_axes if mesh.shape.get(a, 1) > 1)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         partial(dot_interaction_pallas, block_batch=block_batch, interpret=interpret),
         mesh=mesh,
         in_specs=P(present if present else None, None, None),
@@ -143,8 +130,7 @@ def _interaction_forward(
 ) -> jnp.ndarray:
     from jax.experimental import pallas as pl
 
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = pallas_interpret(interpret)
     b, f, d = stacked.shape
     out_f = f * (f - 1) // 2
     block_batch = min(block_batch, b)

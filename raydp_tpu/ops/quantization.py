@@ -18,6 +18,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from raydp_tpu.ops.backend import on_tpu
+
 
 def quantize_int8(x: jnp.ndarray, seed: int | None = None, stochastic: bool = False,
                   block_rows: int = 256):
@@ -30,7 +32,7 @@ def quantize_int8(x: jnp.ndarray, seed: int | None = None, stochastic: bool = Fa
         return values, scales
     if seed is None:
         raise ValueError("stochastic quantization requires a per-step seed")
-    if jax.default_backend() != "tpu":
+    if not on_tpu():
         scales = jnp.maximum(
             jnp.max(jnp.abs(x), axis=-1, keepdims=True) / 127.0, 1e-12
         )

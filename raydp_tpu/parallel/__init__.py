@@ -1,7 +1,6 @@
 """Parallelism: mesh construction, sharding rules, sequence parallelism."""
 
 from raydp_tpu.parallel.mesh import (
-    axis_env_size,
     data_parallel_mesh,
     make_mesh,
     mesh_axis_size,
@@ -26,7 +25,6 @@ __all__ = [
     "DataParallelPartitioner",
     "NullPartitioner",
     "Partitioner",
-    "axis_env_size",
     "moe_apply",
     "moe_sharded",
     "pipeline_apply",

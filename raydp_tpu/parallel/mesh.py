@@ -57,19 +57,6 @@ def mesh_axis_size(mesh, axis: str) -> int:
     return int(mesh.shape.get(axis, 1))
 
 
-def axis_env_size(axis_name) -> int:
-    """Size of a named MAPPED axis from inside shard_map/pmap — the axis-env
-    compat shim. Modern jax spells this ``lax.axis_size``; older releases
-    (0.4.x) don't have it, but ``psum`` of a Python-int literal constant-folds
-    to a static int at trace time (verified on 0.4.37), so both branches
-    return a value usable for shapes and loop bounds."""
-    from jax import lax
-
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
-
-
 def multihost_mesh(axes: Dict[str, int], process_axis: str = "data"):
     """Multi-host mesh: each process contributes its local devices; the
     ``process_axis`` spans hosts (DCN), remaining axes stay intra-host (ICI).

@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from raydp_tpu.parallel.mesh import axis_env_size
 
 
 def moe_apply(
@@ -53,7 +52,7 @@ def moe_apply(
     """
     import math
 
-    n = axis_env_size(axis_name)
+    n = lax.axis_size(axis_name)
     b, d = x.shape
     k = min(top_k, n)
     # ceil keeps the requested headroom even at small per-device batches;
@@ -144,11 +143,6 @@ def moe_sharded(
     ``axis``; tokens sharded over the same axis (dp=ep co-located)."""
     from jax.sharding import PartitionSpec as P
 
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-
     def body(params_local, router, x_local):
         params = jax.tree.map(lambda p: p[0], params_local)
         return moe_apply(
@@ -163,7 +157,7 @@ def moe_sharded(
             P(axis),
             {"load_balance_loss": P(), "drop_fraction": P()},
         )
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(axis), stacked_expert_params), P(), P(axis)),

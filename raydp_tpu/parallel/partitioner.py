@@ -15,10 +15,12 @@ call site:
   semantically identical to a sharded ``device_put``; the toggle
   (``shard_direct=False``) keeps the legacy driver-staged ``device_put`` as
   the A/B arm (parity tests assert byte-identical results).
-- **single-device meshes stay uncommitted**: a committed array (even
-  SingleDeviceSharding) forces the SPMD-executor path on some PJRT plugins —
-  ~10ms per call, measured 14× step slowdown — so the default device takes a
-  plain ``jnp.asarray`` and only an explicit non-default device pins.
+- **single-device meshes stay uncommitted**: dispatching a jitted step on a
+  committed array (even SingleDeviceSharding) costs more than on an
+  uncommitted one — 276 µs vs 192 µs per call for a tiny step on one TPU
+  v5e (chip run, PR 21; what it costs a real fit is ROADMAP Queue 3 item
+  8's to measure) — so the default device takes a plain ``jnp.asarray`` and
+  only an explicit non-default device pins.
 """
 
 from __future__ import annotations
@@ -123,9 +125,8 @@ class DataParallelPartitioner(Partitioner):
 
             device = _mesh_single_device(self.mesh)
             if device == jax.devices()[0]:
-                # default device: stay UNCOMMITTED — a committed array (even
-                # SingleDeviceSharding) forces a ~10ms/call executor path on
-                # some PJRT plugins (14× step slowdown measured)
+                # default device: stay UNCOMMITTED — committed arrays cost
+                # more per dispatch (module docstring)
                 return jnp.asarray(x)
             return jax.device_put(x, device)  # explicit non-default pin
         sharding = self._sharding(max(1, x.ndim), stacked)

@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from raydp_tpu.parallel.mesh import axis_env_size
 
 
 def pipeline_apply(
@@ -38,7 +37,7 @@ def pipeline_apply(
     Returns [M, B, F_out] (meaningful on the last device; replicate or
     psum-select outside as needed — see ``pipeline_sharded`` below).
     """
-    n = axis_env_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     m = microbatches.shape[0]
     ticks = m + n - 1
@@ -76,19 +75,11 @@ def pipeline_apply(
     zero_in = jnp.zeros(sample_out.shape, sample_out.dtype)
     # fresh zeros are device-invariant; the carry becomes varying over the
     # pipeline axis (axis_index-dependent), so mark the initial values too
-    zero_in, out_buffer = (_pvary(v, axis_name) for v in (zero_in, out_buffer))
+    zero_in, out_buffer = (
+        lax.pcast(v, (axis_name,), to="varying") for v in (zero_in, out_buffer)
+    )
     (_, outputs), _ = lax.scan(tick, (zero_in, out_buffer), jnp.arange(ticks))
     return outputs
-
-
-def _pvary(x, axis_name):
-    try:
-        return lax.pcast(x, (axis_name,), to="varying")
-    except AttributeError:
-        try:
-            return lax.pvary(x, (axis_name,))
-        except AttributeError:
-            return x
 
 
 def pipeline_sharded(
@@ -104,11 +95,6 @@ def pipeline_sharded(
     is the pipelined result [B, F]."""
     from jax.sharding import PartitionSpec as P
 
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-
     b = x.shape[0]
     if b % num_microbatches:
         raise ValueError(f"batch {b} not divisible by {num_microbatches} microbatches")
@@ -120,12 +106,12 @@ def pipeline_sharded(
         outs = pipeline_apply(stage_fn, params, micro_all, axis_name=axis)
         # broadcast the last stage's banked outputs to every device so the
         # out_spec can be replicated
-        n = axis_env_size(axis)
+        n = lax.axis_size(axis)
         mask = (lax.axis_index(axis) == n - 1).astype(outs.dtype)
         return lax.psum(outs * mask, axis)
 
     param_specs = jax.tree.map(lambda _: P(axis), stacked_params)
-    out = shard_map(
+    out = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(param_specs, P()),

@@ -26,8 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from raydp_tpu.parallel.mesh import axis_env_size
-
 NEG_INF = -1e30
 
 
@@ -63,7 +61,7 @@ def _merge(o1, m1, l1, o2, m2, l2):
 
 def _ring_forward_stats(q, k, v, axis_name, causal, use_flash):
     """Ring forward returning (o_unnormalized, m, l)."""
-    n = axis_env_size(axis_name)
+    n = lax.axis_size(axis_name)
     my_idx = lax.axis_index(axis_name)
     b, h, t, d = q.shape
     tk = k.shape[2]
@@ -173,7 +171,7 @@ def _ring_fwd(q, k, v, axis_name, causal, use_flash):
 
 def _ring_bwd(axis_name, causal, use_flash, residuals, g):
     q, k, v, out, lse = residuals
-    n = axis_env_size(axis_name)
+    n = lax.axis_size(axis_name)
     my_idx = lax.axis_index(axis_name)
     t, tk = q.shape[2], k.shape[2]
     perm = [(i, (i + 1) % n) for i in range(n)]
@@ -221,21 +219,19 @@ def ring_attention_sharded(
     the sequence dim; runs ring_attention under shard_map."""
     from jax.sharding import PartitionSpec as P
 
-    from raydp_tpu.parallel.sharding import shard_map_compat
-
     spec = P(None, None, axis, None)
 
     # use_flash: the pallas interpreter can't reconcile invariant grid slices
     # with varying operands; JAX's documented workaround is check_vma=False
     # (numerics are validated against full attention in tests)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         partial(
             ring_attention, axis_name=axis, causal=causal, use_flash=use_flash
         ),
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        check_vma=False if use_flash else None,
+        check_vma=not use_flash,
     )
     return fn(q, k, v)
 
@@ -255,7 +251,7 @@ def ulysses_attention(
     budget; call inside shard_map. Per-device shapes: [B, H, T_local, D].
     ``use_flash``: compute the local attention with the fused pallas flash
     kernel (O(T) memory for the gathered sequence) instead of the einsum."""
-    n = axis_env_size(axis_name)
+    n = lax.axis_size(axis_name)
     b, h, t, d = q.shape
     if h % n:
         raise ValueError(f"heads {h} not divisible by sequence axis {n}")

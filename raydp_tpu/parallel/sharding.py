@@ -61,23 +61,3 @@ def sharding_rules_fn(rules: Sequence[Tuple[str, Any]]) -> Callable:
         return shard_params_by_rules(mesh, params, rules)
 
     return fn
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs, check_vma=None):
-    """``jax.shard_map`` across jax versions. Modern jax exposes it at the
-    top level with a ``check_vma`` kwarg; the legacy experimental entry point
-    spells the same switch ``check_rep`` — translating here keeps every
-    caller on one signature (passing check_vma to the legacy one is a
-    TypeError)."""
-    kwargs = {}
-    try:
-        from jax import shard_map
-
-        if check_vma is not None:
-            kwargs["check_vma"] = check_vma
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
-
-        if check_vma is not None:
-            kwargs["check_rep"] = check_vma
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)

@@ -13,11 +13,11 @@ share a step — the property the SIGKILL-mid-decode chaos gate's
 token-identity check rests on.
 
 Determinism contract (docs/serving.md): with a float32 cache, a decode step
-is bit-identical to a prefill pass over the same tokens (the kernel-family
-parity in ``ops/flash_attention.py``), so a stream resumed on another
-replica by RE-PREFILLING prompt + already-emitted tokens continues with
-exactly the tokens the dead replica would have produced. Sampling is greedy
-(argmax) — deterministic by construction.
+repeats a prefill pass's per-row arithmetic over the same tokens (the
+kernel family in ``ops/flash_attention.py``; bit-identical compiled on TPU),
+so a stream resumed on another replica by RE-PREFILLING prompt +
+already-emitted tokens continues with the tokens the dead replica would
+have produced. Sampling is greedy (argmax) — deterministic by construction.
 
 Admission is vetoed by the memory-watermark plane: the KV arena lives in
 shm where ``mem.pressure`` sees it, and new sequences wait while pressure
@@ -136,6 +136,9 @@ class DecodeEngine:
 
         import jax
 
+        from raydp_tpu.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
         self._prefill_fn = jax.jit(
             lambda p, toks: model.apply(p, toks, return_kv=True)
         )

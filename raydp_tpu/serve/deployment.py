@@ -70,6 +70,7 @@ class Deployment:
         # serving replica, stream id) — explain_last_stream starts here
         # (guarded-by: self._lock)
         self._last_stream: Optional[dict] = None
+        self._spawn_error: Optional[BaseException] = None  # newest failure
         admission = None
         if conf.tenant:
             # ride the named tenant's fair-share queue (docs/multitenancy.md);
@@ -92,6 +93,14 @@ class Deployment:
                 replicas=self._target,
             ):
                 self._reconcile()
+            if self.replica_count() == 0:
+                # healing tolerates a failed spawn while survivors serve; a
+                # deployment that STARTS with none has nothing to serve from
+                raise ClusterError(
+                    f"deployment {self._name!r}: no replica came up "
+                    f"(requested platform: {spec.platform or 'any'}): "
+                    f"{self._spawn_error}"
+                )
             self.controller = ServeController(self, conf)
         except BaseException:
             # a deployment that failed to come up must not leave batcher
@@ -130,10 +139,11 @@ class Deployment:
             if current < target:
                 try:
                     handle = self._spawn_one()
-                except (ClusterError, OSError):
+                except (ClusterError, OSError) as exc:
                     # cluster unreachable (teardown racing a heal tick) or
                     # spawn rejected: serve on with the survivors rather
                     # than wedging the controller in a spawn-retry loop
+                    self._spawn_error = exc
                     obs.log.warning(
                         "serve replica spawn failed; continuing with "
                         "current pool", deployment=self._name, exc_info=True,
@@ -261,10 +271,11 @@ class Deployment:
         decode engine, and polls tokens out as they land. On replica
         death or reload mid-stream the deployment heals and RESUBMITS to
         a survivor with prompt + already-emitted tokens as the prefix —
-        the KV cache is re-prefilled there, and because a decode step is
-        bit-identical to a prefill over the same tokens (the kernel-family
-        parity contract, f32 cache), the continuation carries on with
-        exactly the tokens the dead replica would have produced. No token
+        the KV cache is re-prefilled there, and because a decode step
+        repeats a prefill's per-row arithmetic over the same tokens (the
+        kernel-family contract, f32 cache; docs/serving.md), the
+        continuation carries on with the tokens the dead replica would
+        have produced. No token
         is ever emitted twice and none is lost: zero-drop re-admission,
         stream edition.
 
@@ -519,6 +530,7 @@ def deploy(
     conf: Optional[dict] = None,
     example=None,
     feature_columns=None,
+    platform: Optional[str] = None,
 ) -> Deployment:
     """Stand up an online serving deployment for a trained model.
 
@@ -528,7 +540,10 @@ def deploy(
     ``example`` (one feature row) lets replicas AOT-compile every batch
     bucket at boot so no request ever pays a compile. ``conf`` takes
     ``serve.*`` keys (docs/serving.md); an active ETL session's ``serve.*``
-    configs are merged underneath it."""
+    configs are merged underneath it. ``platform`` ("tpu", "cpu", ...) is
+    the backend the replicas must serve from: one that initializes any
+    other is a failed spawn, and a deployment whose every initial spawn
+    fails raises instead of serving from nothing."""
     if estimator is not None:
         model = model if model is not None else estimator._model_arg
         checkpoint_dir = checkpoint_dir or estimator.checkpoint_dir
@@ -560,6 +575,7 @@ def deploy(
         example=example,
         name=name,
         decode=decode_kwargs,
+        platform=platform,
     )
     return Deployment(
         spec, resolved, replicas=replicas, feature_columns=feature_columns

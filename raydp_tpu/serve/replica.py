@@ -55,6 +55,13 @@ class ReplicaSpec:
     # DecodeEngine kwargs (serve/decode.py) — non-empty enables the
     # decode_submit/decode_poll streaming surface on each replica
     decode: dict = field(default_factory=dict)
+    # the platform the driver asked to serve from ("tpu", "cpu", ...): a
+    # replica that comes up on any other is a FAILED spawn. A chip belongs
+    # to one process; a second replica process on the same chip either
+    # fails backend init (JAX_PLATFORMS names tpu) or — JAX_PLATFORMS unset
+    # — lands on the CPU backend without a word. None = serve from whatever
+    # JAX picked.
+    platform: Optional[str] = None
 
 
 class _ModelState:
@@ -167,8 +174,25 @@ class ModelReplica:
         self._decode_lock = sanitize.named_lock(
             "serve.replica_decode", threading.Lock()
         )
+        import jax
+
+        from raydp_tpu.compile_cache import enable_compile_cache
         from raydp_tpu.estimator.jax_estimator import JaxEstimator
 
+        devices = jax.devices()
+        self._device = {
+            "platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "device_count": len(devices),
+        }
+        if spec.platform and self._device["platform"] != spec.platform:
+            raise RuntimeError(
+                f"replica {spec.name!r} came up on "
+                f"{self._device['platform']!r} ({self._device['device_kind']}), "
+                f"not the requested {spec.platform!r} — is the chip held by "
+                "another process?"
+            )
+        enable_compile_cache()
         self._est = JaxEstimator(
             model=spec.model,
             checkpoint_dir=spec.checkpoint_dir,
@@ -391,4 +415,5 @@ class ModelReplica:
             "epoch": state.epoch if state else None,
             "step": state.step if state else None,
             "buckets_compiled": len(state.compiled) if state else 0,
+            **self._device,
         }

@@ -37,7 +37,7 @@ def initialize_from_env(
     )
     if world <= 1 or coordinator is None:
         return
-    if getattr(jax.distributed, "is_initialized", lambda: False)():
+    if jax.distributed.is_initialized():
         return
     jax.distributed.initialize(
         coordinator_address=coordinator,

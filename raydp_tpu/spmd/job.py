@@ -66,23 +66,11 @@ class SpmdWorker:
     ) -> int:
         """Join the jax.distributed mesh (the reference's analog: each mpi
         rank joins Ray via ray.init(address), mpi_worker.py:158-166)."""
-        import os
-
         import jax
 
-        # honor a CPU request even if the image pre-imports jax with a TPU
-        # plugin registered (config must be set before backend init)
-        if os.environ.get("JAX_PLATFORMS", "").lower() == "cpu":
-            try:
-                jax.config.update("jax_platforms", "cpu")
-            except Exception:
-                from raydp_tpu.obs import log as obs_log
+        from raydp_tpu.compile_cache import enable_compile_cache
 
-                obs_log.warning(
-                    "could not force jax_platforms=cpu; the rank may "
-                    "initialize against the image's default backend",
-                    exc_info=True,
-                )
+        enable_compile_cache()
         jax.distributed.initialize(
             coordinator_address=coordinator_address,
             num_processes=num_processes,

@@ -837,7 +837,7 @@ def test_rpc_contract_cli_gates_pass():
     env = dict(os.environ, PYTHONPATH=REPO_ROOT)
     done = subprocess.run(
         [sys.executable, "-m", "tools.analyze",
-         "raydp_tpu/", "tools/", "bench.py", "examples/",
+         "raydp_tpu/", "tools/", "bench.py", "examples/", "chip_smoke.py",
          os.path.join("tests", "conftest.py"),
          "--check-contract", "--check-rpc-table"],
         cwd=REPO_ROOT, env=env, capture_output=True, text=True,
@@ -848,10 +848,11 @@ def test_rpc_contract_cli_gates_pass():
 
 
 def test_repo_is_lint_clean():
-    """The exact invocation CI gates on: every finding in raydp_tpu/, the
-    self-hosted tools/ tree, bench.py, examples/, and tests/conftest.py
-    carries an explicit suppression — with the full-surface registry rules
-    (metric/conf/env closure) and exception-flow rules active."""
+    """The invocation CI gates on, plus chip_smoke.py: every finding in
+    raydp_tpu/, the self-hosted tools/ tree, bench.py, examples/,
+    chip_smoke.py and tests/conftest.py carries an explicit suppression —
+    with the full-surface registry rules (metric/conf/env closure) and
+    exception-flow rules active."""
     from tools.analyze.__main__ import config_excludes
 
     project = load_project(
@@ -860,6 +861,7 @@ def test_repo_is_lint_clean():
             os.path.join(REPO_ROOT, "tools"),
             os.path.join(REPO_ROOT, "bench.py"),
             os.path.join(REPO_ROOT, "examples"),
+            os.path.join(REPO_ROOT, "chip_smoke.py"),
             os.path.join(REPO_ROOT, "tests", "conftest.py"),
         ],
         root=REPO_ROOT,

@@ -290,6 +290,31 @@ def test_global_zygote_key_and_guards(tmp_path):
     assert _marker_pid_alive(marker) is None
 
 
+def test_jax_warm_zygote_keys_on_the_jax_environment(monkeypatch):
+    """jax.config reads its environment once, at import: a child forked from
+    a template that imported jax would serve under the TEMPLATE's
+    JAX_COMPILATION_CACHE_DIR / JAX_PLATFORMS whatever its own environment
+    says. So a jax-warm template is only shared between drivers whose JAX
+    environment agrees; a plain one (children import jax themselves, after
+    adopting their environment) is shared as before."""
+    from raydp_tpu.cluster.common import _zygote_source_key
+    from raydp_tpu.cluster.zygote import WARM_JAX_ENV
+
+    monkeypatch.delenv(WARM_JAX_ENV, raising=False)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/cache/a")
+    plain = _zygote_source_key()
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/cache/b")
+    assert _zygote_source_key() == plain
+
+    monkeypatch.setenv(WARM_JAX_ENV, "1")
+    warm_b = _zygote_source_key()
+    assert warm_b != plain
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/cache/a")
+    assert _zygote_source_key() != warm_b
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/cache/b")
+    assert _zygote_source_key() == warm_b
+
+
 def test_zygote_adoption_stamp_blocks_idle_retirement(tmp_path):
     """ADVICE r5 regression: the idle clock is bumped UNDER the adoption
     flock (lock-protected adoption stamp) and the retirement path re-checks

@@ -56,18 +56,13 @@ def test_ulysses_attention_matches_full(mesh8):
 
     from raydp_tpu.parallel import full_attention, ulysses_attention
 
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-
     rng = np.random.default_rng(1)
     q, k, v = (
         jnp.asarray(rng.standard_normal((2, 8, 64, 8)), jnp.float32)
         for _ in range(3)
     )
     spec = P(None, None, "sp", None)
-    out = shard_map(
+    out = jax.shard_map(
         partial(ulysses_attention, axis_name="sp", causal=True),
         mesh=mesh8, in_specs=(spec,) * 3, out_specs=spec,
     )(q, k, v)
@@ -250,7 +245,7 @@ def test_dlrm_forward_and_sharded_tables(cpu_mesh_devices):
     table = params_sharded["params"]["embedding_0"]
     assert table.sharding.spec == P("model", None)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         out = jax.jit(model.apply)(params_sharded, x)
     assert out.shape == (16, 1)
     ref = model.apply(params, x)
@@ -540,7 +535,6 @@ def test_flash_attention_composes_with_shard_map(cpu_mesh_devices):
     from raydp_tpu.ops import flash_attention
     from raydp_tpu.ops.flash_attention import _reference
     from raydp_tpu.parallel import make_mesh
-    from raydp_tpu.parallel.sharding import shard_map_compat
 
     mesh = make_mesh({"data": 4}, jax.devices()[:4])
     rng = np.random.default_rng(13)
@@ -550,11 +544,10 @@ def test_flash_attention_composes_with_shard_map(cpu_mesh_devices):
     )
     spec = P("data", None, None, None)  # batch-sharded; attention is local
     # check_vma=False: the pallas interpreter can't reconcile invariant grid
-    # slices with varying operands (JAX's documented workaround);
-    # shard_map_compat translates it to check_rep on pre-typeof jax
-    out = shard_map_compat(
+    # slices with varying operands (JAX's documented workaround)
+    out = jax.shard_map(
         lambda q_, k_, v_: flash_attention(q_, k_, v_, True, 32, 32),
-        mesh, in_specs=(spec,) * 3, out_specs=spec, check_vma=False,
+        mesh=mesh, in_specs=(spec,) * 3, out_specs=spec, check_vma=False,
     )(q, k, v)
     ref = _reference(q, k, v, True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
@@ -697,7 +690,6 @@ def test_ulysses_flash_matches_full(mesh8):
     from jax.sharding import PartitionSpec as P
 
     from raydp_tpu.parallel import full_attention, ulysses_attention
-    from raydp_tpu.parallel.sharding import shard_map_compat
 
     rng = np.random.default_rng(4)
     q, k, v = (
@@ -705,7 +697,7 @@ def test_ulysses_flash_matches_full(mesh8):
         for _ in range(3)
     )
     spec = P(None, None, "sp", None)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         partial(ulysses_attention, axis_name="sp", causal=True, use_flash=True),
         mesh=mesh8, in_specs=(spec,) * 3, out_specs=spec, check_vma=False,
     )

@@ -182,6 +182,13 @@ def test_e2e_deploy_concurrent_clients_parity(served_model):
         for t in threads:
             t.join()
         assert not errors
+        # a caller can see where each replica serves from
+        import jax
+
+        for info in dep.infos():
+            assert info["platform"] == jax.devices()[0].platform
+            assert info["device_kind"] == jax.devices()[0].device_kind
+            assert info["device_count"] == len(jax.devices())
         # correctness: every client's rows match the direct model within
         # float tolerance regardless of which bucket its batch landed in
         for i, out in results.items():
@@ -532,4 +539,20 @@ def test_request_exceeding_max_batch_rejected(served_model):
         with pytest.raises(ValueError, match="max_batch_size"):
             dep.predict(x[:8])
         # the deployment still serves admissible requests afterwards
+        assert dep.predict(x[:2]).shape == (2, 1)
+
+
+def test_deploy_raises_when_no_replica_reaches_the_platform(served_model):
+    """One process per chip: a replica that comes up on another platform
+    than the driver asked for (here "tpu" on a CPU box — on a TPU host, the
+    CPU backend a second process silently gets when the first holds the
+    chip and JAX_PLATFORMS is unset) is a failed spawn, and a deployment
+    that starts with no replica raises instead of serving from nothing."""
+    from raydp_tpu.cluster.common import ClusterError
+
+    est, ckpt_dir, x, eval_ds = served_model
+    with pytest.raises(ClusterError, match="came up on 'cpu'"):
+        serve.deploy(est, example=x[:1], platform="tpu")
+    # asking for the platform the replicas do reach deploys as before
+    with serve.deploy(est, example=x[:1], platform="cpu") as dep:
         assert dep.predict(x[:2]).shape == (2, 1)

@@ -1,5 +1,7 @@
 """Utility tests (parity: reference python/raydp/tests/test_spark_utils.py)."""
 
+import os
+
 import numpy as np
 import pytest
 
@@ -99,3 +101,33 @@ def test_memory_size_string_exact_or_bytes():
     for n in [(1 << 30) + 1024, (1 << 30) + 512, (1 << 30) + 1, 999]:
         assert parse_memory_size(memory_size_string(n)) == n
     assert memory_size_string(1 << 30) == "1GB"
+
+
+def test_host_side_planes_import_without_jax():
+    """A chip belongs to one process. ETL executors, the store, the actor
+    runtime and the serving DRIVER (deployment + batcher) must be importable
+    — and are imported, in their own processes — without jax ever loading:
+    an executor with jax in it is one call away from contending with the
+    trainer for the chip (chip_smoke.py's fit phase asserts it live)."""
+    import subprocess
+    import sys
+
+    modules = [
+        "raydp_tpu.etl.executor", "raydp_tpu.etl.tasks",
+        "raydp_tpu.store.object_store", "raydp_tpu.cluster.worker",
+        "raydp_tpu.exchange", "raydp_tpu.exchange.dataset",
+        "raydp_tpu.serve", "raydp_tpu.serve.deployment",
+        "raydp_tpu.serve.batcher",
+    ]
+    program = (
+        "import importlib, sys\n"
+        f"for name in {modules!r}:\n"
+        "    importlib.import_module(name)\n"
+        "    assert 'jax' not in sys.modules, name + ' imported jax'\n"
+    )
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    done = subprocess.run(
+        [sys.executable, "-c", program], cwd=repo, capture_output=True,
+        text=True, timeout=120,
+    )
+    assert done.returncode == 0, done.stderr[-2000:]
