@@ -15,6 +15,7 @@ parquet-vs-object-store path and ``stop_etl_after_conversion`` (:332-363),
 
 from __future__ import annotations
 
+import contextlib
 import os
 import tempfile
 import time
@@ -23,6 +24,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Union
 
 import numpy as np
 
+from raydp_tpu import obs
 from raydp_tpu.estimator.base import EstimatorInterface, EtlEstimatorInterface
 from raydp_tpu.estimator.metrics import Metrics
 
@@ -104,15 +106,6 @@ def _put_stacked_batch(mesh, arr, shard_direct=True):
     return _fmap(
         lambda a: device_put_stacked(a, mesh, shard_direct=shard_direct), arr
     )
-
-
-def _compile_span(what):
-    """The one timer for AOT compile sites: the span's duration feeds
-    ``compile_seconds_`` and (when tracing ships) the trace timeline — no
-    parallel perf_counter bookkeeping."""
-    from raydp_tpu import obs
-
-    return obs.span("estimator.compile", what=str(what))
 
 
 def _scan_over_batches(step_impl, params, opt_state, xb, yb):
@@ -445,17 +438,18 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
             cache[key] = staged
             return staged
         groups = self._feature_groups()
-        if groups is not None:
-            features, labels = ds.to_numpy_grouped(
-                groups, self.label_column, label_dtype=self.label_dtype
-            )
-        else:
-            features, labels = ds.to_numpy(
-                self.feature_columns,
-                self.label_column,
-                feature_dtype=self.feature_dtype,
-                label_dtype=self.label_dtype,
-            )
+        with self._stage_span("host"):
+            if groups is not None:
+                features, labels = ds.to_numpy_grouped(
+                    groups, self.label_column, label_dtype=self.label_dtype
+                )
+            else:
+                features, labels = ds.to_numpy(
+                    self.feature_columns,
+                    self.label_column,
+                    feature_dtype=self.feature_dtype,
+                    label_dtype=self.label_dtype,
+                )
         p = jax.process_count()
         if p > 1:
             # slice this process's equal share in memory (no object-store
@@ -471,6 +465,38 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
             cache.pop(next(iter(cache)))
         cache[key] = staged
         return staged
+
+    @contextlib.contextmanager
+    def _stage_span(self, what):
+        """The one timer for staging (ETL frame → host arrays → device,
+        before the first dispatch): span ``exchange.stage``, whose duration
+        feeds the ``exchange.stage_seconds`` counter, readable mid-fit."""
+        with obs.span("exchange.stage", what=what) as span:
+            yield span
+        obs.metrics.counter("exchange.stage_seconds").inc(span.duration)
+
+    @contextlib.contextmanager
+    def _compile_span(self, what):
+        """The one timer for compile sites (lower, compile, load from the
+        cache, the FLOPs probe): span ``estimator.compile``, whose duration
+        feeds ``compile_seconds_`` and the ``estimator.compile_seconds``
+        counter, readable while a fit runs — no parallel perf_counter
+        bookkeeping."""
+        with obs.span("estimator.compile", what=str(what)) as span:
+            yield span
+        self.compile_seconds_ += span.duration
+        obs.metrics.counter("estimator.compile_seconds").inc(span.duration)
+
+    def _dispatch(self, compiled, steps, *args):
+        """One compiled call of ``steps`` train steps — ``(params,
+        opt_state, loss)`` comes back before the device has run them. Span
+        ``estimator.dispatch`` is the host's time inside the call (phase
+        ``dispatch``); the loss handle goes to the recorder, which learns
+        from it when the steps have completed."""
+        with obs.span("estimator.dispatch", steps=steps) as span:
+            out = compiled(*args)
+        self._step_recorder.dispatched(span.duration, out[2], steps)
+        return out
 
     def clear_staging_cache(self) -> None:
         """Release the staged host arrays AND the device-resident copy of
@@ -505,8 +531,6 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
 
         baseline = latest_checkpoint(self.checkpoint_dir) if retry_resume else None
         saved_resume = self.resume_from_epoch
-        from raydp_tpu import obs
-
         try:
             while True:
                 try:
@@ -590,16 +614,18 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
             eval_source = evaluate_ds
             first = next(i for i, c in enumerate(train_ds.counts) if c > 0)
             groups = self._feature_groups()
-            if groups is not None:
-                feats, _ = _table_to_numpy_grouped(
-                    train_ds.get_block(first), groups,
-                    self.label_column, self.label_dtype,
-                )
-            else:
-                feats, _ = _table_to_numpy(
-                    train_ds.get_block(first), self.feature_columns,
-                    self.label_column, self.feature_dtype, self.label_dtype,
-                )
+            with self._stage_span("sample_block"):
+                if groups is not None:
+                    feats, _ = _table_to_numpy_grouped(
+                        train_ds.get_block(first), groups,
+                        self.label_column, self.label_dtype,
+                    )
+                else:
+                    feats, _ = _table_to_numpy(
+                        train_ds.get_block(first), self.feature_columns,
+                        self.label_column, self.feature_dtype,
+                        self.label_dtype,
+                    )
             sample_np = _fmap(
                 lambda a: np.resize(a, (batch_size,) + a.shape[1:]), feats
             )
@@ -611,7 +637,6 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
             )
             sample_np = _fmap(lambda a: a[:batch_size], train_source.features)
 
-        from raydp_tpu import obs
         from raydp_tpu.obs import costmodel as _costmodel
         from raydp_tpu.obs import profiler as _profiler
 
@@ -624,13 +649,15 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
         fit_capture = self._fit_capture = _profiler.armed_capture()
         self._flops_per_step = None
         self._fit_step_wall = 0.0
+        self._mfu_mark = self._mfu_origin = None
         self._peak_info = _costmodel.device_peak_flops()
 
         from raydp_tpu.compile_cache import enable_compile_cache
 
         enable_compile_cache()
         rng = jax.random.PRNGKey(self.seed)
-        with obs.span("estimator.compile", what="init") as init_span:
+        self.compile_seconds_ = 0.0
+        with self._compile_span("init"):
             # one jitted init: flax init run eagerly compiles dozens of tiny
             # ops, which costs ~0.5s EACH on cold TPU backends (~30s total)
             sample = _fmap(jnp.asarray, sample_np)
@@ -638,7 +665,6 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                 lambda r, s: (lambda p: (p, tx.init(p)))(module.init(r, s))
             )(rng, sample)
             jax.block_until_ready(params)
-        init_compile = init_span.duration
         from raydp_tpu.parallel.partitioner import _mesh_device_count, _mesh_single_device
 
         if self.param_sharding_rules is not None:
@@ -668,13 +694,13 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
             def compute(p):
                 return loss_fn(module.apply(p, x), y)
 
-            loss, grads = jax.value_and_grad(compute)(params)
-            updates, opt_state2 = tx.update(grads, opt_state, params)
-            return (
-                optax.apply_updates(params, updates),
-                opt_state2,
-                loss_sum + loss,
-            )
+            # stable names in the device trace (metadata only)
+            with jax.named_scope("loss_and_grad"):
+                loss, grads = jax.value_and_grad(compute)(params)
+            with jax.named_scope("optimizer_update"):
+                updates, opt_state2 = tx.update(grads, opt_state, params)
+                params2 = optax.apply_updates(params, updates)
+            return params2, opt_state2, loss_sum + loss
 
         train_step = partial_jit(donate_argnums=donate)(step_impl)
 
@@ -739,8 +765,6 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                 start_epoch = resume_epoch
                 start_step = resume_step
 
-        import contextlib
-
         profile_ctx = (
             jax.profiler.trace(self.profile_dir)
             if self.profile_dir
@@ -748,7 +772,6 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
         )
 
         self._history = []
-        self.compile_seconds_ = init_compile
         first_step_done = False
         # the ExitStack is entered FIRST so its callbacks run LAST: the
         # streaming pipeline's close (registered below once the runner
@@ -838,6 +861,7 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                 ]
                 t_fit = time.perf_counter()
                 compile_before = self.compile_seconds_
+                self._mark_mfu_origin()
                 full = run_fullfit(params, opt_state, seeds)
                 if full is not None:
                     params, opt_state, losses, steps_per_epoch = full
@@ -855,13 +879,14 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                     ]
                     fullfit_done = True
 
+            if not fullfit_done:
+                self._mark_mfu_origin()
             for epoch in (
                 () if fullfit_done else range(start_epoch, self.num_epochs)
             ):
                 epoch_seed = None if not self.shuffle else self.seed + epoch
                 epoch_start_step = start_step if epoch == start_epoch else 0
                 phase_before = recorder.totals()
-                steps_before = getattr(recorder, "steps", 0)
                 # the epoch span IS the epoch timer: history's epoch_seconds
                 # is read from the same record the trace timeline shows
                 with obs.span(
@@ -914,8 +939,10 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                         # explicit next() so the step profiler can split
                         # each iteration into its phases: ingest (host
                         # slice + queue wait), h2d (device_put dispatch,
-                        # read from the iterator's own split), compute
-                        # (the train_step call), sync (the bounded fence)
+                        # read from the iterator's own split), dispatch
+                        # (the host's time inside the train_step call; no
+                        # span here: one per step would cost more than the
+                        # call), sync (the bounded fence)
                         profiled = recorder.enabled
                         t_loop0 = time.perf_counter()
                         while True:
@@ -948,39 +975,40 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                                 # separately
                                 if fit_capture is not None:
                                     fit_capture.begin_steps()
-                                with obs.span(
-                                    "estimator.compile", what="first_step"
-                                ) as cspan:
+                                with self._compile_span("first_step"):
                                     params, opt_state, loss_sum = train_step(
                                         params, opt_state, loss_sum, x, y
                                     )
                                     jax.block_until_ready(loss_sum)
-                                self.compile_seconds_ += cspan.duration
                                 first_step_done = True
                                 # XLA's own flops count for the live MFU
                                 # gauge: one extra lower()+compile(), served
                                 # from the (persistent) compilation cache
                                 # the first dispatch just filled
-                                self._flops_per_step = (
-                                    _costmodel.step_flops_from_jitted(
-                                        train_step, params, opt_state,
-                                        loss_sum, x, y,
+                                with self._compile_span("flops_probe"):
+                                    self._flops_per_step = (
+                                        _costmodel.step_flops_from_jitted(
+                                            train_step, params, opt_state,
+                                            loss_sum, x, y,
+                                        )
                                     )
-                                )
                                 # the compile step is NOT a steady-state
-                                # step: keep it (and the flops lookup) out
-                                # of both the compute histogram and the
-                                # step-wall clock the phases are gated
-                                # against — compile_seconds_ carries it
+                                # step: it counts as dispatched and
+                                # completed, and stays (with the flops
+                                # lookup) out of the dispatch histogram, the
+                                # live MFU's window and the step-wall clock
+                                # the phases are gated against —
+                                # compile_seconds_ carries it
+                                recorder.dispatched(None, loss_sum)
+                                self._mark_mfu_origin()
                                 t_loop0 += time.perf_counter() - t_c
                             else:
                                 params, opt_state, loss_sum = train_step(
                                     params, opt_state, loss_sum, x, y
                                 )
-                                if profiled:
-                                    recorder.note(
-                                        "compute", time.perf_counter() - t_c
-                                    )
+                                recorder.dispatched(
+                                    time.perf_counter() - t_c, loss_sum
+                                )
                             if fit_capture is not None:
                                 fit_capture.note_step()
                             steps += 1
@@ -1007,21 +1035,24 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                     if phase_delta:
                         # the analyzer's phase-split args: explain_last_fit
                         # attributes this epoch's interval into ingest/h2d/
-                        # compute/sync exactly like query stage spans split
+                        # dispatch/sync exactly like query stage spans split
                         # by read_s/compute_s/emit_s
                         epoch_span.set(
                             ingest_s=round(phase_delta.get("ingest", 0.0), 6),
                             h2d_s=round(phase_delta.get("h2d", 0.0), 6),
-                            compute_s=round(phase_delta.get("compute", 0.0), 6),
+                            dispatch_s=round(
+                                phase_delta.get("dispatch", 0.0), 6
+                            ),
                             sync_s=round(phase_delta.get("sync", 0.0), 6),
                         )
+                # steps DISPATCHED: the device may be an epoch behind
+                # (estimator.steps_completed, completed_steps())
                 obs.metrics.counter("estimator.steps").inc(steps)
-                # the RECORDER's step delta, not the loop's: the compile
-                # step is excluded from both numerator and denominator —
-                # the live gauge and fit_stats_ must describe one ratio
-                self._update_live_mfu(
-                    phase_delta, getattr(recorder, "steps", 0) - steps_before
-                )
+                # ship telemetry NOW, while the device still works through
+                # what was just dispatched: a flush after the epoch's
+                # closing fence (10-40 ms of RPC and memory sampling) is
+                # time the device has nothing to do
+                obs.flush_throttled(1.0)
                 if steps == 0 and epoch_start_step > 0:
                     # resumed exactly at this epoch's end (a stale final-step
                     # checkpoint from an older layout): nothing trained —
@@ -1045,6 +1076,14 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                                 eval_source, params, eval_fns, mesh, batch_size
                             )
                         )
+                if (
+                    eval_source is not None or recorder.drained
+                ) and epoch + 1 < self.num_epochs:
+                    # the epoch's closing fence (the evaluation's loss fetch,
+                    # or a sync fence with no dispatch since) has returned:
+                    # the device idles until the next epoch's first dispatch
+                    recorder.open_restart(epoch)
+                self._update_live_mfu()
                 self._history.append(record)
                 # EVERY process calls save: orbax's Checkpointer runs
                 # cross-process barriers and writes from the primary host
@@ -1067,13 +1106,6 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                 ) / max(self.num_epochs, 1)
                 for rec in self._history:
                     rec["epoch_seconds"] = per_epoch_s
-                # the whole fit was ONE dispatch: its fenced wall time is
-                # the only honest compute figure (per-step phases don't
-                # exist inside a single XLA program)
-                recorder.note(
-                    "compute", per_epoch_s * self.num_epochs,
-                    steps=self.num_epochs * steps_per_epoch,
-                )
             else:
                 stacked = np.asarray(
                     jnp.stack([rec["train_loss"][0] for rec in self._history])
@@ -1088,23 +1120,16 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
         # checkpointing does its own device_get
         self._params = params
         obs.metrics.counter("estimator.fits").inc()
-        obs.metrics.gauge("estimator.compile_s").set(self.compile_seconds_)
         # fit_stats_: the compute observatory's fit-level summary — phase
-        # totals, FLOPs accounting, and the MFU the live gauge reported
+        # totals, FLOPs accounting, and the live MFU's ratio over the whole
+        # fit (the history fetch above was the last fence)
         phase_totals = recorder.totals()
-        device_s = phase_totals.get("compute", 0.0) + phase_totals.get(
-            "sync", 0.0
-        )
         flops_step = getattr(self, "_flops_per_step", None)
-        steps_total = getattr(recorder, "steps", 0)
-        mfps = (
-            flops_step * steps_total / device_s
-            if flops_step and steps_total and device_s > 0
-            else None
-        )
+        mfps, _ = self._completed_flops_per_sec(self._mfu_origin)
         mfu_val = _costmodel.mfu(mfps, self._peak_info.get("peak"))
         self.fit_stats_ = {
-            "steps": steps_total,
+            "steps": getattr(recorder, "steps", 0),
+            "steps_completed": recorder.poll()[0],
             "step_phase_seconds": {
                 k: round(v, 6) for k, v in phase_totals.items()
             },
@@ -1139,7 +1164,7 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
     def explain_last_fit(self, top_k: int = 5) -> dict:
         """Critical-path wall-time attribution of the last ``fit()`` (the
         PR 14 analyzer over the fit's span tree: epoch leaves phase-split
-        into ingest/h2d/compute/sync by the step profiler's args). The
+        into ingest/h2d/dispatch/sync by the step profiler's args). The
         report's ``text`` field is human-readable."""
         records = getattr(self, "last_fit_records_", None)
         if not records:
@@ -1148,31 +1173,61 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
 
         return explain_fit(records, top_k=top_k)
 
-    def _update_live_mfu(self, phase_delta: Dict[str, float],
-                         steps: int) -> None:
-        """Refresh the ``estimator.mfu`` / ``estimator.model_flops_per_sec``
-        gauges from one epoch's measured device time (compute + sync phase
-        seconds) — called at every epoch boundary so a scrape MID-fit shows
-        the live number. Async backends undercount the denominator between
-        fences; ``sync_every_steps`` bounds the error (docs/observability.md
-        "Compute observatory")."""
-        flops_step = getattr(self, "_flops_per_step", None)
-        if not flops_step or not steps:
-            return
-        device_s = phase_delta.get("compute", 0.0) + phase_delta.get(
-            "sync", 0.0
+    def completed_steps(self) -> int:
+        """Train steps of the current (or last) fit that the DEVICE has
+        finished — ``estimator.steps`` counts dispatches, which run ahead of
+        it by up to an epoch. Read from any thread, mid-fit; blocks nothing
+        (the step profiler polls ``is_ready()`` of the loss handles the
+        compiled calls returned). 0 before the first fit, and with the step
+        profiler off (``RAYDP_TPU_STEP_PROFILER=0``)."""
+        recorder = getattr(self, "_step_recorder", None)
+        return recorder.poll()[0] if recorder is not None else 0
+
+    def _mark_mfu_origin(self) -> None:
+        """Where the live MFU starts counting: (steps completed so far, now,
+        compile seconds so far)."""
+        self._mfu_mark = self._mfu_origin = (
+            self._step_recorder.poll()[0],
+            time.perf_counter(),
+            self.compile_seconds_,
         )
-        if device_s <= 0.0:
+
+    def _completed_flops_per_sec(self, since):
+        """``(flops_per_step × Δsteps_completed / Δwall, mark)`` between the
+        mark ``since`` and the recorder's last observation of completion,
+        which is the returned mark. The wall time is everything between the
+        two observations but compiles: the evaluation and the epoch's
+        restart are inside it, as they are inside the rate a user sees; the
+        host's time in a dispatch never stands in for the device's. The
+        rate is None where nothing completed in between."""
+        now = (*self._step_recorder.poll(), self.compile_seconds_)
+        flops_step = getattr(self, "_flops_per_step", None)
+        if not flops_step or since is None:
+            return None, now
+        steps = now[0] - since[0]
+        wall = (now[1] - since[1]) - (now[2] - since[2])
+        if steps <= 0 or wall <= 0.0:
+            return None, now
+        return flops_step * steps / wall, now
+
+    def _update_live_mfu(self) -> None:
+        """Refresh the ``estimator.mfu`` / ``estimator.model_flops_per_sec``
+        gauges from the work the device COMPLETED since the last refresh —
+        called at every epoch's end, after its closing fence where it has
+        one, so a scrape MID-fit shows the live number (the epoch loop
+        ships it with its next flush, which it makes while the device is
+        busy). Without a fence the observation lags completion by up to one
+        dispatch (docs/observability.md "Compute observatory")."""
+        mfps, now = self._completed_flops_per_sec(self._mfu_mark)
+        if mfps is None:
             return
-        from raydp_tpu import obs
         from raydp_tpu.obs import costmodel
 
-        mfps = flops_step * steps / device_s
+        self._mfu_mark = now
         obs.metrics.gauge("estimator.model_flops_per_sec").set(mfps)
         mfu_val = costmodel.mfu(mfps, self._peak_info.get("peak"))
         if mfu_val is not None:
             obs.metrics.gauge("estimator.mfu").set(mfu_val)
-        obs.flush_throttled(1.0)
 
     def _note_step_flops_abstract(self, step_fn: Any, params: Any,
                                   opt_state: Any, batch_x: Any,
@@ -1194,14 +1249,15 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
             def sds(a):
                 return jax.ShapeDtypeStruct(a.shape, a.dtype)
 
-            self._flops_per_step = costmodel.step_flops_abstract(
-                step_fn,
-                jax.tree.map(sds, params),
-                jax.tree.map(sds, opt_state),
-                jax.ShapeDtypeStruct((), jnp.float32),
-                jax.tree.map(sds, batch_x),
-                jax.tree.map(sds, batch_y),
-            )
+            with self._compile_span("flops_probe"):
+                self._flops_per_step = costmodel.step_flops_abstract(
+                    step_fn,
+                    jax.tree.map(sds, params),
+                    jax.tree.map(sds, opt_state),
+                    jax.ShapeDtypeStruct((), jnp.float32),
+                    jax.tree.map(sds, batch_x),
+                    jax.tree.map(sds, batch_y),
+                )
         except Exception:  # raydp-lint: disable=swallowed-exceptions (flops stay unknown; the fit is unaffected)
             self._flops_per_step = None
 
@@ -1402,10 +1458,14 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
             segment k+1 DECODES while segment k's async device_put is in
             flight — block IO, wire encode, staging copy, and transfer all
             overlap."""
+            # from this thread's start to its first segment handed over:
+            # the streamed fit's staging
+            staging = obs.span(
+                "exchange.stage", what="stream_first_segment"
+            ).start()
 
             def _emit(item) -> bool:
-                from raydp_tpu import obs
-
+                nonlocal staging
                 t0 = time.perf_counter()
                 while not stop.is_set():
                     try:
@@ -1416,14 +1476,18 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                         obs.metrics.counter(
                             "estimator.stream.producer_idle_s"
                         ).inc(idle)
+                        if staging is not None:
+                            staging.finish()
+                            obs.metrics.counter("exchange.stage_seconds").inc(
+                                staging.duration
+                            )
+                            staging = None
                         return True
                     except queue.Full:  # raydp-lint: disable=swallowed-exceptions (bounded-queue backpressure loop)
                         continue
                 return False
 
             def _upload(hx, hy):
-                from raydp_tpu import obs
-
                 logical = _f_nbytes(hx) + hy.nbytes
                 if wire_on:
                     hx = _wire_encode(hx)
@@ -1435,16 +1499,16 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                     nbytes
                 )
                 obs.metrics.counter("estimator.stream.segments").inc()
-                t_up = time.perf_counter()
-                dx, dy = uploader.upload(hx, hy)
-                # producer-side H2D dispatch wall, normalized per-step by
-                # the segment's REAL batch count (hy is stacked [S, B] on
-                # both producer paths — the tail segment is shorter than
-                # seg); a lost cross-thread race costs one sample, like
-                # every other lock-free instrument
+                with obs.span("exchange.upload", bytes=nbytes) as up_span:
+                    dx, dy = uploader.upload(hx, hy)
+                # producer-side H2D dispatch wall (one segment's staging
+                # copy + device_put dispatch), normalized per-step by the
+                # segment's REAL batch count (hy is stacked [S, B] on both
+                # producer paths — the tail segment is shorter than seg); a
+                # lost cross-thread race costs one sample, like every other
+                # lock-free instrument
                 recorder.note(
-                    "h2d", time.perf_counter() - t_up,
-                    steps=max(1, hy.shape[0]),
+                    "h2d", up_span.duration, steps=max(1, hy.shape[0])
                 )
                 stats["staging_copies"] = uploader.staging_copies
                 return dx, dy
@@ -1544,8 +1608,16 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                 return
             pipe["q"] = queue.Queue(maxsize=self.stream_prefetch_segments)
             pipe["stop"] = threading.Event()
+            # the producer adopts the fit thread's trace context and
+            # collectors: its spans are real and parent under estimator.fit
+            ctx, sinks = obs.current_context(), obs.current_sinks()
+
+            def _adopted(*args):
+                with obs.use_context(ctx), obs.use_sinks(sinks):
+                    _produce_fit(*args)
+
             pipe["thread"] = threading.Thread(
-                target=_produce_fit,
+                target=_adopted,
                 args=(epoch_plan, list(epochs), pipe["q"], pipe["stop"]),
                 daemon=True,
             )
@@ -1622,11 +1694,10 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                 xb, yb = cache[int(oi)]
                 length = _f0(xb).shape[0]
                 if length not in compiled:
-                    with _compile_span(length) as cspan:
+                    with self._compile_span(length):
                         compiled[length] = jitted.lower(
                             params, opt_state, xb, yb
                         ).compile()
-                    self.compile_seconds_ += cspan.duration
                     self._note_step_flops_abstract(
                         scan_step, params, opt_state,
                         _fmap(
@@ -1637,12 +1708,8 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                         ),
                         jax.ShapeDtypeStruct(yb.shape[1:], yb.dtype),
                     )
-                t_c = time.perf_counter()
-                params, opt_state, loss_sum = compiled[length](
-                    params, opt_state, xb, yb
-                )
-                recorder.note(
-                    "compute", time.perf_counter() - t_c, steps=length
+                params, opt_state, loss_sum = self._dispatch(
+                    compiled[length], length, params, opt_state, xb, yb
                 )
                 loss_total = (
                     loss_sum if loss_total is None else loss_total + loss_sum
@@ -1668,13 +1735,13 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
             dispatches = 0
             cache_bytes = 0
             seg_q = pipe["q"]
-            from raydp_tpu import obs
-
             while True:
-                t0 = time.perf_counter()
-                item = seg_q.get()
-                # time parked on an EMPTY queue = transfer/producer-bound
-                idle = time.perf_counter() - t0
+                with obs.span("estimator.segment_wait") as wait_span:
+                    item = seg_q.get()
+                # time parked on an EMPTY queue = transfer/producer-bound;
+                # the span is the one measurement behind the stream stats,
+                # the stream counter and the ingest phase
+                idle = wait_span.duration
                 stats["consumer_idle_s"] += idle
                 obs.metrics.counter("estimator.stream.consumer_idle_s").inc(
                     idle
@@ -1704,11 +1771,10 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                     pending_save = None
                 length = _f0(xb).shape[0]
                 if length not in compiled:
-                    with _compile_span(length) as cspan:
+                    with self._compile_span(length):
                         compiled[length] = jitted.lower(
                             params, opt_state, xb, yb
                         ).compile()
-                    self.compile_seconds_ += cspan.duration
                     self._note_step_flops_abstract(
                         scan_step, params, opt_state,
                         _fmap(
@@ -1721,12 +1787,8 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                     )
                 if fit_capture is not None:
                     fit_capture.begin_steps()
-                t_c = time.perf_counter()
-                params, opt_state, loss_sum = compiled[length](
-                    params, opt_state, xb, yb
-                )
-                recorder.note(
-                    "compute", time.perf_counter() - t_c, steps=length
+                params, opt_state, loss_sum = self._dispatch(
+                    compiled[length], length, params, opt_state, xb, yb
                 )
                 if fit_capture is not None:
                     fit_capture.note_step(length)
@@ -1851,14 +1913,15 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                 # HBM; released by clear_staging_cache() or the next dataset.
                 xs_dev, ys_dev = cached[2], cached[3]
             else:
-                if device != jax.devices()[0]:
-                    xs_dev = jax.device_put(feats, device)  # pytree-ok
-                    ys_dev = jax.device_put(labs, device)
-                else:
-                    # default device: stay uncommitted (committed arrays
-                    # cost more per dispatch — see device_put_batch)
-                    xs_dev = _fmap(jnp.asarray, feats)
-                    ys_dev = jnp.asarray(labs)
+                with self._stage_span("device"):
+                    if device != jax.devices()[0]:
+                        xs_dev = jax.device_put(feats, device)  # pytree-ok
+                        ys_dev = jax.device_put(labs, device)
+                    else:
+                        # default device: stay uncommitted (committed arrays
+                        # cost more per dispatch — see device_put_batch)
+                        xs_dev = _fmap(jnp.asarray, feats)
+                        ys_dev = jnp.asarray(labs)
                 self._device_stage = (train_source, device, xs_dev, ys_dev)
 
             def make_gather(length):
@@ -1881,20 +1944,18 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                     order[start * batch_size : (start + length) * batch_size]
                 )
                 if length not in compiled:
-                    with _compile_span(length) as cspan:
+                    with self._compile_span(length):
                         compiled[length] = (
                             make_gather(length)
                             .lower(params, opt_state, xs_dev, ys_dev, perm)
                             .compile()
                         )
-                    self.compile_seconds_ += cspan.duration
                     _note_flops(params, opt_state)
                 if fit_capture is not None:
                     fit_capture.begin_steps()
-                t_c = time.perf_counter()
-                out = compiled[length](params, opt_state, xs_dev, ys_dev, perm)
-                recorder.note(
-                    "compute", time.perf_counter() - t_c, steps=length
+                out = self._dispatch(
+                    compiled[length], length,
+                    params, opt_state, xs_dev, ys_dev, perm,
                 )
                 if fit_capture is not None:
                     fit_capture.note_step(length)
@@ -1927,18 +1988,15 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                     "h2d", time.perf_counter() - t_h, steps=length
                 )
                 if length not in compiled:
-                    with _compile_span(length) as cspan:
+                    with self._compile_span(length):
                         compiled[length] = jitted.lower(
                             params, opt_state, xb, yb
                         ).compile()
-                    self.compile_seconds_ += cspan.duration
                     _note_flops(params, opt_state)
                 if fit_capture is not None:
                     fit_capture.begin_steps()
-                t_c = time.perf_counter()
-                out = compiled[length](params, opt_state, xb, yb)
-                recorder.note(
-                    "compute", time.perf_counter() - t_c, steps=length
+                out = self._dispatch(
+                    compiled[length], length, params, opt_state, xb, yb
                 )
                 if fit_capture is not None:
                     fit_capture.note_step(length)
@@ -2005,7 +2063,7 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                 perms = jnp.asarray(np.stack([_order(s) for s in seeds]))
                 key = ("fullfit", len(seeds))
                 if key not in compiled:
-                    with _compile_span("fullfit") as cspan:
+                    with self._compile_span("fullfit"):
                         compiled[key] = (
                             partial_jit(
                                 donate_argnums=(0, 1) if donate else (),
@@ -2013,10 +2071,12 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                             .lower(params, opt_state, xs_dev, ys_dev, perms)
                             .compile()
                         )
-                    self.compile_seconds_ += cspan.duration
                     _note_flops(params, opt_state)
-                params, opt_state, losses = compiled[key](
-                    params, opt_state, xs_dev, ys_dev, perms
+                # the whole fit is ONE dispatch; the history fetch is its
+                # fence, and completed work over that wall the live MFU
+                params, opt_state, losses = self._dispatch(
+                    compiled[key], len(seeds) * steps_per_epoch,
+                    params, opt_state, xs_dev, ys_dev, perms,
                 )
                 return params, opt_state, losses, steps_per_epoch
 

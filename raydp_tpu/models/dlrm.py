@@ -26,6 +26,7 @@ from __future__ import annotations
 from typing import Optional, Sequence
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from raydp_tpu.ops.backend import on_tpu
@@ -98,7 +99,11 @@ class DLRM(nn.Module):
             # meshes run it per-shard via shard_map (dot_interaction_fused) —
             # the dp×tp path keeps the kernel instead of falling back
             use_pallas = on_tpu()
-        interact = dot_interaction_fused(t) if use_pallas else dot_interaction(t)
+        # a stable name in the device trace, whichever path computes it
+        with jax.named_scope("dlrm_interaction"):
+            interact = (
+                dot_interaction_fused(t) if use_pallas else dot_interaction(t)
+            )
         z = jnp.concatenate([h, interact.astype(self.dtype)], axis=1)
 
         for width in self.top_mlp:

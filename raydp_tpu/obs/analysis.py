@@ -177,20 +177,23 @@ def _phase_split(node: _Node, lo: int, hi: int) -> Optional[List[dict]]:
 def _step_phase_split(node: _Node, args: dict, lo: int,
                       hi: int) -> Optional[List[dict]]:
     """Split a leaf EPOCH span by the step profiler's phase totals
-    (``ingest_s``/``h2d_s``/``compute_s``/``sync_s`` args, obs/profiler.py)
+    (``ingest_s``/``h2d_s``/``dispatch_s``/``sync_s`` args, obs/profiler.py)
     into the compute-plane categories — ``explain_last_fit`` gets the same
     fine-grained attribution queries get from the stage phase args. Time
     the phases don't cover stays the epoch's own (named) category.
 
     Gated on the step profiler's OWN keys (``ingest_s``/``h2d_s``/
-    ``sync_s``): ``compute_s`` alone must not claim a planner stage span,
-    whose read/compute/emit split belongs to the server-phase arm."""
+    ``sync_s``), so a planner stage span, whose read/compute/emit split
+    belongs to the server-phase arm, is never claimed. ``dispatch_s`` is
+    the host's time inside the compiled calls; where the device is the
+    bottleneck the wait for it shows as ``sync`` (or, at the epoch's end,
+    inside ``estimator.eval``)."""
     if not any(k in args for k in ("ingest_s", "h2d_s", "sync_s")):
         return None
     phases = [
         ("ingest", float(args.get("ingest_s", 0.0))),
         ("h2d", float(args.get("h2d_s", 0.0))),
-        ("compute", float(args.get("compute_s", 0.0))),
+        ("dispatch", float(args.get("dispatch_s", 0.0))),
         ("sync", float(args.get("sync_s", 0.0))),
     ]
     covered_s = sum(seconds for _, seconds in phases)

@@ -6,12 +6,16 @@ measured with (the xprof/JAX-profiler role in the TPU ecosystem, the
 Dapper-style complement to request tracing). Three pieces:
 
 - **Step profiler** (:class:`StepPhaseRecorder`): always-on per-step phase
-  decomposition of a fit — host decode/ingest wait, H2D upload, jitted
-  compute, device sync — feeding ``estimator.step.{ingest,h2d,compute,
-  sync}_ms`` histograms into the PR 14 TSDB (scrapeable mid-fit). The
-  instruments are the registry's lock-free histograms; overhead is gated
-  ≤5% on the fit step p50 in perf_smoke (``fit_profile_probe``), and
-  ``RAYDP_TPU_STEP_PROFILER=0`` turns the recorder into a shared no-op.
+  decomposition of a fit — host decode/ingest wait, H2D upload, the host's
+  time inside a compiled call (``dispatch``: the call returns before the
+  device finishes, so this is never device time), device sync — feeding
+  ``estimator.step.{ingest,h2d,dispatch,sync}_ms`` histograms into the PR 14
+  TSDB (scrapeable mid-fit), and the count of steps the device has
+  FINISHED, read without a fence from the returned loss handles
+  (``estimator.steps_completed``). The instruments are the registry's
+  lock-free histograms; overhead is gated ≤5% on the fit step p50 in
+  perf_smoke (``fit_profile_probe``), and ``RAYDP_TPU_STEP_PROFILER=0``
+  turns the recorder into a shared no-op.
 - **Capture window** (:class:`CaptureWindow` / :func:`profile_fit`): an
   on-demand deep capture — wraps ``jax.profiler`` start/stop_trace when
   the backend supports it, and ALWAYS collects the obs span records of the
@@ -32,6 +36,7 @@ memory sampler — a ``python -S`` worker without jax must flush cleanly).
 
 from __future__ import annotations
 
+import collections
 import json
 import os
 import sys
@@ -45,7 +50,7 @@ STEP_PROFILER_ENV = "RAYDP_TPU_STEP_PROFILER"
 ARTIFACTS_DIR_ENV = "RAYDP_TPU_ARTIFACTS_DIR"
 JAX_PROFILER_ENV = "RAYDP_TPU_JAX_PROFILER"
 
-STEP_PHASES = ("ingest", "h2d", "compute", "sync")
+STEP_PHASES = ("ingest", "h2d", "dispatch", "sync")
 
 _step_profiler_on = os.environ.get(STEP_PROFILER_ENV, "1") not in (
     "0", "false", "False"
@@ -86,8 +91,19 @@ class _NoopRecorder:
     __slots__ = ()
     enabled = False
     steps = 0
+    steps_dispatched = 0
+    drained = False
 
     def note(self, phase: str, seconds: float, steps: int = 1) -> None:
+        pass
+
+    def dispatched(self, seconds, handle, steps: int = 1) -> None:
+        pass
+
+    def poll(self):
+        return 0, 0.0
+
+    def open_restart(self, epoch: int) -> None:
         pass
 
     def totals(self) -> Dict[str, float]:
@@ -96,9 +112,15 @@ class _NoopRecorder:
 
 _NOOP_RECORDER = _NoopRecorder()
 
+# in-flight dispatches remembered for poll(): entries carry CUMULATIVE step
+# counts, so one that falls off the left end loses nothing — the next ready
+# handle advances the count past it
+_INFLIGHT_MAX = 1024
+
 
 class StepPhaseRecorder:
-    """Accumulates one fit's per-step phase decomposition.
+    """Accumulates one fit's per-step phase decomposition, and counts the
+    steps the device has finished.
 
     ``note(phase, seconds, steps)`` charges ``seconds`` of wall time to a
     phase across ``steps`` train steps: the per-step loop calls it once per
@@ -106,26 +128,113 @@ class StepPhaseRecorder:
     histogram then records the per-step average for that segment — the
     honest granularity when S steps ride one dispatch). Instruments are
     resolved ONCE (the per-step hot path is a float add + a lock-free
-    histogram observe)."""
+    histogram observe).
 
-    __slots__ = ("enabled", "steps", "_totals", "_hists")
+    ``dispatched(seconds, handle, steps)`` follows every compiled call: the
+    host's ``seconds`` inside the call go to phase ``dispatch`` (observed
+    per DISPATCH, not per step: that is what the host pays), and ``handle``
+    — the loss array the call returned — is kept with the cumulative step
+    count. ``poll()`` pops the heads whose ``is_ready()`` is true, which
+    blocks nothing, and so learns how far the device has got:
+    ``estimator.steps_completed``. Dispatched minus completed is the
+    host's run-ahead. Safe from any thread."""
+
+    __slots__ = ("enabled", "steps", "drained", "_totals", "_hists", "_lock",
+                 "_inflight", "steps_dispatched", "_completed", "_seen_at",
+                 "_completed_counter", "_restart", "_restart_hist")
 
     def __init__(self):
         self.enabled = True
         self.steps = 0
+        # the last thing the fit did to the device was wait for it (a sync
+        # fence with no dispatch since): an epoch that ends so has a closing
+        # fence even without an evaluation
+        self.drained = False
         self._totals = {phase: 0.0 for phase in STEP_PHASES}
         self._hists = {
             phase: metrics.histogram(f"estimator.step.{phase}_ms")
             for phase in STEP_PHASES
         }
+        self._lock = threading.Lock()
+        self._inflight: "collections.deque" = collections.deque(
+            maxlen=_INFLIGHT_MAX
+        )
+        self.steps_dispatched = 0
+        self._completed = 0
+        self._seen_at = time.perf_counter()
+        self._completed_counter = metrics.counter("estimator.steps_completed")
+        self._restart = None
+        self._restart_hist = metrics.histogram("estimator.epoch.restart_ms")
 
     def note(self, phase: str, seconds: float, steps: int = 1) -> None:
         if seconds < 0.0:
             seconds = 0.0
         self._totals[phase] += seconds
-        if phase == "compute":
+        if phase == "dispatch":
             self.steps += steps
+            steps = 1
         self._hists[phase].observe(seconds / max(steps, 1) * 1000.0)
+        if phase == "sync":
+            # an existing fence just returned: everything dispatched is done
+            self.drained = True
+            self.poll()
+
+    def dispatched(self, seconds: Optional[float], handle: Any,
+                   steps: int = 1) -> None:
+        """A compiled call returned ``handle`` after ``seconds`` on the
+        host (None: a compiling first call, counted but kept out of the
+        steady-state phase)."""
+        if seconds is not None:
+            self.note("dispatch", seconds, steps)
+        self.drained = False
+        restart = self._restart
+        if restart is not None:
+            # the first dispatch after an epoch's closing fence is back: the
+            # device has work again
+            self._restart = None
+            restart.finish()
+            self._restart_hist.observe(restart.duration * 1000.0)
+        with self._lock:
+            self.steps_dispatched += steps
+            self._inflight.append((self.steps_dispatched, handle))
+        self.poll()
+
+    def poll(self):
+        """(steps the device has finished, ``perf_counter`` when the last
+        advance was seen). Never blocks: ``jax.Array.is_ready()``. Steps
+        finish in dispatch order, so a handle a later step consumed
+        (donated: deleted) is dropped and its steps are counted with the
+        next ready one."""
+        with self._lock:
+            inflight = self._inflight
+            done = None
+            while inflight:
+                count, handle = inflight[0]
+                try:
+                    ready = handle.is_ready()
+                except RuntimeError:
+                    ready = None  # donated to a later step: deleted
+                if ready is False:
+                    break
+                if ready:
+                    done = count
+                inflight.popleft()
+            if done is not None:
+                self._completed_counter.inc(done - self._completed)
+                self._completed = done
+                self._seen_at = time.perf_counter()
+            return self._completed, self._seen_at
+
+    def open_restart(self, epoch: int) -> None:
+        """An epoch's closing fence has returned and another epoch follows:
+        until the next dispatch returns the device provably has nothing to
+        do because of the host (history append, checkpoint, permutation
+        ship, queue get, dispatch). ``estimator.epoch.restart_ms`` takes one
+        observation per epoch boundary, from the ``estimator.restart``
+        span's one clock."""
+        from raydp_tpu.obs import tracing
+
+        self._restart = tracing.span("estimator.restart", epoch=epoch).start()
 
     def totals(self) -> Dict[str, float]:
         return dict(self._totals)
@@ -301,7 +410,7 @@ def capture(out_dir: Optional[str] = None,
 def explain_fit(records: List[dict], top_k: int = 5) -> dict:
     """Critical-path attribution of one fit's span records (the PR 14
     analyzer over the ``estimator.fit`` tree: epoch/compile/eval children,
-    epoch leaves phase-split by the step profiler's ingest/h2d/compute/sync
+    epoch leaves phase-split by the step profiler's ingest/h2d/dispatch/sync
     args). ``JaxEstimator.explain_last_fit()`` is the instance-method
     spelling."""
     from raydp_tpu.obs.analysis import attribute, format_report

@@ -5,6 +5,12 @@ trace, id, parent, args}`` with ``ts``/``dur`` in microseconds of wall time
 (``time.time_ns`` — one comparable timeline across processes on a machine;
 multi-host traces carry each host's clock, see docs/observability.md).
 
+In a process that has ALREADY imported jax, every real span also opens a
+``jax.profiler.TraceAnnotation`` of its name: while a profiler session runs
+the span lies in the trace's ``/host:`` plane, on the device trace's clock
+(one inactive TraceMe otherwise). This module never imports jax itself — an
+executor without jax pays one ``sys.modules`` lookup per real span.
+
 Two consumers, decoupled:
 
 - **collectors** (thread-local, always available): ``with collect() as got:``
@@ -28,6 +34,7 @@ from __future__ import annotations
 import atexit
 import collections
 import os
+import sys
 import threading
 import time
 import uuid
@@ -149,13 +156,40 @@ class _NoopSpan:
     def set(self, **attrs):
         return self
 
+    def start(self):
+        return self
+
+    def finish(self):
+        pass
+
 
 _NOOP = _NoopSpan()
+
+# jax.profiler.TraceAnnotation, resolved the first time a span opens in a
+# process where jax is loaded (the _device_live_bytes rule: obs never
+# imports jax, and a process without it pays nothing)
+_trace_annotation = None
+
+
+def _open_annotation(name: str):
+    """The span's twin in the profiler's trace, entered — or None where jax
+    is not loaded (or only half imported on another thread)."""
+    global _trace_annotation
+    cls = _trace_annotation
+    if cls is None:
+        jax = sys.modules.get("jax")
+        cls = getattr(getattr(jax, "profiler", None), "TraceAnnotation", None)
+        if cls is None:
+            return None
+        _trace_annotation = cls
+    annotation = cls(name)
+    annotation.__enter__()
+    return annotation
 
 
 class Span:
     __slots__ = ("name", "args", "trace", "id", "parent", "_t0", "_ts",
-                 "duration", "_saved_ctx", "_ship")
+                 "duration", "_saved_ctx", "_ship", "_nested", "_annotation")
 
     def __init__(self, name: str, args: Dict[str, Any], ship: bool):
         self.name = name
@@ -169,6 +203,8 @@ class Span:
         self.id = uuid.uuid4().hex[:16]
         self._ship = ship
         self._saved_ctx = ctx
+        self._nested = False
+        self._annotation = None
         self.duration = 0.0
         self._ts = time.time_ns() // 1000
         self._t0 = time.perf_counter()
@@ -179,11 +215,27 @@ class Span:
 
     def __enter__(self) -> "Span":
         _set_context((self.trace, self.id))
+        self._nested = True
+        self._annotation = _open_annotation(self.name)
         return self
+
+    def start(self) -> "Span":
+        """Open WITHOUT becoming the context later spans parent under, for
+        an interval that straddles other spans' boundaries (the fit's
+        epoch restart runs from one epoch's fence into the next epoch's
+        span); ``finish()`` closes it, on the same thread."""
+        self._annotation = _open_annotation(self.name)
+        return self
+
+    def finish(self) -> None:
+        self.__exit__(None, None, None)
 
     def __exit__(self, exc_type, exc, tb):
         self.duration = time.perf_counter() - self._t0
-        _set_context(self._saved_ctx)
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+        if self._nested:
+            _set_context(self._saved_ctx)
         if exc_type is not None:
             self.args.setdefault("error", exc_type.__name__)
         record = {

@@ -151,5 +151,6 @@ def _interaction_forward(
         ],
         out_specs=pl.BlockSpec((block_batch, out_f), lambda i: (i, 0)),
         interpret=interpret,
+        name="dlrm_interaction",
     )(stacked)
     return out[:b]

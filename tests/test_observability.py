@@ -32,6 +32,38 @@ def test_span_disabled_fast_path_is_noop():
     with s as entered:
         entered.set(b=2)
     assert s.duration == 0.0
+    # ... and answer the detached form too, still as the shared object
+    assert s.start() is tracing._NOOP
+    s.finish()
+    assert s.duration == 0.0
+
+
+def test_span_without_jax_never_imports_it():
+    """A real span in a process that has not imported jax works, and obs is
+    not what drags jax in (the profiler bridge looks in sys.modules only)."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "from raydp_tpu import obs\n"
+        "from raydp_tpu.obs import tracing\n"
+        "with obs.collect() as got:\n"
+        "    with obs.span('etl.stage', n=1) as s:\n"
+        "        pass\n"
+        "    d = obs.span('etl.detached').start()\n"
+        "    d.finish()\n"
+        "assert [r['name'] for r in got] == ['etl.stage', 'etl.detached']\n"
+        "assert s._annotation is None and tracing._trace_annotation is None\n"
+        "assert 'jax' not in sys.modules, 'obs imported jax'\n"
+        "print('ok')\n"
+    )
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = subprocess.run(
+        [sys.executable, "-c", code], cwd=repo, capture_output=True,
+        text=True, timeout=60,
+    )
+    assert out.returncode == 0 and out.stdout.strip() == "ok", out.stderr
 
 
 def test_collector_captures_spans_and_instants():

@@ -101,7 +101,7 @@ def test_timeseries_max_fanout():
     store = SeriesStore()
     store.ingest("driver:1", "driver", {
         "mem.rss_bytes": {"type": "gauge", "value": 10.0, "max": 50.0},
-        "estimator.step.compute_ms": {
+        "estimator.step.dispatch_ms": {
             "type": "histogram", "count": 4, "sum": 8.0, "min": 1.0,
             "max": 5.0, "mean": 2.0, "p50": 2.0, "p99": 5.0,
         },
@@ -109,7 +109,7 @@ def test_timeseries_max_fanout():
     names = store.series_names()
     assert "mem.rss_bytes" in names
     assert "mem.rss_bytes.max" in names
-    assert "estimator.step.compute_ms.max" in names
+    assert "estimator.step.dispatch_ms.max" in names
     peak = store.query("mem.rss_bytes.max")
     assert peak and peak[0]["last"] == 50.0
 
@@ -136,8 +136,8 @@ def test_step_phase_histograms_present_and_sane(per_step_fit):
     # first (compile) step is excluded from the steady-state histograms
     assert stats["steps"] == steps_expected - 1
     phases = stats["step_phase_seconds"]
-    assert set(phases) == {"ingest", "h2d", "compute", "sync"}
-    assert phases["compute"] > 0.0
+    assert set(phases) == {"ingest", "h2d", "dispatch", "sync"}
+    assert phases["dispatch"] > 0.0
     # phases tile the measured step-loop wall: the sum must account for
     # (nearly) all of it — an uninstrumented gap shows up here first
     wall = stats["step_wall_s"]
@@ -146,7 +146,8 @@ def test_step_phase_histograms_present_and_sane(per_step_fit):
     assert 0.7 * wall <= covered <= 1.1 * wall, (covered, wall)
     # the registry carries the per-step histograms (scrapeable mid-fit)
     snap = obs.metrics.snapshot()
-    for phase in ("ingest", "h2d", "compute"):
+    assert "estimator.step.compute_ms" not in snap
+    for phase in ("ingest", "h2d", "dispatch"):
         hist = snap[f"estimator.step.{phase}_ms"]
         assert hist["type"] == "histogram" and hist["count"] > 0
         assert hist["max"] >= hist["p50"] >= 0.0
@@ -158,8 +159,10 @@ def test_explain_last_fit_attribution(per_step_fit):
     assert report["root"] == "estimator.fit"
     # acceptance gate: ≥0.9 of the fit's wall time lands in NAMED segments
     assert report["attributed_frac"] >= 0.9, report["text"]
-    # the step-phase split surfaces real compute-plane categories
-    assert report["by_category"].get("compute", 0.0) > 0.0
+    # the step-phase split surfaces real compute-plane categories: the
+    # host's time inside the step calls, and the fences it waited at
+    assert report["by_category"].get("dispatch", 0.0) > 0.0
+    assert report["by_category"].get("sync", 0.0) > 0.0
     assert "compile" in report["by_category"]
     assert report["text"].startswith("critical path of estimator.fit")
 
@@ -179,6 +182,13 @@ def test_live_mfu_vs_analytic_parity(per_step_fit):
     assert obs.metrics.gauge("estimator.mfu").value == pytest.approx(
         stats["mfu"]
     )
+    # the ratio is completed work over the wall time between two
+    # observations of completion, never over the host's time in dispatches
+    assert stats["steps_completed"] == 2 * (2048 // 64)
+    wall = stats["flops_per_step"] * (stats["steps_completed"] - 1) / (
+        stats["model_flops_per_sec"]
+    )
+    assert wall > stats["step_phase_seconds"]["dispatch"]
 
 
 def test_scan_path_reports_same_flops(host_ds, per_step_fit):
@@ -212,6 +222,176 @@ def test_step_profiler_off_is_noop(host_ds):
         assert est.fit_stats_["step_phase_seconds"] == {}
     finally:
         profiler.set_step_profiler(True)
+
+
+# ---------------------------------------------------------------------------
+# completed steps, the epoch restart, stable device names (ISSUE 24)
+# ---------------------------------------------------------------------------
+
+_RUNNERS = {
+    # the per-step loop
+    "per_step": dict(scan_epochs=False),
+    # staged data too large for the whole-epoch scan: 8-step segment scans
+    # fed by the producer thread
+    "segment_streamed": dict(scan_memory_limit=1, stream_scan_steps=8),
+    # lax.scan epochs over the device-resident copy
+    "resident_scan": dict(),
+}
+
+
+@pytest.mark.parametrize("runner", sorted(_RUNNERS))
+def test_completed_steps_from_second_thread(host_ds, runner):
+    """completed_steps() read mid-fit from another thread is monotone, never
+    above the dispatched count, and equals it once the fit is over."""
+    import threading
+
+    est = _make_est(num_epochs=3, **_RUNNERS[runner])
+    seen, stop = [], threading.Event()
+
+    def watch():
+        while not stop.is_set():
+            completed = est.completed_steps()
+            recorder = getattr(est, "_step_recorder", None)
+            seen.append((completed, getattr(recorder, "steps_dispatched", 0)))
+
+    counted_before = obs.metrics.counter("estimator.steps_completed").value
+    watcher = threading.Thread(target=watch, daemon=True)
+    watcher.start()
+    try:
+        # the evaluation keeps the resident runner off the one-dispatch
+        # whole-fit path, and gives every epoch a closing fence
+        est.fit(host_ds, host_ds)
+    finally:
+        stop.set()
+        watcher.join(timeout=10)
+    total = 3 * (2048 // 64)
+    assert est.completed_steps() == total
+    assert est._step_recorder.steps_dispatched == total
+    assert est.fit_stats_["steps_completed"] == total
+    assert obs.metrics.counter(
+        "estimator.steps_completed"
+    ).value - counted_before == total
+    completed = [c for c, _ in seen]
+    assert completed == sorted(completed)
+    assert all(c <= d for c, d in seen), [p for p in seen if p[0] > p[1]][:3]
+    if runner == "segment_streamed":
+        names = {r["name"] for r in est.last_fit_records_}
+        # the producer thread adopted the fit's context and collectors
+        assert {"exchange.upload", "estimator.segment_wait",
+                "estimator.dispatch", "exchange.stage"} <= names
+        fit_id = next(r["id"] for r in est.last_fit_records_
+                      if r["name"] == "estimator.fit")
+        assert all(r["parent"] == fit_id for r in est.last_fit_records_
+                   if r["name"] == "exchange.upload")
+
+
+def test_restart_histogram_one_observation_per_epoch_boundary(host_ds):
+    hist = obs.metrics.histogram("estimator.epoch.restart_ms")
+    before = hist.count
+    est = _make_est(num_epochs=4)
+    est.fit(host_ds, host_ds)
+    assert hist.count - before == 3
+    restarts = [r for r in est.last_fit_records_
+                if r["name"] == "estimator.restart"]
+    assert len(restarts) == 3
+    # a restart runs from one epoch's fence INTO the next epoch's span
+    epochs = {r["args"]["epoch"]: r for r in est.last_fit_records_
+              if r["name"] == "estimator.epoch"}
+    for r in restarts:
+        nxt = epochs[r["args"]["epoch"] + 1]
+        assert r["ts"] <= nxt["ts"] < r["ts"] + max(r["dur"], 1) + 1
+    # no evaluation and no sync fence: no closing fence, nothing observed
+    est = _make_est(num_epochs=3, sync_every_steps=0, scan_epochs=False)
+    before = hist.count
+    est.fit(host_ds)
+    assert hist.count == before
+
+
+def test_compile_and_stage_counters_readable(host_ds):
+    compile_c = obs.metrics.counter("estimator.compile_seconds")
+    stage_c = obs.metrics.counter("exchange.stage_seconds")
+    before = compile_c.value, stage_c.value
+    est = _make_est(num_epochs=1)
+    est._stage_cache = {}
+    est.fit(_HostDs(host_ds._f.copy(), host_ds._l.copy()))
+    assert compile_c.value - before[0] == pytest.approx(est.compile_seconds_)
+    whats = {r["args"]["what"] for r in est.last_fit_records_
+             if r["name"] == "estimator.compile"}
+    assert {"init", "flops_probe"} <= whats
+    assert stage_c.value > before[1]
+    assert "estimator.compile_s" not in obs.metrics.snapshot()
+
+
+def test_step_hlo_carries_scope_names(monkeypatch):
+    """The table update, the loss/gradient and the interaction call have
+    stable names in the compiled step: what a trace reader finds them by."""
+    import jax
+
+    from raydp_tpu.models import DLRM
+
+    lowered = {}
+
+    def probe(fn, *args):
+        lowered["text"] = jax.jit(fn).lower(*args).compile().as_text()
+        return 1.0
+
+    monkeypatch.setattr(costmodel, "step_flops_abstract", probe)
+    vocab = (11, 7)
+    rng = np.random.default_rng(0)
+    dense = rng.random((256, 3)).astype(np.float32)
+    ids = np.stack([rng.integers(0, v, 256) for v in vocab], 1)
+
+    class _Grouped(_HostDs):
+        def to_numpy_grouped(self, groups, label_column, label_dtype):
+            return (dense, ids.astype(np.int32)), self._l.astype(label_dtype)
+
+    est = JaxEstimator(
+        model=DLRM(vocab_sizes=vocab, num_dense=3, embed_dim=4,
+                   bottom_mlp=(8, 4), top_mlp=(8, 1),
+                   use_pallas_interaction=False),
+        optimizer="adagrad", loss="bce",
+        feature_columns=["i0", "i1", "i2", "c0", "c1"],
+        categorical_columns=["c0", "c1"], label_column="y", batch_size=64,
+        num_epochs=1, seed=1, mesh=_single_device_mesh(),
+    )
+    est.fit(_Grouped(dense, (rng.random(256) > 0.5).astype(np.float32)))
+    for scope in ("loss_and_grad", "optimizer_update", "dlrm_interaction"):
+        assert scope in lowered["text"], scope
+
+
+def test_span_lies_in_profiler_trace_host_plane(tmp_path):
+    """A program span opened while a jax.profiler session runs is an event
+    of its own name in the trace's host plane (the device trace's clock)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    with jax.profiler.trace(str(tmp_path)):
+        with obs.collect() as got:
+            with obs.span("estimator.test_bridge", k=1):
+                jnp.ones((8,)).block_until_ready()
+            detached = obs.span("estimator.test_detached").start()
+            with obs.span("estimator.test_inner"):
+                pass
+            detached.finish()
+    # the span's own record is what it always was
+    record = next(r for r in got if r["name"] == "estimator.test_bridge")
+    assert record["args"] == {"k": 1} and record["dur"] >= 0
+    inner = next(r for r in got if r["name"] == "estimator.test_inner")
+    outer = next(r for r in got if r["name"] == "estimator.test_detached")
+    assert inner["parent"] != outer["id"]  # start() installs no context
+    (path,) = glob.glob(
+        os.path.join(str(tmp_path), "plugins", "profile", "*", "*.xplane.pb")
+    )
+    host_events = {
+        ev.name
+        for plane in ProfileData.from_file(path).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for ev in line.events
+    }
+    assert {"estimator.test_bridge", "estimator.test_detached",
+            "estimator.test_inner"} <= host_events
 
 
 # ---------------------------------------------------------------------------
