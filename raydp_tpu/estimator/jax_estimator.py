@@ -108,6 +108,40 @@ def _put_stacked_batch(mesh, arr, shard_direct=True):
     )
 
 
+def make_train_step(module, loss_fn, tx, row_paths=()):
+    """The one train-step body that the scan runner, the stream runner and
+    the per-step loop wrap: ``(params, opt_state, loss_sum, x, y) ->
+    (params, opt_state, loss_sum + loss)``. ``row_paths``: the parameters
+    that are differentiated and updated by the rows the batch read
+    (``row_update.plan`` decides them); none is the dense step."""
+    import jax
+    import optax
+
+    from raydp_tpu.estimator import row_update
+
+    # loss accumulates ON DEVICE: a host float(loss) per step would force
+    # a sync and serialize the H2D/compute pipeline (measured 6× slowdown)
+    def step_impl(params, opt_state, loss_sum, x, y):
+        if row_paths:
+            params2, opt_state2, loss = row_update.step(
+                module, loss_fn, tx, row_paths, params, opt_state, x, y
+            )
+            return params2, opt_state2, loss_sum + loss
+
+        def compute(p):
+            return loss_fn(module.apply(p, x), y)
+
+        # stable names in the device trace (metadata only)
+        with jax.named_scope("loss_and_grad"):
+            loss, grads = jax.value_and_grad(compute)(params)
+        with jax.named_scope("optimizer_update"):
+            updates, opt_state2 = tx.update(grads, opt_state, params)
+            params2 = optax.apply_updates(params, updates)
+        return params2, opt_state2, loss_sum + loss
+
+    return step_impl
+
+
 def _scan_over_batches(step_impl, params, opt_state, xb, yb):
     """Run the train step over stacked batches [S, B, ...] with ONE
     ``lax.scan`` — the shared core of the whole-epoch and segment-stream
@@ -332,6 +366,7 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
         self._params = None
         self._history: List[Dict[str, float]] = []
         self.compile_seconds_: float = 0.0
+        self._row_plan = None  # row_update.RowPlan, once a fit has decided it
 
     # ------------------------------------------------------------------
     # component resolution (instance-or-creator, reference :88-136)
@@ -484,6 +519,11 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
         bookkeeping."""
         with obs.span("estimator.compile", what=str(what)) as span:
             yield span
+            if self._row_plan is not None:  # a program of this fit's step
+                span.set(
+                    row_update_params=len(self._row_plan.paths),
+                    row_update_bytes_skipped=self._row_plan.bytes_skipped,
+                )
         self.compile_seconds_ += span.duration
         obs.metrics.counter("estimator.compile_seconds").inc(span.duration)
 
@@ -657,6 +697,7 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
         enable_compile_cache()
         rng = jax.random.PRNGKey(self.seed)
         self.compile_seconds_ = 0.0
+        self._row_plan = None
         with self._compile_span("init"):
             # one jitted init: flax init run eagerly compiles dozens of tiny
             # ops, which costs ~0.5s EACH on cold TPU backends (~30s total)
@@ -688,19 +729,23 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
 
         donate = (0, 1, 2) if self.donate_state else ()
 
-        # loss accumulates ON DEVICE: a host float(loss) per step would force
-        # a sync and serialize the H2D/compute pipeline (measured 6× slowdown)
-        def step_impl(params, opt_state, loss_sum, x, y):
-            def compute(p):
-                return loss_fn(module.apply(p, x), y)
+        # which parameters the step differentiates and updates by the rows
+        # the batch read, decided once per fit from what the model declares,
+        # how the optimizer behaves on a toy tree and the tables' shapes
+        # (row_update.py); none: the dense step, traced as ever
+        from raydp_tpu.estimator import row_update
 
-            # stable names in the device trace (metadata only)
-            with jax.named_scope("loss_and_grad"):
-                loss, grads = jax.value_and_grad(compute)(params)
-            with jax.named_scope("optimizer_update"):
-                updates, opt_state2 = tx.update(grads, opt_state, params)
-                params2 = optax.apply_updates(params, updates)
-            return params2, opt_state2, loss_sum + loss
+        # a span of its own: the probe runs eagerly on the host and compiles
+        # no program of the fit, so it stays out of compile_seconds_
+        with obs.span("estimator.row_update_probe") as probe_span:
+            row_plan = row_update.plan(module, tx, params, sample, batch_size)
+        self._row_plan = row_plan
+        obs.metrics.gauge("estimator.row_update.params").set(len(row_plan.paths))
+        obs.metrics.gauge("estimator.row_update.bytes_skipped").set(
+            row_plan.bytes_skipped
+        )
+
+        step_impl = make_train_step(module, loss_fn, tx, row_plan.paths)
 
         train_step = partial_jit(donate_argnums=donate)(step_impl)
 
@@ -1143,6 +1188,9 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
             "device_kind": self._peak_info.get("kind"),
             "peak_source": self._peak_info.get("peak_source"),
             "profiler": "on" if recorder.enabled else "off",
+            "row_update": {
+                **row_plan.stats(), "probe_seconds": probe_span.duration,
+            },
         }
         if mfps:
             obs.metrics.gauge("estimator.model_flops_per_sec").set(mfps)

@@ -1,0 +1,353 @@
+"""Row-wise optimizer update: a train step that differentiates and updates
+only the rows of a parameter that the batch read.
+
+A dense step differentiates with respect to a whole embedding table: XLA
+zero-fills a ``[V, D]`` gradient, scatter-adds the batch's ``B`` rows into it
+and runs the optimizer over all ``V`` rows of table and state, though at most
+``B`` of them changed. Here the step gathers the ``<= B`` distinct rows the
+batch reads, differentiates with respect to those, runs the SAME
+``tx.update`` on the mini-tree (those rows of the parameter and of every
+parameter-shaped leaf of the optimizer state, every other leaf whole) and
+scatters the rows back in place. The pytrees of ``params`` and ``opt_state``
+keep their structure and shapes.
+
+That is the same mathematics only where the optimizer is row-local and leaves
+a row with a zero gradient, and its state, as they were. Nothing here knows
+an optimizer by name: :func:`plan` looks at the state the transformation
+keeps (nothing beside copies of the parameters, so that it cannot count
+steps) and observes it on a toy tree (:func:`probe`). Three things decide,
+all observed, none set by a user: the model (it has to declare
+``row_gathers``), the optimizer (its state and the probe) and the shape (a
+table of few rows is cheaper updated whole).
+"""
+
+from __future__ import annotations
+
+import math
+from dataclasses import dataclass
+from typing import Any, Dict, Optional, Tuple
+
+import numpy as np
+
+# A declared table takes the row path from this many rows per row of the
+# batch on. Measured on the v5e at batch 2048, embed 16, Adagrad, with the
+# scatter of _put below (PERF.md, Findings, PR 25, chip call E): the whole
+# step with this constant at 4 / 32 / 64 / 128 takes 5.88 / 5.80 / 5.78-5.80
+# / 6.31 ms. At 6-7 rows per row of the batch (12,517 and 14,992 rows) the
+# dense update is the cheaper by 0.04 ms a table, at 45 (93,145 rows) the
+# two tie within 0.3 % of the step, at 70 (142,572 rows) the row path wins
+# by 0.53 ms. The configuration measured has no table between 7 and 45:
+# every value from 8 to 45 gives it the same program, and 32 is not
+# resolved against its neighbours by any measurement.
+MIN_ROWS_PER_BATCH_ROW = 32
+
+# marks, in an index tree, a leaf that is updated whole
+_WHOLE = object()
+
+
+@dataclass(frozen=True)
+class RowPlan:
+    """What one fit's step does: ``paths`` take the row path (empty: the
+    dense step, for ``reason``); ``bytes_skipped`` is what the update no
+    longer reads each step (the rows of those parameters and of their
+    parameter-shaped optimizer state that the batch cannot have touched)."""
+
+    paths: Tuple[Tuple[str, ...], ...] = ()
+    bytes_skipped: int = 0
+    reason: str = ""
+
+    def stats(self) -> Dict[str, Any]:
+        return {
+            "params": len(self.paths),
+            "bytes_skipped": self.bytes_skipped,
+            "reason": self.reason,
+            "paths": ["/".join(p) for p in self.paths],
+        }
+
+
+def _path(key_path) -> Tuple[str, ...]:
+    return tuple(str(getattr(k, "key", k)) for k in key_path)
+
+
+def _by_path(tree) -> Dict[Tuple[str, ...], Any]:
+    import jax
+
+    return {_path(kp): leaf for kp, leaf in jax.tree_util.tree_leaves_with_path(tree)}
+
+
+def _index_tree(params, index: Dict[Tuple[str, ...], Any]):
+    """``params``' structure with ``index[path]`` at the row-path leaves and
+    the whole-leaf mark everywhere else."""
+    import jax
+
+    return jax.tree_util.tree_map_with_path(
+        lambda kp, _: index.get(_path(kp), _WHOLE), params
+    )
+
+
+def _state_index_tree(tx, opt_state, index_tree):
+    """The index tree of ``opt_state``: every copy of the parameter tree
+    inside it (optax finds them) carries ``index_tree``, the rest is whole."""
+    import optax
+
+    return optax.tree_map_params(
+        tx, lambda _, i: i, opt_state, index_tree,
+        transform_non_params=lambda _: _WHOLE,
+    )
+
+
+def _stateful_leaves(tx, opt_state) -> int:
+    """How many leaves of ``opt_state`` lie outside the copies of the
+    parameter tree in it: a step count, a schedule's, accumulated updates
+    kept beside it, injected hyperparameters."""
+    import jax
+    import optax
+
+    outside = optax.tree_map_params(
+        tx, lambda _: False, opt_state, transform_non_params=lambda _: True
+    )
+    return sum(jax.tree.leaves(outside))
+
+
+def _take(leaf, idx):
+    # the padding slots of idx lie past the last row: they read it (clip)
+    # and are dropped again by _put
+    return leaf if idx is _WHOLE else leaf.at[idx].get(mode="clip")
+
+
+def _put(leaf, rows, idx):
+    # idx is sorted and without repeats, and XLA is not told: promised
+    # both, XLA:TPU scatters into a table of 100,000-300,000 rows in time
+    # proportional to the table, 0.44 ms against 0.14 (PERF.md, PR 25)
+    return rows if idx is _WHOLE else leaf.at[idx].set(rows, mode="drop")
+
+
+def sorted_unique(ids, sizes):
+    """Distinct ids of each row of ``ids`` (int32 ``[S, N]``, row ``s`` in
+    ``[0, sizes[s])``), by one batched sort and not one ``jnp.unique`` a row.
+    Returns ``(uniq, inv)``, both int32 ``[S, N]``: ``uniq[s]`` ascending and
+    without repeats, the distinct ids first and then padding from
+    ``sizes[s]`` up (past the last row, so a scatter drops it);
+    ``uniq[s, inv[s, n]] == ids[s, n]``."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    iota = lax.broadcasted_iota(jnp.int32, ids.shape, 1)
+    sorted_ids, order = lax.sort_key_val(ids, iota, dimension=1)
+    first = jnp.concatenate(
+        [
+            jnp.ones((ids.shape[0], 1), bool),
+            sorted_ids[:, 1:] != sorted_ids[:, :-1],
+        ],
+        axis=1,
+    )
+    # the slot of each sorted id among its row's distinct ones
+    slot = jnp.cumsum(first, axis=1, dtype=jnp.int32) - 1
+    pad = jnp.asarray(sizes, jnp.int32)[:, None] + iota
+    uniq = lax.sort(jnp.where(first, sorted_ids, pad), dimension=1)
+    _, inv = lax.sort_key_val(order, slot, dimension=1)  # the batch's order
+    return uniq, inv
+
+
+def update_rows(tx, params, opt_state, mini_params, mini_grads, index_tree):
+    """``tx.update`` on the mini-tree and the scatter back: the dense
+    ``tx.update`` + ``apply_updates`` where ``tx`` passes :func:`probe`."""
+    import jax
+    import optax
+
+    state_index = _state_index_tree(tx, opt_state, index_tree)
+    mini_state = jax.tree.map(_take, opt_state, state_index)
+    updates, mini_state = tx.update(mini_grads, mini_state, mini_params)
+    mini_params = optax.apply_updates(mini_params, updates)
+    return (
+        jax.tree.map(_put, params, mini_params, index_tree),
+        jax.tree.map(_put, opt_state, mini_state, state_index),
+    )
+
+
+def step(module, loss_fn, tx, paths, params, opt_state, x, y):
+    """One train step with ``paths`` on the row path. Returns ``(params,
+    opt_state, loss)`` as the dense step does."""
+    import jax
+    import jax.numpy as jnp
+
+    with jax.named_scope("loss_and_grad"):
+        whole = _by_path(params)
+        ids = module.row_gathers(x)
+        uniq, inv = sorted_unique(
+            jnp.stack([ids[p] for p in paths]),
+            [whole[p].shape[0] for p in paths],
+        )
+        index_tree = _index_tree(params, {p: uniq[s] for s, p in enumerate(paths)})
+        mini_params = jax.tree.map(_take, params, index_tree)
+
+        def compute(mini):
+            rows = _by_path(mini)
+            # the model gets every table whole, as a constant of the
+            # derivative, and reads none of those it is handed rows of: a
+            # sample's row is its distinct row's, so the derivative sums the
+            # gradients of repeated ids (Adagrad needs (sum g)^2, not
+            # sum g^2)
+            variables = jax.tree.map(
+                lambda full, m, i: m if i is _WHOLE else full,
+                params, mini, index_tree,
+            )
+            gathered = {p: rows[p][inv[s]] for s, p in enumerate(paths)}
+            return loss_fn(module.apply(variables, x, rows=gathered), y)
+
+        loss, mini_grads = jax.value_and_grad(compute)(mini_params)
+    with jax.named_scope("optimizer_update"):
+        params, opt_state = update_rows(
+            tx, params, opt_state, mini_params, mini_grads, index_tree
+        )
+    return params, opt_state, loss
+
+
+def probe(tx, params, paths) -> Optional[str]:
+    """Observe whether ``tx`` may take the row path: None, or the reason it
+    may not. On a toy tree of ``params``' structure (8 rows of 4 to a
+    row-path leaf, 4 to every axis elsewhere), off the accelerator, bit for
+    bit:
+
+    - two dense steps whose gradients touch two rows each, not the same two,
+      against :func:`update_rows` on the same gradients: parameters and
+      every leaf of the state. A moment that decays at a zero gradient
+      (Adam), weight decay, or statistics shared between rows (Adafactor's
+      factors, a trust ratio) show here;
+    - the first dense step again with every OTHER gradient changed: the
+      touched rows' updates and state must not move. Anything that couples
+      a row to the rest of the tree (clipping by the global norm) shows
+      here.
+
+    Operation by operation and not under ``jit``: fused, the two sides of a
+    comparison round differently (they are fused differently) and nothing
+    passes. Few shapes, so that few operations compile: about a second in a
+    process's first fit, a fifth of one after."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    rows, touched = 8, ((1, 5), (2, 7))
+    rng = np.random.default_rng(0)
+
+    def toy(kp, leaf):
+        shape = (rows, 4) if _path(kp) in paths else (4,) * leaf.ndim
+        return jnp.asarray(rng.standard_normal(shape), leaf.dtype)
+
+    def on_rows(hit, touched_rows, elsewhere):
+        """A row-path leaf's ``touched_rows`` on the rows ``hit``,
+        ``elsewhere`` on its other rows."""
+        mask = np.zeros((rows, 1), bool)
+        mask[list(hit)] = True
+        return jnp.where(mask, touched_rows, elsewhere)
+
+    def observe():
+        p0 = jax.tree_util.tree_map_with_path(toy, params)
+        s0 = tx.init(p0)
+        dense = rowwise = (p0, s0)
+        for step_no, hit in enumerate(touched):
+            index_tree = _index_tree(
+                p0, {p: jnp.asarray(hit + (rows, rows + 1), jnp.int32)
+                     for p in paths})
+            grads = jax.tree.map(
+                lambda g, i: g if i is _WHOLE else on_rows(hit, g, 0.0),
+                jax.tree_util.tree_map_with_path(toy, params), index_tree)
+            if step_no == 0:
+                # the same step, every gradient but the touched rows' changed
+                other = jax.tree.map(
+                    lambda g, i: 2 * g + 1 if i is _WHOLE
+                    else on_rows(hit, g, 1.0), grads, index_tree)
+                near = jnp.asarray(hit, jnp.int32)
+                alone, coupled = (
+                    jax.tree.map(
+                        lambda leaf, i: () if i is _WHOLE else leaf[near],
+                        tx.update(g, s0, p0),
+                        (index_tree, _state_index_tree(tx, s0, index_tree)))
+                    for g in (grads, other))
+            p, s = dense
+            updates, s = tx.update(grads, s, p)
+            dense = (optax.apply_updates(p, updates), s)
+            p, s = rowwise
+            rowwise = update_rows(
+                tx, p, s, jax.tree.map(_take, p, index_tree),
+                jax.tree.map(
+                    lambda g, i: g if i is _WHOLE else g.at[i].get(
+                        mode="fill", fill_value=0), grads, index_tree),
+                index_tree)
+        return dense, rowwise, alone, coupled
+
+    def same(a, b):
+        la, lb = jax.tree.leaves(a), jax.tree.leaves(b)
+        return len(la) == len(lb) and all(
+            np.array_equal(np.asarray(u), np.asarray(v), equal_nan=True)
+            for u, v in zip(la, lb))
+
+    try:
+        try:
+            device = jax.devices("cpu")[0]
+        except RuntimeError:  # no CPU backend in this process: where we are
+            device = None
+        with jax.default_device(device):
+            dense, rowwise, alone, coupled = observe()
+    except Exception as exc:  # noqa: BLE001 - any optimizer a user passes; the dense step needs none of this
+        return f"the optimizer could not be observed on rows ({exc!r:.200})"
+    if not same(dense, rowwise):
+        return ("the optimizer is not row-wise: on a toy tree, two steps on "
+                "the rows a gradient touched differ from two dense steps")
+    if not same(alone, coupled):
+        return ("the optimizer couples rows: on a toy tree, a row's update "
+                "moved when only other gradients changed")
+    return None
+
+
+def plan(module, tx, params, x, batch: int) -> RowPlan:
+    """Decide one fit's step from what can be observed of it."""
+    import jax
+
+    declare = getattr(module, "row_gathers", None)
+    if declare is None:
+        return RowPlan(reason="the model declares no row-gathered parameters")
+    whole = _by_path(params)
+    declared = tuple(jax.eval_shape(declare, x))
+    paths = tuple(
+        p for p in declared
+        if whole[p].shape[0] >= MIN_ROWS_PER_BATCH_ROW * batch
+    )
+    if not paths:
+        return RowPlan(reason=(
+            f"none of the {len(declared)} declared parameters has "
+            f"{MIN_ROWS_PER_BATCH_ROW} rows to a row of the batch ({batch})"
+        ))
+    index_tree = _index_tree(params, {p: True for p in paths})
+    state = jax.eval_shape(tx.init, params)
+    try:
+        state_index = _state_index_tree(tx, state, index_tree)
+    except Exception as exc:  # noqa: BLE001 - optax's own assertions, on any optimizer a user passes
+        return RowPlan(reason=(
+            "optax cannot tell which leaves of this optimizer's state follow "
+            f"the parameters (tree_map_params: {exc!r:.160})"
+        ))
+    # The probe watches two steps. What an update does on a later step it
+    # can only have learned from the state, and a parameter-shaped leaf the
+    # probe has seen through (a row's state moves with that row's gradient
+    # alone). A leaf outside the parameter copies is where a transformation
+    # counts steps: apply_every(k) pays out every k-th step to all rows, a
+    # schedule switches a weight decay on at step n. Without one, the two
+    # steps are enough.
+    outside = _stateful_leaves(tx, state)
+    if outside:
+        return RowPlan(reason=(
+            f"the optimizer keeps state beside the parameters' ({outside} "
+            "leaf or leaves: a step count, a schedule's): what it does on a "
+            "later step cannot be observed in two"
+        ))
+    why = probe(tx, params, paths)
+    if why:
+        return RowPlan(reason=why)
+    skipped = 0
+    for tree, index in ((params, index_tree), (state, state_index)):
+        for leaf, i in zip(jax.tree.leaves(tree), jax.tree.leaves(index)):
+            if i is not _WHOLE:
+                row = math.prod(leaf.shape[1:]) * leaf.dtype.itemsize
+                skipped += (leaf.shape[0] - batch) * row
+    return RowPlan(paths=paths, bytes_skipped=skipped)

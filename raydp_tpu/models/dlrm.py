@@ -58,19 +58,39 @@ class DLRM(nn.Module):
                 "array (JaxEstimator categorical_columns / x=(dense, ids))"
             )
 
-    @nn.compact
-    def __call__(self, x):
+    def _split(self, x):
+        """``(dense, ids)`` of either input form; ``ids[i]`` is column ``i``
+        as int32 [B], clipped to table ``i``'s rows."""
         if isinstance(x, (tuple, list)):
             # mixed-dtype input (dense, ids): integer ids are exact at any
             # vocab size; float ids get the same guard as the legacy path
             dense, ids = x
-            dense = dense.astype(self.dtype)
             self._check_float_ids(ids.dtype)
-            ids = ids.astype(jnp.int32)
         else:
-            dense = x[:, : self.num_dense].astype(self.dtype)
             self._check_float_ids(x.dtype)
-            ids = x[:, self.num_dense :].astype(jnp.int32)  # [B, S]
+            dense, ids = x[:, : self.num_dense], x[:, self.num_dense :]
+        ids = ids.astype(jnp.int32)  # [B, S]
+        return dense.astype(self.dtype), [
+            jnp.clip(ids[:, i], 0, vocab - 1)
+            for i, vocab in enumerate(self.vocab_sizes)
+        ]
+
+    def row_gathers(self, x):
+        """The parameters ``__call__`` reads by rows only, and the row each
+        sample of ``x`` reads of them: ``{path in the variables: ids [B]}``.
+        A training step that finds this method may differentiate and update
+        those rows alone (``JaxEstimator``, docs/estimators.md "Row-wise
+        update"), handing them to ``__call__`` as ``rows``."""
+        _, ids = self._split(x)
+        return {("params", f"embedding_{i}"): col for i, col in enumerate(ids)}
+
+    @nn.compact
+    def __call__(self, x, rows=None):
+        """``rows`` (optional): ``{path: [B, embed_dim]}`` for any of
+        ``row_gathers(x)``'s paths, the rows already gathered at its ids;
+        the table itself is then not read."""
+        dense, ids = self._split(x)
+        rows = rows or {}
 
         # bottom MLP → dense embedding of dim embed_dim
         h = dense
@@ -87,10 +107,12 @@ class DLRM(nn.Module):
                 (vocab, self.embed_dim),
                 jnp.float32,
             )
-            rows = jnp.take(
-                table.astype(self.dtype), jnp.clip(ids[:, i], 0, vocab - 1), axis=0
+            given = rows.get(("params", f"embedding_{i}"))
+            stacked.append(
+                jnp.take(table.astype(self.dtype), ids[i], axis=0)
+                if given is None
+                else given.astype(self.dtype)
             )
-            stacked.append(rows)
         t = jnp.stack(stacked, axis=1)  # [B, 1+S, D]
 
         use_pallas = self.use_pallas_interaction
@@ -120,7 +142,15 @@ def dlrm_optimizer(embedding_lr: float = 1e-2, dense_lr: float = 1e-3):
     by). Adafactor with the factoring threshold lowered to cover embedding
     shapes keeps O(rows + cols) second-moment state: the same big-vocab
     step measures ~34ms (>10x) and fits comfortably. Pass the result as
-    ``JaxEstimator(optimizer=dlrm_optimizer())``."""
+    ``JaxEstimator(optimizer=dlrm_optimizer())``.
+
+    It buys memory, not speed: every step still reads and writes every row
+    of every table. Adafactor's factored statistics are shared between the
+    rows of a table and Adam's moments decay at a zero gradient, so the
+    estimator's row-wise update (docs/estimators.md "Row-wise update") does
+    not engage under this optimizer, where it does under ``"adagrad"`` or
+    ``"sgd"``: the fit observes that on a toy tree and keeps the dense step
+    (``fit_stats_["row_update"]["reason"]``)."""
     import optax
 
     def label_fn(params):

@@ -45,6 +45,10 @@ def session(monkeypatch):
     # ``service_addr`` when it is remotely reachable (tcp://), which is
     # what these transport tests exercise
     monkeypatch.setenv("RAYDP_TPU_TCP", "1")
+    # a cluster that an earlier module of this worker left up (test_chaos,
+    # test_block_service) was booted without the variable and advertises no
+    # service_addr: start from none
+    cluster.shutdown()
     s = raydp_tpu.init_etl(
         "test-xhost", num_executors=2, executor_cores=1,
         executor_memory="300M",
