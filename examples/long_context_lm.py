@@ -2,6 +2,10 @@
 every device; each device holds T/N tokens and K/V blocks rotate over ICI.
 Nothing like this exists in the reference — long context is first-class here.
 
+This script drives a hand-written ``jax.jit`` step. An LM that trains through
+``JaxEstimator.fit`` from an ETL frame (a ``FixedSizeList<int32>`` sequence
+column, ``loss="model"``) is in docs/estimators.md, "Language models".
+
 Run under a CPU mesh for demonstration:
   JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
       python examples/long_context_lm.py

@@ -63,6 +63,33 @@ def _loss_softmax_ce(pred, target):
     )
 
 
+# ``loss="model"``: the model computes its own objective. Its flax method
+# ``loss(x, y)`` returns a scalar, or ``(scalar, {name: array})`` whose named
+# arrays (a value per exit, per head, ...) the evaluation averages and
+# reports. A model whose loss is not a function of one prediction array and
+# one label column (a language model: labels are the features shifted, the
+# prediction is several exits it must never hold at once) trains through the
+# same step, runners and donation as any other.
+MODEL_LOSS = "model"
+
+
+def make_objective(module, loss_fn):
+    """``objective(params, x, y) -> (loss, aux)``: the one place that knows
+    whether the loss is a function of the prediction or the model's own."""
+    if loss_fn == MODEL_LOSS:
+
+        def objective(params, x, y):
+            out = module.apply(params, x, y, method="loss")
+            return out if isinstance(out, tuple) else (out, {})
+
+    else:
+
+        def objective(params, x, y):
+            return loss_fn(module.apply(params, x), y), {}
+
+    return objective
+
+
 _LOSSES = {
     "mse": _loss_mse,
     "mae": _loss_mae,
@@ -70,6 +97,7 @@ _LOSSES = {
     "binary_cross_entropy": _loss_bce,
     "softmax_cross_entropy": _loss_softmax_ce,
     "cross_entropy": _loss_softmax_ce,
+    MODEL_LOSS: MODEL_LOSS,
 }
 
 
@@ -97,6 +125,25 @@ from raydp_tpu.exchange.features import f_stack as _f_stack
 from raydp_tpu.exchange.features import fmap as _fmap
 
 
+def _lmap(fn, labels):
+    """``fn`` on the staged labels; a fit whose loss is the model's own may
+    have none (``label_column=None``), and None is an empty pytree to jit
+    and ``lax.scan`` alike."""
+    return None if labels is None else fn(labels)
+
+
+def _lnbytes(labels) -> int:
+    return 0 if labels is None else labels.nbytes
+
+
+def _shuffled(rows: int, seed) -> np.ndarray:
+    """Row order of one epoch: the identity for ``seed`` None."""
+    order = np.arange(rows)
+    if seed is not None:
+        np.random.default_rng(seed).shuffle(order)
+    return order.astype(np.int32)
+
+
 def _put_stacked_batch(mesh, arr, shard_direct=True):
     """Upload recipe shared by the scan and stream runners — delegates to
     the exchange layer's one implementation of the placement rules
@@ -119,6 +166,10 @@ def make_train_step(module, loss_fn, tx, row_paths=()):
 
     from raydp_tpu.estimator import row_update
 
+    objective = make_objective(module, loss_fn)
+    if row_paths and loss_fn == MODEL_LOSS:
+        raise ValueError("the row-wise update needs a loss of the prediction")
+
     # loss accumulates ON DEVICE: a host float(loss) per step would force
     # a sync and serialize the H2D/compute pipeline (measured 6× slowdown)
     def step_impl(params, opt_state, loss_sum, x, y):
@@ -128,12 +179,11 @@ def make_train_step(module, loss_fn, tx, row_paths=()):
             )
             return params2, opt_state2, loss_sum + loss
 
-        def compute(p):
-            return loss_fn(module.apply(p, x), y)
-
         # stable names in the device trace (metadata only)
         with jax.named_scope("loss_and_grad"):
-            loss, grads = jax.value_and_grad(compute)(params)
+            (loss, _), grads = jax.value_and_grad(
+                lambda p: objective(p, x, y), has_aux=True
+            )(params)
         with jax.named_scope("optimizer_update"):
             updates, opt_state2 = tx.update(grads, opt_state, params)
             params2 = optax.apply_updates(params, updates)
@@ -519,6 +569,8 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
         bookkeeping."""
         with obs.span("estimator.compile", what=str(what)) as span:
             yield span
+            if getattr(self, "_fit_facts", None):
+                span.set(**self._fit_facts)
             if self._row_plan is not None:  # a program of this fit's step
                 span.set(
                     row_update_params=len(self._row_plan.paths),
@@ -698,12 +750,38 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
         rng = jax.random.PRNGKey(self.seed)
         self.compile_seconds_ = 0.0
         self._row_plan = None
+        # what the model says of itself, once per fit (``fit_facts(sample)``
+        # on a sample of the staged features: a looped model's loop count,
+        # ...): numbers become gauges ``model.<name>``, all of it attributes
+        # of this fit's compile spans. Two of them the estimator reads:
+        # ``tokens_per_row`` (a language model's predicted tokens in a row;
+        # no shape is taken for one) feeds ``estimator.tokens_completed``,
+        # and ``flops_per_row`` stands in for XLA's count of the step
+        # program, whose probe is then not compiled at all
+        facts = getattr(module, "fit_facts", None)
+        self._fit_facts = dict(facts(sample_np)) if callable(facts) else {}
+        for name, value in self._fit_facts.items():
+            if isinstance(value, (int, float)) and not isinstance(value, bool):
+                obs.metrics.gauge(f"model.{name}").set(value)
+        self._tokens_per_step = batch_size * int(
+            self._fit_facts.get("tokens_per_row", 0)
+        )
+        recorder.count_tokens(self._tokens_per_step)
+        if self._fit_facts.get("flops_per_row"):
+            self._flops_per_step = float(
+                batch_size * self._fit_facts["flops_per_row"]
+            )
         with self._compile_span("init"):
             # one jitted init: flax init run eagerly compiles dozens of tiny
             # ops, which costs ~0.5s EACH on cold TPU backends (~30s total)
             sample = _fmap(jnp.asarray, sample_np)
+            init = (
+                (lambda r, s: module.init(r, s, None, method="loss"))
+                if loss_fn == MODEL_LOSS
+                else module.init
+            )
             params, opt_state = jax.jit(
-                lambda r, s: (lambda p: (p, tx.init(p)))(module.init(r, s))
+                lambda r, s: (lambda p: (p, tx.init(p)))(init(r, s))
             )(rng, sample)
             jax.block_until_ready(params)
         from raydp_tpu.parallel.partitioner import _mesh_device_count, _mesh_single_device
@@ -1030,13 +1108,14 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                                 # gauge: one extra lower()+compile(), served
                                 # from the (persistent) compilation cache
                                 # the first dispatch just filled
-                                with self._compile_span("flops_probe"):
-                                    self._flops_per_step = (
-                                        _costmodel.step_flops_from_jitted(
-                                            train_step, params, opt_state,
-                                            loss_sum, x, y,
+                                if not self._flops_per_step:
+                                    with self._compile_span("flops_probe"):
+                                        self._flops_per_step = (
+                                            _costmodel.step_flops_from_jitted(
+                                                train_step, params, opt_state,
+                                                loss_sum, x, y,
+                                            )
                                         )
-                                    )
                                 # the compile step is NOT a steady-state
                                 # step: it counts as dispatched and
                                 # completed, and stays (with the flops
@@ -1231,6 +1310,14 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
         recorder = getattr(self, "_step_recorder", None)
         return recorder.poll()[0] if recorder is not None else 0
 
+    def epoch_order(self, epoch: int, rows: int) -> np.ndarray:
+        """The order in which the scanned runners consume a staged source of
+        ``rows`` rows in epoch ``epoch`` (0-based): indices into the staged
+        arrays, batch after batch, the rows past the last whole batch left
+        out by the caller. A function of ``seed`` and the epoch alone, so
+        whoever holds the same rows can replay a fit step by step."""
+        return _shuffled(rows, self.seed + epoch if self.shuffle else None)
+
     def _mark_mfu_origin(self) -> None:
         """Where the live MFU starts counting: (steps completed so far, now,
         compile seconds so far)."""
@@ -1240,38 +1327,55 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
             self.compile_seconds_,
         )
 
-    def _completed_flops_per_sec(self, since):
-        """``(flops_per_step × Δsteps_completed / Δwall, mark)`` between the
-        mark ``since`` and the recorder's last observation of completion,
-        which is the returned mark. The wall time is everything between the
-        two observations but compiles: the evaluation and the epoch's
-        restart are inside it, as they are inside the rate a user sees; the
-        host's time in a dispatch never stands in for the device's. The
-        rate is None where nothing completed in between."""
+    def _completed_steps_per_sec(self, since):
+        """``(Δsteps_completed / Δwall, mark)`` between the mark ``since``
+        and the recorder's last observation of completion, which is the
+        returned mark. The wall time is everything between the two
+        observations but compiles: the evaluation and the epoch's restart
+        are inside it, as they are inside the rate a user sees; the host's
+        time in a dispatch never stands in for the device's. The rate is
+        None where nothing completed in between."""
         now = (*self._step_recorder.poll(), self.compile_seconds_)
-        flops_step = getattr(self, "_flops_per_step", None)
-        if not flops_step or since is None:
+        if since is None:
             return None, now
         steps = now[0] - since[0]
         wall = (now[1] - since[1]) - (now[2] - since[2])
         if steps <= 0 or wall <= 0.0:
             return None, now
-        return flops_step * steps / wall, now
+        return steps / wall, now
+
+    def _completed_flops_per_sec(self, since):
+        """The completed steps' rate times the step's FLOPs (None where
+        either is unknown), and the mark."""
+        rate, now = self._completed_steps_per_sec(since)
+        flops_step = getattr(self, "_flops_per_step", None)
+        if not flops_step or rate is None:
+            return None, now
+        return flops_step * rate, now
 
     def _update_live_mfu(self) -> None:
         """Refresh the ``estimator.mfu`` / ``estimator.model_flops_per_sec``
-        gauges from the work the device COMPLETED since the last refresh —
-        called at every epoch's end, after its closing fence where it has
-        one, so a scrape MID-fit shows the live number (the epoch loop
-        ships it with its next flush, which it makes while the device is
-        busy). Without a fence the observation lags completion by up to one
-        dispatch (docs/observability.md "Compute observatory")."""
-        mfps, now = self._completed_flops_per_sec(self._mfu_mark)
-        if mfps is None:
+        / ``estimator.tokens_per_sec`` gauges from the work the device
+        COMPLETED since the last refresh — called at every epoch's end,
+        after its closing fence where it has one, so a scrape MID-fit shows
+        the live number (the epoch loop ships it with its next flush, which
+        it makes while the device is busy). Without a fence the observation
+        lags completion by up to one dispatch (docs/observability.md
+        "Compute observatory")."""
+        rate, now = self._completed_steps_per_sec(self._mfu_mark)
+        if rate is None:
             return
         from raydp_tpu.obs import costmodel
 
         self._mfu_mark = now
+        if self._tokens_per_step:
+            obs.metrics.gauge("estimator.tokens_per_sec").set(
+                rate * self._tokens_per_step
+            )
+        flops_step = getattr(self, "_flops_per_step", None)
+        if not flops_step:
+            return
+        mfps = flops_step * rate
         obs.metrics.gauge("estimator.model_flops_per_sec").set(mfps)
         mfu_val = costmodel.mfu(mfps, self._peak_info.get("peak"))
         if mfu_val is not None:
@@ -1892,10 +1996,10 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
         if self.scan_epochs is False:
             return None, None
         feats, labs = train_source.features, train_source.labels
-        if labs is None or len(_f0(feats)) < batch_size:
+        if len(_f0(feats)) < batch_size:
             return None, None
         if self.scan_epochs is None:
-            if _f_nbytes(feats) + labs.nbytes > self.scan_memory_limit:
+            if _f_nbytes(feats) + _lnbytes(labs) > self.scan_memory_limit:
                 return None, None
 
         n = len(_f0(feats))
@@ -1934,16 +2038,16 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                     ),
                     feats,
                 ),
-                jax.ShapeDtypeStruct(
-                    (batch_size,) + labs.shape[1:], np.dtype(labs.dtype)
+                _lmap(
+                    lambda a: jax.ShapeDtypeStruct(
+                        (batch_size,) + a.shape[1:], np.dtype(a.dtype)
+                    ),
+                    labs,
                 ),
             )
 
         def _order(seed):
-            order = np.arange(n)
-            if self.shuffle:
-                np.random.default_rng(seed).shuffle(order)
-            return order[:n_used].astype(np.int32)
+            return _shuffled(n, seed)[:n_used]
 
         if device_resident:
             from raydp_tpu.parallel.partitioner import _mesh_single_device
@@ -1969,7 +2073,7 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                         # default device: stay uncommitted (committed arrays
                         # cost more per dispatch — see device_put_batch)
                         xs_dev = _fmap(jnp.asarray, feats)
-                        ys_dev = jnp.asarray(labs)
+                        ys_dev = _lmap(jnp.asarray, labs)
                 self._device_stage = (train_source, device, xs_dev, ys_dev)
 
             def make_gather(length):
@@ -1980,7 +2084,12 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                         ),
                         xs,
                     )
-                    yb = ys[perm].reshape((length, batch_size) + ys.shape[1:])
+                    yb = _lmap(
+                        lambda a: a[perm].reshape(
+                            (length, batch_size) + a.shape[1:]
+                        ),
+                        ys,
+                    )
                     return epoch_body(params, opt_state, xb, yb)
 
                 return partial_jit(
@@ -2027,10 +2136,13 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                     ),
                     shard_direct=self.shard_direct,
                 )
-                yb = _put_stacked_batch(
-                    mesh,
-                    labs[sel].reshape((length, batch_size) + labs.shape[1:]),
-                    shard_direct=self.shard_direct,
+                yb = _lmap(
+                    lambda a: _put_stacked_batch(
+                        mesh,
+                        a[sel].reshape((length, batch_size) + a.shape[1:]),
+                        shard_direct=self.shard_direct,
+                    ),
+                    labs,
                 )
                 recorder.note(
                     "h2d", time.perf_counter() - t_h, steps=length
@@ -2094,8 +2206,11 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                         ),
                         xs,
                     )
-                    yb = ys[perm].reshape(
-                        (steps_per_epoch, batch_size) + ys.shape[1:]
+                    yb = _lmap(
+                        lambda a: a[perm].reshape(
+                            (steps_per_epoch, batch_size) + a.shape[1:]
+                        ),
+                        ys,
                     )
                     p, o, loss_sum = epoch_body(p, o, xb, yb)
                     return (p, o), loss_sum
@@ -2184,16 +2299,32 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
         from jax import lax
 
         metrics = self._metrics
+        objective = make_objective(module, loss_fn)
+        if loss_fn == MODEL_LOSS and metrics.names:
+            raise ValueError(
+                'metrics are functions of a prediction; loss="model" gives '
+                "the evaluation a loss (and what the model reports beside it)"
+            )
+
+        def one_batch(params, mstate, x, y):
+            if loss_fn == MODEL_LOSS:
+                loss, aux = objective(params, x, y)
+            else:
+                pred = module.apply(params, x)
+                mstate = metrics.update(mstate, pred, y)
+                loss, aux = loss_fn(pred, y), {}
+            return mstate, loss, aux
 
         # ROW-weighted loss accumulation (matches the Torch estimator's
         # reporting): a short tail batch must not count as much as a full
-        # one, or one odd row could contribute half of eval_loss
+        # one, or one odd row could contribute half of eval_loss. What the
+        # model reports beside its loss (``aux``) is weighted alike.
         @jax.jit
         def eval_step(params, mstate, loss_sum, count, x, y):
-            pred = module.apply(params, x)
-            mstate = metrics.update(mstate, pred, y)
             rows = float(_f0(x).shape[0])
-            return mstate, loss_sum + loss_fn(pred, y) * rows, count + rows
+            mstate, loss, aux = one_batch(params, mstate, x, y)
+            return (mstate, loss_sum + loss * rows, count + rows,
+                    jax.tree.map(lambda a: a * rows, aux))
 
         @jax.jit
         def eval_scan(params, mstate, xb, yb):
@@ -2201,13 +2332,12 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
 
             def body(carry, xy):
                 ms, ls, c = carry
-                pred = module.apply(params, xy[0])
-                ms = metrics.update(ms, pred, xy[1])
-                return (ms, ls + loss_fn(pred, xy[1]) * rows, c + rows), None
+                ms, loss, aux = one_batch(params, ms, xy[0], xy[1])
+                return (ms, ls + loss * rows, c + rows), aux
 
             init = (mstate, jnp.zeros(()), jnp.zeros(()))
-            (ms, ls, c), _ = lax.scan(body, init, (xb, yb))
-            return ms, ls, c
+            (ms, ls, c), aux = lax.scan(body, init, (xb, yb))
+            return ms, ls, c, jax.tree.map(lambda a: a.sum(0) * rows, aux)
 
         return eval_step, eval_scan
 
@@ -2224,16 +2354,16 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
         mstate = self._metrics.init_state()
         loss_sum = jnp.zeros(())
         count = jnp.zeros(())
+        aux_sums = []  # row-weighted sums of what the model reports
 
         scannable = (
             isinstance(source, _HostArrays)
-            and source.labels is not None
             and self.scan_epochs is not False
             and jax.process_count() == 1
             and _mesh_device_count(mesh) == 1
             and (
                 self.scan_epochs is True
-                or _f_nbytes(source.features) + source.labels.nbytes
+                or _f_nbytes(source.features) + _lnbytes(source.labels)
                 <= self.scan_memory_limit
             )
         )
@@ -2262,40 +2392,59 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                         ),
                         feats,
                     )
-                    yb = labs[: steps * batch_size].reshape(
-                        (steps, batch_size) + labs.shape[1:]
+                    yb = _lmap(
+                        lambda a: a[: steps * batch_size].reshape(
+                            (steps, batch_size) + a.shape[1:]
+                        ),
+                        labs,
                     )
                     if device != jax.devices()[0]:
                         xb = jax.device_put(xb, device)  # pytree-ok
                         yb = jax.device_put(yb, device)
                     else:
                         xb = _fmap(jnp.asarray, xb)
-                        yb = jnp.asarray(yb)
+                        yb = _lmap(jnp.asarray, yb)
                     # one slot, like the train-set device cache: per-epoch
                     # eval must not re-upload the eval set every epoch
                     self._eval_device_stage = (source, batch_size, device, xb, yb)
-                mstate, loss_sum, count = eval_scan(params, mstate, xb, yb)
+                mstate, loss_sum, count, aux = eval_scan(params, mstate, xb, yb)
+                aux_sums.append(aux)
             if n % batch_size:
                 tail_x = _fmap(
                     lambda a: jnp.asarray(a[steps * batch_size :]), feats
                 )
-                tail_y = jnp.asarray(labs[steps * batch_size :])
-                mstate, loss_sum, count = eval_step(
+                tail_y = _lmap(lambda a: jnp.asarray(a[steps * batch_size :]), labs)
+                mstate, loss_sum, count, aux = eval_step(
                     params, mstate, loss_sum, count, tail_x, tail_y
                 )
+                aux_sums.append(aux)
         else:
             for x, y in PrefetchingDeviceIterator(
                 self._epoch_batches(source, batch_size, None, shuffle=False),
                 mesh, shard_direct=self.shard_direct,
             ):
-                mstate, loss_sum, count = eval_step(
+                mstate, loss_sum, count, aux = eval_step(
                     params, mstate, loss_sum, count, x, y
                 )
+                aux_sums.append(aux)
         # one transfer for both scalars: separate float() calls would pay a
         # device round trip each
         loss_v, count_v = np.asarray(jnp.stack([loss_sum, count]))
-        out = {"eval_loss": float(loss_v) / max(float(count_v), 1.0)}
+        rows = max(float(count_v), 1.0)
+        out = {"eval_loss": float(loss_v) / rows}
         out.update({f"eval_{k}": v for k, v in self._metrics.compute(mstate).items()})
+        if aux_sums and aux_sums[0]:
+            # what the model reports beside its loss (a value per exit, ...):
+            # history's eval_<name> and the gauges estimator.eval.<name>.<i>;
+            # the loss fetch above was the fence, this one waits for nothing
+            total = jax.device_get(
+                jax.tree.map(lambda *parts: sum(parts), *aux_sums)
+            )
+            for name, value in total.items():
+                values = (np.asarray(value, np.float64) / rows).ravel().tolist()
+                out[f"eval_{name}"] = values
+                for i, v in enumerate(values):
+                    obs.metrics.gauge(f"estimator.eval.{name}.{i}").set(v)
         return out
 
     def evaluate(self, ds) -> Dict[str, float]:

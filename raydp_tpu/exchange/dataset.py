@@ -431,6 +431,22 @@ class Dataset:
         return [store.owner_of(b) for b in self.blocks]
 
 
+def _column_to_numpy(column) -> np.ndarray:
+    """One Arrow column as numpy: [N] for a scalar column, [N, k] for a
+    fixed-length sequence column (``FixedSizeList<T>[k]``: a packed token
+    sequence), whose flat child buffer is reshaped, not copied row by row."""
+    arr = column.combine_chunks() if isinstance(column, pa.ChunkedArray) else column
+    if pa.types.is_fixed_size_list(arr.type):
+        if arr.null_count:
+            raise ValueError(
+                f"fixed-length sequence column of type {arr.type} contains "
+                "null rows; fill or drop them in ETL first"
+            )
+        k = arr.type.list_size
+        return arr.flatten().to_numpy(zero_copy_only=False).reshape(len(arr), k)
+    return arr.to_numpy(zero_copy_only=False)
+
+
 def _table_to_numpy(
     table: pa.Table,
     feature_columns: Sequence[str],
@@ -455,7 +471,7 @@ def _table_to_numpy_grouped(
     pass — the mixed-dtype feeding path (dense floats + integer ids)."""
 
     def _col(c, dtype):
-        arr = table.column(c).combine_chunks().to_numpy(zero_copy_only=False)
+        arr = _column_to_numpy(table.column(c))
         target = np.dtype(dtype)
         if np.issubdtype(target, np.integer):
             if np.issubdtype(arr.dtype, np.floating):
@@ -481,10 +497,18 @@ def _table_to_numpy_grouped(
                     )
         return arr
 
-    features = tuple(
-        np.stack([_col(c, dtype) for c in cols], axis=1).astype(dtype)
-        for cols, dtype in feature_groups
-    )
+    def _matrix(cols, dtype):
+        parts = [_col(c, dtype) for c in cols]
+        if any(a.ndim == 2 for a in parts):
+            # a fixed-length sequence column brings its own width: [N, k]
+            # beside the scalars' [N, 1]; alone it IS the matrix (no copy)
+            parts = [a if a.ndim == 2 else a[:, None] for a in parts]
+            stacked = parts[0] if len(parts) == 1 else np.concatenate(parts, 1)
+        else:
+            stacked = np.stack(parts, axis=1)
+        return stacked.astype(dtype, copy=False)
+
+    features = tuple(_matrix(cols, dtype) for cols, dtype in feature_groups)
     labels = None
     if label_column is not None:
         labels = (

@@ -1,15 +1,18 @@
 """Model zoo: the reference's workload families, TPU-native."""
 
 from raydp_tpu.models.dlrm import DLRM, dlrm_optimizer, dlrm_sharding_rules
+from raydp_tpu.models.looplm import LoopLM, looplm_optimizer
 from raydp_tpu.models.mlp import MLPClassifier, MLPRegressor
 from raydp_tpu.models.transformer import TransformerLM, sequence_parallel_apply
 
 __all__ = [
     "DLRM",
+    "LoopLM",
     "MLPClassifier",
     "MLPRegressor",
     "TransformerLM",
     "dlrm_optimizer",
     "dlrm_sharding_rules",
+    "looplm_optimizer",
     "sequence_parallel_apply",
 ]

@@ -1,0 +1,271 @@
+"""Looped decoder LM (Ouro / LoopLM, arXiv:2510.25741): one stack of ``L``
+layers applied ``R`` times with ONE set of parameters, an exit (the shared
+head) and an exit gate after every loop step, and a loss over all exits.
+
+::
+
+    h = E[x]
+    for t in 1..R:                       # the SAME L layers every t
+      for l in 1..L:
+        a = h + RMSNorm_l2(Attn_l(RMSNorm_l1(h)))        # sandwich norm
+        h = a + RMSNorm_l4(MLP_l (RMSNorm_l3(a)))
+      h = RMSNorm_f(h)                   # closes every loop step: feeds exit t AND step t+1
+      z_t = h W_head ;  lam_t = sigmoid(h w_g + b_g)
+    p_t = lam_t prod_{j<t}(1 - lam_j)  (t < R);  p_R = prod_{j<R}(1 - lam_j)
+    loss = mean_tokens[sum_t p_t CE(z_t, next)] - beta mean_tokens[H(p)]
+
+The parameter tree holds L layers, not R x L: sharing is by construction, and
+a weight's gradient sums over its R uses. Parameters are float32; ``dtype``
+is the compute dtype (bf16 on the chip): matmul operands and the residual
+stream; norms' statistics, RoPE, the gate, the logits and the loss are
+float32. Attention is the repo's ``_attend`` (``attn_impl="flash"`` on the
+chip), shared with ``models/transformer.py``.
+
+The estimator trains it with ``loss="model"``: ``loss(x)`` takes the int32
+``[B, T+1]`` sequence column whole, reads ``x[:, :-1]`` and predicts
+``x[:, 1:]``. The exit loss never holds more than one token chunk of one
+exit's logits (``loss_chunk`` tokens; recomputed in the backward pass), and
+blocks are recomputed from their inputs (``remat``).
+"""
+
+from __future__ import annotations
+
+from typing import Any
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from raydp_tpu.models.transformer import _attend
+
+LAYER_MATRICES = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+LAYER_NORMS = ("norm1", "norm2", "norm3", "norm4")
+
+
+def rms_norm(x, gain, eps):
+    x32 = x.astype(jnp.float32)
+    y = x32 * lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (y * gain).astype(x.dtype)
+
+
+def rope_tables(t: int, head_dim: int, theta: float):
+    """cos, sin [T, head_dim/2] in float32 (rotate-half over the whole head)."""
+    inv = 1.0 / theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
+    angles = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
+    return jnp.cos(angles), jnp.sin(angles)
+
+
+def apply_rope(x, cos, sin):
+    """x [B, H, T, D]: out = x * cos + rotate_half(x) * sin, in float32."""
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return out.astype(x.dtype)
+
+
+def exit_mass(lam):
+    """Exit distribution p [R, ...] from the gates lam [R, ...] (the last
+    gate is not used: the last exit takes what is left)."""
+    survive = jnp.cumprod(1.0 - lam[:-1], axis=0)
+    before = jnp.concatenate([jnp.ones_like(lam[:1]), survive[:-1]], axis=0)
+    return jnp.concatenate([lam[:-1] * before, survive[-1:]], axis=0)
+
+
+class LoopLM(nn.Module):
+    vocab_size: int
+    hidden_size: int = 2048
+    num_heads: int = 16
+    num_layers: int = 6
+    intermediate_size: int = 5632
+    loop_steps: int = 4
+    rope_theta: float = 1e6
+    rms_eps: float = 1e-6
+    entropy_beta: float = 0.1
+    attn_impl: str = "full"  # "flash" on the chip; as TransformerLM's
+    dtype: Any = jnp.bfloat16  # compute dtype; parameters are float32
+    remat: bool = True  # recompute each block (and each exit's logits) backward
+    loss_chunk: int = 2048  # tokens of one exit's logits held at a time; 0: all
+
+    def setup(self):
+        d, f, v = self.hidden_size, self.intermediate_size, self.vocab_size
+        matrix = nn.initializers.normal(0.02)
+        shapes = {"wq": (d, d), "wk": (d, d), "wv": (d, d), "wo": (d, d),
+                  "w_gate": (d, f), "w_up": (d, f), "w_down": (f, d)}
+
+        def layer(rng):
+            keys = jax.random.split(rng, len(LAYER_MATRICES))
+            out = {name: matrix(k, shapes[name], jnp.float32)
+                   for name, k in zip(LAYER_MATRICES, keys)}
+            out.update({name: jnp.ones((d,), jnp.float32) for name in LAYER_NORMS})
+            return out
+
+        def gate(rng):
+            return {"w": matrix(rng, (d,), jnp.float32),
+                    "b": jnp.zeros((), jnp.float32)}
+
+        self.embed = self.param("embed", matrix, (v, d), jnp.float32)
+        # ONE tree of L layers, whatever the loop count
+        self.layers = [self.param(f"layer_{i}", layer)
+                       for i in range(self.num_layers)]
+        self.final_norm = self.param("final_norm", nn.initializers.ones, (d,),
+                                     jnp.float32)
+        self.head_w = self.param("head", matrix, (d, v), jnp.float32)
+        self.gate = self.param("gate", gate)
+
+    # -- what the estimator writes down once per fit (fit_facts rule) --------
+    def fit_facts(self, x) -> dict:
+        """``x`` is a sample of the staged feature, [rows, T + 1] ids: a row
+        holds T predicted tokens. ``flops_per_row`` is the model's FLOPs of
+        a training step on one row, forward + backward = 3 x forward, from
+        shapes: every layer counted ``loop_steps`` times, one head per
+        exit, causal attention (T (T + 1) / 2 kept pairs); recomputation
+        does not count. XLA's count of the step program cannot stand in: it
+        counts a scanned loop's body once and no Mosaic call."""
+        t = x.shape[1] - 1
+        d, applications = self.hidden_size, self.loop_steps * self.num_layers
+        layer = 4 * d * d + 3 * d * self.intermediate_size
+        flops = (6 * layer * t * applications
+                 + 6 * d * self.vocab_size * t * self.loop_steps
+                 + 12 * d * (t * (t + 1) // 2) * applications)
+        return {"loop_steps": self.loop_steps,
+                "layer_applications_per_step": applications,
+                "loop": "scan", "remat": bool(self.remat),
+                "tokens_per_row": t, "flops_per_row": flops}
+
+    # -- pieces --------------------------------------------------------------
+    def _dot(self, x, w):
+        return jnp.dot(x, w.astype(self.dtype))
+
+    def _block(self, w, h, cos, sin):
+        with jax.named_scope("looplm.block"):
+            b, t, d = h.shape
+            heads, eps = self.num_heads, self.rms_eps
+
+            def split(z):  # [B, T, D] -> [B, H, T, Dh]
+                return z.reshape(b, t, heads, d // heads).transpose(0, 2, 1, 3)
+
+            y = rms_norm(h, w["norm1"], eps)
+            q = apply_rope(split(self._dot(y, w["wq"])), cos, sin)
+            k = apply_rope(split(self._dot(y, w["wk"])), cos, sin)
+            v = split(self._dot(y, w["wv"]))
+            o = _attend(q, k, v, impl=self.attn_impl, axis="sp", causal=True)
+            o = o.transpose(0, 2, 1, 3).reshape(b, t, d)
+            a = h + rms_norm(self._dot(o, w["wo"]), w["norm2"], eps)
+            y = rms_norm(a, w["norm3"], eps)
+            m = self._dot(
+                nn.silu(self._dot(y, w["w_gate"])) * self._dot(y, w["w_up"]),
+                w["w_down"])
+            return a + rms_norm(m, w["norm4"], eps)
+
+    def _loop_step(self, h, cos, sin):
+        """The L layers once, then the final norm: the state that feeds this
+        step's exit, its gate and the next step."""
+        block = jax.checkpoint(self._block) if self.remat else self._block
+        for w in self.layers:
+            h = block(w, h, cos, sin)
+        return rms_norm(h, self.final_norm, self.rms_eps)
+
+    def _gate(self, h):
+        z = jnp.einsum("btd,d->bt", h.astype(jnp.float32), self.gate["w"])
+        return jax.nn.sigmoid(z + self.gate["b"])
+
+    def head(self, h):
+        """One exit's logits, float32, from a loop step's closing state."""
+        return jnp.dot(h, self.head_w.astype(self.dtype),
+                       preferred_element_type=jnp.float32)
+
+    def _exit_ce(self, h, targets):
+        """Per-token cross-entropy [B, T] of one exit, ``loss_chunk`` tokens
+        of logits at a time."""
+        b, t, d = h.shape
+
+        def chunk_ce(h_c, y_c):
+            z = self.head(h_c)
+            picked = jnp.take_along_axis(z, y_c[:, None], axis=-1)[:, 0]
+            return jax.nn.logsumexp(z, axis=-1) - picked
+
+        if self.remat:
+            chunk_ce = jax.checkpoint(chunk_ce)
+        with jax.named_scope("looplm.exit_loss"):
+            n = b * t
+            chunk = self.loss_chunk
+            flat_h, flat_y = h.reshape(n, d), targets.reshape(n)
+            if not chunk or chunk >= n or n % chunk:
+                return chunk_ce(flat_h, flat_y).reshape(b, t)
+            ce = lax.map(lambda hy: chunk_ce(*hy),
+                         (flat_h.reshape(n // chunk, chunk, d),
+                          flat_y.reshape(n // chunk, chunk)))
+            return ce.reshape(b, t)
+
+    def _embed(self, tokens):
+        cos, sin = rope_tables(tokens.shape[1],
+                               self.hidden_size // self.num_heads,
+                               self.rope_theta)
+        return self.embed[tokens].astype(self.dtype), cos, sin
+
+    # -- surfaces ------------------------------------------------------------
+    def hidden_states(self, tokens):
+        """(h [R, B, T, D], lam [R, B, T]): every loop step's closing state
+        and gate (the loop as a Python ``for``: small sizes, tests, checks)."""
+        h, cos, sin = self._embed(tokens)
+        hs, lams = [], []
+        for _ in range(self.loop_steps):
+            h = self._loop_step(h, cos, sin)
+            hs.append(h)
+            lams.append(self._gate(h))
+        return jnp.stack(hs), jnp.stack(lams)
+
+    def exits(self, tokens):
+        """(logits [R, B, T, V], lam [R, B, T]): every exit whole."""
+        hs, lam = self.hidden_states(tokens)
+        return jnp.stack([self.head(h) for h in hs]), lam
+
+    def __call__(self, tokens):
+        """The last exit's logits [B, T, V]."""
+        hs, _ = self.hidden_states(tokens)
+        return self.head(hs[-1])
+
+    def loss(self, x, y=None, with_states=False):
+        """The training objective on ``x`` int32 [B, T+1] (inputs
+        ``x[:, :-1]``, targets ``x[:, 1:]``; ``y`` is not used). Returns
+        ``(loss, {"exit_loss": [R], "exit_mass": [R]})``: each exit's mean
+        cross-entropy and mean mass, which the estimator's evaluation
+        reports. ``with_states`` adds what the scanned loop itself held:
+        ``hidden`` [R, B, T, D], every loop step's closing state, and
+        ``mass`` [R, B, T], the exit distribution (for a comparison exit by
+        exit; not for a fit, whose evaluation would average them)."""
+        tokens, targets = x[:, :-1], x[:, 1:]
+        h, cos, sin = self._embed(tokens)
+        steps = self.loop_steps
+
+        def step(carry, t):
+            h, survive = carry
+            h = self._loop_step(h, cos, sin)
+            ce = self._exit_ce(h, targets)
+            lam = self._gate(h)
+            p = jnp.where(t < steps - 1, lam * survive, survive)
+            return (h, survive * (1.0 - lam)), (
+                jnp.mean(p * ce), -jnp.mean(p * jnp.log(jnp.maximum(p, 1e-30))),
+                jnp.mean(ce), jnp.mean(p), (h, p) if with_states else ())
+
+        carry = (h, jnp.ones(tokens.shape, jnp.float32))
+        with jax.named_scope("looplm.loop"):
+            _, outs = lax.scan(step, carry, jnp.arange(steps))
+        weighted, ent, exit_loss, mass, states = outs
+        loss = jnp.sum(weighted) - self.entropy_beta * jnp.sum(ent)
+        aux = {"exit_loss": exit_loss, "exit_mass": mass}
+        if with_states:
+            aux.update(hidden=states[0], mass=states[1])
+        return loss, aux
+
+
+def looplm_optimizer(learning_rate: float = 3e-4, b1: float = 0.9,
+                     b2: float = 0.95, weight_decay: float = 0.1):
+    """AdamW as LM pre-training runs it: decay on the parameters with two or
+    more axes only (not on norm gains or the gate), float32 moments, no
+    schedule."""
+    import optax
+
+    return optax.adamw(
+        learning_rate, b1=b1, b2=b2, weight_decay=weight_decay,
+        mask=lambda params: jax.tree.map(lambda a: a.ndim >= 2, params))

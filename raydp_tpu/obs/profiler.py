@@ -103,6 +103,9 @@ class _NoopRecorder:
     def poll(self):
         return 0, 0.0
 
+    def count_tokens(self, tokens_per_step: int) -> None:
+        pass
+
     def open_restart(self, epoch: int) -> None:
         pass
 
@@ -141,7 +144,8 @@ class StepPhaseRecorder:
 
     __slots__ = ("enabled", "steps", "drained", "_totals", "_hists", "_lock",
                  "_inflight", "steps_dispatched", "_completed", "_seen_at",
-                 "_completed_counter", "_restart", "_restart_hist")
+                 "_completed_counter", "_restart", "_restart_hist",
+                 "_tokens_per_step", "_tokens_counter")
 
     def __init__(self):
         self.enabled = True
@@ -165,6 +169,16 @@ class StepPhaseRecorder:
         self._completed_counter = metrics.counter("estimator.steps_completed")
         self._restart = None
         self._restart_hist = metrics.histogram("estimator.epoch.restart_ms")
+        self._tokens_per_step = 0
+        self._tokens_counter = None
+
+    def count_tokens(self, tokens_per_step: int) -> None:
+        """A fit whose steps each train ``tokens_per_step`` tokens:
+        ``estimator.tokens_completed`` advances with
+        ``estimator.steps_completed``, from the same observation."""
+        self._tokens_per_step = int(tokens_per_step)
+        if self._tokens_per_step:
+            self._tokens_counter = metrics.counter("estimator.tokens_completed")
 
     def note(self, phase: str, seconds: float, steps: int = 1) -> None:
         if seconds < 0.0:
@@ -221,6 +235,10 @@ class StepPhaseRecorder:
                 inflight.popleft()
             if done is not None:
                 self._completed_counter.inc(done - self._completed)
+                if self._tokens_counter is not None:
+                    self._tokens_counter.inc(
+                        (done - self._completed) * self._tokens_per_step
+                    )
                 self._completed = done
                 self._seen_at = time.perf_counter()
             return self._completed, self._seen_at
