@@ -453,6 +453,31 @@ def phase_kernels(ph: Phase) -> None:
           jax.jit(jax.grad(lambda s: (inter.dot_interaction(s) ** 2).sum()))(
               stacked), tol)
 
+    # row write-back into a table of the DLRM fit (its rows fill no whole
+    # number of 128-row blocks), a parameter and its state in one call:
+    # against XLA's scatter, bit for bit
+    from raydp_tpu.estimator.row_update import sorted_unique
+    from raydp_tpu.ops.row_write_back import row_write_back
+
+    size = max(sz.vocab_sizes) - 3
+    uniq, _ = sorted_unique(jnp.asarray(np.concatenate([
+        rng.integers(0, size, sz.batch - 2), [size - 1, 0]])[None], jnp.int32),
+        [size])
+    tables = [jnp.asarray(rng.standard_normal((size, sz.embed_dim)),
+                          jnp.float32) for _ in range(2)]
+    new = [jnp.asarray(rng.standard_normal((sz.batch, sz.embed_dim)),
+                       jnp.float32) for _ in range(2)]
+    wfn = jax.jit(row_write_back)
+    require_kernel(ph, "row write-back", wfn.lower(tables, new, uniq[0]))
+    got = ph.first_call("row write-back", lambda: wfn(tables, new, uniq[0]))
+    same = all(
+        np.array_equal(np.asarray(g), np.asarray(t.at[uniq[0]].set(
+            r, mode="drop"))) for g, t, r in zip(got, tables, new))
+    ph.say(f"row write-back vs scatter, {size} rows of {sz.embed_dim}: "
+           f"{'bit-identical' if same else 'NOT bit-identical FAIL'}")
+    if not same:
+        failures.append("row write-back")
+
     # stochastic int8 quantize: the Pallas PRNG kernel on a TPU
     x = jnp.asarray(rng.standard_normal((sz.batch, 128)), jnp.float32) * 3.0
     sums = []

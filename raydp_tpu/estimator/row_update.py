@@ -9,7 +9,9 @@ batch reads, differentiates with respect to those, runs the SAME
 ``tx.update`` on the mini-tree (those rows of the parameter and of every
 parameter-shaped leaf of the optimizer state, every other leaf whole) and
 scatters the rows back in place. The pytrees of ``params`` and ``opt_state``
-keep their structure and shapes.
+keep their structure and shapes. The rows go back through a kernel that owns
+its DMAs (``ops/row_write_back.py``) where the leaf allows it, and through
+XLA's scatter where not (:func:`_scatter_reason`).
 
 That is the same mathematics only where the optimizer is row-local and leaves
 a row with a zero gradient, and its state, as they were. Nothing here knows
@@ -38,11 +40,24 @@ import numpy as np
 # two tie within 0.3 % of the step, at 70 (142,572 rows) the row path wins
 # by 0.53 ms. The configuration measured has no table between 7 and 45:
 # every value from 8 to 45 gives it the same program, and 32 is not
-# resolved against its neighbours by any measurement.
+# resolved against its neighbours by any measurement. With the write-back
+# kernel of PR 28 (which has no cost in the table's size) the whole step at
+# 4 / 32 / 64 takes 4.38-4.43 / 4.20-4.30 / 4.42 ms (PERF.md, Findings, PR
+# 28): the two tables of 12,517 and 14,992 rows are still cheaper dense.
 MIN_ROWS_PER_BATCH_ROW = 32
 
 # marks, in an index tree, a leaf that is updated whole
 _WHOLE = object()
+
+
+@dataclass(frozen=True, eq=False)
+class _Rows:
+    """In an index tree, a leaf that is updated by rows: their ids (what
+    :func:`sorted_unique` gives) and whether the kernel writes them back.
+    A parameter and the state that follows it hold the same object."""
+
+    idx: Any
+    kernel: bool = False
 
 
 @dataclass(frozen=True)
@@ -55,6 +70,13 @@ class RowPlan:
     paths: Tuple[Tuple[str, ...], ...] = ()
     bytes_skipped: int = 0
     reason: str = ""
+    # the paths whose rows (the parameter's and its state's) the kernel
+    # writes back, and how many leaves that makes; the other ``scatter_leaves``
+    # go through XLA's scatter, for ``scatter_reason`` (the first found)
+    kernel_paths: Tuple[Tuple[str, ...], ...] = ()
+    kernel_leaves: int = 0
+    scatter_leaves: int = 0
+    scatter_reason: str = ""
 
     def stats(self) -> Dict[str, Any]:
         return {
@@ -62,6 +84,11 @@ class RowPlan:
             "bytes_skipped": self.bytes_skipped,
             "reason": self.reason,
             "paths": ["/".join(p) for p in self.paths],
+            "write_back": {
+                "kernel": self.kernel_leaves,
+                "scatter": self.scatter_leaves,
+                "reason": self.scatter_reason,
+            },
         }
 
 
@@ -109,17 +136,60 @@ def _stateful_leaves(tx, opt_state) -> int:
     return sum(jax.tree.leaves(outside))
 
 
-def _take(leaf, idx):
+def _take(leaf, i):
     # the padding slots of idx lie past the last row: they read it (clip)
     # and are dropped again by _put
-    return leaf if idx is _WHOLE else leaf.at[idx].get(mode="clip")
+    return leaf if i is _WHOLE else leaf.at[i.idx].get(mode="clip")
 
 
-def _put(leaf, rows, idx):
-    # idx is sorted and without repeats, and XLA is not told: promised
-    # both, XLA:TPU scatters into a table of 100,000-300,000 rows in time
-    # proportional to the table, 0.44 ms against 0.14 (PERF.md, PR 25)
-    return rows if idx is _WHOLE else leaf.at[idx].set(rows, mode="drop")
+def _scatter_reason(leaf) -> str:
+    """Why the rows of ``leaf`` (an array, or its shape and dtype) go back
+    through XLA's scatter and not through the kernel; empty where the kernel
+    takes them. The kernel moves blocks of the table's own device layout by
+    DMA: that layout is a TPU's, and a Pallas call is not partitioned."""
+    import jax
+
+    from raydp_tpu.ops import backend, row_write_back
+
+    if not backend.on_tpu():
+        return f"the backend is {jax.default_backend()}, not a TPU"
+    sharding = getattr(leaf, "sharding", None)
+    devices = len(sharding.device_set) if sharding is not None else 1
+    if devices > 1:
+        return (f"the leaf lies on {devices} devices, and a Pallas call is "
+                "not partitioned")
+    return row_write_back.supports(leaf.shape, leaf.dtype)
+
+
+def _put(trees, minis, indexes):
+    """``trees`` with the rows of ``minis`` written back where ``indexes``
+    says. A parameter and its state share their ids, so the kernel writes
+    them in one call: one pass over the ids, twice the transfers in flight."""
+    import jax
+
+    from raydp_tpu.ops.row_write_back import row_write_back
+
+    leaves, treedef = jax.tree.flatten(trees)
+    rows = treedef.flatten_up_to(minis)
+    index = treedef.flatten_up_to(indexes)
+    calls: Dict[int, list] = {}
+    for n, (leaf, new, i) in enumerate(zip(leaves, rows, index)):
+        if i is _WHOLE:
+            leaves[n] = new
+        elif i.kernel:
+            calls.setdefault(id(i), []).append(n)
+        else:
+            # idx is sorted and without repeats, and XLA is not told:
+            # promised both, XLA:TPU scatters into a table of
+            # 100,000-300,000 rows in time proportional to the table, 0.44
+            # ms against 0.14 (PERF.md, PR 25)
+            leaves[n] = leaf.at[i.idx].set(new, mode="drop")
+    for where in calls.values():
+        for n, leaf in zip(where, row_write_back(
+                [leaves[n] for n in where], [rows[n] for n in where],
+                index[where[0]].idx)):
+            leaves[n] = leaf
+    return treedef.unflatten(leaves)
 
 
 def sorted_unique(ids, sizes):
@@ -159,14 +229,15 @@ def update_rows(tx, params, opt_state, mini_params, mini_grads, index_tree):
     mini_state = jax.tree.map(_take, opt_state, state_index)
     updates, mini_state = tx.update(mini_grads, mini_state, mini_params)
     mini_params = optax.apply_updates(mini_params, updates)
-    return (
-        jax.tree.map(_put, params, mini_params, index_tree),
-        jax.tree.map(_put, opt_state, mini_state, state_index),
+    return _put(
+        (params, opt_state), (mini_params, mini_state),
+        (index_tree, state_index),
     )
 
 
-def step(module, loss_fn, tx, paths, params, opt_state, x, y):
-    """One train step with ``paths`` on the row path. Returns ``(params,
+def step(module, loss_fn, tx, paths, params, opt_state, x, y, kernel_paths=()):
+    """One train step with ``paths`` on the row path, those of
+    ``kernel_paths`` written back by the kernel. Returns ``(params,
     opt_state, loss)`` as the dense step does."""
     import jax
     import jax.numpy as jnp
@@ -178,7 +249,8 @@ def step(module, loss_fn, tx, paths, params, opt_state, x, y):
             jnp.stack([ids[p] for p in paths]),
             [whole[p].shape[0] for p in paths],
         )
-        index_tree = _index_tree(params, {p: uniq[s] for s, p in enumerate(paths)})
+        index_tree = _index_tree(params, {
+            p: _Rows(uniq[s], p in kernel_paths) for s, p in enumerate(paths)})
         mini_params = jax.tree.map(_take, params, index_tree)
 
         def compute(mini):
@@ -247,7 +319,7 @@ def probe(tx, params, paths) -> Optional[str]:
         dense = rowwise = (p0, s0)
         for step_no, hit in enumerate(touched):
             index_tree = _index_tree(
-                p0, {p: jnp.asarray(hit + (rows, rows + 1), jnp.int32)
+                p0, {p: _Rows(jnp.asarray(hit + (rows, rows + 1), jnp.int32))
                      for p in paths})
             grads = jax.tree.map(
                 lambda g, i: g if i is _WHOLE else on_rows(hit, g, 0.0),
@@ -271,7 +343,7 @@ def probe(tx, params, paths) -> Optional[str]:
             rowwise = update_rows(
                 tx, p, s, jax.tree.map(_take, p, index_tree),
                 jax.tree.map(
-                    lambda g, i: g if i is _WHOLE else g.at[i].get(
+                    lambda g, i: g if i is _WHOLE else g.at[i.idx].get(
                         mode="fill", fill_value=0), grads, index_tree),
                 index_tree)
         return dense, rowwise, alone, coupled
@@ -318,7 +390,8 @@ def plan(module, tx, params, x, batch: int) -> RowPlan:
             f"none of the {len(declared)} declared parameters has "
             f"{MIN_ROWS_PER_BATCH_ROW} rows to a row of the batch ({batch})"
         ))
-    index_tree = _index_tree(params, {p: True for p in paths})
+    # a row-path leaf carries its parameter's path, here only
+    index_tree = _index_tree(params, {p: "/".join(p) for p in paths})
     state = jax.eval_shape(tx.init, params)
     try:
         state_index = _state_index_tree(tx, state, index_tree)
@@ -345,9 +418,22 @@ def plan(module, tx, params, x, batch: int) -> RowPlan:
     if why:
         return RowPlan(reason=why)
     skipped = 0
+    leaves = {"/".join(p): 0 for p in paths}
+    scatter: Dict[str, str] = {}  # the paths with a leaf the kernel refuses
     for tree, index in ((params, index_tree), (state, state_index)):
         for leaf, i in zip(jax.tree.leaves(tree), jax.tree.leaves(index)):
             if i is not _WHOLE:
                 row = math.prod(leaf.shape[1:]) * leaf.dtype.itemsize
                 skipped += (leaf.shape[0] - batch) * row
-    return RowPlan(paths=paths, bytes_skipped=skipped)
+                leaves[i] += 1
+                why = _scatter_reason(leaf)
+                if why:
+                    scatter.setdefault(i, why)
+    kernel_paths = tuple(p for p in paths if "/".join(p) not in scatter)
+    kernel_leaves = sum(leaves["/".join(p)] for p in kernel_paths)
+    return RowPlan(
+        paths=paths, bytes_skipped=skipped, kernel_paths=kernel_paths,
+        kernel_leaves=kernel_leaves,
+        scatter_leaves=sum(leaves.values()) - kernel_leaves,
+        scatter_reason=next(iter(scatter.values()), ""),
+    )

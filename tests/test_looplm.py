@@ -294,6 +294,8 @@ def test_estimator_fit_on_an_etl_frame_with_the_sequence_column():
     assert abs(sum(history[-1]["eval_exit_mass"]) - 1.0) < 1e-5
     stats = est.fit_stats_
     assert stats["row_update"]["params"] == 0  # the dense step
+    assert stats["row_update"]["write_back"] == {
+        "kernel": 0, "scatter": 0, "reason": ""}  # and no row to write back
     assert stats["steps"] == 3 * 4 and stats["steps_completed"] == 12
     snap = obs.metrics.snapshot()
     assert snap["estimator.tokens_completed"]["value"] - before == 12 * 2 * T

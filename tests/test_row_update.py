@@ -11,6 +11,7 @@ import pytest
 from raydp_tpu.estimator import JaxEstimator, row_update
 from raydp_tpu.estimator.jax_estimator import _LOSSES, make_train_step
 from raydp_tpu.exchange import dataframe_to_dataset
+from raydp_tpu.ops import backend
 from tests.test_jax_estimator import criteo_df, session  # noqa: F401 - fixtures
 
 BATCH = 32
@@ -195,12 +196,15 @@ def _lowered(step, params, tx, x, y):
 
 
 @pytest.mark.parametrize("case", ["mlp", "dlrm+dlrm_optimizer"])
-def test_bypass_traces_the_dense_step(case):
+def test_bypass_traces_the_dense_step(case, monkeypatch):
     """(3) a module that declares nothing, and a DLRM under an optimizer
     the probe refuses, lower to the step the parent commit traced: the same
-    text, so no ``sort`` and no ``scatter`` it did not have."""
+    text, so no ``sort`` and no ``scatter`` it did not have, on a TPU too
+    (where the row path would bring the write-back kernel)."""
     import jax
     import optax
+
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
 
     x, y = next(_batches(1))
     loss_fn = _LOSSES["bce"]
@@ -212,7 +216,9 @@ def test_bypass_traces_the_dense_step(case):
         module, tx = _dlrm(), _optimizers()["dlrm_optimizer"]()
     params = module.init(jax.random.PRNGKey(0), x)
     plan = row_update.plan(module, tx, params, x, BATCH)
-    assert not plan.paths
+    assert not plan.paths and not plan.kernel_paths
+    assert plan.stats()["write_back"] == {
+        "kernel": 0, "scatter": 0, "reason": ""}
 
     def parent_step(params, opt_state, loss_sum, x, y):
         with jax.named_scope("loss_and_grad"):
@@ -223,10 +229,11 @@ def test_bypass_traces_the_dense_step(case):
             params = optax.apply_updates(params, updates)
         return params, opt_state, loss_sum + loss
 
-    got = _lowered(make_train_step(module, loss_fn, tx, plan.paths),
-                   params, tx, x, y)
+    got = _lowered(
+        make_train_step(module, loss_fn, tx, plan.paths, plan.kernel_paths),
+        params, tx, x, y)
     assert got == _lowered(parent_step, params, tx, x, y)
-    assert "stablehlo.sort" not in got
+    assert "stablehlo.sort" not in got and "row_write_back" not in got
     if case == "mlp":
         assert "stablehlo.scatter" not in got
     # and the row path is what brings them
@@ -339,7 +346,11 @@ def test_sharded_tables_parity(cpu_mesh_devices, name):
                 state = step(*state, jax.tree.map(put, x), put(y))
         return state
 
-    got = run(make_train_step(module, loss_fn, tx, ROW_PATHS), sharded)
+    # what a fit would run here: the scatter's step, its text unchanged
+    plan = row_update.plan(module, tx, sharded, batches[0][0], BATCH)
+    assert plan.paths == ROW_PATHS and not plan.kernel_paths
+    got = run(make_train_step(module, loss_fn, tx, plan.paths,
+                              plan.kernel_paths), sharded)
     want = run(make_train_step(module, loss_fn, tx), sharded)
     assert got[0]["params"]["embedding_0"].sharding.is_equivalent_to(
         sharded["params"]["embedding_0"].sharding, 2)

@@ -63,3 +63,87 @@ def test_flash_forward_and_backward_compile_at_ouro_widths(
         # the instruction's name: %jvp_<name>_.1, %transpose_jvp_<name>__.1
         assert re.search(rf"%[\w.\-]*{name}[\w.\-]* = ", text), name
     assert text.count("tpu_custom_call") >= 3
+
+
+# the Criteo-Kaggle cardinalities of benchmark/configs/dlrm-criteo-kaggle.json
+CRITEO_KAGGLE = (
+    1460, 583, 10131227, 2202608, 305, 24, 12517, 633, 3, 93145, 5683,
+    8351593, 3194, 27, 14992, 5461306, 10, 5652, 2173, 4, 7046547, 18, 15,
+    286181, 105, 142572)
+ROW_TABLES = (10131227, 2202608, 93145, 8351593, 5461306, 7046547, 286181,
+              142572)
+
+
+def _table_results(text, ops, layout=""):
+    """The instructions of ``ops`` whose result is a row-path table, as
+    ``[V, 16]`` or as the kernel's ``[16, V]`` view."""
+    shapes = "|".join(f"{v},16|16,{v}" for v in ROW_TABLES)
+    return re.findall(
+        rf"= f32\[(?:{shapes})\]\{{{layout}[^}}]*\}} (?:{ops})\(", text)
+
+
+def test_dlrm_step_writes_rows_back_with_the_kernel(
+        one_chip, no_compile_cache, monkeypatch):
+    """The benchmark's DLRM step (batch 2048, the 26 Criteo-Kaggle tables,
+    Adagrad) with the write-back kernel, against the same step through XLA's
+    scatter (the parent commit's step, text for text): eight kernel calls
+    (a table and its accumulator each) on bitcasts of the tables, no table
+    written back whole, no second copy of a table among the temporaries."""
+    import optax
+
+    from raydp_tpu.estimator import row_update
+    from raydp_tpu.estimator.jax_estimator import _LOSSES, make_train_step
+    from raydp_tpu.models import DLRM
+    from raydp_tpu.ops import backend
+
+    # what the TPU backend would say of itself (ops/backend.py): the plan
+    # then takes the kernel, and the kernel compiles and is not interpreted
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    batch = 2048
+    module = DLRM(vocab_sizes=CRITEO_KAGGLE, num_dense=13, embed_dim=16,
+                  bottom_mlp=(512, 256, 64), top_mlp=(512, 256),
+                  use_pallas_interaction=False)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda leaf: jax.ShapeDtypeStruct(
+            leaf.shape, leaf.dtype, sharding=one_chip), tree)
+
+    x = on_chip((jax.ShapeDtypeStruct((batch, 13), jnp.float32),
+                 jax.ShapeDtypeStruct((batch, 26), jnp.int32)))
+    y = on_chip(jax.ShapeDtypeStruct((batch,), jnp.float32))
+    params = on_chip(jax.eval_shape(module.init, jax.random.PRNGKey(0), x))
+    tx = optax.adagrad(0.01)
+    state = on_chip(jax.eval_shape(tx.init, params))
+    plan = row_update.plan(module, tx, params, x, batch)
+    assert sorted(params["params"][p[1]].shape[0] for p in plan.paths) == sorted(
+        ROW_TABLES)
+    assert plan.stats()["write_back"] == {
+        "kernel": 16, "scatter": 0, "reason": ""}
+
+    def compiled(kernel_paths):
+        step = make_train_step(module, _LOSSES["bce"], tx, plan.paths,
+                               kernel_paths)
+        return jax.jit(step, donate_argnums=(0, 1, 2)).lower(
+            params, state, on_chip(jax.ShapeDtypeStruct((), jnp.float32)),
+            x, y).compile()
+
+    kernel, scatter = compiled(plan.kernel_paths), compiled(())
+    text, parent = kernel.as_text(), scatter.as_text()
+    assert len(re.findall(r"%row_write_back[\w.\-]* = ", text)) == 8
+    assert "row_write_back" not in parent
+    assert len(_table_results(parent, "scatter")) == 16
+    # the kernel's operands and results are the tables themselves
+    assert not _table_results(text, "scatter|transpose")
+    assert len(_table_results(text, "bitcast")) == 32
+    # XLA's scatter copies the three tables of 93,145-286,181 rows to the
+    # row-major layout and back; with the kernel no table comes back whole
+    # ({0,1}: the tables' own layout), none is copied for the [16, V] view,
+    # and what is left is the copy that XLA's gather (``_take``) reads
+    copies = "copy|copy-done"
+    assert _table_results(parent, copies, layout="0,1")
+    assert not _table_results(text, copies, layout="0,1")
+    assert not [c for c in _table_results(text, copies) if "[16," in c]
+    assert 2 * len(_table_results(text, copies)) <= len(
+        _table_results(parent, copies))
+    assert (kernel.memory_analysis().temp_size_in_bytes
+            <= scatter.memory_analysis().temp_size_in_bytes)
