@@ -76,8 +76,7 @@ def main():
         num_epochs=int(os.environ.get("EXAMPLE_EPOCHS", 5)),
         learning_rate=1e-3,
         # for datasets larger than host memory, pass streaming=True
-        # (O(block) memory) or streaming="hybrid" (epoch 1 streams, later
-        # epochs scan device-pinned segments — no host IO, ~5x faster)
+        # (O(block) memory)
     )
     history = est.fit_on_etl(train_df, test_df, stop_etl_after_conversion=True)
     for record in history:

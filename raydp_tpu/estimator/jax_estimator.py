@@ -288,8 +288,7 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
         donate_state: bool = True,
         profile_dir: Optional[str] = None,
         resume_from_epoch: Optional[int] = None,
-        streaming: Union[bool, str] = False,
-        stream_cache_memory_limit: Optional[int] = None,
+        streaming: bool = False,
         sync_every_steps: int = 32,
         scan_epochs: Optional[bool] = None,
         scan_memory_limit: int = 1 << 30,
@@ -298,7 +297,6 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
         stream_prefetch_segments: int = 3,
         keep_checkpoints: Optional[int] = None,
         shard_direct: bool = True,
-        stream_wire_quant: Union[bool, str] = False,
         stream_executor_decode: bool = True,
     ):
         self._model_arg = model
@@ -349,21 +347,13 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
         # streaming=True: epochs iterate the dataset block-by-block with
         # double-buffered staging — host memory O(block) instead of
         # O(dataset); shuffle becomes block-order + within-block.
-        # streaming="hybrid": epoch 1 streams AND pins its uploaded segments
-        # in device memory; later epochs scan them from HBM (no host IO, no
-        # re-upload) while they fit the device budget — host stays
-        # O(segment), device becomes O(dataset). Segment order reshuffles
-        # per epoch; batch composition is epoch-1's (the block-scoped
-        # streaming shuffle trade, one step further). Cached epochs write no
-        # MID-epoch step checkpoints (their replay order differs from a
-        # streamed epoch's, so a step-resume could not replay the right
-        # tail); epoch-boundary checkpoints are unaffected.
-        self.streaming = streaming
-        # device-byte budget for hybrid pinning. None = scan_memory_limit,
-        # additionally capped at half the device's reported HBM when the
-        # backend exposes memory_stats (params/activations need the rest);
-        # overflow falls back to pure streaming mid-epoch.
-        self.stream_cache_memory_limit = stream_cache_memory_limit
+        if not isinstance(streaming, (bool, np.bool_)):
+            # a string that is merely truthy must not mean plain streaming
+            raise ValueError(
+                f"streaming={streaming!r}: streaming is a bool; pass "
+                "streaming=True to stream blocks every epoch"
+            )
+        self.streaming = bool(streaming)
         # cap the async dispatch queue: drain every N steps, so the host
         # runs at most N steps (and their uploaded batches) ahead of the
         # device and the step profiler's compute/sync split has a fence to
@@ -371,12 +361,8 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
         # anything on a directly attached chip is unmeasured (ROADMAP
         # Queue 3 item 8). 0 disables.
         self.sync_every_steps = sync_every_steps
-        # scan_epochs: drive a whole epoch with ONE jitted lax.scan instead
-        # of a Python dispatch per step — removes the per-step framework
-        # overhead entirely (the 13-16% train-only gap vs a raw jit loop).
-        # None = auto: on when the staged arrays fit scan_memory_limit.
-        # Single-device additionally keeps the dataset resident on device and
-        # gathers shuffled batches there, so H2D happens once per fit.
+        # scan_epochs, scan_memory_limit, stream_scan_steps: inputs of
+        # _choose_runner, which says what each value selects
         self.scan_epochs = scan_epochs
         self.scan_memory_limit = scan_memory_limit
         # step-cadence checkpointing: every K completed steps write
@@ -386,10 +372,6 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
         # mid-epoch — batch order is deterministic per (seed, epoch), so the
         # resumed run replays exactly the tail steps.
         self.save_every_steps = save_every_steps
-        # streaming (and oversized-staging) fits run SEGMENTS of this many
-        # batches through one jitted lax.scan each: O(segment) host memory
-        # with ~N× fewer dispatches than a per-step loop. 0 restores the
-        # per-step path.
         self.stream_scan_steps = stream_scan_steps
         # streaming upload pipeline depth: the producer keeps up to this
         # many segments staged-and-uploading ahead of the consumer's scan
@@ -406,13 +388,6 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
         # device_put (the A/B arm; byte-identical results, but multi-host it
         # stages the global batch per process).
         self.shard_direct = bool(shard_direct)
-        # mixed-dtype ON-WIRE staging for streaming fits: float feature
-        # leaves are staged int8 with per-row scales and widened back to
-        # float INSIDE the jitted segment scan (~3.2x fewer H2D bytes per
-        # dense leaf; integer id leaves always ride exact int32 — any vocab
-        # size). Lossy by construction (int8 rounding), so OFF by default;
-        # accepts True (alias for "int8") or "int8".
-        self.stream_wire_quant = stream_wire_quant
         # streaming segment decode (Arrow block -> numpy) runs in the etl
         # EXECUTOR processes when the dataset's session is still alive —
         # the consumer thread only sequences uploads. Falls back to
@@ -917,21 +892,16 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
         # queue would leak the thread and pin its in-flight device segments
         # (the leaks sanitizer audits exactly this at shutdown)
         with contextlib.ExitStack() as _fit_stack, profile_ctx, jax.set_mesh(mesh):
-            run_scan_epoch, run_fullfit = self._build_scan_runner(
-                train_source, batch_size, mesh, step_impl, donate
-            )
-            # scan_epochs=False is an explicit opt-out of lax.scan-driven
-            # training for staged data — it must restore the true per-step
-            # loop, not silently reroute into segment scans (streaming fits
-            # opt out with stream_scan_steps=0 instead)
-            run_stream_segments = (
-                self._build_stream_runner(mesh, step_impl, donate, batch_size)
-                if run_scan_epoch is None
-                and self.stream_scan_steps > 0
-                and self.label_column is not None
-                and (self.streaming or self.scan_epochs is not False)
-                else None
-            )
+            runner = self._choose_runner(train_source, batch_size)
+            run_scan_epoch = run_stream_segments = None
+            if runner == "resident_scan":
+                run_scan_epoch = self._build_scan_runner(
+                    train_source, batch_size, mesh, step_impl, donate
+                )
+            elif runner == "segment_scan":
+                run_stream_segments = self._build_stream_runner(
+                    mesh, step_impl, donate, batch_size
+                )
             save_steps = self.save_every_steps if self.checkpoint_dir else None
 
             def save_mid_epoch(params_, opt_state_, epoch_, step_):
@@ -974,56 +944,16 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                 )
                 _fit_stack.callback(run_stream_segments.close)
 
-            # whole-fit fast path: when nothing needs params BETWEEN epochs
-            # (no checkpointing, no per-epoch eval, no resume), the entire
-            # fit is one dispatch — an outer epoch-scan over stacked
-            # permutations. One dispatch + one history fetch per FIT.
-            fullfit_done = False
-            if (
-                run_fullfit is not None
-                and not self.checkpoint_dir
-                and eval_source is None
-                and start_epoch == 0
-                and start_step == 0
-                and self.num_epochs > 0
-                # an armed capture window needs per-epoch dispatches: the
-                # whole-fit single dispatch has no step boundary for the
-                # budget to stop at, and its trace would show one opaque
-                # launch instead of steady-state steps
-                and fit_capture is None
-            ):
-                seeds = [
-                    None if not self.shuffle else self.seed + e
-                    for e in range(self.num_epochs)
-                ]
-                t_fit = time.perf_counter()
-                compile_before = self.compile_seconds_
-                self._mark_mfu_origin()
-                full = run_fullfit(params, opt_state, seeds)
-                if full is not None:
-                    params, opt_state, losses, steps_per_epoch = full
-                    # the loss/time placeholders stay None: the dispatch is
-                    # ASYNC — real training time is only known at the final
-                    # losses fetch (the fence), which fills both in; and
-                    # slicing losses[e] here would dispatch E unused gathers
-                    self._history = [
-                        {
-                            "epoch": e,
-                            "train_loss": (None, steps_per_epoch),
-                            "epoch_seconds": None,
-                        }
-                        for e in range(self.num_epochs)
-                    ]
-                    fullfit_done = True
-
-            if not fullfit_done:
-                self._mark_mfu_origin()
-            for epoch in (
-                () if fullfit_done else range(start_epoch, self.num_epochs)
-            ):
+            self._mark_mfu_origin()
+            for epoch in range(start_epoch, self.num_epochs):
                 epoch_seed = None if not self.shuffle else self.seed + epoch
                 epoch_start_step = start_step if epoch == start_epoch else 0
                 phase_before = recorder.totals()
+                save_cb = (
+                    (lambda p, o, s, _e=epoch: save_mid_epoch(p, o, _e, s))
+                    if save_steps
+                    else None
+                )
                 # the epoch span IS the epoch timer: history's epoch_seconds
                 # is read from the same record the trace timeline shows
                 with obs.span(
@@ -1033,12 +963,7 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                     if run_scan_epoch is not None:
                         params, opt_state, loss_sum, steps = run_scan_epoch(
                             params, opt_state, epoch_seed,
-                            start_step=epoch_start_step,
-                            save_cb=(
-                                (lambda p, o, s, _e=epoch: save_mid_epoch(p, o, _e, s))
-                                if save_steps
-                                else None
-                            ),
+                            start_step=epoch_start_step, save_cb=save_cb,
                         )
                     elif run_stream_segments is not None:
                         # consume this epoch's segments off the whole-fit
@@ -1047,12 +972,8 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                         # coalesced whole-segment slices except on a
                         # mid-segment resume)
                         params, opt_state, loss_sum, steps = run_stream_segments(
-                            params, opt_state, epoch, epoch_start_step,
-                            save_cb=(
-                                (lambda p, o, s, _e=epoch: save_mid_epoch(p, o, _e, s))
-                                if save_steps
-                                else None
-                            ),
+                            params, opt_state, epoch_start_step,
+                            save_cb=save_cb,
                         )
                     else:
                         host_iter = self._epoch_batches(
@@ -1232,22 +1153,10 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
 
         if self._history:
             # ONE host fetch for every epoch's loss: a per-record float()
-            # would pay a device round trip PER EPOCH. The fullfit path
-            # already returns the losses as one [E] array — fetch it
-            # directly (no stack dispatch, one round trip instead of two).
-            if fullfit_done:
-                stacked = np.asarray(losses)  # the fence: training is done
-                per_epoch_s = (
-                    time.perf_counter()
-                    - t_fit
-                    - (self.compile_seconds_ - compile_before)
-                ) / max(self.num_epochs, 1)
-                for rec in self._history:
-                    rec["epoch_seconds"] = per_epoch_s
-            else:
-                stacked = np.asarray(
-                    jnp.stack([rec["train_loss"][0] for rec in self._history])
-                )
+            # would pay a device round trip PER EPOCH
+            stacked = np.asarray(
+                jnp.stack([rec["train_loss"][0] for rec in self._history])
+            )
             for rec, val in zip(self._history, stacked):
                 _, steps = rec["train_loss"]
                 rec["train_loss"] = float(val) / max(steps, 1)
@@ -1281,6 +1190,7 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
             "device_kind": self._peak_info.get("kind"),
             "peak_source": self._peak_info.get("peak_source"),
             "profiler": "on" if recorder.enabled else "off",
+            "runner": runner,
             "row_update": {
                 **row_plan.stats(), "probe_seconds": probe_span.duration,
             },
@@ -1427,35 +1337,59 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
         except Exception:  # raydp-lint: disable=swallowed-exceptions (flops stay unknown; the fit is unaffected)
             self._flops_per_step = None
 
+    def _choose_runner(self, train_source, batch_size) -> str:
+        """Which of the three training runners this fit takes, from what it
+        can observe before any device work — the one place that decides:
+
+        - ``resident_scan``: an epoch is one ``lax.scan`` over the staged
+          arrays (device-resident on one device). Staged data of a batch or
+          more that fits ``scan_memory_limit`` (``scan_epochs=True`` waives
+          the limit);
+        - ``segment_scan``: scans of ``stream_scan_steps`` batches fed by
+          the producer thread: a streamed fit, or staged data over the limit;
+        - ``per_step``: one dispatch a batch. ``scan_epochs=False`` (staged)
+          and ``stream_scan_steps=0`` ask for it, and a fit with no label
+          column that cannot take ``resident_scan`` falls to it: the segment
+          runner stacks labels."""
+        if (
+            not self.streaming
+            and isinstance(train_source, _HostArrays)
+            and self.scan_epochs is not False
+            and len(_f0(train_source.features)) >= batch_size
+            and (
+                self.scan_epochs is True
+                or _f_nbytes(train_source.features)
+                + _lnbytes(train_source.labels)
+                <= self.scan_memory_limit
+            )
+        ):
+            return "resident_scan"
+        if (
+            self.stream_scan_steps > 0
+            and self.label_column is not None
+            and (self.streaming or self.scan_epochs is not False)
+        ):
+            return "segment_scan"
+        return "per_step"
+
     def _build_stream_runner(self, mesh, step_impl, donate, batch_size=None):
-        """Segment-scanned streaming (ROADMAP r3 #3): assemble
-        ``stream_scan_steps`` host batches into a [S, B, ...] super-batch,
-        upload once, drive it with ONE jitted ``lax.scan`` — O(segment) host
-        memory with ~S× fewer dispatches than the per-step loop. Used for
-        streaming fits and for staged data too large for the whole-epoch
-        scan. With save_every_steps, the segment length snaps to the save
-        cadence so step checkpoints land exactly on their steps; saves are
-        deferred until the next segment begins, so a checkpoint always has
-        tail steps to replay.
+        """The ``segment_scan`` runner: ``stream_scan_steps`` host batches
+        as one [S, B, ...] super-batch, uploaded once and driven by ONE
+        jitted ``lax.scan`` — O(segment) host memory. With
+        save_every_steps, the segment length snaps to the save cadence so
+        step checkpoints land exactly on their steps; saves are deferred
+        until the next segment begins, so a checkpoint always has tail
+        steps to replay.
 
         Segments are pipelined ``stream_prefetch_segments`` deep through
         N-way rotating upload streams: ONE producer thread lives for the
-        WHOLE fit (not per epoch), reads blocks, shapes segments, and
-        starts their H2D uploads while earlier segments' scans are still
-        executing — and at an epoch boundary it rolls straight into the
-        next epoch's first segment, so the consumer never waits out a
-        decode ramp between epochs (the per-epoch producer restart used to
-        cost ~a first-segment decode of consumer idle EVERY epoch). On the
-        (default) coalesced path the host iterator yields whole segments
-        as one contiguous slice and the producer just reshapes it
-        ([S·B, ...] → [S, B, ...], zero-copy) — the per-batch Python loop
-        and the np.stack copy per segment exist only on the legacy
-        batch-granular path (mid-segment resume).
-
-        With ``stream_wire_quant`` float feature leaves travel the wire
-        int8 + per-row scales and are widened back INSIDE the jitted scan
-        (see jax_io's wire-staging helpers); integer id leaves always ride
-        exact int32."""
+        WHOLE fit, reads blocks, shapes segments, and starts their H2D
+        uploads while earlier segments' scans are still executing, rolling
+        straight from an epoch's last segment into the next epoch's first.
+        On the (default) coalesced path the host iterator yields whole
+        segments as one contiguous slice and the producer just reshapes it
+        ([S·B, ...] → [S, B, ...], zero-copy); batches are stacked one by
+        one only on a mid-segment resume."""
         import queue
         import threading
 
@@ -1490,81 +1424,10 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
             SegmentUploader,
             iter_prefetch,
             partitioner_for,
-            quantize_rows,
-            widen_wire,
         )
-
-        # -- mixed-dtype wire spec (static for the whole fit) --------------
-        # which feature leaves quantize: float leaves only; integer id
-        # leaves already ride the wire exact (int32 feature_groups)
-        groups = self._feature_groups()
-        leaf_dtypes = (
-            [np.dtype(self.feature_dtype)]
-            if groups is None
-            else [np.dtype(dt) for _, dt in groups]
-        )
-        wire_dtype = None
-        if self.stream_wire_quant:
-            wire_dtype = (
-                "int8"
-                if self.stream_wire_quant is True
-                else str(self.stream_wire_quant)
-            )
-            if wire_dtype != "int8":
-                raise ValueError(
-                    f"stream_wire_quant={self.stream_wire_quant!r}: only "
-                    "'int8' (or True) is supported"
-                )
-        wire_flags = [
-            wire_dtype is not None and np.issubdtype(dt, np.floating)
-            for dt in leaf_dtypes
-        ]
-        wire_on = any(wire_flags)
-        single_leaf = groups is None
-
-        def _wire_encode(hx):
-            """Host half of the wire format: each float leaf becomes
-            (int8 q, float32 per-row scale); the wire container is a FLAT
-            tuple ``(leaves..., scales...)`` of plain arrays, so the
-            uploader's staging/ping-pong machinery needs no special cases."""
-            leaves = list(hx) if isinstance(hx, tuple) else [hx]
-            wire, scales = [], []
-            for leaf, flag in zip(leaves, wire_flags):
-                if flag:
-                    q, s = quantize_rows(np.asarray(leaf))
-                    wire.append(q)
-                    scales.append(s)
-                else:
-                    wire.append(np.asarray(leaf))
-            return tuple(wire + scales)
-
-        def _wire_widen(x):
-            """Device half, traced INSIDE the jitted scan body: widen each
-            quantized leaf back to its model dtype (bit-identical to the
-            host dequant) and rebuild the model's feature container."""
-            nf = len(wire_flags)
-            scales = list(x[nf:])
-            out, si = [], 0
-            for leaf, flag, dt in zip(x[:nf], wire_flags, leaf_dtypes):
-                if flag:
-                    out.append(widen_wire(leaf, scales[si], dt))
-                    si += 1
-                else:
-                    out.append(leaf)
-            return out[0] if single_leaf else tuple(out)
-
-        if wire_on:
-            # widen PER STEP inside the scan: only one batch's float copy
-            # ever materializes, and XLA fuses the dequant into the step
-            def _wire_step(p, o, ls, x, y):
-                return step_impl(p, o, ls, _wire_widen(x), y)
-
-            scan_step = _wire_step
-        else:
-            scan_step = step_impl
 
         def epoch_body(params, opt_state, xb, yb):
-            return _scan_over_batches(scan_step, params, opt_state, xb, yb)
+            return _scan_over_batches(step_impl, params, opt_state, xb, yb)
 
         # the streaming runner's feeds AND its step jit ride the same
         # partitioner: shard_stacked places the segments, partition_step
@@ -1590,13 +1453,10 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
             "producer_idle_s": 0.0,
             "consumer_idle_s": 0.0,
             "segments": 0,
-            "cached_epochs": 0,
             "staging_buffer_reuse": uploader.reuse_host_buffers,
             "staging_copies": 0,
             "upload_streams": uploader.upload_streams,
             "shard_direct": self.shard_direct,
-            "wire_dtype": wire_dtype if wire_on else None,
-            "wire_bytes_saved": 0,
             "executor_decode": False,
         }
 
@@ -1605,25 +1465,22 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
             back to back: shape each segment, START its device upload, and
             at an epoch boundary roll straight into the next epoch's blocks
             (the next epoch's first segment decodes while the current
-            epoch's tail is still training — the per-epoch producer restart
-            used to hand the consumer a decode-ramp stall every epoch).
-            Items are a (dx, dy) segment, ``None`` for epoch end, or an
-            exception to re-raise consumer-side (epochs are consumed
-            strictly in production order, so no per-item epoch tag is
-            needed). The bounded queue (depth = stream_prefetch_segments)
-            applies backpressure so only that many segments' worth of
-            host/device memory is in flight; ``stop`` lets a failing
-            consumer unblock a producer parked on the full queue.
-            ``epoch_plan(epoch)`` returns that epoch's ``(host_iter,
-            coalesced, block_iter)`` (block_iter = the unwrapped
-            block-stream iterator carrying the executor-decode evidence
-            flag) — coalesced
-            items are whole-segment slices (reshaped zero-copy), per-batch
-            items are stacked (mid-segment resume only). The host iterator
-            is itself prefetched one segment deep (``iter_prefetch``), so
-            segment k+1 DECODES while segment k's async device_put is in
-            flight — block IO, wire encode, staging copy, and transfer all
-            overlap."""
+            epoch's tail is still training). Items are a (dx, dy) segment,
+            ``None`` for epoch end, or an exception to re-raise
+            consumer-side (epochs are consumed strictly in production
+            order, so no per-item epoch tag is needed). The bounded queue
+            (depth = stream_prefetch_segments) applies backpressure so only
+            that many segments' worth of host/device memory is in flight;
+            ``stop`` lets a failing consumer unblock a producer parked on
+            the full queue. ``epoch_plan(epoch)`` returns that epoch's
+            ``(host_iter, coalesced, block_iter)`` (block_iter = the
+            unwrapped block-stream iterator carrying the executor-decode
+            evidence flag) — coalesced items are whole-segment slices
+            (reshaped zero-copy), per-batch items are stacked (mid-segment
+            resume only). The host iterator is itself prefetched one
+            segment deep (``iter_prefetch``), so segment k+1 DECODES while
+            segment k's async device_put is in flight — block IO, staging
+            copy, and transfer all overlap."""
             # from this thread's start to its first segment handed over:
             # the streamed fit's staging
             staging = obs.span(
@@ -1654,12 +1511,8 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                 return False
 
             def _upload(hx, hy):
-                logical = _f_nbytes(hx) + hy.nbytes
-                if wire_on:
-                    hx = _wire_encode(hx)
                 nbytes = _f_nbytes(hx) + hy.nbytes
                 stats["bytes_uploaded"] += nbytes
-                stats["wire_bytes_saved"] += max(0, logical - nbytes)
                 stats["segments"] += 1
                 obs.metrics.counter("estimator.stream.bytes_uploaded").inc(
                     nbytes
@@ -1682,23 +1535,6 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
             try:
                 for epoch_ in epochs:
                     if stop.is_set():
-                        return
-                    if (
-                        hybrid_gate is not None
-                        and not hybrid_gate.is_set()
-                        and epoch_ != epochs[0]
-                    ):
-                        # hybrid, decision pending: epoch 1 usually seals the
-                        # device cache and every later epoch replays it —
-                        # running ahead would upload segments only to throw
-                        # them away. Hold at the boundary until the consumer
-                        # rules (sealed → exit; overflow/resume → stream on).
-                        while not hybrid_gate.wait(0.2):
-                            if stop.is_set():
-                                return
-                    if cache is not None and cache_ready["ok"]:
-                        # hybrid: everything from here on replays the device
-                        # cache — no more host IO to do
                         return
                     host_iter, coalesced, block_iter = epoch_plan(epoch_)
                     if coalesced:
@@ -1737,33 +1573,6 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
             except BaseException as exc:  # noqa: BLE001 - surface in consumer
                 _emit(exc)
 
-        # hybrid mode: the first FULLY-streamed epoch's uploaded segments are
-        # pinned here and later epochs scan them straight from device memory
-        # (order reshuffled per epoch). None = disabled or overflowed the
-        # device budget mid-stream.
-        hybrid = self.streaming == "hybrid"
-        cache: Optional[List[Any]] = [] if hybrid else None
-        cache_ready = {"ok": False}
-        # set once the consumer has ruled on the device cache (sealed OR
-        # abandoned): until then the producer holds at epoch boundaries —
-        # see _produce_fit
-        hybrid_gate = threading.Event() if hybrid else None
-
-        def _device_cache_budget() -> int:
-            budget = self.stream_cache_memory_limit or self.scan_memory_limit
-            try:
-                stats_ = jax.devices()[0].memory_stats() or {}
-                limit = int(stats_.get("bytes_limit", 0))
-                if limit > 0:
-                    # leave at least half of HBM for params/activations —
-                    # pinning must degrade to streaming, not to device OOM
-                    budget = min(budget, limit // 2)
-            except Exception:  # raydp-lint: disable=swallowed-exceptions (backend without memory stats: keep the config budget)
-                pass  # backend without memory stats: keep the config budget
-            return budget
-
-        cache_budget = _device_cache_budget() if hybrid else 0
-
         # the whole-fit pipeline: one queue + one producer thread, started
         # once by _fit_once before the epoch loop and closed in its finally
         pipe: Dict[str, Any] = {"q": None, "stop": None, "thread": None}
@@ -1790,10 +1599,9 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
             pipe["thread"].start()
 
         def close():
-            """Stop + drain + join the producer. A failing (or cache-served)
-            consumer must not abandon a producer parked on the full queue —
-            it would pin ``stream_prefetch_segments`` device segments
-            forever."""
+            """Stop + drain + join the producer. A failing consumer must
+            not abandon a producer parked on the full queue — it would pin
+            ``stream_prefetch_segments`` device segments forever."""
             thread = pipe["thread"]
             if thread is None:
                 return
@@ -1806,100 +1614,15 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
             thread.join(timeout=10)
             pipe["thread"] = None
 
-        def run(params, opt_state, epoch, start_step, save_cb=None):
-            nonlocal cache
-            if cache is not None and not cache_ready["ok"] and start_step != 0:
-                # a resumed (partial) epoch must not become the cache: later
-                # epochs would silently replay only its tail
-                cache = None
-            if cache is not None and cache_ready["ok"] and start_step == 0:
-                # hybrid steady state: replay the device cache. The producer
-                # may have run ahead into this epoch before the cache sealed
-                # — close it now so its prefetched segments don't sit pinned
-                # behind a full queue for the rest of the fit
-                close()
-                return _run_cached(params, opt_state, epoch)
+        def run(params, opt_state, start_step, save_cb=None):
             if pipe["thread"] is None:
                 raise RuntimeError(
                     "stream pipeline not started (run.start was not called)"
                 )
             done = start_step
             loss_total = jnp.zeros((), jnp.float32)
-            try:
-                params, opt_state, loss_total, done = _consume(
-                    params, opt_state, loss_total, done, epoch, save_cb
-                )
-                if cache is not None and start_step == 0:
-                    cache_ready["ok"] = True  # one FULL epoch pinned
-            finally:
-                if hybrid_gate is not None:
-                    # the cache ruling for this epoch is in (sealed,
-                    # abandoned, or the fit is failing): unblock a producer
-                    # holding at the boundary either way
-                    hybrid_gate.set()
-            return params, opt_state, loss_total, done - start_step
-
-        run.start = start
-        run.close = close
-
-        def _run_cached(params, opt_state, epoch):
-            """Hybrid later-epoch path: scan the pinned device segments —
-            zero host IO, zero H2D. Segment order reshuffles per GLOBAL
-            epoch (same seed+epoch convention as the streamed path). No
-            mid-epoch step checkpoints: a step-resume streams its epoch
-            fresh, whose batch order differs from the cached replay — only
-            epoch-boundary checkpoints are replay-consistent here."""
-            stats["cached_epochs"] += 1
-            loss_total = None
-            done = 0
-            dispatches = 0
-            order = np.arange(len(cache))
-            if self.shuffle:
-                np.random.default_rng((self.seed or 0) + epoch).shuffle(order)
-            for oi in order:
-                xb, yb = cache[int(oi)]
-                length = _f0(xb).shape[0]
-                if length not in compiled:
-                    with self._compile_span(length):
-                        compiled[length] = jitted.lower(
-                            params, opt_state, xb, yb
-                        ).compile()
-                    self._note_step_flops_abstract(
-                        scan_step, params, opt_state,
-                        _fmap(
-                            lambda a: jax.ShapeDtypeStruct(
-                                a.shape[1:], a.dtype
-                            ),
-                            xb,
-                        ),
-                        jax.ShapeDtypeStruct(yb.shape[1:], yb.dtype),
-                    )
-                params, opt_state, loss_sum = self._dispatch(
-                    compiled[length], length, params, opt_state, xb, yb
-                )
-                loss_total = (
-                    loss_sum if loss_total is None else loss_total + loss_sum
-                )
-                done += length
-                dispatches += 1
-                if (
-                    self.sync_every_steps
-                    and dispatches % self.sync_every_steps == 0
-                ):
-                    # same queue-depth cap as _consume: multi-epoch cached
-                    # fits must not enqueue unbounded async dispatches
-                    t_s = time.perf_counter()
-                    jax.block_until_ready(loss_total)
-                    recorder.note("sync", time.perf_counter() - t_s)
-            if loss_total is None:
-                loss_total = jnp.zeros((), jnp.float32)
-            return params, opt_state, loss_total, done
-
-        def _consume(params, opt_state, loss_total, done, epoch, save_cb):
-            nonlocal cache
             pending_save = None
             dispatches = 0
-            cache_bytes = 0
             seg_q = pipe["q"]
             while True:
                 with obs.span("estimator.segment_wait") as wait_span:
@@ -1922,12 +1645,6 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                 recorder.note(
                     "ingest", idle, steps=max(1, _f0(xb).shape[0])
                 )
-                if cache is not None and not cache_ready["ok"]:
-                    cache_bytes += _f_nbytes(xb) + yb.nbytes
-                    if cache_bytes > cache_budget:
-                        cache = None  # over the device budget: stay streaming
-                    else:
-                        cache.append((xb, yb))
                 if pending_save is not None:
                     # more data follows the boundary: commit the deferred
                     # step checkpoint (a boundary at stream end is dropped —
@@ -1942,7 +1659,7 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                             params, opt_state, xb, yb
                         ).compile()
                     self._note_step_flops_abstract(
-                        scan_step, params, opt_state,
+                        step_impl, params, opt_state,
                         _fmap(
                             lambda a: jax.ShapeDtypeStruct(
                                 a.shape[1:], a.dtype
@@ -1973,14 +1690,15 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                     t_s = time.perf_counter()
                     jax.block_until_ready(loss_total)
                     recorder.note("sync", time.perf_counter() - t_s)
-            return params, opt_state, loss_total, done
+            return params, opt_state, loss_total, done - start_step
 
+        run.start = start
+        run.close = close
         return run
 
     def _build_scan_runner(self, train_source, batch_size, mesh, step_impl, donate):
-        """Whole-epoch training as ONE jitted ``lax.scan`` over the staged
-        batches — removes the per-step Python dispatch that costs 13-16% vs a
-        raw jit loop (VERDICT r2 item 2). Two variants:
+        """The ``resident_scan`` runner: whole-epoch training as ONE jitted
+        ``lax.scan`` over the staged batches. Two variants:
 
         - single-device: the dataset lives ON DEVICE for the whole fit; each
           epoch ships only a permutation vector and gathers shuffled batches
@@ -1991,31 +1709,14 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
 
         Compilation is AOT (``lower().compile()``) so ``compile_seconds_``
         records the real compile cost rather than folding a whole epoch's
-        compute into it. Returns ``(run_epoch, run_fullfit)`` — the second
-        drives the WHOLE fit (all epochs) as one dispatch via an outer
-        epoch-scan over stacked permutations, available on the
-        device-resident path only (None otherwise); callers use it when no
-        per-epoch side effect (checkpoint, eval) needs params between
-        epochs. Returns (None, None) when the scan path doesn't apply
-        (streaming, oversized staged arrays, or scan_epochs=False)."""
+        compute into it. Built only for a fit that ``_choose_runner`` gave
+        ``resident_scan``: ``train_source`` is staged host arrays of a
+        batch or more."""
         import jax
         import jax.numpy as jnp
-        from jax import lax
-        from jax.sharding import NamedSharding, PartitionSpec
-
         from raydp_tpu.parallel.partitioner import _mesh_device_count
 
-        if self.streaming or not isinstance(train_source, _HostArrays):
-            return None, None
-        if self.scan_epochs is False:
-            return None, None
         feats, labs = train_source.features, train_source.labels
-        if len(_f0(feats)) < batch_size:
-            return None, None
-        if self.scan_epochs is None:
-            if _f_nbytes(feats) + _lnbytes(labs) > self.scan_memory_limit:
-                return None, None
-
         n = len(_f0(feats))
         steps_per_epoch = n // batch_size
         n_used = steps_per_epoch * batch_size
@@ -2059,9 +1760,6 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                     labs,
                 ),
             )
-
-        def _order(seed):
-            return _shuffled(n, seed)[:n_used]
 
         if device_resident:
             from raydp_tpu.parallel.partitioner import _mesh_single_device
@@ -2177,7 +1875,7 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                 return out
 
         def run_epoch(params, opt_state, seed, start_step=0, save_cb=None):
-            order = _order(seed)
+            order = _shuffled(n, seed)[:n_used]
             # the common one-segment epoch must not pay an extra scalar-add
             # dispatch per epoch
             loss_total = None
@@ -2198,66 +1896,7 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                 loss_total = jnp.zeros((), jnp.float32)
             return params, opt_state, loss_total, steps_per_epoch - start_step
 
-        run_fullfit = None
-        # Mixed-dtype (embedding-gather) workloads run FASTER as per-epoch
-        # dispatches than as one whole-fit dispatch: on v5e at the DLRM
-        # tracked config the nested epoch-scan measured 1.7-2.1M sps and a
-        # flattened single scan 2.0-2.2M, vs 2.8M for per-epoch dispatch
-        # with whole-epoch pre-gather — the outer scan defeats XLA's gather
-        # fusion. Dense models (MLP) keep the fullfit win (r4: 1.26x pure).
-        if device_resident and not isinstance(feats, tuple):
-
-            def fullfit_body(params, opt_state, xs, ys, perms):
-                # outer scan over epochs of the inner per-step scan: ONE
-                # dispatch trains the whole fit; per-epoch loss sums come
-                # back as one [E] array. The pure-JAX ceiling dispatches
-                # once per epoch — this path beats it by construction.
-                def one_epoch(carry, perm):
-                    p, o = carry
-                    xb = _fmap(
-                        lambda a: a[perm].reshape(
-                            (steps_per_epoch, batch_size) + a.shape[1:]
-                        ),
-                        xs,
-                    )
-                    yb = _lmap(
-                        lambda a: a[perm].reshape(
-                            (steps_per_epoch, batch_size) + a.shape[1:]
-                        ),
-                        ys,
-                    )
-                    p, o, loss_sum = epoch_body(p, o, xb, yb)
-                    return (p, o), loss_sum
-
-                (params, opt_state), losses = jax.lax.scan(
-                    one_epoch, (params, opt_state), perms
-                )
-                return params, opt_state, losses
-
-            def run_fullfit(params, opt_state, seeds):
-                if len(seeds) * n_used * 4 > self.scan_memory_limit:
-                    return None  # permutation stack would not fit; use epochs
-                perms = jnp.asarray(np.stack([_order(s) for s in seeds]))
-                key = ("fullfit", len(seeds))
-                if key not in compiled:
-                    with self._compile_span("fullfit"):
-                        compiled[key] = (
-                            partial_jit(
-                                donate_argnums=(0, 1) if donate else (),
-                            )(fullfit_body)
-                            .lower(params, opt_state, xs_dev, ys_dev, perms)
-                            .compile()
-                        )
-                    _note_flops(params, opt_state)
-                # the whole fit is ONE dispatch; the history fetch is its
-                # fence, and completed work over that wall the live MFU
-                params, opt_state, losses = self._dispatch(
-                    compiled[key], len(seeds) * steps_per_epoch,
-                    params, opt_state, xs_dev, ys_dev, perms,
-                )
-                return params, opt_state, losses, steps_per_epoch
-
-        return run_epoch, run_fullfit
+        return run_epoch
 
     def _epoch_batches(self, source, batch_size, seed, shuffle=None,
                        segment_rows=None):

@@ -1,6 +1,6 @@
 """MLP models — the NYCTaxi workload family (reference
 examples/pytorch_nyctaxi.py builds a 5-layer torch MLP; this is the flax
-equivalent used by examples, tests, and bench.py)."""
+equivalent used by examples and tests)."""
 
 from __future__ import annotations
 

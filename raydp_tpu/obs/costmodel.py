@@ -1,30 +1,23 @@
 """Analytic compute cost model: FLOPs accounting, device peaks, MFU.
 
-Until PR 15 this lived as one-shot code inside ``bench.py``
-(``lm_train_flops_per_step``, ``_device_peak_flops``, the r05 roofline) —
-which meant MFU existed only while a bench ran, and ROADMAP item 2's
-"re-run the roofline probe on real hardware" required carrying a script
-around. This module is the library version the cluster carries:
+The FLOPs accounting the cluster carries, so that MFU exists while any fit
+or decode runs:
 
 - **analytic FLOPs** for the model families the repo ships
   (:func:`lm_train_flops_per_step`, :func:`mlp_train_flops_per_step`) —
-  matmul-only accounting, fwd+bwd as 3x forward, the convention every
-  BENCH_r* MFU number was computed with;
+  matmul-only accounting, fwd+bwd as 3x forward;
 - **measured FLOPs** from XLA's own cost analysis
   (:func:`step_flops_from_compiled`) — what the estimator's live MFU gauge
   uses, since a fit's step function is arbitrary user code the analytic
   tables can't know. The two accountings agree to within the optimizer /
   elementwise overhead XLA counts and the analytic tables deliberately
-  ignore (``fit_profile_probe`` cross-checks them; docs/observability.md
-  "Compute observatory");
+  ignore (``tests/test_profiler.py`` cross-checks them;
+  docs/observability.md "Compute observatory");
 - **peak FLOP/s** per device (:func:`device_peak_flops`): the TPU bf16
   table, an env override (``RAYDP_TPU_PEAK_FLOPS``) for exotic backends,
   and a NOMINAL cpu estimate (cores × 3 GHz × 16 f32 lanes) so the MFU
   gauge exists on dev boxes too — explicitly approximate, labeled
   ``peak_source`` so nobody mistakes a CPU MFU for a measured roofline.
-
-One FLOPs accounting, bit-identical numbers in ``bench.py`` and the live
-``estimator.mfu`` gauge — both import THIS module.
 
 Stdlib + jax-on-demand only: importable before (or without) jax.
 """
@@ -100,39 +93,6 @@ def lm_train_flops_per_step(batch: int, seq: int, d_model: int,
     per_token = num_layers * (24 * d_model**2 + 2 * d_model * (seq + 1))
     per_token += 2 * d_model * vocab
     return 3 * batch * seq * per_token
-
-
-def lm_nonattn_flops_per_step(batch: int, seq: int, d_model: int,
-                              num_layers: int, vocab: int) -> int:
-    """The step's FLOPs with attention as identity — the roofline
-    decomposition's other arm (attention FLOPs = total - this)."""
-    return 3 * batch * seq * (
-        num_layers * 24 * d_model**2 + 2 * d_model * vocab
-    )
-
-
-def lm_decode_flops_per_token(d_model: int, num_layers: int, vocab: int,
-                              context: int) -> int:
-    """Analytic matmul FLOPs to decode ONE token with ``context`` tokens of
-    KV behind it (forward only — serving runs no backward): per layer
-    24*d^2 dense matmuls plus 4*d*context attention (QK^T and AV each read
-    the full cache), plus the d*V lm_head. The capacity planner's per-token
-    roofline arm (tools/capacity_plan.py)."""
-    per_token = num_layers * (24 * d_model**2 + 4 * d_model * int(context))
-    per_token += 2 * d_model * vocab
-    return int(per_token)
-
-
-def lm_prefill_flops(prompt: int, d_model: int, num_layers: int,
-                     vocab: int) -> int:
-    """Forward-only matmul FLOPs of one prefill pass over ``prompt``
-    tokens: the train accounting's forward third (causal attention at
-    average context (prompt+1)/2) — bounds the TTFT compute floor."""
-    per_token = num_layers * (
-        24 * d_model**2 + 2 * d_model * (int(prompt) + 1)
-    )
-    per_token += 2 * d_model * vocab
-    return int(prompt) * per_token
 
 
 def mlp_train_flops_per_step(batch: int, layer_dims: Sequence[int]) -> int:

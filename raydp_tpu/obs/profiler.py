@@ -13,9 +13,8 @@ Dapper-style complement to request tracing). Three pieces:
   TSDB (scrapeable mid-fit), and the count of steps the device has
   FINISHED, read without a fence from the returned loss handles
   (``estimator.steps_completed``). The instruments are the registry's
-  lock-free histograms; overhead is gated ≤5% on the fit step p50 in
-  perf_smoke (``fit_profile_probe``), and ``RAYDP_TPU_STEP_PROFILER=0``
-  turns the recorder into a shared no-op.
+  lock-free histograms; ``RAYDP_TPU_STEP_PROFILER=0`` turns the recorder
+  into a shared no-op.
 - **Capture window** (:class:`CaptureWindow` / :func:`profile_fit`): an
   on-demand deep capture — wraps ``jax.profiler`` start/stop_trace when
   the backend supports it, and ALWAYS collects the obs span records of the

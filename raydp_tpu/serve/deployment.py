@@ -29,6 +29,7 @@ from typing import List, Optional
 from raydp_tpu import obs, sanitize
 from raydp_tpu.cluster import api as cluster
 from raydp_tpu.cluster.common import ActorState, ClusterError
+from raydp_tpu.obs import tracing
 from raydp_tpu.serve.autoscaler import ServeController
 from raydp_tpu.serve.batcher import DynamicBatcher
 from raydp_tpu.serve.config import ServeConf
@@ -124,6 +125,10 @@ class Deployment:
             max_restarts=0,
             max_concurrency=self._conf.replica_max_concurrency,
             light=self._conf.replica_light,
+            # a replica traces when the driver that deploys it does: the
+            # head (and its zygote) may predate this driver's tracing, and a
+            # worker reads its tracing state from the fork request's env
+            env={tracing.TRACE_ENV: "1"} if tracing.enabled() else None,
         )
         return handle
 

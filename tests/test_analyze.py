@@ -171,15 +171,15 @@ def test_conf_registry_clean_on_fixed():
 
 
 def test_env_registry_catches_seed():
-    """env-registry runs only on full-surface sweeps (package + bench in
-    scope): the fixture's undocumented RAYDP_TPU_ETLFX_FIXTURE_FLAG read is
+    """env-registry runs only on full-surface sweeps (package + the tools'
+    reader side in scope): the fixture's undocumented RAYDP_TPU_ETLFX_FIXTURE_FLAG read is
     the single finding against the real docs tree."""
     from tools.analyze.__main__ import config_excludes
 
     project = load_project(
         [
             os.path.join(REPO_ROOT, "raydp_tpu"),
-            os.path.join(REPO_ROOT, "bench.py"),
+            os.path.join(REPO_ROOT, "tools", "trace_analyze.py"),
             os.path.join(REPO_ROOT, "tests", "conftest.py"),
             os.path.join(FIXTURES, "envreg_bad.py"),
         ],
@@ -198,7 +198,7 @@ def test_env_registry_clean_on_fixed():
     project = load_project(
         [
             os.path.join(REPO_ROOT, "raydp_tpu"),
-            os.path.join(REPO_ROOT, "bench.py"),
+            os.path.join(REPO_ROOT, "tools", "trace_analyze.py"),
             os.path.join(REPO_ROOT, "tests", "conftest.py"),
             os.path.join(FIXTURES, "envreg_good.py"),
         ],
@@ -309,7 +309,6 @@ def test_metric_registry_mutation_check():
         [
             os.path.join(REPO_ROOT, "raydp_tpu"),
             os.path.join(REPO_ROOT, "tools"),
-            os.path.join(REPO_ROOT, "bench.py"),
             os.path.join(REPO_ROOT, "examples"),
             os.path.join(REPO_ROOT, "tests", "conftest.py"),
         ],
@@ -388,7 +387,6 @@ def test_repo_suppressions_within_budget():
         [
             os.path.join(REPO_ROOT, "raydp_tpu"),
             os.path.join(REPO_ROOT, "tools"),
-            os.path.join(REPO_ROOT, "bench.py"),
             os.path.join(REPO_ROOT, "examples"),
             os.path.join(REPO_ROOT, "tests", "conftest.py"),
         ],
@@ -710,7 +708,6 @@ def _full_sweep_project():
         [
             os.path.join(REPO_ROOT, "raydp_tpu"),
             os.path.join(REPO_ROOT, "tools"),
-            os.path.join(REPO_ROOT, "bench.py"),
             os.path.join(REPO_ROOT, "examples"),
             os.path.join(REPO_ROOT, "tests", "conftest.py"),
         ],
@@ -837,7 +834,7 @@ def test_rpc_contract_cli_gates_pass():
     env = dict(os.environ, PYTHONPATH=REPO_ROOT)
     done = subprocess.run(
         [sys.executable, "-m", "tools.analyze",
-         "raydp_tpu/", "tools/", "bench.py", "examples/", "chip_smoke.py",
+         "raydp_tpu/", "tools/", "examples/", "chip_smoke.py",
          os.path.join("tests", "conftest.py"),
          "--check-contract", "--check-rpc-table"],
         cwd=REPO_ROOT, env=env, capture_output=True, text=True,
@@ -849,7 +846,7 @@ def test_rpc_contract_cli_gates_pass():
 
 def test_repo_is_lint_clean():
     """The invocation CI gates on, plus chip_smoke.py: every finding in
-    raydp_tpu/, the self-hosted tools/ tree, bench.py, examples/,
+    raydp_tpu/, the self-hosted tools/ tree, examples/,
     chip_smoke.py and tests/conftest.py carries an explicit suppression —
     with the full-surface registry rules (metric/conf/env closure) and
     exception-flow rules active."""
@@ -859,7 +856,6 @@ def test_repo_is_lint_clean():
         [
             os.path.join(REPO_ROOT, "raydp_tpu"),
             os.path.join(REPO_ROOT, "tools"),
-            os.path.join(REPO_ROOT, "bench.py"),
             os.path.join(REPO_ROOT, "examples"),
             os.path.join(REPO_ROOT, "chip_smoke.py"),
             os.path.join(REPO_ROOT, "tests", "conftest.py"),

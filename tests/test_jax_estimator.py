@@ -268,7 +268,7 @@ def test_dlrm_mixed_dtype_fit(session, criteo_df):
 
 
 def test_dlrm_mixed_dtype_fit_with_eval_and_ckpt(session, criteo_df):
-    """The per-epoch (non-fullfit) scan path: eval each epoch + checkpoint
+    """The resident per-epoch scan: eval each epoch + checkpoint
     round-trip with tuple features."""
     ckpt = tempfile.mkdtemp()
     ds = dataframe_to_dataset(criteo_df)
@@ -277,6 +277,7 @@ def test_dlrm_mixed_dtype_fit_with_eval_and_ckpt(session, criteo_df):
     assert len(history) == 3
     assert all(np.isfinite(r["eval_loss"]) for r in history)
     assert os.path.isdir(os.path.join(ckpt, "epoch_2"))
+    assert est.fit_stats_["runner"] == "resident_scan"
 
 
 def test_dlrm_mixed_dtype_streaming(session, criteo_df):
@@ -286,70 +287,18 @@ def test_dlrm_mixed_dtype_streaming(session, criteo_df):
     est = _dlrm_est([1000, 50], streaming=True, shuffle=False, num_epochs=3)
     history = est.fit(ds)
     assert len(history) == 3
+    assert est.fit_stats_["runner"] == "segment_scan"
     assert history[-1]["train_loss"] < history[0]["train_loss"]
 
 
-def test_dlrm_mixed_dtype_streaming_hybrid(session, criteo_df):
-    """hybrid streaming × mixed-dtype: the device cache pins TUPLE-featured
-    segments (dense f32, ids i32) and later epochs scan them from HBM."""
-    ds = dataframe_to_dataset(criteo_df)
-    est = _dlrm_est(
-        [1000, 50], streaming="hybrid", shuffle=False, num_epochs=4
-    )
-    history = est.fit(ds)
-    assert len(history) == 4
-    assert history[-1]["train_loss"] < history[0]["train_loss"]
-    stats = est.stream_stats_
-    assert stats["cached_epochs"] == 3, stats  # only epoch 1 streamed
-    assert stats["bytes_uploaded"] > 0
-
-
-def test_streaming_hybrid_caches_segments(session, linear_df):
-    """streaming="hybrid": epoch 1 streams and pins segments on device;
-    later epochs scan from HBM (no re-upload). Loss trajectory must stay
-    sane and the pipeline stats must show exactly one streamed epoch."""
-    ds = dataframe_to_dataset(linear_df)
-    est = JaxEstimator(
-        model=_mlp(), optimizer="adam", loss="mse",
-        feature_columns=["x", "y"], label_column="z",
-        batch_size=128, num_epochs=5, learning_rate=3e-3,
-        shuffle=True, seed=0, streaming="hybrid",
-    )
-    history = est.fit(ds)
-    assert len(history) == 5
-    assert history[-1]["train_loss"] < history[0]["train_loss"]
-    stats = est.stream_stats_
-    # 2048 rows -> 16 batches -> 1 segment of 16 + stats from ONE epoch only
-    assert stats["cached_epochs"] == 4
-    assert stats["bytes_uploaded"] > 0
-    # vs pure streaming: every epoch re-streams, nothing cached
-    est2 = JaxEstimator(
-        model=_mlp(), optimizer="adam", loss="mse",
-        feature_columns=["x", "y"], label_column="z",
-        batch_size=128, num_epochs=5, learning_rate=3e-3,
-        shuffle=True, seed=0, streaming=True,
-    )
-    h2 = est2.fit(ds)
-    assert est2.stream_stats_["cached_epochs"] == 0
-    assert est2.stream_stats_["bytes_uploaded"] > stats["bytes_uploaded"] * 3
-    # same data, same seeds: comparable convergence
-    assert h2[-1]["train_loss"] < h2[0]["train_loss"]
-
-
-def test_streaming_hybrid_overflow_falls_back(session, linear_df):
-    """A dataset larger than scan_memory_limit must NOT be pinned: hybrid
-    silently stays in pure streaming mode."""
-    ds = dataframe_to_dataset(linear_df)
-    est = JaxEstimator(
-        model=_mlp(), optimizer="adam", loss="mse",
-        feature_columns=["x", "y"], label_column="z",
-        batch_size=128, num_epochs=3, learning_rate=3e-3,
-        shuffle=False, seed=0, streaming="hybrid",
-        scan_memory_limit=1024,  # far below the dataset's bytes
-    )
-    history = est.fit(ds)
-    assert len(history) == 3
-    assert est.stream_stats_["cached_epochs"] == 0
+def test_streaming_takes_a_bool_only():
+    """`streaming` is a bool since the hybrid mode went: a string that is
+    merely truthy must not start to mean plain streaming in silence."""
+    with pytest.raises(ValueError, match="streaming=True"):
+        JaxEstimator(
+            model=_mlp(), feature_columns=["x", "y"], label_column="z",
+            streaming="hybrid",
+        )
 
 
 def test_dlrm_big_vocab_exact_ids(session):
@@ -765,12 +714,92 @@ def test_fit_on_etl_rejects_junk_input(session):
         est.fit_on_etl([1, 2, 3])
 
 
-def test_fullfit_scan_matches_epoch_paths():
-    """The whole-fit scan (one dispatch for all epochs), the per-epoch scan
-    (forced via checkpoint_dir), and the explicit per-step loop
-    (scan_epochs=False) must train IDENTICALLY for the same seed: same host
-    permutations, same step math — per-epoch losses equal to float32
-    tolerance. Guards the fullfit fast path against silent divergence."""
+class _ArraysDS:
+    """A dataset that hands `_stage_host` its arrays: a staged fit with no
+    cluster behind it."""
+
+    def __init__(self, x, y):
+        self.x, self.y = x, y
+
+    def to_numpy(self, fc, lc, feature_dtype=None, label_dtype=None):
+        return self.x.copy(), self.y.copy()
+
+
+# every way a fit reaches each of its three training runners: constructor
+# arguments, the training source (staged host arrays of 2048 rows x 3
+# float32 + labels = 32 KiB, or a Dataset a streamed fit reads block by
+# block) -> runner. An evaluation set or a checkpoint directory is no input
+# of the choice: test_staged_fit_takes_the_resident_scan runs those fits.
+_RUNNER_TABLE = [
+    ("resident", dict(), "staged", "resident_scan"),
+    ("resident-forced-over-limit",
+     dict(scan_epochs=True, scan_memory_limit=1024), "staged", "resident_scan"),
+    ("resident-no-label", dict(label_column=None), "staged", "resident_scan"),
+    ("staged-over-limit", dict(scan_memory_limit=1024), "staged",
+     "segment_scan"),
+    ("staged-under-a-batch", dict(batch_size=4096), "staged", "segment_scan"),
+    ("streamed", dict(streaming=True), "dataset", "segment_scan"),
+    ("streamed-scan-epochs-off", dict(streaming=True, scan_epochs=False),
+     "dataset", "segment_scan"),
+    ("streamed-no-label", dict(streaming=True, label_column=None), "dataset",
+     "per_step"),
+    ("scan-epochs-off", dict(scan_epochs=False), "staged", "per_step"),
+    ("segments-off-streamed", dict(streaming=True, stream_scan_steps=0),
+     "dataset", "per_step"),
+    ("segments-off-over-limit",
+     dict(scan_memory_limit=1024, stream_scan_steps=0), "staged", "per_step"),
+    ("no-label-over-limit", dict(scan_memory_limit=1024, label_column=None),
+     "staged", "per_step"),
+]
+
+
+@pytest.mark.parametrize(
+    "kwargs,source,expected",
+    [row[1:] for row in _RUNNER_TABLE],
+    ids=[row[0] for row in _RUNNER_TABLE],
+)
+def test_choose_runner_table(kwargs, source, expected):
+    from raydp_tpu.estimator.jax_estimator import _HostArrays
+
+    kwargs = {"label_column": "l", "batch_size": 128, **kwargs}
+    est = JaxEstimator(
+        model=_mlp(), feature_columns=["a", "b", "c"], **kwargs
+    )
+    if source == "staged":
+        labels = (
+            None if kwargs["label_column"] is None
+            else np.zeros(2048, np.float32)
+        )
+        train_source = _HostArrays(np.zeros((2048, 3), np.float32), labels)
+    else:
+        train_source = object()  # a Dataset: anything but staged arrays
+    assert est._choose_runner(train_source, kwargs["batch_size"]) == expected
+
+
+@pytest.mark.parametrize("how", ["no-eval-no-ckpt", "evaluated", "checkpoint-dir"])
+def test_staged_fit_takes_the_resident_scan(how):
+    """A staged fit that nothing looks into between epochs (once one
+    whole-fit dispatch), an evaluated one and a checkpointed one all run
+    the per-epoch resident scan, and each epoch has its own record."""
+    x = np.random.default_rng(2).random((512, 3)).astype(np.float32)
+    ds = _ArraysDS(x, x.sum(1))
+    est = JaxEstimator(
+        model=_mlp(), loss="mse", feature_columns=["a", "b", "c"],
+        label_column="l", batch_size=128, num_epochs=2,
+        checkpoint_dir=tempfile.mkdtemp() if how == "checkpoint-dir" else None,
+    )
+    history = est.fit(ds, ds if how == "evaluated" else None)
+    assert est.fit_stats_["runner"] == "resident_scan"
+    assert [r["epoch"] for r in history] == [0, 1]
+    assert all(r["epoch_seconds"] > 0 for r in history)
+    assert ("eval_loss" in history[0]) == (how == "evaluated")
+
+
+def test_resident_scan_matches_per_step_loop():
+    """The resident per-epoch scan (with and without a checkpoint
+    directory) and the explicit per-step loop (scan_epochs=False) must
+    train IDENTICALLY for the same seed: same host permutations, same step
+    math — per-epoch losses equal to float32 tolerance."""
     from raydp_tpu.models import MLPRegressor
 
     rng = np.random.default_rng(9)
@@ -778,11 +807,7 @@ def test_fullfit_scan_matches_epoch_paths():
     x = rng.random((n, 3)).astype(np.float32)
     y = (x @ np.array([1.0, -2.0, 0.5], np.float32)).astype(np.float32)
 
-    class ArraysDS:
-        def to_numpy(self, fc, lc, feature_dtype=None, label_dtype=None):
-            return x.copy(), y.copy()
-
-    def run(**kw):
+    def run(runner="resident_scan", **kw):
         est = JaxEstimator(
             model=MLPRegressor(),
             optimizer="adam",
@@ -796,11 +821,12 @@ def test_fullfit_scan_matches_epoch_paths():
             seed=4,
             **kw,
         )
-        return [r["train_loss"] for r in est.fit(ArraysDS())]
+        losses = [r["train_loss"] for r in est.fit(_ArraysDS(x, y))]
+        assert est.fit_stats_["runner"] == runner
+        return losses
 
-    fullfit = run()  # no checkpoint/eval → whole-fit scan
-    # a checkpoint dir disables the fullfit fast path → per-epoch scans
-    per_epoch = run(checkpoint_dir=tempfile.mkdtemp())
-    loop = run(scan_epochs=False)  # true per-step dispatch loop
-    np.testing.assert_allclose(fullfit, per_epoch, rtol=1e-5)
-    np.testing.assert_allclose(fullfit, loop, rtol=1e-4)
+    scan = run()
+    with_ckpt = run(checkpoint_dir=tempfile.mkdtemp())
+    loop = run("per_step", scan_epochs=False)  # true per-step dispatch loop
+    np.testing.assert_allclose(scan, with_ckpt, rtol=1e-5)
+    np.testing.assert_allclose(scan, loop, rtol=1e-4)

@@ -113,6 +113,34 @@ def test_cross_node_shuffle_query(two_nodes):
     assert served_after > served_before
 
 
+def test_reduce_placement_follows_the_bytes(two_nodes):
+    """With an executor on each host, every reducer of a cross-host shuffle
+    is placed on the host that holds most of its input bytes: the planner
+    counts ``planner.locality_hits`` and no miss."""
+    from raydp_tpu import obs
+    from raydp_tpu.etl import functions as F
+
+    table = pa.table(
+        {"k": np.arange(4000) % 13, "v": np.arange(4000, dtype=np.float64)}
+    )
+    blocks = [
+        T.write_table_block(table.slice(i * 1000, 1000))[0] for i in range(4)
+    ]
+    hits = obs.metrics.counter("planner.locality_hits")
+    misses = obs.metrics.counter("planner.locality_misses")
+    hits_before, misses_before = hits.value, misses.value
+    planner = Planner(two_nodes["executors"], default_parallelism=4)
+    mat = planner.materialize(
+        lp.GroupByAgg(lp.ArrowSource(blocks, table.schema), ["k"], [F.sum("v")])
+    )
+    out = pa.concat_tables(
+        [T.read_table_block(b) for b in mat.blocks if b is not None]
+    )
+    assert out.num_rows == 13
+    assert hits.value > hits_before
+    assert misses.value == misses_before
+
+
 def test_cross_node_block_read_and_gc(two_nodes):
     """Blocks produced on the agent node are readable from the driver only
     via the network pull path, and deletes unlink them on the agent's host."""

@@ -711,6 +711,25 @@ def test_serve_request_trace_linkage(served_model):
         assert entry["count"] >= 1 and entry["mean_ms"] >= 0.0
 
 
+def test_scrape_carries_serve_series(served_model):
+    """One real scrape of the head's endpoint with a deployment up parses
+    and carries at least one ``serve_`` series beside a ``tenant``-labeled
+    one: what a dashboard of a serving cluster reads."""
+    from raydp_tpu.cluster import api as cluster
+    from raydp_tpu.obs.timeseries import parse_prometheus_text, scrape
+
+    dep, x = served_model
+    dep.predict(x[0:1])
+    host, port = cluster.head_rpc("obs_configure", scrape_port=0)["scrape_addr"]
+    obs.flush()
+    parsed = parse_prometheus_text(scrape(host, port))
+    assert any(name.startswith("raydp_serve_") for name in parsed), sorted(parsed)
+    assert any(
+        key == "tenant"
+        for series in parsed.values() for labels in series for key, _ in labels
+    )
+
+
 def test_serve_request_trace_sampling_off(served_model):
     """Unsampled arm: with shipping disabled no serve.request spans are
     minted (the sampler gates on tracing), while the stage histograms —

@@ -195,7 +195,7 @@ def test_scan_path_reports_same_flops(host_ds, per_step_fit):
     """The segment-scanned path must report the SAME FLOPs-per-step as the
     per-step loop (one accounting): the scan executable is opaque to cost
     analysis, so the single-step abstract lowering covers it."""
-    est_scan = _make_est()  # default scan_epochs → scan/fullfit path
+    est_scan = _make_est()  # default scan_epochs → the resident scan
     est_scan.fit(host_ds)
     per_step_est, _ = per_step_fit
     assert est_scan.fit_stats_["flops_per_step"] == pytest.approx(

@@ -115,7 +115,7 @@ def test_null_partitioner_passthrough():
 
 
 # ---------------------------------------------------------------------------
-# mixed-dtype wire staging helpers
+# row-quantization helpers of exchange/jax_io.py
 # ---------------------------------------------------------------------------
 
 
@@ -194,65 +194,14 @@ def test_streaming_upload_streams_follow_prefetch_depth(session):
 
 
 # ---------------------------------------------------------------------------
-# mixed-dtype wire staging through a real streaming fit
+# integer ids through a real streaming fit
 # ---------------------------------------------------------------------------
 
 
-def test_streaming_wire_quant_matches_equivalent_fp32_feed(session):
-    """int8 wire staging parity: a fit fed the original data with
-    stream_wire_quant="int8" must land bit-identical params to a plain fp32
-    fit fed the HOST-DEQUANTIZED data (quantize→dequantize applied up
-    front). That is exactly the claim that the on-chip widen equals the
-    host dequant — carried through an entire training run."""
-    import jax
-    import pyarrow as pa
-
-    from raydp_tpu.etl.tasks import write_table_block
-    from raydp_tpu.exchange.dataset import Dataset
-    from raydp_tpu.exchange.jax_io import dequantize_rows, quantize_rows
-
-    rng = np.random.default_rng(17)
-    n = 1024
-    feats = (rng.standard_normal((n, 3)) * 10).astype(np.float32)
-    z = (feats @ np.array([1.0, -2.0, 0.5], np.float32)).astype(np.float32)
-
-    def _ds(values):
-        cols = {f"x{i}": values[:, i].copy() for i in range(3)}
-        cols["z"] = z
-        ref, cnt = write_table_block(pa.table(cols))
-        t = pa.table(cols)
-        return Dataset([ref], t.schema, [cnt])
-
-    # reference arm: pre-quantized values through the plain fp32 wire
-    q, scale = quantize_rows(feats)
-    ref_est = _stream_fit(_ds(dequantize_rows(q, scale)),
-                          ["x0", "x1", "x2"])
-    assert ref_est.stream_stats_["wire_dtype"] is None
-
-    # wire arm: original values, quantized on the wire, widened on chip
-    wq_est = _stream_fit(_ds(feats), ["x0", "x1", "x2"],
-                         stream_wire_quant="int8")
-    assert wq_est.stream_stats_["wire_dtype"] == "int8"
-    assert wq_est.stream_stats_["wire_bytes_saved"] > 0
-
-    for a, b in zip(
-        jax.tree.leaves(ref_est.get_model().params),
-        jax.tree.leaves(wq_est.get_model().params),
-    ):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-def test_wire_quant_rejects_unknown_dtype(session):
-    ds, features = _block_dataset(n=256, seed=1)
-    with pytest.raises(ValueError, match="int8"):
-        _stream_fit(ds, features, stream_wire_quant="int4")
-
-
-def test_streaming_wire_quant_big_vocab_ids_exact(session):
-    """Wire quant must NEVER touch integer id leaves: a DLRM streaming fit
-    with vocab beyond float32's 2^24 exact range keeps adjacent
-    top-of-range ids distinct with stream_wire_quant on (ids ride exact
-    int32; only the float dense leaf quantizes)."""
+def test_streaming_big_vocab_ids_exact(session):
+    """Integer id leaves ride the streamed wire exact (int32): a DLRM
+    streaming fit with vocab beyond float32's 2^24 exact range keeps
+    adjacent top-of-range ids distinct through the segment runner."""
     from raydp_tpu.models import DLRM, dlrm_optimizer
 
     vocab = 2**24 + 8
@@ -279,11 +228,10 @@ def test_streaming_wire_quant_big_vocab_ids_exact(session):
         num_epochs=2,
         seed=0,
         streaming=True,
-        stream_wire_quant="int8",
     )
     history = est.fit(ds)
     assert np.isfinite(history[-1]["train_loss"])
-    assert est.stream_stats_["wire_dtype"] == "int8"
+    assert est.stream_stats_["segments"] > 0
     # the parity signal is learnable only if adjacent ids hit DISTINCT
     # embedding rows — float32-collapsed ids could not separate these
     model = est.get_model()

@@ -167,7 +167,7 @@ class Surfaces:
         self.doc_envs: List[DocEntry] = []
         self.doc_files: Dict[str, DocFile] = {}
         # full-surface mode: the project under analysis includes both the
-        # package and the bench/tools readers, so doc-side (dead-row) and
+        # package and the tools readers, so doc-side (dead-row) and
         # whole-program checks are meaningful. Partial sweeps (one
         # subdirectory) only get code-side checks.
         self.full_surface: bool = False
@@ -327,7 +327,7 @@ def _conf_wrapper_of(fn: ast.AST) -> Optional[_ConfWrapper]:
 
 def _get_wrapper_of(fn: ast.AST) -> bool:
     """Detect a generic lookup wrapper: single-key function whose body
-    subscripts/``.get``s an arbitrary mapping with its first param (bench's
+    subscripts/``.get``s an arbitrary mapping with its first param (a
     ``total(name)`` over dump_metrics snapshots). Calls with literal args
     become metric *mentions*."""
     if not isinstance(fn, (ast.FunctionDef, ast.AsyncFunctionDef)):
@@ -692,11 +692,11 @@ def _extract_doc(doc: DocFile, surfaces: Surfaces) -> None:
 # ---------------------------------------------------------------------------
 
 # the files whose presence in the project means "the whole surface is in
-# scope": the metric registry itself plus the bench harness (the scrape /
-# ledger reader side). Doc-side dead-row checks and whole-program
+# scope": the metric registry itself plus the tools' reader side (the trace
+# analyzer CLI). Doc-side dead-row checks and whole-program
 # read-without-writer checks only run then — a partial sweep of one
 # subdirectory must not flag every doc row as dead.
-_FULL_SURFACE_MARKERS = ("raydp_tpu/obs/metrics.py", "bench.py")
+_FULL_SURFACE_MARKERS = ("raydp_tpu/obs/metrics.py", "tools/trace_analyze.py")
 
 DOC_GLOBS = ("docs",)
 
