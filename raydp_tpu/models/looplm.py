@@ -25,7 +25,14 @@ The estimator trains it with ``loss="model"``: ``loss(x)`` takes the int32
 ``[B, T+1]`` sequence column whole, reads ``x[:, :-1]`` and predicts
 ``x[:, 1:]``. The exit loss never holds more than one token chunk of one
 exit's logits (``loss_chunk`` tokens; recomputed in the backward pass), and
-blocks are recomputed from their inputs (``remat``).
+blocks are recomputed from their inputs (``remat``) but for ``REMAT_KEEPS``:
+the flash kernel's output and log-sum-exp and ``w_down``'s output are kept
+from the forward pass (at the published widths, bf16, T 4096: 16.8 MB + 0.26
+MB + 16.8 MB a row and block application, 811.6 MB a row over the 24), so
+the backward pass runs no flash forward and no down-projection a second
+time; q, k, v, the norms, RoPE, ``wo``, gate and up are rebuilt.
+``fit_facts`` says what is kept (``remat_keeps``,
+``remat_kept_bytes_per_row``).
 """
 
 from __future__ import annotations
@@ -36,11 +43,18 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from raydp_tpu.models.transformer import _attend
+from raydp_tpu.ops.flash_attention import SAVED_RESIDUALS
 
 LAYER_MATRICES = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
 LAYER_NORMS = ("norm1", "norm2", "norm3", "norm4")
+# what a recomputed block keeps from its forward pass, the values that cost
+# most to rebuild per byte kept: the flash kernel's output and log-sum-exp
+# (with both kept the recomputed kernel call is dead code) and ``w_down``'s
+# output
+REMAT_KEEPS = SAVED_RESIDUALS + ("mlp_out",)
 
 
 def rms_norm(x, gain, eps):
@@ -127,10 +141,27 @@ class LoopLM(nn.Module):
         flops = (6 * layer * t * applications
                  + 6 * d * self.vocab_size * t * self.loop_steps
                  + 12 * d * (t * (t + 1) // 2) * applications)
+        kept = self._remat_keeps(t)
         return {"loop_steps": self.loop_steps,
                 "layer_applications_per_step": applications,
                 "loop": "scan", "remat": bool(self.remat),
+                "remat_keeps": ",".join(kept),
+                "remat_kept_bytes_per_row": applications * sum(kept.values()),
                 "tokens_per_row": t, "flops_per_row": flops}
+
+    def _remat_keeps(self, t: int) -> dict:
+        """{name: bytes one block application keeps of a row of ``t`` tokens
+        for the backward pass}, of ``REMAT_KEEPS``: nothing without
+        ``remat`` (then everything is kept), and the attention's two only
+        where the flash kernel names them."""
+        if not self.remat:
+            return {}
+        wide = t * self.hidden_size * jnp.dtype(self.dtype).itemsize
+        sizes = {"attn_out": wide, "attn_lse": 4 * self.num_heads * t,
+                 "mlp_out": wide}
+        flash = self.attn_impl in ("flash", "ulysses_flash")
+        return {name: sizes[name] for name in REMAT_KEEPS
+                if flash or name not in SAVED_RESIDUALS}
 
     # -- pieces --------------------------------------------------------------
     def _dot(self, x, w):
@@ -152,15 +183,18 @@ class LoopLM(nn.Module):
             o = o.transpose(0, 2, 1, 3).reshape(b, t, d)
             a = h + rms_norm(self._dot(o, w["wo"]), w["norm2"], eps)
             y = rms_norm(a, w["norm3"], eps)
-            m = self._dot(
+            m = checkpoint_name(self._dot(
                 nn.silu(self._dot(y, w["w_gate"])) * self._dot(y, w["w_up"]),
-                w["w_down"])
+                w["w_down"]), "mlp_out")
             return a + rms_norm(m, w["norm4"], eps)
 
     def _loop_step(self, h, cos, sin):
         """The L layers once, then the final norm: the state that feeds this
         step's exit, its gate and the next step."""
-        block = jax.checkpoint(self._block) if self.remat else self._block
+        block = jax.checkpoint(
+            self._block,
+            policy=jax.checkpoint_policies.save_only_these_names(*REMAT_KEEPS),
+        ) if self.remat else self._block
         for w in self.layers:
             h = block(w, h, cos, sin)
         return rms_norm(h, self.final_norm, self.rms_eps)

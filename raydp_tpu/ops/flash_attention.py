@@ -44,6 +44,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from raydp_tpu.ops.backend import pallas_interpret
 
@@ -616,12 +617,24 @@ def flash_attention(
     return _flash_forward(q, k, v, causal, block_q, block_k, interpret)
 
 
+# the two residuals only the forward kernel can give, by the names a
+# ``jax.checkpoint`` policy saves them under (``save_only_these_names``): a
+# recomputed block that keeps both has no use for a second forward call.
+# Outside such a policy a name is the identity. ``lse`` [B, H, T] and not the
+# kernel's own ``m`` and ``l``: their [B*H, T, 1] results may be padded
+# 128-fold in HBM.
+SAVED_RESIDUALS = ("attn_out", "attn_lse")
+
+
 def _fwd(q, k, v, causal, block_q, block_k, interpret):
     o, m, l = _flash_call(  # noqa: E741
         q, k, v, 0, 0, causal, block_q, block_k, interpret, normalize=True
     )
     # residuals are O(T): inputs + normalized output + per-row logsumexp
-    lse = m + jnp.log(jnp.maximum(l, 1e-30))
+    o = checkpoint_name(o, SAVED_RESIDUALS[0])
+    lse = checkpoint_name(
+        m + jnp.log(jnp.maximum(l, 1e-30)), SAVED_RESIDUALS[1]
+    )
     return o, (q, k, v, o, lse)
 
 

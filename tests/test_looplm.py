@@ -159,20 +159,68 @@ def test_shared_gradient_is_the_sum_over_untied_copies(params, batch):
             jnp.linalg.norm(one))
 
 
-@pytest.mark.parametrize("variant", [
-    {"loss_chunk": 0}, {"remat": False}, {"loss_chunk": 0, "remat": False},
-    {"attn_impl": "flash"},
+FLASH = {"attn_impl": "flash"}
+
+
+@pytest.mark.parametrize("base, variant", [
+    ({}, {"loss_chunk": 0}), ({}, {"remat": False}),
+    ({}, {"loss_chunk": 0, "remat": False}), ({}, FLASH),
+    (FLASH, {**FLASH, "remat": False}),
+    (FLASH, {**FLASH, "loss_chunk": 0, "remat": False}),
 ])
-def test_forms_agree(params, batch, variant):
-    """The chunked exit loss and the whole one, recomputation on and off,
-    the flash kernel (interpreted here) and the plain attention: one
-    arithmetic."""
-    (loss, aux), grads = program(model(), params, batch)
+def test_forms_agree(params, batch, base, variant):
+    """The chunked exit loss and the whole one, recomputation on (with what
+    its policy keeps: ``mlp_out`` in the plain model, the kernel's output and
+    log-sum-exp too in the flash one) and off, the flash kernel (interpreted
+    here) and the plain attention: one arithmetic."""
+    (loss, aux), grads = program(model(**base), params, batch)
     (loss2, aux2), grads2 = program(model(**variant), params, batch)
-    tight = "attn_impl" not in variant
+    tight = base.get("attn_impl") == variant.get("attn_impl")
     assert abs(float(loss) - float(loss2)) <= (1e-6 if tight else 1e-5)
     np.testing.assert_allclose(aux["exit_loss"], aux2["exit_loss"], atol=1e-5)
     leaves_close(grads2, grads, 1e-5 if tight else 1e-4)
+
+
+def _pallas_calls(jaxpr, name):
+    """{path: count} of the pallas calls called ``name`` in a jaxpr, by the
+    ``scan`` equations they lie under: ``("scan1",)`` is the second scan of
+    the top level (a gradient has the forward sweep over the loop steps in
+    one scan and the backward sweep in a later one)."""
+    found = {}
+
+    def walk(jaxpr, path):
+        scans = 0
+        for eqn in jaxpr.eqns:
+            here = path
+            if eqn.primitive.name == "scan":
+                here = path + (f"scan{scans}",)
+                scans += 1
+            if eqn.primitive.name == "pallas_call" and name in eqn.params["name"]:
+                found[path] = found.get(path, 0) + 1
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, here)
+
+    walk(jaxpr, ())
+    return found
+
+
+@pytest.mark.parametrize("remat", [True, False])
+def test_the_backward_pass_runs_no_flash_forward(params, batch, remat):
+    """What the policy is for: the gradient's forward scan (over the loop
+    steps) holds one ``flash_attention_fwd`` call a layer, and its backward
+    scan none: the kernel's output and log-sum-exp are kept and the
+    recomputed call is dead code. Without ``remat`` nothing is recomputed,
+    so the counts are the same; a bare ``jax.checkpoint`` had one a layer in
+    each scan."""
+    m = model(attn_impl="flash", remat=remat)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p: m.apply(p, batch, method="loss")[0]))(params)
+    forward = _pallas_calls(jaxpr.jaxpr, "flash_attention_fwd")
+    backward = _pallas_calls(jaxpr.jaxpr, "flash_attention_bwd_dq")
+    # one scan holds every forward call, another every backward one
+    assert list(forward.values()) == [L], forward
+    assert list(backward.values()) == [L], backward
+    assert set(forward) != set(backward)
 
 
 @pytest.mark.parametrize("what", ["states", "mass", "loss"])
@@ -214,6 +262,19 @@ def test_fit_facts_say_what_a_row_holds():
         + 12 * 2048 * (4096 * 4097 // 2) * 24)
     assert 2 * facts["flops_per_row"] == pytest.approx(9.03e13, rel=2e-3)
     assert not hasattr(big, "loop")  # one form, no knob
+    # what a recomputed block keeps, a row and all 24 block applications:
+    # bf16 [4096, 2048] twice (the flash output, w_down's output) and the
+    # float32 log-sum-exp of 16 heads x 4096 rows; the plain attention names
+    # nothing, and without remat nothing is recomputed
+    wide, lse = 4096 * 2048 * 2, 16 * 4096 * 4
+    assert (facts["remat_keeps"], facts["remat_kept_bytes_per_row"]) == (
+        "mlp_out", 24 * wide) == ("mlp_out", 402_653_184)
+    flash = big.clone(attn_impl="flash").fit_facts(np.zeros((2, 4097), np.int32))
+    assert flash["remat_keeps"] == "attn_out,attn_lse,mlp_out"
+    assert flash["remat_kept_bytes_per_row"] == 24 * (2 * wide + lse) == 811_597_824
+    off = big.clone(remat=False).fit_facts(np.zeros((2, 4097), np.int32))
+    assert (off["remat"], off["remat_keeps"], off["remat_kept_bytes_per_row"]) == (
+        False, "", 0)
 
 
 # -- the sequence column ------------------------------------------------------
@@ -310,6 +371,10 @@ def test_estimator_fit_on_an_etl_frame_with_the_sequence_column():
     assert step_programs, [r["args"] for r in compiles]  # one scan of 4 steps
     assert step_programs[0]["args"]["loop"] == "scan"
     assert step_programs[0]["args"]["remat"] is True
+    # what the recomputed blocks keep (the plain attention names nothing):
+    # float32 [T, D] of w_down's output, a row and R x L block applications
+    assert step_programs[0]["args"]["remat_keeps"] == "mlp_out"
+    assert snap["model.remat_kept_bytes_per_row"]["value"] == R * L * T * D * 4
     # tokens and FLOPs are the model's own word (fit_facts), no probe compiled
     assert snap["model.tokens_per_row"]["value"] == T
     assert not [r for r in compiles if r["args"].get("what") == "flops_probe"]

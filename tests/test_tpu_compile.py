@@ -4,6 +4,7 @@ What interpret mode cannot show: VMEM budgets and tile alignment. Keep every
 such test in THIS file: one process holds the TPU library at a time, and the
 topology is described inside a fixture, never at import."""
 
+import functools
 import importlib
 import re
 
@@ -36,6 +37,21 @@ def no_compile_cache():
     yield
     jax.config.update("jax_enable_compilation_cache", before)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture()
+def kernels_compile(monkeypatch):
+    # what the TPU backend would say of itself (ops/backend.py): a kernel
+    # whose ``interpret`` is left to the rule compiles and is not interpreted
+    from raydp_tpu.ops import backend
+
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+
+
+def _on_chip(tree, one_chip):
+    """The shapes of ``tree`` as arguments that live on the described chip."""
+    return jax.tree.map(lambda leaf: jax.ShapeDtypeStruct(
+        leaf.shape, leaf.dtype, sharding=one_chip), tree)
 
 
 @pytest.mark.parametrize("dtype, precision, tile", [
@@ -83,7 +99,7 @@ def _table_results(text, ops, layout=""):
 
 
 def test_dlrm_step_writes_rows_back_with_the_kernel(
-        one_chip, no_compile_cache, monkeypatch):
+        one_chip, no_compile_cache, kernels_compile):
     """The benchmark's DLRM step (batch 2048, the 26 Criteo-Kaggle tables,
     Adagrad) with the write-back kernel, against the same step through XLA's
     scatter (the parent commit's step, text for text): eight kernel calls
@@ -94,20 +110,13 @@ def test_dlrm_step_writes_rows_back_with_the_kernel(
     from raydp_tpu.estimator import row_update
     from raydp_tpu.estimator.jax_estimator import _LOSSES, make_train_step
     from raydp_tpu.models import DLRM
-    from raydp_tpu.ops import backend
 
-    # what the TPU backend would say of itself (ops/backend.py): the plan
-    # then takes the kernel, and the kernel compiles and is not interpreted
-    monkeypatch.setattr(backend, "on_tpu", lambda: True)
-    batch = 2048
+    batch = 2048  # ``kernels_compile``: the plan then takes the kernel
     module = DLRM(vocab_sizes=CRITEO_KAGGLE, num_dense=13, embed_dim=16,
                   bottom_mlp=(512, 256, 64), top_mlp=(512, 256),
                   use_pallas_interaction=False)
 
-    def on_chip(tree):
-        return jax.tree.map(lambda leaf: jax.ShapeDtypeStruct(
-            leaf.shape, leaf.dtype, sharding=one_chip), tree)
-
+    on_chip = functools.partial(_on_chip, one_chip=one_chip)
     x = on_chip((jax.ShapeDtypeStruct((batch, 13), jnp.float32),
                  jax.ShapeDtypeStruct((batch, 26), jnp.int32)))
     y = on_chip(jax.ShapeDtypeStruct((batch,), jnp.float32))
@@ -150,3 +159,72 @@ def test_dlrm_step_writes_rows_back_with_the_kernel(
         _table_results(parent, copies))
     assert (kernel.memory_analysis().temp_size_in_bytes
             <= scatter.memory_analysis().temp_size_in_bytes)
+
+
+# -- the looped LM's gradient at the published widths --------------------------
+
+OURO = dict(vocab_size=49152, attn_impl="flash")  # LoopLM's defaults are the rest
+FLASH_FWD = r"%[\w.\-]*flash_attention_fwd[\w.\-]* = "
+
+
+def _looplm_gradient(one_chip, rows, tokens, matched=False, **kw):
+    """The gradient of ``LoopLM.loss`` compiled for the described chip: the
+    timed step's bf16 form, or the float32 / highest / ``with_states`` form
+    of the benchmark's ``matched`` check."""
+    from raydp_tpu.models import LoopLM
+
+    module = LoopLM(**OURO, dtype=jnp.float32 if matched else jnp.bfloat16, **kw)
+    x = jax.ShapeDtypeStruct((rows, tokens + 1), jnp.int32, sharding=one_chip)
+    params = _on_chip(jax.eval_shape(
+        lambda r, s: module.init(r, s, None, method="loss"),
+        jax.random.PRNGKey(0), x), one_chip)
+
+    def grads(p, x):
+        with jax.default_matmul_precision("highest" if matched else None):
+            return jax.value_and_grad(
+                lambda p: module.apply(p, x, None, matched, method="loss"),
+                has_aux=True)(p)
+
+    return jax.jit(grads).lower(params, x).compile()
+
+
+@pytest.mark.parametrize("matched, temp_limit", [(False, 7.0e9), (True, 10.0e9)],
+                         ids=["bf16", "float32_matched"])
+def test_looplm_gradient_keeps_what_the_flash_forward_gave(
+        one_chip, no_compile_cache, kernels_compile, matched, temp_limit):
+    """Batch 2 x 4096 at the published widths, blocks recomputed: the
+    compiled gradient holds as many flash forward calls as one that
+    recomputes nothing (``remat=False``, which fits the chip only at a
+    smaller batch: 1 x 1024), one a layer: the kept ``attn_out`` and
+    ``attn_lse`` make the recomputed call dead code (a bare
+    ``jax.checkpoint`` compiled 12). And what is kept fits: temporaries
+    6.72 GB (bf16) and 9.65 GB (float32) here with all three names, from
+    4.28 and 5.06 with none (PR 30)."""
+    kept = _looplm_gradient(one_chip, 2, 4096, matched)
+    plain = _looplm_gradient(one_chip, 1, 1024, matched, remat=False)
+    calls = len(re.findall(FLASH_FWD, kept.as_text()))
+    assert calls == len(re.findall(FLASH_FWD, plain.as_text())) == 6
+    assert kept.memory_analysis().temp_size_in_bytes <= temp_limit
+
+
+@pytest.mark.parametrize("remat, calls_a_layer", [(False, 3), (True, 4)],
+                         ids=["no_remat", "remat"])
+def test_transformer_flash_gradient_is_the_program_it_was(
+        one_chip, no_compile_cache, kernels_compile, remat, calls_a_layer):
+    """``flash_attention`` names its residuals for a policy that saves by
+    name; ``TransformerLM`` has none (``nn.remat`` bare, or no remat), so a
+    name is the identity and its gradient compiles to the Mosaic calls it
+    had: forward, dq, dk/dv a layer, and the forward again under remat."""
+    from raydp_tpu.models.transformer import TransformerLM
+
+    layers = 2
+    module = TransformerLM(vocab_size=8192, d_model=2048, num_heads=16,
+                           num_layers=layers, attn_impl="flash", remat=remat)
+    x = jax.ShapeDtypeStruct((1, 4096), jnp.int32, sharding=one_chip)
+    params = _on_chip(jax.eval_shape(module.init, jax.random.PRNGKey(0), x),
+                      one_chip)
+    text = jax.jit(jax.grad(
+        lambda p, x: module.apply(p, x).astype(jnp.float32).sum()
+    )).lower(params, x).compile().as_text()
+    assert text.count("tpu_custom_call") == calls_a_layer * layers
+    assert len(re.findall(FLASH_FWD, text)) == (2 if remat else 1) * layers
