@@ -85,6 +85,33 @@ def exit_mass(lam):
     return jnp.concatenate([lam[:-1] * before, survive[-1:]], axis=0)
 
 
+def chunked_cross_entropy(head, h, targets, chunk: int, remat: bool,
+                          scope: str):
+    """Per-token cross-entropy [B, T] of the float32 logits ``head(h)``
+    against ``targets`` [B, T], ``chunk`` tokens of logits at a time (0, a
+    chunk of the whole or one that does not divide it: all at once); under
+    ``remat`` a chunk's logits are recomputed in the backward pass, so that
+    no more than one chunk of them is ever held."""
+    b, t, d = h.shape
+
+    def chunk_ce(h_c, y_c):
+        z = head(h_c)
+        picked = jnp.take_along_axis(z, y_c[:, None], axis=-1)[:, 0]
+        return jax.nn.logsumexp(z, axis=-1) - picked
+
+    if remat:
+        chunk_ce = jax.checkpoint(chunk_ce)
+    with jax.named_scope(scope):
+        n = b * t
+        flat_h, flat_y = h.reshape(n, d), targets.reshape(n)
+        if not chunk or chunk >= n or n % chunk:
+            return chunk_ce(flat_h, flat_y).reshape(b, t)
+        ce = lax.map(lambda hy: chunk_ce(*hy),
+                     (flat_h.reshape(n // chunk, chunk, d),
+                      flat_y.reshape(n // chunk, chunk)))
+        return ce.reshape(b, t)
+
+
 class LoopLM(nn.Module):
     vocab_size: int
     hidden_size: int = 2048
@@ -211,25 +238,8 @@ class LoopLM(nn.Module):
     def _exit_ce(self, h, targets):
         """Per-token cross-entropy [B, T] of one exit, ``loss_chunk`` tokens
         of logits at a time."""
-        b, t, d = h.shape
-
-        def chunk_ce(h_c, y_c):
-            z = self.head(h_c)
-            picked = jnp.take_along_axis(z, y_c[:, None], axis=-1)[:, 0]
-            return jax.nn.logsumexp(z, axis=-1) - picked
-
-        if self.remat:
-            chunk_ce = jax.checkpoint(chunk_ce)
-        with jax.named_scope("looplm.exit_loss"):
-            n = b * t
-            chunk = self.loss_chunk
-            flat_h, flat_y = h.reshape(n, d), targets.reshape(n)
-            if not chunk or chunk >= n or n % chunk:
-                return chunk_ce(flat_h, flat_y).reshape(b, t)
-            ce = lax.map(lambda hy: chunk_ce(*hy),
-                         (flat_h.reshape(n // chunk, chunk, d),
-                          flat_y.reshape(n // chunk, chunk)))
-            return ce.reshape(b, t)
+        return chunked_cross_entropy(self.head, h, targets, self.loss_chunk,
+                                     self.remat, "looplm.exit_loss")
 
     def _embed(self, tokens):
         cos, sin = rope_tables(tokens.shape[1],

@@ -584,8 +584,10 @@ def pick_blocks(t_q: int, t_k: int, head_dim: int | None = None,
     precision for a check) double a tile's bytes — 1024-row float32 tiles
     at D=128 ask the backward kernel for 19.5 MB of its 16 MB of VMEM."""
 
+    # a head narrower than the 128 lanes is padded to them in VMEM: float32
+    # heads of 64 at 1024 rows asked the forward kernel for 16.44 MB (PR 31)
     cap = 1024
-    while cap > 128 and cap * (head_dim or 128) * itemsize > 1024 * 128 * 2:
+    while cap > 128 and cap * max(head_dim or 128, 128) * itemsize > 1024 * 128 * 2:
         cap //= 2
 
     def _block(t, cap):

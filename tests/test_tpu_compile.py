@@ -228,3 +228,78 @@ def test_transformer_flash_gradient_is_the_program_it_was(
     )).lower(params, x).compile().as_text()
     assert text.count("tpu_custom_call") == calls_a_layer * layers
     assert len(re.findall(FLASH_FWD, text)) == (2 if remat else 1) * layers
+
+
+# -- the hybrid state-space LM's epoch program at the published widths ---------
+
+
+@pytest.mark.parametrize("matched", [False, True], ids=["bf16", "float32_matched"])
+def test_flash_kernels_compile_at_head_dim_64(one_chip, no_compile_cache, matched):
+    """[1 x 32 heads, T 8192, head 64], causal: the grouped-query layer's
+    kernels after K and V are repeated to the query heads (the flash
+    kernels had only ever run heads of 128), in the timed step's bf16
+    (1024-row tiles) and in the float32 of the benchmark's matched check
+    (512: a head of 64 is padded to the 128 lanes in VMEM, and 1024-row
+    float32 tiles ask the forward kernel for 16.44 MB of its 16)."""
+    fa = importlib.import_module("raydp_tpu.ops.flash_attention")
+    dtype = jnp.float32 if matched else jnp.bfloat16
+    tile = 512 if matched else 1024
+    assert fa.pick_blocks(8192, 8192, head_dim=64,
+                          itemsize=jnp.dtype(dtype).itemsize) == (tile, tile)
+    q = jax.ShapeDtypeStruct((1, 32, 8192, 64), dtype, sharding=one_chip)
+
+    def grads(q, k, v):
+        with jax.default_matmul_precision("highest" if matched else None):
+            return jax.grad(
+                lambda q, k, v: fa.flash_attention(
+                    q, k, v, True, None, None, False
+                ).astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    text = jax.jit(grads).lower(q, q, q).compile().as_text()
+    for name in ("flash_attention_fwd", "flash_attention_bwd_dq",
+                 "flash_attention_bwd_dkv"):
+        assert re.search(rf"%[\w.\-]*{name}[\w.\-]* = ", text), name
+
+
+def test_hybridlm_epoch_program_fits_the_chip(
+        one_chip, no_compile_cache, kernels_compile):
+    """Step 0 of ISSUE 31: the benchmark's epoch program of
+    ``granite-4.0-h-micro.pretrain-8k`` (849.2 M float32 parameters, AdamW,
+    3 steps of 1 x 8192 tokens gathered from the resident rows and scanned,
+    parameters and optimizer state donated: what the resident scan runner
+    compiles) for the described v5e: arguments + outputs - aliased +
+    temporaries within 15.5e9 bytes (13.66e9 here: arguments 10.19e9, all
+    aliased, temporaries 3.47e9), one flash forward, one dq and one dk/dv
+    call at head_dim 64 (the attention layer's ``attn_out`` and ``attn_lse``
+    are kept, so the backward pass recomputes none)."""
+    from raydp_tpu.estimator.jax_estimator import (
+        MODEL_LOSS, _scan_over_batches, make_train_step)
+    from raydp_tpu.models import HybridLM, hybridlm_optimizer
+
+    steps, tokens = 3, 8192
+    module = HybridLM(vocab_size=50176, attn_impl="flash")  # published widths
+    on_chip = functools.partial(_on_chip, one_chip=one_chip)
+    rows = on_chip(jax.ShapeDtypeStruct((steps, tokens + 1), jnp.int32))
+    perm = on_chip(jax.ShapeDtypeStruct((steps,), jnp.int32))
+    params = on_chip(jax.eval_shape(
+        lambda r, s: module.init(r, s, None, method="loss"),
+        jax.random.PRNGKey(0), jax.ShapeDtypeStruct((1, tokens + 1), jnp.int32)))
+    assert sum(leaf.size for leaf in jax.tree.leaves(params)) == 849_230_784
+    tx = hybridlm_optimizer()
+    state = on_chip(jax.eval_shape(tx.init, params))
+    step = make_train_step(module, MODEL_LOSS, tx)
+
+    def epoch(params, state, rows, perm):
+        return _scan_over_batches(
+            step, params, state, rows[perm].reshape(steps, 1, tokens + 1), None)
+
+    compiled = jax.jit(epoch, donate_argnums=(0, 1)).lower(
+        params, state, rows, perm).compile()
+    memory = compiled.memory_analysis()
+    held = (memory.argument_size_in_bytes + memory.output_size_in_bytes
+            - memory.alias_size_in_bytes + memory.temp_size_in_bytes)
+    assert held <= 15.5e9, held
+    text = compiled.as_text()
+    for name in ("flash_attention_fwd", "flash_attention_bwd_dq",
+                 "flash_attention_bwd_dkv"):
+        assert len(re.findall(rf"%[\w.\-]*{name}[\w.\-]* = ", text)) == 1, name
