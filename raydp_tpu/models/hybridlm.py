@@ -36,7 +36,11 @@ logits and the loss are float32.
 The estimator trains it with ``loss="model"`` exactly as it trains
 ``LoopLM``: ``loss(x)`` takes the int32 ``[B, T+1]`` sequence column whole.
 Blocks are recomputed from their inputs (``remat``) but for
-``REMAT_KEEPS``; the loss holds ``loss_chunk`` tokens of logits at a time.
+``REMAT_KEEPS``. The loss is ``looplm.chunked_cross_entropy`` on the tied
+embedding: each chunk of ``loss_chunk`` tokens computes its logits once and
+takes their gradient in the forward sweep; it holds one chunk of float32
+logits, the gradient back to the final norm's output and one float32
+accumulator of the embedding's own [V, D] shape, and recomputes nothing.
 """
 
 from __future__ import annotations
@@ -50,7 +54,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from raydp_tpu.models.looplm import (
-    chunked_cross_entropy, looplm_optimizer, rms_norm)
+    LOSS_FACTS, chunked_cross_entropy, looplm_optimizer, rms_norm)
 from raydp_tpu.models.transformer import _attend
 from raydp_tpu.ops.flash_attention import SAVED_RESIDUALS
 from raydp_tpu.ops.ssd import ssd_chunk_scan
@@ -88,7 +92,7 @@ class HybridLM(nn.Module):
     rms_eps: float = 1e-5
     attn_impl: str = "full"  # "flash" on the chip; as LoopLM's
     dtype: Any = jnp.bfloat16  # compute dtype; parameters are float32
-    remat: bool = True  # recompute each block (and the logits) backward
+    remat: bool = True  # recompute each block in the backward pass
     loss_chunk: int = 2048  # tokens of logits held at a time; 0: all
 
     @classmethod
@@ -221,6 +225,7 @@ class HybridLM(nn.Module):
             "ssd_flops_per_row": parts["scan"],
             "remat": bool(self.remat), "remat_keeps": ",".join(kept),
             "remat_kept_bytes_per_row": sum(kept.values()),
+            **LOSS_FACTS,
             "tokens_per_row": t, "flops_per_row": sum(parts.values())}
 
     def flops_per_row_parts(self, t: int) -> dict:
@@ -362,9 +367,10 @@ class HybridLM(nn.Module):
         state the head read, into ``aux`` (for a comparison of the logits;
         not for a fit, whose evaluation would average it)."""
         h = self.hidden_states(x[:, :-1])
-        ce = chunked_cross_entropy(self.head, h, x[:, 1:], self.loss_chunk,
-                                   self.remat, "hybridlm.loss")
-        return jnp.mean(ce), {"hidden": h} if with_states else {}
+        loss, _ = chunked_cross_entropy(
+            h, self.embed, 1, x[:, 1:], self.loss_chunk, "hybridlm.loss",
+            scale=1.0 / self.logits_scaling)
+        return loss, {"hidden": h} if with_states else {}
 
 
 def hybridlm_optimizer(learning_rate: float = 3e-4, b1: float = 0.9,

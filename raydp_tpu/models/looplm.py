@@ -23,20 +23,27 @@ chip), shared with ``models/transformer.py``.
 
 The estimator trains it with ``loss="model"``: ``loss(x)`` takes the int32
 ``[B, T+1]`` sequence column whole, reads ``x[:, :-1]`` and predicts
-``x[:, 1:]``. The exit loss never holds more than one token chunk of one
-exit's logits (``loss_chunk`` tokens; recomputed in the backward pass), and
-blocks are recomputed from their inputs (``remat``) but for ``REMAT_KEEPS``:
+``x[:, 1:]``. The four exits' loss is ONE call of ``chunked_cross_entropy``
+after the loop, over the R x B x T closing states: each chunk of
+``loss_chunk`` tokens computes its logits once, and under differentiation
+takes their gradient there and then (three products a chunk: logits, the
+gradient back to the state, the head's gradient; none in the backward
+pass). What the loss holds: one chunk of float32 logits, the gradient back
+to the states (their shape and dtype) and one float32 accumulator of the
+head's shape; never a second chunk of logits, nothing recomputed. Blocks
+are recomputed from their inputs (``remat``) but for ``REMAT_KEEPS``:
 the flash kernel's output and log-sum-exp and ``w_down``'s output are kept
 from the forward pass (at the published widths, bf16, T 4096: 16.8 MB + 0.26
 MB + 16.8 MB a row and block application, 811.6 MB a row over the 24), so
 the backward pass runs no flash forward and no down-projection a second
 time; q, k, v, the norms, RoPE, ``wo``, gate and up are rebuilt.
 ``fit_facts`` says what is kept (``remat_keeps``,
-``remat_kept_bytes_per_row``).
+``remat_kept_bytes_per_row``) and what the loss does (``LOSS_FACTS``).
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import flax.linen as nn
@@ -55,6 +62,10 @@ LAYER_NORMS = ("norm1", "norm2", "norm3", "norm4")
 # (with both kept the recomputed kernel call is dead code) and ``w_down``'s
 # output
 REMAT_KEEPS = SAVED_RESIDUALS + ("mlp_out",)
+# what ``chunked_cross_entropy`` does, for ``fit_facts``: the loss's gradient
+# is taken in the forward sweep, with three products over the vocabulary a
+# chunk (logits, the gradient back to the state, the head's gradient)
+LOSS_FACTS = {"loss_grad": "forward", "loss_products_per_chunk": 3}
 
 
 def rms_norm(x, gain, eps):
@@ -80,36 +91,114 @@ def apply_rope(x, cos, sin):
 def exit_mass(lam):
     """Exit distribution p [R, ...] from the gates lam [R, ...] (the last
     gate is not used: the last exit takes what is left)."""
-    survive = jnp.cumprod(1.0 - lam[:-1], axis=0)
-    before = jnp.concatenate([jnp.ones_like(lam[:1]), survive[:-1]], axis=0)
-    return jnp.concatenate([lam[:-1] * before, survive[-1:]], axis=0)
+    survive, mass = jnp.ones_like(lam[0]), []
+    for gate in lam[:-1]:
+        mass.append(gate * survive)
+        survive = survive * (1.0 - gate)
+    return jnp.stack(mass + [survive])
 
 
-def chunked_cross_entropy(head, h, targets, chunk: int, remat: bool,
-                          scope: str):
-    """Per-token cross-entropy [B, T] of the float32 logits ``head(h)``
-    against ``targets`` [B, T], ``chunk`` tokens of logits at a time (0, a
-    chunk of the whole or one that does not divide it: all at once); under
-    ``remat`` a chunk's logits are recomputed in the backward pass, so that
-    no more than one chunk of them is ever held."""
-    b, t, d = h.shape
+def _chunk_ce(h_c, y_c, w, contract, scale):
+    """One chunk: its cross-entropy [c], its float32 logits ``scale * h_c .
+    w`` [c, V] with their log-sum-exp [c], and where the targets lie in them
+    (bool [c, V]); ``contract`` is the axis of ``w`` that meets ``h_c``'s
+    features."""
+    # the chunk as a buffer of its own: with its slice fused into the
+    # products, they read a stack of states too large for the chip's fast
+    # memory (the four exits', 134 MB) from HBM tile by tile, and the head's
+    # gradient product took 58.8 ms a step where 40.5 is its time (PR 32)
+    h_c = lax.optimization_barrier(h_c)
+    z = scale * lax.dot_general(h_c, w, (((1,), (contract,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+    lse = jax.nn.logsumexp(z, axis=-1)
+    hot = y_c[:, None] == lax.broadcasted_iota(jnp.int32, z.shape, 1)
+    return lse - jnp.sum(jnp.where(hot, z, 0.0), axis=-1), z, lse, hot
 
-    def chunk_ce(h_c, y_c):
-        z = head(h_c)
-        picked = jnp.take_along_axis(z, y_c[:, None], axis=-1)[:, 0]
-        return jax.nn.logsumexp(z, axis=-1) - picked
 
-    if remat:
-        chunk_ce = jax.checkpoint(chunk_ce)
+def _chunks(chunk, *flat):
+    """``flat`` arrays [n, ...] as [n / chunk, chunk, ...]; one chunk of the
+    whole where ``chunk`` is 0, not smaller or does not divide ``n``."""
+    n = flat[0].shape[0]
+    size = chunk if chunk and chunk < n and not n % chunk else n
+    return tuple(a.reshape(n // size, size, *a.shape[1:]) for a in flat)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _head_cross_entropy(h, w, targets, weight, contract, scale, chunk):
+    """(sum of ``weight`` x cross-entropy, per-token cross-entropy [n]) of
+    the float32 logits ``scale * h . w`` of ``h`` [n, D] against ``targets``
+    [n], a chunk of logits at a time. Under differentiation the forward
+    sweep takes the gradient too (``_head_cross_entropy_fwd``)."""
+    cast = w.astype(h.dtype)
+    ce = lax.map(lambda hy: _chunk_ce(*hy, cast, contract, scale)[0],
+                 _chunks(chunk, h, targets)).reshape(-1)
+    return jnp.sum(weight * ce), ce
+
+
+def _head_cross_entropy_fwd(h, w, targets, weight, contract, scale, chunk):
+    """Each chunk once: its logits, its cross-entropy, the gradient of
+    ``weight . ce`` with respect to the logits (float32, the operand dtype
+    autodiff's two backward products take it in), and from it the gradient
+    back to ``h`` (kept, ``h``'s dtype) and to ``w`` (summed over chunks in
+    ONE float32 accumulator of ``w``'s own layout). No chunk's logits
+    outlive the chunk."""
+    cast = w.astype(h.dtype)
+    vocab = 1 - contract
+
+    def body(dw, chunk_of):
+        h_c, y_c, weight_c = chunk_of
+        ce, z, lse, hot = _chunk_ce(h_c, y_c, cast, contract, scale)
+        softmax = jnp.exp(z - lse[:, None])
+        dz = jnp.where(hot, softmax - 1.0, softmax) * (
+            weight_c * scale)[:, None]
+        dh = lax.dot_general(dz, cast, (((1,), (vocab,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+        # in the weight's own layout: [D, V] = h^T dz, [V, D] = dz^T h
+        pair = (h_c, dz) if vocab else (dz, h_c)
+        dw = dw + lax.dot_general(*pair, (((0,), (0,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+        return dw, (ce, dh.astype(h.dtype))
+
+    dw, (ce, dh) = lax.scan(body, jnp.zeros(w.shape, jnp.float32),
+                            _chunks(chunk, h, targets, weight))
+    ce = ce.reshape(-1)
+    return (jnp.sum(weight * ce), ce), (dh.reshape(h.shape), dw, ce)
+
+
+def _head_cross_entropy_bwd(contract, scale, chunk, kept, cotangents):
+    (dh, dw, ce), (g, _) = kept, cotangents  # ``ce`` is for reporting only
+    return (g * dh).astype(dh.dtype), g * dw, None, g * ce
+
+
+_head_cross_entropy.defvjp(_head_cross_entropy_fwd, _head_cross_entropy_bwd)
+
+
+def chunked_cross_entropy(h, w, contract: int, targets, chunk: int,
+                          scope: str, scale: float = 1.0, weight=None):
+    """The head's product and the cross-entropy in one: ``(sum over tokens
+    of weight x ce, ce)`` of the float32 logits ``scale * h . w`` against
+    ``targets``, ``chunk`` tokens of logits at a time (0, a chunk of the
+    whole or one that does not divide it: all at once). ``h`` is [..., D],
+    ``targets`` and ``weight`` its leading shape (no ``weight``: the mean),
+    ``w`` the float32 head, cast to ``h``'s dtype here, whose axis
+    ``contract`` meets D (0 for [D, V], 1 for a tied embedding's [V, D]).
+    ``ce`` is for reporting and carries no gradient.
+
+    Its backward pass is its own: under differentiation every chunk's
+    logits are computed ONCE, in the forward sweep, and their gradient is
+    taken there: three products a chunk (logits, the gradient back to
+    ``h``, the weight's gradient), none in the backward sweep, which scales
+    what was kept by the cotangent. Kept: the gradient back to ``h`` (its
+    shape and dtype) and one float32 accumulator of ``w``'s shape; never a
+    chunk of logits. Without differentiation: the chunked forward alone."""
+    n = targets.size
+    if weight is None:
+        weight = jnp.full(targets.shape, 1.0 / n, jnp.float32)
     with jax.named_scope(scope):
-        n = b * t
-        flat_h, flat_y = h.reshape(n, d), targets.reshape(n)
-        if not chunk or chunk >= n or n % chunk:
-            return chunk_ce(flat_h, flat_y).reshape(b, t)
-        ce = lax.map(lambda hy: chunk_ce(*hy),
-                     (flat_h.reshape(n // chunk, chunk, d),
-                      flat_y.reshape(n // chunk, chunk)))
-        return ce.reshape(b, t)
+        total, ce = _head_cross_entropy(
+            h.reshape(n, h.shape[-1]), w, targets.reshape(n),
+            weight.reshape(n), contract, scale, chunk)
+    return total, lax.stop_gradient(ce).reshape(targets.shape)
 
 
 class LoopLM(nn.Module):
@@ -124,8 +213,8 @@ class LoopLM(nn.Module):
     entropy_beta: float = 0.1
     attn_impl: str = "full"  # "flash" on the chip; as TransformerLM's
     dtype: Any = jnp.bfloat16  # compute dtype; parameters are float32
-    remat: bool = True  # recompute each block (and each exit's logits) backward
-    loss_chunk: int = 2048  # tokens of one exit's logits held at a time; 0: all
+    remat: bool = True  # recompute each block in the backward pass
+    loss_chunk: int = 2048  # tokens of the exits' logits held at a time; 0: all
 
     def setup(self):
         d, f, v = self.hidden_size, self.intermediate_size, self.vocab_size
@@ -174,7 +263,7 @@ class LoopLM(nn.Module):
                 "loop": "scan", "remat": bool(self.remat),
                 "remat_keeps": ",".join(kept),
                 "remat_kept_bytes_per_row": applications * sum(kept.values()),
-                "tokens_per_row": t, "flops_per_row": flops}
+                **LOSS_FACTS, "tokens_per_row": t, "flops_per_row": flops}
 
     def _remat_keeps(self, t: int) -> dict:
         """{name: bytes one block application keeps of a row of ``t`` tokens
@@ -235,12 +324,6 @@ class LoopLM(nn.Module):
         return jnp.dot(h, self.head_w.astype(self.dtype),
                        preferred_element_type=jnp.float32)
 
-    def _exit_ce(self, h, targets):
-        """Per-token cross-entropy [B, T] of one exit, ``loss_chunk`` tokens
-        of logits at a time."""
-        return chunked_cross_entropy(self.head, h, targets, self.loss_chunk,
-                                     self.remat, "looplm.exit_loss")
-
     def _embed(self, tokens):
         cos, sin = rope_tables(tokens.shape[1],
                                self.hidden_size // self.num_heads,
@@ -280,27 +363,27 @@ class LoopLM(nn.Module):
         exit; not for a fit, whose evaluation would average them)."""
         tokens, targets = x[:, :-1], x[:, 1:]
         h, cos, sin = self._embed(tokens)
-        steps = self.loop_steps
 
-        def step(carry, t):
-            h, survive = carry
+        def step(h, _):
             h = self._loop_step(h, cos, sin)
-            ce = self._exit_ce(h, targets)
-            lam = self._gate(h)
-            p = jnp.where(t < steps - 1, lam * survive, survive)
-            return (h, survive * (1.0 - lam)), (
-                jnp.mean(p * ce), -jnp.mean(p * jnp.log(jnp.maximum(p, 1e-30))),
-                jnp.mean(ce), jnp.mean(p), (h, p) if with_states else ())
+            return h, (h, self._gate(h))
 
-        carry = (h, jnp.ones(tokens.shape, jnp.float32))
         with jax.named_scope("looplm.loop"):
-            _, outs = lax.scan(step, carry, jnp.arange(steps))
-        weighted, ent, exit_loss, mass, states = outs
-        loss = jnp.sum(weighted) - self.entropy_beta * jnp.sum(ent)
-        aux = {"exit_loss": exit_loss, "exit_mass": mass}
+            _, (hidden, lam) = lax.scan(step, h, None,
+                                         length=self.loop_steps)
+        # every exit's loss in ONE call, after the loop: its weight's
+        # gradient is then one accumulator, not one a loop step
+        p = exit_mass(lam)
+        weighted, ce = chunked_cross_entropy(
+            hidden, self.head_w, 0, jnp.broadcast_to(targets, p.shape),
+            self.loss_chunk, "looplm.exit_loss", weight=p / targets.size)
+        entropy = -jnp.sum(jnp.mean(
+            p * jnp.log(jnp.maximum(p, 1e-30)), axis=(1, 2)))
+        aux = {"exit_loss": jnp.mean(ce, axis=(1, 2)),
+               "exit_mass": jnp.mean(p, axis=(1, 2))}
         if with_states:
-            aux.update(hidden=states[0], mass=states[1])
-        return loss, aux
+            aux.update(hidden=hidden, mass=p)
+        return weighted - self.entropy_beta * entropy, aux
 
 
 def looplm_optimizer(learning_rate: float = 3e-4, b1: float = 0.9,

@@ -225,6 +225,8 @@ def test_fit_facts_say_what_a_row_holds():
     facts = big.fit_facts(np.zeros((1, 8193), np.int32))
     t = 8192
     assert facts["tokens_per_row"] == t and facts["ssd_chunk"] == 256
+    # what the loss does (ISSUE 32): its gradient in the forward sweep
+    assert (facts["loss_grad"], facts["loss_products_per_chunk"]) == ("forward", 3)
     assert facts["layer_kinds.mamba"] == 9 and facts["layer_kinds.attention"] == 1
     assert facts["layer_kinds"].split(",").index("attention") == 5
     mamba = 2048 * 8512 + 4096 * 2048 + 3 * 2048 * 8192

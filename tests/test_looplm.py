@@ -262,6 +262,9 @@ def test_fit_facts_say_what_a_row_holds():
         + 12 * 2048 * (4096 * 4097 // 2) * 24)
     assert 2 * facts["flops_per_row"] == pytest.approx(9.03e13, rel=2e-3)
     assert not hasattr(big, "loop")  # one form, no knob
+    # the loss's gradient is taken in the forward sweep: three products over
+    # the vocabulary a chunk, not the four of a recomputed chunk (ISSUE 32)
+    assert (facts["loss_grad"], facts["loss_products_per_chunk"]) == ("forward", 3)
     # what a recomputed block keeps, a row and all 24 block applications:
     # bf16 [4096, 2048] twice (the flash output, w_down's output) and the
     # float32 log-sum-exp of 16 heads x 4096 rows; the plain attention names
@@ -371,6 +374,8 @@ def test_estimator_fit_on_an_etl_frame_with_the_sequence_column():
     assert step_programs, [r["args"] for r in compiles]  # one scan of 4 steps
     assert step_programs[0]["args"]["loop"] == "scan"
     assert step_programs[0]["args"]["remat"] is True
+    assert step_programs[0]["args"]["loss_grad"] == "forward"
+    assert snap["model.loss_products_per_chunk"]["value"] == 3
     # what the recomputed blocks keep (the plain attention names nothing):
     # float32 [T, D] of w_down's output, a row and R x L block applications
     assert step_programs[0]["args"]["remat_keeps"] == "mlp_out"

@@ -167,6 +167,14 @@ OURO = dict(vocab_size=49152, attn_impl="flash")  # LoopLM's defaults are the re
 FLASH_FWD = r"%[\w.\-]*flash_attention_fwd[\w.\-]* = "
 
 
+def _loss_products(text, scope):
+    """The matrix products (the TPU compiler's ``convolution``s) under the
+    loss's named scope: logits, the gradient back to the state, the head's
+    gradient; a fourth would be a chunk's logits rebuilt (PR 32)."""
+    return len([line for line in text.splitlines()
+                if " convolution(" in line and scope in line])
+
+
 def _looplm_gradient(one_chip, rows, tokens, matched=False, **kw):
     """The gradient of ``LoopLM.loss`` compiled for the described chip: the
     timed step's bf16 form, or the float32 / highest / ``with_states`` form
@@ -198,13 +206,16 @@ def test_looplm_gradient_keeps_what_the_flash_forward_gave(
     smaller batch: 1 x 1024), one a layer: the kept ``attn_out`` and
     ``attn_lse`` make the recomputed call dead code (a bare
     ``jax.checkpoint`` compiled 12). And what is kept fits: temporaries
-    6.72 GB (bf16) and 9.65 GB (float32) here with all three names, from
-    4.28 and 5.06 with none (PR 30)."""
+    5.62 GB (bf16) and 9.47 GB (float32) here with all three names and the
+    exits' loss taken once after the loop, its gradient in the forward
+    sweep (PR 32; 6.72 and 9.65 with the loss inside the loop and its
+    logits recomputed, PR 30, from 4.28 and 5.06 with no name kept)."""
     kept = _looplm_gradient(one_chip, 2, 4096, matched)
     plain = _looplm_gradient(one_chip, 1, 1024, matched, remat=False)
     calls = len(re.findall(FLASH_FWD, kept.as_text()))
     assert calls == len(re.findall(FLASH_FWD, plain.as_text())) == 6
     assert kept.memory_analysis().temp_size_in_bytes <= temp_limit
+    assert _loss_products(kept.as_text(), "looplm.exit_loss") == 3
 
 
 @pytest.mark.parametrize("remat, calls_a_layer", [(False, 3), (True, 4)],
@@ -268,10 +279,12 @@ def test_hybridlm_epoch_program_fits_the_chip(
     3 steps of 1 x 8192 tokens gathered from the resident rows and scanned,
     parameters and optimizer state donated: what the resident scan runner
     compiles) for the described v5e: arguments + outputs - aliased +
-    temporaries within 15.5e9 bytes (13.66e9 here: arguments 10.19e9, all
-    aliased, temporaries 3.47e9), one flash forward, one dq and one dk/dv
+    temporaries within 15.5e9 bytes (13.78e9 here: arguments 10.19e9, all
+    aliased, temporaries 3.59e9; 13.66e9 before PR 32 put the loss's
+    gradient, with its accumulator, into the forward sweep), one flash forward, one dq and one dk/dv
     call at head_dim 64 (the attention layer's ``attn_out`` and ``attn_lse``
-    are kept, so the backward pass recomputes none)."""
+    are kept, so the backward pass recomputes none), three products in the
+    loss (no chunk's logits computed twice)."""
     from raydp_tpu.estimator.jax_estimator import (
         MODEL_LOSS, _scan_over_batches, make_train_step)
     from raydp_tpu.models import HybridLM, hybridlm_optimizer
@@ -303,3 +316,4 @@ def test_hybridlm_epoch_program_fits_the_chip(
     for name in ("flash_attention_fwd", "flash_attention_bwd_dq",
                  "flash_attention_bwd_dkv"):
         assert len(re.findall(rf"%[\w.\-]*{name}[\w.\-]* = ", text)) == 1, name
+    assert _loss_products(text, "hybridlm.loss") == 3
