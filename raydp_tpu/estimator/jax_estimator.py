@@ -170,26 +170,36 @@ def make_train_step(module, loss_fn, tx, row_paths=(), kernel_paths=()):
     objective = make_objective(module, loss_fn)
     if row_paths and loss_fn == MODEL_LOSS:
         raise ValueError("the row-wise update needs a loss of the prediction")
+    reported = train_report_names(module, loss_fn)
 
     # loss accumulates ON DEVICE: a host float(loss) per step would force
     # a sync and serialize the H2D/compute pipeline (measured 6× slowdown)
-    def step_impl(params, opt_state, loss_sum, x, y):
+    def reporting(params, opt_state, loss_sum, x, y):
         if row_paths:
             params2, opt_state2, loss = row_update.step(
                 module, loss_fn, tx, row_paths, params, opt_state, x, y,
                 kernel_paths,
             )
-            return params2, opt_state2, loss_sum + loss
+            return params2, opt_state2, loss_sum + loss, {}
 
         # stable names in the device trace (metadata only)
         with jax.named_scope("loss_and_grad"):
-            (loss, _), grads = jax.value_and_grad(
+            (loss, aux), grads = jax.value_and_grad(
                 lambda p: objective(p, x, y), has_aux=True
             )(params)
         with jax.named_scope("optimizer_update"):
             updates, opt_state2 = tx.update(grads, opt_state, params)
             params2 = optax.apply_updates(params, updates)
-        return params2, opt_state2, loss_sum + loss
+        report = {name: aux[name] for name in reported if name in aux}
+        return params2, opt_state2, loss_sum + loss, report
+
+    def step_impl(params, opt_state, loss_sum, x, y):
+        return reporting(params, opt_state, loss_sum, x, y)[:3]
+
+    # the same step with what the model's loss reports of it (the names in
+    # ``module.train_report``; {} for a model that names none, and then the
+    # program is the one without): what ``_scan_over_batches`` sums
+    step_impl.reporting = reporting
 
     if kernel_paths:
         # what the FLOPs probe compiles in this step's place: the same step
@@ -200,22 +210,47 @@ def make_train_step(module, loss_fn, tx, row_paths=(), kernel_paths=()):
     return step_impl
 
 
+def train_report_names(module, loss_fn) -> tuple:
+    """The names in the ``aux`` of a model's own loss that it wants summed
+    over an epoch's TRAINING steps (``module.train_report``; the rest of
+    ``aux`` is the evaluation's): none for a loss of the prediction."""
+    if loss_fn != MODEL_LOSS:
+        return ()
+    return tuple(getattr(module, "train_report", ()))
+
+
+def _add_reports(total, part):
+    """Two segments' reports, summed ({} where the model reports nothing)."""
+    import jax
+
+    return part if not total else jax.tree.map(lambda a, b: a + b, total, part)
+
+
 def _scan_over_batches(step_impl, params, opt_state, xb, yb):
     """Run the train step over stacked batches [S, B, ...] with ONE
     ``lax.scan`` — the shared core of the whole-epoch and segment-stream
-    runners (one dispatch per call instead of one per step)."""
+    runners (one dispatch per call instead of one per step). Returns
+    ``(params, opt_state, loss_sum, report)``: ``report`` is what the
+    steps reported (``step_impl.reporting``) summed over them, inside the
+    program; ``{}`` from a model that reports nothing, whose program then
+    has no such output."""
+    import jax
     import jax.numpy as jnp
     from jax import lax
 
-    def body(carry, xy):
-        p, o, ls = carry
-        p, o, ls = step_impl(p, o, ls, xy[0], xy[1])
-        return (p, o, ls), None
+    # a step that is not make_train_step's reports nothing
+    step = getattr(step_impl, "reporting", None) or (
+        lambda *args: step_impl(*args) + ({},))
 
-    (params, opt_state, loss_sum), _ = lax.scan(
+    def body(carry, xy):
+        *carry, report = step(*carry, xy[0], xy[1])
+        return tuple(carry), report
+
+    (params, opt_state, loss_sum), reports = lax.scan(
         body, (params, opt_state, jnp.zeros((), jnp.float32)), (xb, yb)
     )
-    return params, opt_state, loss_sum
+    return params, opt_state, loss_sum, jax.tree.map(
+        lambda a: a.sum(axis=0), reports)
 
 
 class _HostArrays:
@@ -573,6 +608,22 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
             out = compiled(*args)
         self._step_recorder.dispatched(span.duration, out[2], steps)
         return out
+
+    def _note_train_report(self, module, report, steps: int) -> dict:
+        """An epoch's training report on the host, and what the model makes
+        of it (``module.epoch_facts(report, steps)`` -> ``{"counters":
+        {name: increment}, "gauges": {name: value}}``) in the program's
+        counters and gauges ``model.<name>``."""
+        import jax
+
+        host = {k: np.asarray(v) for k, v in jax.device_get(report).items()}
+        facts = getattr(module, "epoch_facts", None)
+        said = facts(host, steps) if callable(facts) else {}
+        for name, value in said.get("counters", {}).items():
+            obs.metrics.counter(f"model.{name}").inc(value)
+        for name, value in said.get("gauges", {}).items():
+            obs.metrics.gauge(f"model.{name}").set(value)
+        return host
 
     def clear_staging_cache(self) -> None:
         """Release the staged host arrays AND the device-resident copy of
@@ -960,8 +1011,14 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                     "estimator.epoch", epoch=epoch,
                     resumed_at=epoch_start_step,
                 ) as epoch_span:
+                    # what the epoch's training steps reported, summed inside
+                    # the epoch program (the scan runners; the per-step loop
+                    # reports nothing)
+                    train_report = {}
                     if run_scan_epoch is not None:
-                        params, opt_state, loss_sum, steps = run_scan_epoch(
+                        (
+                            params, opt_state, loss_sum, steps, train_report,
+                        ) = run_scan_epoch(
                             params, opt_state, epoch_seed,
                             start_step=epoch_start_step, save_cb=save_cb,
                         )
@@ -971,7 +1028,9 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                         # builds each epoch's host iterator itself —
                         # coalesced whole-segment slices except on a
                         # mid-segment resume)
-                        params, opt_state, loss_sum, steps = run_stream_segments(
+                        (
+                            params, opt_state, loss_sum, steps, train_report,
+                        ) = run_stream_segments(
                             params, opt_state, epoch_start_step,
                             save_cb=save_cb,
                         )
@@ -1135,6 +1194,13 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                                 eval_source, params, eval_fns, mesh, batch_size
                             )
                         )
+                if train_report:
+                    # after the evaluation's loss fetch, the epoch's fence:
+                    # this fetch waits for nothing (without an evaluation it
+                    # is itself the fence)
+                    record["train_report"] = self._note_train_report(
+                        module, train_report, steps
+                    )
                 if (
                     eval_source is not None or recorder.drained
                 ) and epoch + 1 < self.num_epochs:
@@ -1621,6 +1687,7 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                 )
             done = start_step
             loss_total = jnp.zeros((), jnp.float32)
+            report_total = {}
             pending_save = None
             dispatches = 0
             seg_q = pipe["q"]
@@ -1670,12 +1737,13 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                     )
                 if fit_capture is not None:
                     fit_capture.begin_steps()
-                params, opt_state, loss_sum = self._dispatch(
+                params, opt_state, loss_sum, report = self._dispatch(
                     compiled[length], length, params, opt_state, xb, yb
                 )
                 if fit_capture is not None:
                     fit_capture.note_step(length)
                 loss_total = loss_total + loss_sum
+                report_total = _add_reports(report_total, report)
                 done += length
                 if save_every is not None and done % save_every == 0:
                     pending_save = done
@@ -1690,7 +1758,9 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                     t_s = time.perf_counter()
                     jax.block_until_ready(loss_total)
                     recorder.note("sync", time.perf_counter() - t_s)
-            return params, opt_state, loss_total, done - start_step
+            return (
+                params, opt_state, loss_total, done - start_step, report_total
+            )
 
         run.start = start
         run.close = close
@@ -1879,22 +1949,27 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
             # the common one-segment epoch must not pay an extra scalar-add
             # dispatch per epoch
             loss_total = None
+            report_total = {}
             done = start_step
             while done < steps_per_epoch:
                 length = min(seg_cap, steps_per_epoch - done)
-                params, opt_state, loss_sum = run_segment(
+                params, opt_state, loss_sum, report = run_segment(
                     params, opt_state, order, done, length
                 )
                 loss_total = (
                     loss_sum if loss_total is None else loss_total + loss_sum
                 )
+                report_total = _add_reports(report_total, report)
                 done += length
                 # the epoch-complete checkpoint is the outer loop's epoch_N
                 if save_cb is not None and done < steps_per_epoch:
                     save_cb(params, opt_state, done)
             if loss_total is None:
                 loss_total = jnp.zeros((), jnp.float32)
-            return params, opt_state, loss_total, steps_per_epoch - start_step
+            return (
+                params, opt_state, loss_total, steps_per_epoch - start_step,
+                report_total,
+            )
 
         return run_epoch
 

@@ -1,7 +1,8 @@
 """Model zoo: the reference's workload families, TPU-native."""
 
 from raydp_tpu.models.dlrm import DLRM, dlrm_optimizer, dlrm_sharding_rules
-from raydp_tpu.models.hybridlm import HybridLM, hybridlm_optimizer
+from raydp_tpu.models.hybridlm import (
+    HybridLM, RoutedHybridLM, hybridlm_optimizer)
 from raydp_tpu.models.looplm import LoopLM, looplm_optimizer
 from raydp_tpu.models.mlp import MLPClassifier, MLPRegressor
 from raydp_tpu.models.transformer import TransformerLM, sequence_parallel_apply
@@ -12,6 +13,7 @@ __all__ = [
     "LoopLM",
     "MLPClassifier",
     "MLPRegressor",
+    "RoutedHybridLM",
     "TransformerLM",
     "dlrm_optimizer",
     "dlrm_sharding_rules",

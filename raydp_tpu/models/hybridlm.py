@@ -1,19 +1,48 @@
-"""Hybrid state-space / attention decoder LM (the ``granitemoehybrid`` family
-with no experts: Mamba-2 layers and grouped-query attention layers in the
-order ``layer_types`` gives, each followed by one dense SwiGLU).
+"""Hybrid decoder LM: a MIXER KIND (``mamba``, ``attention``, ``conv``) and an
+FFN KIND (``dense``, ``experts``) per layer, in the order ``layer_types`` and
+``ffn_types`` give. Two published families are built from their
+``config.json`` (``from_config`` reads ``model_type``): ``granitemoehybrid``
+with no experts (Mamba-2 and grouped-query attention without positions, a
+dense SwiGLU after each, four multipliers) and ``lfm2_moe`` (gated short
+convolutions and grouped-query attention with RoPE and per-head q/k norms;
+leading dense SwiGLUs, then routed experts of which this chip holds a share).
 
 ::
 
     h = embedding_multiplier E[x]
-    for kind in layer_types:
-      h = h + residual_multiplier Mixer_kind(RMSNorm(h))     # pre-norm only
-      [g, u] = W_in RMSNorm(h);  h = h + residual_multiplier W_out(silu(g) u)
+    for mixer, ffn in zip(layer_types, ffn_types):
+      h = h + residual_multiplier Mixer_mixer(RMSNorm(h))     # pre-norm only
+      h = h + residual_multiplier FFN_ffn(RMSNorm(h))
     logits = RMSNorm_f(h) E^T / logits_scaling               # tied head
     loss = mean next-token cross-entropy
 
+``dense``: ``[g, u] = W_in y; W_out(silu(g) u)``. ``experts``
+(``ops.experts.routed_experts``, which says how): sigmoid scores over ALL
+``experts_total`` experts, the ``experts_per_token`` largest of score +
+``expert_bias`` (the bias enters the selection only; what the layer hands
+back as its gradient is each expert's excess load, of which
+``hybridlm_optimizer`` makes the balancing rule's step), the selected scores
+normalised; the experts ``first_expert ..
+first_expert + experts_held - 1`` are here and their part of the result is
+computed, for every pair routed to them, however uneven the load; what the
+absent experts would add is left out (one chip's share of an
+expert-parallel group, without its exchange). What the expert layers
+report of a step (``train_report``: each held expert's load, the pairs past
+the row buffer's bound: zero, the buffer holds tokens x ``experts_per_token``
+rows, every choice held) the estimator sums over an epoch's steps and hands
+to ``epoch_facts``.
+
+``conv`` (a gated short convolution)::
+
+    [B | C | x] = W_in u;  y = W_out( C * conv1d_causal(B * x) )
+
+depthwise over time, ``conv_kernel`` taps, no bias, float32.
+
 ``attention``: q of ``num_heads`` heads, k and v of ``num_kv_heads`` (each
-serves ``num_heads / num_kv_heads`` consecutive query heads), no bias, no
-positions, causal softmax of ``attention_multiplier q.k``. It runs through
+serves ``num_heads / num_kv_heads`` consecutive query heads), no bias;
+``qk_norm``: an RMSNorm over each head of q and of k (gains [head_dim]);
+``rope_theta`` > 0: RoPE (``looplm``'s rotate-half) on q and k, else no
+positions; causal softmax of ``attention_multiplier q.k``. It runs through
 the repo's ``_attend`` (``attn_impl="flash"`` on the chip): q is multiplied
 by ``attention_multiplier sqrt(head_dim)`` beforehand, so that the kernels'
 own ``head_dim ** -0.5`` gives the multiplier, and K and V are repeated to
@@ -51,15 +80,19 @@ from typing import Any, Sequence
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from raydp_tpu.models.looplm import (
-    LOSS_FACTS, chunked_cross_entropy, looplm_optimizer, rms_norm)
+    LOSS_FACTS, apply_rope, chunked_cross_entropy, looplm_optimizer, rms_norm,
+    rope_tables)
 from raydp_tpu.models.transformer import _attend
+from raydp_tpu.ops import experts as experts_op
 from raydp_tpu.ops.flash_attention import SAVED_RESIDUALS
 from raydp_tpu.ops.ssd import ssd_chunk_scan
 
-MAMBA, ATTENTION = "mamba", "attention"
+MAMBA, ATTENTION, CONV = "mamba", "attention", "conv"
+DENSE, EXPERTS = "dense", "experts"
 # what a recomputed block keeps from its forward pass: the flash kernel's
 # output and log-sum-exp (an attention layer; with both kept the recomputed
 # kernel call is dead code) and ``w_out``'s output (every layer). A Mamba
@@ -67,6 +100,13 @@ MAMBA, ATTENTION = "mamba", "attention"
 # scores and the chunk states, not ``y``, so a kept ``ssd_out`` would spare
 # two of its five products for 67 MB a layer and row
 REMAT_KEEPS = SAVED_RESIDUALS + ("mlp_out",)
+# an expert layer keeps ``mlp_out`` (its combined result) as every layer
+# does, and its discrete part (``ops.experts.KEPT``: every token's choice
+# and the sort's three permutations, four int32 vectors of tokens x k, 2 MB
+# a layer at 32,768 tokens): the backward pass must not choose again (a
+# near-tie could fall the other way) and need not sort again; the first
+# grouped product's output (940 MB a layer at 131,072 rows) is recomputed
+EXPERT_KEEPS = (experts_op.KEPT,)
 
 
 def _inverse_softplus(x):
@@ -94,26 +134,67 @@ class HybridLM(nn.Module):
     dtype: Any = jnp.bfloat16  # compute dtype; parameters are float32
     remat: bool = True  # recompute each block in the backward pass
     loss_chunk: int = 2048  # tokens of logits held at a time; 0: all
+    # -- what the second family adds; the defaults build the first ----------
+    ffn_types: Sequence[str] = ()  # a kind a layer; (): every layer dense
+    rope_theta: float = 0.0  # 0: no positions
+    qk_norm: bool = False  # RMSNorm over each head of q and k
+    conv_kernel: int = 3  # taps of the gated short convolution
+    expert_width: int = 0
+    experts_total: int = 0  # the router's width
+    experts_held: int = 0  # first_expert .. first_expert + experts_held - 1
+    first_expert: int = 0
+    experts_per_token: int = 0
+    routed_scaling: float = 1.0
+    expert_bias_spread: float = 0.0  # expert_bias ~ U(+-spread); 0: zeros
+
+    # what ``loss`` reports of a TRAINING step beside its loss, by name in
+    # its ``aux``: the estimator sums these over an epoch's steps inside the
+    # epoch program and gives the sums to ``epoch_facts``
+    train_report = ("expert_load", "pairs_dropped")
 
     @classmethod
     def from_config(cls, config: dict, **kw):
-        """From the published ``config.json``'s keys (``granitemoehybrid``):
-        the first ``num_hidden_layers`` entries of ``layer_types``. What the
-        model does not build is refused, not ignored."""
-        refused = {
+        """From a published ``config.json``'s keys, by ``model_type``
+        (``granitemoehybrid``, the default, or ``lfm2_moe``). What the model
+        does not build is refused, not ignored."""
+        family = config.get("model_type", "granitemoehybrid")
+        if family == "granitemoehybrid":
+            fields = cls._granite_fields(config)
+        elif family == "lfm2_moe":
+            fields = cls._lfm2_fields(config)
+        else:
+            raise ValueError(f"HybridLM builds model_type granitemoehybrid "
+                             f"and lfm2_moe, not {family!r}")
+        fields.update(kw)  # attn_impl, dtype, remat, loss_chunk; overrides
+        fields["dtype"] = jnp.dtype(fields.get("dtype", cls.dtype))
+        return cls(**fields)
+
+    @staticmethod
+    def _refuse(config: dict, only: dict, why: dict = None) -> None:
+        for key, value in only.items():
+            if config.get(key, value) != value:
+                raise ValueError(
+                    f"HybridLM builds {key}={value!r} only, not "
+                    f"{config[key]!r}" + (why or {}).get(key, ""))
+
+    @classmethod
+    def _granite_fields(cls, config: dict) -> dict:
+        """The first ``num_hidden_layers`` entries of ``layer_types``."""
+        cls._refuse(config, {
             "num_local_experts": 0, "mamba_n_groups": 1,
             "mamba_proj_bias": False, "attention_bias": False,
             "mamba_conv_bias": True, "tie_word_embeddings": True,
-            "position_embedding_type": "nope", "hidden_act": "silu"}
-        for key, only in refused.items():
-            if config.get(key, only) != only:
-                raise ValueError(f"HybridLM builds {key}={only!r} only, "
-                                 f"not {config[key]!r}")
+            "position_embedding_type": "nope", "hidden_act": "silu"},
+            {"num_local_experts": (
+                ": this family's experts are a shared expert beside routed "
+                "ones under another routing rule (softmax over the "
+                "selected), which is not built; the experts FFN kind here "
+                "is lfm2_moe's")})
         if config["mamba_expand"] * config["hidden_size"] != (
                 config["mamba_n_heads"] * config["mamba_d_head"]):
             raise ValueError("mamba_expand x hidden_size is not "
                              "mamba_n_heads x mamba_d_head")
-        fields = dict(
+        return dict(
             vocab_size=config["vocab_size"],
             layer_types=tuple(
                 config["layer_types"][:config["num_hidden_layers"]]),
@@ -131,9 +212,48 @@ class HybridLM(nn.Module):
             attention_multiplier=float(config["attention_multiplier"]),
             logits_scaling=float(config["logits_scaling"]),
             rms_eps=float(config["rms_norm_eps"]))
-        fields.update(kw)  # attn_impl, dtype, remat, loss_chunk; overrides
-        fields["dtype"] = jnp.dtype(fields.get("dtype", cls.dtype))
-        return cls(**fields)
+
+    @classmethod
+    def _lfm2_fields(cls, config: dict) -> dict:
+        """``num_hidden_layers`` entries of ``layer_types`` from
+        ``share["first_layer"]`` on (a pipeline stage's layers), the first
+        ``num_dense_layers`` of them with a dense SwiGLU of
+        ``intermediate_size``, the others with routed experts:
+        ``num_experts`` of them held here, ``share["first_expert"]`` the
+        first, of the ``share["experts_total"]`` the router scores (both
+        default to the whole: every expert held)."""
+        cls._refuse(config, {
+            "conv_bias": False, "norm_topk_prob": True,
+            "use_expert_bias": True, "tie_word_embeddings": True})
+        share = config.get("share", {})
+        first, depth = share.get("first_layer", 0), config["num_hidden_layers"]
+        kinds = list(config["layer_types"][first:first + depth])
+        names = {"conv": CONV, "full_attention": ATTENTION}
+        unknown = sorted(set(kinds) - set(names))
+        if unknown or len(kinds) != depth:
+            raise ValueError(f"lfm2_moe layers {first}..{first + depth - 1}: "
+                             f"layer_types gives {kinds}")
+        dense = config["num_dense_layers"]
+        head_dim = config["hidden_size"] // config["num_attention_heads"]
+        return dict(
+            vocab_size=config["vocab_size"],
+            layer_types=tuple(names[k] for k in kinds),
+            ffn_types=(DENSE,) * dense + (EXPERTS,) * (depth - dense),
+            hidden_size=config["hidden_size"],
+            num_heads=config["num_attention_heads"],
+            num_kv_heads=config["num_key_value_heads"],
+            intermediate_size=config["intermediate_size"],
+            conv_kernel=config["conv_L_cache"],
+            rope_theta=float(config["rope_theta"]), qk_norm=True,
+            expert_width=config["moe_intermediate_size"],
+            experts_held=config["num_experts"],
+            experts_total=share.get("experts_total", config["num_experts"]),
+            first_expert=share.get("first_expert", 0),
+            experts_per_token=config["num_experts_per_tok"],
+            routed_scaling=float(config["routed_scaling_factor"]),
+            embedding_multiplier=1.0, residual_multiplier=1.0,
+            attention_multiplier=head_dim ** -0.5, logits_scaling=1.0,
+            rms_eps=float(config["norm_eps"]))
 
     # -- shapes ----------------------------------------------------------------
     @property
@@ -144,46 +264,98 @@ class HybridLM(nn.Module):
     def mamba_inner(self) -> int:
         return self.mamba_heads * self.mamba_head_dim
 
-    def matrix_shapes(self, kind: str) -> dict:
-        """{name: (in, out)} of one layer's matrices."""
+    @property
+    def ffn_kinds(self) -> tuple:
+        return tuple(self.ffn_types) or (DENSE,) * len(self.layer_types)
+
+    @property
+    def expert_layers(self) -> int:
+        return self.ffn_kinds.count(EXPERTS)
+
+    def matrix_shapes(self, kind: str, ffn: str = DENSE) -> dict:
+        """{name: (in, out)} of one layer's matrices, a held expert's as one
+        of its own: what a token passes through."""
         d, f, inner = self.hidden_size, self.intermediate_size, self.mamba_inner
-        ffn = {"w_in": (d, 2 * f), "w_out": (f, d)}
+        if ffn == DENSE:
+            after = {"w_in": (d, 2 * f), "w_out": (f, d)}
+        else:
+            after = {"router": (d, self.experts_total),
+                     "w13": (d, 2 * self.expert_width),
+                     "w2": (self.expert_width, d)}
         if kind == ATTENTION:
             kv = self.num_kv_heads * self.head_dim
             return {"wq": (d, d), "wk": (d, kv), "wv": (d, kv), "wo": (d, d),
-                    **ffn}
+                    **after}
+        if kind == CONV:
+            return {"in_proj": (d, 3 * d), "out_proj": (d, d), **after}
         return {"in_proj": (d, 2 * inner + 2 * self.mamba_state
                             + self.mamba_heads),
-                "out_proj": (inner, d), **ffn}
+                "out_proj": (inner, d), **after}
+
+    def expert_row_bound(self, tokens: int) -> int:
+        """Rows of an expert layer's buffer for a batch of ``tokens``: the
+        worst case, every choice held, so that no pair can be dropped."""
+        return experts_op.row_bound_for(tokens * self.experts_per_token)
 
     def setup(self):
         d = self.hidden_size
-        for kind in self.layer_types:
-            if kind not in (MAMBA, ATTENTION):
-                raise ValueError(f"layer kind {kind!r} is neither "
-                                 f"{MAMBA!r} nor {ATTENTION!r}")
+        kinds, ffns = self.layer_types, self.ffn_kinds
+        for kind in kinds:
+            if kind not in (MAMBA, ATTENTION, CONV):
+                raise ValueError(f"layer kind {kind!r} is none of {MAMBA!r}, "
+                                 f"{ATTENTION!r}, {CONV!r}")
+        if len(ffns) != len(kinds) or set(ffns) - {DENSE, EXPERTS}:
+            raise ValueError(f"ffn_types {ffns} does not give {DENSE!r} or "
+                             f"{EXPERTS!r} for each of {len(kinds)} layers")
         if self.num_heads % self.num_kv_heads or d % self.num_heads:
             raise ValueError("query heads must divide the hidden size, "
                              "K/V heads the query heads")
+        if EXPERTS in ffns and not (
+                0 < self.experts_per_token <= self.experts_total
+                and 0 < self.experts_held
+                and self.first_expert + self.experts_held <= self.experts_total
+                and self.first_expert >= 0 and self.expert_width > 0):
+            raise ValueError(
+                f"experts {self.first_expert}..+{self.experts_held} of "
+                f"{self.experts_total}, {self.experts_per_token} a token, "
+                f"width {self.expert_width}: not an expert layer's share")
         matrix = nn.initializers.normal(0.02)
 
-        def layer(kind):
+        def layer(kind, ffn):
             def init(rng):
-                shapes = self.matrix_shapes(kind)
+                shapes = self.matrix_shapes(kind, ffn)
                 keys = jax.random.split(rng, len(shapes) + 3)
-                out = {name: matrix(k, shape, jnp.float32)
-                       for (name, shape), k in zip(shapes.items(), keys)}
+                out = {}
+                for (name, shape), k in zip(shapes.items(), keys):
+                    if name in ("w13", "w2"):  # one a held expert, stacked
+                        shape = (self.experts_held,) + shape
+                    out[name] = matrix(k, shape, jnp.float32)
                 out.update(norm1=jnp.ones((d,), jnp.float32),
                            norm2=jnp.ones((d,), jnp.float32))
                 if kind == MAMBA:
                     out.update(self._mamba_vectors(keys[-3:]))
+                elif kind == CONV:
+                    # as torch initialises a depthwise Conv1d
+                    bound = self.conv_kernel ** -0.5
+                    out["conv_w"] = jax.random.uniform(
+                        keys[-1], (self.conv_kernel, d), jnp.float32,
+                        -bound, bound)
+                elif self.qk_norm:
+                    out.update(q_norm=jnp.ones((self.head_dim,), jnp.float32),
+                               k_norm=jnp.ones((self.head_dim,), jnp.float32))
+                if ffn == EXPERTS:
+                    spread = self.expert_bias_spread
+                    out["expert_bias"] = jax.random.uniform(
+                        keys[-2], (self.experts_total,), jnp.float32,
+                        -spread, spread) if spread else jnp.zeros(
+                            (self.experts_total,), jnp.float32)
                 return out
             return init
 
         self.embed = self.param("embed", matrix, (self.vocab_size, d),
                                 jnp.float32)
-        self.layers = [self.param(f"layer_{i}", layer(kind))
-                       for i, kind in enumerate(self.layer_types)]
+        self.layers = [self.param(f"layer_{i}", layer(kind, ffn))
+                       for i, (kind, ffn) in enumerate(zip(kinds, ffns))]
         self.final_norm = self.param("final_norm", nn.initializers.ones, (d,),
                                      jnp.float32)
 
@@ -213,50 +385,76 @@ class HybridLM(nn.Module):
         """``x`` is a sample of the staged feature, [rows, T + 1] ids: a row
         holds T predicted tokens. ``flops_per_row`` is the model's FLOPs of
         a training step on one row, forward + backward = 3 x forward, from
-        shapes (``flops_per_row_parts``); recomputation does not count."""
+        shapes (``flops_per_row_parts``); recomputation does not count, and
+        the experts are counted AT THE UNIFORM SHARE (tokens x
+        experts_per_token x held / total pairs a layer): a number from
+        shapes, so ``estimator.mfu`` does not move with the routing."""
         t = x.shape[1] - 1
         parts = self.flops_per_row_parts(t)
         kept = self._remat_keeps(t)
-        return {
+        facts = {
             "layer_kinds": ",".join(self.layer_types),
             "layer_kinds.mamba": self.layer_types.count(MAMBA),
             "layer_kinds.attention": self.layer_types.count(ATTENTION),
+            "layer_kinds.conv": self.layer_types.count(CONV),
             "ssd_chunk": min(self.mamba_chunk, t),
             "ssd_flops_per_row": parts["scan"],
             "remat": bool(self.remat), "remat_keeps": ",".join(kept),
             "remat_kept_bytes_per_row": sum(kept.values()),
             **LOSS_FACTS,
             "tokens_per_row": t, "flops_per_row": sum(parts.values())}
+        if self.expert_layers:
+            facts.update({
+                "ffn_kinds": ",".join(self.ffn_kinds),
+                "experts.held": self.experts_held,
+                "experts.total": self.experts_total,
+                "experts.per_token": self.experts_per_token,
+                "experts.layers": self.expert_layers,
+                "experts.rows_per_row": self.expert_row_bound(t),
+                "experts.flops_per_row": parts["experts"],
+                "experts.flops_counted": "uniform share"})
+        return facts
 
     def flops_per_row_parts(self, t: int) -> dict:
         """Model FLOPs of a training step on a row of ``t`` tokens by part:
-        ``layers`` (6 x matrix parameters x tokens, the convolution's 2 k a
-        channel beside them), ``scan`` (the dual form's four products at
-        this chunk size, causal pairs inside a chunk), ``attention`` (causal:
-        t (t + 1) / 2 kept pairs), ``head`` (the tied embedding, once)."""
+        ``layers`` (6 x matrix parameters x tokens, a convolution's 2 k a
+        channel beside them; routers here, experts not), ``scan`` (the dual
+        form's four products at this chunk size, causal pairs inside a
+        chunk), ``attention`` (causal: t (t + 1) / 2 kept pairs), ``head``
+        (the tied embedding, once) and, with expert layers, ``experts`` (6
+        x one expert's parameters x the uniform share of the pairs)."""
         d, n = self.hidden_size, self.mamba_state
         heads, p = self.mamba_heads, self.mamba_head_dim
         mamba = self.layer_types.count(MAMBA)
         matrices = sum(
-            a * b for kind in self.layer_types
-            for a, b in self.matrix_shapes(kind).values())
-        conv = mamba * self.mamba_conv * (self.mamba_inner + 2 * n)
+            a * b for kind, ffn in zip(self.layer_types, self.ffn_kinds)
+            for name, (a, b) in self.matrix_shapes(kind, ffn).items()
+            if name not in ("w13", "w2"))
+        conv = (mamba * self.mamba_conv * (self.mamba_inner + 2 * n)
+                + self.layer_types.count(CONV) * self.conv_kernel * d)
         q = min(self.mamba_chunk, t)
         pairs = (t // q) * (q * (q + 1) // 2)  # kept (i, j) pairs of a row
         scan = mamba * (2 * n * pairs + 2 * heads * p * pairs
                         + 2 * 2 * heads * p * n * t)
-        return {
+        parts = {
             "layers": 6 * (matrices + conv) * t,
             "scan": 3 * scan,
             "attention": self.layer_types.count(ATTENTION)
             * 12 * d * (t * (t + 1) // 2),
             "head": 6 * d * self.vocab_size * t}
+        if self.expert_layers:
+            # tokens x k x held / total pairs a layer, whole numbers here
+            pairs_here = (t * self.experts_per_token * self.experts_held
+                          // self.experts_total)
+            parts["experts"] = (self.expert_layers * 6 * 3 * d
+                                * self.expert_width * pairs_here)
+        return parts
 
     def _remat_keeps(self, t: int) -> dict:
         """{name: bytes the layers keep of a row of ``t`` tokens for the
-        backward pass}, of ``REMAT_KEEPS``: nothing without ``remat`` (then
-        everything is kept), the attention's two only where the flash
-        kernel names them."""
+        backward pass}, of ``REMAT_KEEPS`` (and ``EXPERT_KEEPS`` with expert
+        layers): nothing without ``remat`` (then everything is kept), the
+        attention's two only where the flash kernel names them."""
         if not self.remat:
             return {}
         wide = t * self.hidden_size * jnp.dtype(self.dtype).itemsize
@@ -265,8 +463,33 @@ class HybridLM(nn.Module):
                  "attn_lse": attention * 4 * self.num_heads * t,
                  "mlp_out": len(self.layer_types) * wide}
         flash = self.attn_impl in ("flash", "ulysses_flash")
-        return {name: sizes[name] for name in REMAT_KEEPS
+        kept = {name: sizes[name] for name in REMAT_KEEPS
                 if flash or name not in SAVED_RESIDUALS}
+        if self.expert_layers:
+            # tok and pair of a row; the choice and the row of a pair: int32
+            kept[experts_op.KEPT] = self.expert_layers * 4 * (
+                2 * self.expert_row_bound(t) + 2 * t * self.experts_per_token)
+        return kept
+
+    def epoch_facts(self, report: dict, steps: int) -> dict:
+        """What an epoch's summed ``train_report`` says, for the estimator's
+        counters and gauges (``model.<name>``): ``report["expert_load"]``
+        [expert layers, held] pairs routed to each held expert and
+        ``report["pairs_dropped"]``, over ``steps`` steps."""
+        if not self.expert_layers or not steps:
+            return {}
+        load = np.asarray(report["expert_load"], np.float64)
+        dropped = float(np.sum(report["pairs_dropped"]))
+        held = float(load.sum()) - dropped
+        mean = np.maximum(load.mean(axis=1), 1e-9)
+        return {
+            "counters": {"experts.pairs_held": held,
+                         "experts.pairs_dropped": dropped,
+                         "experts.steps_reported": steps},
+            "gauges": {
+                "experts.load_max_over_mean": float(
+                    (load.max(axis=1) / mean).mean()),
+                "experts.pairs_held_per_step": held / steps}}
 
     # -- pieces --------------------------------------------------------------
     def _dot(self, x, w):
@@ -286,10 +509,17 @@ class HybridLM(nn.Module):
             def split(z):  # [B, T, heads x Dh] -> [B, heads, T, Dh]
                 return z.reshape(b, t, -1, dh).transpose(0, 2, 1, 3)
 
+            q, k = split(self._dot(y, w["wq"])), split(self._dot(y, w["wk"]))
+            if self.qk_norm:
+                q = rms_norm(q, w["q_norm"], self.rms_eps)
+                k = rms_norm(k, w["k_norm"], self.rms_eps)
+            if self.rope_theta:
+                cos, sin = rope_tables(t, dh, self.rope_theta)
+                q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
             # the attention's own scale is head_dim ** -0.5
             scale = self.attention_multiplier * math.sqrt(dh)
-            q = split(self._dot(y, w["wq"])) * jnp.asarray(scale, self.dtype)
-            k = jnp.repeat(split(self._dot(y, w["wk"])), group, axis=1)
+            q = q * jnp.asarray(scale, self.dtype)
+            k = jnp.repeat(k, group, axis=1)
             v = jnp.repeat(split(self._dot(y, w["wv"])), group, axis=1)
             o = _attend(q, k, v, impl=self.attn_impl, axis="sp", causal=True)
             o = o.transpose(0, 2, 1, 3).reshape(b, t, self.hidden_size)
@@ -326,17 +556,48 @@ class HybridLM(nn.Module):
         gated = y.astype(jnp.float32) * nn.silu(z.astype(jnp.float32))
         return rms_norm(gated.astype(self.dtype), w["gate_norm"], self.rms_eps)
 
+    def _short_conv(self, w, u):
+        """The gated short convolution: ``W_out(C * conv(B * x))``, the
+        depthwise causal convolution (no bias, no activation) and both
+        gates in float32."""
+        with jax.named_scope("hybridlm.conv"):
+            k, t = self.conv_kernel, u.shape[1]
+            bm, cm, x = jnp.split(
+                self._dot(u, w["in_proj"]).astype(jnp.float32), 3, axis=-1)
+            padded = jnp.pad(bm * x, ((0, 0), (k - 1, 0), (0, 0)))
+            conv = sum(w["conv_w"][i] * padded[:, i:i + t] for i in range(k))
+            return self._dot((cm * conv).astype(self.dtype), w["out_proj"])
+
     def _mlp(self, w, y):
         with jax.named_scope("hybridlm.mlp"):
             g, u = jnp.split(self._dot(y, w["w_in"]), 2, axis=-1)
             return checkpoint_name(self._dot(nn.silu(g) * u, w["w_out"]),
                                    "mlp_out")
 
-    def _block(self, kind, w, h):
-        mixer = self._mamba if kind == MAMBA else self._attention
+    def _experts(self, w, y):
+        """(this chip's part of the routed experts' result [B, T, D], what
+        the layer reports: ``ops.experts.routed_experts``'s)."""
+        with jax.named_scope("hybridlm.experts"):
+            b, t, d = y.shape
+            out, report = experts_op.routed_experts(
+                y.reshape(b * t, d), w["router"], w["expert_bias"], w["w13"],
+                w["w2"], first=self.first_expert,
+                top_k=self.experts_per_token, scaling=self.routed_scaling,
+                row_bound=self.expert_row_bound(b * t),
+                scope="hybridlm.experts")
+            report["sel"] = report["sel"].reshape(b, t, -1)
+            return checkpoint_name(out.astype(self.dtype).reshape(b, t, d),
+                                   "mlp_out"), report
+
+    def _block(self, kind, ffn, w, h):
+        """(h after the layer, what its FFN reports: {} for a dense one)."""
+        mixer = {MAMBA: self._mamba, ATTENTION: self._attention,
+                 CONV: self._short_conv}[kind]
         h = self._residual(h, mixer(w, rms_norm(h, w["norm1"], self.rms_eps)))
-        return self._residual(
-            h, self._mlp(w, rms_norm(h, w["norm2"], self.rms_eps)))
+        y = rms_norm(h, w["norm2"], self.rms_eps)
+        out, report = self._experts(w, y) if ffn == EXPERTS else (
+            self._mlp(w, y), {})
+        return self._residual(h, out), report
 
     def head(self, h):
         """Logits, float32, from the final norm's output (the tied head)."""
@@ -345,16 +606,27 @@ class HybridLM(nn.Module):
                        ) / self.logits_scaling
 
     # -- surfaces ------------------------------------------------------------
+    def _states(self, tokens):
+        """(the final norm's output [B, T, D], the expert layers' reports
+        stacked: {} without expert layers)."""
+        h = (self.embedding_multiplier * self.embed[tokens]).astype(self.dtype)
+        keeps = REMAT_KEEPS + (EXPERT_KEEPS if self.expert_layers else ())
+        block = jax.checkpoint(
+            self._block, static_argnums=(0, 1),
+            policy=jax.checkpoint_policies.save_only_these_names(*keeps),
+        ) if self.remat else self._block
+        reports = []
+        for kind, ffn, w in zip(self.layer_types, self.ffn_kinds, self.layers):
+            h, report = block(kind, ffn, w, h)
+            if report:
+                reports.append(report)
+        stacked = {key: jnp.stack([r[key] for r in reports])
+                   for key in (reports[0] if reports else ())}
+        return rms_norm(h, self.final_norm, self.rms_eps), stacked
+
     def hidden_states(self, tokens):
         """The final norm's output [B, T, D]: what the head reads."""
-        h = (self.embedding_multiplier * self.embed[tokens]).astype(self.dtype)
-        block = jax.checkpoint(
-            self._block, static_argnums=(0,),
-            policy=jax.checkpoint_policies.save_only_these_names(*REMAT_KEEPS),
-        ) if self.remat else self._block
-        for kind, w in zip(self.layer_types, self.layers):
-            h = block(kind, w, h)
-        return rms_norm(h, self.final_norm, self.rms_eps)
+        return self._states(tokens)[0]
 
     def __call__(self, tokens):
         """Logits [B, T, V]."""
@@ -363,20 +635,64 @@ class HybridLM(nn.Module):
     def loss(self, x, y=None, with_states=False):
         """Mean next-token cross-entropy on ``x`` int32 [B, T+1] (inputs
         ``x[:, :-1]``, targets ``x[:, 1:]``; ``y`` is not used). Returns
-        ``(loss, aux)``; ``with_states`` puts ``hidden`` [B, T, D], the
-        state the head read, into ``aux`` (for a comparison of the logits;
-        not for a fit, whose evaluation would average it)."""
-        h = self.hidden_states(x[:, :-1])
+        ``(loss, aux)``. With expert layers ``aux`` holds ``train_report``'s
+        two: ``expert_load`` [expert layers, held] and ``pairs_dropped``.
+        ``with_states`` adds ``hidden`` [B, T, D], the state the head read,
+        and ``routing`` int32 [expert layers, B, T, k], every token's
+        choice (for a comparison; not for a fit, whose evaluation would
+        average them)."""
+        h, reports = self._states(x[:, :-1])
         loss, _ = chunked_cross_entropy(
             h, self.embed, 1, x[:, 1:], self.loss_chunk, "hybridlm.loss",
             scale=1.0 / self.logits_scaling)
-        return loss, {"hidden": h} if with_states else {}
+        aux = {}
+        if reports:
+            aux.update(expert_load=reports["load"],
+                       pairs_dropped=reports["dropped"].sum())
+        if with_states:
+            aux["hidden"] = h
+            if reports:
+                aux["routing"] = reports["sel"]
+        return loss, aux
+
+
+class RoutedHybridLM(HybridLM):
+    """``HybridLM`` under the name a configuration with expert layers asks
+    for (``config["model"]["class"]``): a program from before the experts
+    FFN kind has ``HybridLM`` and not this name, and a benchmark that asks
+    for it there leaves at once instead of failing inside a fit."""
 
 
 def hybridlm_optimizer(learning_rate: float = 3e-4, b1: float = 0.9,
-                       b2: float = 0.95, weight_decay: float = 0.1):
+                       b2: float = 0.95, weight_decay: float = 0.1,
+                       warmup_steps: int = 0, expert_bias_rate: float = 0.0):
     """AdamW as LM pre-training runs it: decay on the parameters with two or
     more axes only (the matrices, the embedding, the convolution's taps;
     not on norm gains, ``A_log``, ``D``, ``dt_bias`` or the convolution's
-    bias), float32 moments, no schedule."""
-    return looplm_optimizer(learning_rate, b1, b2, weight_decay)
+    bias), float32 moments. ``warmup_steps``: the rate climbs linearly to
+    ``learning_rate`` over that many steps (step i runs at (i + 1) /
+    warmup_steps of it); 0: no schedule.
+
+    ``expert_bias_rate``: every ``expert_bias`` leaves AdamW for the
+    BALANCING RULE, ``b_e -= rate x excess_e`` (auxiliary-loss-free
+    balancing, arXiv:2408.15664, with the error itself in place of its
+    sign: its step shrinks as the load evens out). ``excess_e`` is what an
+    expert layer hands back as the bias's gradient: the pairs that chose
+    expert e over the even share, less 1 (``ops.experts.route``). 0: the
+    biases stay under AdamW, which then moves them by the rate x the
+    excess's sign, more or less."""
+    import optax
+
+    if not warmup_steps and not expert_bias_rate:
+        return looplm_optimizer(learning_rate, b1, b2, weight_decay)
+    rate = learning_rate if not warmup_steps else (
+        lambda count: learning_rate * jnp.minimum(
+            1.0, (count + 1) / warmup_steps))
+    adamw = looplm_optimizer(rate, b1, b2, weight_decay)
+    if not expert_bias_rate:
+        return adamw
+    return optax.multi_transform(
+        {"adamw": adamw, "balance": optax.sgd(expert_bias_rate)},
+        lambda params: jax.tree_util.tree_map_with_path(
+            lambda path, _: "balance" if getattr(
+                path[-1], "key", None) == "expert_bias" else "adamw", params))

@@ -1,0 +1,311 @@
+"""Routed experts, ONE CHIP'S SHARE of an expert-parallel group: the layer is
+told which experts it holds (``first``, and as many as its weights stack),
+routes every token over ALL the experts, computes its own experts' part of
+the result and leaves the rest out. On one chip it runs without the group's
+exchange; nothing here stands in for the absent chips.
+
+::
+
+    s = sigmoid(u W_g)                       [N, E], float32 (highest)
+    sel = top_k(s + b)                       b enters the SELECTION only
+    excess_e = pairs chosen for e / (N k / E) - 1    over ALL E, for the
+                                                     balancing rule
+    w = s[sel] / (sum s[sel] + 1e-6) x scaling      over the k selected,
+                                                     held here or not
+    out[n] = sum_{e in sel[n], e held} w[n, e] W2_e(silu(W1_e u_n) W3_e u_n)
+
+The (token, choice) pairs whose expert is held are ordered by expert (one
+stable sort of the pairs' keys), their tokens gathered into rows, the rows
+run through two GROUPED matrix products over the ragged groups (``W1 | W3``
+stacked ``[held, D, 2F]``, then ``W2`` ``[held, F, D]``; operands in the
+compute dtype, float32 accumulation), and each row is scaled by its weight
+and added back to its token in float32. NO PAIR IS DROPPED for balance:
+there is no capacity factor. The rows' buffer has a static bound
+(``row_bound``; tokens x k, every choice held, is always enough); pairs
+beyond a smaller bound are left out AND COUNTED (``report["dropped"]``), so
+that a caller who chose a smaller bound can hold the count to zero.
+
+Both directions of both moves are GATHERS (``_rows_of_tokens``,
+``_tokens_of_rows``): the sort gives the permutation and its inverse, so the
+backward pass of "row r reads token tok[r]" is "token n reads its k rows",
+not a scatter-add with repeated indices (XLA:TPU serialises those).
+
+The grouped product is ``impl="ragged_dot"`` (``lax.ragged_dot``: XLA:TPU
+runs it as a Mosaic kernel of its own, ``%ragged-dot-*`` in a trace, and
+XLA:CPU expands it) or ``impl="megablox"``
+(``jax.experimental.pallas.ops.tpu.megablox``: ``%*gmm*`` / ``%*tgmm*``);
+both visit only the row tiles the groups fill, whatever the bound.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.ad_checkpoint import checkpoint_name
+
+from raydp_tpu.ops import backend
+
+IMPLS = ("ragged_dot", "megablox")
+# rows are visited a tile at a time: the bound is a multiple of it
+ROW_TILE = 512
+MEGABLOX_TILING = (ROW_TILE, 1024, 1024)  # rows, contraction, columns
+# the name under which a recomputed block keeps the layer's discrete part:
+# every token's choice and the sort's three permutations
+KEPT = "experts_perm"
+
+
+@jax.custom_vjp
+def _hand_bias(w, bias, excess):
+    """``w`` as it is. On the way back ``bias`` is handed ``excess`` AS ITS
+    GRADIENT, whatever ``w``'s cotangent is: the bias enters a top-k and so
+    has no gradient of the loss, and the rule that moves it (auxiliary-
+    loss-free balancing, ``b_e -= rate x excess_e``) is the optimizer's to
+    apply (``models.hybridlm.hybridlm_optimizer``), so the layer's word on
+    the load leaves it the way every parameter's does, with no fetch."""
+    return w
+
+
+def _hand_bias_fwd(w, bias, excess):
+    return w, excess
+
+
+def _hand_bias_bwd(excess, d_w):
+    return d_w, excess, jnp.zeros_like(excess)
+
+
+_hand_bias.defvjp(_hand_bias_fwd, _hand_bias_bwd)
+
+
+def route(u, w_gate, bias, top_k: int, scaling: float = 1.0):
+    """(sel int32 [N, k], w float32 [N, k]) of tokens ``u`` [N, D]: sigmoid
+    scores over all of ``w_gate``'s experts, the k largest of score + bias,
+    the selected scores normalised over the k. All of it float32, the
+    product at ``highest``: a bf16 product moves a logit by 2e-3, and the
+    4th and 5th scores of a token lie closer than that often enough."""
+    logits = jnp.dot(u.astype(jnp.float32), w_gate.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, sel = lax.top_k(scores + lax.stop_gradient(bias), top_k)
+    # THE CHOICE IS KEPT, with the permutations made from it (``KEPT``): a
+    # recomputed block that chose again could choose otherwise for a token
+    # whose k-th and (k+1)-th scores nearly tie (another fusion rounds
+    # otherwise), and rows sorted by one choice under groups sized by the
+    # other are garbage (gradients 1e5 times too large; my chip run, PR 34)
+    sel = checkpoint_name(sel.astype(jnp.int32), KEPT)
+    w = jnp.take_along_axis(scores, sel, axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6) * scaling
+    return sel, w
+
+
+def excess_load(sel, experts: int):
+    """float32 [experts]: the pairs of ``sel`` int32 [N, k] that chose each
+    of ALL the experts, over the even share (N k / experts), less 1."""
+    chosen = jnp.sum(sel[..., None] == jnp.arange(experts, dtype=jnp.int32),
+                     axis=(0, 1), dtype=jnp.float32)
+    return chosen * (experts / sel.size) - 1.0
+
+
+def row_bound_for(pairs: int) -> int:
+    """Rows of a buffer that holds ``pairs`` pairs: a whole number of row
+    tiles. tokens x k pairs is the worst case (every choice held) and
+    always correct."""
+    return -(-max(pairs, 1) // ROW_TILE) * ROW_TILE
+
+
+def plan(sel, first: int, count: int, row_bound: int):
+    """The sort. ``sel`` int32 [N, k] -> a dict of
+
+    ``tok``    int32 [R]     the token row r reads (rows past ``rows`` read
+                             some token or other: never used)
+    ``pair``   int32 [R]     the (token, choice) pair of row r, flat
+    ``rank``   int32 [N, k]  the row of a pair (clipped into the buffer)
+    ``valid``  bool [N, k]   the pair's expert is held AND its row is
+                             inside the bound
+    ``sizes``  int32 [count] rows of each held expert inside the bound
+    ``load``   int32 [count] pairs routed to each held expert
+    ``rows``   int32 []      rows filled (the sum of ``sizes``)
+    ``dropped`` int32 []     held pairs past the bound
+    """
+    n, k = sel.shape
+    local = sel - first
+    held = (local >= 0) & (local < count)
+    key = jnp.where(held, local, count).reshape(-1)
+    pairs = lax.iota(jnp.int32, n * k)
+    # stable: inside an expert's group the pairs keep the tokens' order
+    _, order = lax.sort((key, pairs), num_keys=1, is_stable=True)
+    _, rank = lax.sort((order, pairs), num_keys=1)  # the inverse permutation
+    load = jnp.sum(key[:, None] == jnp.arange(count, dtype=jnp.int32),
+                   axis=0, dtype=jnp.int32)
+    ends = jnp.minimum(jnp.cumsum(load), row_bound)
+    sizes = ends - jnp.concatenate([jnp.zeros((1,), jnp.int32), ends[:-1]])
+    rank = rank.reshape(n, k)
+    valid = held & (rank < row_bound)
+    # a bound rounded up past the pairs there are: rows nobody fills
+    order = jnp.pad(order, (0, max(0, row_bound - n * k)))[:row_bound]
+    return {
+        "tok": order // k, "pair": order,
+        "rank": jnp.minimum(rank, row_bound - 1), "valid": valid,
+        "sizes": sizes, "load": load, "rows": ends[-1],
+        "dropped": jnp.sum(load) - ends[-1],
+    }
+
+
+# -- the two moves, gathers in both directions ---------------------------------
+
+
+def _sum_over_choices(rows, rank, valid, weight=None):
+    """[N, D] float32: each token's sum over its k choices of the row the
+    choice went to (x ``weight`` [N, k]), choices not ``valid`` left out."""
+    total = None
+    for j in range(rank.shape[1]):
+        part = rows[rank[:, j]].astype(jnp.float32)
+        if weight is not None:
+            part = part * weight[:, j, None]
+        part = jnp.where(valid[:, j, None], part, 0.0)
+        total = part if total is None else total + part
+    return total
+
+
+@jax.custom_vjp
+def _rows_of_tokens(u, tok, rank, valid):
+    """Dispatch: row r is token ``tok[r]``'s features."""
+    return u[tok]
+
+
+def _rows_of_tokens_fwd(u, tok, rank, valid):
+    return u[tok], (rank, valid, jnp.zeros((0,), u.dtype))
+
+
+def _rows_of_tokens_bwd(kept, d_rows):
+    rank, valid, like = kept
+    return (_sum_over_choices(d_rows, rank, valid).astype(like.dtype),
+            None, None, None)
+
+
+_rows_of_tokens.defvjp(_rows_of_tokens_fwd, _rows_of_tokens_bwd)
+
+
+@jax.custom_vjp
+def _tokens_of_rows(y, w, tok, pair, rank, valid, row_valid):
+    """Combine: token n is the sum of its valid choices' rows, each scaled by
+    the choice's weight, in float32."""
+    return _sum_over_choices(y, rank, valid, w)
+
+
+def _tokens_of_rows_fwd(y, w, tok, pair, rank, valid, row_valid):
+    return (_sum_over_choices(y, rank, valid, w),
+            (y, w, tok, pair, rank, valid, row_valid))
+
+
+def _tokens_of_rows_bwd(kept, d_out):
+    y, w, tok, pair, rank, valid, row_valid = kept
+    w_row = w.reshape(-1)[pair]
+    # the cotangent in the rows' dtype BEFORE the gather: it writes a row for
+    # every row of the buffer, filled or not (5.0 ms a layer for float32 rows
+    # at 131,072 x 2048; my chip run, PR 34)
+    d_y = jnp.where(row_valid[:, None],
+                    d_out.astype(y.dtype)[tok].astype(jnp.float32)
+                    * w_row[:, None], 0.0)
+    d_w = jnp.stack([
+        jnp.where(valid[:, j],
+                  jnp.sum(y[rank[:, j]].astype(jnp.float32)
+                          * d_out.astype(jnp.float32), axis=-1), 0.0)
+        for j in range(rank.shape[1])], axis=1)
+    return d_y.astype(y.dtype), d_w.astype(w.dtype), None, None, None, None, None
+
+
+_tokens_of_rows.defvjp(_tokens_of_rows_fwd, _tokens_of_rows_bwd)
+
+
+# -- the grouped product -------------------------------------------------------
+
+
+# one expert layer forward + backward at the cell's shapes (32,768 tokens of
+# 2048, 8 experts of 1792 held of 32, top-4, 29,296 pairs held, rows' bound
+# 131,072; benchmark/tools/moe_layer_bench.py, my chip run, PR 34): megablox
+# 54.0 ms a call, its six kernel calls 14.2 ms (69 % of the pairs' 9.8 ms of
+# needed FLOPs); ragged_dot 57.2 ms, its kernels 17.0 ms (58 %). Both are
+# over a third of their roofline, so no kernel of the repo's own; megablox on
+# the chip. Off it the Pallas interpreter does not run megablox's data-sized
+# grid, and XLA:CPU expands ragged_dot: the CPU tests run that.
+# With float32 operands (the benchmark's ``matched`` run) megablox's tiles
+# ask for 18 MB of the 16 MB of VMEM, forward at 1024 x 1024 and backward
+# at 512 x 1024 alike, and XLA's kernel is right to 8e-7 at ``highest``
+# (benchmark/tools/moe_layer_check.py, my chip run, PR 34): ragged_dot there.
+IMPL_WHY = ("megablox for 2-byte operands on a TPU (69 % of roofline against "
+            "58 %), ragged_dot for float32 ones and off a TPU")
+
+
+def default_impl(dtype=jnp.bfloat16) -> str:
+    """What ``impl=None`` means for operands of ``dtype`` (``IMPL_WHY``)."""
+    narrow = jnp.dtype(dtype).itemsize <= 2
+    return "megablox" if backend.on_tpu() and narrow else "ragged_dot"
+
+
+def grouped_dot(x, w, sizes, impl: str | None = None):
+    """``x[rows of group g] @ w[g]`` for each group: ``x`` [R, K] ordered by
+    group, ``w`` [G, K, N], ``sizes`` int32 [G] (their sum may be under R:
+    rows past it come back as whatever the kernel left there, and the
+    caller masks them). Operands in ``x``'s dtype, float32 accumulation,
+    the result in ``x``'s dtype."""
+    impl = impl or default_impl(x.dtype)
+    w = w.astype(x.dtype)
+    if impl == "ragged_dot":
+        return lax.ragged_dot(x, w, sizes, preferred_element_type=x.dtype)
+    if impl == "megablox":
+        from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+        return megablox.gmm(x, w, sizes, x.dtype, MEGABLOX_TILING,
+                            interpret=backend.pallas_interpret())
+    raise ValueError(f"grouped product {impl!r} is not one of {IMPLS}")
+
+
+# -- the layer -----------------------------------------------------------------
+
+
+def routed_experts(u, w_gate, bias, w13, w2, *, first: int, top_k: int,
+                   scaling: float = 1.0, row_bound: int | None = None,
+                   impl: str | None = None, scope: str = "experts"):
+    """This chip's part of the routed experts' result for tokens ``u``
+    [N, D]: ``(out float32 [N, D], report)``.
+
+    ``w_gate`` [D, E] and ``bias`` [E] are the router's, over ALL E experts;
+    ``w13`` [held, D, 2F] (gate | up) and ``w2`` [held, F, D] the experts
+    ``first .. first + held - 1``. ``report``: ``sel`` int32 [N, k] (every
+    token's choice), ``load`` float32 [held] (pairs routed to each held
+    expert), ``dropped`` float32 [] (held pairs past ``row_bound``: zero
+    at the default, tokens x k). ``bias`` comes back from a backward pass
+    with ``excess_load`` in its gradient's place (``_hand_bias``)."""
+    n, _ = u.shape
+    count, _, two_f = w13.shape
+    if row_bound is None:
+        row_bound = row_bound_for(n * top_k)
+    if first < 0 or first + count > w_gate.shape[1]:
+        raise ValueError(
+            f"experts {first}..{first + count - 1} are not among the "
+            f"router's {w_gate.shape[1]}")
+    with jax.named_scope(f"{scope}.route"):
+        sel, w = route(u, w_gate, bias, top_k, scaling)
+        # the bias's "gradient": every expert's excess load
+        w = _hand_bias(w, bias, excess_load(sel, w_gate.shape[1]))
+    with jax.named_scope(f"{scope}.dispatch"):
+        p = plan(sel, first, count, row_bound)
+        # kept beside the choice they were made from: a recomputed block
+        # does not sort again
+        p["tok"], p["pair"], p["rank"] = (
+            checkpoint_name(p[key], KEPT) for key in ("tok", "pair", "rank"))
+        row_valid = lax.iota(jnp.int32, row_bound) < p["rows"]
+        x = _rows_of_tokens(u, p["tok"], p["rank"], p["valid"])
+    with jax.named_scope(f"{scope}.gmm"):
+        h = grouped_dot(x, w13, p["sizes"], impl)
+        gate, up = h[:, :two_f // 2], h[:, two_f // 2:]
+        # rows past the groups hold whatever the kernel left: zeroed here,
+        # in the pass that computes the activation anyway
+        a = jnp.where(row_valid[:, None], jax.nn.silu(gate) * up, 0)
+        y = grouped_dot(a.astype(u.dtype), w2, p["sizes"], impl)
+    with jax.named_scope(f"{scope}.combine"):
+        out = _tokens_of_rows(y, w, p["tok"], p["pair"], p["rank"],
+                              p["valid"], row_valid)
+    return out, {"sel": sel, "load": p["load"].astype(jnp.float32),
+                 "dropped": p["dropped"].astype(jnp.float32)}

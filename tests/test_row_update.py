@@ -12,7 +12,22 @@ from raydp_tpu.estimator import JaxEstimator, row_update
 from raydp_tpu.estimator.jax_estimator import _LOSSES, make_train_step
 from raydp_tpu.exchange import dataframe_to_dataset
 from raydp_tpu.ops import backend
-from tests.test_jax_estimator import criteo_df, session  # noqa: F401 - fixtures
+from tests.test_jax_estimator import criteo_df  # noqa: F401 - a fixture
+
+
+@pytest.fixture(scope="module")
+def session():
+    """An ETL session under this module's own name: on one xdist worker right
+    after tests/test_jax_estimator.py, whose session the same name stops, the
+    head can still hold that name ('already taken', every test here an
+    error)."""
+    import raydp_tpu
+
+    s = raydp_tpu.init_etl("test-row-update", num_executors=2,
+                           executor_cores=1, executor_memory="300M")
+    yield s
+    raydp_tpu.stop_etl()
+
 
 BATCH = 32
 # two tables above the shape rule (32 rows to a row of the batch), three

@@ -317,3 +317,92 @@ def test_hybridlm_epoch_program_fits_the_chip(
                  "flash_attention_bwd_dkv"):
         assert len(re.findall(rf"%[\w.\-]*{name}[\w.\-]* = ", text)) == 1, name
     assert _loss_products(text, "hybridlm.loss") == 3
+
+
+# -- the routed-experts LM's grouped products and epoch program ------------------
+
+
+@pytest.mark.parametrize("impl, kernel", [
+    ("ragged_dot", "ragged-dot"), ("megablox", "gmm")])
+def test_grouped_products_compile_at_lfm2_widths(
+        one_chip, no_compile_cache, kernels_compile, impl, kernel):
+    """[131,072 rows x 2048] x [8 experts, 2048, 3584] and its gradients
+    (the rows' and the weights'), bf16 operands, ragged groups: both
+    implementations of ``ops.experts.grouped_dot`` are Mosaic calls on the
+    chip (XLA:TPU runs ``lax.ragged_dot`` as a kernel of its own), named so
+    that ``harness/moe_costs.GMM`` finds them in a trace."""
+    from benchmark.harness import moe_costs
+    from raydp_tpu.ops import experts
+
+    x = jax.ShapeDtypeStruct((131072, 2048), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((8, 2048, 3584), jnp.float32, sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip)
+
+    def grads(x, w, sizes):
+        return jax.grad(lambda x, w: experts.grouped_dot(
+            x, w, sizes, impl).astype(jnp.float32).sum(), argnums=(0, 1))(x, w)
+
+    text = jax.jit(grads).lower(x, w, sizes).compile().as_text()
+    calls = re.findall(rf"(%[\w.\-]*{kernel}[\w.\-]*) = \S+ custom-call", text)
+    assert len([c for c in calls if "metadata" not in c]) == 2, calls
+    assert all(moe_costs.GMM.search(c) for c in calls)
+
+
+def test_routed_hybridlm_epoch_program_fits_the_chip(
+        one_chip, no_compile_cache, kernels_compile):
+    """ISSUE 34: the benchmark's epoch program of
+    ``lfm2-8b-a1b.pretrain-8k-routed`` (507.8 M float32 parameters, AdamW, 3
+    steps of 4 x 8192 tokens gathered from the resident rows and scanned,
+    parameters and optimizer state donated, the steps' report summed) for
+    the described v5e, at the WORST-CASE rows' bound (tokens x 4 = 131,072
+    rows an expert layer: no pair can be dropped), AdamW under its warm-up
+    with the balancing rule on the biases: within 15.5e9 bytes (13.01e9
+    here), one flash forward, one dq and one dk/dv call, and
+    the grouped product a Mosaic call 32 times (4 expert layers x (2 forward
+    + 2 recomputed + 4 backward))."""
+    import json
+    import os
+
+    from raydp_tpu.estimator.jax_estimator import (
+        MODEL_LOSS, _scan_over_batches, make_train_step)
+    from raydp_tpu.models import RoutedHybridLM, hybridlm_optimizer
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "lfm2-8b-a1b.json")) as f:
+        config = json.load(f)
+    steps, batch, tokens = 3, 4, 8192
+    module = RoutedHybridLM.from_config(config, **config["model"]["kwargs"])
+    assert module.expert_row_bound(batch * tokens) == batch * tokens * 4
+    on_chip = functools.partial(_on_chip, one_chip=one_chip)
+    rows = on_chip(jax.ShapeDtypeStruct((steps * batch, tokens + 1), jnp.int32))
+    perm = on_chip(jax.ShapeDtypeStruct((steps * batch,), jnp.int32))
+    params = on_chip(jax.eval_shape(
+        lambda r, s: module.init(r, s, None, method="loss"),
+        jax.random.PRNGKey(0), jax.ShapeDtypeStruct((1, tokens + 1), jnp.int32)))
+    assert sum(leaf.size for leaf in jax.tree.leaves(params)) == 507_820_288
+    # AdamW under the warm-up, the balancing rule on the biases
+    tx = hybridlm_optimizer(**config["model"]["adamw"])
+    state = on_chip(jax.eval_shape(tx.init, params))
+    step = make_train_step(module, MODEL_LOSS, tx)
+
+    def epoch(params, state, rows, perm):
+        return _scan_over_batches(
+            step, params, state,
+            rows[perm].reshape(steps, batch, tokens + 1), None)
+
+    compiled = jax.jit(epoch, donate_argnums=(0, 1)).lower(
+        params, state, rows, perm).compile()
+    memory = compiled.memory_analysis()
+    held = (memory.argument_size_in_bytes + memory.output_size_in_bytes
+            - memory.alias_size_in_bytes + memory.temp_size_in_bytes)
+    print("routed epoch program holds", held)
+    assert held <= 15.5e9, held
+    text = compiled.as_text()
+    for name in ("flash_attention_fwd", "flash_attention_bwd_dq",
+                 "flash_attention_bwd_dkv"):
+        assert len(re.findall(rf"%[\w.\-]*{name}[\w.\-]* = ", text)) == 1, name
+    assert len(re.findall(r"%[\w.\-]*gmm[\w.\-]* = \S+ custom-call", text)) == 32
+    assert _loss_products(text, "hybridlm.loss") == 3
+    # the epoch's report leaves the program: [expert layers, held] and a count
+    assert "f32[4,8]" in text.split("ENTRY")[1].split("\n")[0]
