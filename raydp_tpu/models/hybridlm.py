@@ -26,11 +26,13 @@ normalised; the experts ``first_expert ..
 first_expert + experts_held - 1`` are here and their part of the result is
 computed, for every pair routed to them, however uneven the load; what the
 absent experts would add is left out (one chip's share of an
-expert-parallel group, without its exchange). What the expert layers
-report of a step (``train_report``: each held expert's load, the pairs past
-the row buffer's bound: zero, the buffer holds tokens x ``experts_per_token``
-rows, every choice held) the estimator sums over an epoch's steps and hands
-to ``epoch_facts``.
+expert-parallel group, without its exchange). The layer cuts its rows'
+buffer to the load it sees (``ops.experts``: the likely bound, with the
+worst case, tokens x ``experts_per_token`` rows, as the path of an
+overflow). What the expert layers report of a step (``train_report``: each
+held expert's load; the pairs past the buffer's bound: zero, an overflow
+runs the worst case; the layers that did run it) the estimator sums over an
+epoch's steps and hands to ``epoch_facts``.
 
 ``conv`` (a gated short convolution)::
 
@@ -105,7 +107,8 @@ REMAT_KEEPS = SAVED_RESIDUALS + ("mlp_out",)
 # and the sort's three permutations, four int32 vectors of tokens x k, 2 MB
 # a layer at 32,768 tokens): the backward pass must not choose again (a
 # near-tie could fall the other way) and need not sort again; the first
-# grouped product's output (940 MB a layer at 131,072 rows) is recomputed
+# grouped product's output (294 MB a layer at the likely bound of 40,960
+# rows, 940 MB at the worst case's 131,072) is recomputed
 EXPERT_KEEPS = (experts_op.KEPT,)
 
 
@@ -150,7 +153,7 @@ class HybridLM(nn.Module):
     # what ``loss`` reports of a TRAINING step beside its loss, by name in
     # its ``aux``: the estimator sums these over an epoch's steps inside the
     # epoch program and gives the sums to ``epoch_facts``
-    train_report = ("expert_load", "pairs_dropped")
+    train_report = ("expert_load", "pairs_dropped", "layers_at_full_bound")
 
     @classmethod
     def from_config(cls, config: dict, **kw):
@@ -293,9 +296,21 @@ class HybridLM(nn.Module):
                 "out_proj": (inner, d), **after}
 
     def expert_row_bound(self, tokens: int) -> int:
-        """Rows of an expert layer's buffer for a batch of ``tokens``: the
-        worst case, every choice held, so that no pair can be dropped."""
+        """Rows of an expert layer's buffer for a batch of ``tokens`` IN THE
+        WORST CASE, every choice held: what the facts, the kept
+        permutations and the benchmark's readers count with, and the bound
+        of the layer's overflow path. It is no longer what the layer
+        allocates: that is ``expert_likely_row_bound`` wherever the batch's
+        load fits it (``ops.experts.routed_experts`` chooses by the load)."""
         return experts_op.row_bound_for(tokens * self.experts_per_token)
+
+    def expert_likely_row_bound(self, tokens: int) -> int:
+        """Rows the layer runs at wherever the load fits them
+        (``ops.experts.likely_row_bound``: ``SLACK`` x the even share of
+        the held experts); the worst case where every expert is held."""
+        return experts_op.likely_row_bound(
+            tokens * self.experts_per_token, self.experts_held,
+            self.experts_total)
 
     def setup(self):
         d = self.hidden_size
@@ -411,6 +426,7 @@ class HybridLM(nn.Module):
                 "experts.per_token": self.experts_per_token,
                 "experts.layers": self.expert_layers,
                 "experts.rows_per_row": self.expert_row_bound(t),
+                "experts.rows_likely_per_row": self.expert_likely_row_bound(t),
                 "experts.flops_per_row": parts["experts"],
                 "experts.flops_counted": "uniform share"})
         return facts
@@ -474,22 +490,28 @@ class HybridLM(nn.Module):
     def epoch_facts(self, report: dict, steps: int) -> dict:
         """What an epoch's summed ``train_report`` says, for the estimator's
         counters and gauges (``model.<name>``): ``report["expert_load"]``
-        [expert layers, held] pairs routed to each held expert and
-        ``report["pairs_dropped"]``, over ``steps`` steps."""
+        [expert layers, held] pairs routed to each held expert,
+        ``report["pairs_dropped"]`` and ``report["layers_at_full_bound"]``
+        (expert layers x steps whose load overflowed the likely rows' bound
+        and ran at the worst-case one), over ``steps`` steps."""
         if not self.expert_layers or not steps:
             return {}
         load = np.asarray(report["expert_load"], np.float64)
         dropped = float(np.sum(report["pairs_dropped"]))
+        overflows = float(np.sum(report["layers_at_full_bound"]))
         held = float(load.sum()) - dropped
         mean = np.maximum(load.mean(axis=1), 1e-9)
         return {
             "counters": {"experts.pairs_held": held,
                          "experts.pairs_dropped": dropped,
+                         "experts.layers_at_full_bound": overflows,
                          "experts.steps_reported": steps},
             "gauges": {
                 "experts.load_max_over_mean": float(
                     (load.max(axis=1) / mean).mean()),
-                "experts.pairs_held_per_step": held / steps}}
+                "experts.pairs_held_per_step": held / steps,
+                "experts.likely_bound_share": 1.0 - overflows / (
+                    steps * self.expert_layers)}}
 
     # -- pieces --------------------------------------------------------------
     def _dot(self, x, w):
@@ -583,7 +605,6 @@ class HybridLM(nn.Module):
                 y.reshape(b * t, d), w["router"], w["expert_bias"], w["w13"],
                 w["w2"], first=self.first_expert,
                 top_k=self.experts_per_token, scaling=self.routed_scaling,
-                row_bound=self.expert_row_bound(b * t),
                 scope="hybridlm.experts")
             report["sel"] = report["sel"].reshape(b, t, -1)
             return checkpoint_name(out.astype(self.dtype).reshape(b, t, d),
@@ -636,7 +657,8 @@ class HybridLM(nn.Module):
         """Mean next-token cross-entropy on ``x`` int32 [B, T+1] (inputs
         ``x[:, :-1]``, targets ``x[:, 1:]``; ``y`` is not used). Returns
         ``(loss, aux)``. With expert layers ``aux`` holds ``train_report``'s
-        two: ``expert_load`` [expert layers, held] and ``pairs_dropped``.
+        three: ``expert_load`` [expert layers, held], ``pairs_dropped`` and
+        ``layers_at_full_bound``.
         ``with_states`` adds ``hidden`` [B, T, D], the state the head read,
         and ``routing`` int32 [expert layers, B, T, k], every token's
         choice (for a comparison; not for a fit, whose evaluation would
@@ -648,7 +670,8 @@ class HybridLM(nn.Module):
         aux = {}
         if reports:
             aux.update(expert_load=reports["load"],
-                       pairs_dropped=reports["dropped"].sum())
+                       pairs_dropped=reports["dropped"].sum(),
+                       layers_at_full_bound=reports["full_bound"].sum())
         if with_states:
             aux["hidden"] = h
             if reports:

@@ -19,10 +19,28 @@ stable sort of the pairs' keys), their tokens gathered into rows, the rows
 run through two GROUPED matrix products over the ragged groups (``W1 | W3``
 stacked ``[held, D, 2F]``, then ``W2`` ``[held, F, D]``; operands in the
 compute dtype, float32 accumulation), and each row is scaled by its weight
-and added back to its token in float32. NO PAIR IS DROPPED for balance:
-there is no capacity factor. The rows' buffer has a static bound
-(``row_bound``; tokens x k, every choice held, is always enough); pairs
-beyond a smaller bound are left out AND COUNTED (``report["dropped"]``), so
+and added back to its token in float32 (the result in the tokens' dtype).
+NO PAIR IS DROPPED for balance: there is no capacity factor.
+
+THE ROWS' BUFFER FOLLOWS THE LOAD. Shapes are static and the load is not:
+tokens x k rows (every choice held) are always enough, and a layer that
+holds ``held`` of ``E`` experts fills ``held / E`` of them at the even load
+the balancing rule keeps. Every pass over the buffer outside the grouped
+kernels (the dispatch's gather, the activation, the cotangents' gathers)
+costs the same filled or not, so the layer cuts the buffer to the LIKELY
+BOUND, ``likely_row_bound``: ``SLACK`` x the even share, a whole number of
+row tiles, computed from what the layer is handed (tokens, k, the experts
+its weights stack, the router's width) and set by no caller. The sort runs
+first, at the worst-case bound (int32 keys, under 1 ms), and says how many
+rows are filled; everything after it (``_rows_pass``) is one function of a
+static bound, run under ``lax.cond`` at the likely bound where the filled
+rows fit it, AND AT THE WORST-CASE BOUND WHERE THEY DO NOT (the overflow's
+path, ``report["full_bound"]`` 1): nothing is dropped for any input, and
+the two arms do the same arithmetic on the same rows. A layer that holds
+every expert has no smaller likely bound and no conditional. The
+conditional has a backward pass of its own (``_rows_pass_by_load`` says
+why). ``row_bound=`` given by the caller keeps its meaning: that bound, no
+fallback, pairs beyond it left out AND COUNTED (``report["dropped"]``), so
 that a caller who chose a smaller bound can hold the count to zero.
 
 Both directions of both moves are GATHERS (``_rows_of_tokens``,
@@ -39,6 +57,9 @@ both visit only the row tiles the groups fill, whatever the bound.
 
 from __future__ import annotations
 
+import functools
+import math
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -50,6 +71,16 @@ IMPLS = ("ragged_dot", "megablox")
 # rows are visited a tile at a time: the bound is a multiple of it
 ROW_TILE = 512
 MEGABLOX_TILING = (ROW_TILE, 1024, 1024)  # rows, contraction, columns
+# the rows' buffer is cut to SLACK x the even share of the held experts (the
+# LIKELY bound; a load past it runs the worst-case bound, so SLACK decides
+# time and never a result). Per expert layer the held experts' pairs over the
+# even share, 3 seeds x 40 steps x 4 layers from seeded parameters under the
+# cell's balancing rule and warm-up (benchmark/tools/moe_load_drift.py, my
+# chip run, PR 35): at most 1.165 in a fit's first step, at most 1.047 from
+# its fourth step on. 1.25 clears both; a step alone took 673.0 ms at 1.25,
+# 679.0 at 1.5, 694.8 at 2 and 740.1 at the worst-case bound (4 x even),
+# all before ``_by_load``'s barrier
+SLACK = 1.25
 # the name under which a recomputed block keeps the layer's discrete part:
 # every token's choice and the sort's three permutations
 KEPT = "experts_perm"
@@ -264,39 +295,27 @@ def grouped_dot(x, w, sizes, impl: str | None = None):
 # -- the layer -----------------------------------------------------------------
 
 
-def routed_experts(u, w_gate, bias, w13, w2, *, first: int, top_k: int,
-                   scaling: float = 1.0, row_bound: int | None = None,
-                   impl: str | None = None, scope: str = "experts"):
-    """This chip's part of the routed experts' result for tokens ``u``
-    [N, D]: ``(out float32 [N, D], report)``.
+def likely_row_bound(pairs: int, held: int, experts: int) -> int:
+    """Rows of the buffer a layer that holds ``held`` of ``experts`` experts
+    runs at for ``pairs`` (token, choice) pairs, almost always: ``SLACK``
+    times the even share, a whole number of row tiles, never over the worst
+    case (which it is when every expert is held)."""
+    return min(row_bound_for(math.ceil(SLACK * pairs * held / experts)),
+               row_bound_for(pairs))
 
-    ``w_gate`` [D, E] and ``bias`` [E] are the router's, over ALL E experts;
-    ``w13`` [held, D, 2F] (gate | up) and ``w2`` [held, F, D] the experts
-    ``first .. first + held - 1``. ``report``: ``sel`` int32 [N, k] (every
-    token's choice), ``load`` float32 [held] (pairs routed to each held
-    expert), ``dropped`` float32 [] (held pairs past ``row_bound``: zero
-    at the default, tokens x k). ``bias`` comes back from a backward pass
-    with ``excess_load`` in its gradient's place (``_hand_bias``)."""
-    n, _ = u.shape
-    count, _, two_f = w13.shape
-    if row_bound is None:
-        row_bound = row_bound_for(n * top_k)
-    if first < 0 or first + count > w_gate.shape[1]:
-        raise ValueError(
-            f"experts {first}..{first + count - 1} are not among the "
-            f"router's {w_gate.shape[1]}")
-    with jax.named_scope(f"{scope}.route"):
-        sel, w = route(u, w_gate, bias, top_k, scaling)
-        # the bias's "gradient": every expert's excess load
-        w = _hand_bias(w, bias, excess_load(sel, w_gate.shape[1]))
+
+def _rows_pass(bound: int, impl, scope: str, u, w, w13, w2, p):
+    """Everything after the plan AT ``bound`` ROWS: dispatch, the two grouped
+    products with the activation between them, combine; [N, D] in ``u``'s
+    dtype. ``p`` is a plan made at ``bound`` rows or more; at fewer rows
+    than it was made for, every row it fills must lie inside ``bound`` (the
+    caller's predicate), so ``valid`` and ``sizes`` hold as they are."""
+    two_f = w13.shape[2]
+    tok, pair = p["tok"][:bound], p["pair"][:bound]
+    rank = jnp.minimum(p["rank"], bound - 1)
+    row_valid = lax.iota(jnp.int32, bound) < p["rows"]
     with jax.named_scope(f"{scope}.dispatch"):
-        p = plan(sel, first, count, row_bound)
-        # kept beside the choice they were made from: a recomputed block
-        # does not sort again
-        p["tok"], p["pair"], p["rank"] = (
-            checkpoint_name(p[key], KEPT) for key in ("tok", "pair", "rank"))
-        row_valid = lax.iota(jnp.int32, row_bound) < p["rows"]
-        x = _rows_of_tokens(u, p["tok"], p["rank"], p["valid"])
+        x = _rows_of_tokens(u, tok, rank, p["valid"])
     with jax.named_scope(f"{scope}.gmm"):
         h = grouped_dot(x, w13, p["sizes"], impl)
         gate, up = h[:, :two_f // 2], h[:, two_f // 2:]
@@ -305,7 +324,112 @@ def routed_experts(u, w_gate, bias, w13, w2, *, first: int, top_k: int,
         a = jnp.where(row_valid[:, None], jax.nn.silu(gate) * up, 0)
         y = grouped_dot(a.astype(u.dtype), w2, p["sizes"], impl)
     with jax.named_scope(f"{scope}.combine"):
-        out = _tokens_of_rows(y, w, p["tok"], p["pair"], p["rank"],
-                              p["valid"], row_valid)
-    return out, {"sel": sel, "load": p["load"].astype(jnp.float32),
-                 "dropped": p["dropped"].astype(jnp.float32)}
+        out = _tokens_of_rows(y, w, tok, pair, rank, p["valid"], row_valid)
+    # in the tokens' dtype HERE: a conditional's result is a buffer of its
+    # own, and a float32 one is written and read again where the caller's
+    # cast used to fuse into the combine
+    return out.astype(u.dtype)
+
+
+def _by_load(likely: int, p, at_bound, *operands):
+    """``at_bound(bound, *operands)`` at ``likely`` rows where the plan's
+    filled rows fit them, else at the rows the plan was made for. The
+    results leave through a barrier: without it XLA moves their consumers'
+    first elementwise step into both arms, and the arms return float32
+    buffers beside the results (the residual stream's sum forward, a product
+    of AdamW's on each expert weight's gradient backward): 672.2 ms a step
+    alone for 656.5 behind the barrier (my chip run, PR 35)."""
+    return lax.optimization_barrier(lax.cond(
+        p["rows"] <= likely, functools.partial(at_bound, likely),
+        functools.partial(at_bound, p["tok"].shape[0]), *operands))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _rows_pass_by_load(likely: int, impl, scope: str, u, w, w13, w2, p):
+    """``_rows_pass`` at ``likely`` rows where the load fits and at the
+    plan's own (worst-case) bound where it does not. ITS BACKWARD PASS IS
+    ITS OWN: autodiff through ``lax.cond`` returns BOTH branches' residuals
+    from the forward conditional, the branch not taken as zeros (x, h, a, y
+    at 131,072 rows: 2.5 GB of zeros a layer written by the branch that was
+    to spare them, and both sets held). Here the forward keeps its inputs
+    and nothing else, and the backward pass chooses its branch by the same
+    predicate and differentiates ``_rows_pass`` INSIDE the branch (one
+    forward of it there, which a recomputed block runs anyway: a block
+    that keeps the layer's output, as ``HybridLM``'s does, runs the pass
+    once forward and once in the backward pass, as before)."""
+    return _by_load(
+        likely, p, lambda bound, *a: _rows_pass(bound, impl, scope, *a),
+        u, w, w13, w2, p)
+
+
+def _rows_pass_by_load_fwd(likely, impl, scope, u, w, w13, w2, p):
+    return (_rows_pass_by_load(likely, impl, scope, u, w, w13, w2, p),
+            (u, w, w13, w2, p))
+
+
+def _rows_pass_by_load_bwd(likely, impl, scope, kept, d_out):
+    *inputs, p = kept
+
+    def back(bound, u, w, w13, w2, p, d_out):
+        _, pull = jax.vjp(
+            lambda *a: _rows_pass(bound, impl, scope, *a, p), u, w, w13, w2)
+        return pull(d_out)
+
+    return (*_by_load(likely, p, back, *inputs, p, d_out), None)
+
+
+_rows_pass_by_load.defvjp(_rows_pass_by_load_fwd, _rows_pass_by_load_bwd)
+
+
+def routed_experts(u, w_gate, bias, w13, w2, *, first: int, top_k: int,
+                   scaling: float = 1.0, row_bound: int | None = None,
+                   impl: str | None = None, scope: str = "experts"):
+    """This chip's part of the routed experts' result for tokens ``u``
+    [N, D]: ``(out [N, D] in ``u``'s dtype, report)``.
+
+    ``w_gate`` [D, E] and ``bias`` [E] are the router's, over ALL E experts;
+    ``w13`` [held, D, 2F] (gate | up) and ``w2`` [held, F, D] the experts
+    ``first .. first + held - 1``. ``row_bound=None``: the layer chooses
+    (``likely_row_bound`` where the batch's load fits it, tokens x k where
+    it does not: nothing dropped, whatever the load). ``row_bound`` given:
+    that bound and no other, pairs past it left out and counted.
+    ``report``: ``sel`` int32 [N, k] (every token's choice), ``load``
+    float32 [held] (pairs routed to each held expert), ``dropped`` float32
+    [] (held pairs past ``row_bound``: zero where the layer chose),
+    ``full_bound`` float32 [] (1 where the worst-case bound ran BECAUSE the
+    load overflowed the likely one, else 0). ``bias`` comes back from a
+    backward pass with ``excess_load`` in its gradient's place
+    (``_hand_bias``)."""
+    n, _ = u.shape
+    count = w13.shape[0]
+    total = w_gate.shape[1]
+    if first < 0 or first + count > total:
+        raise ValueError(
+            f"experts {first}..{first + count - 1} are not among the "
+            f"router's {total}")
+    worst = row_bound_for(n * top_k)
+    likely = likely_row_bound(n * top_k, count, total)
+    if row_bound is not None:
+        worst = likely = row_bound
+    with jax.named_scope(f"{scope}.route"):
+        sel, w = route(u, w_gate, bias, top_k, scaling)
+        # the bias's "gradient": every expert's excess load
+        w = _hand_bias(w, bias, excess_load(sel, total))
+    with jax.named_scope(f"{scope}.dispatch"):
+        p = plan(sel, first, count, worst)
+        # kept beside the choice they were made from: a recomputed block
+        # does not sort again
+        p["tok"], p["pair"], p["rank"] = (
+            checkpoint_name(p[key], KEPT) for key in ("tok", "pair", "rank"))
+    report = {"sel": sel, "load": p.pop("load").astype(jnp.float32),
+              "dropped": p.pop("dropped").astype(jnp.float32),
+              "full_bound": (p["rows"] > likely).astype(jnp.float32)}
+    # the experts' weights in the tokens' dtype BEFORE the conditional: their
+    # gradients leave it in that dtype, and the cast back to the parameters'
+    # fuses into the optimizer's update as it did without a conditional
+    w13, w2 = w13.astype(u.dtype), w2.astype(u.dtype)
+    if likely < worst:
+        out = _rows_pass_by_load(likely, impl, scope, u, w, w13, w2, p)
+    else:  # every expert held, or a bound given: one program, no conditional
+        out = _rows_pass(worst, impl, scope, u, w, w13, w2, p)
+    return out, report

@@ -157,6 +157,123 @@ def test_a_row_bound_too_small_is_counted_not_hidden(layer):
     assert experts.row_bound_for(100) == experts.ROW_TILE
 
 
+# -- the rows' bound follows the load ------------------------------------------
+
+# enough pairs for a likely bound under the worst case: 1024 pairs, experts
+# 2-3 of 8 held: 512 rows (SLACK x 256, a whole tile) for 1024
+MANY = 512
+
+
+@pytest.fixture(scope="module")
+def wide(layer):
+    """``layer`` with ``MANY`` tokens, and the same with a bias that sends
+    every token's two choices to the held experts 2-3: 1024 rows filled."""
+    u = jax.random.normal(jax.random.PRNGKey(5), (MANY, D))
+    crowded = jnp.zeros((E,)).at[jnp.asarray((2, 3))].set(10.0)
+    return {"even": {**layer, "u": u},
+            "overflow": {**layer, "u": u, "bias": crowded}}
+
+
+def _out_and_grads(p, wrap=lambda f: f, **kw):
+    """(out, report, the five gradients: u, w_gate, bias, w13, w2) of the
+    share 2-3; ``wrap`` is applied to the layer (a ``jax.checkpoint``)."""
+    def run(u, w_gate, bias, w13, w2):
+        out, report = wrap(lambda *a: share_of(
+            dict(zip(("u", "w_gate", "bias", "w13", "w2"), a)), 2, 2, **kw))(
+                u, w_gate, bias, w13, w2)
+        return (out * jnp.cos(jnp.arange(D))).sum(), (out, report)
+
+    (_, (out, report)), grads = jax.jit(jax.value_and_grad(
+        run, (0, 1, 2, 3, 4), has_aux=True))(
+            p["u"], p["w_gate"], p["bias"], p["w13"], p["w2"])
+    return out, report, grads
+
+
+def _same(got, want):
+    return all(np.array_equal(np.asarray(a), np.asarray(b))
+               for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)))
+
+
+def test_the_bounds_of_a_share_and_of_the_uncut_layer():
+    pairs = MANY * K
+    assert experts.likely_row_bound(pairs, 2, 8) == 512 < experts.row_bound_for(
+        pairs)
+    assert experts.likely_row_bound(pairs, 8, 8) == experts.row_bound_for(pairs)
+    # the cell's: 32,768 tokens, top-4, 8 of 32 held
+    assert experts.likely_row_bound(131072, 8, 32) == experts.row_bound_for(
+        int(experts.SLACK * 32768))
+    assert 1.25 <= experts.SLACK <= 2.0
+
+
+@pytest.mark.parametrize("load", ["even", "overflow"])
+def test_the_layer_at_its_own_bound_is_the_layer_at_the_worst_case(wide, load):
+    """With a share held the layer runs at the likely bound where the load
+    fits it and at the worst-case one where it does not (``full_bound`` 1,
+    nothing dropped): either way the output and all five gradients are the
+    explicit worst-case layer's, exactly."""
+    p = wide[load]
+    out, report, grads = _out_and_grads(p)
+    want = _out_and_grads(p, row_bound=experts.row_bound_for(MANY * K))
+    assert _same((out, grads), (want[0], want[2]))
+    rows = float(report["load"].sum())
+    assert float(report["dropped"]) == 0 and float(want[1]["full_bound"]) == 0
+    if load == "overflow":
+        assert rows == MANY * K and float(report["full_bound"]) == 1
+    else:
+        assert 0 < rows <= 512 and float(report["full_bound"]) == 0
+    assert bool(out.any()) and all(bool(g.any()) for g in grads)
+
+
+@pytest.mark.parametrize("load", ["even", "overflow"])
+def test_a_recomputed_layer_under_the_models_policy_has_the_same_gradients(
+        wide, load):
+    """Inside ``jax.checkpoint`` with the names a ``HybridLM`` block keeps,
+    both branches give the gradients of the layer without recomputation."""
+    from raydp_tpu.models import hybridlm
+
+    keeps = hybridlm.REMAT_KEEPS + hybridlm.EXPERT_KEEPS
+    policy = jax.checkpoint_policies.save_only_these_names(*keeps)
+    plain = _out_and_grads(wide[load])
+    again = _out_and_grads(
+        wide[load], wrap=lambda f: jax.checkpoint(f, policy=policy))
+    assert float(again[1]["full_bound"]) == (load == "overflow")
+    assert float(jnp.abs(again[0] - plain[0]).max()) <= 1e-6
+    for a, b in zip(again[2], plain[2]):
+        assert float(jnp.abs(a - b).max()) <= 1e-5 * max(
+            1.0, float(jnp.abs(b).max()))
+
+
+@pytest.mark.parametrize("first, count, conditionals", [(0, 8, 0), (2, 2, 2)])
+def test_a_layer_that_holds_every_expert_lowers_without_a_conditional(
+        wide, first, count, conditionals):
+    """``held == E``: the likely bound is the worst case and the program is
+    the one from before the bound followed the load; with a share held
+    there is one conditional forward and one in the backward pass, and no
+    array of the worst-case rows leaves either (no residual of the branch
+    not taken, written as zeros)."""
+    p = wide["even"]
+
+    def loss(u, w13):
+        return share_of({**p, "u": u, "w13": w13}, first, count)[0].sum()
+
+    def conds(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "cond":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from conds(sub)
+
+    found = list(conds(jax.make_jaxpr(jax.value_and_grad(loss, (0, 1)))(
+        p["u"], p["w13"]).jaxpr))
+    assert len(found) == conditionals
+    worst = experts.row_bound_for(MANY * K)
+    for eqn in found:
+        assert all(worst not in v.aval.shape for v in eqn.outvars)
+    text = jax.jit(jax.value_and_grad(loss, (0, 1))).lower(
+        p["u"], p["w13"]).as_text()
+    assert text.count("stablehlo.case") == conditionals
+
+
 @pytest.mark.parametrize("recomputed", [False, True])
 def test_the_bias_is_handed_every_experts_excess_load(layer, recomputed):
     """The bias enters a top-k and has no gradient of the loss: what comes
@@ -435,13 +552,35 @@ def test_the_models_flops_are_the_benchmarks_count(sizes):
 def test_epoch_facts_are_the_loads_own_words():
     m = model()
     said = m.epoch_facts({"expert_load": np.array([[30.0, 10.0], [20.0, 20.0]]),
-                          "pairs_dropped": np.array(0.0)}, steps=2)
+                          "pairs_dropped": np.array(0.0),
+                          "layers_at_full_bound": np.array(0.0)}, steps=2)
     assert said["counters"] == {"experts.pairs_held": 80.0,
                                 "experts.pairs_dropped": 0.0,
+                                "experts.layers_at_full_bound": 0.0,
                                 "experts.steps_reported": 2}
     assert said["gauges"]["experts.load_max_over_mean"] == pytest.approx(1.25)
     assert said["gauges"]["experts.pairs_held_per_step"] == 40.0
     assert HybridLM(vocab_size=8).epoch_facts({}, 3) == {}
+
+
+def test_epoch_facts_count_the_layers_that_ran_at_the_full_bound():
+    """A summed report of 3 steps in which 2 of the 4 x 3 layer-steps
+    overflowed: the counter's increment and the newest epoch's share."""
+    m = model()
+    assert m.train_report == ("expert_load", "pairs_dropped",
+                              "layers_at_full_bound")
+    said = m.epoch_facts({"expert_load": np.full((4, 2), 30.0),
+                          "pairs_dropped": np.array(0.0),
+                          "layers_at_full_bound": np.array(2.0)}, steps=3)
+    assert said["counters"]["experts.layers_at_full_bound"] == 2.0
+    assert said["gauges"]["experts.likely_bound_share"] == pytest.approx(
+        1 - 2 / 12)
+    facts = m.fit_facts(np.zeros((1, T + 1), np.int32))
+    assert facts["experts.rows_likely_per_row"] == m.expert_likely_row_bound(T)
+    published = RoutedHybridLM.from_config(_published())
+    assert published.expert_row_bound(32768) == 131072
+    assert published.expert_likely_row_bound(32768) == experts.row_bound_for(
+        int(experts.SLACK * 32768))
 
 
 # -- the estimator -------------------------------------------------------------

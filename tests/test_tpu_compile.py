@@ -354,12 +354,18 @@ def test_routed_hybridlm_epoch_program_fits_the_chip(
     ``lfm2-8b-a1b.pretrain-8k-routed`` (507.8 M float32 parameters, AdamW, 3
     steps of 4 x 8192 tokens gathered from the resident rows and scanned,
     parameters and optimizer state donated, the steps' report summed) for
-    the described v5e, at the WORST-CASE rows' bound (tokens x 4 = 131,072
-    rows an expert layer: no pair can be dropped), AdamW under its warm-up
-    with the balancing rule on the biases: within 15.5e9 bytes (13.01e9
-    here), one flash forward, one dq and one dk/dv call, and
-    the grouped product a Mosaic call 32 times (4 expert layers x (2 forward
-    + 2 recomputed + 4 backward))."""
+    the described v5e, AdamW under its warm-up with the balancing rule on
+    the biases. ISSUE 35: each expert layer runs at the LIKELY rows' bound
+    (SLACK x the even share) with the worst case (tokens x 4 = 131,072 rows:
+    no pair can be dropped) as the overflow's arm of a conditional, one
+    forward and one in the backward pass. The two arms' temporaries share
+    memory: the program holds what it held with the worst case alone
+    (13.01e9 bytes), one flash forward, one dq and one dk/dv call, and
+    the grouped product is a Mosaic call 32 times IN EITHER ARM (4 expert
+    layers x (2 forward + 2 in the backward pass's own forward + 4
+    backward): a third forward would make it 40), and no arm returns an
+    array of the worst-case rows (a residual of the arm not taken, written
+    as zeros)."""
     import json
     import os
 
@@ -373,7 +379,9 @@ def test_routed_hybridlm_epoch_program_fits_the_chip(
         config = json.load(f)
     steps, batch, tokens = 3, 4, 8192
     module = RoutedHybridLM.from_config(config, **config["model"]["kwargs"])
-    assert module.expert_row_bound(batch * tokens) == batch * tokens * 4
+    worst = module.expert_row_bound(batch * tokens)
+    assert worst == batch * tokens * 4
+    assert module.expert_likely_row_bound(batch * tokens) < worst
     on_chip = functools.partial(_on_chip, one_chip=one_chip)
     rows = on_chip(jax.ShapeDtypeStruct((steps * batch, tokens + 1), jnp.int32))
     perm = on_chip(jax.ShapeDtypeStruct((steps * batch,), jnp.int32))
@@ -397,12 +405,16 @@ def test_routed_hybridlm_epoch_program_fits_the_chip(
     held = (memory.argument_size_in_bytes + memory.output_size_in_bytes
             - memory.alias_size_in_bytes + memory.temp_size_in_bytes)
     print("routed epoch program holds", held)
-    assert held <= 15.5e9, held
+    # the parent's program, the worst case alone, held 13,006,128,128
+    assert held <= 13.05e9, held
     text = compiled.as_text()
     for name in ("flash_attention_fwd", "flash_attention_bwd_dq",
                  "flash_attention_bwd_dkv"):
         assert len(re.findall(rf"%[\w.\-]*{name}[\w.\-]* = ", text)) == 1, name
-    assert len(re.findall(r"%[\w.\-]*gmm[\w.\-]* = \S+ custom-call", text)) == 32
+    conditionals = re.findall(r"= (\(.*?\)) conditional\(", text)
+    assert len(conditionals) == 8
+    assert not any(f"[{worst}," in result for result in conditionals)
+    assert len(re.findall(r"%[\w.\-]*gmm[\w.\-]* = \S+ custom-call", text)) == 64
     assert _loss_products(text, "hybridlm.loss") == 3
     # the epoch's report leaves the program: [expert layers, held] and a count
     assert "f32[4,8]" in text.split("ENTRY")[1].split("\n")[0]
