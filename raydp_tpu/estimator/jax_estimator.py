@@ -16,6 +16,7 @@ parquet-vs-object-store path and ``stop_etl_after_conversion`` (:332-363),
 from __future__ import annotations
 
 import contextlib
+import functools
 import os
 import tempfile
 import time
@@ -155,6 +156,16 @@ def _put_stacked_batch(mesh, arr, shard_direct=True):
     )
 
 
+def _compiled_twin(jitted, *args):
+    """The ``jax.stages.Compiled`` of the program ``jitted(*args)`` runs:
+    something to ask for the program's text, and to note by weak reference
+    (``obs.profiler.note_program``); the function is still what gets called.
+    jax keeps one lowering and one executable a signature while the function
+    lives, so before the first call this compiles what the call would have
+    (the call then compiles nothing), and after it this compiles nothing."""
+    return jitted.lower(*args).compile()
+
+
 def make_train_step(module, loss_fn, tx, row_paths=(), kernel_paths=()):
     """The one train-step body that the scan runner, the stream runner and
     the per-step loop wrap: ``(params, opt_state, loss_sum, x, y) ->
@@ -183,11 +194,11 @@ def make_train_step(module, loss_fn, tx, row_paths=(), kernel_paths=()):
             return params2, opt_state2, loss_sum + loss, {}
 
         # stable names in the device trace (metadata only)
-        with jax.named_scope("loss_and_grad"):
+        with obs.device_scope("loss_and_grad"):
             (loss, aux), grads = jax.value_and_grad(
                 lambda p: objective(p, x, y), has_aux=True
             )(params)
-        with jax.named_scope("optimizer_update"):
+        with obs.device_scope("optimizer_update"):
             updates, opt_state2 = tx.update(grads, opt_state, params)
             params2 = optax.apply_updates(params, updates)
         report = {name: aux[name] for name in reported if name in aux}
@@ -584,9 +595,16 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
         cache, the FLOPs probe): span ``estimator.compile``, whose duration
         feeds ``compile_seconds_`` and the ``estimator.compile_seconds``
         counter, readable while a fit runs — no parallel perf_counter
-        bookkeeping."""
+        bookkeeping. A site that compiled a program the fit will run hands
+        it to what the span yields, ``compiled_as(program)``, as the last
+        thing it does: noted under ``what``, by weak reference, for whoever
+        asks what its instructions belong to
+        (``obs.profiler.device_scopes``). The FLOPs probe's program is never
+        run and is not noted."""
+        from raydp_tpu.obs import profiler
+
         with obs.span("estimator.compile", what=str(what)) as span:
-            yield span
+            yield functools.partial(profiler.note_program, what)
             if getattr(self, "_fit_facts", None):
                 span.set(**self._fit_facts)
             if self._row_plan is not None:  # a program of this fit's step
@@ -806,7 +824,7 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
             self._flops_per_step = float(
                 batch_size * self._fit_facts["flops_per_row"]
             )
-        with self._compile_span("init"):
+        with self._compile_span("init") as compiled_as:
             # one jitted init: flax init run eagerly compiles dozens of tiny
             # ops, which costs ~0.5s EACH on cold TPU backends (~30s total)
             sample = _fmap(jnp.asarray, sample_np)
@@ -815,10 +833,15 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                 if loss_fn == MODEL_LOSS
                 else module.init
             )
-            params, opt_state = jax.jit(
+            init_program = jax.jit(
                 lambda r, s: (lambda p: (p, tx.init(p)))(init(r, s))
-            )(rng, sample)
+            )
+            params, opt_state = init_program(rng, sample)
             jax.block_until_ready(params)
+            # run once and dropped here: in the scope map of a capture
+            # window armed over the fit, and of nobody else
+            compiled_as(_compiled_twin(init_program, rng, sample))
+            del init_program
         from raydp_tpu.parallel.partitioner import _mesh_device_count, _mesh_single_device
 
         if self.param_sharding_rules is not None:
@@ -1092,7 +1115,15 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                                 # separately
                                 if fit_capture is not None:
                                     fit_capture.begin_steps()
-                                with self._compile_span("first_step"):
+                                with self._compile_span(
+                                    "first_step"
+                                ) as compiled_as:
+                                    # lives as long as the fit's frame
+                                    first_step_program = _compiled_twin(
+                                        train_step, params, opt_state,
+                                        loss_sum, x, y,
+                                    )
+                                    compiled_as(first_step_program)
                                     params, opt_state, loss_sum = train_step(
                                         params, opt_state, loss_sum, x, y
                                     )
@@ -1721,10 +1752,11 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                     pending_save = None
                 length = _f0(xb).shape[0]
                 if length not in compiled:
-                    with self._compile_span(length):
+                    with self._compile_span(length) as compiled_as:
                         compiled[length] = jitted.lower(
                             params, opt_state, xb, yb
                         ).compile()
+                        compiled_as(compiled[length])
                     self._note_step_flops_abstract(
                         step_impl, params, opt_state,
                         _fmap(
@@ -1883,12 +1915,13 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                     order[start * batch_size : (start + length) * batch_size]
                 )
                 if length not in compiled:
-                    with self._compile_span(length):
+                    with self._compile_span(length) as compiled_as:
                         compiled[length] = (
                             make_gather(length)
                             .lower(params, opt_state, xs_dev, ys_dev, perm)
                             .compile()
                         )
+                        compiled_as(compiled[length])
                     _note_flops(params, opt_state)
                 if fit_capture is not None:
                     fit_capture.begin_steps()
@@ -1930,10 +1963,11 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                     "h2d", time.perf_counter() - t_h, steps=length
                 )
                 if length not in compiled:
-                    with self._compile_span(length):
+                    with self._compile_span(length) as compiled_as:
                         compiled[length] = jitted.lower(
                             params, opt_state, xb, yb
                         ).compile()
+                        compiled_as(compiled[length])
                     _note_flops(params, opt_state)
                 if fit_capture is not None:
                     fit_capture.begin_steps()
@@ -2067,7 +2101,10 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
             (ms, ls, c), aux = lax.scan(body, init, (xb, yb))
             return ms, ls, c, jax.tree.map(lambda a: a.sum(0) * rows, aux)
 
-        return eval_step, eval_scan
+        # the third: the evaluation's programs as noted for whoever asks
+        # what their instructions belong to (_evaluate_host), alive as long
+        # as the functions are
+        return eval_step, eval_scan, {}
 
     def _evaluate_host(
         self, source, params, eval_fns, mesh, batch_size
@@ -2078,7 +2115,20 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
         from raydp_tpu.exchange.jax_io import PrefetchingDeviceIterator
         from raydp_tpu.parallel.partitioner import _mesh_device_count
 
-        eval_step, eval_scan = eval_fns
+        eval_step, eval_scan, programs = eval_fns
+
+        def noted(what, jitted, *args):
+            # the evaluation has no compile site: a program is noted before
+            # its first call, by the shape of its batch (the features are
+            # the argument before the labels)
+            key = (what, _f0(args[-2]).shape)
+            if key not in programs:
+                from raydp_tpu.obs import profiler
+
+                programs[key] = _compiled_twin(jitted, *args)
+                profiler.note_program(what, programs[key])
+            return jitted(*args)
+
         mstate = self._metrics.init_state()
         loss_sum = jnp.zeros(())
         count = jnp.zeros(())
@@ -2135,15 +2185,18 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                     # one slot, like the train-set device cache: per-epoch
                     # eval must not re-upload the eval set every epoch
                     self._eval_device_stage = (source, batch_size, device, xb, yb)
-                mstate, loss_sum, count, aux = eval_scan(params, mstate, xb, yb)
+                mstate, loss_sum, count, aux = noted(
+                    "eval_scan", eval_scan, params, mstate, xb, yb
+                )
                 aux_sums.append(aux)
             if n % batch_size:
                 tail_x = _fmap(
                     lambda a: jnp.asarray(a[steps * batch_size :]), feats
                 )
                 tail_y = _lmap(lambda a: jnp.asarray(a[steps * batch_size :]), labs)
-                mstate, loss_sum, count, aux = eval_step(
-                    params, mstate, loss_sum, count, tail_x, tail_y
+                mstate, loss_sum, count, aux = noted(
+                    "eval_step", eval_step,
+                    params, mstate, loss_sum, count, tail_x, tail_y,
                 )
                 aux_sums.append(aux)
         else:
@@ -2151,8 +2204,9 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                 self._epoch_batches(source, batch_size, None, shuffle=False),
                 mesh, shard_direct=self.shard_direct,
             ):
-                mstate, loss_sum, count, aux = eval_step(
-                    params, mstate, loss_sum, count, x, y
+                mstate, loss_sum, count, aux = noted(
+                    "eval_step", eval_step,
+                    params, mstate, loss_sum, count, x, y,
                 )
                 aux_sums.append(aux)
         # one transfer for both scalars: separate float() calls would pay a

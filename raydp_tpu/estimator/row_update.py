@@ -31,6 +31,8 @@ from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
 
+from raydp_tpu import obs
+
 # A declared table takes the row path from this many rows per row of the
 # batch on. Measured on the v5e at batch 2048, embed 16, Adagrad, with the
 # scatter of _put below (PERF.md, Findings, PR 25, chip call E): the whole
@@ -242,7 +244,7 @@ def step(module, loss_fn, tx, paths, params, opt_state, x, y, kernel_paths=()):
     import jax
     import jax.numpy as jnp
 
-    with jax.named_scope("loss_and_grad"):
+    with obs.device_scope("loss_and_grad"):
         whole = _by_path(params)
         ids = module.row_gathers(x)
         uniq, inv = sorted_unique(
@@ -268,7 +270,7 @@ def step(module, loss_fn, tx, paths, params, opt_state, x, y, kernel_paths=()):
             return loss_fn(module.apply(variables, x, rows=gathered), y)
 
         loss, mini_grads = jax.value_and_grad(compute)(mini_params)
-    with jax.named_scope("optimizer_update"):
+    with obs.device_scope("optimizer_update"):
         params, opt_state = update_rows(
             tx, params, opt_state, mini_params, mini_grads, index_tree
         )

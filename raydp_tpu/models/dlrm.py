@@ -26,9 +26,9 @@ from __future__ import annotations
 from typing import Optional, Sequence
 
 import flax.linen as nn
-import jax
 import jax.numpy as jnp
 
+from raydp_tpu import obs
 from raydp_tpu.ops.backend import on_tpu
 from raydp_tpu.ops.interaction import dot_interaction, dot_interaction_fused
 
@@ -122,7 +122,7 @@ class DLRM(nn.Module):
             # the dp×tp path keeps the kernel instead of falling back
             use_pallas = on_tpu()
         # a stable name in the device trace, whichever path computes it
-        with jax.named_scope("dlrm_interaction"):
+        with obs.device_scope("dlrm_interaction"):
             interact = (
                 dot_interaction_fused(t) if use_pallas else dot_interaction(t)
             )

@@ -85,6 +85,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
+from raydp_tpu import obs
 from raydp_tpu.models.looplm import (
     LOSS_FACTS, apply_rope, chunked_cross_entropy, looplm_optimizer, rms_norm,
     rope_tables)
@@ -524,7 +525,7 @@ class HybridLM(nn.Module):
                 ).astype(h.dtype)
 
     def _attention(self, w, y):
-        with jax.named_scope("hybridlm.attention"):
+        with obs.device_scope("hybridlm.attention"):
             b, t, _ = y.shape
             dh, group = self.head_dim, self.num_heads // self.num_kv_heads
 
@@ -557,7 +558,7 @@ class HybridLM(nn.Module):
         return nn.silu(out).astype(x.dtype)
 
     def _mamba(self, w, u):
-        with jax.named_scope("hybridlm.mamba"):
+        with obs.device_scope("hybridlm.mamba"):
             b, t, _ = u.shape
             inner, n = self.mamba_inner, self.mamba_state
             heads, p = self.mamba_heads, self.mamba_head_dim
@@ -582,7 +583,7 @@ class HybridLM(nn.Module):
         """The gated short convolution: ``W_out(C * conv(B * x))``, the
         depthwise causal convolution (no bias, no activation) and both
         gates in float32."""
-        with jax.named_scope("hybridlm.conv"):
+        with obs.device_scope("hybridlm.conv"):
             k, t = self.conv_kernel, u.shape[1]
             bm, cm, x = jnp.split(
                 self._dot(u, w["in_proj"]).astype(jnp.float32), 3, axis=-1)
@@ -591,7 +592,7 @@ class HybridLM(nn.Module):
             return self._dot((cm * conv).astype(self.dtype), w["out_proj"])
 
     def _mlp(self, w, y):
-        with jax.named_scope("hybridlm.mlp"):
+        with obs.device_scope("hybridlm.mlp"):
             g, u = jnp.split(self._dot(y, w["w_in"]), 2, axis=-1)
             return checkpoint_name(self._dot(nn.silu(g) * u, w["w_out"]),
                                    "mlp_out")
@@ -599,7 +600,7 @@ class HybridLM(nn.Module):
     def _experts(self, w, y):
         """(this chip's part of the routed experts' result [B, T, D], what
         the layer reports: ``ops.experts.routed_experts``'s)."""
-        with jax.named_scope("hybridlm.experts"):
+        with obs.device_scope("hybridlm.experts"):
             b, t, d = y.shape
             out, report = experts_op.routed_experts(
                 y.reshape(b * t, d), w["router"], w["expert_bias"], w["w13"],

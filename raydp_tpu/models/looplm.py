@@ -52,6 +52,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
+from raydp_tpu import obs
 from raydp_tpu.models.transformer import _attend
 from raydp_tpu.ops.flash_attention import SAVED_RESIDUALS
 
@@ -194,7 +195,7 @@ def chunked_cross_entropy(h, w, contract: int, targets, chunk: int,
     n = targets.size
     if weight is None:
         weight = jnp.full(targets.shape, 1.0 / n, jnp.float32)
-    with jax.named_scope(scope):
+    with obs.device_scope(scope):
         total, ce = _head_cross_entropy(
             h.reshape(n, h.shape[-1]), w, targets.reshape(n),
             weight.reshape(n), contract, scale, chunk)
@@ -284,7 +285,7 @@ class LoopLM(nn.Module):
         return jnp.dot(x, w.astype(self.dtype))
 
     def _block(self, w, h, cos, sin):
-        with jax.named_scope("looplm.block"):
+        with obs.device_scope("looplm.block"):
             b, t, d = h.shape
             heads, eps = self.num_heads, self.rms_eps
 
@@ -368,7 +369,7 @@ class LoopLM(nn.Module):
             h = self._loop_step(h, cos, sin)
             return h, (h, self._gate(h))
 
-        with jax.named_scope("looplm.loop"):
+        with obs.device_scope("looplm.loop"):
             _, (hidden, lam) = lax.scan(step, h, None,
                                          length=self.loop_steps)
         # every exit's loss in ONE call, after the loop: its weight's

@@ -48,6 +48,7 @@ __all__ = [
     "collect",
     "current_context",
     "current_sinks",
+    "device_scope",
     "enabled",
     "explain_last_query",
     "export_trace",
@@ -110,6 +111,15 @@ def profile_fit(steps: int = 16, out_dir=None, jax_trace: bool = True):
     from raydp_tpu.obs.profiler import profile_fit as _profile_fit
 
     return _profile_fit(steps=steps, out_dir=out_dir, jax_trace=jax_trace)
+
+
+def device_scope(name: str):
+    """Name a region of a compiled program (obs/profiler.py): a
+    ``jax.named_scope`` whose name the profiler's scope map knows. Lazy
+    import: jax loads when the first scope is opened, under a trace."""
+    from raydp_tpu.obs.profiler import device_scope as _device_scope
+
+    return _device_scope(name)
 
 
 def sample_memory(force: bool = False):

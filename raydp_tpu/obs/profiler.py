@@ -28,6 +28,12 @@ Dapper-style complement to request tracing). Three pieces:
   value and the peak (``mem.rss_bytes`` / ``mem.rss_bytes.max`` series).
   Crash dossiers attach the per-process ``mem.*`` tails; the elasticity
   and serve-autoscaler controllers read ``mem.pressure`` before growing.
+- **Device scopes** (:func:`device_scope` / :func:`note_program` /
+  :func:`device_scopes`): the one way to name a region of a compiled
+  program, the list of those names, and, for whoever asks, what every
+  instruction of the compiled programs still alive belongs to. A device
+  trace's events carry an instruction's HLO line and no scope; joined to
+  this map by instruction name they give device time by scope.
 
 Stdlib-only at import (jax strictly on demand, and NEVER imported by the
 memory sampler — a ``python -S`` worker without jax must flush cleanly).
@@ -36,12 +42,15 @@ memory sampler — a ``python -S`` worker without jax must flush cleanly).
 from __future__ import annotations
 
 import collections
+import itertools
 import json
 import os
+import re
 import sys
 import threading
 import time
-from typing import Any, Dict, List, Optional
+import weakref
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 from raydp_tpu.obs.metrics import metrics
 
@@ -293,7 +302,10 @@ class CaptureWindow:
     entering thread (span-only capture — the guaranteed floor when
     ``jax.profiler`` is unavailable, disabled via ``RAYDP_TPU_JAX_PROFILER=0``,
     or the backend refuses to trace) and written to
-    ``<out_dir>/spans.json`` at exit. ``result()`` summarizes."""
+    ``<out_dir>/spans.json`` at exit, with ``device_scopes.json`` beside it
+    (:func:`device_scopes` of the programs compiled inside the window and of
+    those still alive: what the deep trace's events are joined to).
+    ``result()`` summarizes."""
 
     def __init__(self, steps: Optional[int] = None,
                  out_dir: Optional[str] = None, jax_trace: bool = True):
@@ -313,6 +325,10 @@ class CaptureWindow:
         self._budget_done = False  # step budget exhausted: stay stopped
         self._seen_steps = 0
         self.path: Optional[str] = None
+        # compiled programs noted while the window is armed (note_program):
+        # alive until the window has written what their instructions belong to
+        self.pinned: List[Any] = []
+        self.device_scopes_path: Optional[str] = None
 
     # -- jax trace half --------------------------------------------------
 
@@ -385,8 +401,15 @@ class CaptureWindow:
             with open(path, "w") as f:
                 json.dump(self.records, f, default=str)
             self.path = path
+            # what each instruction of the compiled programs belongs to: the
+            # deep trace's events carry instruction names and no scope
+            path = os.path.join(self.out_dir, "device_scopes.json")
+            with open(path, "w") as f:
+                json.dump(device_scopes(), f)
+            self.device_scopes_path = path
         except OSError:  # raydp-lint: disable=swallowed-exceptions (a full disk must not fail the profiled fit; the records stay in memory)
-            self.path = None
+            self.device_scopes_path = None
+        self.pinned = []
         return False
 
     def result(self) -> dict:
@@ -395,6 +418,7 @@ class CaptureWindow:
             "spans_path": self.path,
             "span_records": len(self.records),
             "jax_trace_dir": self.jax_trace_dir,
+            "device_scopes_path": self.device_scopes_path,
             "steps_captured": self._seen_steps if self.steps else None,
         }
 
@@ -417,6 +441,235 @@ def capture(out_dir: Optional[str] = None,
     """Bracket-style capture (no step budget): used by the serve replica's
     ``profile()`` and any tool that wants one region deep-traced."""
     return CaptureWindow(steps=None, out_dir=out_dir, jax_trace=jax_trace)
+
+
+# ---------------------------------------------------------------------------
+# device scopes (what each instruction of a compiled program belongs to)
+# ---------------------------------------------------------------------------
+
+# every name a device scope was opened under in this process, in order: what
+# a reader of an ``op_name`` treats as a scope. ``jit(..)``, ``jvp(..)``,
+# ``transpose(..)``, ``checkpoint``, ``rematted_computation``, ``while`` /
+# ``body`` / ``cond`` / ``branch_*`` and the primitive's own name at the end
+# are not in it
+_scope_names: Dict[str, None] = {}
+
+
+def device_scope(name: str):
+    """``jax.named_scope(name)``, with ``name`` noted as a device scope: the
+    one way the estimator, the models and the ops name a region of a
+    compiled program (``obs.device_scope``). The name lands in the
+    ``op_name`` of every instruction traced under it, through ``jvp``,
+    ``transpose`` and ``checkpoint``; it is noted when the scope is opened,
+    at trace time, which a load from the compile cache does not skip."""
+    import jax
+
+    _scope_names[name] = None
+    return jax.named_scope(name)
+
+
+def registered_scopes() -> Tuple[str, ...]:
+    """The names device scopes were opened under so far, in order."""
+    return tuple(_scope_names)
+
+
+class _Program:
+    """A compiled program somebody may ask about: held WEAKLY (a strong
+    reference would keep a finished fit's executables in device memory)."""
+
+    __slots__ = ("what", "seq", "ref", "scopes")
+
+    def __init__(self, what: str, seq: int, program: Any):
+        self.what, self.seq = what, seq
+        self.ref = weakref.ref(program, lambda _ref: _programs.pop(seq, None))
+        self.scopes: Optional[Dict[str, dict]] = None  # read when asked
+
+
+_programs: Dict[int, _Program] = {}
+_program_seq = itertools.count(1)
+
+
+def note_program(what: Any, program: Any) -> None:
+    """Note a compiled program (a ``jax.stages.Compiled``: anything with
+    ``as_text()`` that can be weakly referenced) under ``what``, the
+    ``estimator.compile`` span's. One dictionary write; nothing reads the
+    program until :func:`device_scopes` is asked, and it is forgotten when
+    its owner drops it. While a capture window is armed it pins what is
+    noted, so that the window can still write the map after the fit that
+    owned the programs has returned."""
+    seq = next(_program_seq)
+    _programs[seq] = _Program(str(what), seq, program)
+    capture = _armed_capture
+    if capture is not None:
+        capture.pinned.append(program)
+
+
+def device_scopes() -> Dict[str, Dict[str, dict]]:
+    """What each instruction of the compiled programs still alive belongs
+    to: ``{"<what>#<n>": {instruction name: {"result": <result type>,
+    "scopes": [outermost, ..., innermost registered scope]}}}``, ``n``
+    counting the programs noted in this process. Covered: the entry
+    computation and every called computation a device trace shows as events
+    of its own (loop and conditional bodies, calls); NOT the insides of a
+    fusion, which is one event: it belongs to the scopes of the instruction
+    XLA names it by (its root, or the product it was built around; where
+    that name carries none: the last fused instruction's that has any), and
+    carries ``"mixed": True`` where a fused instruction's innermost scope is
+    not among them. A Mosaic call or a copy the compiler inserted is an
+    instruction like any other; one with no ``op_name``, or with no
+    registered scope in it, has ``"scopes": []``. ``result`` is the result
+    type with layouts left out (``f32[2048,16]``, ``(f32[9,16], f32[9])``):
+    with the name it tells two programs' ``%fusion.12`` apart. Reads each
+    program's text once (seconds for a program of 30,000 instructions); a
+    program that was collected, or whose text cannot be had, is left out."""
+    out: Dict[str, Dict[str, dict]] = {}
+    # in the order noted (a dict keeps it); a copy: a collection may pop one
+    for entry in list(_programs.values()):
+        program = entry.ref()
+        if program is None:
+            continue
+        if entry.scopes is None:
+            try:
+                text = program.as_text()
+            except Exception:  # raydp-lint: disable=swallowed-exceptions (a backend that cannot print a program must not fail whoever asked about the others)
+                continue
+            entry.scopes = scopes_in_text(text)
+        out[f"{entry.what}#{entry.seq}"] = entry.scopes
+    return out
+
+
+_INSTRUCTION = re.compile(r"^\s+(ROOT\s+)?%?([^\s=]+)\s+=\s+(.*)$")
+_COMPUTATION = re.compile(r"^(ENTRY\s+)?%?([^\s(]+)\s+\(.*->.*\{\s*$")
+_OPCODE = re.compile(r"(?:^|\s)([a-z][a-z0-9\-]*)\(")
+_LAYOUT = re.compile(r"\{[^}]*\}")
+_FLAT_TUPLE = re.compile(r"\(([^()]*)\)")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLED = re.compile(
+    r"\b(?:condition|body|to_apply|calls|true_computation|"
+    r"false_computation)=%?([^\s,)}]+)")
+_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
+_NAME_PARTS = re.compile(r"[/()]")
+# whose called computations a device trace shows as events of their own
+_SHOWN_CALLERS = ("while", "conditional", "call", "async-start")
+_NO_WORK = ("parameter", "constant", "tuple", "get-tuple-element")
+
+
+class _Instruction(NamedTuple):
+    name: str
+    result: str
+    opcode: str
+    chain: List[str]  # the registered scopes in its op_name
+    is_root: bool
+    rest: str  # the line after "=": what it calls is read from it
+
+
+def _result_type(type_text: str) -> str:
+    """A result type with layouts left out, by the rule the benchmark's
+    trace reader applies to an event's HLO line (a nested tuple, which only
+    containers have, gives "")."""
+    bare = _LAYOUT.sub("", type_text).strip()
+    flat = _FLAT_TUPLE.match(bare)
+    if flat:
+        return f"({flat.group(1)})"
+    return bare.split(" ")[0].split("(")[0]
+
+
+def scope_chain(op_name: str, names=None) -> List[str]:
+    """The registered scopes in an ``op_name``, outermost first. A scope
+    stands between slashes or inside a transform's brackets
+    (``jit(f)/loss_and_grad/transpose(jvp(ssd))/mul``); the last part is the
+    primitive's own name."""
+    names = _scope_names if names is None else names
+    chain: List[str] = []
+    for part in _NAME_PARTS.split(op_name.rpartition("/")[0]):
+        if part in names and part not in chain:
+            chain.append(part)
+    return chain
+
+
+def _fusion_scopes(own: List[str], fused: List[_Instruction]):
+    """(the scopes a fusion belongs to, whether it is mixed). The scopes of
+    the instruction XLA itself names the fusion by, its own ``op_name``: the
+    root's for an elementwise fusion, the product's for one built around a
+    convolution, whatever was fused in behind it (an optimizer's update of
+    a weight behind that weight's gradient product: the event is mostly the
+    product, and it stays with the layer). Where the compiler left that
+    name without a scope (a root of its own: a tuple of two results, a
+    convert, an expanded gather named "gather"): the root's as the text has
+    it, then the last fused instruction's that has any."""
+    root = next((f.chain for f in fused if f.is_root), [])
+    chain = own or root or next(
+        (f.chain for f in reversed(fused) if f.chain), [])
+    mixed = any(f.chain and f.chain[-1] not in chain
+                for f in fused if f.opcode not in _NO_WORK)
+    return chain, mixed
+
+
+def scopes_in_text(text: str, names=None) -> Dict[str, dict]:
+    """:func:`device_scopes` for one program's text (``Compiled.as_text()``);
+    ``names`` stands in for the registry."""
+    names = _scope_names if names is None else names
+    computations: Dict[str, List[_Instruction]] = {}
+    entry = current = None
+    for line in text.splitlines():
+        if not line:
+            continue
+        if line[0] not in " \t":
+            head = _COMPUTATION.match(line)
+            current = None
+            if head:
+                current = computations.setdefault(head.group(2), [])
+                if head.group(1):
+                    entry = head.group(2)
+            continue
+        found = _INSTRUCTION.match(line) if current is not None else None
+        if not found:
+            continue
+        rest = found.group(3)
+        opcode = _OPCODE.search(rest)
+        op_name = _OP_NAME.search(rest)
+        current.append(_Instruction(
+            found.group(2),
+            _result_type(rest[:opcode.start()] if opcode else rest),
+            opcode.group(1) if opcode else "",
+            scope_chain(op_name.group(1), names) if op_name else [],
+            bool(found.group(1)), rest,
+        ))
+
+    def called(rest):
+        branches = _BRANCHES.search(rest)
+        listed = [] if not branches else [
+            name.strip().lstrip("%") for name in branches.group(1).split(",")]
+        return listed + _CALLED.findall(rest)
+
+    def fused_by(rest, depth=0):
+        # a fusion's instructions in order, a fusion inside it opened up
+        found = []
+        for instruction in (
+                i for c in called(rest) for i in computations.get(c, ())):
+            found.append(instruction)
+            if instruction.opcode == "fusion" and depth < 8:
+                found.extend(fused_by(instruction.rest, depth + 1))
+        return found
+
+    out: Dict[str, dict] = {}
+    pending, seen = [entry] if entry else [], set()
+    while pending:
+        name = pending.pop()
+        if name in seen or name not in computations:
+            continue
+        seen.add(name)
+        for instruction in computations[name]:
+            chain, rest = instruction.chain, instruction.rest
+            said = {"result": instruction.result, "scopes": chain}
+            if instruction.opcode in _SHOWN_CALLERS:
+                pending.extend(called(rest))
+            elif instruction.opcode == "fusion":
+                said["scopes"], mixed = _fusion_scopes(chain, fused_by(rest))
+                if mixed:
+                    said["mixed"] = True
+            out[instruction.name] = said
+    return out
 
 
 # ---------------------------------------------------------------------------

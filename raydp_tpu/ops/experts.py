@@ -65,6 +65,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
+from raydp_tpu import obs
 from raydp_tpu.ops import backend
 
 IMPLS = ("ragged_dot", "megablox")
@@ -314,16 +315,16 @@ def _rows_pass(bound: int, impl, scope: str, u, w, w13, w2, p):
     tok, pair = p["tok"][:bound], p["pair"][:bound]
     rank = jnp.minimum(p["rank"], bound - 1)
     row_valid = lax.iota(jnp.int32, bound) < p["rows"]
-    with jax.named_scope(f"{scope}.dispatch"):
+    with obs.device_scope(f"{scope}.dispatch"):
         x = _rows_of_tokens(u, tok, rank, p["valid"])
-    with jax.named_scope(f"{scope}.gmm"):
+    with obs.device_scope(f"{scope}.gmm"):
         h = grouped_dot(x, w13, p["sizes"], impl)
         gate, up = h[:, :two_f // 2], h[:, two_f // 2:]
         # rows past the groups hold whatever the kernel left: zeroed here,
         # in the pass that computes the activation anyway
         a = jnp.where(row_valid[:, None], jax.nn.silu(gate) * up, 0)
         y = grouped_dot(a.astype(u.dtype), w2, p["sizes"], impl)
-    with jax.named_scope(f"{scope}.combine"):
+    with obs.device_scope(f"{scope}.combine"):
         out = _tokens_of_rows(y, w, tok, pair, rank, p["valid"], row_valid)
     # in the tokens' dtype HERE: a conditional's result is a buffer of its
     # own, and a float32 one is written and read again where the caller's
@@ -411,11 +412,11 @@ def routed_experts(u, w_gate, bias, w13, w2, *, first: int, top_k: int,
     likely = likely_row_bound(n * top_k, count, total)
     if row_bound is not None:
         worst = likely = row_bound
-    with jax.named_scope(f"{scope}.route"):
+    with obs.device_scope(f"{scope}.route"):
         sel, w = route(u, w_gate, bias, top_k, scaling)
         # the bias's "gradient": every expert's excess load
         w = _hand_bias(w, bias, excess_load(sel, total))
-    with jax.named_scope(f"{scope}.dispatch"):
+    with obs.device_scope(f"{scope}.dispatch"):
         p = plan(sel, first, count, worst)
         # kept beside the choice they were made from: a recomputed block
         # does not sort again

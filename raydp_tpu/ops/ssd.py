@@ -23,17 +23,18 @@ cumulative products: with the published initialisation one chunk's
 quotient 0 / 0. Decays, their sums and the state are float32; the matmuls'
 operands are ``x``'s dtype (bf16 on the chip) with float32 accumulation.
 
-The whole region runs under ``jax.named_scope("ssd")`` and its result
-carries the ``checkpoint_name`` ``SAVED_OUTPUT``, so that a trace reader and
-a save-by-name ``jax.checkpoint`` policy can find it.
+The whole region runs under the device scope ``ssd`` (``obs.device_scope``)
+and its result carries the ``checkpoint_name`` ``SAVED_OUTPUT``, so that a
+trace reader and a save-by-name ``jax.checkpoint`` policy can find it.
 """
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
+
+from raydp_tpu import obs
 
 SCOPE = "ssd"
 SAVED_OUTPUT = "ssd_out"
@@ -51,7 +52,7 @@ def ssd_chunk_scan(x, dt, A, B, C, D, chunk: int):
         raise ValueError(f"chunk {q} does not divide the sequence length {t}")
     c = t // q
     dtype, f32 = x.dtype, jnp.float32
-    with jax.named_scope(SCOPE):
+    with obs.device_scope(SCOPE):
         dt = dt.astype(f32)
         # head-major inside a chunk: [b, c, h, q]
         log_a = (dt * A.astype(f32)).reshape(b, c, q, h).transpose(0, 1, 3, 2)

@@ -429,6 +429,332 @@ def test_capture_window_exclusive(tmp_path):
 
 
 # ---------------------------------------------------------------------------
+# device scopes: what each instruction of a compiled program belongs to
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture()
+def fresh_scopes(monkeypatch):
+    """A registry and a programs' table of this test's own."""
+    monkeypatch.setattr(profiler, "_scope_names", {})
+    monkeypatch.setattr(profiler, "_programs", {})
+    return profiler
+
+
+def _scoped_toy():
+    """A step with nested scopes under grad(checkpoint(..)) inside a scan,
+    an optimizer half, and an instruction outside every scope."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    def step(w, x):
+        def loss(w):
+            def body(h, _):
+                with obs.device_scope("toy.block"):
+                    with obs.device_scope("toy.block.mix"):
+                        h = jnp.tanh(h @ w)
+                    with obs.device_scope("toy.block.act"):
+                        h = h * 2.0 + 1.0
+                return h, None
+
+            h, _ = lax.scan(jax.checkpoint(body), x, None, length=3)
+            with obs.device_scope("toy.loss"):
+                return (h ** 2).sum()
+
+        with obs.device_scope("toy.grad"):
+            value, grad = jax.value_and_grad(loss)(w)
+        with obs.device_scope("toy.update"):
+            w = w - 0.1 * grad
+        return w, value, jnp.flip(x, 0)  # the last: outside every scope
+
+    w, x = jnp.ones((64, 64)), jnp.ones((8, 64))
+    return jax.jit(step).lower(w, x).compile()
+
+
+def _op_names(text):
+    import re
+
+    return {m.group(1): m.group(2) for m in re.finditer(
+        r"^\s+(?:ROOT\s+)?%?([^\s=]+) = .*op_name=\"([^\"]*)\"", text, re.M)}
+
+
+def test_device_scopes_follow_grad_checkpoint_and_scan(fresh_scopes):
+    compiled = _scoped_toy()
+    assert profiler.registered_scopes() == (
+        "toy.grad", "toy.block", "toy.block.mix", "toy.block.act",
+        "toy.loss", "toy.update")
+    profiler.note_program("toy", compiled)
+    (key, said), = profiler.device_scopes().items()
+    assert key.startswith("toy#")
+    names = _op_names(compiled.as_text())
+    mix = ["toy.grad", "toy.block", "toy.block.mix"]
+    products = {"forward": [], "recomputed": [], "backward": []}
+    for name, op_name in names.items():
+        if name in said and "toy.block.mix" in op_name and "dot" in name:
+            kind = ("recomputed" if "rematted_computation" in op_name else
+                    "backward" if "transpose(" in op_name else "forward")
+            products[kind].append(name)
+    for kind, found in products.items():
+        assert found, (kind, "no product of that pass in the map")
+        for name in found:
+            assert said[name]["scopes"] == mix, (kind, name)
+            assert said[name]["result"] in ("f32[8,64]", "f32[64,64]")
+    # every one of them lies in a loop's body: called computations are walked
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY"):]
+    assert not any(f"%{name} = " in entry for name in products["forward"])
+    # one elementwise fusion holds the activation and the product's tanh
+    mixed = [v for v in said.values() if v.get("mixed")]
+    assert mixed and any(v["scopes"][-1] == "toy.block.act" for v in mixed)
+    # the half outside the gradient, and the instruction outside everything
+    assert any(v["scopes"] == ["toy.update"] for v in said.values())
+    flips = [name for name, op_name in names.items()
+             if name in said and op_name.endswith("/rev")]
+    assert flips and all(said[name]["scopes"] == [] for name in flips)
+    # parameters and compiler-made instructions carry no op_name: no scope
+    assert all(v["scopes"] == [] for name, v in said.items()
+               if name not in names)
+    # asked again: the text is not read again
+    reads = []
+    compiled.as_text = lambda: reads.append(1)  # noqa: E731
+    assert profiler.device_scopes()[key] is said and not reads
+
+
+def test_a_collected_program_is_left_out_and_nothing_raises(fresh_scopes):
+    import gc
+
+    class Unprintable:
+        def as_text(self):
+            raise RuntimeError("no text on this backend")
+
+    kept, broken = _scoped_toy(), Unprintable()
+    profiler.note_program("kept", kept)
+    profiler.note_program("gone", _scoped_toy())
+    profiler.note_program("broken", broken)
+    gc.collect()
+    assert [k.partition("#")[0] for k in profiler.device_scopes()] == ["kept"]
+    del kept
+    gc.collect()
+    assert profiler.device_scopes() == {} and not profiler._programs.keys() - {
+        entry.seq for entry in profiler._programs.values()
+        if entry.what == "broken"}
+
+
+def test_scope_chain_reads_names_inside_transforms():
+    names = {"loss_and_grad", "hybridlm.experts", "hybridlm.experts.gmm",
+             "ssd", "dot_general"}
+    assert profiler.scope_chain(
+        "jit(f)/loss_and_grad/transpose(jvp())/checkpoint/"
+        "rematted_computation/hybridlm.experts/hybridlm.experts.gmm/"
+        "dot_general", names) == [
+            "loss_and_grad", "hybridlm.experts", "hybridlm.experts.gmm"]
+    assert profiler.scope_chain(
+        "jit(f)/loss_and_grad/transpose(jvp(ssd))/mul", names) == [
+            "loss_and_grad", "ssd"]
+    # the primitive's own name at the end is not a scope, whatever it is
+    assert profiler.scope_chain("jit(f)/while/body/ssd", names) == []
+    assert profiler.scope_chain("gather", names) == []
+
+
+_FUSED_UPDATE = """
+%fused_computation.1 (p.0: f32[8,8], p.1: bf16[4,8], p.2: bf16[4,8]) -> (f32[8,8], f32[8,8]) {
+  %p.0 = f32[8,8]{1,0} parameter(0)
+  %p.1 = bf16[4,8]{1,0} parameter(1)
+  %p.2 = bf16[4,8]{1,0} parameter(2)
+  %convolution.3 = f32[8,8]{1,0} convolution(%p.1, %p.2), dim_labels=fb_io->bf, metadata={op_name="jit(f)/loss_and_grad/transpose(jvp(m.mlp))/dot_general"}
+  %mul.4 = f32[8,8]{1,0} multiply(%convolution.3, %p.0), metadata={op_name="jit(f)/optimizer_update/mul"}
+  %add.5 = f32[8,8]{1,0} add(%mul.4, %p.0), metadata={op_name="jit(f)/optimizer_update/add"}
+  ROOT %tuple.6 = (f32[8,8]{1,0}, f32[8,8]{1,0}) tuple(%add.5, %mul.4)
+}
+
+ENTRY %main.9 (a: f32[8,8], b: bf16[4,8], c: bf16[4,8]) -> (f32[8,8], f32[8,8]) {
+  %a = f32[8,8]{1,0} parameter(0)
+  %b = bf16[4,8]{1,0} parameter(1)
+  %c = bf16[4,8]{1,0} parameter(2)
+  ROOT %multiply_add_fusion.7 = (f32[8,8]{1,0}, /*index=1*/f32[8,8]{1,0}) fusion(%a, %b, %c), kind=kOutput, calls=%fused_computation.1METADATA
+}
+"""
+
+
+@pytest.mark.parametrize("own, want", [
+    # XLA names a fusion around a product by the product: the event is
+    # mostly the product's time, and it stays with the layer
+    ('jit(f)/loss_and_grad/transpose(jvp(m.mlp))/dot_general',
+     ["loss_and_grad", "m.mlp"]),
+    # a name without a scope, a root of the compiler's own (the tuple): the
+    # last fused instruction's that has any
+    ("jit(f)/while/body", ["optimizer_update"]),
+    (None, ["optimizer_update"]),
+])
+def test_a_fusion_belongs_to_what_xla_names_it_by(own, want):
+    names = {"loss_and_grad", "optimizer_update", "m.mlp"}
+    metadata = f', metadata={{op_name="{own}"}}' if own else ""
+    said = profiler.scopes_in_text(
+        _FUSED_UPDATE.replace("METADATA", metadata), names)
+    assert said["multiply_add_fusion.7"] == {
+        "result": "(f32[8,8], /*index=1*/f32[8,8])", "scopes": want,
+        "mixed": True}
+    assert "convolution.3" not in said  # a fusion's insides are no events
+
+
+def test_capture_window_writes_device_scopes(host_ds, tmp_path, fresh_scopes):
+    est = _make_est(num_epochs=1)
+    with profiler.profile_fit(steps=8, out_dir=str(tmp_path / "cap"),
+                              jax_trace=False) as cap:
+        est.fit(host_ds, host_ds)
+    result = cap.result()
+    assert os.path.basename(result["device_scopes_path"]) == "device_scopes.json"
+    with open(result["device_scopes_path"]) as f:
+        programs = json.load(f)
+    # the fit has returned and dropped its programs: the window pinned them
+    whats = {key.partition("#")[0] for key in programs}
+    assert {"init", "32", "eval_scan"} <= whats, whats
+    step = next(v for k, v in programs.items() if k.startswith("32#"))
+    chains = {tuple(said["scopes"]) for said in step.values()}
+    assert {("loss_and_grad",), ("optimizer_update",)} <= chains
+    assert cap.pinned == []
+    import gc
+
+    gc.collect()
+    assert profiler.device_scopes() == {}
+
+
+@pytest.mark.parametrize("runner", ["scan", "per_step"])
+def test_a_fit_nobody_profiles_reads_no_program_text(
+        host_ds, monkeypatch, fresh_scopes, runner):
+    import jax
+
+    reads = []
+    real = jax.stages.Compiled.as_text
+    monkeypatch.setattr(
+        jax.stages.Compiled, "as_text",
+        lambda self, *a, **k: reads.append(1) or real(self, *a, **k))
+    monkeypatch.setattr(
+        costmodel, "step_flops_abstract", lambda *a, **k: 1.0)
+    monkeypatch.setattr(
+        costmodel, "step_flops_from_jitted", lambda *a, **k: 1.0)
+    asked = []
+    monkeypatch.setattr(profiler, "scopes_in_text",
+                        lambda *a, **k: asked.append(1) or {})
+    est = _make_est(num_epochs=2, **(
+        {"scan_epochs": False} if runner == "per_step" else {}))
+    noted = []
+    real_note = profiler.note_program
+    monkeypatch.setattr(
+        profiler, "note_program",
+        lambda what, program: noted.append(str(what)) or real_note(what, program))
+    est.fit(host_ds, host_ds)
+    assert not reads and not asked
+    # one note a compiled program, none an epoch
+    step = "first_step" if runner == "per_step" else "32"
+    evaluation = "eval_step" if runner == "per_step" else "eval_scan"
+    assert sorted(noted) == sorted(["init", step, evaluation]), noted
+
+
+def _toy_model(kind):
+    import jax
+    import jax.numpy as jnp
+
+    from raydp_tpu.estimator import jax_estimator as je
+
+    rng = np.random.default_rng(0)
+    if kind == "dlrm":
+        from raydp_tpu.models import DLRM
+
+        module = DLRM(vocab_sizes=(11, 7), num_dense=3, embed_dim=4,
+                      bottom_mlp=(8, 4), top_mlp=(8, 1),
+                      use_pallas_interaction=False)
+        x = (jnp.asarray(rng.random((16, 3)), jnp.float32),
+             jnp.asarray(rng.integers(0, 7, (16, 2)), jnp.int32))
+        y = jnp.asarray(rng.random(16) > 0.5, jnp.float32)
+        return module, je._LOSSES["bce"], x, y, {"dlrm_interaction"}
+    tokens = jnp.asarray(rng.integers(0, 256, (2, 33)), jnp.int32)
+    if kind == "looplm":
+        from raydp_tpu.models import LoopLM
+
+        module = LoopLM(vocab_size=256, hidden_size=64, num_heads=4,
+                        num_layers=2, intermediate_size=176, loop_steps=2,
+                        dtype=jnp.float32, loss_chunk=16)
+        return module, je.MODEL_LOSS, tokens, None, {
+            "looplm.loop", "looplm.block", "looplm.exit_loss"}
+    from raydp_tpu.models import HybridLM, RoutedHybridLM
+
+    if kind == "hybridlm-mamba":
+        config = {
+            "vocab_size": 256, "hidden_size": 64, "num_attention_heads": 4,
+            "num_key_value_heads": 2, "shared_intermediate_size": 96,
+            "layer_types": ["mamba", "attention", "mamba", "mamba"],
+            "num_hidden_layers": 2, "mamba_n_heads": 8, "mamba_d_head": 16,
+            "mamba_d_state": 16, "mamba_d_conv": 4, "mamba_chunk_size": 8,
+            "mamba_expand": 2, "mamba_n_groups": 1, "embedding_multiplier": 12,
+            "residual_multiplier": 0.22, "attention_multiplier": 0.015625,
+            "logits_scaling": 8, "rms_norm_eps": 1e-5}
+        module = HybridLM.from_config(config, dtype=jnp.float32, loss_chunk=16)
+        return module, je.MODEL_LOSS, tokens, None, {
+            "hybridlm.mamba", "hybridlm.attention", "hybridlm.mlp",
+            "hybridlm.loss", "ssd"}
+    config = {
+        "model_type": "lfm2_moe", "vocab_size": 256, "hidden_size": 64,
+        "num_attention_heads": 4, "num_key_value_heads": 2,
+        "intermediate_size": 96, "moe_intermediate_size": 32,
+        "layer_types": ["conv", "conv", "full_attention", "conv"],
+        "num_hidden_layers": 3, "num_dense_layers": 1,
+        "num_experts": 2, "num_experts_per_tok": 2, "conv_L_cache": 3,
+        "conv_bias": False, "norm_eps": 1e-5, "norm_topk_prob": True,
+        "rope_theta": 1000000, "routed_scaling_factor": 1,
+        "use_expert_bias": True,
+        "share": {"first_layer": 1, "experts_total": 8, "first_expert": 2}}
+    if kind == "hybridlm-dense":
+        config = {**config, "num_hidden_layers": 1}
+        del config["share"]
+        want = {"hybridlm.conv", "hybridlm.mlp", "hybridlm.loss"}
+    else:
+        want = {"hybridlm.conv", "hybridlm.attention", "hybridlm.mlp",
+                "hybridlm.loss", "hybridlm.experts",
+                "hybridlm.experts.route", "hybridlm.experts.dispatch",
+                "hybridlm.experts.gmm", "hybridlm.experts.combine"}
+    module = RoutedHybridLM.from_config(
+        config, dtype=jnp.float32, loss_chunk=16, expert_bias_spread=0.05)
+    return module, je.MODEL_LOSS, tokens, None, want
+
+
+@pytest.mark.parametrize("kind", [
+    "dlrm", "looplm", "hybridlm-dense", "hybridlm-mamba", "hybridlm-experts"])
+def test_every_scope_a_model_registers_is_in_its_compiled_step(
+        kind, fresh_scopes):
+    """The names a model's trace registers are the ones a reader finds in
+    the compiled step: none lost to a transform, a checkpoint or a fusion."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from raydp_tpu.estimator import jax_estimator as je
+
+    module, loss_fn, x, y, want = _toy_model(kind)
+    key = jax.random.PRNGKey(0)
+    params = (module.init(key, x, None, method="loss")
+              if loss_fn == je.MODEL_LOSS else module.init(key, x))
+    assert profiler.registered_scopes()  # init traced the model already
+    tx = optax.adam(1e-3)
+    step = je.make_train_step(module, loss_fn, tx)
+    compiled = jax.jit(step).lower(
+        params, tx.init(params), jnp.zeros(()), x, y).compile()
+    registered = set(profiler.registered_scopes())
+    assert registered == want | {"loss_and_grad", "optimizer_update"}
+    said = profiler.scopes_in_text(compiled.as_text())
+    found = {scope for v in said.values() for scope in v["scopes"]}
+    assert found == registered, registered - found
+    # the halves of a step do not nest, and the model runs in the first (an
+    # instruction the compiler hoisted out of a loop may have lost the
+    # outer half of its name: a causal mask reads ``looplm.block`` alone)
+    for v in said.values():
+        chain = v["scopes"]
+        assert not {"loss_and_grad", "optimizer_update"} <= set(chain)
+        assert not (chain[:1] == ["optimizer_update"] and chain[-1] in want)
+
+
+# ---------------------------------------------------------------------------
 # memory watermark plane
 # ---------------------------------------------------------------------------
 
