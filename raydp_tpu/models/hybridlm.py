@@ -104,10 +104,11 @@ DENSE, EXPERTS = "dense", "experts"
 # two of its five products for 67 MB a layer and row
 REMAT_KEEPS = SAVED_RESIDUALS + ("mlp_out",)
 # an expert layer keeps ``mlp_out`` (its combined result) as every layer
-# does, and its discrete part (``ops.experts.KEPT``: every token's choice
-# and the sort's three permutations, four int32 vectors of tokens x k, 2 MB
-# a layer at 32,768 tokens): the backward pass must not choose again (a
-# near-tie could fall the other way) and need not sort again; the first
+# does, and its discrete part (``ops.experts.KEPT``: every token's choice,
+# the sort's permutation and its inverse, the held pairs in token order:
+# five int32 vectors of tokens x k and one of tokens, 2.8 MB a layer at
+# 32,768 tokens): the backward pass must not choose again (a near-tie could
+# fall the other way) and does not sort again; the first
 # grouped product's output (294 MB a layer at the likely bound of 40,960
 # rows, 940 MB at the worst case's 131,072) is recomputed
 EXPERT_KEEPS = (experts_op.KEPT,)
@@ -298,10 +299,10 @@ class HybridLM(nn.Module):
 
     def expert_row_bound(self, tokens: int) -> int:
         """Rows of an expert layer's buffer for a batch of ``tokens`` IN THE
-        WORST CASE, every choice held: what the facts, the kept
-        permutations and the benchmark's readers count with, and the bound
-        of the layer's overflow path. It is no longer what the layer
-        allocates: that is ``expert_likely_row_bound`` wherever the batch's
+        WORST CASE, every choice held: what the facts and the benchmark's
+        readers count with, and the bound of the layer's overflow path. It
+        is no longer what the layer allocates: that is
+        ``expert_likely_row_bound`` wherever the batch's
         load fits it (``ops.experts.routed_experts`` chooses by the load)."""
         return experts_op.row_bound_for(tokens * self.experts_per_token)
 
@@ -404,7 +405,12 @@ class HybridLM(nn.Module):
         shapes (``flops_per_row_parts``); recomputation does not count, and
         the experts are counted AT THE UNIFORM SHARE (tokens x
         experts_per_token x held / total pairs a layer): a number from
-        shapes, so ``estimator.mfu`` does not move with the routing."""
+        shapes, so ``estimator.mfu`` does not move with the routing.
+        ``experts.token_rows_gathered_per_pass``: the rows one token-side
+        sum of an expert layer gathers for a batch row at the likely bound
+        (``ops.experts.token_rows_gathered``: bound + tokens token-ordered,
+        tokens x k per choice), for a trace's reader to hold the gathers it
+        sees against."""
         t = x.shape[1] - 1
         parts = self.flops_per_row_parts(t)
         kept = self._remat_keeps(t)
@@ -428,6 +434,10 @@ class HybridLM(nn.Module):
                 "experts.layers": self.expert_layers,
                 "experts.rows_per_row": self.expert_row_bound(t),
                 "experts.rows_likely_per_row": self.expert_likely_row_bound(t),
+                "experts.token_rows_gathered_per_pass":
+                    experts_op.token_rows_gathered(
+                        self.expert_likely_row_bound(t), t,
+                        self.experts_per_token),
                 "experts.flops_per_row": parts["experts"],
                 "experts.flops_counted": "uniform share"})
         return facts
@@ -483,9 +493,11 @@ class HybridLM(nn.Module):
         kept = {name: sizes[name] for name in REMAT_KEEPS
                 if flash or name not in SAVED_RESIDUALS}
         if self.expert_layers:
-            # tok and pair of a row; the choice and the row of a pair: int32
+            # int32: of a pair its choice, its place by expert and the row
+            # that is (the sort and its inverse), and the held pairs in token
+            # order by row and by pair; of a token its first place there
             kept[experts_op.KEPT] = self.expert_layers * 4 * (
-                2 * self.expert_row_bound(t) + 2 * t * self.experts_per_token)
+                5 * t * self.experts_per_token + t)
         return kept
 
     def epoch_facts(self, report: dict, steps: int) -> dict:

@@ -44,9 +44,25 @@ fallback, pairs beyond it left out AND COUNTED (``report["dropped"]``), so
 that a caller who chose a smaller bound can hold the count to zero.
 
 Both directions of both moves are GATHERS (``_rows_of_tokens``,
-``_tokens_of_rows``): the sort gives the permutation and its inverse, so the
-backward pass of "row r reads token tok[r]" is "token n reads its k rows",
-not a scatter-add with repeated indices (XLA:TPU serialises those).
+``_tokens_of_rows``), never a scatter-add with repeated indices (XLA:TPU
+serialises those): the sort gives the permutation and its inverse, and a
+third sort gives the held pairs IN TOKEN ORDER. The rows' side reads "row r
+is token tok[r]". The token side, "token n is the sum of its held rows" (the
+combine, and the dispatch's backward pass), is one sum in one of two forms
+(``_sum_per_token``), and the shapes say which runs (``token_ordered``): a
+gather on a TPU costs by the rows it produces, so the form that gathers
+fewer. PER CHOICE: k gathers of N rows, ``rows[rank[:, j]]``, the choices
+not held masked: k x N rows for the rows that are held. TOKEN-ORDERED: one
+gather of the buffer's rows into token order, one pass that adds to each
+slot the next k - 1 slots of the same token (float32, left to right: the
+per-choice sum with its ``+ 0.0`` left out, the same bits), one gather of N
+rows, each token's first slot: bound + N rows. Where this chip holds one
+choice in four the buffer is a fraction of k x N and the second form runs;
+at the worst-case bound (every expert held, the overflow's arm) the buffer
+IS k x N rows and the first does, as it did before there were two. With the
+second form the combine's weights take their gradient on the rows' side too
+(a dot a row beside the pass that computes the rows' cotangent, then one
+gather of a scalar a pair): no row is gathered for it.
 
 The grouped product is ``impl="ragged_dot"`` (``lax.ragged_dot``: XLA:TPU
 runs it as a Mosaic kernel of its own, ``%ragged-dot-*`` in a trace, and
@@ -71,6 +87,7 @@ from raydp_tpu.ops import backend
 IMPLS = ("ragged_dot", "megablox")
 # rows are visited a tile at a time: the bound is a multiple of it
 ROW_TILE = 512
+LANES = 128  # columns of a tile
 MEGABLOX_TILING = (ROW_TILE, 1024, 1024)  # rows, contraction, columns
 # the rows' buffer is cut to SLACK x the even share of the held experts (the
 # LIKELY bound; a load past it runs the worst-case bound, so SLACK decides
@@ -82,8 +99,9 @@ MEGABLOX_TILING = (ROW_TILE, 1024, 1024)  # rows, contraction, columns
 # 679.0 at 1.5, 694.8 at 2 and 740.1 at the worst-case bound (4 x even),
 # all before ``_by_load``'s barrier
 SLACK = 1.25
-# the name under which a recomputed block keeps the layer's discrete part:
-# every token's choice and the sort's three permutations
+# the name under which a recomputed block keeps the layer's discrete part,
+# int32: every token's choice (``route``) and what the sorts made of it
+# (``plan``: four numbers a (token, choice) pair, one a token)
 KEPT = "experts_perm"
 
 
@@ -158,6 +176,16 @@ def plan(sel, first: int, count: int, row_bound: int):
     ``load``   int32 [count] pairs routed to each held expert
     ``rows``   int32 []      rows filled (the sum of ``sizes``)
     ``dropped`` int32 []     held pairs past the bound
+
+    and THE HELD PAIRS IN TOKEN ORDER (by their flat index n k + j: token by
+    token, a token's choices in the order they are summed in), slot by slot:
+
+    ``by_token``      int32 [R]  the row of slot i
+    ``pair_by_token`` int32 [R]  the pair of slot i, flat; N k past the
+                                 filled slots, so ``// k`` gives a slot's
+                                 token and N, no token's, past them
+    ``head``          int32 [N]  a token's first slot (where it has none:
+                                 the slot the next one's would be)
     """
     n, k = sel.shape
     local = sel - first
@@ -167,84 +195,169 @@ def plan(sel, first: int, count: int, row_bound: int):
     # stable: inside an expert's group the pairs keep the tokens' order
     _, order = lax.sort((key, pairs), num_keys=1, is_stable=True)
     _, rank = lax.sort((order, pairs), num_keys=1)  # the inverse permutation
+    # what a sort gives is KEPT beside the choice it was made from, and what
+    # follows is made of what is kept: a recomputed block does not sort again
+    order = checkpoint_name(order, KEPT)
+    rank = checkpoint_name(rank.reshape(n, k), KEPT)
     load = jnp.sum(key[:, None] == jnp.arange(count, dtype=jnp.int32),
                    axis=0, dtype=jnp.int32)
     ends = jnp.minimum(jnp.cumsum(load), row_bound)
     sizes = ends - jnp.concatenate([jnp.zeros((1,), jnp.int32), ends[:-1]])
-    rank = rank.reshape(n, k)
     valid = held & (rank < row_bound)
-    # a bound rounded up past the pairs there are: rows nobody fills
-    order = jnp.pad(order, (0, max(0, row_bound - n * k)))[:row_bound]
+    rank = jnp.minimum(rank, row_bound - 1)
+    # the pairs that are not valid go last: the keys of those that are differ
+    slot_pair, slot_row = lax.sort(
+        (jnp.where(valid, pairs.reshape(n, k), n * k).reshape(-1),
+         rank.reshape(-1)), num_keys=1, is_stable=False)
+    slots = jnp.sum(valid, axis=1, dtype=jnp.int32)
+    slot_pair, slot_row, head = (checkpoint_name(a, KEPT) for a in (
+        slot_pair, slot_row, jnp.cumsum(slots) - slots))
+
+    def fit(a, fill=0):
+        # a bound rounded up past the pairs there are: rows nobody fills
+        return jnp.pad(a, (0, max(0, row_bound - n * k)),
+                       constant_values=fill)[:row_bound]
+
+    order = fit(order)
     return {
-        "tok": order // k, "pair": order,
-        "rank": jnp.minimum(rank, row_bound - 1), "valid": valid,
+        "tok": order // k, "pair": order, "rank": rank, "valid": valid,
         "sizes": sizes, "load": load, "rows": ends[-1],
         "dropped": jnp.sum(load) - ends[-1],
+        "by_token": fit(slot_row), "pair_by_token": fit(slot_pair, n * k),
+        "head": head,
     }
 
 
 # -- the two moves, gathers in both directions ---------------------------------
 
 
-def _sum_over_choices(rows, rank, valid, weight=None):
-    """[N, D] float32: each token's sum over its k choices of the row the
-    choice went to (x ``weight`` [N, k]), choices not ``valid`` left out."""
+def token_ordered(bound: int, tokens: int, k: int) -> bool:
+    """Whether the token side's sums run token-ordered at ``bound`` rows:
+    where that form gathers fewer rows (bound + tokens) than a gather a
+    choice does (k x tokens). Shapes decide, at trace time."""
+    return bound + tokens < k * tokens
+
+
+def token_rows_gathered(bound: int, tokens: int, k: int) -> int:
+    """Rows a token-side sum gathers at ``bound`` rows, in the form
+    ``token_ordered`` says it runs in."""
+    return bound + tokens if token_ordered(bound, tokens, k) else k * tokens
+
+
+def _sum_per_token(rows, q, weight=None):
+    """[N, D] in ``rows``'s dtype: each token's sum, in float32, over its k
+    choices in their order of the row the choice went to (x ``weight``
+    [N, k]), the choices not ``valid`` left out; rounded once. ``q`` is a
+    plan's arrays at ``rows``'s row count (``_plan_at``)."""
+    n, k = q["rank"].shape
+    bound, d = rows.shape
+    if not token_ordered(bound, n, k):
+        total = None
+        for j in range(k):
+            part = rows[q["rank"][:, j]].astype(jnp.float32)
+            if weight is not None:
+                part = part * weight[:, j, None]
+            part = jnp.where(q["valid"][:, j, None], part, 0.0)
+            total = part if total is None else total + part
+        return total.astype(rows.dtype)
+    # ONE TILE A ROW, [bound, D / 128, 128], from the first gather to the
+    # last: a row gathered is then one piece of memory and not sixteen (0.67
+    # ms for 40,960 rows of 2048 against 1.42 as [bound, 2048], whose 16-row
+    # tiles hold 128 columns of a row each), and the row axis is a major
+    # one, so a shift along it is an offset (a one-row shift of [bound,
+    # 2048] is a relayout); the copy into that form takes 0.51 ms (one
+    # expert layer at the cell's shapes, _scratch probes, my chip runs, PR 40)
+    tiled = (bound, d // LANES, LANES) if d % LANES == 0 else (bound, d)
+    over = (slice(None),) + (None,) * (len(tiled) - 1)  # a number a slot
+
+    def ahead_of(a, ahead, fill=0):
+        return jnp.pad(a[ahead:], (0, ahead), constant_values=fill)
+
+    # k - 1 slots past the last, for the shifts to run into (never added:
+    # no token is theirs)
+    g = rows.reshape(tiled)[jnp.pad(q["by_token"], (0, k - 1))]
+    slot_weight = None if weight is None else weight.reshape(-1)[
+        jnp.minimum(q["pair_by_token"], n * k - 1)]
+    token = q["pair_by_token"] // k
+    # a slot takes the k - 1 slots after it that are its token's, left to
+    # right: the sum per choice with its ``+ 0.0`` left out. Only a token's
+    # FIRST slot is read below (a later one holds a tail of the sum; past the
+    # filled slots, whatever the kernel left in the unfilled rows). THE
+    # SHIFTS START AT AN OFFSET XLA CANNOT FOLD (a token's first slot is an
+    # exclusive sum, so the first token's is 0, at run time): a slice at a
+    # constant offset XLA:TPU makes a copy of (0.51 ms each, three a sum),
+    # one at an offset it reads inside the pass that adds (1.19 ms, all four)
+    zero = jnp.minimum(q["head"][0], 0)
     total = None
-    for j in range(rank.shape[1]):
-        part = rows[rank[:, j]].astype(jnp.float32)
-        if weight is not None:
-            part = part * weight[:, j, None]
-        part = jnp.where(valid[:, j, None], part, 0.0)
+    for ahead in range(k):
+        part = lax.dynamic_slice_in_dim(g, zero + ahead, bound).astype(
+            jnp.float32)
+        if slot_weight is not None:
+            part = part * ahead_of(slot_weight, ahead)[over]
+        if ahead:
+            same = ahead_of(token, ahead, -1) == token
+            part = jnp.where(same[over], part, 0.0)
         total = part if total is None else total + part
-    return total
+    out = total.astype(rows.dtype)[q["head"]].reshape(n, d)
+    return jnp.where(q["valid"].any(axis=1)[:, None], out, 0)
 
 
 @jax.custom_vjp
-def _rows_of_tokens(u, tok, rank, valid):
+def _rows_of_tokens(u, q):
     """Dispatch: row r is token ``tok[r]``'s features."""
-    return u[tok]
+    return u[q["tok"]]
 
 
-def _rows_of_tokens_fwd(u, tok, rank, valid):
-    return u[tok], (rank, valid, jnp.zeros((0,), u.dtype))
+def _rows_of_tokens_fwd(u, q):
+    return u[q["tok"]], q
 
 
-def _rows_of_tokens_bwd(kept, d_rows):
-    rank, valid, like = kept
-    return (_sum_over_choices(d_rows, rank, valid).astype(like.dtype),
-            None, None, None)
+def _rows_of_tokens_bwd(q, d_rows):
+    return _sum_per_token(d_rows, q), None
 
 
 _rows_of_tokens.defvjp(_rows_of_tokens_fwd, _rows_of_tokens_bwd)
 
 
 @jax.custom_vjp
-def _tokens_of_rows(y, w, tok, pair, rank, valid, row_valid):
+def _tokens_of_rows(y, w, q):
     """Combine: token n is the sum of its valid choices' rows, each scaled by
-    the choice's weight, in float32."""
-    return _sum_over_choices(y, rank, valid, w)
+    the choice's weight, in float32; in the rows' dtype."""
+    return _sum_per_token(y, q, w)
 
 
-def _tokens_of_rows_fwd(y, w, tok, pair, rank, valid, row_valid):
-    return (_sum_over_choices(y, rank, valid, w),
-            (y, w, tok, pair, rank, valid, row_valid))
+def _tokens_of_rows_fwd(y, w, q):
+    return _sum_per_token(y, q, w), (y, w, q)
 
 
 def _tokens_of_rows_bwd(kept, d_out):
-    y, w, tok, pair, rank, valid, row_valid = kept
-    w_row = w.reshape(-1)[pair]
-    # the cotangent in the rows' dtype BEFORE the gather: it writes a row for
-    # every row of the buffer, filled or not (5.0 ms a layer for float32 rows
-    # at 131,072 x 2048; my chip run, PR 34)
-    d_y = jnp.where(row_valid[:, None],
-                    d_out.astype(y.dtype)[tok].astype(jnp.float32)
-                    * w_row[:, None], 0.0)
-    d_w = jnp.stack([
-        jnp.where(valid[:, j],
-                  jnp.sum(y[rank[:, j]].astype(jnp.float32)
-                          * d_out.astype(jnp.float32), axis=-1), 0.0)
-        for j in range(rank.shape[1])], axis=1)
-    return d_y.astype(y.dtype), d_w.astype(w.dtype), None, None, None, None, None
+    y, w, q = kept
+    n, k = q["rank"].shape
+    # the cotangent a ROW: gathered in the rows' dtype, which the result it
+    # is the cotangent of has (float32 rows would be written for every row of
+    # the buffer, filled or not: 5.0 ms a layer at 131,072 x 2048; my chip
+    # run, PR 34)
+    d_row = d_out[q["tok"]].astype(jnp.float32)
+    d_y = jnp.where(q["row_valid"][:, None],
+                    d_row * w.reshape(-1)[q["pair"]][:, None], 0.0)
+    if token_ordered(y.shape[0], n, k):
+        # a weight's gradient is its row's dot with that cotangent: taken
+        # where the rows are, beside ``d_y``, and handed to the pair by one
+        # gather of a scalar (an unfilled row's is whatever the kernel left
+        # there, and no valid pair's)
+        d_w_row = jnp.sum(y.astype(jnp.float32) * d_row, axis=-1)
+        d_w = jnp.where(q["valid"], d_w_row[q["rank"]], 0.0)
+    else:
+        # per choice, a row gathered a choice. At tokens x k rows the dot
+        # beside ``d_y`` keeps ``d_row`` for a second reader, so ``d_y``
+        # cannot take its buffer: 0.54 GB more held by the epoch program
+        # (compiled for a described v5e)
+        d_w = jnp.stack([
+            jnp.where(q["valid"][:, j],
+                      jnp.sum(y[q["rank"][:, j]].astype(jnp.float32)
+                              * d_out.astype(jnp.float32), axis=-1), 0.0)
+            for j in range(k)], axis=1)
+    return d_y.astype(y.dtype), d_w.astype(w.dtype), None
 
 
 _tokens_of_rows.defvjp(_tokens_of_rows_fwd, _tokens_of_rows_bwd)
@@ -305,6 +418,19 @@ def likely_row_bound(pairs: int, held: int, experts: int) -> int:
                row_bound_for(pairs))
 
 
+def _plan_at(p, bound: int):
+    """What the two moves read of a plan ``p``, AT ``bound`` ROWS (the rows
+    it was made for, or fewer where every row it fills lies inside them):
+    the per-row and per-slot lists cut to ``bound``, what points into them
+    clipped, and ``row_valid`` bool [bound], the rows that are filled."""
+    cut = {key: p[key][:bound] for key in ("tok", "pair", "pair_by_token")}
+    clipped = {key: jnp.minimum(p[key], bound - 1)
+               for key in ("rank", "head")}
+    return {**cut, **clipped, "valid": p["valid"],
+            "by_token": jnp.minimum(p["by_token"][:bound], bound - 1),
+            "row_valid": lax.iota(jnp.int32, bound) < p["rows"]}
+
+
 def _rows_pass(bound: int, impl, scope: str, u, w, w13, w2, p):
     """Everything after the plan AT ``bound`` ROWS: dispatch, the two grouped
     products with the activation between them, combine; [N, D] in ``u``'s
@@ -312,24 +438,20 @@ def _rows_pass(bound: int, impl, scope: str, u, w, w13, w2, p):
     than it was made for, every row it fills must lie inside ``bound`` (the
     caller's predicate), so ``valid`` and ``sizes`` hold as they are."""
     two_f = w13.shape[2]
-    tok, pair = p["tok"][:bound], p["pair"][:bound]
-    rank = jnp.minimum(p["rank"], bound - 1)
-    row_valid = lax.iota(jnp.int32, bound) < p["rows"]
+    q = _plan_at(p, bound)
     with obs.device_scope(f"{scope}.dispatch"):
-        x = _rows_of_tokens(u, tok, rank, p["valid"])
+        x = _rows_of_tokens(u, q)
     with obs.device_scope(f"{scope}.gmm"):
         h = grouped_dot(x, w13, p["sizes"], impl)
         gate, up = h[:, :two_f // 2], h[:, two_f // 2:]
         # rows past the groups hold whatever the kernel left: zeroed here,
         # in the pass that computes the activation anyway
-        a = jnp.where(row_valid[:, None], jax.nn.silu(gate) * up, 0)
+        a = jnp.where(q["row_valid"][:, None], jax.nn.silu(gate) * up, 0)
         y = grouped_dot(a.astype(u.dtype), w2, p["sizes"], impl)
     with obs.device_scope(f"{scope}.combine"):
-        out = _tokens_of_rows(y, w, tok, pair, rank, p["valid"], row_valid)
-    # in the tokens' dtype HERE: a conditional's result is a buffer of its
-    # own, and a float32 one is written and read again where the caller's
-    # cast used to fuse into the combine
-    return out.astype(u.dtype)
+        # in the tokens' dtype, which the rows have: a conditional's result
+        # is a buffer of its own, and a float32 one is written and read again
+        return _tokens_of_rows(y, w, q)
 
 
 def _by_load(likely: int, p, at_bound, *operands):
@@ -418,10 +540,6 @@ def routed_experts(u, w_gate, bias, w13, w2, *, first: int, top_k: int,
         w = _hand_bias(w, bias, excess_load(sel, total))
     with obs.device_scope(f"{scope}.dispatch"):
         p = plan(sel, first, count, worst)
-        # kept beside the choice they were made from: a recomputed block
-        # does not sort again
-        p["tok"], p["pair"], p["rank"] = (
-            checkpoint_name(p[key], KEPT) for key in ("tok", "pair", "rank"))
     report = {"sel": sel, "load": p.pop("load").astype(jnp.float32),
               "dropped": p.pop("dropped").astype(jnp.float32),
               "full_bound": (p["rows"] > likely).astype(jnp.float32)}

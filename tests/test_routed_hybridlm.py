@@ -84,7 +84,8 @@ def share_of(p, first, count, **kw):
     with jax.default_matmul_precision("highest"):
         return experts.routed_experts(
             p["u"], p["w_gate"], p["bias"], p["w13"][first:first + count],
-            p["w2"][first:first + count], first=first, top_k=K, **kw)
+            p["w2"][first:first + count], first=first,
+            **{"top_k": K, **kw})
 
 
 @pytest.mark.parametrize("first, count", [(0, 8), (2, 2), (6, 2)])
@@ -157,6 +158,95 @@ def test_a_row_bound_too_small_is_counted_not_hidden(layer):
     assert experts.row_bound_for(100) == experts.ROW_TILE
 
 
+# -- the plan: the sort, and the held pairs in token order -----------------------
+
+# experts 2-3 held, top-3: token 0 holds none of its choices, token 1 all
+# three (a repeated choice is no router's, and the sort does not care), the
+# others one or two; 8 held pairs
+CHOICES = np.array([[0, 1, 5], [2, 3, 2], [7, 2, 0], [3, 6, 2], [1, 0, 4],
+                    [3, 7, 6], [2, 5, 6]], np.int32)
+
+
+@pytest.mark.parametrize("row_bound", [
+    8,    # the load fills the bound exactly
+    11,   # rows past the filled ones
+    6,    # two held pairs past the bound: left out of every list
+    experts.ROW_TILE])
+def test_the_plan_lists_the_held_pairs_in_token_order(row_bound):
+    """``by_token`` / ``pair_by_token`` / ``head`` against a loop over the
+    pairs in their flat order: slot by slot the row and the pair of the
+    pairs that are valid, the sentinel past them, and every token's first
+    slot, held or not."""
+    n, k = CHOICES.shape
+    p = jax.tree.map(np.asarray, experts.plan(
+        jnp.asarray(CHOICES), 2, 2, row_bound))
+    held = (CHOICES >= 2) & (CHOICES < 4)
+    # the rows: by expert, inside an expert by flat index
+    flat = sorted(np.flatnonzero(held.ravel()),
+                  key=lambda f: (CHOICES.ravel()[f], f))
+    row_of = {f: r for r, f in enumerate(flat) if r < row_bound}
+    assert int(p["rows"]) == len(row_of) == min(8, row_bound)
+    assert int(p["dropped"]) == 8 - len(row_of)
+    rows, pairs, head = [], [], []
+    for f in range(n * k):
+        if f % k == 0:
+            head.append(len(rows))
+        if f in row_of:
+            rows.append(row_of[f])
+            pairs.append(f)
+    filled = len(rows)
+    assert p["by_token"].shape == p["pair_by_token"].shape == (row_bound,)
+    assert p["by_token"][:filled].tolist() == rows
+    assert p["pair_by_token"][:filled].tolist() == pairs
+    assert (p["pair_by_token"][filled:] == n * k).all()
+    assert (p["by_token"] < row_bound).all() and (p["by_token"] >= 0).all()
+    assert p["head"].tolist() == head
+    # the same pairs as the rows' side lists, the other way round
+    assert (p["pair"][p["by_token"][:filled]] == pairs).all()
+    assert (p["tok"][p["by_token"][:filled]] == np.array(pairs) // k).all()
+    assert p["valid"].sum(1).tolist() == np.diff(head + [filled]).tolist()
+    if row_bound >= 8:
+        assert p["valid"].sum(1).tolist() == [0, 3, 1, 2, 0, 1, 1]
+
+
+@pytest.mark.parametrize("weighted", [False, True])
+@pytest.mark.parametrize("dtype, width", [
+    (jnp.float32, D), (jnp.bfloat16, D), (jnp.bfloat16, 2 * experts.LANES)])
+def test_a_tokens_sum_is_the_same_sum_in_either_form(dtype, width, weighted):
+    """``_sum_per_token`` token-ordered (a buffer of 8 rows for 7 tokens of
+    3 choices) against per choice (the same plan at 24 rows), operation by
+    operation (a compiler may contract a product into the sum after it in
+    one form and not in the other): the same bits, in float32 and in
+    bfloat16, and the sum written out in numpy. At a width of whole tiles
+    the token-ordered form works on rows of one tile each."""
+    n, k = CHOICES.shape
+    p = experts.plan(jnp.asarray(CHOICES), 2, 2, n * k + 3)
+    keys = jax.random.split(jax.random.PRNGKey(7), 2)
+    rows = jax.random.normal(keys[0], (n * k + 3, width)).astype(dtype)
+    w = jax.random.uniform(keys[1], (n, k)) if weighted else None
+    assert experts.token_ordered(8, n, k)
+    assert not experts.token_ordered(n * k + 3, n, k)
+    assert experts.token_rows_gathered(8, n, k) == 8 + n
+    assert experts.token_rows_gathered(n * k + 3, n, k) == n * k
+    with jax.disable_jit():
+        ordered = experts._sum_per_token(rows[:8], experts._plan_at(p, 8), w)
+        per_choice = experts._sum_per_token(
+            rows, experts._plan_at(p, n * k + 3), w)
+    assert ordered.dtype == per_choice.dtype == dtype
+    assert np.array_equal(np.asarray(ordered, np.float32),
+                          np.asarray(per_choice, np.float32))
+    want = np.zeros((n, width), np.float32)
+    rank, valid = np.asarray(p["rank"]), np.asarray(p["valid"])
+    for token in range(n):
+        for j in range(k):
+            if valid[token, j]:
+                part = np.asarray(rows[rank[token, j]], np.float32)
+                want[token] += part * np.float32(w[token, j]) if weighted else part
+    assert np.array_equal(np.asarray(ordered, np.float32),
+                          np.asarray(jnp.asarray(want).astype(dtype), np.float32))
+    assert not np.asarray(ordered[0]).any() and np.asarray(ordered[1]).any()
+
+
 # -- the rows' bound follows the load ------------------------------------------
 
 # enough pairs for a likely bound under the worst case: 1024 pairs, experts
@@ -164,34 +254,51 @@ def test_a_row_bound_too_small_is_counted_not_hidden(layer):
 MANY = 512
 
 
-@pytest.fixture(scope="module")
-def wide(layer):
-    """``layer`` with ``MANY`` tokens, and the same with a bias that sends
-    every token's two choices to the held experts 2-3: 1024 rows filled."""
-    u = jax.random.normal(jax.random.PRNGKey(5), (MANY, D))
+def _crowd(layer, tokens):
+    """``layer`` with ``tokens`` tokens, and the same with a bias that sends
+    every token's first two choices to the held experts 2-3."""
+    u = jax.random.normal(jax.random.PRNGKey(5), (tokens, D))
     crowded = jnp.zeros((E,)).at[jnp.asarray((2, 3))].set(10.0)
     return {"even": {**layer, "u": u},
             "overflow": {**layer, "u": u, "bias": crowded}}
 
 
-def _out_and_grads(p, wrap=lambda f: f, **kw):
+@pytest.fixture(scope="module")
+def wide(layer):
+    """``MANY`` tokens: 1024 rows filled where the load overflows."""
+    return _crowd(layer, MANY)
+
+
+def _out_and_grads(p, wrap=lambda f: f, dtype=jnp.float32, **kw):
     """(out, report, the five gradients: u, w_gate, bias, w13, w2) of the
-    share 2-3; ``wrap`` is applied to the layer (a ``jax.checkpoint``)."""
+    share 2-3, the tokens in ``dtype``; ``wrap`` is applied to the layer (a
+    ``jax.checkpoint``)."""
     def run(u, w_gate, bias, w13, w2):
         out, report = wrap(lambda *a: share_of(
             dict(zip(("u", "w_gate", "bias", "w13", "w2"), a)), 2, 2, **kw))(
                 u, w_gate, bias, w13, w2)
-        return (out * jnp.cos(jnp.arange(D))).sum(), (out, report)
+        return (out.astype(jnp.float32) * jnp.cos(jnp.arange(D))).sum(), (
+            out, report)
 
     (_, (out, report)), grads = jax.jit(jax.value_and_grad(
         run, (0, 1, 2, 3, 4), has_aux=True))(
-            p["u"], p["w_gate"], p["bias"], p["w13"], p["w2"])
+            p["u"].astype(dtype), p["w_gate"], p["bias"], p["w13"], p["w2"])
     return out, report, grads
 
 
 def _same(got, want):
-    return all(np.array_equal(np.asarray(a), np.asarray(b))
+    return all(np.array_equal(np.asarray(a, np.float32),
+                              np.asarray(b, np.float32))
                for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)))
+
+
+def _eqns(jaxpr, name):
+    """Every equation of primitive ``name`` in ``jaxpr`` and below it."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == name:
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub, name)
 
 
 def test_the_bounds_of_a_share_and_of_the_uncut_layer():
@@ -205,42 +312,144 @@ def test_the_bounds_of_a_share_and_of_the_uncut_layer():
     assert 1.25 <= experts.SLACK <= 2.0
 
 
-@pytest.mark.parametrize("load", ["even", "overflow"])
-def test_the_layer_at_its_own_bound_is_the_layer_at_the_worst_case(wide, load):
+def test_the_form_of_the_token_side_follows_the_rows_gathered():
+    """Token-ordered where that gathers fewer rows than a gather a choice:
+    the cell's likely arm (40,960 + 32,768 rows for 131,072); per choice at
+    the worst case and in a layer that holds every expert, where the buffer
+    is tokens x k rows and the other form would gather 5 N for 4 N."""
+    n, k = 32768, 4
+    likely = experts.likely_row_bound(n * k, 8, 32)
+    assert experts.token_ordered(likely, n, k)
+    assert experts.token_rows_gathered(likely, n, k) == 40960 + 32768
+    for bound in (experts.row_bound_for(n * k),
+                  experts.likely_row_bound(n * k, 32, 32)):
+        assert not experts.token_ordered(bound, n, k)
+        assert experts.token_rows_gathered(bound, n, k) == n * k
+    # as many rows either way: per choice, the form that was there
+    assert not experts.token_ordered(512, 512, 2)
+
+
+# (load, tokens, top_k, dtype): at 512 tokens of 2 choices both bounds run per
+# choice (512 + 512 rows are no fewer than 2 x 512); at 768 of 3 the likely
+# bound (1024 rows for 2304 pairs) runs token-ordered and the worst case per
+# choice, so the equality below is the new form against the old
+BY_LOAD = [("even", MANY, 2, jnp.float32), ("overflow", MANY, 2, jnp.float32),
+           ("even", 768, 3, jnp.float32), ("overflow", 768, 3, jnp.float32),
+           ("even", 768, 3, jnp.bfloat16)]
+
+
+@pytest.mark.parametrize("load, tokens, top_k, dtype", BY_LOAD)
+def test_the_layer_at_its_own_bound_is_the_layer_at_the_worst_case(
+        layer, load, tokens, top_k, dtype):
     """With a share held the layer runs at the likely bound where the load
     fits it and at the worst-case one where it does not (``full_bound`` 1,
     nothing dropped): either way the output and all five gradients are the
-    explicit worst-case layer's, exactly."""
-    p = wide[load]
-    out, report, grads = _out_and_grads(p)
-    want = _out_and_grads(p, row_bound=experts.row_bound_for(MANY * K))
-    assert _same((out, grads), (want[0], want[2]))
+    explicit worst-case layer's, exactly. WHICH FORM the token side ran in is
+    read off the gathers each side makes: the likely arm's token-ordered
+    where that gathers fewer rows, the explicit worst case's per choice."""
+    p = _crowd(layer, tokens)[load]
+    worst = experts.row_bound_for(tokens * top_k)
+    likely = experts.likely_row_bound(tokens * top_k, 2, 8)
+    assert experts.token_ordered(likely, tokens, top_k) == (top_k == 3)
+    assert not experts.token_ordered(worst, tokens, top_k)
+
+    def row_gathers(**kw):
+        jaxpr = jax.make_jaxpr(lambda u: share_of(
+            {**p, "u": u}, 2, 2, top_k=top_k, **kw)[0])(p["u"]).jaxpr
+        shapes = [eqn.outvars[0].aval.shape for eqn in _eqns(jaxpr, "gather")]
+        return sorted(shape[0] for shape in shapes if shape[1:] == (D,))
+
+    def gathers_at(bound):  # the dispatch's, then the combine's
+        # (token-ordered: k - 1 slots past the last for the shifts' sake)
+        return [bound] + ([bound + top_k - 1, tokens] if experts.token_ordered(
+            bound, tokens, top_k) else [tokens] * top_k)
+
+    assert row_gathers(row_bound=worst) == sorted(gathers_at(worst))
+    assert row_gathers() == sorted(gathers_at(likely) + gathers_at(worst))
+
+    out, report, grads = _out_and_grads(p, dtype=dtype, top_k=top_k)
+    want = _out_and_grads(p, dtype=dtype, top_k=top_k, row_bound=worst)
     rows = float(report["load"].sum())
     assert float(report["dropped"]) == 0 and float(want[1]["full_bound"]) == 0
     if load == "overflow":
-        assert rows == MANY * K and float(report["full_bound"]) == 1
+        assert rows == tokens * 2 and float(report["full_bound"]) == 1
     else:
-        assert 0 < rows <= 512 and float(report["full_bound"]) == 0
+        assert 0 < rows <= likely and float(report["full_bound"]) == 0
     assert bool(out.any()) and all(bool(g.any()) for g in grads)
+    if dtype == jnp.bfloat16:
+        # the rows' dtype rounds the sums alike; the experts' weights'
+        # gradients leave XLA:CPU's expanded grouped product in bfloat16
+        # rounded by the rows a program holds, not by the form
+        assert _same((out, grads[:3]), (want[0], want[2][:3]))
+        for a, b in zip(grads[3:], want[2][3:]):
+            assert float(jnp.abs(a - b).max()) <= 1e-2 * float(jnp.abs(b).max())
+    elif load == "even" and top_k == 3:
+        # float32 under jit: XLA:CPU contracts a product into the sum after
+        # it (one rounding for two) where a form's fusion lets it, so the
+        # forms agree to that rounding; operation by operation they agree
+        # exactly (test_a_tokens_sum_is_the_same_sum_in_either_form)
+        assert float(jnp.abs(out - want[0]).max()) <= 1e-6 * float(
+            jnp.abs(want[0]).max())
+        assert _same(grads, want[2])
+    else:
+        assert _same((out, grads), (want[0], want[2]))
 
 
-@pytest.mark.parametrize("load", ["even", "overflow"])
+@pytest.mark.parametrize("load, tokens, top_k", [
+    ("even", MANY, 2), ("overflow", MANY, 2), ("even", 768, 3)])
 def test_a_recomputed_layer_under_the_models_policy_has_the_same_gradients(
-        wide, load):
+        layer, load, tokens, top_k):
     """Inside ``jax.checkpoint`` with the names a ``HybridLM`` block keeps,
-    both branches give the gradients of the layer without recomputation."""
+    both branches, and both forms of the token side, give the gradients of
+    the layer without recomputation."""
     from raydp_tpu.models import hybridlm
 
     keeps = hybridlm.REMAT_KEEPS + hybridlm.EXPERT_KEEPS
     policy = jax.checkpoint_policies.save_only_these_names(*keeps)
-    plain = _out_and_grads(wide[load])
+    p = _crowd(layer, tokens)[load]
+    plain = _out_and_grads(p, top_k=top_k)
     again = _out_and_grads(
-        wide[load], wrap=lambda f: jax.checkpoint(f, policy=policy))
+        p, wrap=lambda f: jax.checkpoint(f, policy=policy), top_k=top_k)
     assert float(again[1]["full_bound"]) == (load == "overflow")
     assert float(jnp.abs(again[0] - plain[0]).max()) <= 1e-6
     for a, b in zip(again[2], plain[2]):
         assert float(jnp.abs(a - b).max()) <= 1e-5 * max(
             1.0, float(jnp.abs(b).max()))
+
+
+def test_a_recomputed_layer_neither_sorts_nor_chooses_again(layer):
+    """What ``KEPT`` names is all of the layer's discrete part: the
+    recomputed block (the ``remat2`` equation of the backward pass) holds
+    no sort, no top-k and no cumulative sum, with the token order's lists
+    among the names as with the sort's."""
+    from raydp_tpu.models import hybridlm
+
+    p = _crowd(layer, 768)["even"]
+    policy = jax.checkpoint_policies.save_only_these_names(
+        *hybridlm.REMAT_KEEPS, *hybridlm.EXPERT_KEEPS)
+
+    def loss(u, w_gate, w13, w2, policy):
+        return jax.checkpoint(lambda *a: share_of(
+            {**p, **dict(zip(("u", "w_gate", "w13", "w2"), a))}, 2, 2,
+            top_k=3)[0].sum(), policy=policy)(u, w_gate, w13, w2)
+
+    def discrete(policy, inside):
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda *a: loss(*a, policy), (0, 1, 2, 3)))(
+                p["u"], p["w_gate"], p["w13"], p["w2"]).jaxpr
+        blocks = list(_eqns(jaxpr, "remat2"))
+        assert len(blocks) == 1
+        where = blocks[0].params["jaxpr"] if inside else jaxpr
+        return {name: len(list(_eqns(where, name)))
+                for name in ("sort", "top_k", "cumsum")}
+
+    # the one cumulative sum left is the held experts' loads' (two numbers)
+    assert discrete(policy, inside=True) == {"sort": 0, "top_k": 0, "cumsum": 1}
+    # the forward pass's own: the sort by expert, its inverse, the token order
+    assert discrete(policy, inside=False)["sort"] == 3
+    # and a block that keeps nothing does all of it again
+    assert discrete(jax.checkpoint_policies.nothing_saveable, inside=True) == {
+        "sort": 3, "top_k": 1, "cumsum": 2}
 
 
 @pytest.mark.parametrize("first, count, conditionals", [(0, 8, 0), (2, 2, 2)])
@@ -256,15 +465,8 @@ def test_a_layer_that_holds_every_expert_lowers_without_a_conditional(
     def loss(u, w13):
         return share_of({**p, "u": u, "w13": w13}, first, count)[0].sum()
 
-    def conds(jaxpr):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "cond":
-                yield eqn
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                yield from conds(sub)
-
-    found = list(conds(jax.make_jaxpr(jax.value_and_grad(loss, (0, 1)))(
-        p["u"], p["w13"]).jaxpr))
+    found = list(_eqns(jax.make_jaxpr(jax.value_and_grad(loss, (0, 1)))(
+        p["u"], p["w13"]).jaxpr, "cond"))
     assert len(found) == conditionals
     worst = experts.row_bound_for(MANY * K)
     for eqn in found:
@@ -581,6 +783,25 @@ def test_epoch_facts_count_the_layers_that_ran_at_the_full_bound():
     assert published.expert_row_bound(32768) == 131072
     assert published.expert_likely_row_bound(32768) == experts.row_bound_for(
         int(experts.SLACK * 32768))
+
+
+def test_the_facts_count_what_the_token_side_gathers_and_keeps():
+    """``experts.token_rows_gathered_per_pass`` is the rows one token-side
+    sum gathers for a batch row at the likely bound, in the form the shapes
+    choose; ``remat_kept_bytes_per_row`` counts the kept discrete part, five
+    int32 a (token, choice) pair and one a token a layer."""
+    published = RoutedHybridLM.from_config(
+        _published(), **_published()["model"]["kwargs"])
+    facts = published.fit_facts(np.zeros((1, 8193), np.int32))
+    assert facts["experts.rows_likely_per_row"] == 10240
+    assert facts["experts.token_rows_gathered_per_pass"] == 10240 + 8192
+    kept = published._remat_keeps(8192)
+    assert kept[experts.KEPT] == 4 * 4 * (5 * 8192 * 4 + 8192)
+    assert facts["remat_kept_bytes_per_row"] == sum(kept.values())
+    # every expert held: the buffer is tokens x k rows, a gather a choice
+    whole = published.clone(experts_held=32, first_expert=0)
+    assert whole.fit_facts(np.zeros((1, 8193), np.int32))[
+        "experts.token_rows_gathered_per_pass"] == 8192 * 4
 
 
 # -- the estimator -------------------------------------------------------------

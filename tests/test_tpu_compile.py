@@ -365,7 +365,14 @@ def test_routed_hybridlm_epoch_program_fits_the_chip(
     layers x (2 forward + 2 in the backward pass's own forward + 4
     backward): a third forward would make it 40), and no arm returns an
     array of the worst-case rows (a residual of the arm not taken, written
-    as zeros)."""
+    as zeros). ISSUE 40: in a likely arm the token side gathers each held
+    row once, a row one tile: an expert layer's forward arm holds the
+    dispatch's gather (40,960 rows of [2048]) and the combine's two (40,960
+    + 3 rows into token order, 32,768 first slots, rows of [16, 128]), its
+    backward arm the dispatch's again, the result's cotangent a row, and
+    the two of the dispatch's backward sum: 7 a layer where a gather a
+    choice made 15."""
+    import collections
     import json
     import os
 
@@ -416,5 +423,12 @@ def test_routed_hybridlm_epoch_program_fits_the_chip(
     assert not any(f"[{worst}," in result for result in conditionals)
     assert len(re.findall(r"%[\w.\-]*gmm[\w.\-]* = \S+ custom-call", text)) == 64
     assert _loss_products(text, "hybridlm.loss") == 3
+    # the likely arm is the conditional's branch 1 (its predicate true)
+    likely = collections.Counter(
+        shape for shape, name in re.findall(
+            r"= bf16\[(\d+,(?:2048|16,128))\]\S* gather\(.*?op_name=\"([^\"]*)\"",
+            text) if "branch_1_fun" in name)
+    assert likely == {"40960,2048": 4 * 3, "40963,16,128": 4 * 2,
+                      "32768,16,128": 4 * 2}, likely
     # the epoch's report leaves the program: [expert layers, held] and a count
     assert "f32[4,8]" in text.split("ENTRY")[1].split("\n")[0]
