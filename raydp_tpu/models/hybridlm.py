@@ -1,11 +1,16 @@
 """Hybrid decoder LM: a MIXER KIND (``mamba``, ``attention``, ``conv``) and an
 FFN KIND (``dense``, ``experts``) per layer, in the order ``layer_types`` and
-``ffn_types`` give. Two published families are built from their
+``ffn_types`` give. Three published families are built from their
 ``config.json`` (``from_config`` reads ``model_type``): ``granitemoehybrid``
 with no experts (Mamba-2 and grouped-query attention without positions, a
-dense SwiGLU after each, four multipliers) and ``lfm2_moe`` (gated short
+dense SwiGLU after each, four multipliers), ``lfm2_moe`` (gated short
 convolutions and grouped-query attention with RoPE and per-head q/k norms;
-leading dense SwiGLUs, then routed experts of which this chip holds a share).
+leading dense SwiGLUs, then routed experts of which this chip holds a share)
+and ``smallthinker`` (every layer grouped-query attention with an explicit
+``head_dim`` and routed ReGLU experts: per layer a window and RoPE, or
+neither, as ``sliding_window_layout`` and ``rope_layout`` say; the router is
+fed from the block's INPUT, before the first norm, and takes a softmax over
+the experts it selected; an untied head).
 
 ::
 
@@ -13,11 +18,16 @@ leading dense SwiGLUs, then routed experts of which this chip holds a share).
     for mixer, ffn in zip(layer_types, ffn_types):
       h = h + residual_multiplier Mixer_mixer(RMSNorm(h))     # pre-norm only
       h = h + residual_multiplier FFN_ffn(RMSNorm(h))
-    logits = RMSNorm_f(h) E^T / logits_scaling               # tied head
+    logits = RMSNorm_f(h) E^T / logits_scaling               # tied head; or
+                                                             # W_head, untied
     loss = mean next-token cross-entropy
 
 ``dense``: ``[g, u] = W_in y; W_out(silu(g) u)``. ``experts``
-(``ops.experts.routed_experts``, which says how): sigmoid scores over ALL
+(``ops.experts.routed_experts``, which says how, and the second scoring
+rule, ``expert_scoring="softmax"``: the ``experts_per_token`` largest logits
+and a softmax over them, no bias; ``expert_activation``: ``silu`` or
+``relu``; ``router_input="block"``: the router reads the block's input
+``h`` in ``y``'s place): sigmoid scores over ALL
 ``experts_total`` experts, the ``experts_per_token`` largest of score +
 ``expert_bias`` (the bias enters the selection only; what the layer hands
 back as its gradient is each expert's excess load, of which
@@ -40,15 +50,31 @@ epoch's steps and hands to ``epoch_facts``.
 
 depthwise over time, ``conv_kernel`` taps, no bias, float32.
 
-``attention``: q of ``num_heads`` heads, k and v of ``num_kv_heads`` (each
+``attention``: q of ``num_heads`` heads of ``head_dim`` (the hidden size
+over the heads unless given), k and v of ``num_kv_heads`` (each
 serves ``num_heads / num_kv_heads`` consecutive query heads), no bias;
 ``qk_norm``: an RMSNorm over each head of q and of k (gains [head_dim]);
 ``rope_theta`` > 0: RoPE (``looplm``'s rotate-half) on q and k, else no
-positions; causal softmax of ``attention_multiplier q.k``. It runs through
+positions (``rope_layers``, a flag a layer, takes RoPE away from the layers
+it marks 0); causal softmax of ``attention_multiplier q.k``, and in a layer
+whose entry of ``attention_windows`` is W > 0 over the query's own position
+and the W - 1 before it only (scope ``hybridlm.attention.window``, the
+others ``hybridlm.attention.global``; the flash kernels' grid follows the
+window). It runs through
 the repo's ``_attend`` (``attn_impl="flash"`` on the chip): q is multiplied
 by ``attention_multiplier sqrt(head_dim)`` beforehand, so that the kernels'
 own ``head_dim ** -0.5`` gives the multiplier, and K and V are repeated to
 the query heads in HBM.
+
+PLACEMENT (``placed_by_load``). The softmax-over-the-selected rule takes no
+bias, so no rule evens its load, and which experts sit on this chip is what
+a group has left to choose: from the load the seeded routers give on the
+training batches, layer after layer, ``ops.experts.place`` deals each
+layer's experts to the group's chips and the router's columns are re-ordered
+so that this chip's slots score the experts placed here
+(``expert_placement``, read by ``init`` alone: the experts' own weights are
+seeded alike). ``embed_std`` is the embedding's seeded spread; the matrices'
+is 0.02.
 
 ``mamba`` (Mamba-2; H heads of P, one group, state N)::
 
@@ -76,6 +102,9 @@ accumulator of the embedding's own [V, D] shape, and recomputes nothing.
 
 from __future__ import annotations
 
+import contextlib
+import functools
+import logging
 import math
 from typing import Any, Sequence
 
@@ -151,25 +180,49 @@ class HybridLM(nn.Module):
     experts_per_token: int = 0
     routed_scaling: float = 1.0
     expert_bias_spread: float = 0.0  # expert_bias ~ U(+-spread); 0: zeros
+    # -- what the third family adds; the defaults build the first two --------
+    head_dim: Any = None  # None: hidden_size // num_heads
+    attention_windows: Sequence[int] = ()  # keys a layer sees; 0: all. (): 0s
+    rope_layers: Sequence[int] = ()  # 1: the layer carries RoPE. (): all do
+    expert_scoring: str = "sigmoid"  # or "softmax": over the selected, no bias
+    expert_activation: str = "silu"  # or "relu"
+    router_input: str = "ffn"  # or "block": the block's input, before norm1
+    tied_head: bool = True  # False: a head [D, V] of its own
+    embed_std: float = 0.02  # the embedding's seeded spread (matrices: 0.02)
+    # an expert layer's seeded router columns in the order the group PLACED
+    # its experts (``placed_by_load``); (): as seeded. Read by ``init`` alone
+    expert_placement: Sequence[Sequence[int]] = ()
 
     # what ``loss`` reports of a TRAINING step beside its loss, by name in
     # its ``aux``: the estimator sums these over an epoch's steps inside the
     # epoch program and gives the sums to ``epoch_facts``
     train_report = ("expert_load", "pairs_dropped", "layers_at_full_bound")
 
+    def __post_init__(self):
+        if self.head_dim is None:
+            if self.hidden_size % self.num_heads:
+                raise ValueError("query heads must divide the hidden size "
+                                 "where no head_dim is given")
+            object.__setattr__(self, "head_dim",
+                               self.hidden_size // self.num_heads)
+        super().__post_init__()
+
     @classmethod
     def from_config(cls, config: dict, **kw):
         """From a published ``config.json``'s keys, by ``model_type``
-        (``granitemoehybrid``, the default, or ``lfm2_moe``). What the model
-        does not build is refused, not ignored."""
+        (``granitemoehybrid``, the default, ``lfm2_moe`` or
+        ``smallthinker``). What the model does not build is refused, not
+        ignored."""
         family = config.get("model_type", "granitemoehybrid")
         if family == "granitemoehybrid":
             fields = cls._granite_fields(config)
         elif family == "lfm2_moe":
             fields = cls._lfm2_fields(config)
+        elif family == "smallthinker":
+            fields = cls._smallthinker_fields(config)
         else:
-            raise ValueError(f"HybridLM builds model_type granitemoehybrid "
-                             f"and lfm2_moe, not {family!r}")
+            raise ValueError(f"HybridLM builds model_type granitemoehybrid, "
+                             f"lfm2_moe and smallthinker, not {family!r}")
         fields.update(kw)  # attn_impl, dtype, remat, loss_chunk; overrides
         fields["dtype"] = jnp.dtype(fields.get("dtype", cls.dtype))
         return cls(**fields)
@@ -192,9 +245,8 @@ class HybridLM(nn.Module):
             "position_embedding_type": "nope", "hidden_act": "silu"},
             {"num_local_experts": (
                 ": this family's experts are a shared expert beside routed "
-                "ones under another routing rule (softmax over the "
-                "selected), which is not built; the experts FFN kind here "
-                "is lfm2_moe's")})
+                "ones, and a shared expert is not built; the experts FFN "
+                "kind here is lfm2_moe's and smallthinker's")})
         if config["mamba_expand"] * config["hidden_size"] != (
                 config["mamba_n_heads"] * config["mamba_d_head"]):
             raise ValueError("mamba_expand x hidden_size is not "
@@ -260,10 +312,91 @@ class HybridLM(nn.Module):
             attention_multiplier=head_dim ** -0.5, logits_scaling=1.0,
             rms_eps=float(config["norm_eps"]))
 
+    @classmethod
+    def _smallthinker_fields(cls, config: dict) -> dict:
+        """``num_hidden_layers`` layers from ``share["first_layer"]`` on (a
+        pipeline stage's), each grouped-query attention under routed ReGLU
+        experts: layer l sees ``sliding_window_size`` keys where
+        ``sliding_window_layout[l]`` is 1 and all of them where 0, and
+        carries RoPE where ``rope_layout[l]`` is 1 (two independent keys:
+        each layer reads its own entry of each). ``moe_num_primary_experts``
+        experts are held here, ``share["first_expert"]`` the first, of the
+        ``share["experts_total"]`` the router scores (both default to the
+        whole). The layers built must be a whole number of the layouts'
+        periods from a period's first layer."""
+        cls._refuse(config, {
+            "moe_primary_router_apply_softmax": True, "norm_topk_prob": True,
+            "tie_word_embeddings": False, "rope_scaling": None},
+            {"moe_primary_router_apply_softmax": (
+                ": the sigmoid rule of this family (scores normalised over "
+                "the selected, no bias) is not built")})
+        share = config.get("share", {})
+        first, depth = share.get("first_layer", 0), config["num_hidden_layers"]
+        layout = list(zip(config["sliding_window_layout"],
+                          config["rope_layout"]))
+        period = next(p for p in range(1, len(layout) + 1)
+                      if not len(layout) % p
+                      and all(layout[i] == layout[i % p]
+                              for i in range(len(layout))))
+        if (first % period or depth % period or depth <= 0
+                or first + depth > len(layout)):
+            raise ValueError(
+                f"smallthinker layers {first}..{first + depth - 1} of "
+                f"{len(layout)}: not a whole number of periods of {period} "
+                "layers (sliding_window_layout, rope_layout) from a "
+                "period's first layer")
+        held = config["moe_num_primary_experts"]
+        return dict(
+            vocab_size=config["vocab_size"],
+            layer_types=(ATTENTION,) * depth, ffn_types=(EXPERTS,) * depth,
+            hidden_size=config["hidden_size"],
+            num_heads=config["num_attention_heads"],
+            num_kv_heads=config["num_key_value_heads"],
+            head_dim=config["head_dim"],
+            attention_windows=tuple(
+                config["sliding_window_size"] if windowed else 0
+                for windowed, _ in layout[first:first + depth]),
+            rope_layers=tuple(
+                int(bool(rope)) for _, rope in layout[first:first + depth]),
+            rope_theta=float(config["rope_theta"]),
+            expert_width=config["moe_ffn_hidden_size"],
+            experts_held=held,
+            experts_total=share.get("experts_total", held),
+            first_expert=share.get("first_expert", 0),
+            experts_per_token=config["moe_num_active_primary_experts"],
+            expert_scoring="softmax", expert_activation="relu",
+            router_input="block", tied_head=False,
+            embedding_multiplier=1.0, residual_multiplier=1.0,
+            attention_multiplier=config["head_dim"] ** -0.5,
+            logits_scaling=1.0, rms_eps=float(config["rms_norm_eps"]))
+
     # -- shapes ----------------------------------------------------------------
     @property
-    def head_dim(self) -> int:
-        return self.hidden_size // self.num_heads
+    def attention_width(self) -> int:
+        """Query heads x head_dim: what ``wq`` gives and ``wo`` takes."""
+        return self.num_heads * self.head_dim
+
+    @property
+    def layer_windows(self) -> tuple:
+        """Keys a layer's queries see, a number a layer; 0: all of them."""
+        return tuple(self.attention_windows) or (0,) * len(self.layer_types)
+
+    @property
+    def layer_ropes(self) -> tuple:
+        """Whether a layer's attention carries RoPE, a flag a layer."""
+        return tuple(bool(r) for r in self.rope_layers) or (
+            bool(self.rope_theta),) * len(self.layer_types)
+
+    def attention_pairs(self, t: int) -> int:
+        """(query, key) pairs the attention layers of a row of ``t`` tokens
+        need: t (t + 1) / 2 for a global layer, W (W + 1) / 2 + (t - W) W
+        for a layer that sees W < t keys."""
+        def pairs(window):
+            w = min(window, t) if window else t
+            return w * (w + 1) // 2 + (t - w) * w
+
+        return sum(pairs(window) for kind, window in zip(
+            self.layer_types, self.layer_windows) if kind == ATTENTION)
 
     @property
     def mamba_inner(self) -> int:
@@ -288,9 +421,9 @@ class HybridLM(nn.Module):
                      "w13": (d, 2 * self.expert_width),
                      "w2": (self.expert_width, d)}
         if kind == ATTENTION:
-            kv = self.num_kv_heads * self.head_dim
-            return {"wq": (d, d), "wk": (d, kv), "wv": (d, kv), "wo": (d, d),
-                    **after}
+            kv, wide = self.num_kv_heads * self.head_dim, self.attention_width
+            return {"wq": (d, wide), "wk": (d, kv), "wv": (d, kv),
+                    "wo": (wide, d), **after}
         if kind == CONV:
             return {"in_proj": (d, 3 * d), "out_proj": (d, d), **after}
         return {"in_proj": (d, 2 * inner + 2 * self.mamba_state
@@ -324,9 +457,22 @@ class HybridLM(nn.Module):
         if len(ffns) != len(kinds) or set(ffns) - {DENSE, EXPERTS}:
             raise ValueError(f"ffn_types {ffns} does not give {DENSE!r} or "
                              f"{EXPERTS!r} for each of {len(kinds)} layers")
-        if self.num_heads % self.num_kv_heads or d % self.num_heads:
-            raise ValueError("query heads must divide the hidden size, "
-                             "K/V heads the query heads")
+        if self.num_heads % self.num_kv_heads:
+            raise ValueError("K/V heads must divide the query heads")
+        if (len(self.layer_windows) != len(kinds)
+                or len(self.layer_ropes) != len(kinds)):
+            raise ValueError(
+                f"attention_windows {tuple(self.attention_windows)} and "
+                f"rope_layers {tuple(self.rope_layers)} give a number for "
+                f"each of {len(kinds)} layers, or are empty")
+        if (self.expert_scoring not in experts_op.SCORINGS
+                or self.expert_activation not in experts_op.ACTIVATIONS
+                or self.router_input not in ("ffn", "block")):
+            raise ValueError(
+                f"expert_scoring {self.expert_scoring!r}, expert_activation "
+                f"{self.expert_activation!r}, router_input "
+                f"{self.router_input!r}: not among {experts_op.SCORINGS}, "
+                f"{tuple(experts_op.ACTIVATIONS)}, ('ffn', 'block')")
         if EXPERTS in ffns and not (
                 0 < self.experts_per_token <= self.experts_total
                 and 0 < self.experts_held
@@ -336,9 +482,15 @@ class HybridLM(nn.Module):
                 f"experts {self.first_expert}..+{self.experts_held} of "
                 f"{self.experts_total}, {self.experts_per_token} a token, "
                 f"width {self.expert_width}: not an expert layer's share")
+        if self.expert_placement and [sorted(o) for o in
+                                      self.expert_placement] != [
+                list(range(self.experts_total))] * ffns.count(EXPERTS):
+            raise ValueError(
+                "expert_placement gives each expert layer a permutation of "
+                f"its {self.experts_total} experts, or is empty")
         matrix = nn.initializers.normal(0.02)
 
-        def layer(kind, ffn):
+        def layer(kind, ffn, order=None):
             def init(rng):
                 shapes = self.matrix_shapes(kind, ffn)
                 keys = jax.random.split(rng, len(shapes) + 3)
@@ -347,6 +499,8 @@ class HybridLM(nn.Module):
                     if name in ("w13", "w2"):  # one a held expert, stacked
                         shape = (self.experts_held,) + shape
                     out[name] = matrix(k, shape, jnp.float32)
+                if order is not None:  # slot j scores the seeded expert order[j]
+                    out["router"] = out["router"][:, jnp.asarray(order)]
                 out.update(norm1=jnp.ones((d,), jnp.float32),
                            norm2=jnp.ones((d,), jnp.float32))
                 if kind == MAMBA:
@@ -360,7 +514,7 @@ class HybridLM(nn.Module):
                 elif self.qk_norm:
                     out.update(q_norm=jnp.ones((self.head_dim,), jnp.float32),
                                k_norm=jnp.ones((self.head_dim,), jnp.float32))
-                if ffn == EXPERTS:
+                if ffn == EXPERTS and self.expert_scoring == "sigmoid":
                     spread = self.expert_bias_spread
                     out["expert_bias"] = jax.random.uniform(
                         keys[-2], (self.experts_total,), jnp.float32,
@@ -369,11 +523,18 @@ class HybridLM(nn.Module):
                 return out
             return init
 
-        self.embed = self.param("embed", matrix, (self.vocab_size, d),
-                                jnp.float32)
-        self.layers = [self.param(f"layer_{i}", layer(kind, ffn))
-                       for i, (kind, ffn) in enumerate(zip(kinds, ffns))]
+        self.embed = self.param(
+            "embed", nn.initializers.normal(self.embed_std),
+            (self.vocab_size, d), jnp.float32)
+        placed = iter(self.expert_placement or [None] * len(kinds))
+        self.layers = [
+            self.param(f"layer_{i}", layer(
+                kind, ffn, next(placed) if ffn == EXPERTS else None))
+            for i, (kind, ffn) in enumerate(zip(kinds, ffns))]
         self.final_norm = self.param("final_norm", nn.initializers.ones, (d,),
+                                     jnp.float32)
+        if not self.tied_head:
+            self.head_w = self.param("head", matrix, (d, self.vocab_size),
                                      jnp.float32)
 
     def _mamba_vectors(self, keys) -> dict:
@@ -440,6 +601,16 @@ class HybridLM(nn.Module):
                         self.experts_per_token),
                 "experts.flops_per_row": parts["experts"],
                 "experts.flops_counted": "uniform share"})
+        if any(self.layer_windows):
+            attention = [w for kind, w in zip(self.layer_types,
+                                              self.layer_windows)
+                         if kind == ATTENTION]
+            facts.update({
+                "layer_kinds.window": sum(1 for w in attention if w),
+                "layer_kinds.global": attention.count(0),
+                "attention.window": max(attention),
+                # what the step's attention NEEDS: the pairs the windows keep
+                "attention.pairs_per_row": self.attention_pairs(t)})
         return facts
 
     def flops_per_row_parts(self, t: int) -> dict:
@@ -447,8 +618,9 @@ class HybridLM(nn.Module):
         ``layers`` (6 x matrix parameters x tokens, a convolution's 2 k a
         channel beside them; routers here, experts not), ``scan`` (the dual
         form's four products at this chunk size, causal pairs inside a
-        chunk), ``attention`` (causal: t (t + 1) / 2 kept pairs), ``head``
-        (the tied embedding, once) and, with expert layers, ``experts`` (6
+        chunk), ``attention`` (causal: t (t + 1) / 2 kept pairs, a window
+        layer's fewer: ``attention_pairs``), ``head``
+        (the embedding or the head, once) and, with expert layers, ``experts`` (6
         x one expert's parameters x the uniform share of the pairs)."""
         d, n = self.hidden_size, self.mamba_state
         heads, p = self.mamba_heads, self.mamba_head_dim
@@ -466,8 +638,7 @@ class HybridLM(nn.Module):
         parts = {
             "layers": 6 * (matrices + conv) * t,
             "scan": 3 * scan,
-            "attention": self.layer_types.count(ATTENTION)
-            * 12 * d * (t * (t + 1) // 2),
+            "attention": 12 * self.attention_width * self.attention_pairs(t),
             "head": 6 * d * self.vocab_size * t}
         if self.expert_layers:
             # tokens x k x held / total pairs a layer, whole numbers here
@@ -484,9 +655,10 @@ class HybridLM(nn.Module):
         attention's two only where the flash kernel names them."""
         if not self.remat:
             return {}
-        wide = t * self.hidden_size * jnp.dtype(self.dtype).itemsize
+        itemsize = jnp.dtype(self.dtype).itemsize
+        wide = t * self.hidden_size * itemsize
         attention = self.layer_types.count(ATTENTION)
-        sizes = {"attn_out": attention * wide,
+        sizes = {"attn_out": attention * t * self.attention_width * itemsize,
                  "attn_lse": attention * 4 * self.num_heads * t,
                  "mlp_out": len(self.layer_types) * wide}
         flash = self.attn_impl in ("flash", "ulysses_flash")
@@ -536,8 +708,15 @@ class HybridLM(nn.Module):
                 + self.residual_multiplier * m.astype(jnp.float32)
                 ).astype(h.dtype)
 
-    def _attention(self, w, y):
-        with obs.device_scope("hybridlm.attention"):
+    def _attention(self, w, y, window: int = 0, rope: bool = True):
+        """``window`` > 0: the layer's queries see that many keys, their own
+        position among them; ``rope`` False: no positions, whatever
+        ``rope_theta``. A model with window layers names the two kinds
+        (``hybridlm.attention.window`` / ``.global`` inside the scope)."""
+        kind = contextlib.nullcontext() if not any(
+            self.layer_windows) else obs.device_scope(
+                "hybridlm.attention." + ("window" if window else "global"))
+        with obs.device_scope("hybridlm.attention"), kind:
             b, t, _ = y.shape
             dh, group = self.head_dim, self.num_heads // self.num_kv_heads
 
@@ -548,7 +727,7 @@ class HybridLM(nn.Module):
             if self.qk_norm:
                 q = rms_norm(q, w["q_norm"], self.rms_eps)
                 k = rms_norm(k, w["k_norm"], self.rms_eps)
-            if self.rope_theta:
+            if self.rope_theta and rope:
                 cos, sin = rope_tables(t, dh, self.rope_theta)
                 q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
             # the attention's own scale is head_dim ** -0.5
@@ -556,8 +735,9 @@ class HybridLM(nn.Module):
             q = q * jnp.asarray(scale, self.dtype)
             k = jnp.repeat(k, group, axis=1)
             v = jnp.repeat(split(self._dot(y, w["wv"])), group, axis=1)
-            o = _attend(q, k, v, impl=self.attn_impl, axis="sp", causal=True)
-            o = o.transpose(0, 2, 1, 3).reshape(b, t, self.hidden_size)
+            o = _attend(q, k, v, impl=self.attn_impl, axis="sp", causal=True,
+                        window=window or None)
+            o = o.transpose(0, 2, 1, 3).reshape(b, t, self.attention_width)
             return self._dot(o, w["wo"])
 
     def _conv(self, w, x):
@@ -609,33 +789,44 @@ class HybridLM(nn.Module):
             return checkpoint_name(self._dot(nn.silu(g) * u, w["w_out"]),
                                    "mlp_out")
 
-    def _experts(self, w, y):
+    def _experts(self, w, y, block_input=None):
         """(this chip's part of the routed experts' result [B, T, D], what
-        the layer reports: ``ops.experts.routed_experts``'s)."""
+        the layer reports: ``ops.experts.routed_experts``'s).
+        ``block_input``: what the router reads in ``y``'s place
+        (``router_input="block"``)."""
         with obs.device_scope("hybridlm.experts"):
             b, t, d = y.shape
             out, report = experts_op.routed_experts(
-                y.reshape(b * t, d), w["router"], w["expert_bias"], w["w13"],
-                w["w2"], first=self.first_expert,
+                y.reshape(b * t, d), w["router"], w.get("expert_bias"),
+                w["w13"], w["w2"], first=self.first_expert,
                 top_k=self.experts_per_token, scaling=self.routed_scaling,
-                scope="hybridlm.experts")
+                scope="hybridlm.experts", scoring=self.expert_scoring,
+                activation=self.expert_activation,
+                router_input=None if block_input is None
+                else block_input.reshape(b * t, d))
             report["sel"] = report["sel"].reshape(b, t, -1)
             return checkpoint_name(out.astype(self.dtype).reshape(b, t, d),
                                    "mlp_out"), report
 
-    def _block(self, kind, ffn, w, h):
-        """(h after the layer, what its FFN reports: {} for a dense one)."""
-        mixer = {MAMBA: self._mamba, ATTENTION: self._attention,
-                 CONV: self._short_conv}[kind]
+    def _block(self, kind, ffn, w, h, window: int = 0, rope: bool = True):
+        """(h after the layer, what its FFN reports: {} for a dense one).
+        ``window`` and ``rope`` are an attention layer's."""
+        mixer = {MAMBA: self._mamba, CONV: self._short_conv,
+                 ATTENTION: functools.partial(
+                     self._attention, window=window, rope=rope)}[kind]
+        # the router's input where it is the block's: h before the first norm
+        routed_from = h if self.router_input == "block" else None
         h = self._residual(h, mixer(w, rms_norm(h, w["norm1"], self.rms_eps)))
         y = rms_norm(h, w["norm2"], self.rms_eps)
-        out, report = self._experts(w, y) if ffn == EXPERTS else (
+        out, report = self._experts(w, y, routed_from) if ffn == EXPERTS else (
             self._mlp(w, y), {})
         return self._residual(h, out), report
 
     def head(self, h):
-        """Logits, float32, from the final norm's output (the tied head)."""
-        return jnp.dot(h, self.embed.T.astype(self.dtype),
+        """Logits, float32, from the final norm's output (the tied head, or
+        the model's own)."""
+        w = self.embed.T if self.tied_head else self.head_w
+        return jnp.dot(h, w.astype(self.dtype),
                        preferred_element_type=jnp.float32
                        ) / self.logits_scaling
 
@@ -646,12 +837,14 @@ class HybridLM(nn.Module):
         h = (self.embedding_multiplier * self.embed[tokens]).astype(self.dtype)
         keeps = REMAT_KEEPS + (EXPERT_KEEPS if self.expert_layers else ())
         block = jax.checkpoint(
-            self._block, static_argnums=(0, 1),
+            self._block, static_argnums=(0, 1, 4, 5),
             policy=jax.checkpoint_policies.save_only_these_names(*keeps),
         ) if self.remat else self._block
         reports = []
-        for kind, ffn, w in zip(self.layer_types, self.ffn_kinds, self.layers):
-            h, report = block(kind, ffn, w, h)
+        for kind, ffn, w, window, rope in zip(
+                self.layer_types, self.ffn_kinds, self.layers,
+                self.layer_windows, self.layer_ropes):
+            h, report = block(kind, ffn, w, h, window, rope)
             if report:
                 reports.append(report)
         stacked = {key: jnp.stack([r[key] for r in reports])
@@ -666,6 +859,54 @@ class HybridLM(nn.Module):
         """Logits [B, T, V]."""
         return self.head(self.hidden_states(tokens))
 
+    def placed_by_load(self, rng, batches):
+        """(this model with its experts PLACED by the load its seeded routers
+        give on ``batches`` (int32 [B, T+1] each), every expert layer's
+        share of the even load before and after): what an expert-parallel
+        group does at set-up about a router that takes NO bias, whose load
+        no rule evens. Layer after layer, first to last (where a layer's
+        experts sit decides which of them add to the stream the next
+        layer's router reads): every token's choice under the parameters
+        ``init(rng, ...)`` gives, ``ops.experts.place`` over the group's
+        ``experts_total / experts_held`` chips, and the router's columns
+        re-ordered so that this chip's slots score the experts placed
+        here. The experts' own weights are seeded alike, so which of them
+        a slot's weights "were" says nothing: the placement is a
+        permutation of the router's columns, ``expert_placement``, that
+        ``init`` applies, and the same ``rng`` then gives every caller the
+        placed parameters. One compile of the forward pass, a run a layer
+        and batch."""
+        chips = self.experts_total // max(self.experts_held, 1)
+        here = self.first_expert // max(self.experts_held, 1)
+        if (not self.expert_layers or self.expert_placement
+                or self.experts_total % self.experts_held
+                or self.first_expert % self.experts_held):
+            raise ValueError(
+                "placed_by_load places the experts of an unplaced model "
+                "whose share is one of experts_total / experts_held equal "
+                "chips'")
+        params = jax.jit(
+            lambda r: self.init(r, batches[0], None, method="loss"))(rng)
+        chosen = jax.jit(lambda p, x: self.apply(
+            p, x, None, True, method="loss")[1]["routing"])
+        names = [f"layer_{i}" for i, ffn in enumerate(self.ffn_kinds)
+                 if ffn == EXPERTS]
+        slots = slice(here * self.experts_held, (here + 1) * self.experts_held)
+        orders, before, after = [], [], []
+        for layer, name in enumerate(names):
+            loads = sum(np.bincount(
+                np.asarray(chosen(params, x)[layer]).ravel(),
+                minlength=self.experts_total) for x in batches)
+            order = experts_op.place(loads, chips)
+            orders.append(order)
+            before.append(float(loads[slots].sum() * chips / loads.sum()))
+            after.append(float(
+                loads[list(order[slots])].sum() * chips / loads.sum()))
+            w = params["params"][name]
+            params = {"params": {**params["params"], name: {
+                **w, "router": w["router"][:, np.asarray(order)]}}}
+        return self.clone(expert_placement=tuple(orders)), before, after
+
     def loss(self, x, y=None, with_states=False):
         """Mean next-token cross-entropy on ``x`` int32 [B, T+1] (inputs
         ``x[:, :-1]``, targets ``x[:, 1:]``; ``y`` is not used). Returns
@@ -677,8 +918,10 @@ class HybridLM(nn.Module):
         choice (for a comparison; not for a fit, whose evaluation would
         average them)."""
         h, reports = self._states(x[:, :-1])
+        head, contract = (self.embed, 1) if self.tied_head else (
+            self.head_w, 0)
         loss, _ = chunked_cross_entropy(
-            h, self.embed, 1, x[:, 1:], self.loss_chunk, "hybridlm.loss",
+            h, head, contract, x[:, 1:], self.loss_chunk, "hybridlm.loss",
             scale=1.0 / self.logits_scaling)
         aux = {}
         if reports:
@@ -716,7 +959,10 @@ def hybridlm_optimizer(learning_rate: float = 3e-4, b1: float = 0.9,
     expert layer hands back as the bias's gradient: the pairs that chose
     expert e over the even share, less 1 (``ops.experts.route``). 0: the
     biases stay under AdamW, which then moves them by the rate x the
-    excess's sign, more or less."""
+    excess's sign, more or less. A model WITHOUT such leaves (a router
+    that takes no bias: the softmax-over-the-selected rule) gives the rule
+    nothing to move: the optimizer then is AdamW alone, and says so once,
+    at ``init``."""
     import optax
 
     if not warmup_steps and not expert_bias_rate:
@@ -727,8 +973,21 @@ def hybridlm_optimizer(learning_rate: float = 3e-4, b1: float = 0.9,
     adamw = looplm_optimizer(rate, b1, b2, weight_decay)
     if not expert_bias_rate:
         return adamw
-    return optax.multi_transform(
-        {"adamw": adamw, "balance": optax.sgd(expert_bias_rate)},
-        lambda params: jax.tree_util.tree_map_with_path(
+
+    def labels(params):
+        return jax.tree_util.tree_map_with_path(
             lambda path, _: "balance" if getattr(
-                path[-1], "key", None) == "expert_bias" else "adamw", params))
+                path[-1], "key", None) == "expert_bias" else "adamw", params)
+
+    both = optax.multi_transform(
+        {"adamw": adamw, "balance": optax.sgd(expert_bias_rate)}, labels)
+
+    def init(params):
+        if "balance" not in jax.tree.leaves(labels(params)):
+            logging.getLogger(__name__).warning(
+                "hybridlm_optimizer(expert_bias_rate=%s): the model has no "
+                "expert_bias leaf, so the balancing rule moves nothing",
+                expert_bias_rate)
+        return both.init(params)
+
+    return optax.GradientTransformation(init, both.update)

@@ -25,18 +25,23 @@ from raydp_tpu.parallel.ring_attention import (
 )
 
 
-def _attend(q, k, v, *, impl: str, axis: str, causal: bool):
+def _attend(q, k, v, *, impl: str, axis: str, causal: bool,
+            window: int | None = None):
+    """``window`` (a query sees its own position and the ``window - 1``
+    before it) is built by ``full`` and ``flash`` only."""
     if impl == "skip":
         # diagnostic: attention replaced by identity — isolates the
         # non-attention step time for roofline decomposition (bench only)
         return v
     if impl == "full":
-        return full_attention(q, k, v, causal=causal)
+        return full_attention(q, k, v, causal=causal, window=window)
     if impl == "flash":
         from raydp_tpu.ops.flash_attention import flash_attention
 
         # default blocks = pick_blocks: the measured-fastest large tiles
-        return flash_attention(q, k, v, causal)
+        return flash_attention(q, k, v, causal, window=window)
+    if window is not None:
+        raise ValueError(f"attention impl {impl!r} builds no window")
     if impl == "ring":
         return ring_attention(q, k, v, axis_name=axis, causal=causal)
     if impl == "ring_flash":

@@ -6,13 +6,21 @@ exchange; nothing here stands in for the absent chips.
 
 ::
 
-    s = sigmoid(u W_g)                       [N, E], float32 (highest)
-    sel = top_k(s + b)                       b enters the SELECTION only
+    z = r W_g                                [N, E], float32 (highest); r
+                                             is u unless the caller hands
+                                             the router another input
+    scoring "sigmoid":  s = sigmoid(z); sel = top_k(s + b)   b enters the
+                        w = s[sel] / (sum s[sel] + 1e-6)     SELECTION only
+    scoring "softmax":  sel = top_k(z);  w = softmax(z[sel])    no bias
+    w = w x scaling                          over the k selected, held here
+                                             or not
     excess_e = pairs chosen for e / (N k / E) - 1    over ALL E, for the
-                                                     balancing rule
-    w = s[sel] / (sum s[sel] + 1e-6) x scaling      over the k selected,
-                                                     held here or not
-    out[n] = sum_{e in sel[n], e held} w[n, e] W2_e(silu(W1_e u_n) W3_e u_n)
+                                             balancing rule (with a bias)
+    out[n] = sum_{e in sel[n], e held} w[n, e] W2_e(act(W1_e u_n) W3_e u_n)
+
+``act`` is ``silu`` (SwiGLU) or ``relu`` (ReGLU). The scoring rule and the
+activation are static arguments a model's configuration chooses
+(``SCORINGS``, ``ACTIVATIONS``), not knobs.
 
 The (token, choice) pairs whose expert is held are ordered by expert (one
 stable sort of the pairs' keys), their tokens gathered into rows, the rows
@@ -64,6 +72,13 @@ second form the combine's weights take their gradient on the rows' side too
 (a dot a row beside the pass that computes the rows' cotangent, then one
 gather of a scalar a pair): no row is gathered for it.
 
+WHERE THE EXPERTS SIT (``place``). A router with a bias is evened by the
+balancing rule above. One without (the softmax rule) is not, and what a
+group then chooses is the placement: ``place(loads, chips)`` deals a layer's
+experts to the chips by the load observed, so that every chip holds near
+the even share; ``models.hybridlm.HybridLM.placed_by_load`` makes a model's
+seeded routers score the experts so placed.
+
 The grouped product is ``impl="ragged_dot"`` (``lax.ragged_dot``: XLA:TPU
 runs it as a Mosaic kernel of its own, ``%ragged-dot-*`` in a trace, and
 XLA:CPU expands it) or ``impl="megablox"``
@@ -85,6 +100,9 @@ from raydp_tpu import obs
 from raydp_tpu.ops import backend
 
 IMPLS = ("ragged_dot", "megablox")
+# the router's two scoring rules and the experts' two gate activations
+SCORINGS = ("sigmoid", "softmax")
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 # rows are visited a tile at a time: the bound is a multiple of it
 ROW_TILE = 512
 LANES = 128  # columns of a tile
@@ -127,14 +145,26 @@ def _hand_bias_bwd(excess, d_w):
 _hand_bias.defvjp(_hand_bias_fwd, _hand_bias_bwd)
 
 
-def route(u, w_gate, bias, top_k: int, scaling: float = 1.0):
-    """(sel int32 [N, k], w float32 [N, k]) of tokens ``u`` [N, D]: sigmoid
-    scores over all of ``w_gate``'s experts, the k largest of score + bias,
-    the selected scores normalised over the k. All of it float32, the
+def route(u, w_gate, bias, top_k: int, scaling: float = 1.0,
+          scoring: str = "sigmoid"):
+    """(sel int32 [N, k], w float32 [N, k]) of tokens ``u`` [N, D].
+    ``scoring="sigmoid"``: sigmoid scores over all of ``w_gate``'s experts,
+    the k largest of score + bias, the selected scores normalised over the
+    k. ``scoring="softmax"``: the k largest LOGITS (no bias: ``bias`` is
+    None), a softmax over the selected k. All of it float32, the
     product at ``highest``: a bf16 product moves a logit by 2e-3, and the
     4th and 5th scores of a token lie closer than that often enough."""
     logits = jnp.dot(u.astype(jnp.float32), w_gate.astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)
+    if scoring == "softmax":
+        if bias is not None:
+            raise ValueError("softmax over the selected takes no bias")
+        _, sel = lax.top_k(logits, top_k)
+        sel = checkpoint_name(sel.astype(jnp.int32), KEPT)  # as below
+        w = jax.nn.softmax(jnp.take_along_axis(logits, sel, axis=-1), axis=-1)
+        return sel, w * scaling
+    if scoring != "sigmoid":
+        raise ValueError(f"scoring {scoring!r} is not one of {SCORINGS}")
     scores = jax.nn.sigmoid(logits)
     _, sel = lax.top_k(scores + lax.stop_gradient(bias), top_k)
     # THE CHOICE IS KEPT, with the permutations made from it (``KEPT``): a
@@ -154,6 +184,44 @@ def excess_load(sel, experts: int):
     chosen = jnp.sum(sel[..., None] == jnp.arange(experts, dtype=jnp.int32),
                      axis=(0, 1), dtype=jnp.float32)
     return chosen * (experts / sel.size) - 1.0
+
+
+def place(loads, chips: int) -> tuple:
+    """A group's PLACEMENT of one layer's experts by observed load, on the
+    host: ``loads`` [E], the pairs that chose each expert -> ``order``, a
+    permutation of the E experts in which chip c of ``chips`` holds
+    ``order[c x E / chips : (c + 1) x E / chips]``. The heaviest expert
+    first, each to the chip with the least load so far that still has a
+    free slot; then, while it narrows the gap, the one swap of an expert
+    between the fullest and the emptiest chip that narrows it most. Every
+    chip ends near the even share wherever no single expert is most of a
+    chip's (a router WITHOUT a bias has no rule that evens its load: where
+    the experts sit is what a group has left to choose). Ties go to the
+    lower expert and the lower chip: the same loads, the same order."""
+    loads = [float(v) for v in loads]
+    per = len(loads) // chips
+    if per * chips != len(loads):
+        raise ValueError(f"{len(loads)} experts over {chips} chips")
+    held, total = [[] for _ in range(chips)], [0.0] * chips
+    for e in sorted(range(len(loads)), key=lambda e: (-loads[e], e)):
+        c = min((c for c in range(chips) if len(held[c]) < per),
+                key=lambda c: (total[c], c))
+        held[c].append(e)
+        total[c] += loads[e]
+    while True:
+        hi = max(range(chips), key=lambda c: (total[c], -c))
+        lo = min(range(chips), key=lambda c: (total[c], c))
+        gap = total[hi] - total[lo]
+        # moving d = loads[a] - loads[b] from hi to lo leaves |gap - 2 d|
+        swaps = [(abs(gap - 2 * (loads[a] - loads[b])), a, b)
+                 for a in held[hi] for b in held[lo]]
+        left, a, b = min(swaps) if swaps else (gap, None, None)
+        if not left < gap:
+            break
+        held[hi][held[hi].index(a)], held[lo][held[lo].index(b)] = b, a
+        total[hi] -= loads[a] - loads[b]
+        total[lo] += loads[a] - loads[b]
+    return tuple(e for chip in held for e in sorted(chip))
 
 
 def row_bound_for(pairs: int) -> int:
@@ -431,10 +499,10 @@ def _plan_at(p, bound: int):
             "row_valid": lax.iota(jnp.int32, bound) < p["rows"]}
 
 
-def _rows_pass(bound: int, impl, scope: str, u, w, w13, w2, p):
+def _rows_pass(bound: int, impl, scope: str, act: str, u, w, w13, w2, p):
     """Everything after the plan AT ``bound`` ROWS: dispatch, the two grouped
-    products with the activation between them, combine; [N, D] in ``u``'s
-    dtype. ``p`` is a plan made at ``bound`` rows or more; at fewer rows
+    products with the activation ``act`` between them, combine; [N, D] in
+    ``u``'s dtype. ``p`` is a plan made at ``bound`` rows or more; at fewer rows
     than it was made for, every row it fills must lie inside ``bound`` (the
     caller's predicate), so ``valid`` and ``sizes`` hold as they are."""
     two_f = w13.shape[2]
@@ -446,7 +514,7 @@ def _rows_pass(bound: int, impl, scope: str, u, w, w13, w2, p):
         gate, up = h[:, :two_f // 2], h[:, two_f // 2:]
         # rows past the groups hold whatever the kernel left: zeroed here,
         # in the pass that computes the activation anyway
-        a = jnp.where(q["row_valid"][:, None], jax.nn.silu(gate) * up, 0)
+        a = jnp.where(q["row_valid"][:, None], ACTIVATIONS[act](gate) * up, 0)
         y = grouped_dot(a.astype(u.dtype), w2, p["sizes"], impl)
     with obs.device_scope(f"{scope}.combine"):
         # in the tokens' dtype, which the rows have: a conditional's result
@@ -467,8 +535,9 @@ def _by_load(likely: int, p, at_bound, *operands):
         functools.partial(at_bound, p["tok"].shape[0]), *operands))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def _rows_pass_by_load(likely: int, impl, scope: str, u, w, w13, w2, p):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
+def _rows_pass_by_load(likely: int, impl, scope: str, act: str, u, w, w13, w2,
+                       p):
     """``_rows_pass`` at ``likely`` rows where the load fits and at the
     plan's own (worst-case) bound where it does not. ITS BACKWARD PASS IS
     ITS OWN: autodiff through ``lax.cond`` returns BOTH branches' residuals
@@ -481,21 +550,22 @@ def _rows_pass_by_load(likely: int, impl, scope: str, u, w, w13, w2, p):
     that keeps the layer's output, as ``HybridLM``'s does, runs the pass
     once forward and once in the backward pass, as before)."""
     return _by_load(
-        likely, p, lambda bound, *a: _rows_pass(bound, impl, scope, *a),
+        likely, p, lambda bound, *a: _rows_pass(bound, impl, scope, act, *a),
         u, w, w13, w2, p)
 
 
-def _rows_pass_by_load_fwd(likely, impl, scope, u, w, w13, w2, p):
-    return (_rows_pass_by_load(likely, impl, scope, u, w, w13, w2, p),
+def _rows_pass_by_load_fwd(likely, impl, scope, act, u, w, w13, w2, p):
+    return (_rows_pass_by_load(likely, impl, scope, act, u, w, w13, w2, p),
             (u, w, w13, w2, p))
 
 
-def _rows_pass_by_load_bwd(likely, impl, scope, kept, d_out):
+def _rows_pass_by_load_bwd(likely, impl, scope, act, kept, d_out):
     *inputs, p = kept
 
     def back(bound, u, w, w13, w2, p, d_out):
         _, pull = jax.vjp(
-            lambda *a: _rows_pass(bound, impl, scope, *a, p), u, w, w13, w2)
+            lambda *a: _rows_pass(bound, impl, scope, act, *a, p),
+            u, w, w13, w2)
         return pull(d_out)
 
     return (*_by_load(likely, p, back, *inputs, p, d_out), None)
@@ -506,13 +576,21 @@ _rows_pass_by_load.defvjp(_rows_pass_by_load_fwd, _rows_pass_by_load_bwd)
 
 def routed_experts(u, w_gate, bias, w13, w2, *, first: int, top_k: int,
                    scaling: float = 1.0, row_bound: int | None = None,
-                   impl: str | None = None, scope: str = "experts"):
+                   impl: str | None = None, scope: str = "experts",
+                   scoring: str = "sigmoid", activation: str = "silu",
+                   router_input=None):
     """This chip's part of the routed experts' result for tokens ``u``
     [N, D]: ``(out [N, D] in ``u``'s dtype, report)``.
 
     ``w_gate`` [D, E] and ``bias`` [E] are the router's, over ALL E experts;
     ``w13`` [held, D, 2F] (gate | up) and ``w2`` [held, F, D] the experts
-    ``first .. first + held - 1``. ``row_bound=None``: the layer chooses
+    ``first .. first + held - 1``. ``scoring``: ``"sigmoid"`` (sigmoid
+    scores, the k largest of score + ``bias``, normalised over the k) or
+    ``"softmax"`` (the k largest logits, a softmax over the selected;
+    ``bias`` None). ``activation``: the experts' gate's, ``"silu"`` or
+    ``"relu"``. ``router_input`` [N, D]: what the router reads in ``u``'s
+    place (a model whose router is fed from the block's input, before its
+    attention); the experts read ``u`` either way. ``row_bound=None``: the layer chooses
     (``likely_row_bound`` where the batch's load fits it, tokens x k where
     it does not: nothing dropped, whatever the load). ``row_bound`` given:
     that bound and no other, pairs past it left out and counted.
@@ -523,6 +601,9 @@ def routed_experts(u, w_gate, bias, w13, w2, *, first: int, top_k: int,
     load overflowed the likely one, else 0). ``bias`` comes back from a
     backward pass with ``excess_load`` in its gradient's place
     (``_hand_bias``)."""
+    if activation not in ACTIVATIONS:
+        raise ValueError(f"activation {activation!r} is not one of "
+                         f"{tuple(ACTIVATIONS)}")
     n, _ = u.shape
     count = w13.shape[0]
     total = w_gate.shape[1]
@@ -535,9 +616,11 @@ def routed_experts(u, w_gate, bias, w13, w2, *, first: int, top_k: int,
     if row_bound is not None:
         worst = likely = row_bound
     with obs.device_scope(f"{scope}.route"):
-        sel, w = route(u, w_gate, bias, top_k, scaling)
-        # the bias's "gradient": every expert's excess load
-        w = _hand_bias(w, bias, excess_load(sel, total))
+        sel, w = route(u if router_input is None else router_input, w_gate,
+                       bias, top_k, scaling, scoring)
+        if bias is not None:
+            # the bias's "gradient": every expert's excess load
+            w = _hand_bias(w, bias, excess_load(sel, total))
     with obs.device_scope(f"{scope}.dispatch"):
         p = plan(sel, first, count, worst)
     report = {"sel": sel, "load": p.pop("load").astype(jnp.float32),
@@ -548,7 +631,8 @@ def routed_experts(u, w_gate, bias, w13, w2, *, first: int, top_k: int,
     # fuses into the optimizer's update as it did without a conditional
     w13, w2 = w13.astype(u.dtype), w2.astype(u.dtype)
     if likely < worst:
-        out = _rows_pass_by_load(likely, impl, scope, u, w, w13, w2, p)
+        out = _rows_pass_by_load(likely, impl, scope, activation, u, w, w13,
+                                 w2, p)
     else:  # every expert held, or a bound given: one program, no conditional
-        out = _rows_pass(worst, impl, scope, u, w, w13, w2, p)
+        out = _rows_pass(worst, impl, scope, activation, u, w, w13, w2, p)
     return out, report

@@ -8,6 +8,12 @@ matrix never materializes — O(T) memory instead of O(T²). Scores run on the
 MXU (`preferred_element_type=f32`); masking and the softmax update run on the
 VPU. Causal masking uses global positions (runtime offsets from SMEM), and
 k-blocks entirely in the future are skipped outright (~2x causal throughput).
+A causal WINDOW (``flash_attention(..., window=W)``: a query sees its own
+position and the W - 1 before it) bounds the GRID of all three kernels: the
+inner axis has only the steps a block's window can touch and the index maps
+start at the block's first live partner, so the blocks a window hides are
+neither stepped over nor fetched (``window_steps``); those calls carry names
+of their own (``flash_attention_window_fwd`` / ``_bwd_dq`` / ``_bwd_dkv``).
 
 One kernel family serves three surfaces:
 - ``flash_attention``: normalized output, offsets 0 — the single-device /
@@ -60,50 +66,107 @@ def use_onepass_default() -> bool:
     )
 
 
-def _causal_block_live(q_off_ref, k_off_ref, qi, ki, block_q, block_k, causal):
+def _causal_block_live(q_off_ref, k_off_ref, qi, ki, block_q, block_k, causal,
+                       window=None):
     """Whether a (q-block, k-block) pair has any unmasked entry. Causal: a
     k-block entirely in the future contributes nothing — skip its matmul +
     update outright (~2x causal throughput). Offsets are runtime values
-    (SMEM), so the predicate is computed at runtime too."""
+    (SMEM), so the predicate is computed at runtime too. ``window``: a
+    k-block entirely past the window (every key ``window`` or more
+    positions behind the block's first query) is dead as well."""
     if not causal:
         return ki >= 0
     q_last = q_off_ref[0] + qi * block_q + block_q - 1
     k_first = k_off_ref[0] + ki * block_k
-    return q_last >= k_first
+    live = q_last >= k_first
+    if window is not None:
+        q_first = q_off_ref[0] + qi * block_q
+        live = jnp.logical_and(live, k_first + block_k - 1 > q_first - window)
+    return live
 
 
-def _causal_mask(s, q_off_ref, k_off_ref, qi, ki, block_q, block_k):
+def _causal_mask(s, q_off_ref, k_off_ref, qi, ki, block_q, block_k,
+                 window=None):
     """Mask scores s [BQ, BK] to NEG_INF where global k position > q
-    position. Shared by the forward and both backward kernels so the mask
-    semantics can never diverge between them."""
+    position, and with a ``window`` where the key lies ``window`` or more
+    positions behind the query (a query sees its own position and the
+    ``window - 1`` before it). Shared by the forward and both backward
+    kernels so the mask semantics can never diverge between them."""
     q_pos = q_off_ref[0] + qi * block_q + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0
     )
     k_pos = k_off_ref[0] + ki * block_k + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 1
     )
-    return jnp.where(q_pos >= k_pos, s, NEG_INF)
+    keep = q_pos >= k_pos
+    if window is not None:
+        keep = jnp.logical_and(keep, q_pos - k_pos < window)
+    return jnp.where(keep, s, NEG_INF)
+
+
+# -- a window bounds the GRID ---------------------------------------------------
+# With ``window`` the inner grid axis has only as many steps as a block's
+# window can touch, and step j of q-block i reads k-block ``first + j``
+# (dk/dv: step j of k-block i reads q-block ``first + j``): the blocks past
+# the window are neither stepped over nor fetched. ``first`` is computed the
+# same way in the index maps (which clamp it into the array: a block index
+# must exist) and in the kernels (which do not, and predicate a step past
+# the last block off: clamped, it would read a block already seen).
+
+
+def _first_k_block(qi, block_q, block_k, window):
+    """The first k-block a q-block's window touches."""
+    return jnp.maximum(qi * block_q - (window - 1), 0) // block_k
+
+
+def _first_q_block(kj, block_q, block_k):
+    """The first q-block that sees a k-block (causal: the one its first key
+    lies in)."""
+    return (kj * block_k) // block_q
+
+
+def window_steps(t: int, block_q: int, block_k: int, window: int) -> tuple:
+    """(k-steps of the forward and dq grids, q-steps of the dk/dv grid) for
+    a causal window over ``t`` positions: the most blocks any q-block's
+    window touches (its keys span ``window + block_q - 1`` positions: never
+    more than ``ceil((window + block_q - 1) / block_k) + 1`` blocks), and
+    the most q-blocks that see any k-block."""
+    nq, nk = t // block_q, t // block_k
+    k_steps = max(
+        ((i + 1) * block_q - 1) // block_k
+        - max(i * block_q - (window - 1), 0) // block_k + 1
+        for i in range(nq))
+    q_steps = max(
+        min(((j + 1) * block_k - 1 + window - 1) // block_q, nq - 1)
+        - (j * block_k) // block_q + 1
+        for j in range(nk))
+    return k_steps, q_steps
 
 
 def _flash_kernel(
     q_off_ref, k_off_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
     o_acc, m_acc, l_acc, *, scale, causal, block_q, block_k, normalize,
+    window=None, inner_blocks=None,
 ):
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    step = pl.program_id(2)
     num_k = pl.num_programs(2)
+    ki = step if window is None else step + _first_k_block(
+        qi, block_q, block_k, window)
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         o_acc[:] = jnp.zeros_like(o_acc)
         m_acc[:] = jnp.full_like(m_acc, NEG_INF)
         l_acc[:] = jnp.zeros_like(l_acc)
 
     block_live = _causal_block_live(
-        q_off_ref, k_off_ref, qi, ki, block_q, block_k, causal
+        q_off_ref, k_off_ref, qi, ki, block_q, block_k, causal, window
     )
+    if window is not None:
+        block_live = jnp.logical_and(block_live, ki < inner_blocks)
 
     @pl.when(block_live)
     def _accumulate():
@@ -116,7 +179,8 @@ def _flash_kernel(
 
         if causal:
             scores = _causal_mask(
-                scores, q_off_ref, k_off_ref, qi, ki, block_q, block_k
+                scores, q_off_ref, k_off_ref, qi, ki, block_q, block_k,
+                window,
             )
 
         m_prev = m_acc[:, :1]  # [BQ, 1] (stats broadcast across lanes)
@@ -136,7 +200,7 @@ def _flash_kernel(
         m_acc[:] = jnp.broadcast_to(m_new, m_acc.shape)
         l_acc[:] = jnp.broadcast_to(l_new, l_acc.shape)
 
-    @pl.when(ki == num_k - 1)
+    @pl.when(step == num_k - 1)
     def _finalize():
         if normalize:
             o_ref[0] = (
@@ -151,6 +215,7 @@ def _flash_kernel(
 def _flash_kernel_onepass(
     q_off_ref, k_off_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
     o_acc, m_acc, l_acc, *, scale, causal, block_q, block_k, normalize,
+    window=None, inner_blocks=None,
 ):
     """One-pass online softmax with the accumulator rescale deferred.
 
@@ -165,18 +230,22 @@ def _flash_kernel_onepass(
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    step = pl.program_id(2)
     num_k = pl.num_programs(2)
+    ki = step if window is None else step + _first_k_block(
+        qi, block_q, block_k, window)
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         o_acc[:] = jnp.zeros_like(o_acc)
         m_acc[:] = jnp.full_like(m_acc, NEG_INF)
         l_acc[:] = jnp.zeros_like(l_acc)
 
     block_live = _causal_block_live(
-        q_off_ref, k_off_ref, qi, ki, block_q, block_k, causal
+        q_off_ref, k_off_ref, qi, ki, block_q, block_k, causal, window
     )
+    if window is not None:
+        block_live = jnp.logical_and(block_live, ki < inner_blocks)
 
     @pl.when(block_live)
     def _accumulate():
@@ -189,7 +258,8 @@ def _flash_kernel_onepass(
 
         if causal:
             scores = _causal_mask(
-                scores, q_off_ref, k_off_ref, qi, ki, block_q, block_k
+                scores, q_off_ref, k_off_ref, qi, ki, block_q, block_k,
+                window,
             )
 
         m_prev = m_acc[:, :1]  # [BQ, 1]
@@ -223,7 +293,7 @@ def _flash_kernel_onepass(
 
         m_acc[:] = jnp.broadcast_to(m_new, m_acc.shape)
 
-    @pl.when(ki == num_k - 1)
+    @pl.when(step == num_k - 1)
     def _finalize():
         if normalize:
             o_ref[0] = (
@@ -249,9 +319,27 @@ def _vary_like(x, vma):
     return jax.lax.pcast(x, missing, to="varying") if missing else x
 
 
+def _window_for(window, causal, t, tk, q_offset=0, k_offset=0):
+    """``window`` as the kernels take it: None where it hides no key (a
+    window of the whole sequence or more IS the causal call, the same
+    program under the same name); refused where the kernels do not build
+    it: the grids count a block's first live neighbour from position 0, so
+    both offsets must be the static 0 (a ring's step is not)."""
+    if window is None:
+        return None
+    if (not causal or t != tk or window < 1
+            or not all(isinstance(o, int) and o == 0
+                       for o in (q_offset, k_offset))):
+        raise ValueError(
+            f"a window ({window}) is built for causal self-attention only "
+            f"(causal={causal}, {t} queries over {tk} keys, offsets "
+            f"{q_offset!r} and {k_offset!r} that must be the static 0)")
+    return None if window >= tk else int(window)
+
+
 def _flash_call(
     q, k, v, q_offset, k_offset, causal, block_q, block_k, interpret,
-    normalize, onepass=None,
+    normalize, onepass=None, window=None,
 ):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -273,10 +361,24 @@ def _flash_call(
     kf = k.reshape(bh, tk, d)
     vf = v.reshape(bh, tk, d)
 
+    window = _window_for(window, causal, t, tk, q_offset, k_offset)
+    k_steps, k_block = tk // block_k, (lambda i, j: j)
+    if window is not None:
+        # the grid follows the window: only the k-blocks it can touch are
+        # stepped over and fetched (``window_steps``)
+        k_steps, _ = window_steps(t, block_q, block_k, window)
+        last = tk // block_k - 1
+
+        def k_block(i, j):
+            return jnp.minimum(
+                _first_k_block(i, block_q, block_k, window) + j, last)
+
     kernel = functools.partial(
         _flash_kernel_onepass if onepass else _flash_kernel,
         scale=d**-0.5, causal=causal, block_q=block_q, block_k=block_k,
         normalize=normalize,
+        **({} if window is None else dict(
+            window=window, inner_blocks=tk // block_k)),
     )
     union = _union_vma(qf, kf, vf)
 
@@ -294,13 +396,15 @@ def _flash_call(
             sds((bh, t, 1), jnp.float32),
             sds((bh, t, 1), jnp.float32),
         ),
-        grid=(bh, t // block_q, tk // block_k),
+        grid=(bh, t // block_q, k_steps),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b_, i, j: (b_, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b_, i, j: (b_, j, 0)),
+            pl.BlockSpec((1, block_k, d),
+                         lambda b_, i, j: (b_, k_block(i, j), 0)),
+            pl.BlockSpec((1, block_k, d),
+                         lambda b_, i, j: (b_, k_block(i, j), 0)),
         ],
         out_specs=(
             pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0)),
@@ -313,7 +417,11 @@ def _flash_call(
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
         interpret=interpret,
-        name="flash_attention_fwd",
+        # a window call under a name of its own: a reader that counts every
+        # ``flash_attention_fwd`` call as causal would credit it with the
+        # pairs the window hides
+        name="flash_attention_fwd" if window is None
+        else "flash_attention_window_fwd",
     )(q_off, k_off, qf, kf, vf)
     return (
         o.reshape(b, h, t, d),
@@ -341,10 +449,12 @@ def flash_attention_stats(
 
 
 def _flash_forward(
-    q, k, v, causal: bool, block_q: int, block_k: int, interpret: bool | None
+    q, k, v, causal: bool, block_q: int, block_k: int, interpret: bool | None,
+    window: int | None = None,
 ):
     o, _, _ = _flash_call(
-        q, k, v, 0, 0, causal, block_q, block_k, interpret, normalize=True
+        q, k, v, 0, 0, causal, block_q, block_k, interpret, normalize=True,
+        window=window,
     )
     return o
 
@@ -360,21 +470,26 @@ def _flash_forward(
 
 def _bwd_dq_kernel(
     q_off_ref, k_off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref,
-    dq_ref, dq_acc, *, scale, causal, block_q, block_k,
+    dq_ref, dq_acc, *, scale, causal, block_q, block_k, window=None,
+    inner_blocks=None,
 ):
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    step = pl.program_id(2)
     num_k = pl.num_programs(2)
+    ki = step if window is None else step + _first_k_block(
+        qi, block_q, block_k, window)
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
     block_live = _causal_block_live(
-        q_off_ref, k_off_ref, qi, ki, block_q, block_k, causal
+        q_off_ref, k_off_ref, qi, ki, block_q, block_k, causal, window
     )
+    if window is not None:
+        block_live = jnp.logical_and(block_live, ki < inner_blocks)
 
     @pl.when(block_live)
     def _accumulate():
@@ -389,7 +504,8 @@ def _bwd_dq_kernel(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale
         if causal:
-            s = _causal_mask(s, q_off_ref, k_off_ref, qi, ki, block_q, block_k)
+            s = _causal_mask(s, q_off_ref, k_off_ref, qi, ki, block_q, block_k,
+                             window)
         p = jnp.exp(s - lse)  # masked entries: exp(NEG_INF - lse) == 0
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -399,7 +515,7 @@ def _bwd_dq_kernel(
             ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
-    @pl.when(ki == num_k - 1)
+    @pl.when(step == num_k - 1)
     def _finalize():
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
@@ -407,21 +523,26 @@ def _bwd_dq_kernel(
 def _bwd_dkv_kernel(
     q_off_ref, k_off_ref, k_ref, v_ref, q_ref, do_ref, lse_ref, dsum_ref,
     dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal, block_q, block_k,
+    window=None, inner_blocks=None,
 ):
     from jax.experimental import pallas as pl
 
     kj = pl.program_id(1)
-    qi = pl.program_id(2)
+    step = pl.program_id(2)
     num_q = pl.num_programs(2)
+    qi = step if window is None else step + _first_q_block(
+        kj, block_q, block_k)
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
     block_live = _causal_block_live(
-        q_off_ref, k_off_ref, qi, kj, block_q, block_k, causal
+        q_off_ref, k_off_ref, qi, kj, block_q, block_k, causal, window
     )
+    if window is not None:
+        block_live = jnp.logical_and(block_live, qi < inner_blocks)
 
     @pl.when(block_live)
     def _accumulate():
@@ -436,7 +557,8 @@ def _bwd_dkv_kernel(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale
         if causal:
-            s = _causal_mask(s, q_off_ref, k_off_ref, qi, kj, block_q, block_k)
+            s = _causal_mask(s, q_off_ref, k_off_ref, qi, kj, block_q, block_k,
+                             window)
         p = jnp.exp(s - lse)  # [BQ, BK]
         dv_acc[:] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
@@ -449,14 +571,14 @@ def _bwd_dkv_kernel(
             ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
-    @pl.when(qi == num_q - 1)
+    @pl.when(step == num_q - 1)
     def _finalize():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
 def _flash_backward(
-    q, k, v, o, lse, g, causal, block_q, block_k, interpret
+    q, k, v, o, lse, g, causal, block_q, block_k, interpret, window=None
 ):
     """Blockwise dq/dk/dv for the single-device surface (offsets 0).
     lse: [B,H,T] logsumexp of the scaled scores; o: normalized forward
@@ -465,21 +587,24 @@ def _flash_backward(
         g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1
     )
     return flash_backward_blocks(
-        q, k, v, lse, dsum, g, 0, 0, causal, block_q, block_k, interpret
+        q, k, v, lse, dsum, g, 0, 0, causal, block_q, block_k, interpret,
+        window,
     )
 
 
 def flash_backward_blocks(
     q, k, v, lse, dsum, g, q_offset, k_offset, causal: bool = False,
     block_q: int | None = None, block_k: int | None = None,
-    interpret: bool | None = None,
+    interpret: bool | None = None, window: int | None = None,
 ):
     """One blockwise-backward pass: (dq, dk, dv) partials of q [B,H,Tq,D]
     against k/v [B,H,Tk,D], given the GLOBAL per-row logsumexp ``lse`` and
     ``dsum = rowsum(do·o)`` [B,H,Tq] and the blocks' global positions for
     causal masking — the per-ring-step counterpart of
     ``flash_attention_stats``: `parallel.ring_attention` sums these partials
-    as K/V (and their gradient accumulators) rotate around the ring."""
+    as K/V (and their gradient accumulators) rotate around the ring.
+    ``window`` (offsets 0, Tq = Tk: ``flash_attention``'s own backward pass):
+    both grids follow it, as the forward's does."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -511,18 +636,41 @@ def flash_backward_blocks(
     q_off = _vary_like(jnp.asarray([q_offset], jnp.int32).reshape(1), union)
     k_off = _vary_like(jnp.asarray([k_offset], jnp.int32).reshape(1), union)
 
+    window = _window_for(window, causal, t, tk, q_offset, k_offset)
+    k_steps, q_steps = tk // block_k, t // block_q
+    k_block = q_block = (lambda outer, j: j)
+    names = ("flash_attention_bwd_dq", "flash_attention_bwd_dkv")
+    dq_window = dkv_window = {}
+    if window is not None:
+        k_steps, q_steps = window_steps(t, block_q, block_k, window)
+        last_k, last_q = tk // block_k - 1, t // block_q - 1
+        names = ("flash_attention_window_bwd_dq",
+                 "flash_attention_window_bwd_dkv")
+        dq_window = dict(window=window, inner_blocks=tk // block_k)
+        dkv_window = dict(window=window, inner_blocks=t // block_q)
+
+        def k_block(i, j):
+            return jnp.minimum(
+                _first_k_block(i, block_q, block_k, window) + j, last_k)
+
+        def q_block(kj, j):
+            return jnp.minimum(
+                _first_q_block(kj, block_q, block_k) + j, last_q)
+
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     q_spec = pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0))
-    k_spec_dq = pl.BlockSpec((1, block_k, d), lambda b_, i, j: (b_, j, 0))
+    k_spec_dq = pl.BlockSpec(
+        (1, block_k, d), lambda b_, i, j: (b_, k_block(i, j), 0))
     stat_spec_dq = pl.BlockSpec((1, block_q, 1), lambda b_, i, j: (b_, i, 0))
 
     dq = pl.pallas_call(
         functools.partial(
             _bwd_dq_kernel,
             scale=scale, causal=causal, block_q=block_q, block_k=block_k,
+            **dq_window,
         ),
         out_shape=sds((bh, t, d), q.dtype),
-        grid=(bh, t // block_q, tk // block_k),
+        grid=(bh, t // block_q, k_steps),
         in_specs=[
             smem, smem, q_spec, k_spec_dq, k_spec_dq, q_spec,
             stat_spec_dq, stat_spec_dq,
@@ -530,21 +678,24 @@ def flash_backward_blocks(
         out_specs=q_spec,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
-        name="flash_attention_bwd_dq",
+        name=names[0],
     )(q_off, k_off, qf, kf, vf, dof, lsef, dsumf)
 
     # dk/dv: k-block outer, q-block inner
     k_spec = pl.BlockSpec((1, block_k, d), lambda b_, j, i: (b_, j, 0))
-    q_spec_kv = pl.BlockSpec((1, block_q, d), lambda b_, j, i: (b_, i, 0))
-    stat_spec_kv = pl.BlockSpec((1, block_q, 1), lambda b_, j, i: (b_, i, 0))
+    q_spec_kv = pl.BlockSpec(
+        (1, block_q, d), lambda b_, j, i: (b_, q_block(j, i), 0))
+    stat_spec_kv = pl.BlockSpec(
+        (1, block_q, 1), lambda b_, j, i: (b_, q_block(j, i), 0))
 
     dk, dv = pl.pallas_call(
         functools.partial(
             _bwd_dkv_kernel,
             scale=scale, causal=causal, block_q=block_q, block_k=block_k,
+            **dkv_window,
         ),
         out_shape=(sds((bh, tk, d), k.dtype), sds((bh, tk, d), v.dtype)),
-        grid=(bh, tk // block_k, t // block_q),
+        grid=(bh, tk // block_k, q_steps),
         in_specs=[
             smem, smem, k_spec, k_spec, q_spec_kv, q_spec_kv,
             stat_spec_kv, stat_spec_kv,
@@ -555,7 +706,7 @@ def flash_backward_blocks(
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
-        name="flash_attention_bwd_dkv",
+        name=names[1],
     )(q_off, k_off, kf, vf, qf, dof, lsef, dsumf)
 
     return (
@@ -607,16 +758,23 @@ def _reference(q, k, v, causal):
     return full_attention(q, k, v, causal=causal)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(
     q, k, v, causal: bool = False, block_q: int | None = None,
     block_k: int | None = None, interpret: bool | None = None,
+    window: int | None = None,
 ):
     """Fused attention: q,k,v [B, H, T, D] → [B, H, T, D]. ``block_q`` /
     ``block_k`` default to ``pick_blocks`` (measured-fastest large tiles);
     pass explicit sizes only to pin a tiling (tests / VMEM-constrained
-    shard_map bodies)."""
-    return _flash_forward(q, k, v, causal, block_q, block_k, interpret)
+    shard_map bodies). ``window`` (causal only): a query sees its own
+    position and the ``window - 1`` before it. THE GRID FOLLOWS THE WINDOW
+    in all three kernels: the blocks it hides are not stepped over and not
+    fetched (``window_steps``), and the calls are named
+    ``flash_attention_window_fwd`` / ``_bwd_dq`` / ``_bwd_dkv``. None, or a
+    window of the whole sequence or more, is the causal call as it was."""
+    return _flash_forward(q, k, v, causal, block_q, block_k, interpret,
+                          window)
 
 
 # the two residuals only the forward kernel can give, by the names a
@@ -628,9 +786,10 @@ def flash_attention(
 SAVED_RESIDUALS = ("attn_out", "attn_lse")
 
 
-def _fwd(q, k, v, causal, block_q, block_k, interpret):
+def _fwd(q, k, v, causal, block_q, block_k, interpret, window=None):
     o, m, l = _flash_call(  # noqa: E741
-        q, k, v, 0, 0, causal, block_q, block_k, interpret, normalize=True
+        q, k, v, 0, 0, causal, block_q, block_k, interpret, normalize=True,
+        window=window,
     )
     # residuals are O(T): inputs + normalized output + per-row logsumexp
     o = checkpoint_name(o, SAVED_RESIDUALS[0])
@@ -640,10 +799,10 @@ def _fwd(q, k, v, causal, block_q, block_k, interpret):
     return o, (q, k, v, o, lse)
 
 
-def _bwd(causal, block_q, block_k, interpret, residuals, g):
+def _bwd(causal, block_q, block_k, interpret, window, residuals, g):
     q, k, v, o, lse = residuals
     return _flash_backward(
-        q, k, v, o, lse, g, causal, block_q, block_k, interpret
+        q, k, v, o, lse, g, causal, block_q, block_k, interpret, window
     )
 
 

@@ -282,12 +282,20 @@ def ulysses_attention(
     return heads_to_seq(og)
 
 
-def full_attention(q, k, v, causal: bool = False) -> jnp.ndarray:
-    """Single-device reference implementation (for tests and small models)."""
+def full_attention(q, k, v, causal: bool = False,
+                   window: int | None = None) -> jnp.ndarray:
+    """Single-device reference implementation (for tests and small models).
+    ``window`` (causal only): a query sees its own position and the
+    ``window - 1`` before it."""
     scale = q.shape[-1] ** -0.5
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+    if window is not None and not causal:
+        raise ValueError("a window is built for causal attention only")
     if causal:
         tq, tk = scores.shape[-2:]
-        mask = jnp.arange(tq)[:, None] >= jnp.arange(tk)[None, :]
+        behind = jnp.arange(tq)[:, None] - jnp.arange(tk)[None, :]
+        mask = behind >= 0
+        if window is not None:
+            mask &= behind < window
         scores = jnp.where(mask, scores, NEG_INF)
     return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(scores, -1), v)

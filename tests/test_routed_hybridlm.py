@@ -602,7 +602,7 @@ def test_forms_agree(params, batch, want, form):
 
 
 def _route_mutant(kind):
-    def route(u, w_gate, bias, top_k, scaling=1.0):
+    def route(u, w_gate, bias, top_k, scaling=1.0, scoring="sigmoid"):
         logits = jnp.dot(u.astype(jnp.float32), w_gate,
                          precision=jax.lax.Precision.HIGHEST)
         scores = (jax.nn.softmax(logits, axis=-1) if kind == "softmax"
@@ -631,8 +631,8 @@ def _b_and_c_swapped(params):
 class NoQKNorm(RoutedHybridLM):
     """q and k go to RoPE as the projections give them."""
 
-    def _attention(self, w, y):
-        return HybridLM._attention(self.clone(qk_norm=False), w, y)
+    def _attention(self, w, y, **layer):
+        return HybridLM._attention(self.clone(qk_norm=False), w, y, **layer)
 
 
 ROUTE_MUTATIONS = ("bias_in_the_weights", "unbiased_top_k",
