@@ -345,12 +345,36 @@ def phase_kernels(ph: Phase) -> None:
     grad_fn = jax.jit(jax.grad(
         loss(lambda q, k, v: fa.flash_attention(q, k, v, True)),
         argnums=(0, 1, 2)))
-    require_kernel(ph, "flash bwd (dq, dkv)", grad_fn.lower(q, k, v, w))
+    require_kernel(ph, "flash bwd (dq_dkv)", grad_fn.lower(q, k, v, w))
     got = ph.first_call("flash bwd", lambda: grad_fn(q, k, v, w))
     want = jax.jit(jax.grad(loss(exact_ref), argnums=(0, 1, 2)))(
         q[sl], k[sl], v[sl], w[sl])
     for name, g, r in zip(("dq", "dk", "dv"), got, want):
         close(f"flash bwd {name} vs f32 reference", g[sl], r, tol)
+
+    # the backward pass above is ONE fused call (static offsets); offsets that
+    # are values of the program, a ring step's, run the two-call pass over
+    # the same tiles: the same bits
+    lse = one[1] + jnp.log(jnp.maximum(one[2], 1e-30))
+    dsum = jnp.sum(w.astype(jnp.float32) * one[0].astype(jnp.float32), axis=-1)
+    blocks = jax.jit(lambda q_off, k_off: fa.flash_backward_blocks(
+        q, k, v, lse, dsum, w, q_off, k_off, True))
+    zero = jnp.int32(0)
+    forms = (fa.backward_form(t, t, d), fa.backward_form(t, t, d, q_offset=zero))
+    ph.say(f"flash bwd form at static | runtime offsets: {' | '.join(forms)}")
+    if forms != ("fused", "two_call"):
+        failures.append("backward_form")
+    two_call = ph.first_call("flash bwd two-call", lambda: blocks(zero, zero))
+    fused = ph.first_call("flash bwd fused", lambda: jax.jit(
+        lambda: fa.flash_backward_blocks(q, k, v, lse, dsum, w, 0, 0, True))())
+    same = all(
+        bool((np.asarray(a, np.float32) == np.asarray(c, np.float32)).all())
+        for a, c in zip(fused, two_call)
+    )
+    ph.say(f"flash bwd fused vs two-call bit-identical: {same}")
+    ph.facts["fused_backward_bit_identical"] = same
+    if not same:
+        failures.append("fused vs two-call backward parity")
 
     # the ring schedule's per-step block product: unnormalized output +
     # (m, l) stats at caller offsets, merged and normalized here

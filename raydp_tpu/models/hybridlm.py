@@ -118,7 +118,7 @@ from raydp_tpu import obs
 from raydp_tpu.models.looplm import (
     LOSS_FACTS, apply_rope, chunked_cross_entropy, looplm_optimizer, rms_norm,
     rope_tables)
-from raydp_tpu.models.transformer import _attend
+from raydp_tpu.models.transformer import _attend, attention_backward_facts
 from raydp_tpu.ops import experts as experts_op
 from raydp_tpu.ops.flash_attention import SAVED_RESIDUALS
 from raydp_tpu.ops.ssd import ssd_chunk_scan
@@ -571,7 +571,9 @@ class HybridLM(nn.Module):
         sum of an expert layer gathers for a batch row at the likely bound
         (``ops.experts.token_rows_gathered``: bound + tokens token-ordered,
         tokens x k per choice), for a trace's reader to hold the gathers it
-        sees against."""
+        sees against. ``attention_backward`` and its two numbers: the form
+        the attention layers' backward pass takes by layer kind
+        (``transformer.attention_backward_facts``)."""
         t = x.shape[1] - 1
         parts = self.flops_per_row_parts(t)
         kept = self._remat_keeps(t)
@@ -601,13 +603,17 @@ class HybridLM(nn.Module):
                         self.experts_per_token),
                 "experts.flops_per_row": parts["experts"],
                 "experts.flops_counted": "uniform share"})
+        attention = [w for kind, w in zip(self.layer_types, self.layer_windows)
+                     if kind == ATTENTION]
+        kinds = {"global": attention.count(0),
+                 "window": sum(1 for w in attention if w)}
+        facts.update(attention_backward_facts(
+            self.attn_impl, t, self.head_dim, self.dtype,
+            {kind: n for kind, n in kinds.items() if n}))
         if any(self.layer_windows):
-            attention = [w for kind, w in zip(self.layer_types,
-                                              self.layer_windows)
-                         if kind == ATTENTION]
             facts.update({
-                "layer_kinds.window": sum(1 for w in attention if w),
-                "layer_kinds.global": attention.count(0),
+                "layer_kinds.window": kinds["window"],
+                "layer_kinds.global": kinds["global"],
                 "attention.window": max(attention),
                 # what the step's attention NEEDS: the pairs the windows keep
                 "attention.pairs_per_row": self.attention_pairs(t)})

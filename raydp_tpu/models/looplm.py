@@ -53,7 +53,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from raydp_tpu import obs
-from raydp_tpu.models.transformer import _attend
+from raydp_tpu.models.transformer import _attend, attention_backward_facts
 from raydp_tpu.ops.flash_attention import SAVED_RESIDUALS
 
 LAYER_MATRICES = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
@@ -251,7 +251,9 @@ class LoopLM(nn.Module):
         shapes: every layer counted ``loop_steps`` times, one head per
         exit, causal attention (T (T + 1) / 2 kept pairs); recomputation
         does not count. XLA's count of the step program cannot stand in: it
-        counts a scanned loop's body once and no Mosaic call."""
+        counts a scanned loop's body once and no Mosaic call.
+        ``attention_backward`` and its two numbers: the form the blocks'
+        attention backward takes (``transformer.attention_backward_facts``)."""
         t = x.shape[1] - 1
         d, applications = self.hidden_size, self.loop_steps * self.num_layers
         layer = 4 * d * d + 3 * d * self.intermediate_size
@@ -264,6 +266,9 @@ class LoopLM(nn.Module):
                 "loop": "scan", "remat": bool(self.remat),
                 "remat_keeps": ",".join(kept),
                 "remat_kept_bytes_per_row": applications * sum(kept.values()),
+                **attention_backward_facts(
+                    self.attn_impl, t, d // self.num_heads, self.dtype,
+                    {"global": applications}),
                 **LOSS_FACTS, "tokens_per_row": t, "flops_per_row": flops}
 
     def _remat_keeps(self, t: int) -> dict:

@@ -63,6 +63,34 @@ def _attend(q, k, v, *, impl: str, axis: str, causal: bool,
     raise ValueError(f"unknown attention impl {impl!r}")
 
 
+def attention_backward_facts(impl: str, t: int, head_dim: int, dtype,
+                             layers: dict) -> dict:
+    """What a model whose attention goes through ``_attend`` says in its
+    ``fit_facts`` of the attention's backward pass over rows of ``t``
+    tokens. ``layers``: {layer kind (``global``, ``window``): its layer
+    applications a step}. ``attention_backward``: the form a kind's backward
+    pass takes, from the shapes (``ops.flash_attention.backward_form``:
+    ``fused``, one call that computes every live tile once, or
+    ``two_call``; a ring's step has runtime offsets and is ``two_call``;
+    ``xla`` where no flash kernel runs). ``attention.backward_fused_layers``:
+    the layer applications a step whose backward is the fused call.
+    ``attention.dq_resident_bytes``: what that call keeps in VMEM for a
+    head's float32 dq."""
+    from raydp_tpu.ops.flash_attention import backward_form, dq_resident_bytes
+
+    form = "xla"
+    if impl in ("flash", "ulysses_flash"):
+        form = backward_form(t, t, head_dim, jnp.dtype(dtype).itemsize)
+    elif impl == "ring_flash":
+        form = "two_call"
+    fused = sum(layers.values()) if form == "fused" else 0
+    return {
+        "attention_backward": ",".join(f"{kind}={form}" for kind in layers),
+        "attention.backward_fused_layers": fused,
+        "attention.dq_resident_bytes":
+            dq_resident_bytes(t, head_dim) if fused else 0}
+
+
 def _scatter_rows(cache, new, starts):
     """Insert ``new`` [B, H, t, D] into ``cache`` [B, H, T, D] at per-batch
     position ``starts`` [B] along the sequence dim (vmapped dynamic update —

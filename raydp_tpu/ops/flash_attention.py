@@ -9,17 +9,25 @@ MXU (`preferred_element_type=f32`); masking and the softmax update run on the
 VPU. Causal masking uses global positions (runtime offsets from SMEM), and
 k-blocks entirely in the future are skipped outright (~2x causal throughput).
 A causal WINDOW (``flash_attention(..., window=W)``: a query sees its own
-position and the W - 1 before it) bounds the GRID of all three kernels: the
+position and the W - 1 before it) bounds the GRID of every kernel: the
 inner axis has only the steps a block's window can touch and the index maps
 start at the block's first live partner, so the blocks a window hides are
 neither stepped over nor fetched (``window_steps``); those calls carry names
-of their own (``flash_attention_window_fwd`` / ``_bwd_dq`` / ``_bwd_dkv``).
+of their own (``flash_attention_window_fwd`` / ``_bwd_dq_dkv``, and
+``_bwd_dq`` / ``_bwd_dkv`` where the two-call pass runs).
 
 One kernel family serves three surfaces:
 - ``flash_attention``: normalized output, offsets 0 — the single-device /
   per-shard attention op. Its custom VJP is a blockwise FlashAttention-2
-  backward (two pallas kernels over the saved output + logsumexp), so
-  TRAINING is O(T) memory too — no [T, T] matrix in either direction.
+  backward over the saved output + logsumexp, so TRAINING is O(T) memory
+  too — no [T, T] matrix in either direction. The backward pass of causal
+  self-attention is ONE pallas call (``flash_attention_bwd_dq_dkv``): the
+  dk/dv grid with the head's dq held in float32 in VMEM, every live tile's
+  scores, probabilities and ``ds`` computed once, five products a tile. What
+  that form does not cover (``backward_form``: runtime offsets, Tq != Tk,
+  non-causal, unequal blocks, a dq past the VMEM a call may ask for) runs
+  the two-call pass (``flash_attention_bwd_dq`` then ``_bwd_dkv``), which
+  recomputes the tile in each call: seven products. Same bits either way.
 - ``flash_attention_stats``: UNNORMALIZED output + (m, l) stats with caller
   offsets — the per-ring-step block product `parallel.ring_attention`
   merges across devices (``use_flash=True``).
@@ -90,7 +98,7 @@ def _causal_mask(s, q_off_ref, k_off_ref, qi, ki, block_q, block_k,
     """Mask scores s [BQ, BK] to NEG_INF where global k position > q
     position, and with a ``window`` where the key lies ``window`` or more
     positions behind the query (a query sees its own position and the
-    ``window - 1`` before it). Shared by the forward and both backward
+    ``window - 1`` before it). Shared by the forward and the backward
     kernels so the mask semantics can never diverge between them."""
     q_pos = q_off_ref[0] + qi * block_q + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0
@@ -349,13 +357,7 @@ def _flash_call(
         onepass = use_onepass_default()
     b, h, t, d = q.shape
     tk = k.shape[2]
-    auto_q, auto_k = pick_blocks(t, tk, head_dim=d, itemsize=q.dtype.itemsize)
-    block_q = min(block_q or auto_q, t)
-    block_k = min(block_k or auto_k, tk)
-    if t % block_q or tk % block_k:
-        raise ValueError(
-            f"sequence lengths ({t}, {tk}) must divide blocks ({block_q}, {block_k})"
-        )
+    block_q, block_k = _blocks(t, tk, d, q.dtype.itemsize, block_q, block_k)
     bh = b * h
     qf = q.reshape(bh, t, d)
     kf = k.reshape(bh, tk, d)
@@ -462,10 +464,50 @@ def _flash_forward(
 # ---------------------------------------------------------------------------
 # backward kernels (FlashAttention-2): blockwise dq/dk/dv from the saved
 # normalized output and per-row logsumexp — O(T) memory for TRAINING too, not
-# just the forward. Two kernels because TPU has no cross-block atomics:
-# dq iterates k-blocks innermost (accumulating one q-block's dq in VMEM),
-# dk/dv iterates q-blocks innermost (accumulating one k-block's dk+dv).
+# just the forward. TPU has no cross-block atomics, so a gradient lives in
+# VMEM while its tiles arrive. Causal self-attention at equal blocks and
+# static offsets runs ONE call (``_bwd_fused_kernel``): the dk/dv grid
+# (k-block outer, q-block inner) with the whole head's dq held in a float32
+# scratch, every live tile's scores, probabilities and ``ds`` computed once.
+# Everything else (``backward_form``) runs the two-call pass: a dq call that
+# iterates k-blocks innermost (one q-block's dq in VMEM) and a dk/dv call
+# that iterates q-blocks innermost (one k-block's dk + dv), each of which
+# recomputes the tile. All three kernels take the tile from ``_bwd_tile``.
 # ---------------------------------------------------------------------------
+
+# the default scoped VMEM of a Mosaic call on the chips this runs on, and what
+# the fused call may ask for of a core's 128 MiB through ``vmem_limit_bytes``
+VMEM_DEFAULT_BYTES = 16 * 2**20
+VMEM_ASK_BOUND_BYTES = 96 * 2**20
+
+
+def _bwd_tile(
+    q_off_ref, k_off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref,
+    qi, ki, *, scale, causal, block_q, block_k, window,
+):
+    """One (q-block, k-block) tile of the backward pass: the operands and
+    ``p`` [BQ, BK], ``ds`` [BQ, BK] in float32. The one place the mask and
+    the ``ds`` expression are written, for the fused and the two-call
+    kernels alike."""
+    q = q_ref[0]
+    k = k_ref[0]
+    v = v_ref[0]
+    do = do_ref[0].astype(jnp.float32)
+    lse = lse_ref[0]  # [BQ, 1]
+    dsum = dsum_ref[0]  # [BQ, 1]  rowsum(do * o)
+
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * scale
+    if causal:
+        s = _causal_mask(s, q_off_ref, k_off_ref, qi, ki, block_q, block_k,
+                         window)
+    p = jnp.exp(s - lse)  # masked entries: exp(NEG_INF - lse) == 0
+    dp = jax.lax.dot_general(
+        do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    ds = p * (dp - dsum) * scale
+    return q, k, do, p, ds
 
 
 def _bwd_dq_kernel(
@@ -493,24 +535,10 @@ def _bwd_dq_kernel(
 
     @pl.when(block_live)
     def _accumulate():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0]  # [BQ, 1]
-        dsum = dsum_ref[0]  # [BQ, 1]  rowsum(do * o)
-
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        if causal:
-            s = _causal_mask(s, q_off_ref, k_off_ref, qi, ki, block_q, block_k,
-                             window)
-        p = jnp.exp(s - lse)  # masked entries: exp(NEG_INF - lse) == 0
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - dsum) * scale
+        _, k, _, _, ds = _bwd_tile(
+            q_off_ref, k_off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+            dsum_ref, qi, ki, scale=scale, causal=causal, block_q=block_q,
+            block_k=block_k, window=window)
         dq_acc[:] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
@@ -546,30 +574,82 @@ def _bwd_dkv_kernel(
 
     @pl.when(block_live)
     def _accumulate():
-        k = k_ref[0]
-        v = v_ref[0]
-        q = q_ref[0]
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0]
-        dsum = dsum_ref[0]
-
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        if causal:
-            s = _causal_mask(s, q_off_ref, k_off_ref, qi, kj, block_q, block_k,
-                             window)
-        p = jnp.exp(s - lse)  # [BQ, BK]
+        q, _, do, p, ds = _bwd_tile(
+            q_off_ref, k_off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+            dsum_ref, qi, kj, scale=scale, causal=causal, block_q=block_q,
+            block_k=block_k, window=window)
         dv_acc[:] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - dsum) * scale  # [BQ, BK]
         dk_acc[:] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
+
+    @pl.when(step == num_q - 1)
+    def _finalize():
+        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+
+def _bwd_fused_kernel(
+    q_off_ref, k_off_ref, k_ref, v_ref, q_ref, do_ref, lse_ref, dsum_ref,
+    dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *, scale, block,
+    window=None, inner_blocks=None,
+):
+    """The whole backward pass of causal self-attention in the dk/dv
+    kernel's grid (k-block ``kj`` outer, q-block inner, equal blocks): a
+    live tile is computed ONCE and feeds dv, dk (the k-block's accumulators)
+    and dq (``dq_acc[qi]``: the head's dq, float32, in VMEM). A q-block
+    takes its k-blocks in ascending order, as the dq kernel adds them: it is
+    zeroed at its first (k-block 0, or the first its window touches) and
+    complete at its diagonal tile, the first live step of ``kj == qi``,
+    where it is written, in the operands' dtype, to the out block that
+    follows the outer axis."""
+    from jax.experimental import pallas as pl
+
+    kj = pl.program_id(1)
+    step = pl.program_id(2)
+    num_q = pl.num_programs(2)
+    qi = step if window is None else step + _first_q_block(kj, block, block)
+
+    @pl.when(step == 0)
+    def _init():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    block_live = _causal_block_live(
+        q_off_ref, k_off_ref, qi, kj, block, block, True, window
+    )
+    if window is not None:
+        block_live = jnp.logical_and(block_live, qi < inner_blocks)
+
+    @pl.when(block_live)
+    def _accumulate():
+        q, k, do, p, ds = _bwd_tile(
+            q_off_ref, k_off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+            dsum_ref, qi, kj, scale=scale, causal=True, block_q=block,
+            block_k=block, window=window)
+        dv_acc[:] += jax.lax.dot_general(
+            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        dk_acc[:] += jax.lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+
+        first = 0 if window is None else _first_k_block(
+            qi, block, block, window)
+
+        @pl.when(kj == first)
+        def _first():
+            dq_acc[qi] = jnp.zeros(dq_acc.shape[1:], dq_acc.dtype)
+
+        dq_acc[qi] += jax.lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+
+        @pl.when(qi == kj)
+        def _diagonal():
+            dq_ref[0] = dq_acc[qi].astype(dq_ref.dtype)
 
     @pl.when(step == num_q - 1)
     def _finalize():
@@ -592,6 +672,68 @@ def _flash_backward(
     )
 
 
+def _lanes(head_dim: int) -> int:
+    """A row of ``head_dim`` elements as VMEM holds it: whole 128-lane tiles."""
+    return -(-head_dim // 128) * 128
+
+
+def dq_resident_bytes(t: int, head_dim: int) -> int:
+    """What the fused backward call keeps in VMEM for a head's dq: float32
+    ``[t, head_dim]``, a head narrower than the 128 lanes padded to them."""
+    return t * _lanes(head_dim) * 4
+
+
+def fused_vmem_bytes(t: int, head_dim: int, block: int, itemsize: int) -> int:
+    """The VMEM the fused backward call asks for, from its shapes: the
+    head's dq; the dk and dv accumulators; two buffers of every pipelined
+    block (q, do, k, v in, dq, dk, dv out, and ``lse`` / ``dsum``, whose
+    ``[block, 1]`` float32 columns are padded to the lanes); and the tile's
+    ``[block, block]`` float32 values (s, p, dp, ds, the mask's two iotas
+    and what the compiler keeps beside them), counted as EIGHT: compiled
+    for a described v5e the call needed 2.5 of them beside the rest at bf16
+    1024-row tiles (16.6 MB + dq) and 6.3 at float32 512-row ones (11.3 MB
+    + dq), and what is asked for and not used costs nothing
+    (``tests/test_tpu_compile`` compiles the cells' shapes)."""
+    row = block * _lanes(head_dim)
+    return (dq_resident_bytes(t, head_dim) + 2 * row * 4
+            + 2 * (7 * row * itemsize + 2 * block * 128 * 4)
+            + 8 * block * block * 4)
+
+
+def _blocks(t, tk, head_dim, itemsize, block_q, block_k):
+    """The tiles of a call, forward and backward alike: the caller's, or
+    ``pick_blocks``' for this head and operand width, clamped to the
+    sequence lengths, which they must divide."""
+    auto_q, auto_k = pick_blocks(t, tk, head_dim=head_dim, itemsize=itemsize)
+    block_q, block_k = min(block_q or auto_q, t), min(block_k or auto_k, tk)
+    if t % block_q or tk % block_k:
+        raise ValueError(
+            f"sequence lengths ({t}, {tk}) must divide blocks ({block_q}, {block_k})"
+        )
+    return block_q, block_k
+
+
+def backward_form(
+    t: int, tk: int, head_dim: int, itemsize: int = 2, *, causal: bool = True,
+    block_q: int | None = None, block_k: int | None = None,
+    q_offset=0, k_offset=0,
+) -> str:
+    """Which form the backward pass of these shapes and arguments takes:
+    ``"fused"`` (one call, every live tile once) for causal self-attention
+    (``t == tk``) at equal blocks and the static offsets 0 whose float32 dq
+    fits the VMEM a call may ask for; ``"two_call"`` for everything else: a
+    ring step's runtime offsets, ``t != tk``, a non-causal call (its dq is
+    complete at no tile of a k-outer grid but the last), unequal blocks.
+    Decided from what the call sees, by no flag."""
+    block_q, block_k = _blocks(t, tk, head_dim, itemsize, block_q, block_k)
+    static = all(isinstance(o, int) and o == 0 for o in (q_offset, k_offset))
+    if (causal and static and t == tk and block_q == block_k
+            and fused_vmem_bytes(t, head_dim, block_q, itemsize)
+            <= VMEM_ASK_BOUND_BYTES):
+        return "fused"
+    return "two_call"
+
+
 def flash_backward_blocks(
     q, k, v, lse, dsum, g, q_offset, k_offset, causal: bool = False,
     block_q: int | None = None, block_k: int | None = None,
@@ -604,20 +746,18 @@ def flash_backward_blocks(
     ``flash_attention_stats``: `parallel.ring_attention` sums these partials
     as K/V (and their gradient accumulators) rotate around the ring.
     ``window`` (offsets 0, Tq = Tk: ``flash_attention``'s own backward pass):
-    both grids follow it, as the forward's does."""
+    the grids follow it, as the forward's does. ``backward_form`` says
+    whether the pass is the one fused call or the dq and the dk/dv call."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     interpret = pallas_interpret(interpret)
     b, h, t, d = q.shape
     tk = k.shape[2]
-    auto_q, auto_k = pick_blocks(t, tk, itemsize=q.dtype.itemsize)
-    block_q = min(block_q or auto_q, t)
-    block_k = min(block_k or auto_k, tk)
-    if t % block_q or tk % block_k:
-        raise ValueError(
-            f"sequence lengths ({t}, {tk}) must divide blocks ({block_q}, {block_k})"
-        )
+    block_q, block_k = _blocks(t, tk, d, q.dtype.itemsize, block_q, block_k)
+    form = backward_form(
+        t, tk, d, q.dtype.itemsize, causal=causal, block_q=block_q,
+        block_k=block_k, q_offset=q_offset, k_offset=k_offset)
     bh = b * h
     scale = d**-0.5
 
@@ -639,13 +779,12 @@ def flash_backward_blocks(
     window = _window_for(window, causal, t, tk, q_offset, k_offset)
     k_steps, q_steps = tk // block_k, t // block_q
     k_block = q_block = (lambda outer, j: j)
-    names = ("flash_attention_bwd_dq", "flash_attention_bwd_dkv")
+    name = "flash_attention_bwd_"
     dq_window = dkv_window = {}
     if window is not None:
         k_steps, q_steps = window_steps(t, block_q, block_k, window)
         last_k, last_q = tk // block_k - 1, t // block_q - 1
-        names = ("flash_attention_window_bwd_dq",
-                 "flash_attention_window_bwd_dkv")
+        name = "flash_attention_window_bwd_"
         dq_window = dict(window=window, inner_blocks=tk // block_k)
         dkv_window = dict(window=window, inner_blocks=t // block_q)
 
@@ -658,56 +797,84 @@ def flash_backward_blocks(
                 _first_q_block(kj, block_q, block_k) + j, last_q)
 
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    q_spec = pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0))
-    k_spec_dq = pl.BlockSpec(
-        (1, block_k, d), lambda b_, i, j: (b_, k_block(i, j), 0))
-    stat_spec_dq = pl.BlockSpec((1, block_q, 1), lambda b_, i, j: (b_, i, 0))
-
-    dq = pl.pallas_call(
-        functools.partial(
-            _bwd_dq_kernel,
-            scale=scale, causal=causal, block_q=block_q, block_k=block_k,
-            **dq_window,
-        ),
-        out_shape=sds((bh, t, d), q.dtype),
-        grid=(bh, t // block_q, k_steps),
-        in_specs=[
-            smem, smem, q_spec, k_spec_dq, k_spec_dq, q_spec,
-            stat_spec_dq, stat_spec_dq,
-        ],
-        out_specs=q_spec,
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=interpret,
-        name=names[0],
-    )(q_off, k_off, qf, kf, vf, dof, lsef, dsumf)
-
-    # dk/dv: k-block outer, q-block inner
+    # k-block outer, q-block inner: the dk/dv call's grid and the fused one's
     k_spec = pl.BlockSpec((1, block_k, d), lambda b_, j, i: (b_, j, 0))
     q_spec_kv = pl.BlockSpec(
         (1, block_q, d), lambda b_, j, i: (b_, q_block(j, i), 0))
     stat_spec_kv = pl.BlockSpec(
         (1, block_q, 1), lambda b_, j, i: (b_, q_block(j, i), 0))
-
-    dk, dv = pl.pallas_call(
-        functools.partial(
-            _bwd_dkv_kernel,
-            scale=scale, causal=causal, block_q=block_q, block_k=block_k,
-            **dkv_window,
-        ),
-        out_shape=(sds((bh, tk, d), k.dtype), sds((bh, tk, d), v.dtype)),
+    kv_grid = dict(
         grid=(bh, tk // block_k, q_steps),
         in_specs=[
             smem, smem, k_spec, k_spec, q_spec_kv, q_spec_kv,
             stat_spec_kv, stat_spec_kv,
         ],
-        out_specs=(k_spec, k_spec),
-        scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
-        ],
         interpret=interpret,
-        name=names[1],
-    )(q_off, k_off, kf, vf, qf, dof, lsef, dsumf)
+    )
+    kv_operands = (q_off, k_off, kf, vf, qf, dof, lsef, dsumf)
+    kv_acc = [
+        pltpu.VMEM((block_k, d), jnp.float32),
+        pltpu.VMEM((block_k, d), jnp.float32),
+    ]
+
+    if form == "fused":
+        # the names begin as the dq call's do: a reader that counts passes
+        # by ``flash_attention[_window]_bwd_dq`` counts this call once, with
+        # the whole pass's time
+        dq, dk, dv = pl.pallas_call(
+            functools.partial(
+                _bwd_fused_kernel, scale=scale, block=block_k, **dkv_window),
+            out_shape=(sds((bh, t, d), q.dtype), sds((bh, tk, d), k.dtype),
+                       sds((bh, tk, d), v.dtype)),
+            # dq's block follows the OUTER axis: q-block kj is complete, and
+            # written, at the first live step of k-block kj
+            out_specs=(k_spec, k_spec, k_spec),
+            scratch_shapes=[
+                pltpu.VMEM((t // block_q, block_q, d), jnp.float32), *kv_acc],
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=max(
+                    fused_vmem_bytes(t, d, block_k, q.dtype.itemsize),
+                    VMEM_DEFAULT_BYTES)),
+            name=name + "dq_dkv",
+            **kv_grid,
+        )(*kv_operands)
+    else:
+        q_spec = pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0))
+        k_spec_dq = pl.BlockSpec(
+            (1, block_k, d), lambda b_, i, j: (b_, k_block(i, j), 0))
+        stat_spec_dq = pl.BlockSpec(
+            (1, block_q, 1), lambda b_, i, j: (b_, i, 0))
+
+        dq = pl.pallas_call(
+            functools.partial(
+                _bwd_dq_kernel,
+                scale=scale, causal=causal, block_q=block_q, block_k=block_k,
+                **dq_window,
+            ),
+            out_shape=sds((bh, t, d), q.dtype),
+            grid=(bh, t // block_q, k_steps),
+            in_specs=[
+                smem, smem, q_spec, k_spec_dq, k_spec_dq, q_spec,
+                stat_spec_dq, stat_spec_dq,
+            ],
+            out_specs=q_spec,
+            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+            interpret=interpret,
+            name=name + "dq",
+        )(q_off, k_off, qf, kf, vf, dof, lsef, dsumf)
+
+        dk, dv = pl.pallas_call(
+            functools.partial(
+                _bwd_dkv_kernel,
+                scale=scale, causal=causal, block_q=block_q, block_k=block_k,
+                **dkv_window,
+            ),
+            out_shape=(sds((bh, tk, d), k.dtype), sds((bh, tk, d), v.dtype)),
+            out_specs=(k_spec, k_spec),
+            scratch_shapes=kv_acc,
+            name=name + "dkv",
+            **kv_grid,
+        )(*kv_operands)
 
     return (
         dq.reshape(b, h, t, d),
@@ -769,10 +936,11 @@ def flash_attention(
     pass explicit sizes only to pin a tiling (tests / VMEM-constrained
     shard_map bodies). ``window`` (causal only): a query sees its own
     position and the ``window - 1`` before it. THE GRID FOLLOWS THE WINDOW
-    in all three kernels: the blocks it hides are not stepped over and not
-    fetched (``window_steps``), and the calls are named
-    ``flash_attention_window_fwd`` / ``_bwd_dq`` / ``_bwd_dkv``. None, or a
-    window of the whole sequence or more, is the causal call as it was."""
+    in the forward and the backward kernels: the blocks it hides are not
+    stepped over and not fetched (``window_steps``), and the calls are named
+    ``flash_attention_window_fwd`` / ``_bwd_dq_dkv``. None, or a window of
+    the whole sequence or more, is the causal call as it was. The backward
+    pass is one fused call wherever ``backward_form`` says so."""
     return _flash_forward(q, k, v, causal, block_q, block_k, interpret,
                           window)
 

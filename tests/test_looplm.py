@@ -216,7 +216,9 @@ def test_the_backward_pass_runs_no_flash_forward(params, batch, remat):
     jaxpr = jax.make_jaxpr(jax.grad(
         lambda p: m.apply(p, batch, method="loss")[0]))(params)
     forward = _pallas_calls(jaxpr.jaxpr, "flash_attention_fwd")
-    backward = _pallas_calls(jaxpr.jaxpr, "flash_attention_bwd_dq")
+    # the backward pass is ONE call a layer since PR 43
+    backward = _pallas_calls(jaxpr.jaxpr, "flash_attention_bwd_dq_dkv")
+    assert not _pallas_calls(jaxpr.jaxpr, "flash_attention_bwd_dkv")
     # one scan holds every forward call, another every backward one
     assert list(forward.values()) == [L], forward
     assert list(backward.values()) == [L], backward
@@ -278,6 +280,31 @@ def test_fit_facts_say_what_a_row_holds():
     off = big.clone(remat=False).fit_facts(np.zeros((2, 4097), np.int32))
     assert (off["remat"], off["remat_keeps"], off["remat_kept_bytes_per_row"]) == (
         False, "", 0)
+
+
+ATTENTION_FACTS = ("attention_backward", "attention.backward_fused_layers",
+                   "attention.dq_resident_bytes")
+
+
+@pytest.mark.parametrize("impl, tokens, want", [
+    # every block application's backward is the one fused call; a head's
+    # float32 dq [4096, 128] stays in VMEM
+    ("flash", 4096, ("global=fused", 24, 4096 * 128 * 4)),
+    ("ulysses_flash", 4096, ("global=fused", 24, 4096 * 128 * 4)),
+    # a ring step's offsets are values of the program
+    ("ring_flash", 4096, ("global=two_call", 0, 0)),
+    # 128k tokens: a dq of 64 MB is past what a call may ask for
+    ("flash", 131072, ("global=two_call", 0, 0)),
+    # no flash kernel: autodiff's backward
+    ("full", 4096, ("global=xla", 0, 0))])
+def test_fit_facts_say_which_form_the_attention_backward_takes(
+        impl, tokens, want):
+    """PR 43: from ``attn_impl`` and the shapes, as the kernel decides it
+    (``ops.flash_attention.backward_form``); numbers become gauges
+    ``model.attention.*``."""
+    big = LoopLM(vocab_size=49152, attn_impl=impl)
+    facts = big.fit_facts(np.zeros((2, tokens + 1), np.int32))
+    assert tuple(facts[name] for name in ATTENTION_FACTS) == want
 
 
 # -- the sequence column ------------------------------------------------------

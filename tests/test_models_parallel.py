@@ -138,6 +138,205 @@ def test_flash_attention_backward_blockwise_exact():
             np.testing.assert_allclose(np.asarray(dv), np.asarray(rdv), atol=1e-4)
 
 
+def _flash_module():
+    # ``raydp_tpu.ops.flash_attention`` the attribute is the function
+    import importlib
+
+    return importlib.import_module("raydp_tpu.ops.flash_attention")
+
+
+def _flash_grads(fa, q, k, v, g, causal=True, block_q=None, block_k=None,
+                 window=None):
+    import jax
+
+    _, vjp = jax.vjp(
+        lambda q_, k_, v_: fa.flash_attention(
+            q_, k_, v_, causal, block_q, block_k, None, window), q, k, v)
+    return vjp(g)
+
+
+def _backward_calls(fa, q, causal=True, block_q=None, block_k=None,
+                    window=None):
+    """The names of the Mosaic calls in the gradient's jaxpr."""
+    import re
+
+    import jax
+
+    text = str(jax.make_jaxpr(lambda q_, k_, v_, g_: _flash_grads(
+        fa, q_, k_, v_, g_, causal, block_q, block_k, window))(q, q, q, q))
+    return sorted(set(re.findall(r"flash_attention_(?:window_)?bwd_\w+", text)))
+
+
+# window: None = causal; in blocks of 16 rows: 1 key, a block, several
+# blocks (2.5), the whole sequence; T of 2, 4 and 8 blocks
+FUSED_CASES = [
+    (None, 4, 32, "float32"), (1, 4, 32, "float32"), (16, 4, 32, "float32"),
+    (40, 4, 32, "float32"), (64, 4, 32, "float32"),
+    (None, 2, 32, "float32"), (40, 2, 32, "float32"),
+    (None, 8, 32, "float32"), (40, 8, 32, "float32"),
+    (None, 4, 64, "float32"), (40, 4, 64, "float32"),
+    (None, 4, 128, "float32"), (40, 4, 128, "float32"),
+    (None, 4, 32, "bfloat16"), (1, 4, 32, "bfloat16"),
+    (40, 4, 64, "bfloat16"), (16, 8, 128, "bfloat16"),
+    (None, 2, 128, "bfloat16"),
+]
+
+
+@pytest.mark.parametrize("window, blocks, head, dtype", FUSED_CASES)
+def test_flash_backward_fused_equals_two_call(monkeypatch, window, blocks,
+                                              head, dtype):
+    """The ONE-call backward pass (every live tile's scores, probabilities
+    and ``ds`` computed once, dq a head long in VMEM) gives the two-call
+    pass's dq, dk and dv BIT FOR BIT, causal and under every kind of window,
+    and (float32) the exact reference's gradients at the blockwise test's
+    tolerance."""
+    import jax.numpy as jnp
+
+    from raydp_tpu.parallel import full_attention
+
+    fa = _flash_module()
+    block, t = 16, 16 * blocks
+    rng = np.random.default_rng(43)
+    q, k, v, g = (jnp.asarray(rng.standard_normal((2, 2, t, head)), dtype)
+                  for _ in range(4))
+    assert fa.backward_form(t, t, head, q.dtype.itemsize, block_q=block,
+                            block_k=block) == "fused"
+    calls = _backward_calls(fa, q, True, block, block, window)
+    hidden = window is not None and window < t
+    assert calls == [("flash_attention_window_bwd_dq_dkv" if hidden
+                      else "flash_attention_bwd_dq_dkv")]
+    fused = _flash_grads(fa, q, k, v, g, True, block, block, window)
+    monkeypatch.setattr(fa, "backward_form", lambda *a, **kw: "two_call")
+    assert len(_backward_calls(fa, q, True, block, block, window)) == 2
+    two_call = _flash_grads(fa, q, k, v, g, True, block, block, window)
+    for name, got, want in zip(("dq", "dk", "dv"), fused, two_call):
+        assert got.dtype == want.dtype == q.dtype, name
+        np.testing.assert_array_equal(
+            np.asarray(got.astype(jnp.float32)),
+            np.asarray(want.astype(jnp.float32)), err_msg=name)
+    if dtype == "float32":
+        import jax
+
+        _, ref_vjp = jax.vjp(lambda q_, k_, v_: full_attention(
+            q_, k_, v_, causal=True, window=window), q, k, v)
+        for name, got, want in zip(("dq", "dk", "dv"), fused, ref_vjp(g)):
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       atol=1e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("case", [
+    "runtime_offsets", "tq_is_not_tk", "non_causal", "unequal_blocks",
+    "dq_past_the_vmem_bound"])
+def test_backward_form_keeps_the_two_call_pass(monkeypatch, case):
+    """What the fused form does not cover runs the two-call pass, decided
+    from the shapes and arguments alone, and gives the gradients it gave."""
+    import jax
+    import jax.numpy as jnp
+
+    from raydp_tpu.parallel import full_attention
+
+    fa = _flash_module()
+    rng = np.random.default_rng(44)
+    t, d, block = 64, 32, 16
+
+    def randn(rows):
+        return jnp.asarray(rng.standard_normal((1, 2, rows, d)), jnp.float32)
+
+    q, k, v, g = randn(t), randn(t), randn(t), randn(t)
+    assert fa.backward_form(t, t, d, 4, block_q=block, block_k=block) == "fused"
+    two_names = ["flash_attention_bwd_dkv", "flash_attention_bwd_dq"]
+
+    def reference(causal, k_=k, v_=v):
+        _, vjp = jax.vjp(lambda a, b, c: full_attention(a, b, c, causal=causal),
+                         q, k_, v_)
+        return vjp(g)
+
+    if case == "runtime_offsets":
+        # a ring step's call: the offsets are values of the program
+        assert fa.backward_form(
+            t, t, d, 4, block_q=block, block_k=block,
+            q_offset=jnp.int32(0), k_offset=0) == "two_call"
+        o = full_attention(q, k, v, causal=True)
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * d ** -0.5
+        s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
+        lse = jax.nn.logsumexp(s, axis=-1)
+        dsum = jnp.sum(g * o, axis=-1)
+
+        def ring_step(q_off, k_off):
+            return fa.flash_backward_blocks(
+                q, k, v, lse, dsum, g, q_off, k_off, True, block, block)
+
+        text = str(jax.make_jaxpr(ring_step)(jnp.int32(0), jnp.int32(0)))
+        assert "bwd_dq_dkv" not in text and "flash_attention_bwd_dkv" in text
+        got = jax.jit(ring_step)(jnp.int32(0), jnp.int32(0))
+        want = reference(True)
+        # the fused call of the same tiles (static offsets): the same bits
+        for a, b in zip(got, fa.flash_backward_blocks(
+                q, k, v, lse, dsum, g, 0, 0, True, block, block)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    elif case == "tq_is_not_tk":
+        k, v = randn(2 * t), randn(2 * t)
+        assert fa.backward_form(t, 2 * t, d, 4, block_q=block,
+                                block_k=block) == "two_call"
+        _, vjp = jax.vjp(lambda a, b, c: fa.flash_attention(
+            a, b, c, True, block, block), q, k, v)
+        got, want = vjp(g), reference(True, k, v)
+    elif case == "non_causal":
+        assert fa.backward_form(t, t, d, 4, causal=False, block_q=block,
+                                block_k=block) == "two_call"
+        assert _backward_calls(fa, q, False, block, block) == two_names
+        got = _flash_grads(fa, q, k, v, g, False, block, block)
+        want = reference(False)
+    elif case == "unequal_blocks":
+        assert fa.backward_form(t, t, d, 4, block_q=32,
+                                block_k=block) == "two_call"
+        assert _backward_calls(fa, q, True, 32, block) == two_names
+        got = _flash_grads(fa, q, k, v, g, True, 32, block)
+        want = reference(True)
+    else:
+        # from the shapes: 128k rows of 128 are a dq of 64 MB, past what a
+        # call may ask for beside its tiles; half of that is not
+        assert fa.backward_form(131072, 131072, 128) == "two_call"
+        assert fa.backward_form(65536, 65536, 128) == "fused"
+        assert fa.dq_resident_bytes(16384, 128) == 8 * 2**20
+        assert fa.dq_resident_bytes(8192, 64) == 4 * 2**20  # lane-padded
+        assert fa.fused_vmem_bytes(16384, 128, 1024, 2) > (
+            fa.VMEM_DEFAULT_BYTES + fa.dq_resident_bytes(16384, 128))
+        # the same decision at a size the interpreter runs
+        monkeypatch.setattr(fa, "VMEM_ASK_BOUND_BYTES",
+                            fa.fused_vmem_bytes(t, d, block, 4) - 1)
+        assert fa.backward_form(t, t, d, 4, block_q=block,
+                                block_k=block) == "two_call"
+        assert _backward_calls(fa, q, True, block, block) == two_names
+        got = _flash_grads(fa, q, k, v, g, True, block, block)
+        want = reference(True)
+    for name, a, b in zip(("dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4,
+                                   err_msg=f"{case} {name}")
+
+
+def test_flash_backward_blocks_picks_its_tiles_as_the_forward_does(monkeypatch):
+    """Past a head of 128 the forward's tile halves (``pick_blocks`` keeps
+    a tile's VMEM footprint): the backward pass asks with the same head."""
+    import jax
+    import jax.numpy as jnp
+
+    fa = _flash_module()
+    asked = []
+    pick = fa.pick_blocks
+
+    def noting(*args, **kwargs):
+        asked.append(kwargs.get("head_dim"))
+        return pick(*args, **kwargs)
+
+    monkeypatch.setattr(fa, "pick_blocks", noting)
+    q = jax.ShapeDtypeStruct((1, 1, 1024, 256), jnp.bfloat16)
+    jax.eval_shape(lambda q_, k_, v_, g_: _flash_grads(fa, q_, k_, v_, g_),
+                   q, q, q, q)
+    assert asked and set(asked) == {256}
+    assert pick(1024, 1024, head_dim=256) == (512, 512)
+
+
 def test_flash_attention_training_memory_is_linear():
     """Jaxpr-level check that the backward never materializes a [T, T]
     score matrix: the largest intermediate in the VJP scales with T, not T²

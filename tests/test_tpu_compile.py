@@ -54,6 +54,11 @@ def _instructions(text):
     return len(re.findall(r"^\s+(?:ROOT )?%[\w.\-]+ = ", text, re.M))
 
 
+# the two-call pass's second call (PR 43: gone from the cells' programs;
+# ``flash_attention_bwd_dq_dkv`` does not match)
+BWD_DKV = r"%[\w.\-]*flash_attention_(?:window_)?bwd_dkv"
+
+
 def _on_chip(tree, one_chip):
     """The shapes of ``tree`` as arguments that live on the described chip."""
     return jax.tree.map(lambda leaf: jax.ShapeDtypeStruct(
@@ -80,11 +85,54 @@ def test_flash_forward_and_backward_compile_at_ouro_widths(
                 ).astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
 
     text = jax.jit(grads).lower(q, q, q).compile().as_text()
-    for name in ("flash_attention_fwd", "flash_attention_bwd_dq",
-                 "flash_attention_bwd_dkv"):
+    for name in ("flash_attention_fwd", "flash_attention_bwd_dq_dkv"):
         # the instruction's name: %jvp_<name>_.1, %transpose_jvp_<name>__.1
         assert re.search(rf"%[\w.\-]*{name}[\w.\-]* = ", text), name
-    assert text.count("tpu_custom_call") >= 3
+    # PR 43: the backward pass is ONE call
+    assert not re.search(BWD_DKV, text)
+    assert text.count("tpu_custom_call") == 2
+
+
+@pytest.mark.parametrize("heads, t, head, window", [
+    (32, 4096, 128, None), (32, 8192, 64, None), (128, 8192, 64, None),
+    (56, 16384, 128, None), (56, 16384, 128, 4096)],
+    ids=["ouro", "granite", "routed", "window_cell_global",
+         "window_cell_window"])
+@pytest.mark.parametrize("dtype, precision", [
+    (jnp.bfloat16, None), (jnp.float32, "highest")],
+    ids=["bf16", "float32_matched"])
+def test_fused_flash_backward_compiles_at_the_cells_shapes(
+        one_chip, no_compile_cache, heads, t, head, window, dtype, precision):
+    """PR 43: the ONE-call backward pass alone, [batch x heads, T, head] of
+    the four LM cells (the timed bf16 step and the matched check's float32
+    tiles): a head's float32 dq lives in VMEM (2-8 MB) beside the tile, so
+    the call asks for more than a Mosaic call's 16 MB by
+    ``vmem_limit_bytes`` (``fused_vmem_bytes``, from the shapes): what it
+    asks for must cover what the compiler needs, here and not on the chip."""
+    fa = importlib.import_module("raydp_tpu.ops.flash_attention")
+    itemsize = jnp.dtype(dtype).itemsize
+    block, _ = fa.pick_blocks(t, t, head_dim=head, itemsize=itemsize)
+    assert fa.backward_form(t, t, head, itemsize) == "fused"
+    assert (fa.dq_resident_bytes(t, head) < fa.fused_vmem_bytes(
+        t, head, block, itemsize) <= fa.VMEM_ASK_BOUND_BYTES)
+    q = jax.ShapeDtypeStruct((1, heads, t, head), dtype, sharding=one_chip)
+    stat = jax.ShapeDtypeStruct((1, heads, t), jnp.float32, sharding=one_chip)
+
+    def backward(q, k, v, lse, dsum, g):
+        with jax.default_matmul_precision(precision):
+            return fa.flash_backward_blocks(
+                q, k, v, lse, dsum, g, 0, 0, True, None, None, False, window)
+
+    compiled = jax.jit(backward).lower(q, q, q, stat, stat, q).compile()
+    text = compiled.as_text()
+    name = "flash_attention_window_bwd_dq_dkv" if window else (
+        "flash_attention_bwd_dq_dkv")
+    assert len(re.findall(rf"%[\w.\-]*{name}[\w.\-]* = ", text)) == 1
+    assert text.count("tpu_custom_call") == 1
+    # dq leaves the call in the operands' dtype: no float32 [heads, T, head]
+    # array anywhere in the program when the operands are bf16
+    if dtype == jnp.bfloat16:
+        assert f"f32[{heads},{t},{head}]" not in text
 
 
 # the Criteo-Kaggle cardinalities of benchmark/configs/dlrm-criteo-kaggle.json
@@ -222,20 +270,21 @@ def test_looplm_gradient_keeps_what_the_flash_forward_gave(
     assert calls == len(re.findall(FLASH_FWD, plain.as_text())) == 6
     assert kept.memory_analysis().temp_size_in_bytes <= temp_limit
     assert _loss_products(kept.as_text(), "looplm.exit_loss") == 3
-    # PR 42 (a window in the flash kernels, ``window=None`` here): the
-    # program the parent compiled, instruction for instruction
+    # what a change that leaves the model's options alone must not move
+    # (12,395 until PR 43 made the flash backward one call of two)
     if not matched:
-        assert _instructions(kept.as_text()) == 12_395
+        assert _instructions(kept.as_text()) == 12_341
 
 
-@pytest.mark.parametrize("remat, calls_a_layer", [(False, 3), (True, 4)],
+@pytest.mark.parametrize("remat, calls_a_layer", [(False, 2), (True, 3)],
                          ids=["no_remat", "remat"])
 def test_transformer_flash_gradient_is_the_program_it_was(
         one_chip, no_compile_cache, kernels_compile, remat, calls_a_layer):
     """``flash_attention`` names its residuals for a policy that saves by
     name; ``TransformerLM`` has none (``nn.remat`` bare, or no remat), so a
     name is the identity and its gradient compiles to the Mosaic calls it
-    had: forward, dq, dk/dv a layer, and the forward again under remat."""
+    had but for the backward pass's two being one since PR 43: forward and
+    the fused backward a layer, and the forward again under remat."""
     from raydp_tpu.models.transformer import TransformerLM
 
     layers = 2
@@ -277,9 +326,9 @@ def test_flash_kernels_compile_at_head_dim_64(one_chip, no_compile_cache, matche
                 ).astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
 
     text = jax.jit(grads).lower(q, q, q).compile().as_text()
-    for name in ("flash_attention_fwd", "flash_attention_bwd_dq",
-                 "flash_attention_bwd_dkv"):
+    for name in ("flash_attention_fwd", "flash_attention_bwd_dq_dkv"):
         assert re.search(rf"%[\w.\-]*{name}[\w.\-]* = ", text), name
+    assert not re.search(BWD_DKV, text)
 
 
 def test_hybridlm_epoch_program_fits_the_chip(
@@ -291,7 +340,7 @@ def test_hybridlm_epoch_program_fits_the_chip(
     compiles) for the described v5e: arguments + outputs - aliased +
     temporaries within 15.5e9 bytes (13.78e9 here: arguments 10.19e9, all
     aliased, temporaries 3.59e9; 13.66e9 before PR 32 put the loss's
-    gradient, with its accumulator, into the forward sweep), one flash forward, one dq and one dk/dv
+    gradient, with its accumulator, into the forward sweep), one flash forward and one fused backward
     call at head_dim 64 (the attention layer's ``attn_out`` and ``attn_lse``
     are kept, so the backward pass recomputes none), three products in the
     loss (no chunk's logits computed twice)."""
@@ -323,13 +372,13 @@ def test_hybridlm_epoch_program_fits_the_chip(
             - memory.alias_size_in_bytes + memory.temp_size_in_bytes)
     assert held <= 15.5e9, held
     text = compiled.as_text()
-    for name in ("flash_attention_fwd", "flash_attention_bwd_dq",
-                 "flash_attention_bwd_dkv"):
+    for name in ("flash_attention_fwd", "flash_attention_bwd_dq_dkv"):
         assert len(re.findall(rf"%[\w.\-]*{name}[\w.\-]* = ", text)) == 1, name
+    assert not re.search(BWD_DKV, text)
     assert _loss_products(text, "hybridlm.loss") == 3
-    # PR 42 (a window, a second scoring rule and activation, an explicit
-    # head_dim, all left at their defaults here): the parent's program
-    assert _instructions(text) == 30_285
+    # what a change that leaves the model's options alone must not move
+    # (30,285 until PR 43 made the flash backward one call of two)
+    assert _instructions(text) == 30_162
 
 
 # -- the routed-experts LM's grouped products and epoch program ------------------
@@ -373,7 +422,7 @@ def test_routed_hybridlm_epoch_program_fits_the_chip(
     no pair can be dropped) as the overflow's arm of a conditional, one
     forward and one in the backward pass. The two arms' temporaries share
     memory: the program holds what it held with the worst case alone
-    (13.01e9 bytes), one flash forward, one dq and one dk/dv call, and
+    (13.01e9 bytes), one flash forward and one fused backward call, and
     the grouped product is a Mosaic call 32 times IN EITHER ARM (4 expert
     layers x (2 forward + 2 in the backward pass's own forward + 4
     backward): a third forward would make it 40), and no arm returns an
@@ -428,9 +477,9 @@ def test_routed_hybridlm_epoch_program_fits_the_chip(
     # the parent's program, the worst case alone, held 13,006,128,128
     assert held <= 13.05e9, held
     text = compiled.as_text()
-    for name in ("flash_attention_fwd", "flash_attention_bwd_dq",
-                 "flash_attention_bwd_dkv"):
+    for name in ("flash_attention_fwd", "flash_attention_bwd_dq_dkv"):
         assert len(re.findall(rf"%[\w.\-]*{name}[\w.\-]* = ", text)) == 1, name
+    assert not re.search(BWD_DKV, text)
     conditionals = re.findall(r"= (\(.*?\)) conditional\(", text)
     assert len(conditionals) == 8
     assert not any(f"[{worst}," in result for result in conditionals)
@@ -445,9 +494,10 @@ def test_routed_hybridlm_epoch_program_fits_the_chip(
                       "32768,16,128": 4 * 2}, likely
     # the epoch's report leaves the program: [expert layers, held] and a count
     assert "f32[4,8]" in text.split("ENTRY")[1].split("\n")[0]
-    # PR 42: ``window=None``, ``silu``, sigmoid + bias and the FFN's own
-    # router input compile to the parent's program, instruction for instruction
-    assert _instructions(text) == 31_024
+    # what a change that leaves the model's options alone must not move
+    # (31,024 until PR 43 made the flash backward one call of two: one
+    # Mosaic call fewer, and the compiler schedules 199 instructions more)
+    assert _instructions(text) == 31_223
 
 
 # -- window layers over routed experts (PR 42) -----------------------------------
@@ -461,8 +511,9 @@ def test_window_kernels_compile_with_a_grid_that_follows_the_window(
     step's tiles and the float32 ones of the matched check. The k axis of
     the forward and dq grids has the k-blocks a q-block's window can touch
     (5 at 1024-row tiles: never more than ceil((W + block_q - 1) / block_k)
-    + 1 = 6), NOT T / block_k = 16; the dk/dv grid's q axis likewise. The
-    three calls compile for the described v5e under their own names."""
+    + 1 = 6), NOT T / block_k = 16; the backward call's q axis likewise
+    (PR 43: one fused call in the dk/dv grid). The calls compile for the
+    described v5e under their own names."""
     import math
 
     fa = importlib.import_module("raydp_tpu.ops.flash_attention")
@@ -483,13 +534,13 @@ def test_window_kernels_compile_with_a_grid_that_follows_the_window(
     grids = re.findall(r"grid=\((\d+), (\d+), (\d+)\)",
                        str(jax.make_jaxpr(grads)(q, q, q)))
     assert grids == [("56", str(t // tile), str(k_steps)),   # forward
-                     ("56", str(t // tile), str(k_steps)),   # dq
-                     ("56", str(t // tile), str(q_steps))], grids
+                     ("56", str(t // tile), str(q_steps))], grids  # backward
     text = jax.jit(grads).lower(q, q, q).compile().as_text()
-    for name in ("flash_attention_window_fwd", "flash_attention_window_bwd_dq",
-                 "flash_attention_window_bwd_dkv"):
+    for name in ("flash_attention_window_fwd",
+                 "flash_attention_window_bwd_dq_dkv"):
         assert re.search(rf"%[\w.\-]*{name}[\w.\-]* = ", text), name
     assert not re.search(r"%[\w.\-]*flash_attention_(fwd|bwd)", text)
+    assert not re.search(BWD_DKV, text)
 
 
 def test_windowed_routed_hybridlm_epoch_program_fits_the_chip(
@@ -500,10 +551,11 @@ def test_windowed_routed_hybridlm_epoch_program_fits_the_chip(
     gathered from the resident rows and scanned, parameters and optimizer
     state donated, the steps' report summed) for the described v5e: within
     15.5e9 bytes (14.97e9 here: arguments 7.88e9, all aliased, temporaries
-    7.09e9); ONE causal flash forward, dq and dk/dv call (the global layer)
-    and THREE window calls of each (kept ``attn_out`` and ``attn_lse``: none
-    recomputed); each of the four expert layers at the likely bound of
-    61,440 rows with the worst case (196,608) as the overflow's arm."""
+    7.09e9); ONE causal flash forward and ONE fused backward call (the
+    global layer) and THREE window calls of each (kept ``attn_out`` and
+    ``attn_lse``: none recomputed; PR 43: no dk/dv call of its own); each
+    of the four expert layers at the likely bound of 61,440 rows with the
+    worst case (196,608) as the overflow's arm."""
     import json
     import os
 
@@ -545,13 +597,12 @@ def test_windowed_routed_hybridlm_epoch_program_fits_the_chip(
     assert held <= 15.5e9, held
     text = compiled.as_text()
     for name, calls in (("flash_attention_fwd", 1),
-                        ("flash_attention_bwd_dq", 1),
-                        ("flash_attention_bwd_dkv", 1),
+                        ("flash_attention_bwd_dq_dkv", 1),
                         ("flash_attention_window_fwd", 3),
-                        ("flash_attention_window_bwd_dq", 3),
-                        ("flash_attention_window_bwd_dkv", 3)):
+                        ("flash_attention_window_bwd_dq_dkv", 3)):
         assert len(re.findall(
             rf"%[\w.\-]*{name}[\w.\-]* = ", text)) == calls, name
+    assert not re.search(BWD_DKV, text)
     conditionals = re.findall(r"= (\(.*?\)) conditional\(", text)
     assert len(conditionals) == 8
     assert not any("[196608," in result for result in conditionals)

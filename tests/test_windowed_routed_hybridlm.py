@@ -9,6 +9,7 @@ parts against the uncut reference; the refusals of ``from_config``;
 ``fit_facts``; and a JaxEstimator fit through the normal path."""
 
 import importlib
+import json
 import math
 import os
 import sys
@@ -595,8 +596,6 @@ def test_the_embeddings_spread_is_its_own_and_the_default_is_the_matrices(batch)
 
 @pytest.mark.parametrize("sizes", ["published", "tiny"])
 def test_the_models_flops_are_the_benchmarks_count(sizes):
-    import json
-
     if sizes == "published":
         with open(os.path.join(ROOT, "benchmark", "configs",
                                "smallthinker-21b-a3b.json")) as f:
@@ -628,6 +627,36 @@ def test_the_models_flops_are_the_benchmarks_count(sizes):
             56 * 4 * 128 * 58_722_304)
         assert kernels["flash_window_bwd"]["cost"]["bytes"] == (
             kernels["flash_bwd"]["cost"]["bytes"])
+
+
+@pytest.mark.parametrize("impl, tokens, want", [
+    # the cell: one global and three window layers, each backward pass the
+    # ONE fused call; a head's float32 dq [16384, 128] stays in VMEM
+    ("flash", 16384, ("global=fused,window=fused", 4, 16384 * 128 * 4)),
+    # 128k tokens: a dq of 64 MB is past what a call may ask for
+    ("flash", 131072, ("global=two_call,window=two_call", 0, 0)),
+    # no flash kernel runs: autodiff's backward
+    ("full", 16384, ("global=xla,window=xla", 0, 0))])
+def test_fit_facts_say_which_form_the_attention_backward_takes(
+        impl, tokens, want):
+    """PR 43: by layer kind, from ``attn_impl`` and the shapes, as the
+    kernel decides it (``ops.flash_attention.backward_form``); at the
+    published widths."""
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "smallthinker-21b-a3b.json")) as f:
+        module = HybridLM.from_config(json.load(f), attn_impl=impl)
+    facts = module.fit_facts(np.zeros((2, tokens + 1), np.int32))
+    assert (facts["attention_backward"],
+            facts["attention.backward_fused_layers"],
+            facts["attention.dq_resident_bytes"]) == want
+    # heads of 64 are padded to the 128 lanes in VMEM: Granite's one layer
+    narrow = HybridLM(vocab_size=50176, attn_impl=impl).fit_facts(
+        np.zeros((1, 8193), np.int32))
+    form = want[0].split(",")[0] if tokens == 16384 else "global=fused"
+    assert narrow["attention_backward"] == form
+    if impl == "flash":
+        assert (narrow["attention.backward_fused_layers"],
+                narrow["attention.dq_resident_bytes"]) == (1, 8192 * 128 * 4)
 
 
 def test_a_model_without_window_layers_says_nothing_of_them():
