@@ -2,13 +2,14 @@
 
 from raydp_tpu.models.dlrm import DLRM, dlrm_optimizer, dlrm_sharding_rules
 from raydp_tpu.models.hybridlm import (
-    HybridLM, RoutedHybridLM, hybridlm_optimizer)
+    DeltaHybridLM, HybridLM, RoutedHybridLM, hybridlm_optimizer)
 from raydp_tpu.models.looplm import LoopLM, looplm_optimizer
 from raydp_tpu.models.mlp import MLPClassifier, MLPRegressor
 from raydp_tpu.models.transformer import TransformerLM, sequence_parallel_apply
 
 __all__ = [
     "DLRM",
+    "DeltaHybridLM",
     "HybridLM",
     "LoopLM",
     "MLPClassifier",

@@ -1,7 +1,7 @@
-"""Hybrid decoder LM: a MIXER KIND (``mamba``, ``attention``, ``conv``) and an
-FFN KIND (``dense``, ``experts``) per layer, in the order ``layer_types`` and
-``ffn_types`` give. Three published families are built from their
-``config.json`` (``from_config`` reads ``model_type``): ``granitemoehybrid``
+"""Hybrid decoder LM: a MIXER KIND (``mamba``, ``attention``, ``conv``,
+``delta``) and an FFN KIND (``dense``, ``experts``) per layer, in the order
+``layer_types`` and ``ffn_types`` give. Four published families are built from
+their ``config.json`` (``from_config`` reads ``model_type``): ``granitemoehybrid``
 with no experts (Mamba-2 and grouped-query attention without positions, a
 dense SwiGLU after each, four multipliers), ``lfm2_moe`` (gated short
 convolutions and grouped-query attention with RoPE and per-head q/k norms;
@@ -10,17 +10,28 @@ and ``smallthinker`` (every layer grouped-query attention with an explicit
 ``head_dim`` and routed ReGLU experts: per layer a window and RoPE, or
 neither, as ``sliding_window_layout`` and ``rope_layout`` say; the router is
 fed from the block's INPUT, before the first norm, and takes a softmax over
-the experts it selected; an untied head).
+the experts it selected; an untied head) and ``olmo_hybrid`` (gated
+delta-rule linear-attention layers, ``linear_attention`` in its
+``layer_types``, beside full-attention layers without positions whose q/k
+norms run over the whole projection; a dense SwiGLU in every layer; a
+sub-layer's OUTPUT is normed; this chip holds a share of every mixer's HEADS;
+an untied head).
 
 ::
 
     h = embedding_multiplier E[x]
     for mixer, ffn in zip(layer_types, ffn_types):
-      h = h + residual_multiplier Mixer_mixer(RMSNorm(h))     # pre-norm only
-      h = h + residual_multiplier FFN_ffn(RMSNorm(h))
+      h = h + residual_multiplier Mixer_mixer(RMSNorm(h))     # norm_placement
+      h = h + residual_multiplier FFN_ffn(RMSNorm(h))         # "pre"; "post":
+      # h = h + residual_multiplier RMSNorm(Mixer_mixer(h)), the FFN alike
     logits = RMSNorm_f(h) E^T / logits_scaling               # tied head; or
                                                              # W_head, untied
     loss = mean next-token cross-entropy
+
+``norm_placement`` is a field of the family: ``"pre"`` norms a sub-layer's
+input (the first three families), ``"post"`` its output before the residual
+addition (``olmo_hybrid``: the sub-layer reads the stream as it is; the gains
+are ``norm1`` and ``norm2`` either way).
 
 ``dense``: ``[g, u] = W_in y; W_out(silu(g) u)``. ``experts``
 (``ops.experts.routed_experts``, which says how, and the second scoring
@@ -53,7 +64,9 @@ depthwise over time, ``conv_kernel`` taps, no bias, float32.
 ``attention``: q of ``num_heads`` heads of ``head_dim`` (the hidden size
 over the heads unless given), k and v of ``num_kv_heads`` (each
 serves ``num_heads / num_kv_heads`` consecutive query heads), no bias;
-``qk_norm``: an RMSNorm over each head of q and of k (gains [head_dim]);
+``qk_norm``: an RMSNorm over each head of q and of k (gains [head_dim]; with
+``qk_norm_over="projection"`` over all the heads HELD here at once, gains
+[heads x head_dim], before the split into heads);
 ``rope_theta`` > 0: RoPE (``looplm``'s rotate-half) on q and k, else no
 positions (``rope_layers``, a flag a layer, takes RoPE away from the layers
 it marks 0); causal softmax of ``attention_multiplier q.k``, and in a layer
@@ -75,6 +88,27 @@ so that this chip's slots score the experts placed here
 (``expert_placement``, read by ``init`` alone: the experts' own weights are
 seeded alike). ``embed_std`` is the embedding's seeded spread; the matrices'
 is 0.02.
+
+``delta`` (a gated delta rule; H heads HELD here of ``delta_heads_total``,
+keys of Dk and values of Dv, a state [Dv, Dk] a head)::
+
+    q^, k^, v^ = silu(conv1d_causal(W_q a)), silu(conv(W_k a)), silu(conv(W_v a))
+    q_t = l2norm_head(q^_t) / sqrt(Dk);  k_t = l2norm_head(k^_t);  v_t = v^_t
+    beta_t = 2 sigmoid(W_b a)_t                               # beta in (0, 2)
+    alpha_t = exp(-exp(A_log) softplus((W_a a)_t + dt_bias))
+    S_t = alpha_t S_{t-1} (I - beta_t k_t k_t^T) + beta_t v_t k_t^T;  o_t = S_t q_t
+    out = W_o [ RMSNorm_head(o_t; gate_norm [Dv]) * silu(W_g a)_t ]
+
+depthwise, ``delta_conv`` taps, no bias; the recurrence is
+``ops.delta_rule.gated_delta_rule`` (chunks of ``ops.delta_rule.CHUNK``
+tokens: products and one unit-lower-triangular solve a chunk, a serial
+recurrence over the chunk states; scope ``delta_rule`` inside
+``hybridlm.delta``); convolution, l2 norms, beta, decays, the triangular
+inverse, the state and the read-out's norm are float32. A share of the heads
+is the columns of W_q, W_k, W_v, W_g, W_a, W_b, the convolution's channels,
+``A_log`` and ``dt_bias`` and the rows of W_o that belong to them: what the
+other heads would add through their rows of W_o is left out (one chip of a
+group that divides the heads, without its all-reduce).
 
 ``mamba`` (Mamba-2; H heads of P, one group, state N)::
 
@@ -120,17 +154,20 @@ from raydp_tpu.models.looplm import (
     rope_tables)
 from raydp_tpu.models.transformer import _attend, attention_backward_facts
 from raydp_tpu.ops import experts as experts_op
+from raydp_tpu.ops import delta_rule
 from raydp_tpu.ops.flash_attention import SAVED_RESIDUALS
 from raydp_tpu.ops.ssd import ssd_chunk_scan
 
-MAMBA, ATTENTION, CONV = "mamba", "attention", "conv"
+MAMBA, ATTENTION, CONV, DELTA = "mamba", "attention", "conv", "delta"
 DENSE, EXPERTS = "dense", "experts"
 # what a recomputed block keeps from its forward pass: the flash kernel's
 # output and log-sum-exp (an attention layer; with both kept the recomputed
 # kernel call is dead code) and ``w_out``'s output (every layer). A Mamba
 # mixer keeps nothing: the scan's backward pass needs the decays, the
 # scores and the chunk states, not ``y``, so a kept ``ssd_out`` would spare
-# two of its five products for 67 MB a layer and row
+# two of its five products for 67 MB a layer and row; a delta-rule mixer
+# keeps nothing for the same reason (its scan's result is named
+# ``ops.delta_rule.SAVED_OUTPUT`` for a policy that would)
 REMAT_KEEPS = SAVED_RESIDUALS + ("mlp_out",)
 # an expert layer keeps ``mlp_out`` (its combined result) as every layer
 # does, and its discrete part (``ops.experts.KEPT``: every token's choice,
@@ -145,6 +182,14 @@ EXPERT_KEEPS = (experts_op.KEPT,)
 
 def _inverse_softplus(x):
     return x + jnp.log(-jnp.expm1(-x))
+
+
+def _depthwise_causal(x, taps):
+    """``out_t = sum_k taps[k] x_{t - (K - 1) + k}`` over time, a channel at
+    a time: ``x`` [B, T, C] float32, ``taps`` [K, C]."""
+    k, t = taps.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+    return sum(taps[i] * padded[:, i:i + t] for i in range(k))
 
 
 class HybridLM(nn.Module):
@@ -192,6 +237,14 @@ class HybridLM(nn.Module):
     # an expert layer's seeded router columns in the order the group PLACED
     # its experts (``placed_by_load``); (): as seeded. Read by ``init`` alone
     expert_placement: Sequence[Sequence[int]] = ()
+    # -- what the fourth family adds; the defaults build the first three -----
+    delta_heads: int = 0  # delta-rule heads HELD here (key heads = value heads)
+    delta_heads_total: int = 0  # of so many the layer has; 0: all are held
+    delta_key_dim: int = 0
+    delta_value_dim: int = 0
+    delta_conv: int = 4  # taps of the depthwise convolution on q, k and v
+    norm_placement: str = "pre"  # or "post": a sub-layer's OUTPUT is normed
+    qk_norm_over: str = "head"  # or "projection": all the held heads' width
 
     # what ``loss`` reports of a TRAINING step beside its loss, by name in
     # its ``aux``: the estimator sums these over an epoch's steps inside the
@@ -210,8 +263,8 @@ class HybridLM(nn.Module):
     @classmethod
     def from_config(cls, config: dict, **kw):
         """From a published ``config.json``'s keys, by ``model_type``
-        (``granitemoehybrid``, the default, ``lfm2_moe`` or
-        ``smallthinker``). What the model does not build is refused, not
+        (``granitemoehybrid``, the default, ``lfm2_moe``, ``smallthinker``
+        or ``olmo_hybrid``). What the model does not build is refused, not
         ignored."""
         family = config.get("model_type", "granitemoehybrid")
         if family == "granitemoehybrid":
@@ -220,9 +273,12 @@ class HybridLM(nn.Module):
             fields = cls._lfm2_fields(config)
         elif family == "smallthinker":
             fields = cls._smallthinker_fields(config)
+        elif family == "olmo_hybrid":
+            fields = cls._olmo_hybrid_fields(config)
         else:
             raise ValueError(f"HybridLM builds model_type granitemoehybrid, "
-                             f"lfm2_moe and smallthinker, not {family!r}")
+                             f"lfm2_moe, smallthinker and olmo_hybrid, not "
+                             f"{family!r}")
         fields.update(kw)  # attn_impl, dtype, remat, loss_chunk; overrides
         fields["dtype"] = jnp.dtype(fields.get("dtype", cls.dtype))
         return cls(**fields)
@@ -370,6 +426,59 @@ class HybridLM(nn.Module):
             attention_multiplier=config["head_dim"] ** -0.5,
             logits_scaling=1.0, rms_eps=float(config["rms_norm_eps"]))
 
+    @classmethod
+    def _olmo_hybrid_fields(cls, config: dict) -> dict:
+        """``num_hidden_layers`` entries of ``layer_types`` from
+        ``share["first_layer"]`` on (a pipeline stage's layers):
+        ``linear_attention`` is the gated delta-rule mixer,
+        ``full_attention`` softmax attention without positions
+        (``rope_parameters.rope_theta`` null) under the family's q/k norms,
+        whose statistic runs over the whole projection HELD here; a dense
+        SwiGLU in every layer; a sub-layer's OUTPUT is normed before the
+        residual addition. ``num_attention_heads``, ``num_key_value_heads``
+        and ``linear_num_*_heads`` are the heads held here, of
+        ``share["heads_total"]`` (default: all), so ``head_dim`` is a key of
+        its own where a share is held (default: hidden over the heads)."""
+        cls._refuse(config, {
+            "attention_bias": False, "tie_word_embeddings": False,
+            "hidden_act": "silu", "linear_allow_neg_eigval": True},
+            {"linear_allow_neg_eigval": (
+                ": beta = 2 sigmoid(.), a step that may reflect, is the one "
+                "built")})
+        if config["linear_num_key_heads"] != config["linear_num_value_heads"]:
+            raise ValueError(
+                "HybridLM builds as many delta-rule key heads as value "
+                f"heads, not {config['linear_num_key_heads']} and "
+                f"{config['linear_num_value_heads']}")
+        share = config.get("share", {})
+        first, depth = share.get("first_layer", 0), config["num_hidden_layers"]
+        kinds = list(config["layer_types"][first:first + depth])
+        names = {"linear_attention": DELTA, "full_attention": ATTENTION}
+        if set(kinds) - set(names) or len(kinds) != depth:
+            raise ValueError(f"olmo_hybrid layers {first}..{first + depth - 1}"
+                             f": layer_types gives {kinds}")
+        heads = config["num_attention_heads"]
+        head_dim = config.get("head_dim") or config["hidden_size"] // heads
+        theta = (config.get("rope_parameters") or {}).get("rope_theta")
+        return dict(
+            vocab_size=config["vocab_size"],
+            layer_types=tuple(names[k] for k in kinds),
+            hidden_size=config["hidden_size"],
+            num_heads=heads, num_kv_heads=config["num_key_value_heads"],
+            head_dim=head_dim,
+            intermediate_size=config["intermediate_size"],
+            delta_heads=config["linear_num_key_heads"],
+            delta_heads_total=share.get(
+                "heads_total", config["linear_num_key_heads"]),
+            delta_key_dim=config["linear_key_head_dim"],
+            delta_value_dim=config["linear_value_head_dim"],
+            delta_conv=config["linear_conv_kernel_dim"],
+            norm_placement="post", qk_norm=True, qk_norm_over="projection",
+            rope_theta=float(theta or 0.0), tied_head=False,
+            embedding_multiplier=1.0, residual_multiplier=1.0,
+            attention_multiplier=head_dim ** -0.5, logits_scaling=1.0,
+            rms_eps=float(config["rms_norm_eps"]))
+
     # -- shapes ----------------------------------------------------------------
     @property
     def attention_width(self) -> int:
@@ -426,6 +535,12 @@ class HybridLM(nn.Module):
                     "wo": (wide, d), **after}
         if kind == CONV:
             return {"in_proj": (d, 3 * d), "out_proj": (d, d), **after}
+        if kind == DELTA:
+            heads = self.delta_heads
+            keys, values = heads * self.delta_key_dim, heads * self.delta_value_dim
+            return {"wq": (d, keys), "wk": (d, keys), "wv": (d, values),
+                    "wg": (d, values), "wa": (d, heads), "wb": (d, heads),
+                    "wo": (values, d), **after}
         return {"in_proj": (d, 2 * inner + 2 * self.mamba_state
                             + self.mamba_heads),
                 "out_proj": (inner, d), **after}
@@ -451,9 +566,24 @@ class HybridLM(nn.Module):
         d = self.hidden_size
         kinds, ffns = self.layer_types, self.ffn_kinds
         for kind in kinds:
-            if kind not in (MAMBA, ATTENTION, CONV):
+            if kind not in (MAMBA, ATTENTION, CONV, DELTA):
                 raise ValueError(f"layer kind {kind!r} is none of {MAMBA!r}, "
-                                 f"{ATTENTION!r}, {CONV!r}")
+                                 f"{ATTENTION!r}, {CONV!r}, {DELTA!r}")
+        if DELTA in kinds and not (
+                0 < self.delta_heads <= (self.delta_heads_total
+                                         or self.delta_heads)
+                and self.delta_key_dim > 0 and self.delta_value_dim > 0):
+            raise ValueError(
+                f"{self.delta_heads} delta-rule heads of "
+                f"{self.delta_heads_total or self.delta_heads}, keys of "
+                f"{self.delta_key_dim}, values of {self.delta_value_dim}: "
+                "not a delta-rule layer's share")
+        if (self.norm_placement not in ("pre", "post")
+                or self.qk_norm_over not in ("head", "projection")):
+            raise ValueError(
+                f"norm_placement {self.norm_placement!r}, qk_norm_over "
+                f"{self.qk_norm_over!r}: not among ('pre', 'post'), "
+                "('head', 'projection')")
         if len(ffns) != len(kinds) or set(ffns) - {DENSE, EXPERTS}:
             raise ValueError(f"ffn_types {ffns} does not give {DENSE!r} or "
                              f"{EXPERTS!r} for each of {len(kinds)} layers")
@@ -511,9 +641,15 @@ class HybridLM(nn.Module):
                     out["conv_w"] = jax.random.uniform(
                         keys[-1], (self.conv_kernel, d), jnp.float32,
                         -bound, bound)
+                elif kind == DELTA:
+                    out.update(self._delta_vectors(keys[-3:]))
                 elif self.qk_norm:
-                    out.update(q_norm=jnp.ones((self.head_dim,), jnp.float32),
-                               k_norm=jnp.ones((self.head_dim,), jnp.float32))
+                    whole = self.qk_norm_over == "projection"
+                    out.update(
+                        q_norm=jnp.ones((self.head_dim * (
+                            self.num_heads if whole else 1),), jnp.float32),
+                        k_norm=jnp.ones((self.head_dim * (
+                            self.num_kv_heads if whole else 1),), jnp.float32))
                 if ffn == EXPERTS and self.expert_scoring == "sigmoid":
                     spread = self.expert_bias_spread
                     out["expert_bias"] = jax.random.uniform(
@@ -537,25 +673,45 @@ class HybridLM(nn.Module):
             self.head_w = self.param("head", matrix, (d, self.vocab_size),
                                      jnp.float32)
 
-    def _mamba_vectors(self, keys) -> dict:
-        """Mamba-2's published initialisation: dt log-uniform in [0.001,
-        0.1] through the inverse softplus into ``dt_bias``, ``A_log`` the
-        log of U(1, 16), ``D`` ones; the convolution as torch initialises a
-        depthwise ``Conv1d`` (uniform in +-1/sqrt(k))."""
-        heads, k = self.mamba_heads, self.mamba_conv
-        channels = self.mamba_inner + 2 * self.mamba_state
+    @staticmethod
+    def _decay_vectors(keys, heads: int, taps: int, channels: int) -> dict:
+        """What a mixer with a decay a head and a depthwise convolution
+        seeds, by Mamba-2's published initialisation: dt log-uniform in
+        [0.001, 0.1] through the inverse softplus into ``dt_bias``,
+        ``A_log`` the log of U(1, 16); the convolution as torch initialises
+        a depthwise ``Conv1d`` (uniform in +-1/sqrt(k))."""
         dt = jnp.exp(jax.random.uniform(
             keys[0], (heads,), jnp.float32, math.log(1e-3), math.log(1e-1)))
-        bound = k ** -0.5
+        bound = taps ** -0.5
         return {
-            "conv_w": jax.random.uniform(keys[2], (k, channels), jnp.float32,
-                                         -bound, bound),
-            "conv_b": jnp.zeros((channels,), jnp.float32),
+            "conv_w": jax.random.uniform(keys[2], (taps, channels),
+                                         jnp.float32, -bound, bound),
             "dt_bias": _inverse_softplus(jnp.maximum(dt, 1e-4)),
             "A_log": jnp.log(jax.random.uniform(
                 keys[1], (heads,), jnp.float32, 1.0, 16.0)),
-            "D": jnp.ones((heads,), jnp.float32),
+        }
+
+    def _mamba_vectors(self, keys) -> dict:
+        """``_decay_vectors`` with a zero convolution bias, ``D`` ones and
+        the gated norm's gain."""
+        channels = self.mamba_inner + 2 * self.mamba_state
+        return {
+            **self._decay_vectors(keys, self.mamba_heads, self.mamba_conv,
+                                  channels),
+            "conv_b": jnp.zeros((channels,), jnp.float32),
+            "D": jnp.ones((self.mamba_heads,), jnp.float32),
             "gate_norm": jnp.ones((self.mamba_inner,), jnp.float32),
+        }
+
+    def _delta_vectors(self, keys) -> dict:
+        """``_decay_vectors`` over the q | k | v channels (no bias) and the
+        read-out norm's gain over a value head."""
+        heads = self.delta_heads
+        return {
+            **self._decay_vectors(
+                keys, heads, self.delta_conv,
+                heads * (2 * self.delta_key_dim + self.delta_value_dim)),
+            "gate_norm": jnp.ones((self.delta_value_dim,), jnp.float32),
         }
 
     # -- what the estimator writes down once per fit (fit_facts rule) --------
@@ -603,6 +759,18 @@ class HybridLM(nn.Module):
                         self.experts_per_token),
                 "experts.flops_per_row": parts["experts"],
                 "experts.flops_counted": "uniform share"})
+        delta = self.layer_types.count(DELTA)
+        if delta:
+            facts.update({
+                "layer_kinds.delta": delta,
+                "delta.heads_held": self.delta_heads,
+                "delta.heads_total": self.delta_heads_total or self.delta_heads,
+                "delta.chunk": min(delta_rule.CHUNK, t),
+                "delta.flops_per_row": parts["delta"],
+                # float32 [Dv, Dk] a held head and layer: what a row carries
+                # from token to token
+                "delta.state_bytes_per_row": delta * 4 * self.delta_heads
+                * self.delta_key_dim * self.delta_value_dim})
         attention = [w for kind, w in zip(self.layer_types, self.layer_windows)
                      if kind == ATTENTION]
         kinds = {"global": attention.count(0),
@@ -626,8 +794,11 @@ class HybridLM(nn.Module):
         form's four products at this chunk size, causal pairs inside a
         chunk), ``attention`` (causal: t (t + 1) / 2 kept pairs, a window
         layer's fewer: ``attention_pairs``), ``head``
-        (the embedding or the head, once) and, with expert layers, ``experts`` (6
-        x one expert's parameters x the uniform share of the pairs)."""
+        (the embedding or the head, once), with delta-rule layers ``delta``
+        (the recurrence's own 6 Dk Dv a token and held head:
+        ``ops.delta_rule.recurrence_flops``) and, with expert layers,
+        ``experts`` (6 x one expert's parameters x the uniform share of the
+        pairs)."""
         d, n = self.hidden_size, self.mamba_state
         heads, p = self.mamba_heads, self.mamba_head_dim
         mamba = self.layer_types.count(MAMBA)
@@ -635,8 +806,11 @@ class HybridLM(nn.Module):
             a * b for kind, ffn in zip(self.layer_types, self.ffn_kinds)
             for name, (a, b) in self.matrix_shapes(kind, ffn).items()
             if name not in ("w13", "w2"))
+        delta = self.layer_types.count(DELTA)
         conv = (mamba * self.mamba_conv * (self.mamba_inner + 2 * n)
-                + self.layer_types.count(CONV) * self.conv_kernel * d)
+                + self.layer_types.count(CONV) * self.conv_kernel * d
+                + delta * self.delta_conv * self.delta_heads
+                * (2 * self.delta_key_dim + self.delta_value_dim))
         q = min(self.mamba_chunk, t)
         pairs = (t // q) * (q * (q + 1) // 2)  # kept (i, j) pairs of a row
         scan = mamba * (2 * n * pairs + 2 * heads * p * pairs
@@ -646,6 +820,10 @@ class HybridLM(nn.Module):
             "scan": 3 * scan,
             "attention": 12 * self.attention_width * self.attention_pairs(t),
             "head": 6 * d * self.vocab_size * t}
+        if delta:
+            # what the RECURRENCE needs, whatever implements it
+            parts["delta"] = 3 * delta * delta_rule.recurrence_flops(
+                t, self.delta_heads, self.delta_key_dim, self.delta_value_dim)
         if self.expert_layers:
             # tokens x k x held / total pairs a layer, whole numbers here
             pairs_here = (t * self.experts_per_token * self.experts_held
@@ -729,10 +907,17 @@ class HybridLM(nn.Module):
             def split(z):  # [B, T, heads x Dh] -> [B, heads, T, Dh]
                 return z.reshape(b, t, -1, dh).transpose(0, 2, 1, 3)
 
-            q, k = split(self._dot(y, w["wq"])), split(self._dot(y, w["wk"]))
-            if self.qk_norm:
-                q = rms_norm(q, w["q_norm"], self.rms_eps)
-                k = rms_norm(k, w["k_norm"], self.rms_eps)
+            def normed(q, k, over):  # where the family norms them, if at all
+                if not self.qk_norm or self.qk_norm_over != over:
+                    return q, k
+                return (rms_norm(q, w["q_norm"], self.rms_eps),
+                        rms_norm(k, w["k_norm"], self.rms_eps))
+
+            # "projection": the statistic over all the heads held here,
+            # before the split; "head": over each head, after it
+            q, k = normed(self._dot(y, w["wq"]), self._dot(y, w["wk"]),
+                          "projection")
+            q, k = normed(split(q), split(k), "head")
             if self.rope_theta and rope:
                 cos, sin = rope_tables(t, dh, self.rope_theta)
                 q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
@@ -749,10 +934,8 @@ class HybridLM(nn.Module):
     def _conv(self, w, x):
         """Depthwise causal convolution over time and its silu, float32:
         ``out_t = bias + sum_k w[k] x_{t - (K - 1) + k}``."""
-        k, t = self.mamba_conv, x.shape[1]
-        padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (k - 1, 0), (0, 0)))
-        out = w["conv_b"] + sum(
-            w["conv_w"][i] * padded[:, i:i + t] for i in range(k))
+        out = w["conv_b"] + _depthwise_causal(
+            x.astype(jnp.float32), w["conv_w"])
         return nn.silu(out).astype(x.dtype)
 
     def _mamba(self, w, u):
@@ -771,6 +954,47 @@ class HybridLM(nn.Module):
             return self._dot(self._gated_norm(w, y.reshape(b, t, inner), z),
                              w["out_proj"])
 
+    def _delta_act(self, x, where: str):
+        """The mixer's activation after the convolution (``where`` is
+        ``"conv"``) and on the read-out's gate (``"gate"``): silu, both."""
+        return nn.silu(x)
+
+    def _delta_l2(self, x):
+        """x / sqrt(sum x^2 + 1e-6) over a head's width, float32."""
+        return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+
+    def _delta(self, w, a):
+        """The gated delta-rule mixer on ``a`` [B, T, D]: q, k, v through
+        the depthwise causal convolution and its silu, q and k l2-normed a
+        head (q scaled by Dk ** -0.5), ``beta`` and the decay a head from
+        ``a``, ``ops.delta_rule.gated_delta_rule``, and the read-out normed
+        a head and gated by silu(W_g a) before ``W_o``."""
+        with obs.device_scope("hybridlm.delta"):
+            b, t, _ = a.shape
+            heads, dk, dv = (self.delta_heads, self.delta_key_dim,
+                             self.delta_value_dim)
+            f32 = jnp.float32
+            qkv = jnp.concatenate(
+                [self._dot(a, w[name]) for name in ("wq", "wk", "wv")],
+                axis=-1).astype(f32)
+            qkv = self._delta_act(_depthwise_causal(qkv, w["conv_w"]), "conv")
+            q, k, v = jnp.split(qkv, [heads * dk, 2 * heads * dk], axis=-1)
+            q = self._delta_l2(q.reshape(b, t, heads, dk)) * dk ** -0.5
+            k = self._delta_l2(k.reshape(b, t, heads, dk))
+            # in (0, 2): past 1 a step reflects (linear_allow_neg_eigval)
+            beta = 2.0 * jax.nn.sigmoid(self._dot(a, w["wb"]).astype(f32))
+            log_alpha = -jnp.exp(w["A_log"]) * jax.nn.softplus(
+                self._dot(a, w["wa"]).astype(f32) + w["dt_bias"])
+            o = delta_rule.gated_delta_rule(
+                q.astype(self.dtype), k.astype(self.dtype),
+                v.reshape(b, t, heads, dv).astype(self.dtype),
+                log_alpha, beta)
+            gate = self._delta_act(self._dot(a, w["wg"]).astype(f32), "gate")
+            o = rms_norm(o.astype(f32), w["gate_norm"], self.rms_eps)
+            return self._dot(
+                (o.reshape(b, t, heads * dv) * gate).astype(self.dtype),
+                w["wo"])
+
     def _gated_norm(self, w, y, z):
         """RMSNorm(y silu(z)): the gate BEFORE the norm (Mamba-2's order),
         the norm over the whole inner width (one group)."""
@@ -782,11 +1006,9 @@ class HybridLM(nn.Module):
         depthwise causal convolution (no bias, no activation) and both
         gates in float32."""
         with obs.device_scope("hybridlm.conv"):
-            k, t = self.conv_kernel, u.shape[1]
             bm, cm, x = jnp.split(
                 self._dot(u, w["in_proj"]).astype(jnp.float32), 3, axis=-1)
-            padded = jnp.pad(bm * x, ((0, 0), (k - 1, 0), (0, 0)))
-            conv = sum(w["conv_w"][i] * padded[:, i:i + t] for i in range(k))
+            conv = _depthwise_causal(bm * x, w["conv_w"])
             return self._dot((cm * conv).astype(self.dtype), w["out_proj"])
 
     def _mlp(self, w, y):
@@ -818,15 +1040,23 @@ class HybridLM(nn.Module):
         """(h after the layer, what its FFN reports: {} for a dense one).
         ``window`` and ``rope`` are an attention layer's."""
         mixer = {MAMBA: self._mamba, CONV: self._short_conv,
+                 DELTA: self._delta,
                  ATTENTION: functools.partial(
                      self._attention, window=window, rope=rope)}[kind]
         # the router's input where it is the block's: h before the first norm
         routed_from = h if self.router_input == "block" else None
-        h = self._residual(h, mixer(w, rms_norm(h, w["norm1"], self.rms_eps)))
-        y = rms_norm(h, w["norm2"], self.rms_eps)
+        post = self.norm_placement == "post"
+
+        def normed(x, gain, here):  # "post": a sub-layer reads the stream as
+            # it is and its OUTPUT is normed; "pre": its input is
+            return rms_norm(x, gain, self.rms_eps) if here else x
+
+        h = self._residual(h, normed(
+            mixer(w, normed(h, w["norm1"], not post)), w["norm1"], post))
+        y = normed(h, w["norm2"], not post)
         out, report = self._experts(w, y, routed_from) if ffn == EXPERTS else (
             self._mlp(w, y), {})
-        return self._residual(h, out), report
+        return self._residual(h, normed(out, w["norm2"], post)), report
 
     def head(self, h):
         """Logits, float32, from the final norm's output (the tied head, or
@@ -946,6 +1176,14 @@ class RoutedHybridLM(HybridLM):
     for (``config["model"]["class"]``): a program from before the experts
     FFN kind has ``HybridLM`` and not this name, and a benchmark that asks
     for it there leaves at once instead of failing inside a fit."""
+
+
+class DeltaHybridLM(HybridLM):
+    """``HybridLM`` under the name a configuration with delta-rule layers
+    asks for (``config["model"]["class"]``), as ``RoutedHybridLM`` and for
+    its reason: a program from before the ``delta`` mixer kind has no such
+    name, and a benchmark that asks for it there leaves at once, before any
+    actor is started, instead of failing inside a fit."""
 
 
 def hybridlm_optimizer(learning_rate: float = 3e-4, b1: float = 0.9,

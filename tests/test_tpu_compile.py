@@ -609,3 +609,72 @@ def test_windowed_routed_hybridlm_epoch_program_fits_the_chip(
     assert len(re.findall(r"%[\w.\-]*gmm[\w.\-]* = \S+ custom-call", text)) == 64
     assert _loss_products(text, "hybridlm.loss") == 3
     assert "f32[4,16]" in text.split("ENTRY")[1].split("\n")[0]
+
+
+# -- the delta-rule hybrid's epoch program -----------------------------------------
+
+
+def test_delta_hybridlm_epoch_program_fits_the_chip(
+        one_chip, no_compile_cache, kernels_compile):
+    """ISSUE 45: the benchmark's epoch program of
+    ``olmo-hybrid-7b.pretrain-8k-delta`` (766,241,946 float32 parameters
+    counted from the built tree, AdamW, 3 steps of 1 x 8192 tokens gathered
+    from the resident rows and scanned, parameters and optimizer state
+    donated) for the described v5e: within 15.5e9 bytes; ONE causal flash
+    forward and ONE fused backward call (the full-attention layer, 15 heads
+    of 128; ``attn_out`` and ``attn_lse`` kept: none recomputed); the three
+    delta-rule layers' triangular solves compile for the chip; three
+    products in the loss; every instruction of the scan under the scope
+    ``delta_rule`` inside ``hybridlm.delta``."""
+    import json
+    import os
+
+    from raydp_tpu.estimator.jax_estimator import (
+        MODEL_LOSS, _scan_over_batches, make_train_step)
+    from raydp_tpu.models import HybridLM, hybridlm_optimizer
+    from raydp_tpu.obs import profiler
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "olmo-hybrid-7b.json")) as f:
+        config = json.load(f)
+    steps, tokens = 3, 8192
+    module = HybridLM.from_config(config, **config["model"]["kwargs"])
+    assert module.layer_types == ("delta", "delta", "delta", "attention")
+    on_chip = functools.partial(_on_chip, one_chip=one_chip)
+    rows = on_chip(jax.ShapeDtypeStruct((steps, tokens + 1), jnp.int32))
+    perm = on_chip(jax.ShapeDtypeStruct((steps,), jnp.int32))
+    params = on_chip(jax.eval_shape(
+        lambda r, s: module.init(r, s, None, method="loss"),
+        jax.random.PRNGKey(0), jax.ShapeDtypeStruct((1, tokens + 1), jnp.int32)))
+    sizes = {name: sum(leaf.size for leaf in jax.tree.leaves(sub))
+             for name, sub in params["params"].items()}
+    assert sizes == {
+        "embed": 12_544 * 3840, "head": 3840 * 12_544, "final_norm": 3840,
+        "layer_0": 171_195_102, "layer_1": 171_195_102,
+        "layer_2": 171_195_102, "layer_3": 156_314_880}
+    assert sum(sizes.values()) == 766_241_946
+    tx = hybridlm_optimizer(**config["model"]["adamw"])
+    state = on_chip(jax.eval_shape(tx.init, params))
+    step = make_train_step(module, MODEL_LOSS, tx)
+
+    def epoch(params, state, rows, perm):
+        return _scan_over_batches(
+            step, params, state, rows[perm].reshape(steps, 1, tokens + 1), None)
+
+    compiled = jax.jit(epoch, donate_argnums=(0, 1)).lower(
+        params, state, rows, perm).compile()
+    memory = compiled.memory_analysis()
+    held = (memory.argument_size_in_bytes + memory.output_size_in_bytes
+            - memory.alias_size_in_bytes + memory.temp_size_in_bytes)
+    print("delta hybrid epoch program holds", held)
+    assert held <= 15.5e9, held
+    text = compiled.as_text()
+    for name in ("flash_attention_fwd", "flash_attention_bwd_dq_dkv"):
+        assert len(re.findall(rf"%[\w.\-]*{name}[\w.\-]* = ", text)) == 1, name
+    assert not re.search(BWD_DKV, text)
+    assert _loss_products(text, "hybridlm.loss") == 3
+    chains = [tuple(said["scopes"])
+              for said in profiler.scopes_in_text(text).values()]
+    inside = [c for c in chains if "delta_rule" in c]
+    assert inside and all("hybridlm.delta" in c for c in inside)
