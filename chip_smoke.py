@@ -491,6 +491,23 @@ def phase_kernels(ph: Phase) -> None:
                           jnp.float32) for _ in range(2)]
     new = [jnp.asarray(rng.standard_normal((sz.batch, sz.embed_dim)),
                        jnp.float32) for _ in range(2)]
+    # the same rows read out of the same tables (ops/row_gather.py), first:
+    # against XLA's gather, bit for bit at every slot whose id is a row
+    from raydp_tpu.ops.row_gather import row_gather
+
+    gfn = jax.jit(row_gather)
+    require_kernel(ph, "row gather", gfn.lower(tables, uniq[0]))
+    read = ph.first_call("row gather", lambda: gfn(tables, uniq[0]))
+    live = np.asarray(uniq[0]) < size
+    same = all(
+        np.array_equal(np.asarray(g)[live], np.asarray(
+            t.at[uniq[0]].get(mode="clip"))[live])
+        for g, t in zip(read, tables))
+    ph.say(f"row gather vs XLA's gather, {size} rows of {sz.embed_dim}: "
+           f"{'bit-identical' if same else 'NOT bit-identical FAIL'}")
+    if not same:
+        failures.append("row gather")
+
     wfn = jax.jit(row_write_back)
     require_kernel(ph, "row write-back", wfn.lower(tables, new, uniq[0]))
     got = ph.first_call("row write-back", lambda: wfn(tables, new, uniq[0]))

@@ -166,13 +166,15 @@ def _compiled_twin(jitted, *args):
     return jitted.lower(*args).compile()
 
 
-def make_train_step(module, loss_fn, tx, row_paths=(), kernel_paths=()):
+def make_train_step(module, loss_fn, tx, row_paths=(), kernel_paths=(),
+                    gather_paths=()):
     """The one train-step body that the scan runner, the stream runner and
     the per-step loop wrap: ``(params, opt_state, loss_sum, x, y) ->
     (params, opt_state, loss_sum + loss)``. ``row_paths``: the parameters
-    that are differentiated and updated by the rows the batch read, and
+    that are differentiated and updated by the rows the batch read,
     ``kernel_paths`` those of them whose rows the write-back kernel puts
-    back (``row_update.plan`` decides both); none is the dense step."""
+    back and ``gather_paths`` those whose rows the gather kernel reads
+    (``row_update.plan`` decides all three); none is the dense step."""
     import jax
     import optax
 
@@ -189,7 +191,7 @@ def make_train_step(module, loss_fn, tx, row_paths=(), kernel_paths=()):
         if row_paths:
             params2, opt_state2, loss = row_update.step(
                 module, loss_fn, tx, row_paths, params, opt_state, x, y,
-                kernel_paths,
+                kernel_paths, gather_paths,
             )
             return params2, opt_state2, loss_sum + loss, {}
 
@@ -212,11 +214,11 @@ def make_train_step(module, loss_fn, tx, row_paths=(), kernel_paths=()):
     # program is the one without): what ``_scan_over_batches`` sums
     step_impl.reporting = reporting
 
-    if kernel_paths:
+    if kernel_paths or gather_paths:
         # what the FLOPs probe compiles in this step's place: the same step
-        # through XLA's scatter. XLA's cost analysis sees nothing inside a
-        # Mosaic call, the probe's program is never run, and lowering the
-        # eight kernels again costs a fit 1-2 s of set-up
+        # through XLA's scatter and gather. XLA's cost analysis sees nothing
+        # inside a Mosaic call, the probe's program is never run, and
+        # lowering the kernels again costs a fit 1-2 s of set-up
         step_impl.counted_as = make_train_step(module, loss_fn, tx, row_paths)
     return step_impl
 
@@ -612,6 +614,7 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                     row_update_params=len(self._row_plan.paths),
                     row_update_bytes_skipped=self._row_plan.bytes_skipped,
                     row_update_dma_leaves=self._row_plan.kernel_leaves,
+                    row_update_gather_leaves=self._row_plan.gather_leaves,
                 )
         self.compile_seconds_ += span.duration
         obs.metrics.counter("estimator.compile_seconds").inc(span.duration)
@@ -883,9 +886,13 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
         obs.metrics.gauge("estimator.row_update.dma_leaves").set(
             row_plan.kernel_leaves
         )
+        obs.metrics.gauge("estimator.row_update.gather_leaves").set(
+            row_plan.gather_leaves
+        )
 
         step_impl = make_train_step(
-            module, loss_fn, tx, row_plan.paths, row_plan.kernel_paths
+            module, loss_fn, tx, row_plan.paths, row_plan.kernel_paths,
+            row_plan.gather_paths,
         )
 
         train_step = partial_jit(donate_argnums=donate)(step_impl)

@@ -11,7 +11,9 @@ parameter-shaped leaf of the optimizer state, every other leaf whole) and
 scatters the rows back in place. The pytrees of ``params`` and ``opt_state``
 keep their structure and shapes. The rows go back through a kernel that owns
 its DMAs (``ops/row_write_back.py``) where the leaf allows it, and through
-XLA's scatter where not (:func:`_scatter_reason`).
+XLA's scatter where not (:func:`_scatter_reason`); of those leaves, the ones
+XLA's gather would copy whole to read a batch's rows are READ by the
+kernel's mirror (``ops/row_gather.py``, :func:`_gather_reason`).
 
 That is the same mathematics only where the optimizer is row-local and leaves
 a row with a zero gradient, and its state, as they were. Nothing here knows
@@ -48,6 +50,26 @@ from raydp_tpu import obs
 # 28): the two tables of 12,517 and 14,992 rows are still cheaper dense.
 MIN_ROWS_PER_BATCH_ROW = 32
 
+# A leaf the write-back kernel takes is READ by the gather kernel too
+# (ops/row_gather.py) up to this many rows: where XLA:TPU's gather first
+# copies the whole table, every step. Compiled for a described v5e over a
+# sweep of rows (2048 ids): up to 299,000 rows a relayout copy to the
+# row-major layout (eight times the table's bytes: into VMEM where they fit,
+# into HBM at 286,181 rows and over), from 300,000 to 1,800,000 rows a copy
+# of the table into VMEM, from 2,000,000 on neither. A parameter with its
+# state at the DLRM cells' ids through XLA's gather, us (chip runs, PERF.md
+# Findings, PR 46): 16,384 rows 18; 65,536: 59; 93,145: 83; 142,572: 110;
+# 286,181: 558; 299,000: 581; 320,000: 70; 1,000,000: 127; 1,800,000: 204;
+# 2,000,000: 98; the five tables of 2.2-10.1 M rows 97-145. Through the
+# kernel, whose time goes by the distinct ids and not by the table: 66 / 71 /
+# 80 at 93,145 / 286,181 / 10.1 M rows. So the kernel is the faster from some
+# 80,000 rows on, everywhere; but each table SHAPE it takes costs a process
+# 0.1 s of tracing and 0.15 s a program of lowering, and set-up is paid for
+# too: all eight shapes of the cells on the kernel gave 4 % of the step
+# (3.76 ms for 3.92) for 4.7 s of a 53-s set-up, the three that XLA copies
+# cost 1.8 s. The constant keeps the kernel to the tables XLA copies
+GATHER_KERNEL_MAX_ROWS = 2_000_000
+
 # marks, in an index tree, a leaf that is updated whole
 _WHOLE = object()
 
@@ -55,11 +77,13 @@ _WHOLE = object()
 @dataclass(frozen=True, eq=False)
 class _Rows:
     """In an index tree, a leaf that is updated by rows: their ids (what
-    :func:`sorted_unique` gives) and whether the kernel writes them back.
-    A parameter and the state that follows it hold the same object."""
+    :func:`sorted_unique` gives), whether the kernel writes them back and
+    whether a kernel reads them. A parameter and the state that follows it
+    hold the same object."""
 
     idx: Any
     kernel: bool = False
+    gather: bool = False
 
 
 @dataclass(frozen=True)
@@ -79,6 +103,13 @@ class RowPlan:
     kernel_leaves: int = 0
     scatter_leaves: int = 0
     scatter_reason: str = ""
+    # the same for the rows' way in: ``gather_paths`` are read by the gather
+    # kernel (of the ``kernel_paths``, those XLA's gather would copy whole:
+    # :func:`_gather_reason`), the other ``xla_gather_leaves`` by XLA's gather
+    gather_paths: Tuple[Tuple[str, ...], ...] = ()
+    gather_leaves: int = 0
+    xla_gather_leaves: int = 0
+    gather_reason: str = ""
 
     def stats(self) -> Dict[str, Any]:
         return {
@@ -90,6 +121,11 @@ class RowPlan:
                 "kernel": self.kernel_leaves,
                 "scatter": self.scatter_leaves,
                 "reason": self.scatter_reason,
+            },
+            "gather": {
+                "kernel": self.gather_leaves,
+                "xla": self.xla_gather_leaves,
+                "reason": self.gather_reason,
             },
         }
 
@@ -138,10 +174,43 @@ def _stateful_leaves(tx, opt_state) -> int:
     return sum(jax.tree.leaves(outside))
 
 
-def _take(leaf, i):
+def _take(leaf, i, read=None):
     # the padding slots of idx lie past the last row: they read it (clip)
-    # and are dropped again by _put
+    # and are dropped again by _put. ``read``: the rows, where _read took them
+    if read is not None:
+        return read
     return leaf if i is _WHOLE else leaf.at[i.idx].get(mode="clip")
+
+
+def _read(trees, indexes):
+    """The rows of the leaves of ``trees`` that the gather kernel reads
+    (``_Rows.gather``), in ``trees``' structure, None at every other leaf
+    (:func:`_take` reads those). A parameter and its state share their ids,
+    so the kernel reads them in one call, as :func:`_put` writes them."""
+    import jax
+
+    from raydp_tpu.ops.row_gather import row_gather
+
+    leaves, treedef = jax.tree.flatten(trees)
+    index = treedef.flatten_up_to(indexes)
+    rows = [None] * len(leaves)
+    for where in _kernel_calls(index, "gather"):
+        for n, got in zip(where, row_gather(
+                [leaves[n] for n in where], index[where[0]].idx)):
+            rows[n] = got
+    return treedef.unflatten(rows)
+
+
+def _kernel_calls(index, which="kernel"):
+    """The leaves a kernel takes (``which``: the write-back ``kernel`` or the
+    ``gather`` kernel; their positions in ``index``, a flat list of an index
+    tree's leaves), a list for each set of ids: a parameter and the state
+    that follows it go into one call."""
+    calls: Dict[int, list] = {}
+    for n, i in enumerate(index):
+        if i is not _WHOLE and getattr(i, which):
+            calls.setdefault(id(i), []).append(n)
+    return list(calls.values())
 
 
 def _scatter_reason(leaf) -> str:
@@ -163,6 +232,17 @@ def _scatter_reason(leaf) -> str:
     return row_write_back.supports(leaf.shape, leaf.dtype)
 
 
+def _gather_reason(leaf) -> str:
+    """Why the rows of ``leaf``, which the kernel writes back, are read by
+    XLA's gather and not by the gather kernel; empty where the kernel reads
+    them. The shape decides: the kernel goes where XLA's gather would copy
+    the table whole."""
+    if leaf.shape[0] > GATHER_KERNEL_MAX_ROWS:
+        return (f"XLA's gather reads a table of {leaf.shape[0]} rows where "
+                f"it lies (it copies one of {GATHER_KERNEL_MAX_ROWS} or less)")
+    return ""
+
+
 def _put(trees, minis, indexes):
     """``trees`` with the rows of ``minis`` written back where ``indexes``
     says. A parameter and its state share their ids, so the kernel writes
@@ -174,19 +254,16 @@ def _put(trees, minis, indexes):
     leaves, treedef = jax.tree.flatten(trees)
     rows = treedef.flatten_up_to(minis)
     index = treedef.flatten_up_to(indexes)
-    calls: Dict[int, list] = {}
     for n, (leaf, new, i) in enumerate(zip(leaves, rows, index)):
         if i is _WHOLE:
             leaves[n] = new
-        elif i.kernel:
-            calls.setdefault(id(i), []).append(n)
-        else:
+        elif not i.kernel:
             # idx is sorted and without repeats, and XLA is not told:
             # promised both, XLA:TPU scatters into a table of
             # 100,000-300,000 rows in time proportional to the table, 0.44
             # ms against 0.14 (PERF.md, PR 25)
             leaves[n] = leaf.at[i.idx].set(new, mode="drop")
-    for where in calls.values():
+    for where in _kernel_calls(index):
         for n, leaf in zip(where, row_write_back(
                 [leaves[n] for n in where], [rows[n] for n in where],
                 index[where[0]].idx)):
@@ -221,14 +298,19 @@ def sorted_unique(ids, sizes):
     return uniq, inv
 
 
-def update_rows(tx, params, opt_state, mini_params, mini_grads, index_tree):
+def update_rows(tx, params, opt_state, mini_params, mini_grads, index_tree,
+                state_read=None):
     """``tx.update`` on the mini-tree and the scatter back: the dense
-    ``tx.update`` + ``apply_updates`` where ``tx`` passes :func:`probe`."""
+    ``tx.update`` + ``apply_updates`` where ``tx`` passes :func:`probe`.
+    ``state_read``: the state's rows that :func:`_read` took beside the
+    parameters' (None: none)."""
     import jax
     import optax
 
     state_index = _state_index_tree(tx, opt_state, index_tree)
-    mini_state = jax.tree.map(_take, opt_state, state_index)
+    if state_read is None:
+        state_read = jax.tree.map(lambda _: None, opt_state)
+    mini_state = jax.tree.map(_take, opt_state, state_index, state_read)
     updates, mini_state = tx.update(mini_grads, mini_state, mini_params)
     mini_params = optax.apply_updates(mini_params, updates)
     return _put(
@@ -237,10 +319,12 @@ def update_rows(tx, params, opt_state, mini_params, mini_grads, index_tree):
     )
 
 
-def step(module, loss_fn, tx, paths, params, opt_state, x, y, kernel_paths=()):
+def step(module, loss_fn, tx, paths, params, opt_state, x, y, kernel_paths=(),
+         gather_paths=()):
     """One train step with ``paths`` on the row path, those of
-    ``kernel_paths`` written back by the kernel. Returns ``(params,
-    opt_state, loss)`` as the dense step does."""
+    ``kernel_paths`` written back by the kernel and those of
+    ``gather_paths`` read by one. Returns ``(params, opt_state, loss)`` as
+    the dense step does."""
     import jax
     import jax.numpy as jnp
 
@@ -252,8 +336,15 @@ def step(module, loss_fn, tx, paths, params, opt_state, x, y, kernel_paths=()):
             [whole[p].shape[0] for p in paths],
         )
         index_tree = _index_tree(params, {
-            p: _Rows(uniq[s], p in kernel_paths) for s, p in enumerate(paths)})
-        mini_params = jax.tree.map(_take, params, index_tree)
+            p: _Rows(uniq[s], p in kernel_paths, p in gather_paths)
+            for s, p in enumerate(paths)})
+        # the gather kernel reads a parameter's rows and its state's in one
+        # call: here, for both (what XLA's gather reads of the state it
+        # reads in update_rows, as ever)
+        params_read, state_read = _read(
+            (params, opt_state),
+            (index_tree, _state_index_tree(tx, opt_state, index_tree)))
+        mini_params = jax.tree.map(_take, params, index_tree, params_read)
 
         def compute(mini):
             rows = _by_path(mini)
@@ -272,7 +363,8 @@ def step(module, loss_fn, tx, paths, params, opt_state, x, y, kernel_paths=()):
         loss, mini_grads = jax.value_and_grad(compute)(mini_params)
     with obs.device_scope("optimizer_update"):
         params, opt_state = update_rows(
-            tx, params, opt_state, mini_params, mini_grads, index_tree
+            tx, params, opt_state, mini_params, mini_grads, index_tree,
+            state_read,
         )
     return params, opt_state, loss
 
@@ -422,6 +514,7 @@ def plan(module, tx, params, x, batch: int) -> RowPlan:
     skipped = 0
     leaves = {"/".join(p): 0 for p in paths}
     scatter: Dict[str, str] = {}  # the paths with a leaf the kernel refuses
+    xla: Dict[str, str] = {}  # the paths whose rows XLA's gather reads
     for tree, index in ((params, index_tree), (state, state_index)):
         for leaf, i in zip(jax.tree.leaves(tree), jax.tree.leaves(index)):
             if i is not _WHOLE:
@@ -431,11 +524,19 @@ def plan(module, tx, params, x, batch: int) -> RowPlan:
                 why = _scatter_reason(leaf)
                 if why:
                     scatter.setdefault(i, why)
+                why = why or _gather_reason(leaf)
+                if why:
+                    xla.setdefault(i, why)
     kernel_paths = tuple(p for p in paths if "/".join(p) not in scatter)
     kernel_leaves = sum(leaves["/".join(p)] for p in kernel_paths)
+    gather_paths = tuple(p for p in paths if "/".join(p) not in xla)
+    gather_leaves = sum(leaves["/".join(p)] for p in gather_paths)
     return RowPlan(
         paths=paths, bytes_skipped=skipped, kernel_paths=kernel_paths,
         kernel_leaves=kernel_leaves,
         scatter_leaves=sum(leaves.values()) - kernel_leaves,
         scatter_reason=next(iter(scatter.values()), ""),
+        gather_paths=gather_paths, gather_leaves=gather_leaves,
+        xla_gather_leaves=sum(leaves.values()) - gather_leaves,
+        gather_reason=next(iter(xla.values()), ""),
     )

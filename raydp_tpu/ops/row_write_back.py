@@ -6,7 +6,8 @@ XLA:TPU keeps a ``float32[V, 16]`` table as ``{0,1:T(8,128)}``: transposed,
 takes its own bytes and not eight times that. Its scatter into that layout
 writes one slot after another, padding slots included, and for tables of
 about 15,000-300,000 rows it copies the whole table to the row-major layout
-(16 padded to 128 lanes), scatters, and copies it back.
+(16 padded to 128 lanes), scatters, and copies it back (its gather makes the
+same copy to read rows: ``ops/row_gather.py`` is this kernel's mirror).
 
 ``table.T`` is a bitcast of that layout, and this kernel works on it: the
 ``[D, V]`` view stays in HBM, aliased to the result. For every 128-row block

@@ -7,7 +7,7 @@ import pytest
 
 from raydp_tpu.estimator import row_update
 from raydp_tpu.estimator.jax_estimator import _LOSSES, make_train_step
-from raydp_tpu.ops import backend, row_write_back as rwb
+from raydp_tpu.ops import backend, row_gather, row_write_back as rwb
 from tests.test_jax_estimator import criteo_df, session  # noqa: F401 - fixtures
 from tests.test_row_update import (
     BATCH, ROW_PATHS, _batches, _criteo_est, _dlrm, _losses, _optimizers,
@@ -91,7 +91,10 @@ def kernel_everywhere(monkeypatch):
     ``ops/backend.py``'s rule): the one thing ``_scatter_reason`` reads that
     a test can set without a knob in the program."""
     monkeypatch.setattr(backend, "on_tpu", lambda: True)
-    monkeypatch.setattr(rwb, "pallas_interpret", lambda interpret=None: True)
+    # the plan then reads the rows through the gather kernel too
+    for module in (rwb, row_gather):
+        monkeypatch.setattr(module, "pallas_interpret",
+                            lambda interpret=None: True)
 
 
 @pytest.mark.parametrize("name", ["adagrad", "sgd"])

@@ -142,6 +142,8 @@ CRITEO_KAGGLE = (
     286181, 105, 142572)
 ROW_TABLES = (10131227, 2202608, 93145, 8351593, 5461306, 7046547, 286181,
               142572)
+# of them, those XLA's gather would copy whole: the gather kernel reads them
+GATHERED_TABLES = (93145, 286181, 142572)
 
 
 def _table_results(text, ops, layout=""):
@@ -155,17 +157,19 @@ def _table_results(text, ops, layout=""):
 def test_dlrm_step_writes_rows_back_with_the_kernel(
         one_chip, no_compile_cache, kernels_compile):
     """The benchmark's DLRM step (batch 2048, the 26 Criteo-Kaggle tables,
-    Adagrad) with the write-back kernel, against the same step through XLA's
-    scatter (the parent commit's step, text for text): eight kernel calls
-    (a table and its accumulator each) on bitcasts of the tables, no table
-    written back whole, no second copy of a table among the temporaries."""
+    Adagrad) with both kernels, against the same step through XLA's gather
+    (PR 28's step) and through XLA's scatter too (PR 25's step, text for
+    text): eight write-back calls (a table and its accumulator each) and
+    three gather calls (the tables XLA's gather would copy) on bitcasts of
+    the tables, no operation whose result is a whole row-path table besides
+    the kernels, no second copy of a table among the temporaries."""
     import optax
 
     from raydp_tpu.estimator import row_update
     from raydp_tpu.estimator.jax_estimator import _LOSSES, make_train_step
     from raydp_tpu.models import DLRM
 
-    batch = 2048  # ``kernels_compile``: the plan then takes the kernel
+    batch = 2048  # ``kernels_compile``: the plan then takes the kernels
     module = DLRM(vocab_sizes=CRITEO_KAGGLE, num_dense=13, embed_dim=16,
                   bottom_mlp=(512, 256, 64), top_mlp=(512, 256),
                   use_pallas_interaction=False)
@@ -182,36 +186,49 @@ def test_dlrm_step_writes_rows_back_with_the_kernel(
         ROW_TABLES)
     assert plan.stats()["write_back"] == {
         "kernel": 16, "scatter": 0, "reason": ""}
+    read = plan.stats()["gather"]
+    assert (read["kernel"], read["xla"]) == (6, 10) and "rows" in read["reason"]
+    assert sorted(params["params"][p[1]].shape[0]
+                  for p in plan.gather_paths) == sorted(GATHERED_TABLES)
 
-    def compiled(kernel_paths):
+    def compiled(kernel_paths, gather_paths):
         step = make_train_step(module, _LOSSES["bce"], tx, plan.paths,
-                               kernel_paths)
+                               kernel_paths, gather_paths)
         return jax.jit(step, donate_argnums=(0, 1, 2)).lower(
             params, state, on_chip(jax.ShapeDtypeStruct((), jnp.float32)),
             x, y).compile()
 
-    kernel, scatter = compiled(plan.kernel_paths), compiled(())
-    text, parent = kernel.as_text(), scatter.as_text()
-    calls = r"%row_write_back[\w.\-]* = "
-    assert len(re.findall(calls, text)) == 8
+    kernel = compiled(plan.kernel_paths, plan.gather_paths)
+    gather, scatter = compiled(plan.kernel_paths, ()), compiled((), ())
+    text, xla_reads, parent = (c.as_text() for c in (kernel, gather, scatter))
+    writes, reads = (rf"%{name}[\w.\-]* = " for name in (
+        "row_write_back", "row_gather"))
+    assert len(re.findall(writes, text)) == 8
+    assert len(re.findall(reads, text)) == len(GATHERED_TABLES)
     # the instructions, not the bare name: the text's table of source files
     # names tests/test_row_write_back.py when this worker ran it before
-    assert not re.findall(calls, parent)
+    assert not re.findall(writes, parent) and not re.findall(reads, parent)
+    assert len(re.findall(writes, xla_reads)) == 8
+    assert not re.findall(reads, xla_reads)
     assert len(_table_results(parent, "scatter")) == 16
-    # the kernel's operands and results are the tables themselves
+    # the kernels' operands and results are the tables themselves
     assert not _table_results(text, "scatter|transpose")
-    assert len(_table_results(text, "bitcast")) == 32
+    # (the write-back's 32 views, in and out, and the gather's six)
+    assert len(_table_results(text, "bitcast")) == 32 + 6
     # XLA's scatter copies the three tables of 93,145-286,181 rows to the
-    # row-major layout and back; with the kernel no table comes back whole
-    # ({0,1}: the tables' own layout), none is copied for the [16, V] view,
-    # and what is left is the copy that XLA's gather (``_take``) reads
+    # row-major layout and back, and XLA's gather copies them there to read
+    # them ({1,0}; the tables' own layout is {0,1}); with both kernels no
+    # table is copied in either orientation, for either view, or moved to
+    # VMEM for a call (by halves: ``slice-done f32[V,8]``)
     copies = "copy|copy-done"
     assert _table_results(parent, copies, layout="0,1")
-    assert not _table_results(text, copies, layout="0,1")
-    assert not [c for c in _table_results(text, copies) if "[16," in c]
-    assert 2 * len(_table_results(text, copies)) <= len(
-        _table_results(parent, copies))
+    assert len(_table_results(xla_reads, copies, layout="1,0")) == 6
+    assert not _table_results(xla_reads, copies, layout="0,1")
+    assert not _table_results(text, copies)
+    halves = "|".join(f"{v},8" for v in ROW_TABLES)
+    assert not re.findall(rf"= f32\[(?:{halves})\]", text)
     assert (kernel.memory_analysis().temp_size_in_bytes
+            <= gather.memory_analysis().temp_size_in_bytes
             <= scatter.memory_analysis().temp_size_in_bytes)
 
 
