@@ -697,7 +697,7 @@ def phase_fit(ph: Phase) -> None:
         # shard_map lowers to a manual computation; the einsum has none
         check("sdy.manual_computation" in lowered.as_text(),
               "multi-device DLRM step took the einsum, not the shard_map "
-              "branch of dot_interaction_fused")
+              "branch of interaction_fused")
         table = fitted.params["params"]["embedding_0"]
         on = {s.device for s in table.addressable_shards}
         check(len(on) == n, f"embedding_0 lives on {len(on)}/{n} devices")

@@ -7,8 +7,14 @@ TPU-first differences from the reference:
   NamedSharding rules (``dlrm_sharding_rules``) — XLA partitions the gathers
   and inserts the collectives (the reference trains pure-DP with replicated
   tables; BASELINE.md asks for sharded);
-- the interaction is the fused op from raydp_tpu.ops.interaction (MXU batched
-  Gram matmul), optionally the pallas kernel;
+- the interaction is the op from raydp_tpu.ops.interaction on a
+  FEATURE-MAJOR operand: the bottom MLP's output and every table's rows as
+  ``[embed_dim, B]`` slabs, the batch on the lanes, stacked to ``[1 + S,
+  embed_dim, B]``. A ``[B, embed_dim]`` block pads its 16 columns to 128
+  lanes on the chip, and as a ``[B, 1, embed_dim]`` block of a batch-major
+  stack 8- to 128-fold: the step spent 37 % of its time on such blocks
+  (PERF.md, Findings, PR 47). The Mosaic kernel on a TPU, an einsum elsewhere
+  and wherever the kernel does not take the width (``fit_facts`` says which);
 - bfloat16 compute path for the MXU via ``dtype=jnp.bfloat16``.
 
 Input convention — two forms:
@@ -30,7 +36,7 @@ import jax.numpy as jnp
 
 from raydp_tpu import obs
 from raydp_tpu.ops.backend import on_tpu
-from raydp_tpu.ops.interaction import dot_interaction, dot_interaction_fused
+from raydp_tpu.ops.interaction import interaction_fused, interaction_xla, supports
 
 
 class DLRM(nn.Module):
@@ -84,6 +90,37 @@ class DLRM(nn.Module):
         _, ids = self._split(x)
         return {("params", f"embedding_{i}"): col for i, col in enumerate(ids)}
 
+    def _use_pallas(self) -> bool:
+        if self.use_pallas_interaction is None:
+            # the kernel's forward call takes 50 us for the einsum's 58 at
+            # the Criteo shapes on a v5e, timed alone (PERF.md, Findings, PR
+            # 47); multi-device meshes run it per-shard via shard_map
+            # (interaction_fused) — the dp×tp path keeps the kernel
+            return on_tpu()
+        return self.use_pallas_interaction
+
+    # -- what the estimator writes down once per fit (fit_facts rule) --------
+    def fit_facts(self, x) -> dict:
+        """The interaction as this fit runs it: the operand's layout (one for
+        every caller), which path computes it (``mosaic``: the kernel;
+        ``xla`` and why where it cannot run or is not asked for) and the row
+        blocks a sample sends through it. No ``flops_per_row``: XLA's count
+        of the step program stands."""
+        if not self._use_pallas():
+            why = ("the model's use_pallas_interaction is False"
+                   if self.use_pallas_interaction is not None
+                   else "the backend is no TPU")
+        else:
+            why = supports(self.embed_dim, self.dtype)
+        facts = {
+            "interaction_operand": "feature_major",
+            "interaction_kernel": "xla" if why else "mosaic",
+            "interaction.row_blocks": 1 + len(self.vocab_sizes),
+        }
+        if why:
+            facts["interaction_kernel_reason"] = why
+        return facts
+
     @nn.compact
     def __call__(self, x, rows=None):
         """``rows`` (optional): ``{path: [B, embed_dim]}`` for any of
@@ -98,8 +135,11 @@ class DLRM(nn.Module):
             h = nn.relu(nn.Dense(width, dtype=self.dtype)(h))
         h = nn.Dense(self.embed_dim, dtype=self.dtype, name="bottom_proj")(h)
 
-        # per-feature embedding tables (vocab-sharded under the rules below)
-        stacked = [h]
+        # per-feature embedding tables (vocab-sharded under the rules below).
+        # A sample's 1 + S vectors go to the interaction FEATURE-MAJOR, the
+        # batch on the lanes: each a [D, B] slab of whole tiles, stacked
+        # along a leading axis that moves none (ops/interaction.py)
+        slabs = [h.T]
         for i, vocab in enumerate(self.vocab_sizes):
             table = self.param(
                 f"embedding_{i}",
@@ -108,24 +148,18 @@ class DLRM(nn.Module):
                 jnp.float32,
             )
             given = rows.get(("params", f"embedding_{i}"))
-            stacked.append(
+            slabs.append((
                 jnp.take(table.astype(self.dtype), ids[i], axis=0)
                 if given is None
                 else given.astype(self.dtype)
-            )
-        t = jnp.stack(stacked, axis=1)  # [B, 1+S, D]
+            ).T)
+        t = jnp.stack(slabs)  # [1+S, D, B]
 
-        use_pallas = self.use_pallas_interaction
-        if use_pallas is None:
-            # the fused kernel measures 1.46x the einsum on TPU; multi-device
-            # meshes run it per-shard via shard_map (dot_interaction_fused) —
-            # the dp×tp path keeps the kernel instead of falling back
-            use_pallas = on_tpu()
         # a stable name in the device trace, whichever path computes it
         with obs.device_scope("dlrm_interaction"):
             interact = (
-                dot_interaction_fused(t) if use_pallas else dot_interaction(t)
-            )
+                interaction_fused if self._use_pallas() else interaction_xla
+            )(t)
         z = jnp.concatenate([h, interact.astype(self.dtype)], axis=1)
 
         for width in self.top_mlp:

@@ -2,16 +2,35 @@
 
 The pairwise-feature-interaction at the heart of DLRM (the reference ships it
 inside the pytorch_dlrm notebook's model as a python loop over torch ops): for
-stacked per-feature embeddings T = [B, F, D], compute all pairwise dot
-products and return the strict lower triangle, [B, F*(F-1)/2].
+a sample's ``F`` feature vectors of ``D`` numbers, all pairwise dot products,
+the strict lower triangle packed row by row: ``[B, F*(F-1)/2]``.
 
-Two paths:
-- ``dot_interaction``: XLA einsum + static gather — lowers to one batched MXU
-  matmul; the fallback and autodiff path.
-- ``dot_interaction_pallas``: fused pallas kernel (batch-tiled; keeps T in
-  VMEM, runs the F×F Gram matmul on the MXU, selects the triangle in-register
-  and writes only the packed output). Runs ``interpret=True`` off-TPU so tests
-  exercise the same kernel on the CPU mesh.
+The operand is FEATURE-MAJOR, the batch on the lanes: ``[F, D, B]``. On the
+chip a ``[B, D]`` block of rows (D = 16) pads its 16 columns to the 128
+lanes, eight times its bytes, and as one of ``F`` blocks of a ``[B, F, D]``
+operand it is a ``[B, 1, D]`` array first, which pads 8- to 128-fold (the
+size-1 axis on the sublanes or on the lanes): a DLRM step spent 1.46 of its
+3.9 ms building and slicing such blocks (PERF.md, Findings, PR 47). A
+``[D, B]`` slab is whole tiles, sixteen lane tiles by ``D / 8`` sublane
+tiles with nothing padded, stacking ``F`` of them along a leading axis moves
+no tile, and it is the form the row kernels' results and the tables
+themselves have on the chip (``ops/row_gather.py``).
+
+- ``interaction_xla`` / ``dot_interaction``: XLA, the fallback path (an einsum
+  and a static gather), for the feature-major and for a ``[B, F, D]``
+  operand.
+- ``interaction_pallas``: the Mosaic kernel ``dlrm_interaction`` on the
+  feature-major operand, a tile of lanes a grid step: the tile is turned in
+  VMEM into ``[bb, F, D]`` (where the padding costs no HBM traffic), every
+  sample's Gram matrix is one batched MXU product at the precision of the
+  trace's context, the triangle is packed in registers and only
+  ``[B, F*(F-1)/2]`` is written. Its backward is ``jnp`` on ``[F, D, B]``.
+  Runs ``interpret=True`` off-TPU so tests exercise the same kernel on the
+  CPU.
+- ``interaction_fused``: the kernel where it can run (per shard under a mesh,
+  the batch axis LAST in its specs), XLA where not (:func:`supports`).
+- ``dot_interaction_pallas`` / ``dot_interaction_fused``: the same for a
+  ``[B, F, D]`` operand, transposed on the way in.
 """
 
 from __future__ import annotations
@@ -22,8 +41,16 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
+from jax.experimental import pallas as pl
 
 from raydp_tpu.ops.backend import pallas_interpret
+
+LANES, SUBLANES = 128, 8
+# samples a grid step of the kernel (whole lane tiles): a tile's batch-major
+# form and its Gram matrices are padded to (8, 128) tiles in VMEM, 2 MB each
+# at 128 samples (chip runs by tile: PERF.md Findings, PR 47)
+BLOCK_BATCH = 128
 
 
 def _tril_indices(f: int):
@@ -31,51 +58,117 @@ def _tril_indices(f: int):
     return rows.astype(np.int32), cols.astype(np.int32)
 
 
-def dot_interaction(stacked: jnp.ndarray) -> jnp.ndarray:
-    """[B, F, D] -> [B, F*(F-1)/2] pairwise dots (XLA path)."""
-    gram = jnp.einsum("bfd,bgd->bfg", stacked, stacked)
-    rows, cols = _tril_indices(stacked.shape[1])
+def _triangle(gram: jnp.ndarray) -> jnp.ndarray:
+    """[B, F, F] -> its strict lower triangle, row by row."""
+    rows, cols = _tril_indices(gram.shape[1])
     return gram[:, rows, cols]
 
 
+def dot_interaction(stacked: jnp.ndarray) -> jnp.ndarray:
+    """[B, F, D] -> [B, F*(F-1)/2] pairwise dots (XLA path)."""
+    return _triangle(jnp.einsum("bfd,bgd->bfg", stacked, stacked))
+
+
+def interaction_xla(slabs: jnp.ndarray) -> jnp.ndarray:
+    """[F, D, B] -> [B, F*(F-1)/2] pairwise dots (XLA path)."""
+    return _triangle(jnp.einsum("fdb,gdb->bfg", slabs, slabs))
+
+
+def supports(dim: int, dtype) -> str:
+    """Why a ``[F, dim, B]`` operand of this dtype cannot go through the
+    kernel; empty if it can. A ``[dim, bb]`` slab of the block has to be
+    whole sublane tiles: 8 rows of 4 bytes, 16 of 2."""
+    dtype = jnp.dtype(dtype)
+    if not jnp.issubdtype(dtype, jnp.floating) or dtype.itemsize not in (2, 4):
+        return f"a {dtype.name} operand"
+    rows = SUBLANES * 4 // dtype.itemsize
+    if dim % rows:
+        return f"{dtype.name} vectors of {dim} (not a multiple of {rows})"
+    return ""
+
+
 def _interaction_kernel(t_ref, out_ref):
-    t = t_ref[:]  # [BB, F, D]
-    gram = jax.lax.dot_general(
+    """A tile of lanes of the feature-major operand, turned in VMEM (exact)
+    into the batch-major form the MXU wants, then the Gram of every sample
+    as ONE batched product, at the precision of the trace's context: the
+    arithmetic, and so the bits, of the ``[B, F, D]`` kernel this replaces
+    and of XLA's einsum (PERF.md, Findings, PR 47: a forward that differs in
+    the last place flips a ReLU at its kink now and then)."""
+    t = jnp.transpose(t_ref[...].astype(jnp.float32), (2, 0, 1))  # [bb, F, D]
+    gram = lax.dot_general(
         t, t, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
-    )  # [BB, F, F] — one batched MXU matmul
-    f = t.shape[1]
+    )  # [bb, F, F]
     # pack the strict lower triangle with static slices (F is small and
     # static, so this unrolls; no dynamic gather, which pallas disallows)
     offset = 0
-    for i in range(1, f):
-        out_ref[:, offset : offset + i] = gram[:, i, :i].astype(out_ref.dtype)
+    for i in range(1, t.shape[1]):
+        out_ref[:, offset:offset + i] = gram[:, i, :i].astype(out_ref.dtype)
         offset += i
 
 
+def _interaction_forward(slabs, block_batch, interpret):
+    f, d, b = slabs.shape
+    pairs = f * (f - 1) // 2
+    why = supports(d, slabs.dtype)
+    if why:
+        raise ValueError(f"the interaction kernel does not take {why}")
+    # a tile of lanes a grid step; a batch that fills no tile is padded
+    block = -(-min(block_batch or BLOCK_BATCH, b) // LANES) * LANES
+    padded = -(-b // block) * block
+    if padded != b:
+        slabs = jnp.pad(slabs, ((0, 0), (0, 0), (0, padded - b)))
+    out = pl.pallas_call(
+        _interaction_kernel,
+        grid=(padded // block,),
+        in_specs=[pl.BlockSpec((f, d, block), lambda i: (0, 0, i))],
+        out_specs=pl.BlockSpec((block, pairs), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((padded, pairs), slabs.dtype),
+        interpret=pallas_interpret(interpret),
+        name="dlrm_interaction",
+    )(slabs)
+    return out[:b]
+
+
 @partial(jax.custom_vjp, nondiff_argnums=(1, 2))
-def dot_interaction_pallas(
-    stacked: jnp.ndarray, block_batch: int = 128, interpret: bool | None = None
+def interaction_pallas(
+    slabs: jnp.ndarray, block_batch: int | None = None,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
-    """Fused pallas version (1.4-1.5x the XLA path at Criteo scale on v5e).
-    Falls back to interpret mode off-TPU. Differentiable: the backward pass
-    scatters the packed cotangent back into the symmetric Gram gradient."""
-    return _interaction_forward(stacked, block_batch, interpret)
+    """[F, D, B] -> [B, F*(F-1)/2]: the Mosaic kernel, one call a forward
+    pass; interpreted off-TPU. ``block_batch``: samples a grid step, rounded
+    up to whole lane tiles (None: ``BLOCK_BATCH``). Differentiable: the
+    cotangent comes back feature-major too."""
+    return _interaction_forward(slabs, block_batch, interpret)
 
 
-def _interaction_fwd(stacked, block_batch, interpret):
-    return _interaction_forward(stacked, block_batch, interpret), stacked
+def _interaction_fwd(slabs, block_batch, interpret):
+    return _interaction_forward(slabs, block_batch, interpret), slabs
 
 
-def _interaction_bwd(block_batch, interpret, stacked, g):
-    b, f, d = stacked.shape
+def _interaction_bwd(block_batch, interpret, slabs, g):
+    """d(slabs)[i] = sum over j of g[pair(i, j)] * slabs[j], the batch on the
+    lanes throughout: ``g``'s rows once transposed, a pair's row of it
+    broadcast over the ``D`` sublanes of slab ``j``."""
+    f = slabs.shape[0]
     rows, cols = _tril_indices(f)
-    gram_grad = jnp.zeros((b, f, f), g.dtype)
-    gram_grad = gram_grad.at[:, rows, cols].set(g)
-    sym = gram_grad + jnp.swapaxes(gram_grad, 1, 2)  # d(T Tᵀ) is symmetric
-    return (jnp.einsum("bfg,bgd->bfd", sym, stacked),)
+    pair = np.full((f, f), len(rows), np.int32)  # the diagonal: a row of 0
+    pair[rows, cols] = pair[cols, rows] = np.arange(len(rows))
+    gt = jnp.pad(g.T.astype(jnp.float32), ((0, 1), (0, 0)))  # [pairs + 1, B]
+    sym = gt[pair]  # [F, F, B]
+    grad = (sym[:, :, None, :] * slabs[None].astype(jnp.float32)).sum(1)
+    return (grad.astype(slabs.dtype),)
 
 
-dot_interaction_pallas.defvjp(_interaction_fwd, _interaction_bwd)
+interaction_pallas.defvjp(_interaction_fwd, _interaction_bwd)
+
+
+def dot_interaction_pallas(
+    stacked: jnp.ndarray, block_batch: int | None = None,
+    interpret: bool | None = None,
+) -> jnp.ndarray:
+    """:func:`interaction_pallas` for a ``[B, F, D]`` operand."""
+    return interaction_pallas(
+        jnp.transpose(stacked, (1, 2, 0)), block_batch, interpret)
 
 
 def _active_mesh():
@@ -85,72 +178,53 @@ def _active_mesh():
     return mesh if mesh.shape else None
 
 
-def dot_interaction_fused(
-    stacked: jnp.ndarray,
+def interaction_fused(
+    slabs: jnp.ndarray,
     batch_axes: Sequence[str] = ("data", "dp", "batch"),
-    block_batch: int = 128,
+    block_batch: int | None = None,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
-    """The pallas interaction kernel, runnable under MULTI-DEVICE jit.
+    """The kernel on ``[F, D, B]``, runnable under MULTI-DEVICE jit.
 
     Mosaic kernels cannot be auto-partitioned by XLA, so under a multi-device
-    mesh the kernel is wrapped in ``shard_map`` over the batch axes (the
-    op is embarrassingly parallel in B): each device runs the fused kernel on
-    its local [B/dp, F, D] shard and the surrounding jit keeps dp×tp layouts
-    untouched. Single-device (or no active mesh) falls through to the plain
-    pallas call. ``batch_axes`` lists mesh-axis names that may shard B; any
-    other axes see replicated data."""
+    mesh the kernel is wrapped in ``shard_map`` over the batch axes (the op
+    is embarrassingly parallel in B, the operand's LAST axis): each device
+    runs the fused kernel on its local [F, D, B/dp] shard and the surrounding
+    jit keeps dp×tp layouts untouched. Single-device (or no active mesh)
+    falls through to the plain pallas call. ``batch_axes`` lists mesh-axis
+    names that may shard B; any other axes see replicated data. Where the
+    kernel cannot run (:func:`supports`, or several devices and no mesh) this
+    is the einsum."""
     mesh = _active_mesh()
-    if mesh is None:
-        if jax.device_count() > 1:
-            # a multi-device jit with NO mesh context (plain in_shardings
-            # style) would hand the Mosaic kernel to the auto-partitioner,
-            # which raises NotImplementedError — use the einsum path there
-            return dot_interaction(stacked)
-        return dot_interaction_pallas(stacked, block_batch, interpret)
-    if int(np.prod(list(mesh.shape.values()))) == 1:
-        return dot_interaction_pallas(stacked, block_batch, interpret)
+    if supports(slabs.shape[1], slabs.dtype) or (
+            mesh is None and jax.device_count() > 1):
+        # a multi-device jit with NO mesh context (plain in_shardings style)
+        # would hand the Mosaic kernel to the auto-partitioner, which raises
+        # NotImplementedError — use the einsum path there
+        return interaction_xla(slabs)
+    if mesh is None or int(np.prod(list(mesh.shape.values()))) == 1:
+        return interaction_pallas(slabs, block_batch, interpret)
     from jax.sharding import PartitionSpec as P
 
     present = tuple(a for a in batch_axes if mesh.shape.get(a, 1) > 1)
     fn = jax.shard_map(
-        partial(dot_interaction_pallas, block_batch=block_batch, interpret=interpret),
+        partial(interaction_pallas, block_batch=block_batch, interpret=interpret),
         mesh=mesh,
-        in_specs=P(present if present else None, None, None),
+        in_specs=P(None, None, present if present else None),
         out_specs=P(present if present else None, None),
         # the pallas interpreter can't reconcile invariant grid slices with
         # varying operands; numerics are test-validated against the einsum
         check_vma=False,
     )
-    return fn(stacked)
+    return fn(slabs)
 
 
-def _interaction_forward(
-    stacked: jnp.ndarray, block_batch: int = 128, interpret: bool | None = None
+def dot_interaction_fused(
+    stacked: jnp.ndarray,
+    batch_axes: Sequence[str] = ("data", "dp", "batch"),
+    block_batch: int | None = None,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
-    from jax.experimental import pallas as pl
-
-    interpret = pallas_interpret(interpret)
-    b, f, d = stacked.shape
-    out_f = f * (f - 1) // 2
-    block_batch = min(block_batch, b)
-    if b % block_batch:
-        # pad batch so the grid divides evenly (static shapes for the MXU)
-        pad = block_batch - b % block_batch
-        stacked = jnp.concatenate(
-            [stacked, jnp.zeros((pad, f, d), stacked.dtype)], axis=0
-        )
-    padded_b = stacked.shape[0]
-    grid = (padded_b // block_batch,)
-    out = pl.pallas_call(
-        _interaction_kernel,
-        out_shape=jax.ShapeDtypeStruct((padded_b, out_f), stacked.dtype),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_batch, f, d), lambda i: (i, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_batch, out_f), lambda i: (i, 0)),
-        interpret=interpret,
-        name="dlrm_interaction",
-    )(stacked)
-    return out[:b]
+    """:func:`interaction_fused` for a ``[B, F, D]`` operand."""
+    return interaction_fused(
+        jnp.transpose(stacked, (1, 2, 0)), batch_axes, block_batch, interpret)

@@ -197,11 +197,14 @@ def test_fit_through_the_gather_kernel_equals_xlas_gather_fit(
 
 
 # sha256 of the row-path step's lowered text (``_lowered``: tiny DLRM,
-# Adagrad, batch 32) at the parent commit, 315a501: the CPU backend's, and
-# the 8-device mesh's under ``dlrm_sharding_rules()``
+# Adagrad, batch 32) where the gather kernel does not engage: the CPU
+# backend's, and the 8-device mesh's under ``dlrm_sharding_rules()``. Taken
+# at 315a501, before the kernel existed, and again at PR 47, whose model
+# stacks a sample's rows feature-major (``[D, B]`` slabs): the row path's
+# own part of the text is as it was
 PARENT_STEP = {
-    "cpu": "77b97ea8a7c9f4dec1dc806a2f0725d6e81d3018b71ff0d8d36ca37d021e72ba",
-    "mesh": "b69c355edbc03ed0021e9355b3254e1f2bcb6939aef7b0c9adcba9e9782c2222",
+    "cpu": "07ca55fb2f772c2b457d3f72733e7633772b3ff422abf5f353b4120dc79f2fe8",
+    "mesh": "ce23cc2aa7b752c435b0c4d096366558472b3108d613dee8fc77aa1ad807cfa6",
 }
 
 

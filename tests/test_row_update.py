@@ -36,12 +36,12 @@ VOCABS = (5000, 7, 300, 2000, 3)
 ROW_PATHS = (("params", "embedding_0"), ("params", "embedding_3"))
 
 
-def _dlrm(vocabs=VOCABS, num_dense=4):
+def _dlrm(vocabs=VOCABS, num_dense=4, kernel=False):
     from raydp_tpu.models import DLRM
 
     return DLRM(vocab_sizes=tuple(vocabs), num_dense=num_dense, embed_dim=8,
                 bottom_mlp=(16, 8), top_mlp=(16, 8),
-                use_pallas_interaction=False)
+                use_pallas_interaction=kernel)
 
 
 def _batches(n, seed=0):
@@ -85,17 +85,23 @@ def _optimizers():
     }
 
 
-@pytest.mark.parametrize("name", ["adagrad", "sgd"])
-def test_row_step_equals_dense_optax_steps(name):
+@pytest.mark.parametrize("name, kernel", [
+    ("adagrad", False), ("sgd", False), ("adagrad", True)])
+def test_row_step_equals_dense_optax_steps(name, kernel):
     """(1) the estimator's step on the row path against plain optax dense
     steps: parameters and every leaf of the optimizer state, bit for bit
     (XLA:CPU adds a row's repeated gradients in the batch's order on both
-    sides)."""
+    sides). ``kernel``: the rows reach the interaction's Mosaic kernel
+    (interpreted) and their cotangent leaves its backward feature-major, on
+    both sides; under a mesh of one device, because with eight and no mesh
+    the model's fused entry is the einsum."""
     import jax
     import jax.numpy as jnp
     import optax
 
-    module, loss_fn, tx = _dlrm(), _LOSSES["bce"], _optimizers()[name]()
+    from raydp_tpu.parallel import make_mesh
+
+    module, loss_fn, tx = _dlrm(kernel=kernel), _LOSSES["bce"], _optimizers()[name]()
     batches = list(_batches(6))
     params = module.init(jax.random.PRNGKey(0), batches[0][0])
     plan = row_update.plan(module, tx, params, batches[0][0], BATCH)
@@ -112,9 +118,12 @@ def test_row_step_equals_dense_optax_steps(name):
     step = jax.jit(make_train_step(module, loss_fn, tx, plan.paths))
     want = got = (params, tx.init(params))
     total = jnp.zeros(())
-    for x, y in batches:
-        *want, loss = dense(*want, x, y)
-        *got, total = step(*got, total, x, y)
+    with jax.set_mesh(make_mesh({"data": 1}, jax.devices()[:1])):
+        assert ("pallas_call" in str(jax.make_jaxpr(step)(
+            *got, total, *batches[0]))) == kernel
+        for x, y in batches:
+            *want, loss = dense(*want, x, y)
+            *got, total = step(*got, total, x, y)
     assert jax.tree.structure(want) == jax.tree.structure(got)
     for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
         assert a.shape == b.shape and a.dtype == b.dtype
@@ -164,6 +173,16 @@ def test_optimizer_probe_decides_the_fit(session, criteo_df, name, engages):
     gauges = obs.metrics.snapshot()
     for key in ("params", "bytes_skipped"):
         assert gauges[f"estimator.row_update.{key}"]["value"] == stats[key]
+    # what the model says of its interaction (``DLRM.fit_facts``) is on every
+    # compile span of the fit, the FLOPs probe's among them (it still runs),
+    # and its number a gauge
+    compiles = [r["args"] for r in est.last_fit_records_
+                if r["name"] == "estimator.compile"]
+    assert compiles and all(
+        a["interaction_operand"] == "feature_major"
+        and a["interaction_kernel"] == "xla" for a in compiles)
+    assert "flops_probe" in [a.get("what") for a in compiles]
+    assert gauges["model.interaction.row_blocks"]["value"] == 3  # h, c0, c1
     if engages:
         assert stats["paths"] == ["params/embedding_0"] and not stats["reason"]
         assert stats["bytes_skipped"] > 0

@@ -43,9 +43,12 @@ def no_compile_cache():
 def kernels_compile(monkeypatch):
     # what the TPU backend would say of itself (ops/backend.py): a kernel
     # whose ``interpret`` is left to the rule compiles and is not interpreted
+    # (one chip of it: with several devices and no mesh a Mosaic call that
+    # XLA would have to partition falls back, ops/interaction.py)
     from raydp_tpu.ops import backend
 
     monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    monkeypatch.setattr(jax, "device_count", lambda *_: 1)
 
 
 def _instructions(text):
@@ -154,25 +157,23 @@ def _table_results(text, ops, layout=""):
         rf"= f32\[(?:{shapes})\]\{{{layout}[^}}]*\}} (?:{ops})\(", text)
 
 
-def test_dlrm_step_writes_rows_back_with_the_kernel(
-        one_chip, no_compile_cache, kernels_compile):
+def _dlrm_step(one_chip, interaction_kernel):
     """The benchmark's DLRM step (batch 2048, the 26 Criteo-Kaggle tables,
-    Adagrad) with both kernels, against the same step through XLA's gather
-    (PR 28's step) and through XLA's scatter too (PR 25's step, text for
-    text): eight write-back calls (a table and its accumulator each) and
-    three gather calls (the tables XLA's gather would copy) on bitcasts of
-    the tables, no operation whose result is a whole row-path table besides
-    the kernels, no second copy of a table among the temporaries."""
+    Adagrad; under ``kernels_compile`` the plan takes both row kernels), its
+    interaction through the Mosaic kernel or through XLA: ``(params, plan,
+    compiled, forward)``, ``compiled(kernel_paths, gather_paths)`` the step
+    and ``forward()`` the model alone (the evaluation's program), compiled
+    for the described chip."""
     import optax
 
     from raydp_tpu.estimator import row_update
     from raydp_tpu.estimator.jax_estimator import _LOSSES, make_train_step
     from raydp_tpu.models import DLRM
 
-    batch = 2048  # ``kernels_compile``: the plan then takes the kernels
+    batch = 2048
     module = DLRM(vocab_sizes=CRITEO_KAGGLE, num_dense=13, embed_dim=16,
                   bottom_mlp=(512, 256, 64), top_mlp=(512, 256),
-                  use_pallas_interaction=False)
+                  use_pallas_interaction=interaction_kernel)
 
     on_chip = functools.partial(_on_chip, one_chip=one_chip)
     x = on_chip((jax.ShapeDtypeStruct((batch, 13), jnp.float32),
@@ -182,6 +183,30 @@ def test_dlrm_step_writes_rows_back_with_the_kernel(
     tx = optax.adagrad(0.01)
     state = on_chip(jax.eval_shape(tx.init, params))
     plan = row_update.plan(module, tx, params, x, batch)
+
+    def compiled(kernel_paths, gather_paths):
+        step = make_train_step(module, _LOSSES["bce"], tx, plan.paths,
+                               kernel_paths, gather_paths)
+        return jax.jit(step, donate_argnums=(0, 1, 2)).lower(
+            params, state, on_chip(jax.ShapeDtypeStruct((), jnp.float32)),
+            x, y).compile()
+
+    def forward():
+        return jax.jit(module.apply).lower(params, x).compile()
+
+    return params, plan, compiled, forward
+
+
+def test_dlrm_step_writes_rows_back_with_the_kernel(
+        one_chip, no_compile_cache, kernels_compile):
+    """The benchmark's DLRM step (batch 2048, the 26 Criteo-Kaggle tables,
+    Adagrad) with both kernels, against the same step through XLA's gather
+    (PR 28's step) and through XLA's scatter too (PR 25's step, text for
+    text): eight write-back calls (a table and its accumulator each) and
+    three gather calls (the tables XLA's gather would copy) on bitcasts of
+    the tables, no operation whose result is a whole row-path table besides
+    the kernels, no second copy of a table among the temporaries."""
+    params, plan, compiled, _ = _dlrm_step(one_chip, False)
     assert sorted(params["params"][p[1]].shape[0] for p in plan.paths) == sorted(
         ROW_TABLES)
     assert plan.stats()["write_back"] == {
@@ -190,13 +215,6 @@ def test_dlrm_step_writes_rows_back_with_the_kernel(
     assert (read["kernel"], read["xla"]) == (6, 10) and "rows" in read["reason"]
     assert sorted(params["params"][p[1]].shape[0]
                   for p in plan.gather_paths) == sorted(GATHERED_TABLES)
-
-    def compiled(kernel_paths, gather_paths):
-        step = make_train_step(module, _LOSSES["bce"], tx, plan.paths,
-                               kernel_paths, gather_paths)
-        return jax.jit(step, donate_argnums=(0, 1, 2)).lower(
-            params, state, on_chip(jax.ShapeDtypeStruct((), jnp.float32)),
-            x, y).compile()
 
     kernel = compiled(plan.kernel_paths, plan.gather_paths)
     gather, scatter = compiled(plan.kernel_paths, ()), compiled((), ())
@@ -230,6 +248,51 @@ def test_dlrm_step_writes_rows_back_with_the_kernel(
     assert (kernel.memory_analysis().temp_size_in_bytes
             <= gather.memory_analysis().temp_size_in_bytes
             <= scatter.memory_analysis().temp_size_in_bytes)
+
+
+# the top-level ``copy`` and ``transpose`` of a row block in the row-major
+# form, ``f32[2048,16]{1,0}`` (16 columns padded to 128 lanes, 1 MB for 128
+# KB), that the step and the model's forward pass compile to (PR 47): XLA's
+# gather takes and gives row-major blocks only, so a block is turned once on
+# its way to its [16, 2048] slab and its cotangent once on its way back
+ROW_MAJOR_TURNS = {"step": 70, "forward": 26}
+
+
+@pytest.mark.parametrize("program", ["step", "forward"])
+def test_dlrm_row_blocks_reach_the_interaction_feature_major(
+        one_chip, no_compile_cache, kernels_compile, program):
+    """The same step with the interaction's Mosaic kernel, and the model's
+    forward pass alone (the evaluation's program, 26 takes): no instruction
+    builds a ``f32[2048,1,16]`` block in any layout (the parent's step: 243,
+    27 of them ``copy``; a block pads 8- to 128-fold), the operand is
+    ``f32[27,16,2048]``, ONE ``dlrm_interaction`` call whose result is
+    ``f32[2048,351]`` (``kernel.interaction_roofline`` finds it by that), no
+    result ``f32[rows,16]`` of 10,000 rows or more besides the dense tables'
+    (``estimator.table_update_ms`` sums those)."""
+    _, plan, compiled, forward = _dlrm_step(one_chip, True)
+    text = (compiled(plan.kernel_paths, plan.gather_paths)
+            if program == "step" else forward()).as_text()
+    assert not re.findall(r"= f32\[2048,1,16\]", text)
+    assert re.findall(r"= f32\[27,16,2048\]\{2,1,0", text)
+    calls = re.findall(
+        r"%[\w.\-]*dlrm_interaction[\w.\-]* = (\S+?)\{\S* custom-call\(", text)
+    assert calls == ["f32[2048,351]"]
+    assert len(re.findall(r"= f32\[\d+,351\]\S* custom-call\(", text)) == 1
+    turns = re.findall(
+        r"^\s+(?:ROOT )?%[\w.\-]+ = f32\[2048,16\]\{1,0[^}]*\} "
+        r"(?:copy|transpose)\(", text, re.M)
+    assert len(turns) <= ROW_MAJOR_TURNS[program]
+    dense = {f"f32[{v},16]" for v in CRITEO_KAGGLE if v not in ROW_TABLES}
+    assert set(re.findall(r"= (f32\[\d{5,},16\])", text)) - {
+        f"f32[{v},16]" for v in ROW_TABLES} <= dense
+    # (a row-path table's own `f32[V,16]` lines in the step are its
+    # parameter, its bitcasts into the kernels' views and XLA's gathers of
+    # the five large ones: none of them an operation ON a table. The forward
+    # pass alone has no row path: XLA's gather copies the three tables of
+    # 93,145-286,181 rows row-major to read them, in every evaluation batch:
+    # ROADMAP Queue 1 item 1 (f))
+    copied = _table_results(text, "scatter|transpose|copy|copy-done")
+    assert len(copied) == (0 if program == "step" else len(GATHERED_TABLES))
 
 
 # -- the looped LM's gradient at the published widths --------------------------
