@@ -768,7 +768,8 @@ class Head:
                 # noticed by the 50ms monitor cadence — session stop drains
                 # on DEAD state, so observe the SIGKILL promptly off-lock
                 threading.Thread(
-                    target=self._reap_after_kill, args=(actor,), daemon=True
+                    target=self._reap_after_kill, args=(actor, actor.proc),
+                    daemon=True,
                 ).start()
             else:
                 node = self.nodes.get(actor.node_id) if actor.node_id else None
@@ -781,24 +782,23 @@ class Head:
                         self._on_actor_death(actor)
             return True
 
-    def _reap_after_kill(self, actor: "_Actor") -> None:
-        """Wait (bounded) for a just-SIGKILLed local actor to exit, then run
-        the death bookkeeping immediately instead of on the next monitor
-        poll. Racing the monitor is safe: both transition under the lock and
-        skip actors already DEAD."""
+    def _reap_after_kill(self, actor: "_Actor", proc) -> None:
+        """Wait (bounded) for the just-SIGKILLed local process ``proc`` of
+        ``actor`` to exit, then run the death bookkeeping immediately
+        instead of on the next monitor poll. Racing the monitor is safe:
+        both transition under the lock, and this thread reaps THAT process
+        only. Where the monitor saw the death first, the actor is DEAD or
+        holds no process (its respawn's fork is in flight) or a new one:
+        a second death there would charge one kill two restarts and fence
+        out the fork under way."""
         deadline = time.monotonic() + 5.0
         while time.monotonic() < deadline:
-            # snapshot once per iteration: a concurrent respawn can set
-            # actor.proc = None between a check and a .poll() on the bare
-            # attribute, AttributeError-ing this reaper thread
-            proc = actor.proc
-            if proc is None or proc.poll() is not None:
+            if proc.poll() is not None:
                 with self.lock:
-                    proc = actor.proc
                     if (
-                        actor.state != ActorState.DEAD
+                        actor.proc is proc
+                        and actor.state != ActorState.DEAD
                         and not actor.pending_respawn
-                        and (proc is None or proc.poll() is not None)
                     ):
                         self._on_actor_death(actor)
                 return

@@ -17,11 +17,14 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import itertools
 import os
 import tempfile
 import time
 from dataclasses import dataclass
-from typing import Any, Callable, Dict, List, Optional, Sequence, Union
+from typing import (
+    Any, Callable, Dict, List, NamedTuple, Optional, Sequence, Union,
+)
 
 import numpy as np
 
@@ -168,8 +171,8 @@ def _compiled_twin(jitted, *args):
 
 def make_train_step(module, loss_fn, tx, row_paths=(), kernel_paths=(),
                     gather_paths=()):
-    """The one train-step body that the scan runner, the stream runner and
-    the per-step loop wrap: ``(params, opt_state, loss_sum, x, y) ->
+    """The one train-step body that both runners scan over:
+    ``(params, opt_state, loss_sum, x, y) ->
     (params, opt_state, loss_sum + loss)``. ``row_paths``: the parameters
     that are differentiated and updated by the rows the batch read,
     ``kernel_paths`` those of them whose rows the write-back kernel puts
@@ -266,6 +269,27 @@ def _scan_over_batches(step_impl, params, opt_state, xb, yb):
         lambda a: a.sum(axis=0), reports)
 
 
+# the segment runner fences every this many DISPATCHES (segments, not
+# steps): the host then runs at most that many segments, and their uploaded
+# batches, ahead of the device. Synchronisation, not tuning: it costs one
+# pipeline bubble each time it fires, and a fit of fewer dispatches an epoch
+# (the benchmark's streamed cell makes two) never reaches it.
+MAX_DISPATCHES_IN_FLIGHT = 32
+
+
+class _Runner(NamedTuple):
+    """What both runners' builders return, and all ``_fit_once`` knows of a
+    runner. ``run_epoch(params, opt_state, epoch_seed, start_step, save_cb)
+    -> (params, opt_state, loss_sum, steps, train_report)`` trains one epoch
+    from its ``start_step``; ``save_cb(params, opt_state, step)`` writes a
+    mid-epoch checkpoint. ``start(start_epoch, start_step)`` comes once
+    before the first epoch and ``close()`` on any exit from the fit."""
+
+    run_epoch: Callable
+    start: Callable = lambda start_epoch, start_step: None
+    close: Callable = lambda: None
+
+
 class _HostArrays:
     """Staged (features, labels) host arrays; epochs reshuffle indices only.
     ``features`` is one array or a tuple of arrays (mixed-dtype path)."""
@@ -337,9 +361,7 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
         profile_dir: Optional[str] = None,
         resume_from_epoch: Optional[int] = None,
         streaming: bool = False,
-        sync_every_steps: int = 32,
-        scan_epochs: Optional[bool] = None,
-        scan_memory_limit: int = 1 << 30,
+        scan_memory_limit: Optional[int] = 1 << 30,
         save_every_steps: Optional[int] = None,
         stream_scan_steps: int = 32,
         stream_prefetch_segments: int = 3,
@@ -402,16 +424,8 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                 "streaming=True to stream blocks every epoch"
             )
         self.streaming = bool(streaming)
-        # cap the async dispatch queue: drain every N steps, so the host
-        # runs at most N steps (and their uploaded batches) ahead of the
-        # device and the step profiler's compute/sync split has a fence to
-        # read. Costs one pipeline bubble per N steps; whether the cap buys
-        # anything on a directly attached chip is unmeasured (ROADMAP
-        # Queue 3 item 8). 0 disables.
-        self.sync_every_steps = sync_every_steps
-        # scan_epochs, scan_memory_limit, stream_scan_steps: inputs of
-        # _choose_runner, which says what each value selects
-        self.scan_epochs = scan_epochs
+        # scan_memory_limit, stream_scan_steps: inputs of _choose_runner,
+        # which says what each value selects
         self.scan_memory_limit = scan_memory_limit
         # step-cadence checkpointing: every K completed steps write
         # epoch_N_step_K (a long epoch on a pod must not lose everything
@@ -420,7 +434,12 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
         # mid-epoch — batch order is deterministic per (seed, epoch), so the
         # resumed run replays exactly the tail steps.
         self.save_every_steps = save_every_steps
-        self.stream_scan_steps = stream_scan_steps
+        if int(stream_scan_steps) < 1:
+            raise ValueError(
+                f"stream_scan_steps={stream_scan_steps!r}: a segment is one "
+                "batch or more"
+            )
+        self.stream_scan_steps = int(stream_scan_steps)
         # streaming upload pipeline depth: the producer keeps up to this
         # many segments staged-and-uploading ahead of the consumer's scan
         # (device_put is async, so uploads overlap compute). Deeper absorbs
@@ -736,8 +755,6 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
         import optax
         from jax.sharding import NamedSharding, PartitionSpec
 
-        from raydp_tpu.exchange.jax_io import PrefetchingDeviceIterator
-
         mesh = self._resolve_mesh()
         batch_size = self._effective_batch(mesh)
 
@@ -794,9 +811,8 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
         # (session.profile_fit), and the cost model's peak for the live
         # MFU gauge — all resolved once per fit
         recorder = self._step_recorder = _profiler.step_recorder()
-        fit_capture = self._fit_capture = _profiler.armed_capture()
+        self._fit_capture = _profiler.armed_capture()
         self._flops_per_step = None
-        self._fit_step_wall = 0.0
         self._mfu_mark = self._mfu_origin = None
         self._peak_info = _costmodel.device_peak_flops()
 
@@ -895,8 +911,6 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
             row_plan.gather_paths,
         )
 
-        train_step = partial_jit(donate_argnums=donate)(step_impl)
-
         eval_fns = self._make_eval_step(module, loss_fn)
 
         start_epoch = 0
@@ -965,65 +979,22 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
         )
 
         self._history = []
-        first_step_done = False
         # the ExitStack is entered FIRST so its callbacks run LAST: the
-        # streaming pipeline's close (registered below once the runner
-        # exists) must stop/drain/join the whole-fit producer on ANY exit —
-        # a consumer exception abandoning a producer parked on the full
-        # queue would leak the thread and pin its in-flight device segments
-        # (the leaks sanitizer audits exactly this at shutdown)
+        # runner's close must stop/drain/join the segment runner's whole-fit
+        # producer on ANY exit — a consumer exception abandoning a producer
+        # parked on the full queue would leak the thread and pin its
+        # in-flight device segments (the leaks sanitizer audits exactly this
+        # at shutdown)
         with contextlib.ExitStack() as _fit_stack, profile_ctx, jax.set_mesh(mesh):
             runner = self._choose_runner(train_source, batch_size)
-            run_scan_epoch = run_stream_segments = None
-            if runner == "resident_scan":
-                run_scan_epoch = self._build_scan_runner(
-                    train_source, batch_size, mesh, step_impl, donate
-                )
-            elif runner == "segment_scan":
-                run_stream_segments = self._build_stream_runner(
-                    mesh, step_impl, donate, batch_size
-                )
+            build = {
+                "resident_scan": self._build_scan_runner,
+                "segment_scan": self._build_stream_runner,
+            }[runner]
+            run = build(train_source, batch_size, mesh, step_impl, donate)
+            _fit_stack.callback(run.close)
+            run.start(start_epoch, start_step)
             save_steps = self.save_every_steps if self.checkpoint_dir else None
-
-            def save_mid_epoch(params_, opt_state_, epoch_, step_):
-                self._save_checkpoint(params_, epoch_, opt_state_, step=step_)
-
-            if run_stream_segments is not None:
-                # whole-fit streaming pipeline: ONE producer covers every
-                # epoch (epoch N+1's first segment decodes while epoch N's
-                # tail trains); each epoch's host iterator is built lazily
-                # by this plan when the producer reaches it
-                seg_steps = self._stream_segment_steps
-
-                def _stream_epoch_plan(epoch_):
-                    epoch_seed_ = None if not self.shuffle else self.seed + epoch_
-                    epoch_start_ = start_step if epoch_ == start_epoch else 0
-                    coalesced_ = epoch_start_ % seg_steps == 0
-                    base_iter_ = self._epoch_batches(
-                        train_source, batch_size, epoch_seed_,
-                        segment_rows=(
-                            seg_steps * batch_size if coalesced_ else None
-                        ),
-                    )
-                    host_iter_ = base_iter_
-                    if epoch_start_:
-                        import itertools
-
-                        skip = (
-                            epoch_start_ // seg_steps
-                            if coalesced_
-                            else epoch_start_
-                        )
-                        host_iter_ = itertools.islice(host_iter_, skip, None)
-                    # base_iter_ rides along unwrapped: the executor-decode
-                    # evidence flag lives on the block-stream iterator, which
-                    # an islice wrapper (mid-epoch resume) would hide
-                    return host_iter_, coalesced_, base_iter_
-
-                run_stream_segments.start(
-                    _stream_epoch_plan, range(start_epoch, self.num_epochs)
-                )
-                _fit_stack.callback(run_stream_segments.close)
 
             self._mark_mfu_origin()
             for epoch in range(start_epoch, self.num_epochs):
@@ -1031,7 +1002,8 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                 epoch_start_step = start_step if epoch == start_epoch else 0
                 phase_before = recorder.totals()
                 save_cb = (
-                    (lambda p, o, s, _e=epoch: save_mid_epoch(p, o, _e, s))
+                    (lambda p, o, s, _e=epoch: self._save_checkpoint(
+                        p, _e, o, step=s))
                     if save_steps
                     else None
                 )
@@ -1041,148 +1013,14 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                     "estimator.epoch", epoch=epoch,
                     resumed_at=epoch_start_step,
                 ) as epoch_span:
-                    # what the epoch's training steps reported, summed inside
-                    # the epoch program (the scan runners; the per-step loop
-                    # reports nothing)
-                    train_report = {}
-                    if run_scan_epoch is not None:
-                        (
-                            params, opt_state, loss_sum, steps, train_report,
-                        ) = run_scan_epoch(
-                            params, opt_state, epoch_seed,
-                            start_step=epoch_start_step, save_cb=save_cb,
-                        )
-                    elif run_stream_segments is not None:
-                        # consume this epoch's segments off the whole-fit
-                        # pipeline (the producer, started before the loop,
-                        # builds each epoch's host iterator itself —
-                        # coalesced whole-segment slices except on a
-                        # mid-segment resume)
-                        (
-                            params, opt_state, loss_sum, steps, train_report,
-                        ) = run_stream_segments(
-                            params, opt_state, epoch_start_step,
-                            save_cb=save_cb,
-                        )
-                    else:
-                        host_iter = self._epoch_batches(
-                            train_source, batch_size, epoch_seed
-                        )
-                        if epoch_start_step:
-                            # deterministic order per (seed, epoch): dropping
-                            # the first K batches replays exactly the un-run
-                            # tail
-                            import itertools
-
-                            host_iter = itertools.islice(
-                                host_iter, epoch_start_step, None
-                            )
-                        train_iter = PrefetchingDeviceIterator(
-                            host_iter, mesh, shard_direct=self.shard_direct
-                        )
-                        loss_sum = jnp.zeros((), jnp.float32)
-                        steps = epoch_start_step
-                        pending_save = None
-                        # explicit next() so the step profiler can split
-                        # each iteration into its phases: ingest (host
-                        # slice + queue wait), h2d (device_put dispatch,
-                        # read from the iterator's own split), dispatch
-                        # (the host's time inside the train_step call; no
-                        # span here: one per step would cost more than the
-                        # call), sync (the bounded fence)
-                        profiled = recorder.enabled
-                        t_loop0 = time.perf_counter()
-                        while True:
-                            h2d0 = train_iter.h2d_s
-                            t_iter = time.perf_counter()
-                            try:
-                                x, y = next(train_iter)
-                            except StopIteration:  # raydp-lint: disable=swallowed-exceptions (explicit next(): epoch end is the loop's normal exit)
-                                break
-                            if profiled:
-                                h2d_d = train_iter.h2d_s - h2d0
-                                recorder.note("h2d", h2d_d)
-                                recorder.note(
-                                    "ingest",
-                                    (time.perf_counter() - t_iter) - h2d_d,
-                                )
-                            if pending_save is not None:
-                                # DEFERRED one step: a save that would
-                                # coincide with the epoch's final step is
-                                # dropped (the epoch-complete epoch_N
-                                # supersedes it) — so a step checkpoint
-                                # always has tail steps to replay
-                                save_mid_epoch(params, opt_state, epoch, pending_save)
-                                pending_save = None
-                            t_c = time.perf_counter()
-                            if not first_step_done:
-                                # the first call compiles (cold TPU compiles
-                                # take tens of seconds); record it so callers
-                                # can report steady-state throughput
-                                # separately
-                                if fit_capture is not None:
-                                    fit_capture.begin_steps()
-                                with self._compile_span(
-                                    "first_step"
-                                ) as compiled_as:
-                                    # lives as long as the fit's frame
-                                    first_step_program = _compiled_twin(
-                                        train_step, params, opt_state,
-                                        loss_sum, x, y,
-                                    )
-                                    compiled_as(first_step_program)
-                                    params, opt_state, loss_sum = train_step(
-                                        params, opt_state, loss_sum, x, y
-                                    )
-                                    jax.block_until_ready(loss_sum)
-                                first_step_done = True
-                                # XLA's own flops count for the live MFU
-                                # gauge: one extra lower()+compile(), served
-                                # from the (persistent) compilation cache
-                                # the first dispatch just filled
-                                if not self._flops_per_step:
-                                    with self._compile_span("flops_probe"):
-                                        self._flops_per_step = (
-                                            _costmodel.step_flops_from_jitted(
-                                                train_step, params, opt_state,
-                                                loss_sum, x, y,
-                                            )
-                                        )
-                                # the compile step is NOT a steady-state
-                                # step: it counts as dispatched and
-                                # completed, and stays (with the flops
-                                # lookup) out of the dispatch histogram, the
-                                # live MFU's window and the step-wall clock
-                                # the phases are gated against —
-                                # compile_seconds_ carries it
-                                recorder.dispatched(None, loss_sum)
-                                self._mark_mfu_origin()
-                                t_loop0 += time.perf_counter() - t_c
-                            else:
-                                params, opt_state, loss_sum = train_step(
-                                    params, opt_state, loss_sum, x, y
-                                )
-                                recorder.dispatched(
-                                    time.perf_counter() - t_c, loss_sum
-                                )
-                            if fit_capture is not None:
-                                fit_capture.note_step()
-                            steps += 1
-                            if save_steps and steps % save_steps == 0:
-                                pending_save = steps
-                            if (
-                                self.sync_every_steps
-                                and steps % self.sync_every_steps == 0
-                            ):
-                                # bounded pipeline bubble; see __init__
-                                t_s = time.perf_counter()
-                                jax.block_until_ready(loss_sum)
-                                if profiled:
-                                    recorder.note(
-                                        "sync", time.perf_counter() - t_s
-                                    )
-                        self._fit_step_wall += time.perf_counter() - t_loop0
-                        steps -= epoch_start_step
+                    # train_report: what the epoch's training steps
+                    # reported, summed inside the epoch program
+                    (
+                        params, opt_state, loss_sum, steps, train_report,
+                    ) = run.run_epoch(
+                        params, opt_state, epoch_seed, epoch_start_step,
+                        save_cb,
+                    )
                     epoch_span.set(steps=steps)
                     phase_delta = {
                         k: v - phase_before.get(k, 0.0)
@@ -1284,9 +1122,6 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
             "step_phase_seconds": {
                 k: round(v, 6) for k, v in phase_totals.items()
             },
-            "step_wall_s": (
-                round(self._fit_step_wall, 6) if self._fit_step_wall else None
-            ),
             "flops_per_step": flops_step,
             "model_flops_per_sec": mfps,
             "mfu": mfu_val,
@@ -1442,41 +1277,30 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
             self._flops_per_step = None
 
     def _choose_runner(self, train_source, batch_size) -> str:
-        """Which of the three training runners this fit takes, from what it
+        """Which of the two training runners this fit takes, from what it
         can observe before any device work — the one place that decides:
 
         - ``resident_scan``: an epoch is one ``lax.scan`` over the staged
           arrays (device-resident on one device). Staged data of a batch or
-          more that fits ``scan_memory_limit`` (``scan_epochs=True`` waives
-          the limit);
+          more that fits ``scan_memory_limit`` (None: no limit);
         - ``segment_scan``: scans of ``stream_scan_steps`` batches fed by
-          the producer thread: a streamed fit, or staged data over the limit;
-        - ``per_step``: one dispatch a batch. ``scan_epochs=False`` (staged)
-          and ``stream_scan_steps=0`` ask for it, and a fit with no label
-          column that cannot take ``resident_scan`` falls to it: the segment
-          runner stacks labels."""
+          the producer thread: a streamed fit, or staged data over the
+          limit (or of less than a batch: an epoch of no steps)."""
         if (
             not self.streaming
-            and isinstance(train_source, _HostArrays)
-            and self.scan_epochs is not False
             and len(_f0(train_source.features)) >= batch_size
             and (
-                self.scan_epochs is True
+                self.scan_memory_limit is None
                 or _f_nbytes(train_source.features)
                 + _lnbytes(train_source.labels)
                 <= self.scan_memory_limit
             )
         ):
             return "resident_scan"
-        if (
-            self.stream_scan_steps > 0
-            and self.label_column is not None
-            and (self.streaming or self.scan_epochs is not False)
-        ):
-            return "segment_scan"
-        return "per_step"
+        return "segment_scan"
 
-    def _build_stream_runner(self, mesh, step_impl, donate, batch_size=None):
+    def _build_stream_runner(self, train_source, batch_size, mesh, step_impl,
+                             donate):
         """The ``segment_scan`` runner: ``stream_scan_steps`` host batches
         as one [S, B, ...] super-batch, uploaded once and driven by ONE
         jitted ``lax.scan`` — O(segment) host memory. With
@@ -1514,9 +1338,6 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
             seg = min(seg, save_every)
             while save_every % seg:
                 seg -= 1
-        # callers build the epoch's host iterator at segment granularity
-        # from this (the coalesced fast path)
-        self._stream_segment_steps = seg
         compiled: Dict[int, Any] = {}
         # compute observatory: the per-fit step-phase recorder + armed
         # capture window (set by _fit_once before this builder runs);
@@ -1564,7 +1385,7 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
             "executor_decode": False,
         }
 
-        def _produce_fit(epoch_plan, epochs, out_q: "queue.Queue", stop):
+        def _produce_fit(start_epoch, start_step, out_q: "queue.Queue", stop):
             """THE producer thread — one per fit, streaming every epoch
             back to back: shape each segment, START its device upload, and
             at an epoch boundary roll straight into the next epoch's blocks
@@ -1576,10 +1397,8 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
             (depth = stream_prefetch_segments) applies backpressure so only
             that many segments' worth of host/device memory is in flight;
             ``stop`` lets a failing consumer unblock a producer parked on
-            the full queue. ``epoch_plan(epoch)`` returns that epoch's
-            ``(host_iter, coalesced, block_iter)`` (block_iter = the
-            unwrapped block-stream iterator carrying the executor-decode
-            evidence flag) — coalesced items are whole-segment slices
+            the full queue. ``epoch_plan`` gives each epoch's ``(host_iter,
+            coalesced, block_iter)`` — coalesced items are whole-segment slices
             (reshaped zero-copy), per-batch items are stacked (mid-segment
             resume only). The host iterator is itself prefetched one
             segment deep (``iter_prefetch``), so segment k+1 DECODES while
@@ -1615,7 +1434,7 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                 return False
 
             def _upload(hx, hy):
-                nbytes = _f_nbytes(hx) + hy.nbytes
+                nbytes = _f_nbytes(hx) + _lnbytes(hy)
                 stats["bytes_uploaded"] += nbytes
                 stats["segments"] += 1
                 obs.metrics.counter("estimator.stream.bytes_uploaded").inc(
@@ -1626,49 +1445,49 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                     dx, dy = uploader.upload(hx, hy)
                 # producer-side H2D dispatch wall (one segment's staging
                 # copy + device_put dispatch), normalized per-step by the
-                # segment's REAL batch count (hy is stacked [S, B] on both
-                # producer paths — the tail segment is shorter than seg); a
-                # lost cross-thread race costs one sample, like every other
-                # lock-free instrument
+                # segment's REAL batch count (hx is stacked [S, B, ...] on
+                # both producer paths — the tail segment is shorter than
+                # seg); a lost cross-thread race costs one sample, like every
+                # other lock-free instrument
                 recorder.note(
-                    "h2d", up_span.duration, steps=max(1, hy.shape[0])
+                    "h2d", up_span.duration, steps=max(1, _f0(hx).shape[0])
                 )
                 stats["staging_copies"] = uploader.staging_copies
                 return dx, dy
 
+            def _upload_stacked(xs, ys):
+                return _upload(
+                    _f_stack(xs), None if ys[0] is None else np.stack(ys)
+                )
+
             try:
-                for epoch_ in epochs:
+                for epoch_ in range(start_epoch, self.num_epochs):
                     if stop.is_set():
                         return
-                    host_iter, coalesced, block_iter = epoch_plan(epoch_)
+                    host_iter, coalesced, block_iter = epoch_plan(
+                        epoch_, start_epoch, start_step
+                    )
                     if coalesced:
                         from raydp_tpu.exchange.jax_io import coalesce_segment
 
                         for x, y in iter_prefetch(host_iter, depth=1):
-                            hx, hy, k = coalesce_segment(
-                                x, np.asarray(y), batch_size
-                            )
+                            hx, hy, k = coalesce_segment(x, y, batch_size)
                             if k == 0:
                                 continue  # sub-batch tail: drop_last semantics
                             if not _emit(_upload(hx, hy)):
                                 return
                     else:
                         xs: List[Any] = []
-                        ys: List[np.ndarray] = []
+                        ys: List[Optional[np.ndarray]] = []
                         for x, y in iter_prefetch(host_iter, depth=1):
                             xs.append(_fmap(np.asarray, x))
-                            ys.append(np.asarray(y))
+                            ys.append(_lmap(np.asarray, y))
                             if len(xs) == seg:
-                                if not _emit(
-                                    _upload(_f_stack(xs), np.stack(ys))
-                                ):
+                                if not _emit(_upload_stacked(xs, ys)):
                                     return
                                 xs, ys = [], []
-                        if xs:
-                            if not _emit(
-                                _upload(_f_stack(xs), np.stack(ys))
-                            ):
-                                return
+                        if xs and not _emit(_upload_stacked(xs, ys)):
+                            return
                     stats["executor_decode"] = stats["executor_decode"] or bool(
                         getattr(block_iter, "executor_decode_active", False)
                     )
@@ -1681,8 +1500,32 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
         # once by _fit_once before the epoch loop and closed in its finally
         pipe: Dict[str, Any] = {"q": None, "stop": None, "thread": None}
 
-        def start(epoch_plan, epochs):
-            """Spawn the whole-fit producer (idempotent; one per fit)."""
+        def epoch_plan(epoch, start_epoch, start_step):
+            """``(host_iter, coalesced, block_iter)`` of one epoch, built by
+            the producer when it reaches that epoch: whole-segment slices,
+            except on a resume from the middle of a segment, which feeds
+            batch by batch."""
+            epoch_seed = None if not self.shuffle else self.seed + epoch
+            first = start_step if epoch == start_epoch else 0
+            coalesced = first % seg == 0
+            block_iter = self._epoch_batches(
+                train_source, batch_size, epoch_seed,
+                segment_rows=seg * batch_size if coalesced else None,
+            )
+            # deterministic order per (seed, epoch): dropping what the
+            # checkpoint had consumed replays exactly the un-run tail.
+            # block_iter rides along unwrapped: the executor-decode evidence
+            # flag lives on the block-stream iterator, which an islice
+            # wrapper would hide
+            skip = first // seg if coalesced else first
+            host_iter = (
+                itertools.islice(block_iter, skip, None) if skip else block_iter
+            )
+            return host_iter, coalesced, block_iter
+
+        def start(start_epoch, start_step):
+            """Spawn the whole-fit producer (idempotent; one per fit): ONE
+            producer covers every epoch from the resumed one on."""
             if pipe["thread"] is not None:
                 return
             pipe["q"] = queue.Queue(maxsize=self.stream_prefetch_segments)
@@ -1697,7 +1540,7 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
 
             pipe["thread"] = threading.Thread(
                 target=_adopted,
-                args=(epoch_plan, list(epochs), pipe["q"], pipe["stop"]),
+                args=(start_epoch, start_step, pipe["q"], pipe["stop"]),
                 daemon=True,
             )
             pipe["thread"].start()
@@ -1718,11 +1561,13 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
             thread.join(timeout=10)
             pipe["thread"] = None
 
-        def run(params, opt_state, start_step, save_cb=None):
+        def run_epoch(params, opt_state, epoch_seed, start_step, save_cb):
+            """Consume one epoch's segments off the whole-fit pipeline. The
+            producer seeds each epoch itself (``epoch_plan``): epochs are
+            consumed strictly in production order."""
+            del epoch_seed
             if pipe["thread"] is None:
-                raise RuntimeError(
-                    "stream pipeline not started (run.start was not called)"
-                )
+                raise RuntimeError("stream pipeline not started")
             done = start_step
             loss_total = jnp.zeros((), jnp.float32)
             report_total = {}
@@ -1772,7 +1617,12 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                             ),
                             xb,
                         ),
-                        jax.ShapeDtypeStruct(yb.shape[1:], yb.dtype),
+                        _lmap(
+                            lambda a: jax.ShapeDtypeStruct(
+                                a.shape[1:], a.dtype
+                            ),
+                            yb,
+                        ),
                     )
                 if fit_capture is not None:
                     fit_capture.begin_steps()
@@ -1787,13 +1637,7 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                 if save_every is not None and done % save_every == 0:
                     pending_save = done
                 dispatches += 1
-                if (
-                    self.sync_every_steps
-                    and dispatches % self.sync_every_steps == 0
-                ):
-                    # cap the async dispatch queue (the per-step loop's
-                    # sync_every_steps, counted in DISPATCHES here; see
-                    # __init__)
+                if dispatches % MAX_DISPATCHES_IN_FLIGHT == 0:
                     t_s = time.perf_counter()
                     jax.block_until_ready(loss_total)
                     recorder.note("sync", time.perf_counter() - t_s)
@@ -1801,9 +1645,7 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                 params, opt_state, loss_total, done - start_step, report_total
             )
 
-        run.start = start
-        run.close = close
-        return run
+        return _Runner(run_epoch, start, close)
 
     def _build_scan_runner(self, train_source, batch_size, mesh, step_impl, donate):
         """The ``resident_scan`` runner: whole-epoch training as ONE jitted
@@ -1813,8 +1655,8 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
           epoch ships only a permutation vector and gathers shuffled batches
           device-side (H2D of the data happens once per fit);
         - multi-device / multi-process: host-shuffles, reshapes to
-          [steps, batch, F] and uploads once per epoch (same H2D volume as the
-          per-step path, but a single dispatch), sharded P(None, "data", ...).
+          [steps, batch, F] and uploads once per epoch, sharded
+          P(None, "data", ...).
 
         Compilation is AOT (``lower().compile()``) so ``compile_seconds_``
         records the real compile cost rather than folding a whole epoch's
@@ -1985,8 +1827,8 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                     fit_capture.note_step(length)
                 return out
 
-        def run_epoch(params, opt_state, seed, start_step=0, save_cb=None):
-            order = _shuffled(n, seed)[:n_used]
+        def run_epoch(params, opt_state, epoch_seed, start_step, save_cb):
+            order = _shuffled(n, epoch_seed)[:n_used]
             # the common one-segment epoch must not pay an extra scalar-add
             # dispatch per epoch
             loss_total = None
@@ -2012,7 +1854,7 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                 report_total,
             )
 
-        return run_epoch
+        return _Runner(run_epoch)
 
     def _epoch_batches(self, source, batch_size, seed, shuffle=None,
                        segment_rows=None):
@@ -2143,11 +1985,10 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
 
         scannable = (
             isinstance(source, _HostArrays)
-            and self.scan_epochs is not False
             and jax.process_count() == 1
             and _mesh_device_count(mesh) == 1
             and (
-                self.scan_epochs is True
+                self.scan_memory_limit is None
                 or _f_nbytes(source.features) + _lnbytes(source.labels)
                 <= self.scan_memory_limit
             )

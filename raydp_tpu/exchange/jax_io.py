@@ -98,31 +98,21 @@ class PrefetchingDeviceIterator:
         self._exhausted = False
         # resolved ONCE: __next__ is the per-step hot path
         self._input_wait = metrics.counter("estimator.input_wait_s")
-        # cumulative host-iterator vs device-upload split of the refill
-        # time — the step profiler (obs/profiler.py) reads per-step deltas
-        # to decompose the train loop's input wait into ingest vs H2D
-        self.host_s = 0.0
-        self.h2d_s = 0.0
         self._fill()
 
     def _fill(self):
         while not self._exhausted and len(self._pending) < self._depth:
-            t0 = _perf_counter()
             try:
                 batch = next(self._host_iter)
             except StopIteration:
                 self._exhausted = True
-                self.host_s += _perf_counter() - t0
                 return
-            t1 = _perf_counter()
-            self.host_s += t1 - t0
             self._pending.append(
                 device_put_batch(
                     batch, self._mesh, self._axis,
                     shard_direct=self._shard_direct,
                 )
             )
-            self.h2d_s += _perf_counter() - t1
 
     def __iter__(self):
         return self

@@ -146,19 +146,6 @@ def step_flops_abstract(fn: Any, *args) -> Optional[float]:
         return None
 
 
-def step_flops_from_jitted(jitted: Any, *args) -> Optional[float]:
-    """FLOPs of one call of a jitted function at ``args``'s shapes, via
-    ``lower().compile().cost_analysis()`` — jax caches the compile, so on
-    an already-dispatched jit this costs one trace, not one compile."""
-    lower = getattr(jitted, "lower", None)
-    if lower is None:
-        return None
-    try:
-        return step_flops_from_compiled(lower(*args).compile())
-    except Exception:  # raydp-lint: disable=swallowed-exceptions (an unloweable wrapper degrades to an unknown flops count, not a failed fit)
-        return None
-
-
 def mfu(model_flops_per_sec: Optional[float],
         peak_flops: Optional[float]) -> Optional[float]:
     """Model FLOPs utilization; None when either side is unknown."""

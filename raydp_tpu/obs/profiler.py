@@ -134,12 +134,11 @@ class StepPhaseRecorder:
     steps the device has finished.
 
     ``note(phase, seconds, steps)`` charges ``seconds`` of wall time to a
-    phase across ``steps`` train steps: the per-step loop calls it once per
-    step, the segment-scanned paths once per segment with ``steps=S`` (the
-    histogram then records the per-step average for that segment — the
-    honest granularity when S steps ride one dispatch). Instruments are
-    resolved ONCE (the per-step hot path is a float add + a lock-free
-    histogram observe).
+    phase across ``steps`` train steps: the runners call it once per
+    segment with ``steps=S`` (the histogram then records the per-step
+    average for that segment — the honest granularity when S steps ride one
+    dispatch). Instruments are resolved ONCE (a note is a float add + a
+    lock-free histogram observe).
 
     ``dispatched(seconds, handle, steps)`` follows every compiled call: the
     host's ``seconds`` inside the call go to phase ``dispatch`` (observed
@@ -201,13 +200,11 @@ class StepPhaseRecorder:
             self.drained = True
             self.poll()
 
-    def dispatched(self, seconds: Optional[float], handle: Any,
+    def dispatched(self, seconds: float, handle: Any,
                    steps: int = 1) -> None:
         """A compiled call returned ``handle`` after ``seconds`` on the
-        host (None: a compiling first call, counted but kept out of the
-        steady-state phase)."""
-        if seconds is not None:
-            self.note("dispatch", seconds, steps)
+        host."""
+        self.note("dispatch", seconds, steps)
         self.drained = False
         restart = self._restart
         if restart is not None:

@@ -693,7 +693,7 @@ def fused_vmem_bytes(t: int, head_dim: int, block: int, itemsize: int) -> int:
     for a described v5e the call needed 2.5 of them beside the rest at bf16
     1024-row tiles (16.6 MB + dq) and 6.3 at float32 512-row ones (11.3 MB
     + dq), and what is asked for and not used costs nothing
-    (``tests/test_tpu_compile`` compiles the cells' shapes)."""
+    (``tests/test_tpu_compile_kernels.py`` compiles the cells' shapes)."""
     row = block * _lanes(head_dim)
     return (dq_resident_bytes(t, head_dim) + 2 * row * 4
             + 2 * (7 * row * itemsize + 2 * block * 128 * 4)

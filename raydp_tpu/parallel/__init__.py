@@ -17,7 +17,6 @@ from raydp_tpu.parallel.ring_attention import (
     ring_attention_sharded,
     ulysses_attention,
 )
-from raydp_tpu.parallel.moe import moe_apply, moe_sharded
 from raydp_tpu.parallel.pipeline import pipeline_apply, pipeline_sharded
 from raydp_tpu.parallel.sharding import shard_params_by_rules, sharding_rules_fn
 
@@ -25,8 +24,6 @@ __all__ = [
     "DataParallelPartitioner",
     "NullPartitioner",
     "Partitioner",
-    "moe_apply",
-    "moe_sharded",
     "pipeline_apply",
     "pipeline_sharded",
     "data_parallel_mesh",

@@ -112,13 +112,20 @@ def test_service_crash_restart_keeps_blocks_readable(session):
     ds = _materialized(session, rows=6_000)
     before = _reexecuted()
     svc = session.block_service
+    restarts = svc._record().restarts_used
     svc.kill(no_restart=False)  # crash: the head restarts it
+    # wait for the restart as an EVENT (the head's count of this actor's
+    # restarts rises), then for ALIVE: the state alone also reads ALIVE
+    # before the kill has registered
     deadline = time.monotonic() + 30
     while time.monotonic() < deadline:
-        if svc.state() == ActorState.ALIVE:
+        record = svc._record()
+        if (record.restarts_used > restarts
+                and record.state == ActorState.ALIVE):
             break
         time.sleep(0.1)
-    assert svc.state() == ActorState.ALIVE
+    assert record.restarts_used == restarts + 1
+    assert record.state == ActorState.ALIVE
     assert ds.to_arrow().num_rows == 6_000
     assert _reexecuted() - before == 0
 

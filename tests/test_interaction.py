@@ -1,7 +1,7 @@
 """The DLRM interaction on its feature-major operand (ops/interaction.py): a
 sample's ``F`` vectors as ``[F, D, B]``, the batch on the lanes. The Mosaic
 kernel runs interpreted here; what it compiles to for a chip is
-tests/test_tpu_compile.py's."""
+tests/test_tpu_compile_dlrm.py's."""
 
 import numpy as np
 import pytest

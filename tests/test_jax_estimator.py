@@ -46,6 +46,79 @@ def _mlp():
     return MLP()
 
 
+def _autoencoder():
+    """A model whose loss is its own and reads no label column."""
+    import flax.linen as nn
+    import jax.numpy as jnp
+
+    class AutoEncoder(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            return nn.Dense(x.shape[-1])(nn.tanh(nn.Dense(8)(x)))
+
+        def loss(self, x, y=None):
+            return jnp.mean((self(x) - x) ** 2)
+
+    return AutoEncoder()
+
+
+def _reference_fit(module, tx, loss, seed, epochs):
+    """What the scan runners are held to: a plain loop that calls
+    ``make_train_step``'s step once a batch. ``epochs``: an iterable per
+    epoch of host ``(x, y)`` batches in the order the fit under test takes
+    them. Returns (each epoch's mean loss, the final params)."""
+    import jax
+    import jax.numpy as jnp
+
+    from raydp_tpu.estimator.jax_estimator import (
+        MODEL_LOSS, _LOSSES, make_train_step)
+
+    step = jax.jit(make_train_step(module, _LOSSES[loss], tx))
+    params, opt_state, losses = None, None, []
+    for batches in epochs:
+        loss_sum, steps = jnp.zeros((), jnp.float32), 0
+        for x, y in batches:
+            if params is None:
+                args = (x, None) if loss == MODEL_LOSS else (x,)
+                params = module.init(
+                    jax.random.PRNGKey(seed), *args,
+                    **({"method": "loss"} if loss == MODEL_LOSS else {}))
+                opt_state = tx.init(params)
+            params, opt_state, loss_sum = step(params, opt_state, loss_sum, x, y)
+            steps += 1
+        losses.append(float(loss_sum) / steps)
+    return losses, params
+
+
+def _staged_epochs(est, x, y, num_epochs):
+    """A staged fit's batches: ``epoch_order``'s rows, batch after batch."""
+    b = est.batch_size
+    for epoch in range(num_epochs):
+        order = est.epoch_order(epoch, len(x))[: len(x) // b * b]
+        yield [
+            (x[idx], None if y is None else y[idx])
+            for idx in order.reshape(-1, b)
+        ]
+
+
+def _streamed_epochs(est, ds, num_epochs):
+    """A streamed fit's batches: the Dataset's own block stream, seeded the
+    way the estimator seeds an epoch."""
+    for epoch in range(num_epochs):
+        yield ds.iter_batches(
+            est.batch_size, est.feature_columns, est.label_column,
+            shuffle=est.shuffle, seed=est.seed + epoch, drop_last=True,
+            streaming=True,
+        )
+
+
+def _assert_same_params(a, b, atol):
+    import jax
+
+    for la, lb in zip(jax.tree.leaves(a), jax.tree.leaves(b), strict=True):
+        np.testing.assert_allclose(np.asarray(la), np.asarray(lb), atol=atol)
+
+
 @pytest.mark.parametrize("use_fs_directory", [False, True])
 def test_fit_on_etl_loss_decreases(session, linear_df, use_fs_directory):
     train_df, eval_df = linear_df.random_split([0.8, 0.2], seed=1)
@@ -608,9 +681,9 @@ def test_retry_resumes_midepoch_from_step_checkpoint(session):
 
 def test_stream_segments_match_per_step(session):
     """Segment-scanned streaming (stream_scan_steps) trains identically to
-    the per-step loop — with far fewer dispatches — including when step
-    checkpoints snap the segment length to the save cadence."""
-    import jax
+    one call of the step a batch — with far fewer dispatches — including
+    when step checkpoints snap the segment length to the save cadence."""
+    import optax
 
     ds = _block_dataset(n=3000, seed=5)
     common = dict(
@@ -618,15 +691,12 @@ def test_stream_segments_match_per_step(session):
         label_column="z", batch_size=64, num_epochs=2,
         learning_rate=1e-2, seed=1, streaming=True,
     )
-    ref = JaxEstimator(stream_scan_steps=0, **common)
-    ref.fit(ds)
     seg = JaxEstimator(stream_scan_steps=7, **common)
     seg.fit(ds)
-    for a, b in zip(
-        jax.tree.leaves(ref.get_model().params),
-        jax.tree.leaves(seg.get_model().params),
-    ):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
+    assert seg.fit_stats_["runner"] == "segment_scan"
+    _, ref_params = _reference_fit(
+        _mlp(), optax.adam(1e-2), "mse", 1, _streamed_epochs(seg, ds, 2))
+    _assert_same_params(ref_params, seg.get_model().params, atol=1e-5)
 
     # step checkpoints along segment boundaries, resumable mid-epoch
     ckpt = tempfile.mkdtemp()
@@ -650,11 +720,103 @@ def test_stream_segments_match_per_step(session):
         resume_from_epoch=(1, 20), **common,
     )
     resumed.fit(ds)
-    for a, b in zip(
-        jax.tree.leaves(ref.get_model().params),
-        jax.tree.leaves(resumed.get_model().params),
-    ):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
+    _assert_same_params(ref_params, resumed.get_model().params, atol=1e-5)
+
+
+def _no_label_fit(how, x, **kw):
+    """A ``label_column=None`` fit of 512 rows x 4 that cannot take the
+    resident scan: staged over the limit, or streamed block by block.
+    Returns (the estimator, the epochs of batches it takes)."""
+    import pyarrow as pa
+
+    est = JaxEstimator(
+        model=_autoencoder(), loss="model", optimizer="adam",
+        feature_columns=list("abcd"), label_column=None, batch_size=32,
+        num_epochs=2, learning_rate=1e-2, seed=3,
+        streaming=how == "streamed_no_label",
+        **({} if how == "streamed_no_label" else {"scan_memory_limit": 1024}),
+        **{"stream_scan_steps": 5, **kw},
+    )
+    if how == "streamed_no_label":
+        from raydp_tpu.etl.tasks import write_table_block
+        from raydp_tpu.exchange.dataset import Dataset
+
+        blocks = [
+            write_table_block(pa.table(dict(zip("abcd", part.T))))
+            for part in np.split(x, 4)
+        ]
+        ds = Dataset(
+            [ref for ref, _ in blocks],
+            pa.schema([(c, pa.float32()) for c in "abcd"]),
+            [cnt for _, cnt in blocks],
+        )
+        return est, ds, lambda: _streamed_epochs(est, ds, 2)
+    return est, _ArraysDS(x, None), lambda: _staged_epochs(est, x, None, 2)
+
+
+@pytest.mark.parametrize("save_every_steps", [None, 4], ids=["plain", "saving"])
+@pytest.mark.parametrize("how", ["segments_no_label", "streamed_no_label"])
+def test_no_label_fit_runs_segments(session, how, save_every_steps):
+    """A fit with no label column that cannot take the resident scan runs
+    5-step segments (4-step ones under ``save_every_steps=4``), and trains
+    to what one call of the step a batch gives."""
+    import optax
+
+    x = np.random.default_rng(11).random((512, 4)).astype(np.float32)
+    kw = {}
+    if save_every_steps:
+        kw = dict(save_every_steps=4, checkpoint_dir=tempfile.mkdtemp())
+    est, ds, epochs = _no_label_fit(how, x, **kw)
+    losses = [r["train_loss"] for r in est.fit(ds)]
+    assert est.fit_stats_["runner"] == "segment_scan"
+    # 16 steps an epoch: 5+5+5+1, or four of 4
+    assert est.stream_stats_["segments"] == 2 * 4
+    assert est.fit_stats_["flops_per_step"] > 0
+    ref_losses, ref_params = _reference_fit(
+        _autoencoder(), optax.adam(1e-2), "model", 3, epochs())
+    np.testing.assert_allclose(losses, ref_losses, rtol=1e-4)
+    _assert_same_params(ref_params, est.get_model().params, atol=1e-5)
+
+
+@pytest.mark.parametrize("crash_at", [8, 6], ids=["segment_boundary", "mid_segment"])
+def test_no_label_segment_fit_resumes_mid_epoch(crash_at):
+    """``save_every_steps`` on a no-label segment fit, and a resume from the
+    checkpoint it left mid-epoch: at a segment's boundary the producer
+    skips whole segments, inside one it feeds batch by batch; both land on
+    the uninterrupted reference's params."""
+    import optax
+
+    x = np.random.default_rng(12).random((512, 4)).astype(np.float32)
+    ckpt = tempfile.mkdtemp()
+    est, ds, epochs = _no_label_fit(
+        "segments_no_label", x, save_every_steps=2, checkpoint_dir=ckpt)
+    orig = est._save_checkpoint
+
+    def crash(params, epoch, opt_state, step=None):
+        orig(params, epoch, opt_state, step=step)
+        if epoch == 1 and step == crash_at:
+            raise RuntimeError("boom")
+
+    est._save_checkpoint = crash
+    with pytest.raises(RuntimeError):
+        est.fit(ds)
+    assert f"epoch_1_step_{crash_at}" in os.listdir(ckpt)
+    # the resumed fit scans 4-step segments: step 8 is a boundary, 6 is not
+    resumed, ds, _ = _no_label_fit(
+        "segments_no_label", x, checkpoint_dir=ckpt,
+        resume_from_epoch=(1, crash_at), stream_scan_steps=4,
+    )
+    resumed.fit(ds)
+    assert resumed.fit_stats_["runner"] == "segment_scan"
+    _, ref_params = _reference_fit(
+        _autoencoder(), optax.adam(1e-2), "model", 3, epochs())
+    _assert_same_params(ref_params, resumed.get_model().params, atol=1e-5)
+
+
+@pytest.mark.parametrize("steps", [0, -3])
+def test_a_segment_is_a_batch_or_more(steps):
+    with pytest.raises(ValueError, match="stream_scan_steps"):
+        JaxEstimator(model=_mlp(), stream_scan_steps=steps)
 
 
 def test_keep_checkpoints_retention(session):
@@ -722,34 +884,27 @@ class _ArraysDS:
         self.x, self.y = x, y
 
     def to_numpy(self, fc, lc, feature_dtype=None, label_dtype=None):
-        return self.x.copy(), self.y.copy()
+        return self.x.copy(), None if self.y is None else self.y.copy()
 
 
-# every way a fit reaches each of its three training runners: constructor
+# every way a fit reaches each of its two training runners: constructor
 # arguments, the training source (staged host arrays of 2048 rows x 3
 # float32 + labels = 32 KiB, or a Dataset a streamed fit reads block by
 # block) -> runner. An evaluation set or a checkpoint directory is no input
 # of the choice: test_staged_fit_takes_the_resident_scan runs those fits.
 _RUNNER_TABLE = [
     ("resident", dict(), "staged", "resident_scan"),
-    ("resident-forced-over-limit",
-     dict(scan_epochs=True, scan_memory_limit=1024), "staged", "resident_scan"),
+    ("resident-no-limit", dict(scan_memory_limit=None), "staged",
+     "resident_scan"),
     ("resident-no-label", dict(label_column=None), "staged", "resident_scan"),
     ("staged-over-limit", dict(scan_memory_limit=1024), "staged",
      "segment_scan"),
     ("staged-under-a-batch", dict(batch_size=4096), "staged", "segment_scan"),
     ("streamed", dict(streaming=True), "dataset", "segment_scan"),
-    ("streamed-scan-epochs-off", dict(streaming=True, scan_epochs=False),
-     "dataset", "segment_scan"),
     ("streamed-no-label", dict(streaming=True, label_column=None), "dataset",
-     "per_step"),
-    ("scan-epochs-off", dict(scan_epochs=False), "staged", "per_step"),
-    ("segments-off-streamed", dict(streaming=True, stream_scan_steps=0),
-     "dataset", "per_step"),
-    ("segments-off-over-limit",
-     dict(scan_memory_limit=1024, stream_scan_steps=0), "staged", "per_step"),
+     "segment_scan"),
     ("no-label-over-limit", dict(scan_memory_limit=1024, label_column=None),
-     "staged", "per_step"),
+     "staged", "segment_scan"),
 ]
 
 
@@ -797,9 +952,12 @@ def test_staged_fit_takes_the_resident_scan(how):
 
 def test_resident_scan_matches_per_step_loop():
     """The resident per-epoch scan (with and without a checkpoint
-    directory) and the explicit per-step loop (scan_epochs=False) must
-    train IDENTICALLY for the same seed: same host permutations, same step
-    math — per-epoch losses equal to float32 tolerance."""
+    directory, under the default limit and under none) must train to what
+    one call of the step a batch gives for the same seed: same host
+    permutations, same step math — per-epoch losses equal to float32
+    tolerance."""
+    import optax
+
     from raydp_tpu.models import MLPRegressor
 
     rng = np.random.default_rng(9)
@@ -807,7 +965,7 @@ def test_resident_scan_matches_per_step_loop():
     x = rng.random((n, 3)).astype(np.float32)
     y = (x @ np.array([1.0, -2.0, 0.5], np.float32)).astype(np.float32)
 
-    def run(runner="resident_scan", **kw):
+    def run(**kw):
         est = JaxEstimator(
             model=MLPRegressor(),
             optimizer="adam",
@@ -822,11 +980,95 @@ def test_resident_scan_matches_per_step_loop():
             **kw,
         )
         losses = [r["train_loss"] for r in est.fit(_ArraysDS(x, y))]
-        assert est.fit_stats_["runner"] == runner
-        return losses
+        assert est.fit_stats_["runner"] == "resident_scan"
+        return est, losses
 
-    scan = run()
-    with_ckpt = run(checkpoint_dir=tempfile.mkdtemp())
-    loop = run("per_step", scan_epochs=False)  # true per-step dispatch loop
+    est, scan = run()
+    _, with_ckpt = run(checkpoint_dir=tempfile.mkdtemp())
+    loop, _ = _reference_fit(
+        MLPRegressor(), optax.adam(1e-2), "mse", 4,
+        _staged_epochs(est, x, y, 3))
     np.testing.assert_allclose(scan, with_ckpt, rtol=1e-5)
     np.testing.assert_allclose(scan, loop, rtol=1e-4)
+
+
+def test_no_limit_means_the_resident_scan_over_any_size():
+    """``scan_memory_limit=None`` is no limit: the same 24 KiB that a
+    limit of 1 KiB hands to the segment runner stay on the device, and the
+    evaluation (one scan under no limit, a batch a call over the limit)
+    reads the same loss."""
+    x = np.random.default_rng(2).random((2048, 3)).astype(np.float32)
+    ds = _ArraysDS(x, x.sum(1))
+
+    def run(limit):
+        est = JaxEstimator(
+            model=_mlp(), loss="mse", feature_columns=["a", "b", "c"],
+            label_column="l", batch_size=128, num_epochs=1,
+            scan_memory_limit=limit,
+        )
+        history = est.fit(ds, ds)
+        return est.fit_stats_["runner"], history[0]["eval_loss"]
+
+    unlimited, limited = run(None), run(1024)
+    assert (unlimited[0], limited[0]) == ("resident_scan", "segment_scan")
+    np.testing.assert_allclose(unlimited[1], limited[1], rtol=1e-4)
+
+
+@pytest.mark.parametrize("limit, runner, builder", [
+    (None, "resident_scan", "_build_scan_runner"),
+    (1024, "segment_scan", "_build_stream_runner"),
+])
+def test_fit_once_holds_either_runner_by_the_same_seam(limit, runner, builder):
+    """Both builders take the same arguments and return a ``_Runner``; the
+    epoch loop starts it once, calls ``run_epoch`` once an epoch with the
+    same five arguments for the same five results, and closes it on the way
+    out, also when an epoch raises (the segment runner's producer thread is
+    joined then: none is left behind)."""
+    import threading
+
+    from raydp_tpu.estimator.jax_estimator import _Runner
+
+    x = np.random.default_rng(6).random((1024, 3)).astype(np.float32)
+    ds = _ArraysDS(x, x.sum(1))
+    est = JaxEstimator(
+        model=_mlp(), loss="mse", feature_columns=["a", "b", "c"],
+        label_column="l", batch_size=128, num_epochs=3, seed=2,
+        scan_memory_limit=limit,
+    )
+    calls, fail_at = [], [None]
+    build = getattr(est, builder)
+
+    def spying_build(*args):
+        assert len(args) == 5  # train_source, batch_size, mesh, step, donate
+        real = build(*args)
+        assert isinstance(real, _Runner)
+
+        def run_epoch(*args):
+            assert len(args) == 5
+            calls.append(("run_epoch", args[2], args[3], args[4]))
+            if len(calls) - 1 == fail_at[0]:
+                raise RuntimeError("boom")
+            out = real.run_epoch(*args)
+            assert len(out) == 5 and out[3] == 1024 // 128
+            return out
+
+        return _Runner(
+            run_epoch,
+            lambda *a: calls.append(("start",) + a) or real.start(*a),
+            lambda: calls.append(("close",)) or real.close(),
+        )
+
+    setattr(est, builder, spying_build)
+    threads_before = threading.active_count()
+    est.fit(ds)
+    assert est.fit_stats_["runner"] == runner
+    # seeds seed + epoch, whole epochs, no save callback without a directory
+    assert calls == [("start", 0, 0)] + [
+        ("run_epoch", 2 + epoch, 0, None) for epoch in range(3)
+    ] + [("close",)]
+    del calls[:]
+    fail_at[0] = 2  # the second epoch raises
+    with pytest.raises(RuntimeError, match="boom"):
+        est.fit(ds)
+    assert [c[0] for c in calls] == ["start", "run_epoch", "run_epoch", "close"]
+    assert threading.active_count() == threads_before

@@ -82,306 +82,6 @@ def test_dot_interaction_pallas_matches_xla():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
 
-def test_flash_attention_matches_reference():
-    import jax
-    import jax.numpy as jnp
-
-    from raydp_tpu.ops import flash_attention
-    from raydp_tpu.ops.flash_attention import _reference
-
-    rng = np.random.default_rng(7)
-    q, k, v = (
-        jnp.asarray(rng.standard_normal((2, 4, 128, 32)), jnp.float32)
-        for _ in range(3)
-    )
-    for causal in (False, True):
-        out = flash_attention(q, k, v, causal, 64, 64)
-        ref = _reference(q, k, v, causal)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
-
-    # gradients flow through the custom VJP
-    grad = jax.grad(lambda q_: jnp.sum(flash_attention(q_, k, v, True, 64, 64) ** 2))(q)
-    ref_grad = jax.grad(lambda q_: jnp.sum(_reference(q_, k, v, True) ** 2))(q)
-    np.testing.assert_allclose(np.asarray(grad), np.asarray(ref_grad), atol=5e-4)
-
-
-def test_flash_attention_backward_blockwise_exact():
-    """The pallas backward (dq/dk/dv from saved o + logsumexp — no [T,T]
-    matrix) must match gradients through the exact reference for every input,
-    both maskings, and blocks that straddle the causal diagonal."""
-    import jax
-    import jax.numpy as jnp
-
-    from raydp_tpu.ops import flash_attention
-    from raydp_tpu.ops.flash_attention import _reference
-
-    rng = np.random.default_rng(13)
-    q, k, v = (
-        jnp.asarray(rng.standard_normal((2, 3, 256, 32)), jnp.float32)
-        for _ in range(3)
-    )
-    g = jnp.asarray(rng.standard_normal((2, 3, 256, 32)), jnp.float32)
-
-    for causal in (False, True):
-        for bq, bk in ((64, 64), (128, 32)):
-            _, vjp = jax.vjp(
-                lambda q_, k_, v_: flash_attention(q_, k_, v_, causal, bq, bk),
-                q, k, v,
-            )
-            dq, dk, dv = vjp(g)
-            _, ref_vjp = jax.vjp(
-                lambda q_, k_, v_: _reference(q_, k_, v_, causal), q, k, v
-            )
-            rdq, rdk, rdv = ref_vjp(g)
-            np.testing.assert_allclose(np.asarray(dq), np.asarray(rdq), atol=1e-4)
-            np.testing.assert_allclose(np.asarray(dk), np.asarray(rdk), atol=1e-4)
-            np.testing.assert_allclose(np.asarray(dv), np.asarray(rdv), atol=1e-4)
-
-
-def _flash_module():
-    # ``raydp_tpu.ops.flash_attention`` the attribute is the function
-    import importlib
-
-    return importlib.import_module("raydp_tpu.ops.flash_attention")
-
-
-def _flash_grads(fa, q, k, v, g, causal=True, block_q=None, block_k=None,
-                 window=None):
-    import jax
-
-    _, vjp = jax.vjp(
-        lambda q_, k_, v_: fa.flash_attention(
-            q_, k_, v_, causal, block_q, block_k, None, window), q, k, v)
-    return vjp(g)
-
-
-def _backward_calls(fa, q, causal=True, block_q=None, block_k=None,
-                    window=None):
-    """The names of the Mosaic calls in the gradient's jaxpr."""
-    import re
-
-    import jax
-
-    text = str(jax.make_jaxpr(lambda q_, k_, v_, g_: _flash_grads(
-        fa, q_, k_, v_, g_, causal, block_q, block_k, window))(q, q, q, q))
-    return sorted(set(re.findall(r"flash_attention_(?:window_)?bwd_\w+", text)))
-
-
-# window: None = causal; in blocks of 16 rows: 1 key, a block, several
-# blocks (2.5), the whole sequence; T of 2, 4 and 8 blocks
-FUSED_CASES = [
-    (None, 4, 32, "float32"), (1, 4, 32, "float32"), (16, 4, 32, "float32"),
-    (40, 4, 32, "float32"), (64, 4, 32, "float32"),
-    (None, 2, 32, "float32"), (40, 2, 32, "float32"),
-    (None, 8, 32, "float32"), (40, 8, 32, "float32"),
-    (None, 4, 64, "float32"), (40, 4, 64, "float32"),
-    (None, 4, 128, "float32"), (40, 4, 128, "float32"),
-    (None, 4, 32, "bfloat16"), (1, 4, 32, "bfloat16"),
-    (40, 4, 64, "bfloat16"), (16, 8, 128, "bfloat16"),
-    (None, 2, 128, "bfloat16"),
-]
-
-
-@pytest.mark.parametrize("window, blocks, head, dtype", FUSED_CASES)
-def test_flash_backward_fused_equals_two_call(monkeypatch, window, blocks,
-                                              head, dtype):
-    """The ONE-call backward pass (every live tile's scores, probabilities
-    and ``ds`` computed once, dq a head long in VMEM) gives the two-call
-    pass's dq, dk and dv BIT FOR BIT, causal and under every kind of window,
-    and (float32) the exact reference's gradients at the blockwise test's
-    tolerance."""
-    import jax.numpy as jnp
-
-    from raydp_tpu.parallel import full_attention
-
-    fa = _flash_module()
-    block, t = 16, 16 * blocks
-    rng = np.random.default_rng(43)
-    q, k, v, g = (jnp.asarray(rng.standard_normal((2, 2, t, head)), dtype)
-                  for _ in range(4))
-    assert fa.backward_form(t, t, head, q.dtype.itemsize, block_q=block,
-                            block_k=block) == "fused"
-    calls = _backward_calls(fa, q, True, block, block, window)
-    hidden = window is not None and window < t
-    assert calls == [("flash_attention_window_bwd_dq_dkv" if hidden
-                      else "flash_attention_bwd_dq_dkv")]
-    fused = _flash_grads(fa, q, k, v, g, True, block, block, window)
-    monkeypatch.setattr(fa, "backward_form", lambda *a, **kw: "two_call")
-    assert len(_backward_calls(fa, q, True, block, block, window)) == 2
-    two_call = _flash_grads(fa, q, k, v, g, True, block, block, window)
-    for name, got, want in zip(("dq", "dk", "dv"), fused, two_call):
-        assert got.dtype == want.dtype == q.dtype, name
-        np.testing.assert_array_equal(
-            np.asarray(got.astype(jnp.float32)),
-            np.asarray(want.astype(jnp.float32)), err_msg=name)
-    if dtype == "float32":
-        import jax
-
-        _, ref_vjp = jax.vjp(lambda q_, k_, v_: full_attention(
-            q_, k_, v_, causal=True, window=window), q, k, v)
-        for name, got, want in zip(("dq", "dk", "dv"), fused, ref_vjp(g)):
-            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                       atol=1e-4, err_msg=name)
-
-
-@pytest.mark.parametrize("case", [
-    "runtime_offsets", "tq_is_not_tk", "non_causal", "unequal_blocks",
-    "dq_past_the_vmem_bound"])
-def test_backward_form_keeps_the_two_call_pass(monkeypatch, case):
-    """What the fused form does not cover runs the two-call pass, decided
-    from the shapes and arguments alone, and gives the gradients it gave."""
-    import jax
-    import jax.numpy as jnp
-
-    from raydp_tpu.parallel import full_attention
-
-    fa = _flash_module()
-    rng = np.random.default_rng(44)
-    t, d, block = 64, 32, 16
-
-    def randn(rows):
-        return jnp.asarray(rng.standard_normal((1, 2, rows, d)), jnp.float32)
-
-    q, k, v, g = randn(t), randn(t), randn(t), randn(t)
-    assert fa.backward_form(t, t, d, 4, block_q=block, block_k=block) == "fused"
-    two_names = ["flash_attention_bwd_dkv", "flash_attention_bwd_dq"]
-
-    def reference(causal, k_=k, v_=v):
-        _, vjp = jax.vjp(lambda a, b, c: full_attention(a, b, c, causal=causal),
-                         q, k_, v_)
-        return vjp(g)
-
-    if case == "runtime_offsets":
-        # a ring step's call: the offsets are values of the program
-        assert fa.backward_form(
-            t, t, d, 4, block_q=block, block_k=block,
-            q_offset=jnp.int32(0), k_offset=0) == "two_call"
-        o = full_attention(q, k, v, causal=True)
-        s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * d ** -0.5
-        s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
-        lse = jax.nn.logsumexp(s, axis=-1)
-        dsum = jnp.sum(g * o, axis=-1)
-
-        def ring_step(q_off, k_off):
-            return fa.flash_backward_blocks(
-                q, k, v, lse, dsum, g, q_off, k_off, True, block, block)
-
-        text = str(jax.make_jaxpr(ring_step)(jnp.int32(0), jnp.int32(0)))
-        assert "bwd_dq_dkv" not in text and "flash_attention_bwd_dkv" in text
-        got = jax.jit(ring_step)(jnp.int32(0), jnp.int32(0))
-        want = reference(True)
-        # the fused call of the same tiles (static offsets): the same bits
-        for a, b in zip(got, fa.flash_backward_blocks(
-                q, k, v, lse, dsum, g, 0, 0, True, block, block)):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    elif case == "tq_is_not_tk":
-        k, v = randn(2 * t), randn(2 * t)
-        assert fa.backward_form(t, 2 * t, d, 4, block_q=block,
-                                block_k=block) == "two_call"
-        _, vjp = jax.vjp(lambda a, b, c: fa.flash_attention(
-            a, b, c, True, block, block), q, k, v)
-        got, want = vjp(g), reference(True, k, v)
-    elif case == "non_causal":
-        assert fa.backward_form(t, t, d, 4, causal=False, block_q=block,
-                                block_k=block) == "two_call"
-        assert _backward_calls(fa, q, False, block, block) == two_names
-        got = _flash_grads(fa, q, k, v, g, False, block, block)
-        want = reference(False)
-    elif case == "unequal_blocks":
-        assert fa.backward_form(t, t, d, 4, block_q=32,
-                                block_k=block) == "two_call"
-        assert _backward_calls(fa, q, True, 32, block) == two_names
-        got = _flash_grads(fa, q, k, v, g, True, 32, block)
-        want = reference(True)
-    else:
-        # from the shapes: 128k rows of 128 are a dq of 64 MB, past what a
-        # call may ask for beside its tiles; half of that is not
-        assert fa.backward_form(131072, 131072, 128) == "two_call"
-        assert fa.backward_form(65536, 65536, 128) == "fused"
-        assert fa.dq_resident_bytes(16384, 128) == 8 * 2**20
-        assert fa.dq_resident_bytes(8192, 64) == 4 * 2**20  # lane-padded
-        assert fa.fused_vmem_bytes(16384, 128, 1024, 2) > (
-            fa.VMEM_DEFAULT_BYTES + fa.dq_resident_bytes(16384, 128))
-        # the same decision at a size the interpreter runs
-        monkeypatch.setattr(fa, "VMEM_ASK_BOUND_BYTES",
-                            fa.fused_vmem_bytes(t, d, block, 4) - 1)
-        assert fa.backward_form(t, t, d, 4, block_q=block,
-                                block_k=block) == "two_call"
-        assert _backward_calls(fa, q, True, block, block) == two_names
-        got = _flash_grads(fa, q, k, v, g, True, block, block)
-        want = reference(True)
-    for name, a, b in zip(("dq", "dk", "dv"), got, want):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4,
-                                   err_msg=f"{case} {name}")
-
-
-def test_flash_backward_blocks_picks_its_tiles_as_the_forward_does(monkeypatch):
-    """Past a head of 128 the forward's tile halves (``pick_blocks`` keeps
-    a tile's VMEM footprint): the backward pass asks with the same head."""
-    import jax
-    import jax.numpy as jnp
-
-    fa = _flash_module()
-    asked = []
-    pick = fa.pick_blocks
-
-    def noting(*args, **kwargs):
-        asked.append(kwargs.get("head_dim"))
-        return pick(*args, **kwargs)
-
-    monkeypatch.setattr(fa, "pick_blocks", noting)
-    q = jax.ShapeDtypeStruct((1, 1, 1024, 256), jnp.bfloat16)
-    jax.eval_shape(lambda q_, k_, v_, g_: _flash_grads(fa, q_, k_, v_, g_),
-                   q, q, q, q)
-    assert asked and set(asked) == {256}
-    assert pick(1024, 1024, head_dim=256) == (512, 512)
-
-
-def test_flash_attention_training_memory_is_linear():
-    """Jaxpr-level check that the backward never materializes a [T, T]
-    score matrix: the largest intermediate in the VJP scales with T, not T²
-    (the round-1 backward recomputed through full attention and OOMed at
-    the lengths the forward could handle)."""
-    import jax
-    import jax.numpy as jnp
-
-    from raydp_tpu.ops import flash_attention
-
-    t = 2048
-    q = jax.ShapeDtypeStruct((1, 1, t, 32), jnp.float32)
-
-    def loss(q_, k_, v_):
-        return jnp.sum(flash_attention(q_, k_, v_, True, 128, 128) ** 2)
-
-    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q)
-
-    def subjaxprs(eqn):
-        for val in eqn.params.values():
-            for v in val if isinstance(val, (list, tuple)) else [val]:
-                if hasattr(v, "jaxpr"):
-                    yield v.jaxpr
-                elif hasattr(v, "eqns"):
-                    yield v
-
-    def max_elems(jpr):
-        worst = 0
-        for eqn in jpr.eqns:
-            for var in eqn.outvars:
-                shape = getattr(var.aval, "shape", ())
-                n = int(np.prod(shape)) if shape else 1
-                worst = max(worst, n)
-            for sub in subjaxprs(eqn):
-                worst = max(worst, max_elems(sub))
-        return worst
-
-    largest = max_elems(jaxpr.jaxpr)
-    # O(T): q itself is t*32 elems; a [T,T] matrix would be t*t = 64x larger
-    assert largest <= t * 32 * 4, (
-        f"backward materializes an intermediate of {largest} elements "
-        f"(≥ [T,T] = {t*t})"
-    )
-
-
 def test_transformer_flash_matches_full():
     import jax
     import jax.numpy as jnp
@@ -550,206 +250,6 @@ def test_pipeline_parallel_matches_sequential(cpu_mesh_devices):
 
     ref_grad = jax.grad(seq_loss)(Ws)
     np.testing.assert_allclose(np.asarray(grad), np.asarray(ref_grad), atol=1e-4)
-
-
-def test_moe_expert_parallel_matches_dense(cpu_mesh_devices):
-    import jax
-    import jax.numpy as jnp
-
-    from raydp_tpu.parallel import make_mesh, moe_sharded
-
-    N, D, B = 4, 8, 64
-    mesh = make_mesh({"ep": N}, jax.devices()[:N])
-    rng = np.random.default_rng(10)
-    Ws = jnp.asarray(rng.standard_normal((N, D, D)) * 0.5, jnp.float32)
-    Wr = jnp.asarray(rng.standard_normal((D, N)) * 0.5, jnp.float32)
-    x = jnp.asarray(rng.standard_normal((B, D)), jnp.float32)
-
-    def expert_fn(W, t):
-        return jax.nn.relu(t @ W)
-
-    gates = jax.nn.softmax(x @ Wr, -1)
-    assign = jnp.argmax(gates, -1)
-    gate = jnp.take_along_axis(gates, assign[:, None], 1)[:, 0]
-    dense = jnp.stack([expert_fn(Ws[e], x) for e in range(N)], 1)
-    ref = dense[jnp.arange(B), assign] * gate[:, None]
-
-    out = moe_sharded(expert_fn, Ws, Wr, x, mesh, capacity_factor=8.0)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
-
-    # gradients through the double all_to_all + dispatch einsums
-    grad = jax.grad(
-        lambda w: jnp.sum(moe_sharded(expert_fn, w, Wr, x, mesh, capacity_factor=8.0) ** 2)
-    )(Ws)
-
-    def dense_loss(w):
-        d = jnp.stack([expert_fn(w[e], x) for e in range(N)], 1)
-        return jnp.sum((d[jnp.arange(B), assign] * gate[:, None]) ** 2)
-
-    ref_grad = jax.grad(dense_loss)(Ws)
-    np.testing.assert_allclose(np.asarray(grad), np.asarray(ref_grad), atol=1e-4)
-
-
-def test_moe_top2_matches_dense(cpu_mesh_devices):
-    """Top-2 routing with renormalized gates must equal the dense two-expert
-    mixture when capacity is ample, and expose aux stats."""
-    import jax
-    import jax.numpy as jnp
-
-    from raydp_tpu.parallel import make_mesh, moe_sharded
-
-    N, D, B = 4, 8, 64
-    mesh = make_mesh({"ep": N}, jax.devices()[:N])
-    rng = np.random.default_rng(21)
-    Ws = jnp.asarray(rng.standard_normal((N, D, D)) * 0.5, jnp.float32)
-    Wr = jnp.asarray(rng.standard_normal((D, N)) * 0.5, jnp.float32)
-    x = jnp.asarray(rng.standard_normal((B, D)), jnp.float32)
-
-    def expert_fn(W, t):
-        return jax.nn.relu(t @ W)
-
-    gates = jax.nn.softmax(x @ Wr, -1)
-    top_vals, top_idx = jax.lax.top_k(gates, 2)
-    w = top_vals / jnp.sum(top_vals, -1, keepdims=True)
-    dense = jnp.stack([expert_fn(Ws[e], x) for e in range(N)], 1)  # [B,N,D]
-    ref = (
-        dense[jnp.arange(B), top_idx[:, 0]] * w[:, :1]
-        + dense[jnp.arange(B), top_idx[:, 1]] * w[:, 1:]
-    )
-
-    out, aux = moe_sharded(
-        expert_fn, Ws, Wr, x, mesh, capacity_factor=8.0, top_k=2,
-        return_aux=True,
-    )
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
-    assert float(aux["drop_fraction"]) == 0.0  # ample capacity
-    assert float(aux["load_balance_loss"]) >= 1.0  # ==1 only at perfect balance
-
-    # gradients flow through the top-2 combine
-    grad = jax.grad(
-        lambda ws: jnp.sum(
-            moe_sharded(expert_fn, ws, Wr, x, mesh, capacity_factor=8.0, top_k=2) ** 2
-        )
-    )(Ws)
-
-    def dense_loss(ws):
-        d = jnp.stack([expert_fn(ws[e], x) for e in range(N)], 1)
-        o = (
-            d[jnp.arange(B), top_idx[:, 0]] * w[:, :1]
-            + d[jnp.arange(B), top_idx[:, 1]] * w[:, 1:]
-        )
-        return jnp.sum(o ** 2)
-
-    ref_grad = jax.grad(dense_loss)(Ws)
-    np.testing.assert_allclose(np.asarray(grad), np.asarray(ref_grad), atol=1e-4)
-
-
-def test_moe_drop_fraction_visible(cpu_mesh_devices):
-    """Tokens beyond capacity are dropped — round 1 did this silently; the
-    drop fraction must now be reported."""
-    import jax
-    import jax.numpy as jnp
-
-    from raydp_tpu.parallel import make_mesh, moe_sharded
-
-    N, D, B = 4, 8, 64
-    mesh = make_mesh({"ep": N}, jax.devices()[:N])
-    rng = np.random.default_rng(22)
-    Ws = jnp.asarray(rng.standard_normal((N, D, D)), jnp.float32)
-    # router biased hard toward expert 0 → guaranteed overflow at cf=1.0
-    Wr = jnp.asarray(
-        np.concatenate(
-            [np.full((D, 1), 3.0), np.zeros((D, N - 1))], axis=1
-        ),
-        jnp.float32,
-    )
-    x = jnp.abs(jnp.asarray(rng.standard_normal((B, D)), jnp.float32))
-
-    _, aux = moe_sharded(
-        lambda W, t: t @ W, Ws, Wr, x, mesh, capacity_factor=1.0, top_k=1,
-        return_aux=True,
-    )
-    assert float(aux["drop_fraction"]) > 0.2
-    assert float(aux["load_balance_loss"]) > 1.5  # collapsed router
-
-
-def test_moe_aux_loss_reduces_imbalance(cpu_mesh_devices):
-    """Training the router against load_balance_loss must spread the load:
-    the loss falls toward 1.0 (perfect balance) and drops disappear."""
-    import jax
-    import jax.numpy as jnp
-    import optax
-
-    from raydp_tpu.parallel import make_mesh, moe_sharded
-
-    N, D, B = 4, 8, 64
-    mesh = make_mesh({"ep": N}, jax.devices()[:N])
-    rng = np.random.default_rng(23)
-    Ws = jnp.asarray(rng.standard_normal((N, D, D)) * 0.5, jnp.float32)
-    # collapsed start: every token prefers expert 0
-    Wr0 = jnp.asarray(
-        np.concatenate([np.full((D, 1), 2.0), np.zeros((D, N - 1))], 1)
-        + rng.standard_normal((D, N)) * 0.01,
-        jnp.float32,
-    )
-    x = jnp.abs(jnp.asarray(rng.standard_normal((B, D)), jnp.float32))
-
-    def aux_of(wr):
-        _, aux = moe_sharded(
-            lambda W, t: t @ W, Ws, wr, x, mesh, capacity_factor=1.25,
-            top_k=2, return_aux=True,
-        )
-        return aux["load_balance_loss"], aux["drop_fraction"]
-
-    tx = optax.adam(0.05)
-    opt_state = tx.init(Wr0)
-
-    @jax.jit
-    def step(wr, opt_state):
-        lb, _ = aux_of(wr)
-        g = jax.grad(lambda w: aux_of(w)[0])(wr)
-        updates, opt_state = tx.update(g, opt_state, wr)
-        return optax.apply_updates(wr, updates), opt_state, lb
-
-    wr = Wr0
-    lb_first = None
-    for _ in range(120):
-        wr, opt_state, lb = step(wr, opt_state)
-        if lb_first is None:
-            lb_first = float(lb)
-    lb_last, drop_last = (float(v) for v in aux_of(wr))
-    assert lb_first > 1.5, f"start not collapsed: {lb_first}"
-    assert lb_last < 1.15, f"aux loss failed to rebalance: {lb_last}"
-    assert drop_last < 0.05, f"drops persist after rebalancing: {drop_last}"
-
-
-def test_flash_attention_composes_with_shard_map(cpu_mesh_devices):
-    """Mosaic kernels can't be AUTO-partitioned, but under shard_map (manual
-    partitioning) the flash kernel runs per shard — the composition ring
-    attention's per-device block math will use."""
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P
-
-    from raydp_tpu.ops import flash_attention
-    from raydp_tpu.ops.flash_attention import _reference
-    from raydp_tpu.parallel import make_mesh
-
-    mesh = make_mesh({"data": 4}, jax.devices()[:4])
-    rng = np.random.default_rng(13)
-    q, k, v = (
-        jnp.asarray(rng.standard_normal((8, 2, 64, 16)), jnp.float32)
-        for _ in range(3)
-    )
-    spec = P("data", None, None, None)  # batch-sharded; attention is local
-    # check_vma=False: the pallas interpreter can't reconcile invariant grid
-    # slices with varying operands (JAX's documented workaround)
-    out = jax.shard_map(
-        lambda q_, k_, v_: flash_attention(q_, k_, v_, True, 32, 32),
-        mesh=mesh, in_specs=(spec,) * 3, out_specs=spec, check_vma=False,
-    )(q, k, v)
-    ref = _reference(q, k, v, True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
 def test_quantize_int8_roundtrip():

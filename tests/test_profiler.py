@@ -120,55 +120,60 @@ def test_timeseries_max_fanout():
 
 
 @pytest.fixture(scope="module")
-def per_step_fit(host_ds):
-    """One real 2-epoch fit on the per-step loop path (scan_epochs=False),
-    shared by the phase/attribution/MFU tests."""
-    est = _make_est(scan_epochs=False)
-    history = est.fit(host_ds)
+def segment_fit(host_ds):
+    """One real 2-epoch fit on the segment runner (staged data over the
+    limit: four 8-step segments an epoch), shared by the phase/attribution/
+    MFU tests. The runner fences every second dispatch here, so the
+    ``sync`` phase has something to show."""
+    from raydp_tpu.estimator import jax_estimator
+
+    est = _make_est(scan_memory_limit=1, stream_scan_steps=8)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax_estimator, "MAX_DISPATCHES_IN_FLIGHT", 2)
+        history = est.fit(host_ds)
     return est, history
 
 
-def test_step_phase_histograms_present_and_sane(per_step_fit):
-    est, history = per_step_fit
+def test_step_phase_histograms_present_and_sane(segment_fit):
+    est, history = segment_fit
     assert len(history) == 2
     stats = est.fit_stats_
-    steps_expected = 2 * (2048 // 64)
-    # first (compile) step is excluded from the steady-state histograms
-    assert stats["steps"] == steps_expected - 1
+    assert stats["runner"] == "segment_scan"
+    assert stats["steps"] == 2 * (2048 // 64)
     phases = stats["step_phase_seconds"]
     assert set(phases) == {"ingest", "h2d", "dispatch", "sync"}
-    assert phases["dispatch"] > 0.0
-    # phases tile the measured step-loop wall: the sum must account for
-    # (nearly) all of it — an uninstrumented gap shows up here first
-    wall = stats["step_wall_s"]
-    assert wall and wall > 0.0
-    covered = sum(phases.values())
-    assert 0.7 * wall <= covered <= 1.1 * wall, (covered, wall)
-    # the registry carries the per-step histograms (scrapeable mid-fit)
+    assert all(seconds > 0.0 for seconds in phases.values()), phases
+    # what the consumer thread notes (its wait for a segment, its time in
+    # the compiled call, its fences) lies inside the epochs' spans; the
+    # uploads (h2d) are the producer thread's and overlap them
+    consumer = phases["ingest"] + phases["dispatch"] + phases["sync"]
+    assert consumer <= 1.1 * sum(r["epoch_seconds"] for r in history)
+    # the registry carries the phase histograms (scrapeable mid-fit): an
+    # observation a segment, none a step
     snap = obs.metrics.snapshot()
     assert "estimator.step.compute_ms" not in snap
-    for phase in ("ingest", "h2d", "dispatch"):
+    for phase in ("ingest", "h2d", "dispatch", "sync"):
         hist = snap[f"estimator.step.{phase}_ms"]
         assert hist["type"] == "histogram" and hist["count"] > 0
         assert hist["max"] >= hist["p50"] >= 0.0
 
 
-def test_explain_last_fit_attribution(per_step_fit):
-    est, _history = per_step_fit
+def test_explain_last_fit_attribution(segment_fit):
+    est, _history = segment_fit
     report = est.explain_last_fit()
     assert report["root"] == "estimator.fit"
     # acceptance gate: ≥0.9 of the fit's wall time lands in NAMED segments
     assert report["attributed_frac"] >= 0.9, report["text"]
-    # the step-phase split surfaces real compute-plane categories: the
-    # host's time inside the step calls, and the fences it waited at
+    # the epoch's own spans surface the compute-plane categories: the
+    # host's time inside the compiled calls and the compiles; a fence has
+    # no span and stays the epoch's own time
     assert report["by_category"].get("dispatch", 0.0) > 0.0
-    assert report["by_category"].get("sync", 0.0) > 0.0
     assert "compile" in report["by_category"]
     assert report["text"].startswith("critical path of estimator.fit")
 
 
-def test_live_mfu_vs_analytic_parity(per_step_fit):
-    est, _history = per_step_fit
+def test_live_mfu_vs_analytic_parity(segment_fit):
+    est, _history = segment_fit
     stats = est.fit_stats_
     flops_live = stats["flops_per_step"]
     assert flops_live, stats
@@ -185,26 +190,27 @@ def test_live_mfu_vs_analytic_parity(per_step_fit):
     # the ratio is completed work over the wall time between two
     # observations of completion, never over the host's time in dispatches
     assert stats["steps_completed"] == 2 * (2048 // 64)
-    wall = stats["flops_per_step"] * (stats["steps_completed"] - 1) / (
+    wall = stats["flops_per_step"] * stats["steps_completed"] / (
         stats["model_flops_per_sec"]
     )
     assert wall > stats["step_phase_seconds"]["dispatch"]
 
 
-def test_scan_path_reports_same_flops(host_ds, per_step_fit):
-    """The segment-scanned path must report the SAME FLOPs-per-step as the
-    per-step loop (one accounting): the scan executable is opaque to cost
-    analysis, so the single-step abstract lowering covers it."""
-    est_scan = _make_est()  # default scan_epochs → the resident scan
+def test_scan_path_reports_same_flops(host_ds, segment_fit):
+    """Both runners report the SAME FLOPs-per-step (one accounting): a
+    scan executable is opaque to cost analysis, so the single-step abstract
+    lowering covers both."""
+    est_scan = _make_est()  # under the default limit: the resident scan
     est_scan.fit(host_ds)
-    per_step_est, _ = per_step_fit
+    assert est_scan.fit_stats_["runner"] == "resident_scan"
+    segment_est, _ = segment_fit
     assert est_scan.fit_stats_["flops_per_step"] == pytest.approx(
-        per_step_est.fit_stats_["flops_per_step"]
+        segment_est.fit_stats_["flops_per_step"]
     )
     assert est_scan.fit_stats_["steps"] == 2 * (2048 // 64)
 
 
-def test_mfu_series_reaches_local_mirror(per_step_fit):
+def test_mfu_series_reaches_local_mirror(segment_fit):
     """The estimator.mfu gauge rides the flush tick into the windowed
     time-series mirror — what a head scrape would show."""
     obs.flush()
@@ -216,7 +222,8 @@ def test_mfu_series_reaches_local_mirror(per_step_fit):
 def test_step_profiler_off_is_noop(host_ds):
     profiler.set_step_profiler(False)
     try:
-        est = _make_est(scan_epochs=False, num_epochs=1)
+        est = _make_est(
+            scan_memory_limit=1, stream_scan_steps=8, num_epochs=1)
         est.fit(host_ds)
         assert est.fit_stats_["profiler"] == "off"
         assert est.fit_stats_["step_phase_seconds"] == {}
@@ -229,8 +236,6 @@ def test_step_profiler_off_is_noop(host_ds):
 # ---------------------------------------------------------------------------
 
 _RUNNERS = {
-    # the per-step loop
-    "per_step": dict(scan_epochs=False),
     # staged data too large for the whole-epoch scan: 8-step segment scans
     # fed by the producer thread
     "segment_streamed": dict(scan_memory_limit=1, stream_scan_steps=8),
@@ -301,7 +306,7 @@ def test_restart_histogram_one_observation_per_epoch_boundary(host_ds):
         nxt = epochs[r["args"]["epoch"] + 1]
         assert r["ts"] <= nxt["ts"] < r["ts"] + max(r["dur"], 1) + 1
     # no evaluation and no sync fence: no closing fence, nothing observed
-    est = _make_est(num_epochs=3, sync_every_steps=0, scan_epochs=False)
+    est = _make_est(num_epochs=3, scan_memory_limit=1, stream_scan_steps=8)
     before = hist.count
     est.fit(host_ds)
     assert hist.count == before
@@ -400,7 +405,7 @@ def test_span_lies_in_profiler_trace_host_plane(tmp_path):
 
 
 def test_profile_fit_capture_window(host_ds, tmp_path):
-    est = _make_est(scan_epochs=False, num_epochs=1)
+    est = _make_est(scan_memory_limit=1, stream_scan_steps=8, num_epochs=1)
     out_dir = str(tmp_path / "cap")
     with profiler.profile_fit(steps=8, out_dir=out_dir,
                               jax_trace=False) as cap:
@@ -620,7 +625,7 @@ def test_capture_window_writes_device_scopes(host_ds, tmp_path, fresh_scopes):
     assert profiler.device_scopes() == {}
 
 
-@pytest.mark.parametrize("runner", ["scan", "per_step"])
+@pytest.mark.parametrize("runner", ["scan", "segments"])
 def test_a_fit_nobody_profiles_reads_no_program_text(
         host_ds, monkeypatch, fresh_scopes, runner):
     import jax
@@ -632,13 +637,12 @@ def test_a_fit_nobody_profiles_reads_no_program_text(
         lambda self, *a, **k: reads.append(1) or real(self, *a, **k))
     monkeypatch.setattr(
         costmodel, "step_flops_abstract", lambda *a, **k: 1.0)
-    monkeypatch.setattr(
-        costmodel, "step_flops_from_jitted", lambda *a, **k: 1.0)
     asked = []
     monkeypatch.setattr(profiler, "scopes_in_text",
                         lambda *a, **k: asked.append(1) or {})
     est = _make_est(num_epochs=2, **(
-        {"scan_epochs": False} if runner == "per_step" else {}))
+        {"scan_memory_limit": 1, "stream_scan_steps": 8}
+        if runner == "segments" else {}))
     noted = []
     real_note = profiler.note_program
     monkeypatch.setattr(
@@ -647,8 +651,8 @@ def test_a_fit_nobody_profiles_reads_no_program_text(
     est.fit(host_ds, host_ds)
     assert not reads and not asked
     # one note a compiled program, none an epoch
-    step = "first_step" if runner == "per_step" else "32"
-    evaluation = "eval_step" if runner == "per_step" else "eval_scan"
+    step = "8" if runner == "segments" else "32"
+    evaluation = "eval_step" if runner == "segments" else "eval_scan"
     assert sorted(noted) == sorted(["init", step, evaluation]), noted
 
 

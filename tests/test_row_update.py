@@ -313,25 +313,25 @@ def dense_only(monkeypatch):
     return fit_dense
 
 
-@pytest.mark.parametrize("runner", ["resident", "streamed", "per_step_ckpt"])
+@pytest.mark.parametrize("runner", ["resident", "streamed", "segments_ckpt"])
 def test_fits_reach_the_dense_steps_losses(session, criteo_df, dense_only,
                                            runner):
-    """(4) the three runners that wrap the step, row path against dense
+    """(4) the two runners that wrap the step (the segment runner fed from
+    a stream and from staged data over the limit), row path against dense
     step, and a resume from a checkpoint the row path wrote into the
     unchanged pytree."""
     ds = dataframe_to_dataset(criteo_df)
     kw = {
         "resident": dict(),
         "streamed": dict(streaming=True, shuffle=False),
-        "per_step_ckpt": dict(scan_epochs=False, stream_scan_steps=0,
-                              save_every_steps=8),
+        "segments_ckpt": dict(scan_memory_limit=1, save_every_steps=8),
     }[runner]
 
     def fit(**more):
         est = _criteo_est(**kw, **more)
         return est, _losses(est.fit(ds, ds))
 
-    ckpt = tempfile.mkdtemp() if runner == "per_step_ckpt" else None
+    ckpt = tempfile.mkdtemp() if runner == "segments_ckpt" else None
     more = dict(checkpoint_dir=ckpt) if ckpt else {}
     est, rows = fit(**more)
     assert est.fit_stats_["row_update"]["params"] == 1

@@ -518,15 +518,19 @@ def test_replica_sigkill_mid_stream_drops_nothing(served_model):
         assert all(
             np.array_equal(a, b) for a, b in zip(clean, chaos)
         )
-        # the controller heals the pool back to target
+        # the controller heals the pool back to target: wait for the
+        # replacement as an EVENT (its counter rises), then for the count.
+        # The count alone also reads 2 before the controller has seen the
+        # kill, while the dead replica is still in the pool
+        replacements = obs.metrics.counter("serve.replica_replacements")
         deadline = time.monotonic() + 15.0
-        while dep.replica_count() < 2 and time.monotonic() < deadline:
+        while time.monotonic() < deadline and (
+            replacements.value <= failovers_before
+            or dep.replica_count() < 2
+        ):
             time.sleep(0.05)
+        assert replacements.value > failovers_before
         assert dep.replica_count() == 2
-        assert (
-            obs.metrics.counter("serve.replica_replacements").value
-            > failovers_before
-        )
         # in-flight loss shows up as re-admissions only when the kill landed
         # mid-dispatch; either way the counters moved without any drop
         assert obs.metrics.counter("serve.dropped_requests").value == 0

@@ -39,6 +39,22 @@ def _mlp():
     return MLP()
 
 
+def _autoencoder():
+    """A model whose loss is its own and reads no label column."""
+    import flax.linen as nn
+    import jax.numpy as jnp
+
+    class AutoEncoder(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            return nn.Dense(x.shape[-1])(nn.tanh(nn.Dense(8)(x)))
+
+        def loss(self, x, y=None):
+            return jnp.mean((self(x) - x) ** 2)
+
+    return AutoEncoder()
+
+
 def _block_dataset(n=2048, seed=0, f=2):
     """Driver-written Dataset, independent of the ETL engine."""
     import pyarrow as pa
@@ -282,9 +298,11 @@ def test_streaming_executor_decode_active(session):
     assert est_off.stream_stats_["executor_decode"] is False
 
 
-def test_streaming_executor_decode_matches_local(session):
+@pytest.mark.parametrize("label_column", ["z", None], ids=["labelled", "no_label"])
+def test_streaming_executor_decode_matches_local(session, label_column):
     """Executor-side and driver-local decode must be byte-identical: same
-    data, same seed, params bit-equal."""
+    data, same seed, params bit-equal — with a label column and, for a
+    model whose loss is its own, without one."""
     import jax
 
     rng = np.random.default_rng(23)
@@ -296,12 +314,15 @@ def test_streaming_executor_decode_matches_local(session):
 
     def run(executor_decode):
         est = JaxEstimator(
-            model=_mlp(), loss="mse", feature_columns=["x", "y"],
-            label_column="z", batch_size=64, num_epochs=2,
+            model=_mlp() if label_column else _autoencoder(),
+            loss="mse" if label_column else "model",
+            feature_columns=["x", "y"],
+            label_column=label_column, batch_size=64, num_epochs=2,
             learning_rate=1e-2, seed=9, shuffle=False, streaming=True,
             stream_executor_decode=executor_decode,
         )
         est.fit_on_etl(df)
+        assert est.fit_stats_["runner"] == "segment_scan"
         return est
 
     remote = run(True)

@@ -1,0 +1,137 @@
+"""The kernels' small compiles for a described TPU v5e: the flash kernels at
+every LM cell's widths (the forward and the one-call backward, bf16 and the
+matched check's float32), heads of 64, the grouped products of the routed
+cells (``tpu_compile_helpers`` says how and why)."""
+
+import importlib
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from tpu_compile_helpers import (  # noqa: F401 - fixtures by name
+    BWD_DKV, kernels_compile, no_compile_cache, one_chip)
+
+
+@pytest.mark.parametrize("dtype, precision, tile", [
+    (jnp.bfloat16, None, 1024), (jnp.float32, "highest", 512)])
+def test_flash_forward_and_backward_compile_at_ouro_widths(
+        one_chip, no_compile_cache, dtype, precision, tile):
+    """[2 x 16 heads, T 4096, head 128], causal: the timed bf16 step's tiles,
+    and the float32 ones of the benchmark's matched check (1024-row float32
+    tiles ask the backward kernel for 19.5 MB of its 16 MB of VMEM)."""
+    fa = importlib.import_module("raydp_tpu.ops.flash_attention")
+    assert fa.pick_blocks(4096, 4096, head_dim=128,
+                          itemsize=jnp.dtype(dtype).itemsize) == (tile, tile)
+    q = jax.ShapeDtypeStruct((2, 16, 4096, 128), dtype, sharding=one_chip)
+
+    def grads(q, k, v):
+        with jax.default_matmul_precision(precision):
+            return jax.grad(
+                lambda q, k, v: fa.flash_attention(
+                    q, k, v, True, None, None, False
+                ).astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    text = jax.jit(grads).lower(q, q, q).compile().as_text()
+    for name in ("flash_attention_fwd", "flash_attention_bwd_dq_dkv"):
+        # the instruction's name: %jvp_<name>_.1, %transpose_jvp_<name>__.1
+        assert re.search(rf"%[\w.\-]*{name}[\w.\-]* = ", text), name
+    # PR 43: the backward pass is ONE call
+    assert not re.search(BWD_DKV, text)
+    assert text.count("tpu_custom_call") == 2
+
+
+@pytest.mark.parametrize("heads, t, head, window", [
+    (32, 4096, 128, None), (32, 8192, 64, None), (128, 8192, 64, None),
+    (56, 16384, 128, None), (56, 16384, 128, 4096)],
+    ids=["ouro", "granite", "routed", "window_cell_global",
+         "window_cell_window"])
+@pytest.mark.parametrize("dtype, precision", [
+    (jnp.bfloat16, None), (jnp.float32, "highest")],
+    ids=["bf16", "float32_matched"])
+def test_fused_flash_backward_compiles_at_the_cells_shapes(
+        one_chip, no_compile_cache, heads, t, head, window, dtype, precision):
+    """PR 43: the ONE-call backward pass alone, [batch x heads, T, head] of
+    the four LM cells (the timed bf16 step and the matched check's float32
+    tiles): a head's float32 dq lives in VMEM (2-8 MB) beside the tile, so
+    the call asks for more than a Mosaic call's 16 MB by
+    ``vmem_limit_bytes`` (``fused_vmem_bytes``, from the shapes): what it
+    asks for must cover what the compiler needs, here and not on the chip."""
+    fa = importlib.import_module("raydp_tpu.ops.flash_attention")
+    itemsize = jnp.dtype(dtype).itemsize
+    block, _ = fa.pick_blocks(t, t, head_dim=head, itemsize=itemsize)
+    assert fa.backward_form(t, t, head, itemsize) == "fused"
+    assert (fa.dq_resident_bytes(t, head) < fa.fused_vmem_bytes(
+        t, head, block, itemsize) <= fa.VMEM_ASK_BOUND_BYTES)
+    q = jax.ShapeDtypeStruct((1, heads, t, head), dtype, sharding=one_chip)
+    stat = jax.ShapeDtypeStruct((1, heads, t), jnp.float32, sharding=one_chip)
+
+    def backward(q, k, v, lse, dsum, g):
+        with jax.default_matmul_precision(precision):
+            return fa.flash_backward_blocks(
+                q, k, v, lse, dsum, g, 0, 0, True, None, None, False, window)
+
+    compiled = jax.jit(backward).lower(q, q, q, stat, stat, q).compile()
+    text = compiled.as_text()
+    name = "flash_attention_window_bwd_dq_dkv" if window else (
+        "flash_attention_bwd_dq_dkv")
+    assert len(re.findall(rf"%[\w.\-]*{name}[\w.\-]* = ", text)) == 1
+    assert text.count("tpu_custom_call") == 1
+    # dq leaves the call in the operands' dtype: no float32 [heads, T, head]
+    # array anywhere in the program when the operands are bf16
+    if dtype == jnp.bfloat16:
+        assert f"f32[{heads},{t},{head}]" not in text
+
+
+@pytest.mark.parametrize("matched", [False, True], ids=["bf16", "float32_matched"])
+def test_flash_kernels_compile_at_head_dim_64(one_chip, no_compile_cache, matched):
+    """[1 x 32 heads, T 8192, head 64], causal: the grouped-query layer's
+    kernels after K and V are repeated to the query heads (the flash
+    kernels had only ever run heads of 128), in the timed step's bf16
+    (1024-row tiles) and in the float32 of the benchmark's matched check
+    (512: a head of 64 is padded to the 128 lanes in VMEM, and 1024-row
+    float32 tiles ask the forward kernel for 16.44 MB of its 16)."""
+    fa = importlib.import_module("raydp_tpu.ops.flash_attention")
+    dtype = jnp.float32 if matched else jnp.bfloat16
+    tile = 512 if matched else 1024
+    assert fa.pick_blocks(8192, 8192, head_dim=64,
+                          itemsize=jnp.dtype(dtype).itemsize) == (tile, tile)
+    q = jax.ShapeDtypeStruct((1, 32, 8192, 64), dtype, sharding=one_chip)
+
+    def grads(q, k, v):
+        with jax.default_matmul_precision("highest" if matched else None):
+            return jax.grad(
+                lambda q, k, v: fa.flash_attention(
+                    q, k, v, True, None, None, False
+                ).astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    text = jax.jit(grads).lower(q, q, q).compile().as_text()
+    for name in ("flash_attention_fwd", "flash_attention_bwd_dq_dkv"):
+        assert re.search(rf"%[\w.\-]*{name}[\w.\-]* = ", text), name
+    assert not re.search(BWD_DKV, text)
+
+
+@pytest.mark.parametrize("impl, kernel", [
+    ("ragged_dot", "ragged-dot"), ("megablox", "gmm")])
+def test_grouped_products_compile_at_lfm2_widths(
+        one_chip, no_compile_cache, kernels_compile, impl, kernel):
+    """[131,072 rows x 2048] x [8 experts, 2048, 3584] and its gradients
+    (the rows' and the weights'), bf16 operands, ragged groups: both
+    implementations of ``ops.experts.grouped_dot`` are Mosaic calls on the
+    chip (XLA:TPU runs ``lax.ragged_dot`` as a kernel of its own), named so
+    that ``harness/moe_costs.GMM`` finds them in a trace."""
+    from benchmark.harness import moe_costs
+    from raydp_tpu.ops import experts
+
+    x = jax.ShapeDtypeStruct((131072, 2048), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((8, 2048, 3584), jnp.float32, sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip)
+
+    def grads(x, w, sizes):
+        return jax.grad(lambda x, w: experts.grouped_dot(
+            x, w, sizes, impl).astype(jnp.float32).sum(), argnums=(0, 1))(x, w)
+
+    text = jax.jit(grads).lower(x, w, sizes).compile().as_text()
+    calls = re.findall(rf"(%[\w.\-]*{kernel}[\w.\-]*) = \S+ custom-call", text)
+    assert len([c for c in calls if "metadata" not in c]) == 2, calls
+    assert all(moe_costs.GMM.search(c) for c in calls)

@@ -1,16 +1,14 @@
 """Window layers and the ``smallthinker`` family at tiny sizes, float32, seeded
-random weights: the flash kernels' window (interpret mode) against
-``full_attention`` under the same mask, forward and all three gradients; the
+random weights (the flash kernels' window alone is
+``tests/test_flash_attention.py``'s): the
 router's two scoring rules and the experts' two activations against plain
-``jnp``; ``HybridLM.from_config`` on the catalog row's keys against the plain
-reference (benchmark/reference/smallthinker.py) in loss, logits, every
-gradient and the selection decision for decision; the four shares' expert
+``jnp`` (``HybridLM.from_config`` on the catalog row's keys against the plain
+reference in loss, logits, every gradient and the selection:
+``tests/test_windowed_routed_reference.py``); the four shares' expert
 parts against the uncut reference; the refusals of ``from_config``;
 ``fit_facts``; and a JaxEstimator fit through the normal path."""
 
-import importlib
 import json
-import math
 import os
 import sys
 
@@ -25,135 +23,10 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from benchmark.harness import window_costs  # noqa: E402
-from benchmark.reference import smallthinker as ref  # noqa: E402
-from raydp_tpu.models import (  # noqa: E402
-    HybridLM, RoutedHybridLM, hybridlm_optimizer)
+from raydp_tpu.models import HybridLM, hybridlm_optimizer  # noqa: E402
 from raydp_tpu.ops import experts  # noqa: E402
-from raydp_tpu.parallel.ring_attention import full_attention  # noqa: E402
-
-# the module: ``raydp_tpu.ops.flash_attention`` is the function
-fa = importlib.import_module("raydp_tpu.ops.flash_attention")
-
-# -- (a) the window in the flash kernels ----------------------------------------
-
-
-def _qkv(t, heads=2, group=1, d=32, seed=0):
-    keys = jax.random.split(jax.random.PRNGKey(seed + t), 4)
-    q = jax.random.normal(keys[0], (1, heads * group, t, d))
-    k, v = (jnp.repeat(jax.random.normal(key, (1, heads, t, d)), group, axis=1)
-            for key in keys[1:3])
-    return q, k, v, jax.random.normal(keys[3], q.shape)
-
-
-def _value_and_grads(attend, q, k, v, g):
-    return jax.value_and_grad(
-        lambda q, k, v: jnp.sum(attend(q, k, v) * g), (0, 1, 2))(q, k, v)
-
-
-@pytest.mark.parametrize("t, window, block_q, block_k, group", [
-    (320, 100, 64, 64, 1),   # T no multiple of W
-    (256, 16, 64, 64, 1),    # W smaller than a block
-    (256, 64, 64, 32, 1),    # q tiles wider than k tiles
-    (256, 96, 32, 64, 1),    # and narrower
-    (256, 130, 128, 128, 7),  # heads 7 to 1, W just past a block
-    (256, 255, 64, 64, 1),   # one key hidden
-])
-def test_the_window_kernels_are_full_attention_under_the_same_mask(
-        t, window, block_q, block_k, group):
-    q, k, v, g = _qkv(t, heads=1 if group > 1 else 2, group=group)
-    got, g_got = _value_and_grads(
-        lambda q, k, v: fa.flash_attention(
-            q, k, v, True, block_q, block_k, None, window), q, k, v, g)
-    want, g_want = _value_and_grads(
-        lambda q, k, v: full_attention(q, k, v, True, window), q, k, v, g)
-    assert abs(float(got - want)) <= 1e-4 * max(1.0, abs(float(want)))
-    for name, a, b in zip("qkv", g_got, g_want):
-        assert float(jnp.abs(a - b).max()) <= 2e-5, name
-    # and the window hides something: the causal call differs
-    causal = fa.flash_attention(q, k, v, True, block_q, block_k)
-    windowed = fa.flash_attention(q, k, v, True, block_q, block_k, None, window)
-    assert float(jnp.abs(causal - windowed).max()) > 1e-3
-
-
-@pytest.mark.parametrize("window", [256, 300])
-def test_a_window_of_the_whole_sequence_is_the_causal_call_bit_for_bit(window):
-    q, k, v, g = _qkv(256)
-    want, g_want = _value_and_grads(
-        lambda q, k, v: fa.flash_attention(q, k, v, True, 64, 64), q, k, v, g)
-    got, g_got = _value_and_grads(
-        lambda q, k, v: fa.flash_attention(q, k, v, True, 64, 64, None, window),
-        q, k, v, g)
-    assert float(got) == float(want)
-    for a, b in zip(g_got, g_want):
-        assert np.array_equal(np.asarray(a), np.asarray(b))
-    text = jax.jit(lambda q, k, v: fa.flash_attention(
-        q, k, v, True, 64, 64, None, window)).lower(q, k, v).as_text()
-    assert "flash_attention_window" not in text
-
-
-def test_without_a_window_the_kernels_lower_to_the_program_they_were():
-    q, k, v, g = _qkv(256)
-
-    def text(*window):
-        return jax.jit(lambda q, k, v: _value_and_grads(
-            lambda q, k, v: fa.flash_attention(q, k, v, True, 64, 64, *window),
-            q, k, v, g)).lower(q, k, v).as_text()
-
-    assert text() == text(None, None)
-    assert text(None, 100) != text()
-
-
-@pytest.mark.parametrize("t, block_q, block_k, window", [
-    (16384, 1024, 1024, 4096), (16384, 512, 512, 4096), (256, 64, 32, 64),
-    (256, 32, 64, 96), (320, 64, 64, 100), (256, 64, 64, 1)])
-def test_the_grid_follows_the_window(t, block_q, block_k, window):
-    """The inner axis has as many steps as the blocks a window can touch
-    (counted here key by key), never more than the bound from the spans,
-    and the forward call's grid says so."""
-    k_steps, q_steps = fa.window_steps(t, block_q, block_k, window)
-    rows = np.arange(t)
-    seen = (rows[:, None] >= rows[None, :]) & (
-        rows[:, None] - rows[None, :] < window)
-    blocks = seen.reshape(t // block_q, block_q, t // block_k, block_k).any(
-        axis=(1, 3))
-    assert k_steps == blocks.sum(axis=1).max()
-    assert q_steps == blocks.sum(axis=0).max()
-    assert k_steps <= math.ceil((window + block_q - 1) / block_k) + 1
-    # the first live block is where the index maps start
-    for i in range(t // block_q):
-        assert int(fa._first_k_block(i, block_q, block_k, window)) == int(
-            np.argmax(blocks[i]))
-    for j in range(t // block_k):
-        assert fa._first_q_block(j, block_q, block_k) == int(
-            np.argmax(blocks[:, j]))
-    if t <= 320:
-        q = jnp.zeros((1, 1, t, 32))
-        jaxpr = str(jax.make_jaxpr(lambda q: fa.flash_attention(
-            q, q, q, True, block_q, block_k, None, window))(q))
-        assert f"grid=(1, {t // block_q}, {k_steps})" in jaxpr.replace(
-            "\n", ""), jaxpr[:2000]
-
-
-def test_a_window_is_refused_where_the_kernels_do_not_build_it():
-    q, k, v, _ = _qkv(128)
-    with pytest.raises(ValueError, match="causal self-attention"):
-        fa.flash_attention(q, k, v, False, 64, 64, None, 32)
-    with pytest.raises(ValueError, match="causal self-attention"):
-        fa.flash_attention(q[:, :, :64], k, v, True, 64, 64, None, 32)
-    with pytest.raises(ValueError, match="causal"):
-        full_attention(q, k, v, False, 32)
-    # a ring's step hands the backward blocks their offsets: the window's
-    # grids count from position 0 and would skip live blocks
-    stats = jnp.zeros(q.shape[:3], jnp.float32)
-    for offsets in ((64, 0), (0, 64), (jnp.int32(0), 0)):
-        with pytest.raises(ValueError, match="static 0"):
-            fa.flash_backward_blocks(q, k, v, stats, stats, q, *offsets,
-                                     True, 64, 64, None, 32)
-    from raydp_tpu.models.transformer import _attend
-
-    with pytest.raises(ValueError, match="builds no window"):
-        _attend(q, k, v, impl="ring", axis="sp", causal=True, window=32)
-
+from windowed_routed_model import (  # noqa: E402, F401 - fixtures by name
+    CFG, CONFIG, T, V, W, batch, gaps, model, objective, params, ref)
 
 # -- (d) the router's two rules, the experts' two activations ----------------------
 
@@ -268,124 +141,6 @@ def test_reglu_is_relu_of_the_gate_times_up(layer):
     np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-6)
 
 
-# -- (b) the model against the plain reference ------------------------------------
-
-V, T, W = 256, 40, 8
-LAYOUT = [0, 1, 1, 1] * 3
-# the catalog row's keys at tiny widths: published layers 4-7 (the second
-# period), experts 4-7 of 16
-CONFIG = {
-    "model_name": "smallthinker_tiny", "model_type": "smallthinker",
-    "head_dim": 16, "hidden_size": 48,
-    "max_position_embeddings": 64, "moe_ffn_hidden_size": 24,
-    "moe_num_active_primary_experts": 3, "moe_num_primary_experts": 4,
-    "moe_primary_router_apply_softmax": True, "norm_topk_prob": True,
-    "num_attention_heads": 4, "num_hidden_layers": 4,
-    "num_key_value_heads": 2, "rms_norm_eps": 1e-6, "rope_layout": LAYOUT,
-    "rope_scaling": None, "rope_theta": 1500000,
-    "sliding_window_layout": LAYOUT, "sliding_window_size": W,
-    "tie_word_embeddings": False, "vocab_size": V,
-    "share": {"first_layer": 4, "experts_total": 16, "first_expert": 4}}
-CFG = ref.config_of(CONFIG)
-
-
-def model(config=CONFIG, **kw):
-    return RoutedHybridLM.from_config(
-        config, **{"dtype": jnp.float32, "loss_chunk": 16, **kw})
-
-
-@pytest.fixture(scope="module")
-def batch():
-    return jax.random.randint(jax.random.PRNGKey(0), (2, T + 1), 0, V)
-
-
-@pytest.fixture(scope="module")
-def params(batch):
-    return model().init(jax.random.PRNGKey(1), batch, None, method="loss")
-
-
-def _objective(module, params, batch):
-    with jax.default_matmul_precision("highest"):
-        return jax.value_and_grad(
-            lambda p: module.apply(p, batch, None, True, method="loss"),
-            has_aux=True)(params)
-
-
-def _gaps(got, want):
-    """(loss, hidden, worst relative gradient gap, decisions that differ)."""
-    (loss, aux), grads = got
-    ref_loss, ref_aux, ref_grads = want
-    gap = max(float(jnp.linalg.norm(a - b) / (jnp.linalg.norm(b) + 1e-30))
-              for a, b in zip(jax.tree.leaves(grads),
-                              jax.tree.leaves(ref_grads)))
-    differ = (np.sort(aux["routing"], -1)
-              != np.sort(ref_aux["selection"], -1)).any(-1).sum()
-    return (abs(float(loss) - float(ref_loss)),
-            float(jnp.abs(aux["hidden"] - ref_aux["hidden"]).max()), gap,
-            int(differ))
-
-
-@pytest.fixture(scope="module")
-def want(params, batch):
-    return ref.loss_and_grads(params, batch, CFG, with_states=True)
-
-
-@pytest.mark.parametrize("attn_impl, remat", [
-    ("full", False), ("flash", True), ("flash", False)])
-def test_system_against_the_reference(params, batch, want, attn_impl, remat):
-    module = model(attn_impl=attn_impl, remat=remat)
-    got = _objective(module, params, batch)
-    loss, hidden, grads, differ = _gaps(got, want)
-    assert loss <= 2e-6 and hidden <= 2e-5 and grads <= 2e-5, (
-        loss, hidden, grads)
-    assert differ == 0  # decision for decision
-    logits = module.apply(params, got[0][1]["hidden"], method="head")
-    np.testing.assert_allclose(
-        logits, ref.logits_of(params, want[1]["hidden"], CFG), atol=2e-5)
-    np.testing.assert_allclose(
-        module.apply(params, batch[:, :-1]),
-        ref.forward(params, batch[:, :-1], CFG), atol=2e-5)
-
-
-def test_the_reference_takes_the_routing_it_is_given(params, batch, want):
-    forced = jnp.flip(want[1]["selection"], axis=-1)  # the same sets
-    loss, aux, _ = ref.loss_and_grads(params, batch, CFG, routing=forced)
-    assert abs(float(loss) - float(want[0])) <= 1e-6
-    other = (want[1]["selection"] + 1) % 16
-    assert abs(float(ref.loss_and_grads(
-        params, batch, CFG, routing=other)[0]) - float(want[0])) > 1e-6
-    assert np.array_equal(aux["selection"], want[1]["selection"])
-    assert float(aux["margin"].min()) >= 0
-
-
-MUTATIONS = {
-    "no window": dict(attention_windows=()),
-    "a window one key narrower": dict(attention_windows=(0, W - 1, W - 1, W - 1)),
-    "the global layer windowed": dict(attention_windows=(W,) * 4),
-    "RoPE on the global layer": dict(rope_layers=()),
-    "no RoPE at all": dict(rope_layers=(0,) * 4),
-    "the router fed from the FFN's input": dict(router_input="ffn"),
-    "silu for relu": dict(expert_activation="silu"),
-    "the next share's experts": dict(first_expert=8),
-}
-
-
-@pytest.mark.parametrize("mutation", MUTATIONS)
-def test_a_mutation_fails_the_comparison(params, batch, want, mutation):
-    module = model(attn_impl="flash").clone(**MUTATIONS[mutation])
-    loss, hidden, grads, _ = _gaps(_objective(module, params, batch), want)
-    assert max(loss, hidden, grads) > 1e-3, (loss, hidden, grads)
-
-
-def test_the_sigmoid_rule_in_this_models_place_fails_the_comparison(
-        batch, want):
-    module = model(attn_impl="flash").clone(expert_scoring="sigmoid")
-    other = module.init(jax.random.PRNGKey(1), batch, None, method="loss")
-    assert "expert_bias" in other["params"]["layer_0"]
-    (_, aux), _ = _objective(module, other, batch)
-    assert aux["routing"].shape == want[1]["selection"].shape
-
-
 # -- (c) THE SHARE TEST -------------------------------------------------------------
 
 
@@ -460,10 +215,10 @@ def test_rope_and_window_are_independent_keys(batch):
     assert module.layer_ropes == (True, False, False, True)
     assert module.layer_windows == (0, W, W, W)
     p = module.init(jax.random.PRNGKey(1), batch, None, method="loss")
-    got = _objective(module.clone(attn_impl="flash"), p, batch)
+    got = objective(module.clone(attn_impl="flash"), p, batch)
     want = ref.loss_and_grads(p, batch, ref.config_of(config),
                               with_states=True)
-    assert max(_gaps(got, want)[:3]) <= 2e-5
+    assert max(gaps(got, want)[:3]) <= 2e-5
 
 
 @pytest.mark.parametrize("change, match", [
@@ -675,7 +430,7 @@ def test_the_balancing_rule_with_no_bias_to_move_says_so_and_does_not_fail(
     with caplog.at_level(logging.WARNING):
         state = tx.init(params)
     assert "no expert_bias" in caplog.text
-    (_, _), grads = _objective(model(), params, batch)
+    (_, _), grads = objective(model(), params, batch)
     updates, _ = tx.update(grads, state, params)
     plain = hybridlm_optimizer(warmup_steps=4)
     want, _ = plain.update(grads, plain.init(params), params)
