@@ -2,7 +2,8 @@
 
 from raydp_tpu.models.dlrm import DLRM, dlrm_optimizer, dlrm_sharding_rules
 from raydp_tpu.models.hybridlm import (
-    DeltaHybridLM, HybridLM, RoutedHybridLM, hybridlm_optimizer)
+    DeltaHybridLM, HybridLM, LatentDeltaHybridLM, RoutedHybridLM,
+    hybridlm_optimizer)
 from raydp_tpu.models.looplm import LoopLM, looplm_optimizer
 from raydp_tpu.models.mlp import MLPClassifier, MLPRegressor
 from raydp_tpu.models.transformer import TransformerLM, sequence_parallel_apply
@@ -11,6 +12,7 @@ __all__ = [
     "DLRM",
     "DeltaHybridLM",
     "HybridLM",
+    "LatentDeltaHybridLM",
     "LoopLM",
     "MLPClassifier",
     "MLPRegressor",

@@ -1,6 +1,7 @@
 """Hybrid decoder LM: a MIXER KIND (``mamba``, ``attention``, ``conv``,
-``delta``) and an FFN KIND (``dense``, ``experts``) per layer, in the order
-``layer_types`` and ``ffn_types`` give. Four published families are built from
+``delta``, ``kda``, ``mla``) and an FFN KIND (``dense``, ``experts``) per
+layer, in the order ``layer_types`` and ``ffn_types`` give. Five published
+families are built from
 their ``config.json`` (``from_config`` reads ``model_type``): ``granitemoehybrid``
 with no experts (Mamba-2 and grouped-query attention without positions, a
 dense SwiGLU after each, four multipliers), ``lfm2_moe`` (gated short
@@ -15,7 +16,10 @@ delta-rule linear-attention layers, ``linear_attention`` in its
 ``layer_types``, beside full-attention layers without positions whose q/k
 norms run over the whole projection; a dense SwiGLU in every layer; a
 sub-layer's OUTPUT is normed; this chip holds a share of every mixer's HEADS;
-an untied head).
+an untied head) and ``bailing_hybrid`` (``layer_group_size`` - 1 ``kda``
+layers to one ``mla`` layer, both under a head-wise sigmoid output gate;
+leading dense SwiGLUs, then routed experts under a GROUP-LIMITED selection
+beside a SHARED expert every token passes; an untied head).
 
 ::
 
@@ -110,6 +114,36 @@ is the columns of W_q, W_k, W_v, W_g, W_a, W_b, the convolution's channels,
 other heads would add through their rows of W_o is left out (one chip of a
 group that divides the heads, without its all-reduce).
 
+``kda`` (Kimi Delta Attention, arXiv:2510.26692: the delta rule with a decay
+A CHANNEL of the key; H heads, keys and values of ``delta_key_dim`` /
+``delta_value_dim``)::
+
+    q, k, v as ``delta``'s (convolution, silu, l2 norms, q / sqrt(Dk))
+    beta_t = sigmoid(W_b a)_t                                 # beta in (0, 1)
+    log alpha_t = kda_decay_floor sigmoid(exp(A_log) ((W_f a)_t + dt_bias))
+                                          # a VECTOR [Dk] a head, in (floor, 0)
+    S_t = S_{t-1} Diag(alpha_t) (I - beta_t k_t k_t^T) + beta_t v_t k_t^T
+    out = W_o [ RMSNorm_head(o_t; gate_norm [Dv]) * sigmoid(W_g a)_t[head] ]
+
+``A_log`` a head, ``dt_bias`` a channel, seeded as ``delta``'s; the recurrence
+is ``ops.delta_rule.channel_gated_delta_rule`` under the same scopes
+(``hybridlm.delta`` around ``delta_rule``). No positions.
+
+``mla`` (latent attention, DeepSeek-V2, arXiv:2405.04434, its TRAINING side:
+multi-head attention whose keys are wider than its values; the cache's
+latent form is the serving side and is not built)::
+
+    q = W_q a = [q_nope | q_rope] a head          (head_dim = nope + rope_head_dim)
+    [c | k_rope] = W_kva a                        (latent_rank | rope_head_dim)
+    [k_nope | v] = W_kvb RMSNorm(c; kv_norm)      (nope | value_head_dim a head)
+    k = [k_nope | RoPE(k_rope)], the ONE k_rope shared by all the heads;
+    q = [q_nope | RoPE(q_rope)]; interleaved pairs (the program turns the
+    pairs apart and rotates halves: q.k is the same)
+    out = W_o [ softmax_causal(q.k / sqrt(head_dim)) v * sigmoid(W_g a)[head] ]
+
+scope ``hybridlm.attention`` with ``.latent`` around the two latent products;
+the flash kernels take v, o and do at ``value_head_dim``.
+
 ``mamba`` (Mamba-2; H heads of P, one group, state N)::
 
     [z | xBC | dt] = W_in u
@@ -159,6 +193,7 @@ from raydp_tpu.ops.flash_attention import SAVED_RESIDUALS
 from raydp_tpu.ops.ssd import ssd_chunk_scan
 
 MAMBA, ATTENTION, CONV, DELTA = "mamba", "attention", "conv", "delta"
+KDA, MLA = "kda", "mla"
 DENSE, EXPERTS = "dense", "experts"
 # what a recomputed block keeps from its forward pass: the flash kernel's
 # output and log-sum-exp (an attention layer; with both kept the recomputed
@@ -178,6 +213,12 @@ REMAT_KEEPS = SAVED_RESIDUALS + ("mlp_out",)
 # grouped product's output (294 MB a layer at the likely bound of 40,960
 # rows, 940 MB at the worst case's 131,072) is recomputed
 EXPERT_KEEPS = (experts_op.KEPT,)
+# a ``kda`` layer keeps its scan's result (67 MB a layer at 8192 tokens x 32
+# heads of 128): its scan works a group of heads at a time, each group's pass
+# recomputed in its own backward pass (``ops.delta_rule.HEADS_AT_ONCE``), so
+# with ``o`` kept the block's recomputation has no use for a second forward
+# scan and the scan runs twice a step, not three times
+KDA_KEEPS = (delta_rule.SAVED_OUTPUT,)
 
 
 def _inverse_softplus(x):
@@ -245,6 +286,15 @@ class HybridLM(nn.Module):
     delta_conv: int = 4  # taps of the depthwise convolution on q, k and v
     norm_placement: str = "pre"  # or "post": a sub-layer's OUTPUT is normed
     qk_norm_over: str = "head"  # or "projection": all the held heads' width
+    # -- what the fifth family adds; the defaults build the first four -------
+    kda_decay_floor: float = -5.0  # a kda channel's log-decay lies above it
+    latent_rank: int = 0  # mla: K and V come up from a normed latent this wide
+    rope_head_dim: int = 0  # mla: of head_dim, the part that carries RoPE
+    value_head_dim: int = 0  # mla: v's and o's width; head_dim is q's and k's
+    shared_experts: int = 0  # experts every token passes, beside the routed
+    expert_groups: int = 0  # group-limited selection: the experts' groups
+    expert_groups_kept: int = 0  # and how many a token's choice may lie in
+    expert_weight_eps: float = 1e-6  # beside the selected scores' sum
 
     # what ``loss`` reports of a TRAINING step beside its loss, by name in
     # its ``aux``: the estimator sums these over an epoch's steps inside the
@@ -263,9 +313,9 @@ class HybridLM(nn.Module):
     @classmethod
     def from_config(cls, config: dict, **kw):
         """From a published ``config.json``'s keys, by ``model_type``
-        (``granitemoehybrid``, the default, ``lfm2_moe``, ``smallthinker``
-        or ``olmo_hybrid``). What the model does not build is refused, not
-        ignored."""
+        (``granitemoehybrid``, the default, ``lfm2_moe``, ``smallthinker``,
+        ``olmo_hybrid`` or ``bailing_hybrid``). What the model does not build
+        is refused, not ignored."""
         family = config.get("model_type", "granitemoehybrid")
         if family == "granitemoehybrid":
             fields = cls._granite_fields(config)
@@ -275,10 +325,12 @@ class HybridLM(nn.Module):
             fields = cls._smallthinker_fields(config)
         elif family == "olmo_hybrid":
             fields = cls._olmo_hybrid_fields(config)
+        elif family == "bailing_hybrid":
+            fields = cls._bailing_hybrid_fields(config)
         else:
             raise ValueError(f"HybridLM builds model_type granitemoehybrid, "
-                             f"lfm2_moe, smallthinker and olmo_hybrid, not "
-                             f"{family!r}")
+                             f"lfm2_moe, smallthinker, olmo_hybrid and "
+                             f"bailing_hybrid, not {family!r}")
         fields.update(kw)  # attn_impl, dtype, remat, loss_chunk; overrides
         fields["dtype"] = jnp.dtype(fields.get("dtype", cls.dtype))
         return cls(**fields)
@@ -301,8 +353,9 @@ class HybridLM(nn.Module):
             "position_embedding_type": "nope", "hidden_act": "silu"},
             {"num_local_experts": (
                 ": this family's experts are a shared expert beside routed "
-                "ones, and a shared expert is not built; the experts FFN "
-                "kind here is lfm2_moe's and smallthinker's")})
+                "ones, which from_config builds for bailing_hybrid alone; the "
+                "experts FFN kind is lfm2_moe's, smallthinker's and that "
+                "family's")})
         if config["mamba_expand"] * config["hidden_size"] != (
                 config["mamba_n_heads"] * config["mamba_d_head"]):
             raise ValueError("mamba_expand x hidden_size is not "
@@ -479,7 +532,93 @@ class HybridLM(nn.Module):
             attention_multiplier=head_dim ** -0.5, logits_scaling=1.0,
             rms_eps=float(config["rms_norm_eps"]))
 
+    @classmethod
+    def _bailing_hybrid_fields(cls, config: dict) -> dict:
+        """``num_hidden_layers`` layers from ``share["first_layer"]`` on (a
+        pipeline stage's): published layer l (from 0) is ``mla`` where
+        ``(l + 1) % layer_group_size`` is 0 and ``kda`` elsewhere; the first
+        ``first_k_dense_replace`` of the layers built carry a dense SwiGLU of
+        ``intermediate_size``, the others ``num_experts`` held experts,
+        ``share["first_expert"]`` the first, of the
+        ``share["experts_total"]`` the router scores (both default to the
+        whole), beside ``num_shared_experts`` shared ones. The selection is
+        group-limited (``n_group``, ``topk_group``). Refused: a
+        multi-token-prediction layer whose loss counts, a SwiGLU clamp in a
+        layer built, nGPT, a value norm, low-rank KDA gates, a shared expert
+        of another width than the routed ones."""
+        cls._refuse(config, {
+            "moe_shared_expert_intermediate_size":
+                config["moe_intermediate_size"],
+            "use_nGPT": False, "value_norm": False, "up_proj_norm": False,
+            "use_bias": False, "use_qkv_bias": False,
+            "tie_word_embeddings": False, "q_lora_rank": None,
+            "rope_scaling": None, "score_function": "sigmoid",
+            "topk_method": "noaux_tc", "norm_topk_prob": True,
+            "moe_router_enable_expert_bias": True, "hidden_act": "silu",
+            "kda_safe_gate": True, "no_kda_lora": True, "use_kda_lora": False,
+            "linear_silu": True, "use_qk_norm": True, "group_norm_size": 1,
+            "gated_attention_proj_granularity_type": "head_wise",
+            "rope_interleave": True, "scale_router_input": False,
+            "num_kv_heads_for_linear_attn": 0, "use_mla_nope": False})
+        if config.get("num_nextn_predict_layers") and config.get(
+                "mtp_loss_scaling_factor"):
+            raise ValueError(
+                "HybridLM builds no multi-token-prediction layer: "
+                f"mtp_loss_scaling_factor {config['mtp_loss_scaling_factor']!r}"
+                " gives its loss a weight (at 0 it takes no gradient and is "
+                "left out)")
+        share = config.get("share", {})
+        first, depth = share.get("first_layer", 0), config["num_hidden_layers"]
+        for key in ("expert_swiglu_limit_list",
+                    "share_expert_swiglu_limit_list"):
+            if any(config.get(key, ())[first:first + depth]):
+                raise ValueError(
+                    f"HybridLM builds no SwiGLU clamp: {key} is not 0 in "
+                    f"layers {first}..{first + depth - 1}")
+        period = config["layer_group_size"]
+        dense = config["first_k_dense_replace"]
+        nope, rope = config["qk_nope_head_dim"], config["qk_rope_head_dim"]
+        held = config["num_experts"]
+        return dict(
+            vocab_size=config["vocab_size"],
+            layer_types=tuple(MLA if (layer + 1) % period == 0 else KDA
+                              for layer in range(first, first + depth)),
+            ffn_types=(DENSE,) * dense + (EXPERTS,) * (depth - dense),
+            hidden_size=config["hidden_size"],
+            num_heads=config["num_attention_heads"],
+            num_kv_heads=config["num_key_value_heads"],
+            head_dim=nope + rope, rope_head_dim=rope,
+            value_head_dim=config["v_head_dim"],
+            latent_rank=config["kv_lora_rank"],
+            rope_theta=float(config["rope_theta"]),
+            intermediate_size=config["intermediate_size"],
+            delta_heads=config["num_attention_heads"],
+            delta_heads_total=config["num_attention_heads"],
+            delta_key_dim=config["head_dim"],
+            delta_value_dim=config["head_dim"],
+            delta_conv=config["short_conv_kernel_size"],
+            kda_decay_floor=float(config["kda_lower_bound"]),
+            expert_width=config["moe_intermediate_size"],
+            experts_held=held,
+            experts_total=share.get("experts_total", held),
+            first_expert=share.get("first_expert", 0),
+            experts_per_token=config["num_experts_per_tok"],
+            routed_scaling=float(config["routed_scaling_factor"]),
+            shared_experts=config["num_shared_experts"],
+            expert_groups=config["n_group"],
+            expert_groups_kept=config["topk_group"],
+            expert_weight_eps=1e-20, tied_head=False,
+            embedding_multiplier=1.0, residual_multiplier=1.0,
+            attention_multiplier=(nope + rope) ** -0.5, logits_scaling=1.0,
+            rms_eps=float(config["rms_norm_eps"]))
+
     # -- shapes ----------------------------------------------------------------
+    @property
+    def value_width(self) -> int:
+        """An attention head's v and o: ``head_dim`` but in an ``mla``
+        layer."""
+        return self.value_head_dim or self.head_dim
+
     @property
     def attention_width(self) -> int:
         """Query heads x head_dim: what ``wq`` gives and ``wo`` takes."""
@@ -505,7 +644,7 @@ class HybridLM(nn.Module):
             return w * (w + 1) // 2 + (t - w) * w
 
         return sum(pairs(window) for kind, window in zip(
-            self.layer_types, self.layer_windows) if kind == ATTENTION)
+            self.layer_types, self.layer_windows) if kind in (ATTENTION, MLA))
 
     @property
     def mamba_inner(self) -> int:
@@ -529,6 +668,9 @@ class HybridLM(nn.Module):
             after = {"router": (d, self.experts_total),
                      "w13": (d, 2 * self.expert_width),
                      "w2": (self.expert_width, d)}
+            if self.shared_experts:  # side by side, as one SwiGLU
+                wide = self.shared_experts * self.expert_width
+                after.update(shared_in=(d, 2 * wide), shared_out=(wide, d))
         if kind == ATTENTION:
             kv, wide = self.num_kv_heads * self.head_dim, self.attention_width
             return {"wq": (d, wide), "wk": (d, kv), "wv": (d, kv),
@@ -541,6 +683,20 @@ class HybridLM(nn.Module):
             return {"wq": (d, keys), "wk": (d, keys), "wv": (d, values),
                     "wg": (d, values), "wa": (d, heads), "wb": (d, heads),
                     "wo": (values, d), **after}
+        if kind == KDA:
+            heads = self.delta_heads
+            keys, values = heads * self.delta_key_dim, heads * self.delta_value_dim
+            return {"wq": (d, keys), "wk": (d, keys), "wv": (d, values),
+                    "wf": (d, keys), "wb": (d, heads), "wg": (d, heads),
+                    "wo": (values, d), **after}
+        if kind == MLA:
+            heads, nope = self.num_heads, self.head_dim - self.rope_head_dim
+            return {"wq": (d, self.attention_width),
+                    "wkva": (d, self.latent_rank + self.rope_head_dim),
+                    "wkvb": (self.latent_rank,
+                             heads * (nope + self.value_width)),
+                    "wg": (d, heads), "wo": (heads * self.value_width, d),
+                    **after}
         return {"in_proj": (d, 2 * inner + 2 * self.mamba_state
                             + self.mamba_heads),
                 "out_proj": (inner, d), **after}
@@ -557,7 +713,8 @@ class HybridLM(nn.Module):
     def expert_likely_row_bound(self, tokens: int) -> int:
         """Rows the layer runs at wherever the load fits them
         (``ops.experts.likely_row_bound``: ``SLACK`` x the even share of
-        the held experts); the worst case where every expert is held."""
+        the held experts, its margin wider under a quarter share); the worst
+        case where every expert is held."""
         return experts_op.likely_row_bound(
             tokens * self.experts_per_token, self.experts_held,
             self.experts_total)
@@ -566,10 +723,19 @@ class HybridLM(nn.Module):
         d = self.hidden_size
         kinds, ffns = self.layer_types, self.ffn_kinds
         for kind in kinds:
-            if kind not in (MAMBA, ATTENTION, CONV, DELTA):
+            if kind not in (MAMBA, ATTENTION, CONV, DELTA, KDA, MLA):
                 raise ValueError(f"layer kind {kind!r} is none of {MAMBA!r}, "
-                                 f"{ATTENTION!r}, {CONV!r}, {DELTA!r}")
-        if DELTA in kinds and not (
+                                 f"{ATTENTION!r}, {CONV!r}, {DELTA!r}, "
+                                 f"{KDA!r}, {MLA!r}")
+        if MLA in kinds and not (
+                0 < self.rope_head_dim < self.head_dim
+                and self.rope_head_dim % 2 == 0 and self.latent_rank > 0
+                and self.rope_theta > 0):
+            raise ValueError(
+                f"a latent of {self.latent_rank}, RoPE (theta "
+                f"{self.rope_theta}) on {self.rope_head_dim} of a key's "
+                f"{self.head_dim}: not a latent-attention layer")
+        if (DELTA in kinds or KDA in kinds) and not (
                 0 < self.delta_heads <= (self.delta_heads_total
                                          or self.delta_heads)
                 and self.delta_key_dim > 0 and self.delta_value_dim > 0):
@@ -643,6 +809,12 @@ class HybridLM(nn.Module):
                         -bound, bound)
                 elif kind == DELTA:
                     out.update(self._delta_vectors(keys[-3:]))
+                elif kind == KDA:
+                    out.update(self._delta_vectors(
+                        keys[-3:], self.delta_heads * self.delta_key_dim))
+                elif kind == MLA:
+                    out["kv_norm"] = jnp.ones((self.latent_rank,),
+                                              jnp.float32)
                 elif self.qk_norm:
                     whole = self.qk_norm_over == "projection"
                     out.update(
@@ -674,14 +846,17 @@ class HybridLM(nn.Module):
                                      jnp.float32)
 
     @staticmethod
-    def _decay_vectors(keys, heads: int, taps: int, channels: int) -> dict:
+    def _decay_vectors(keys, heads: int, taps: int, channels: int,
+                       biases: int = 0) -> dict:
         """What a mixer with a decay a head and a depthwise convolution
         seeds, by Mamba-2's published initialisation: dt log-uniform in
-        [0.001, 0.1] through the inverse softplus into ``dt_bias``,
-        ``A_log`` the log of U(1, 16); the convolution as torch initialises
-        a depthwise ``Conv1d`` (uniform in +-1/sqrt(k))."""
+        [0.001, 0.1] through the inverse softplus into ``dt_bias`` (one a
+        head, or ``biases`` of them: a decay a channel), ``A_log`` the log
+        of U(1, 16); the convolution as torch initialises a depthwise
+        ``Conv1d`` (uniform in +-1/sqrt(k))."""
         dt = jnp.exp(jax.random.uniform(
-            keys[0], (heads,), jnp.float32, math.log(1e-3), math.log(1e-1)))
+            keys[0], (biases or heads,), jnp.float32, math.log(1e-3),
+            math.log(1e-1)))
         bound = taps ** -0.5
         return {
             "conv_w": jax.random.uniform(keys[2], (taps, channels),
@@ -703,14 +878,15 @@ class HybridLM(nn.Module):
             "gate_norm": jnp.ones((self.mamba_inner,), jnp.float32),
         }
 
-    def _delta_vectors(self, keys) -> dict:
+    def _delta_vectors(self, keys, biases: int = 0) -> dict:
         """``_decay_vectors`` over the q | k | v channels (no bias) and the
         read-out norm's gain over a value head."""
         heads = self.delta_heads
         return {
             **self._decay_vectors(
                 keys, heads, self.delta_conv,
-                heads * (2 * self.delta_key_dim + self.delta_value_dim)),
+                heads * (2 * self.delta_key_dim + self.delta_value_dim),
+                biases),
             "gate_norm": jnp.ones((self.delta_value_dim,), jnp.float32),
         }
 
@@ -759,10 +935,19 @@ class HybridLM(nn.Module):
                         self.experts_per_token),
                 "experts.flops_per_row": parts["experts"],
                 "experts.flops_counted": "uniform share"})
-        delta = self.layer_types.count(DELTA)
+            if self.shared_experts:
+                facts["experts.shared"] = self.shared_experts
+            if self.expert_groups:
+                facts.update({"experts.groups": self.expert_groups,
+                              "experts.groups_kept": self.expert_groups_kept})
+        delta = self.layer_types.count(DELTA) + self.layer_types.count(KDA)
         if delta:
+            by_kind = {kind: self.layer_types.count(kind)
+                       for kind in (DELTA, KDA) if kind in self.layer_types}
             facts.update({
-                "layer_kinds.delta": delta,
+                **{f"layer_kinds.{kind}": n for kind, n in by_kind.items()},
+                "delta.decay": ",".join(
+                    {DELTA: "head", KDA: "channel"}[kind] for kind in by_kind),
                 "delta.heads_held": self.delta_heads,
                 "delta.heads_total": self.delta_heads_total or self.delta_heads,
                 "delta.chunk": min(delta_rule.CHUNK, t),
@@ -774,10 +959,17 @@ class HybridLM(nn.Module):
         attention = [w for kind, w in zip(self.layer_types, self.layer_windows)
                      if kind == ATTENTION]
         kinds = {"global": attention.count(0),
-                 "window": sum(1 for w in attention if w)}
+                 "window": sum(1 for w in attention if w),
+                 "latent": self.layer_types.count(MLA)}
         facts.update(attention_backward_facts(
             self.attn_impl, t, self.head_dim, self.dtype,
-            {kind: n for kind, n in kinds.items() if n}))
+            {kind: n for kind, n in kinds.items() if n}, self.value_width))
+        if kinds["latent"]:
+            facts.update({
+                "layer_kinds.mla": kinds["latent"],
+                "attention.latent_rank": self.latent_rank,
+                "attention.key_width": self.head_dim,
+                "attention.value_width": self.value_width})
         if any(self.layer_windows):
             facts.update({
                 "layer_kinds.window": kinds["window"],
@@ -806,7 +998,7 @@ class HybridLM(nn.Module):
             a * b for kind, ffn in zip(self.layer_types, self.ffn_kinds)
             for name, (a, b) in self.matrix_shapes(kind, ffn).items()
             if name not in ("w13", "w2"))
-        delta = self.layer_types.count(DELTA)
+        delta = self.layer_types.count(DELTA) + self.layer_types.count(KDA)
         conv = (mamba * self.mamba_conv * (self.mamba_inner + 2 * n)
                 + self.layer_types.count(CONV) * self.conv_kernel * d
                 + delta * self.delta_conv * self.delta_heads
@@ -818,7 +1010,9 @@ class HybridLM(nn.Module):
         parts = {
             "layers": 6 * (matrices + conv) * t,
             "scan": 3 * scan,
-            "attention": 12 * self.attention_width * self.attention_pairs(t),
+            # q.k over head_dim and p.v over the values' width, a kept pair
+            "attention": 6 * self.num_heads * (
+                self.head_dim + self.value_width) * self.attention_pairs(t),
             "head": 6 * d * self.vocab_size * t}
         if delta:
             # what the RECURRENCE needs, whatever implements it
@@ -841,8 +1035,10 @@ class HybridLM(nn.Module):
             return {}
         itemsize = jnp.dtype(self.dtype).itemsize
         wide = t * self.hidden_size * itemsize
-        attention = self.layer_types.count(ATTENTION)
-        sizes = {"attn_out": attention * t * self.attention_width * itemsize,
+        attention = (self.layer_types.count(ATTENTION)
+                     + self.layer_types.count(MLA))
+        sizes = {"attn_out": attention * t * self.num_heads
+                 * self.value_width * itemsize,
                  "attn_lse": attention * 4 * self.num_heads * t,
                  "mlp_out": len(self.layer_types) * wide}
         flash = self.attn_impl in ("flash", "ulysses_flash")
@@ -854,6 +1050,10 @@ class HybridLM(nn.Module):
             # order by row and by pair; of a token its first place there
             kept[experts_op.KEPT] = self.expert_layers * 4 * (
                 5 * t * self.experts_per_token + t)
+        if KDA in self.layer_types:
+            kept[delta_rule.SAVED_OUTPUT] = (
+                self.layer_types.count(KDA) * t * self.delta_heads
+                * self.delta_value_dim * itemsize)
         return kept
 
     def epoch_facts(self, report: dict, steps: int) -> dict:
@@ -963,6 +1163,90 @@ class HybridLM(nn.Module):
         """x / sqrt(sum x^2 + 1e-6) over a head's width, float32."""
         return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
 
+    def _delta_qkv(self, w, a):
+        """(q, k [B, T, H, Dk], v [B, T, H, Dv]) of a delta-rule mixer,
+        float32: through the depthwise causal convolution and its silu, q
+        and k l2-normed a head, q scaled by Dk ** -0.5."""
+        b, t, _ = a.shape
+        heads, dk, dv = (self.delta_heads, self.delta_key_dim,
+                         self.delta_value_dim)
+        qkv = jnp.concatenate(
+            [self._dot(a, w[name]) for name in ("wq", "wk", "wv")],
+            axis=-1).astype(jnp.float32)
+        qkv = self._delta_act(_depthwise_causal(qkv, w["conv_w"]), "conv")
+        q, k, v = jnp.split(qkv, [heads * dk, 2 * heads * dk], axis=-1)
+        q = self._delta_l2(q.reshape(b, t, heads, dk)) * dk ** -0.5
+        k = self._delta_l2(k.reshape(b, t, heads, dk))
+        return q, k, v.reshape(b, t, heads, dv)
+
+    def _kda(self, w, a):
+        """The ``kda`` mixer on ``a`` [B, T, D]: ``_delta``'s q, k and v,
+        ``beta`` in (0, 1), a log-decay A CHANNEL bounded below
+        (``kda_decay_floor`` x a sigmoid), ``ops.delta_rule.
+        channel_gated_delta_rule``, the read-out normed a head and gated by
+        ONE sigmoid a head before ``W_o``."""
+        with obs.device_scope("hybridlm.delta"):
+            b, t, _ = a.shape
+            heads, dk, dv = (self.delta_heads, self.delta_key_dim,
+                             self.delta_value_dim)
+            f32 = jnp.float32
+            q, k, v = self._delta_qkv(w, a)
+            beta = jax.nn.sigmoid(self._dot(a, w["wb"]).astype(f32))
+            log_alpha = self.kda_decay_floor * jax.nn.sigmoid(
+                jnp.exp(w["A_log"])[:, None] * (
+                    self._dot(a, w["wf"]).astype(f32) + w["dt_bias"]
+                ).reshape(b, t, heads, dk))
+            o = delta_rule.channel_gated_delta_rule(
+                q.astype(self.dtype), k.astype(self.dtype),
+                v.astype(self.dtype), log_alpha, beta)
+            gate = jax.nn.sigmoid(self._dot(a, w["wg"]).astype(f32))
+            o = rms_norm(o.astype(f32), w["gate_norm"], self.rms_eps)
+            return self._dot(
+                (o * gate[..., None]).reshape(b, t, heads * dv).astype(
+                    self.dtype), w["wo"])
+
+    def _latent_attention(self, w, y):
+        """The ``mla`` mixer's training side on ``y`` [B, T, D]: K's
+        position-free part and V come up from one normed latent, every head
+        shares the one RoPE key, keys of ``head_dim`` stand over values of
+        ``value_head_dim``; one sigmoid a head gates the read-out."""
+        with obs.device_scope("hybridlm.attention"):
+            b, t, _ = y.shape
+            heads, rope, dv = self.num_heads, self.rope_head_dim, self.value_width
+            nope = self.head_dim - rope
+            q = self._dot(y, w["wq"]).reshape(
+                b, t, heads, nope + rope).transpose(0, 2, 1, 3)
+            with obs.device_scope("hybridlm.attention.latent"):
+                latent, k_rope = jnp.split(
+                    self._dot(y, w["wkva"]), [self.latent_rank], axis=-1)
+                kv = self._dot(
+                    rms_norm(latent, w["kv_norm"], self.rms_eps), w["wkvb"])
+            k_nope, v = jnp.split(
+                kv.reshape(b, t, heads, nope + dv).transpose(0, 2, 1, 3),
+                [nope], axis=-1)
+            cos, sin = rope_tables(t, rope, self.rope_theta)
+
+            def turned(x):
+                # interleaved pairs (x[2i], x[2i+1]): the pairs taken apart
+                # into halves, q and k alike, and the halves rotated; a
+                # pair's angle is the same and q.k does not see the order
+                halves = jnp.moveaxis(
+                    x.reshape(x.shape[:-1] + (rope // 2, 2)), -1, -2)
+                return apply_rope(halves.reshape(x.shape), cos, sin)
+
+            # the attention's own scale is head_dim ** -0.5
+            scale = self.attention_multiplier * math.sqrt(self.head_dim)
+            q = jnp.concatenate([q[..., :nope], turned(q[..., nope:])],
+                                axis=-1) * jnp.asarray(scale, self.dtype)
+            k = jnp.concatenate([k_nope, jnp.broadcast_to(
+                turned(k_rope[:, None]), (b, heads, t, rope))], axis=-1)
+            o = _attend(q, k, v, impl=self.attn_impl, axis="sp", causal=True)
+            gate = jax.nn.sigmoid(
+                self._dot(y, w["wg"]).astype(jnp.float32))
+            o = o.transpose(0, 2, 1, 3).astype(jnp.float32) * gate[..., None]
+            return self._dot(o.reshape(b, t, heads * dv).astype(self.dtype),
+                             w["wo"])
+
     def _delta(self, w, a):
         """The gated delta-rule mixer on ``a`` [B, T, D]: q, k, v through
         the depthwise causal convolution and its silu, q and k l2-normed a
@@ -971,24 +1255,16 @@ class HybridLM(nn.Module):
         a head and gated by silu(W_g a) before ``W_o``."""
         with obs.device_scope("hybridlm.delta"):
             b, t, _ = a.shape
-            heads, dk, dv = (self.delta_heads, self.delta_key_dim,
-                             self.delta_value_dim)
+            heads, dv = self.delta_heads, self.delta_value_dim
             f32 = jnp.float32
-            qkv = jnp.concatenate(
-                [self._dot(a, w[name]) for name in ("wq", "wk", "wv")],
-                axis=-1).astype(f32)
-            qkv = self._delta_act(_depthwise_causal(qkv, w["conv_w"]), "conv")
-            q, k, v = jnp.split(qkv, [heads * dk, 2 * heads * dk], axis=-1)
-            q = self._delta_l2(q.reshape(b, t, heads, dk)) * dk ** -0.5
-            k = self._delta_l2(k.reshape(b, t, heads, dk))
+            q, k, v = self._delta_qkv(w, a)
             # in (0, 2): past 1 a step reflects (linear_allow_neg_eigval)
             beta = 2.0 * jax.nn.sigmoid(self._dot(a, w["wb"]).astype(f32))
             log_alpha = -jnp.exp(w["A_log"]) * jax.nn.softplus(
                 self._dot(a, w["wa"]).astype(f32) + w["dt_bias"])
             o = delta_rule.gated_delta_rule(
                 q.astype(self.dtype), k.astype(self.dtype),
-                v.reshape(b, t, heads, dv).astype(self.dtype),
-                log_alpha, beta)
+                v.astype(self.dtype), log_alpha, beta)
             gate = self._delta_act(self._dot(a, w["wg"]).astype(f32), "gate")
             o = rms_norm(o.astype(f32), w["gate_norm"], self.rms_eps)
             return self._dot(
@@ -1031,16 +1307,28 @@ class HybridLM(nn.Module):
                 scope="hybridlm.experts", scoring=self.expert_scoring,
                 activation=self.expert_activation,
                 router_input=None if block_input is None
-                else block_input.reshape(b * t, d))
+                else block_input.reshape(b * t, d),
+                groups=self.expert_groups,
+                groups_kept=self.expert_groups_kept,
+                weight_eps=self.expert_weight_eps)
             report["sel"] = report["sel"].reshape(b, t, -1)
-            return checkpoint_name(out.astype(self.dtype).reshape(b, t, d),
-                                   "mlp_out"), report
+            out = out.astype(self.dtype).reshape(b, t, d)
+            if self.shared_experts:
+                # every token's, whole on every chip: added AFTER the combine
+                with obs.device_scope("hybridlm.experts.shared"):
+                    g, u = jnp.split(self._dot(y, w["shared_in"]), 2, axis=-1)
+                    act = experts_op.ACTIVATIONS[self.expert_activation]
+                    shared = self._dot(act(g) * u, w["shared_out"])
+                    out = (out.astype(jnp.float32) + shared.astype(
+                        jnp.float32)).astype(self.dtype)
+            return checkpoint_name(out, "mlp_out"), report
 
     def _block(self, kind, ffn, w, h, window: int = 0, rope: bool = True):
         """(h after the layer, what its FFN reports: {} for a dense one).
         ``window`` and ``rope`` are an attention layer's."""
         mixer = {MAMBA: self._mamba, CONV: self._short_conv,
-                 DELTA: self._delta,
+                 DELTA: self._delta, KDA: self._kda,
+                 MLA: self._latent_attention,
                  ATTENTION: functools.partial(
                      self._attention, window=window, rope=rope)}[kind]
         # the router's input where it is the block's: h before the first norm
@@ -1071,7 +1359,8 @@ class HybridLM(nn.Module):
         """(the final norm's output [B, T, D], the expert layers' reports
         stacked: {} without expert layers)."""
         h = (self.embedding_multiplier * self.embed[tokens]).astype(self.dtype)
-        keeps = REMAT_KEEPS + (EXPERT_KEEPS if self.expert_layers else ())
+        keeps = (REMAT_KEEPS + (EXPERT_KEEPS if self.expert_layers else ())
+                 + (KDA_KEEPS if KDA in self.layer_types else ()))
         block = jax.checkpoint(
             self._block, static_argnums=(0, 1, 4, 5),
             policy=jax.checkpoint_policies.save_only_these_names(*keeps),
@@ -1176,6 +1465,13 @@ class RoutedHybridLM(HybridLM):
     for (``config["model"]["class"]``): a program from before the experts
     FFN kind has ``HybridLM`` and not this name, and a benchmark that asks
     for it there leaves at once instead of failing inside a fit."""
+
+
+class LatentDeltaHybridLM(HybridLM):
+    """``HybridLM`` under the name a configuration with ``kda`` and ``mla``
+    layers asks for (``config["model"]["class"]``), as ``RoutedHybridLM``
+    and for its reason: a program from before those mixer kinds has no such
+    name, and a benchmark that asks for it there leaves at once."""
 
 
 class DeltaHybridLM(HybridLM):

@@ -64,11 +64,12 @@ def _attend(q, k, v, *, impl: str, axis: str, causal: bool,
 
 
 def attention_backward_facts(impl: str, t: int, head_dim: int, dtype,
-                             layers: dict) -> dict:
+                             layers: dict, value_dim: int = None) -> dict:
     """What a model whose attention goes through ``_attend`` says in its
     ``fit_facts`` of the attention's backward pass over rows of ``t``
-    tokens. ``layers``: {layer kind (``global``, ``window``): its layer
-    applications a step}. ``attention_backward``: the form a kind's backward
+    tokens. ``layers``: {layer kind (``global``, ``window``, ``latent``): its
+    layer applications a step}; ``value_dim``: v's and o's width where it is
+    not ``head_dim``. ``attention_backward``: the form a kind's backward
     pass takes, from the shapes (``ops.flash_attention.backward_form``:
     ``fused``, one call that computes every live tile once, or
     ``two_call``; a ring's step has runtime offsets and is ``two_call``;
@@ -80,7 +81,8 @@ def attention_backward_facts(impl: str, t: int, head_dim: int, dtype,
 
     form = "xla"
     if impl in ("flash", "ulysses_flash"):
-        form = backward_form(t, t, head_dim, jnp.dtype(dtype).itemsize)
+        form = backward_form(t, t, head_dim, jnp.dtype(dtype).itemsize,
+                             value_dim=value_dim)
     elif impl == "ring_flash":
         form = "two_call"
     fused = sum(layers.values()) if form == "fused" else 0

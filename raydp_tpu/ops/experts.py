@@ -12,6 +12,9 @@ exchange; nothing here stands in for the absent chips.
     scoring "sigmoid":  s = sigmoid(z); sel = top_k(s + b)   b enters the
                         w = s[sel] / (sum s[sel] + 1e-6)     SELECTION only
     scoring "softmax":  sel = top_k(z);  w = softmax(z[sel])    no bias
+    groups G, kept g:   (sigmoid) the E experts in G groups of E / G; a
+                        group's score the sum of its 2 largest s + b; the
+                        top_k is taken inside the g best groups only
     w = w x scaling                          over the k selected, held here
                                              or not
     excess_e = pairs chosen for e / (N k / E) - 1    over ALL E, for the
@@ -117,6 +120,16 @@ MEGABLOX_TILING = (ROW_TILE, 1024, 1024)  # rows, contraction, columns
 # 679.0 at 1.5, 694.8 at 2 and 740.1 at the worst-case bound (4 x even),
 # all before ``_by_load``'s barrier
 SLACK = 1.25
+# the share of the experts at which SLACK was read (8 of 32 and 16 of 64 held:
+# both routed cells hold a quarter). The held load is a sum over the held
+# experts, so its spread over its mean grows as the share shrinks, by
+# share^-1/2 (tokens that route alike widen it and keep the law), and
+# ``likely_row_bound`` widens SLACK's margin by that factor below this share:
+# 8 held of 512 at 8192 tokens x 8 (1,024 pairs at the even load) spread by
+# 14 % of the even load from a fit's fourth step on; 1.25's 1,536 rows were
+# passed by one layer in three consecutive epochs of one window of three
+# (my chip runs, PR 49), the 2,048 this rule gives by none in seven windows
+SLACK_SHARE = 0.25
 # the name under which a recomputed block keeps the layer's discrete part,
 # int32: every token's choice (``route``) and what the sorts made of it
 # (``plan``: four numbers a (token, choice) pair, one a token)
@@ -146,19 +159,26 @@ _hand_bias.defvjp(_hand_bias_fwd, _hand_bias_bwd)
 
 
 def route(u, w_gate, bias, top_k: int, scaling: float = 1.0,
-          scoring: str = "sigmoid"):
+          scoring: str = "sigmoid", groups: int = 0, groups_kept: int = 0,
+          weight_eps: float = 1e-6):
     """(sel int32 [N, k], w float32 [N, k]) of tokens ``u`` [N, D].
     ``scoring="sigmoid"``: sigmoid scores over all of ``w_gate``'s experts,
     the k largest of score + bias, the selected scores normalised over the
-    k. ``scoring="softmax"``: the k largest LOGITS (no bias: ``bias`` is
-    None), a softmax over the selected k. All of it float32, the
-    product at ``highest``: a bf16 product moves a logit by 2e-3, and the
-    4th and 5th scores of a token lie closer than that often enough."""
+    k (their sum + ``weight_eps``). ``groups`` > 0 (sigmoid only): the
+    selection is GROUP-LIMITED (DeepSeek-V3's ``noaux_tc``): the experts lie
+    in ``groups`` groups of consecutive ids, a group's score is the sum of
+    its 2 largest score + bias, and the k are the largest inside the
+    ``groups_kept`` best groups. ``scoring="softmax"``: the k largest
+    LOGITS (no bias: ``bias`` is None), a softmax over the selected k. All
+    of it float32, the product at ``highest``: a bf16 product moves a logit
+    by 2e-3, and the 4th and 5th scores of a token lie closer than that
+    often enough."""
     logits = jnp.dot(u.astype(jnp.float32), w_gate.astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)
     if scoring == "softmax":
-        if bias is not None:
-            raise ValueError("softmax over the selected takes no bias")
+        if bias is not None or groups:
+            raise ValueError("softmax over the selected takes no bias and "
+                             "no group limit")
         _, sel = lax.top_k(logits, top_k)
         sel = checkpoint_name(sel.astype(jnp.int32), KEPT)  # as below
         w = jax.nn.softmax(jnp.take_along_axis(logits, sel, axis=-1), axis=-1)
@@ -166,7 +186,19 @@ def route(u, w_gate, bias, top_k: int, scaling: float = 1.0,
     if scoring != "sigmoid":
         raise ValueError(f"scoring {scoring!r} is not one of {SCORINGS}")
     scores = jax.nn.sigmoid(logits)
-    _, sel = lax.top_k(scores + lax.stop_gradient(bias), top_k)
+    biased = scores + lax.stop_gradient(bias)
+    if groups:
+        n, experts = biased.shape
+        if experts % groups or not 0 < groups_kept <= groups:
+            raise ValueError(f"{groups_kept} of {groups} groups over "
+                             f"{experts} experts")
+        grouped = biased.reshape(n, groups, experts // groups)
+        best, _ = lax.top_k(grouped, 2)
+        _, kept = lax.top_k(best.sum(axis=-1), groups_kept)
+        open_ = jnp.any(kept[:, :, None] == jnp.arange(groups), axis=1)
+        biased = jnp.where(open_[:, :, None], grouped, -jnp.inf).reshape(
+            n, experts)
+    _, sel = lax.top_k(biased, top_k)
     # THE CHOICE IS KEPT, with the permutations made from it (``KEPT``): a
     # recomputed block that chose again could choose otherwise for a token
     # whose k-th and (k+1)-th scores nearly tie (another fusion rounds
@@ -174,7 +206,7 @@ def route(u, w_gate, bias, top_k: int, scaling: float = 1.0,
     # other are garbage (gradients 1e5 times too large; my chip run, PR 34)
     sel = checkpoint_name(sel.astype(jnp.int32), KEPT)
     w = jnp.take_along_axis(scores, sel, axis=-1)
-    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6) * scaling
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + weight_eps) * scaling
     return sel, w
 
 
@@ -480,9 +512,13 @@ def grouped_dot(x, w, sizes, impl: str | None = None):
 def likely_row_bound(pairs: int, held: int, experts: int) -> int:
     """Rows of the buffer a layer that holds ``held`` of ``experts`` experts
     runs at for ``pairs`` (token, choice) pairs, almost always: ``SLACK``
-    times the even share, a whole number of row tiles, never over the worst
-    case (which it is when every expert is held)."""
-    return min(row_bound_for(math.ceil(SLACK * pairs * held / experts)),
+    times the even share (its margin over 1 wider by (``SLACK_SHARE`` /
+    share)^1/2 where the share held is under ``SLACK_SHARE``), a whole
+    number of row tiles, never over the worst case (which it is when every
+    expert is held)."""
+    wider = max(1.0, math.sqrt(SLACK_SHARE * experts / held))
+    slack = 1.0 + (SLACK - 1.0) * wider
+    return min(row_bound_for(math.ceil(slack * pairs * held / experts)),
                row_bound_for(pairs))
 
 
@@ -578,7 +614,8 @@ def routed_experts(u, w_gate, bias, w13, w2, *, first: int, top_k: int,
                    scaling: float = 1.0, row_bound: int | None = None,
                    impl: str | None = None, scope: str = "experts",
                    scoring: str = "sigmoid", activation: str = "silu",
-                   router_input=None):
+                   router_input=None, groups: int = 0, groups_kept: int = 0,
+                   weight_eps: float = 1e-6):
     """This chip's part of the routed experts' result for tokens ``u``
     [N, D]: ``(out [N, D] in ``u``'s dtype, report)``.
 
@@ -590,7 +627,9 @@ def routed_experts(u, w_gate, bias, w13, w2, *, first: int, top_k: int,
     ``bias`` None). ``activation``: the experts' gate's, ``"silu"`` or
     ``"relu"``. ``router_input`` [N, D]: what the router reads in ``u``'s
     place (a model whose router is fed from the block's input, before its
-    attention); the experts read ``u`` either way. ``row_bound=None``: the layer chooses
+    attention); the experts read ``u`` either way. ``groups``,
+    ``groups_kept``, ``weight_eps``: ``route``'s. ``row_bound=None``: the
+    layer chooses
     (``likely_row_bound`` where the batch's load fits it, tokens x k where
     it does not: nothing dropped, whatever the load). ``row_bound`` given:
     that bound and no other, pairs past it left out and counted.
@@ -617,7 +656,8 @@ def routed_experts(u, w_gate, bias, w13, w2, *, first: int, top_k: int,
         worst = likely = row_bound
     with obs.device_scope(f"{scope}.route"):
         sel, w = route(u if router_input is None else router_input, w_gate,
-                       bias, top_k, scaling, scoring)
+                       bias, top_k, scaling, scoring, groups=groups,
+                       groups_kept=groups_kept, weight_eps=weight_eps)
         if bias is not None:
             # the bias's "gradient": every expert's excess load
             w = _hand_bias(w, bias, excess_load(sel, total))

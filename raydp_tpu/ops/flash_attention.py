@@ -356,12 +356,13 @@ def _flash_call(
     if onepass is None:
         onepass = use_onepass_default()
     b, h, t, d = q.shape
-    tk = k.shape[2]
-    block_q, block_k = _blocks(t, tk, d, q.dtype.itemsize, block_q, block_k)
+    tk, dv = k.shape[2], v.shape[3]  # values, and o, at a width of their own
+    block_q, block_k = _blocks(t, tk, d, q.dtype.itemsize, block_q, block_k,
+                               dv)
     bh = b * h
     qf = q.reshape(bh, t, d)
     kf = k.reshape(bh, tk, d)
-    vf = v.reshape(bh, tk, d)
+    vf = v.reshape(bh, tk, dv)
 
     window = _window_for(window, causal, t, tk, q_offset, k_offset)
     k_steps, k_block = tk // block_k, (lambda i, j: j)
@@ -394,7 +395,7 @@ def _flash_call(
     o, m, l = pl.pallas_call(  # noqa: E741
         kernel,
         out_shape=(
-            sds((bh, t, d), out_dtype),
+            sds((bh, t, dv), out_dtype),
             sds((bh, t, 1), jnp.float32),
             sds((bh, t, 1), jnp.float32),
         ),
@@ -405,16 +406,16 @@ def _flash_call(
             pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0)),
             pl.BlockSpec((1, block_k, d),
                          lambda b_, i, j: (b_, k_block(i, j), 0)),
-            pl.BlockSpec((1, block_k, d),
+            pl.BlockSpec((1, block_k, dv),
                          lambda b_, i, j: (b_, k_block(i, j), 0)),
         ],
         out_specs=(
-            pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda b_, i, j: (b_, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b_, i, j: (b_, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b_, i, j: (b_, i, 0)),
         ),
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
@@ -426,7 +427,7 @@ def _flash_call(
         else "flash_attention_window_fwd",
     )(q_off, k_off, qf, kf, vf)
     return (
-        o.reshape(b, h, t, d),
+        o.reshape(b, h, t, dv),
         m.reshape(b, h, t),
         l.reshape(b, h, t),
     )
@@ -683,10 +684,13 @@ def dq_resident_bytes(t: int, head_dim: int) -> int:
     return t * _lanes(head_dim) * 4
 
 
-def fused_vmem_bytes(t: int, head_dim: int, block: int, itemsize: int) -> int:
+def fused_vmem_bytes(t: int, head_dim: int, block: int, itemsize: int,
+                     value_dim: int | None = None) -> int:
     """The VMEM the fused backward call asks for, from its shapes: the
     head's dq; the dk and dv accumulators; two buffers of every pipelined
-    block (q, do, k, v in, dq, dk, dv out, and ``lse`` / ``dsum``, whose
+    block (q, k in and dq, dk out at ``head_dim``; do, v in and dv out at
+    ``value_dim``, the values' width where it is not the keys'; and
+    ``lse`` / ``dsum``, whose
     ``[block, 1]`` float32 columns are padded to the lanes); and the tile's
     ``[block, block]`` float32 values (s, p, dp, ds, the mask's two iotas
     and what the compiler keeps beside them), counted as EIGHT: compiled
@@ -695,16 +699,18 @@ def fused_vmem_bytes(t: int, head_dim: int, block: int, itemsize: int) -> int:
     + dq), and what is asked for and not used costs nothing
     (``tests/test_tpu_compile_kernels.py`` compiles the cells' shapes)."""
     row = block * _lanes(head_dim)
-    return (dq_resident_bytes(t, head_dim) + 2 * row * 4
-            + 2 * (7 * row * itemsize + 2 * block * 128 * 4)
+    row_v = block * _lanes(value_dim or head_dim)
+    return (dq_resident_bytes(t, head_dim) + (row + row_v) * 4
+            + 2 * ((4 * row + 3 * row_v) * itemsize + 2 * block * 128 * 4)
             + 8 * block * block * 4)
 
 
-def _blocks(t, tk, head_dim, itemsize, block_q, block_k):
+def _blocks(t, tk, head_dim, itemsize, block_q, block_k, value_dim=None):
     """The tiles of a call, forward and backward alike: the caller's, or
-    ``pick_blocks``' for this head and operand width, clamped to the
+    ``pick_blocks``' for this head, value and operand width, clamped to the
     sequence lengths, which they must divide."""
-    auto_q, auto_k = pick_blocks(t, tk, head_dim=head_dim, itemsize=itemsize)
+    auto_q, auto_k = pick_blocks(t, tk, head_dim=head_dim, itemsize=itemsize,
+                                 value_dim=value_dim)
     block_q, block_k = min(block_q or auto_q, t), min(block_k or auto_k, tk)
     if t % block_q or tk % block_k:
         raise ValueError(
@@ -716,7 +722,7 @@ def _blocks(t, tk, head_dim, itemsize, block_q, block_k):
 def backward_form(
     t: int, tk: int, head_dim: int, itemsize: int = 2, *, causal: bool = True,
     block_q: int | None = None, block_k: int | None = None,
-    q_offset=0, k_offset=0,
+    q_offset=0, k_offset=0, value_dim: int | None = None,
 ) -> str:
     """Which form the backward pass of these shapes and arguments takes:
     ``"fused"`` (one call, every live tile once) for causal self-attention
@@ -724,11 +730,13 @@ def backward_form(
     fits the VMEM a call may ask for; ``"two_call"`` for everything else: a
     ring step's runtime offsets, ``t != tk``, a non-causal call (its dq is
     complete at no tile of a k-outer grid but the last), unequal blocks.
+    ``value_dim``: the width of v, o and do where it is not q's and k's.
     Decided from what the call sees, by no flag."""
-    block_q, block_k = _blocks(t, tk, head_dim, itemsize, block_q, block_k)
+    block_q, block_k = _blocks(t, tk, head_dim, itemsize, block_q, block_k,
+                               value_dim)
     static = all(isinstance(o, int) and o == 0 for o in (q_offset, k_offset))
     if (causal and static and t == tk and block_q == block_k
-            and fused_vmem_bytes(t, head_dim, block_q, itemsize)
+            and fused_vmem_bytes(t, head_dim, block_q, itemsize, value_dim)
             <= VMEM_ASK_BOUND_BYTES):
         return "fused"
     return "two_call"
@@ -753,18 +761,19 @@ def flash_backward_blocks(
 
     interpret = pallas_interpret(interpret)
     b, h, t, d = q.shape
-    tk = k.shape[2]
-    block_q, block_k = _blocks(t, tk, d, q.dtype.itemsize, block_q, block_k)
+    tk, dv = k.shape[2], v.shape[3]  # v, do and dv at the values' own width
+    block_q, block_k = _blocks(t, tk, d, q.dtype.itemsize, block_q, block_k,
+                               dv)
     form = backward_form(
         t, tk, d, q.dtype.itemsize, causal=causal, block_q=block_q,
-        block_k=block_k, q_offset=q_offset, k_offset=k_offset)
+        block_k=block_k, q_offset=q_offset, k_offset=k_offset, value_dim=dv)
     bh = b * h
     scale = d**-0.5
 
     qf = q.reshape(bh, t, d)
     kf = k.reshape(bh, tk, d)
-    vf = v.reshape(bh, tk, d)
-    dof = g.reshape(bh, t, d)
+    vf = v.reshape(bh, tk, dv)
+    dof = g.reshape(bh, t, dv)
     lsef = lse.reshape(bh, t, 1)
     dsumf = dsum.astype(jnp.float32).reshape(bh, t, 1)
 
@@ -799,14 +808,17 @@ def flash_backward_blocks(
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     # k-block outer, q-block inner: the dk/dv call's grid and the fused one's
     k_spec = pl.BlockSpec((1, block_k, d), lambda b_, j, i: (b_, j, 0))
+    v_spec = pl.BlockSpec((1, block_k, dv), lambda b_, j, i: (b_, j, 0))
     q_spec_kv = pl.BlockSpec(
         (1, block_q, d), lambda b_, j, i: (b_, q_block(j, i), 0))
+    do_spec_kv = pl.BlockSpec(
+        (1, block_q, dv), lambda b_, j, i: (b_, q_block(j, i), 0))
     stat_spec_kv = pl.BlockSpec(
         (1, block_q, 1), lambda b_, j, i: (b_, q_block(j, i), 0))
     kv_grid = dict(
         grid=(bh, tk // block_k, q_steps),
         in_specs=[
-            smem, smem, k_spec, k_spec, q_spec_kv, q_spec_kv,
+            smem, smem, k_spec, v_spec, q_spec_kv, do_spec_kv,
             stat_spec_kv, stat_spec_kv,
         ],
         interpret=interpret,
@@ -814,34 +826,37 @@ def flash_backward_blocks(
     kv_operands = (q_off, k_off, kf, vf, qf, dof, lsef, dsumf)
     kv_acc = [
         pltpu.VMEM((block_k, d), jnp.float32),
-        pltpu.VMEM((block_k, d), jnp.float32),
+        pltpu.VMEM((block_k, dv), jnp.float32),
     ]
 
     if form == "fused":
         # the names begin as the dq call's do: a reader that counts passes
         # by ``flash_attention[_window]_bwd_dq`` counts this call once, with
         # the whole pass's time
-        dq, dk, dv = pl.pallas_call(
+        dq, dk, dv_ = pl.pallas_call(
             functools.partial(
                 _bwd_fused_kernel, scale=scale, block=block_k, **dkv_window),
             out_shape=(sds((bh, t, d), q.dtype), sds((bh, tk, d), k.dtype),
-                       sds((bh, tk, d), v.dtype)),
+                       sds((bh, tk, dv), v.dtype)),
             # dq's block follows the OUTER axis: q-block kj is complete, and
             # written, at the first live step of k-block kj
-            out_specs=(k_spec, k_spec, k_spec),
+            out_specs=(k_spec, k_spec, v_spec),
             scratch_shapes=[
                 pltpu.VMEM((t // block_q, block_q, d), jnp.float32), *kv_acc],
             compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=max(
-                    fused_vmem_bytes(t, d, block_k, q.dtype.itemsize),
+                    fused_vmem_bytes(t, d, block_k, q.dtype.itemsize, dv),
                     VMEM_DEFAULT_BYTES)),
             name=name + "dq_dkv",
             **kv_grid,
         )(*kv_operands)
     else:
         q_spec = pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0))
+        do_spec = pl.BlockSpec((1, block_q, dv), lambda b_, i, j: (b_, i, 0))
         k_spec_dq = pl.BlockSpec(
             (1, block_k, d), lambda b_, i, j: (b_, k_block(i, j), 0))
+        v_spec_dq = pl.BlockSpec(
+            (1, block_k, dv), lambda b_, i, j: (b_, k_block(i, j), 0))
         stat_spec_dq = pl.BlockSpec(
             (1, block_q, 1), lambda b_, i, j: (b_, i, 0))
 
@@ -854,7 +869,7 @@ def flash_backward_blocks(
             out_shape=sds((bh, t, d), q.dtype),
             grid=(bh, t // block_q, k_steps),
             in_specs=[
-                smem, smem, q_spec, k_spec_dq, k_spec_dq, q_spec,
+                smem, smem, q_spec, k_spec_dq, v_spec_dq, do_spec,
                 stat_spec_dq, stat_spec_dq,
             ],
             out_specs=q_spec,
@@ -863,14 +878,14 @@ def flash_backward_blocks(
             name=name + "dq",
         )(q_off, k_off, qf, kf, vf, dof, lsef, dsumf)
 
-        dk, dv = pl.pallas_call(
+        dk, dv_ = pl.pallas_call(
             functools.partial(
                 _bwd_dkv_kernel,
                 scale=scale, causal=causal, block_q=block_q, block_k=block_k,
                 **dkv_window,
             ),
-            out_shape=(sds((bh, tk, d), k.dtype), sds((bh, tk, d), v.dtype)),
-            out_specs=(k_spec, k_spec),
+            out_shape=(sds((bh, tk, d), k.dtype), sds((bh, tk, dv), v.dtype)),
+            out_specs=(k_spec, v_spec),
             scratch_shapes=kv_acc,
             name=name + "dkv",
             **kv_grid,
@@ -879,12 +894,12 @@ def flash_backward_blocks(
     return (
         dq.reshape(b, h, t, d),
         dk.reshape(b, h, tk, d),
-        dv.reshape(b, h, tk, d),
+        dv_.reshape(b, h, tk, dv),
     )
 
 
 def pick_blocks(t_q: int, t_k: int, head_dim: int | None = None,
-                itemsize: int = 2) -> tuple:
+                itemsize: int = 2, value_dim: int | None = None) -> tuple:
     """Largest power-of-two blocks (≤1024 each) dividing the sequence
     lengths. Measured on TPU v5e at T=8k/head_dim 128: 1024×1024 runs the
     fwd+bwd pair ~1.4x faster than the old 512×1024 caps (26.5→18.4ms per
@@ -900,12 +915,15 @@ def pick_blocks(t_q: int, t_k: int, head_dim: int | None = None,
     ``itemsize`` does the same for the operands' width: the cap was
     measured with bf16 operands, and float32 ones (a model traced at full
     precision for a check) double a tile's bytes — 1024-row float32 tiles
-    at D=128 ask the backward kernel for 19.5 MB of its 16 MB of VMEM."""
+    at D=128 ask the backward kernel for 19.5 MB of its 16 MB of VMEM.
+    ``value_dim``: the width of v and o where it is not q's and k's (keys
+    of 192 over values of 128): a tile's bytes go by the two widths' sum."""
 
     # a head narrower than the 128 lanes is padded to them in VMEM: float32
     # heads of 64 at 1024 rows asked the forward kernel for 16.44 MB (PR 31)
     cap = 1024
-    while cap > 128 and cap * max(head_dim or 128, 128) * itemsize > 1024 * 128 * 2:
+    lanes = _lanes(head_dim or 128) + _lanes(value_dim or head_dim or 128)
+    while cap > 128 and cap * lanes * itemsize > 1024 * 256 * 2:
         cap //= 2
 
     def _block(t, cap):
@@ -931,7 +949,10 @@ def flash_attention(
     block_k: int | None = None, interpret: bool | None = None,
     window: int | None = None,
 ):
-    """Fused attention: q,k,v [B, H, T, D] → [B, H, T, D]. ``block_q`` /
+    """Fused attention: q, k [B, H, T, D], v [B, H, T, Dv] → [B, H, T, Dv]
+    (``Dv`` is ``D`` in every family but latent attention's, whose keys of
+    192 stand over values of 128; o and its cotangent have v's width,
+    forward and backward). ``block_q`` /
     ``block_k`` default to ``pick_blocks`` (measured-fastest large tiles);
     pass explicit sizes only to pin a tiling (tests / VMEM-constrained
     shard_map bodies). ``window`` (causal only): a query sees its own

@@ -44,7 +44,9 @@ def test_the_cell_resolves_with_its_driver_readers_and_traffic():
     assert {"model.delta_scope_ms", "kernel.delta_rule_roofline",
             "estimator.mfu", "kernel.flash_fwd_roofline",
             "kernel.flash_bwd_roofline", "model.attention_scope_ms",
-            "device.scope_unattributed_share"} <= names and len(names) == 18
+            "device.scope_unattributed_share"} <= names
+    # PR 45's 18 and, since PR 49, the mixer's scope outside the scan
+    assert len(names) == 19 and "model.delta_mixer_scope_ms" in names
     assert {m["name"] for m in cell.end_to_end} == {"fit_samples_per_s", "setup_s"}
     t = cell.traffic
     assert (t["seq_len"], t["batch"], t["held_out_rows"], t["zipf_a"],
@@ -58,22 +60,29 @@ def test_the_cell_resolves_with_its_driver_readers_and_traffic():
 
 def test_the_benchmark_only_grew_at_its_ends():
     """The six cells, five configurations and the metrics the benchmark had
-    are its first, in their order; this PR's are after them; a metric's list
-    of cells gained the new cell at its end and nothing else."""
+    are its first, in their order; PR 45's are after them (and a later PR's
+    after those); a metric's list of cells gained PR 45's cell after the
+    cells it had and nothing else."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     assert [c["name"] for c in bench["configs"]][:5] == [
         "dlrm-criteo-kaggle", "ouro-2.6b", "granite-4.0-h-micro",
         "lfm2-8b-a1b", "smallthinker-21b-a3b"]
-    assert [c["name"] for c in bench["configs"]][5:] == ["olmo-hybrid-7b"]
-    assert [w["name"] for w in bench["workloads"]][6:] == [CELL]
-    assert [m["name"] for m in bench["per_layer"]][-2:] == [
-        "model.delta_scope_ms", "kernel.delta_rule_roofline"]
-    for metric in bench["end_to_end"] + bench["per_layer"]:
+    assert [c["name"] for c in bench["configs"]][5:6] == ["olmo-hybrid-7b"]
+    earlier = [w["name"] for w in bench["workloads"]][:6]
+    assert [w["name"] for w in bench["workloads"]][6:7] == [CELL]
+    names = [m["name"] for m in bench["per_layer"]]
+    at = names.index("model.delta_scope_ms")
+    assert names[at:at + 2] == ["model.delta_scope_ms",
+                                "kernel.delta_rule_roofline"]
+    assert "model.attention_scope_ms" in names[:at]
+    for metric in bench["end_to_end"] + bench["per_layer"][:at + 2]:
         cells_ = metric.get("workloads", [])
-        assert CELL not in cells_[:-1], metric["name"]
-    new = {m["name"]: m for m in bench["per_layer"][-2:]}
-    assert all(m["workloads"] == [CELL] and m["source"] == "device_trace"
+        if CELL in cells_:
+            assert set(cells_[:cells_.index(CELL)]) <= set(earlier), (
+                metric["name"])
+    new = {m["name"]: m for m in bench["per_layer"][at:at + 2]}
+    assert all(m["workloads"][:1] == [CELL] and m["source"] == "device_trace"
                and m["moves"] == "fit_samples_per_s" for m in new.values())
     assert new["kernel.delta_rule_roofline"]["unit"] == "%"
     assert bench["run_seconds"] == 20

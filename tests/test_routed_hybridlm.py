@@ -602,7 +602,7 @@ def test_forms_agree(params, batch, want, form):
 
 
 def _route_mutant(kind):
-    def route(u, w_gate, bias, top_k, scaling=1.0, scoring="sigmoid"):
+    def route(u, w_gate, bias, top_k, scaling=1.0, scoring="sigmoid", **_):
         logits = jnp.dot(u.astype(jnp.float32), w_gate,
                          precision=jax.lax.Precision.HIGHEST)
         scores = (jax.nn.softmax(logits, axis=-1) if kind == "softmax"

@@ -8,12 +8,24 @@ import pytest
 from raydp_tpu.estimator import row_update
 from raydp_tpu.estimator.jax_estimator import _LOSSES, make_train_step
 from raydp_tpu.ops import backend, row_gather, row_write_back as rwb
-from tests.test_jax_estimator import criteo_df, session  # noqa: F401 - fixtures
+from tests.test_jax_estimator import criteo_df  # noqa: F401 - a fixture
 from tests.test_row_update import (
     BATCH, ROW_PATHS, _batches, _criteo_est, _dlrm, _losses, _optimizers,
 )
 
 SLOTS = 2048
+
+
+@pytest.fixture(scope="module")
+def session():
+    """An ETL session under this module's own name (tests/test_row_update.py
+    says why)."""
+    import raydp_tpu
+
+    s = raydp_tpu.init_etl("test-row-write-back", num_executors=2,
+                           executor_cores=1, executor_memory="300M")
+    yield s
+    raydp_tpu.stop_etl()
 
 
 def _ids(case, size, rng):

@@ -1,0 +1,114 @@
+"""The KDA / latent-attention cell (``ling-3.0-flash.pretrain-8k-kda``)
+compiled for a described TPU v5e: the flash kernels at keys of 192 over
+values of 128, and its epoch program (``tpu_compile_helpers`` says how and
+why)."""
+
+import importlib
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from tpu_compile_helpers import (  # noqa: F401 - fixtures by name
+    BWD_DKV, calls, cell_config, epoch_program, kernels_compile,
+    loss_products, no_compile_cache, one_chip)
+
+
+@pytest.mark.parametrize("dtype, precision, tile", [
+    (jnp.bfloat16, None, 512), (jnp.float32, "highest", 256)])
+def test_flash_kernels_compile_at_keys_of_192_over_values_of_128(
+        one_chip, no_compile_cache, dtype, precision, tile):
+    """[32 heads, T 8192], q and k of 192 lanes, v, o and do of 128: the
+    timed bf16 step's tiles and the float32 ones of the matched check. Mosaic
+    lowers the contraction over 192 lanes as it is (nothing is padded); the
+    tiles halve against equal widths of 128 (a tile's bytes go by the two
+    widths' lanes, 256 + 128); the backward is the one fused call, whose dq
+    [8192, 256 lanes] float32 fits the VMEM it may ask for."""
+    fa = importlib.import_module("raydp_tpu.ops.flash_attention")
+    t, itemsize = 8192, jnp.dtype(dtype).itemsize
+    assert fa.pick_blocks(t, t, head_dim=192, itemsize=itemsize,
+                          value_dim=128) == (tile, tile)
+    assert fa.backward_form(t, t, 192, itemsize, value_dim=128) == "fused"
+    assert fa.fused_vmem_bytes(t, 192, tile, itemsize, 128) < (
+        fa.fused_vmem_bytes(t, 192, tile, itemsize)) <= fa.VMEM_ASK_BOUND_BYTES
+    q = jax.ShapeDtypeStruct((1, 32, t, 192), dtype, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, 32, t, 128), dtype, sharding=one_chip)
+
+    def grads(q, k, v):
+        with jax.default_matmul_precision(precision):
+            return jax.grad(
+                lambda q, k, v: fa.flash_attention(
+                    q, k, v, True, None, None, False
+                ).astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    compiled = jax.jit(grads).lower(q, q, v).compile()
+    text = compiled.as_text()
+    for name in ("flash_attention_fwd", "flash_attention_bwd_dq_dkv"):
+        assert calls(text, name) == 1, name
+    assert not re.search(BWD_DKV, text)
+    shapes = [tuple(leaf.shape) for leaf in jax.tree.leaves(
+        jax.eval_shape(grads, q, q, v))]
+    assert shapes == [(1, 32, t, 192), (1, 32, t, 192), (1, 32, t, 128)]
+
+
+def test_latent_delta_hybridlm_epoch_program_fits_the_chip(
+        one_chip, no_compile_cache, kernels_compile):
+    """ISSUE 49: the benchmark's epoch program of
+    ``ling-3.0-flash.pretrain-8k-kda`` (714,989,856 float32 parameters counted
+    from the built tree, AdamW under its warm-up with the balancing rule, 3
+    steps of 1 x 8192 tokens gathered from the resident rows and scanned,
+    parameters and optimizer state donated, the steps' report summed) for the
+    described v5e: under the window cell's 14.96e9 bytes, the most any cell
+    holds; ONE causal flash forward and ONE fused backward call (the MLA
+    layer; kept ``attn_out`` and ``attn_lse``: none recomputed); the five KDA
+    layers' scans under ``delta_rule`` inside ``hybridlm.delta``; the shared
+    expert under ``hybridlm.experts.shared``; each of the five expert layers
+    at the likely bound with the worst case (65,536 rows) as the overflow's
+    arm; three products in the loss."""
+    from raydp_tpu.models import LatentDeltaHybridLM, hybridlm_optimizer
+    from raydp_tpu.obs import profiler
+
+    config = cell_config("ling-3.0-flash")
+    module = LatentDeltaHybridLM.from_config(
+        config, **config["model"]["kwargs"])
+    assert module.layer_types == ("kda", "kda", "kda", "kda", "mla", "kda")
+    assert module.ffn_kinds == ("dense",) + ("experts",) * 5
+    assert module.expert_row_bound(8192) == 65_536
+    assert module.expert_likely_row_bound(8192) == LIKELY_ROWS
+    params, compiled, held = epoch_program(
+        module, hybridlm_optimizer(**config["model"]["adamw"]), 3, 1, 8192,
+        one_chip)
+    sizes = {name: sum(leaf.size for leaf in jax.tree.leaves(sub))
+             for name, sub in params["params"].items()}
+    assert sizes == {
+        "embed": 19_648 * 2560, "head": 2560 * 19_648, "final_norm": 2560,
+        "layer_0": 99_837_088, "layer_1": 107_046_560,
+        "layer_2": 107_046_560, "layer_3": 107_046_560,
+        "layer_4": 86_366_208, "layer_5": 107_046_560}
+    assert sum(sizes.values()) == 714_989_856
+    mixer = {name: leaf.size for name, leaf in params["params"]["layer_1"].items()
+             if name not in ("norm1", "norm2", "router", "expert_bias", "w13",
+                             "w2", "shared_in", "shared_out")}
+    assert sum(mixer.values()) == 52_646_048, mixer
+    print("latent delta hybrid epoch program holds", held)
+    assert held <= 14.96e9, held
+    text = compiled.as_text()
+    for name in ("flash_attention_fwd", "flash_attention_bwd_dq_dkv"):
+        assert calls(text, name) == 1, name
+    assert not re.search(BWD_DKV, text)
+    assert loss_products(text, "hybridlm.loss") == 3
+    chains = [tuple(said["scopes"])
+              for said in profiler.scopes_in_text(text).values()]
+    inside = [c for c in chains if "delta_rule" in c]
+    assert inside and all("hybridlm.delta" in c for c in inside)
+    for scope in ("hybridlm.attention.latent", "hybridlm.experts.shared",
+                  "hybridlm.experts.route", "hybridlm.experts.gmm"):
+        assert any(scope in c for c in chains), scope
+    assert all("hybridlm.experts" in c for c in chains
+               if "hybridlm.experts.shared" in c)
+
+
+# rows an expert layer runs at wherever the load fits them: at a share of 1/64
+# ops.experts.likely_row_bound widens SLACK's margin of 0.25 by (1/4 x 64)^1/2
+# = 4: twice the even share (8192 x 8 x 8 / 512 = 1024 pairs)
+LIKELY_ROWS = 2048
