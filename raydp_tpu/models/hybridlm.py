@@ -126,8 +126,9 @@ A CHANNEL of the key; H heads, keys and values of ``delta_key_dim`` /
     out = W_o [ RMSNorm_head(o_t; gate_norm [Dv]) * sigmoid(W_g a)_t[head] ]
 
 ``A_log`` a head, ``dt_bias`` a channel, seeded as ``delta``'s; the recurrence
-is ``ops.delta_rule.channel_gated_delta_rule`` under the same scopes
-(``hybridlm.delta`` around ``delta_rule``). No positions.
+is ``ops.delta_rule.channel_gated_delta_rule`` (one Pallas kernel forward and
+one backward, reading and writing [T, H * 128] as the mixer holds it) under
+the same scopes (``hybridlm.delta`` around ``delta_rule``). No positions.
 
 ``mla`` (latent attention, DeepSeek-V2, arXiv:2405.04434, its TRAINING side:
 multi-head attention whose keys are wider than its values; the cache's
@@ -214,10 +215,10 @@ REMAT_KEEPS = SAVED_RESIDUALS + ("mlp_out",)
 # rows, 940 MB at the worst case's 131,072) is recomputed
 EXPERT_KEEPS = (experts_op.KEPT,)
 # a ``kda`` layer keeps its scan's result (67 MB a layer at 8192 tokens x 32
-# heads of 128): its scan works a group of heads at a time, each group's pass
-# recomputed in its own backward pass (``ops.delta_rule.HEADS_AT_ONCE``), so
-# with ``o`` kept the block's recomputation has no use for a second forward
-# scan and the scan runs twice a step, not three times
+# heads of 128): the scan's backward kernel keeps nothing of the forward
+# call but its operands (``ops.delta_rule``), so with ``o`` kept the block's
+# recomputation has no use for a second forward call and the scan runs once
+# a step, not twice
 KDA_KEEPS = (delta_rule.SAVED_OUTPUT,)
 
 
@@ -744,6 +745,13 @@ class HybridLM(nn.Module):
                 f"{self.delta_heads_total or self.delta_heads}, keys of "
                 f"{self.delta_key_dim}, values of {self.delta_value_dim}: "
                 "not a delta-rule layer's share")
+        if KDA in kinds and not (
+                delta_rule.LOG_DECAY_FLOOR <= self.kda_decay_floor <= 0):
+            raise ValueError(
+                f"kda_decay_floor {self.kda_decay_floor}: the channel-decay "
+                "scan bears log-decays a token and channel from "
+                f"{delta_rule.LOG_DECAY_FLOOR} to 0 (a sub-block's operands "
+                "are decayed from its middle row: float32's exponent)")
         if (self.norm_placement not in ("pre", "post")
                 or self.qk_norm_over not in ("head", "projection")):
             raise ValueError(
@@ -944,10 +952,11 @@ class HybridLM(nn.Module):
         if delta:
             by_kind = {kind: self.layer_types.count(kind)
                        for kind in (DELTA, KDA) if kind in self.layer_types}
+            decays = [{DELTA: "head", KDA: "channel"}[kind] for kind in by_kind]
             facts.update({
                 **{f"layer_kinds.{kind}": n for kind, n in by_kind.items()},
-                "delta.decay": ",".join(
-                    {DELTA: "head", KDA: "channel"}[kind] for kind in by_kind),
+                "delta.decay": ",".join(decays),
+                "delta.scan": ",".join(delta_rule.SCAN[d] for d in decays),
                 "delta.heads_held": self.delta_heads,
                 "delta.heads_total": self.delta_heads_total or self.delta_heads,
                 "delta.chunk": min(delta_rule.CHUNK, t),

@@ -60,7 +60,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from raydp_tpu.ops.backend import pallas_interpret
+from raydp_tpu.ops.backend import VMEM_ASK_BOUND_BYTES, pallas_interpret
 
 NEG_INF = -1e30
 
@@ -476,10 +476,10 @@ def _flash_forward(
 # recomputes the tile. All three kernels take the tile from ``_bwd_tile``.
 # ---------------------------------------------------------------------------
 
-# the default scoped VMEM of a Mosaic call on the chips this runs on, and what
-# the fused call may ask for of a core's 128 MiB through ``vmem_limit_bytes``
+# the default scoped VMEM of a Mosaic call on the chips this runs on; what
+# the fused call may ask for through ``vmem_limit_bytes`` is
+# ``VMEM_ASK_BOUND_BYTES`` (``ops/backend.py``)
 VMEM_DEFAULT_BYTES = 16 * 2**20
-VMEM_ASK_BOUND_BYTES = 96 * 2**20
 
 
 def _bwd_tile(

@@ -43,8 +43,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.sharding import PartitionSpec as P
 
-from raydp_tpu.ops.backend import pallas_interpret
+from raydp_tpu.ops.backend import (
+    BATCH_AXES, active_mesh, pallas_interpret, per_shard, unpartitioned)
 
 LANES, SUBLANES = 128, 8
 # samples a grid step of the kernel (whole lane tiles): a tile's batch-major
@@ -171,16 +173,9 @@ def dot_interaction_pallas(
         jnp.transpose(stacked, (1, 2, 0)), block_batch, interpret)
 
 
-def _active_mesh():
-    """The mesh governing the current trace (``jax.set_mesh``), or None when
-    no mesh context is active."""
-    mesh = jax.sharding.get_abstract_mesh()
-    return mesh if mesh.shape else None
-
-
 def interaction_fused(
     slabs: jnp.ndarray,
-    batch_axes: Sequence[str] = ("data", "dp", "batch"),
+    batch_axes: Sequence[str] = BATCH_AXES,
     block_batch: int | None = None,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
@@ -195,33 +190,20 @@ def interaction_fused(
     names that may shard B; any other axes see replicated data. Where the
     kernel cannot run (:func:`supports`, or several devices and no mesh) this
     is the einsum."""
-    mesh = _active_mesh()
-    if supports(slabs.shape[1], slabs.dtype) or (
-            mesh is None and jax.device_count() > 1):
-        # a multi-device jit with NO mesh context (plain in_shardings style)
-        # would hand the Mosaic kernel to the auto-partitioner, which raises
-        # NotImplementedError — use the einsum path there
+    mesh = active_mesh()
+    if supports(slabs.shape[1], slabs.dtype) or unpartitioned():
         return interaction_xla(slabs)
     if mesh is None or int(np.prod(list(mesh.shape.values()))) == 1:
         return interaction_pallas(slabs, block_batch, interpret)
-    from jax.sharding import PartitionSpec as P
-
-    present = tuple(a for a in batch_axes if mesh.shape.get(a, 1) > 1)
-    fn = jax.shard_map(
-        partial(interaction_pallas, block_batch=block_batch, interpret=interpret),
-        mesh=mesh,
-        in_specs=P(None, None, present if present else None),
-        out_specs=P(present if present else None, None),
-        # the pallas interpreter can't reconcile invariant grid slices with
-        # varying operands; numerics are test-validated against the einsum
-        check_vma=False,
-    )
-    return fn(slabs)
+    return per_shard(
+        partial(interaction_pallas, block_batch=block_batch,
+                interpret=interpret), mesh,
+        lambda batch: (P(None, None, batch), P(batch, None)), batch_axes)(slabs)
 
 
 def dot_interaction_fused(
     stacked: jnp.ndarray,
-    batch_axes: Sequence[str] = ("data", "dp", "batch"),
+    batch_axes: Sequence[str] = BATCH_AXES,
     block_batch: int | None = None,
     interpret: bool | None = None,
 ) -> jnp.ndarray:

@@ -715,6 +715,7 @@ def test_delta_fit_facts_say_what_a_row_holds():
             facts["layer_kinds.mamba"]) == (3, 1, 0)
     assert (facts["delta.heads_held"], facts["delta.heads_total"],
             facts["delta.chunk"]) == (15, 30, delta_rule.CHUNK)
+    assert (facts["delta.decay"], facts["delta.scan"]) == ("head", "plain")
     assert facts["delta.flops_per_row"] == 3 * 3 * 6 * 96 * 192 * 15 * t
     assert facts["delta.state_bytes_per_row"] == 3 * 15 * 192 * 96 * 4
     linear = 3840 * (1440 + 1440 + 2880 + 2880 + 30) + 2880 * 3840

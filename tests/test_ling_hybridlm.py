@@ -143,6 +143,18 @@ def test_from_config_refuses_what_the_family_does_not_build(change, match):
         HybridLM.from_config(_config(**change))
 
 
+def test_a_decay_floor_the_scan_cannot_bear_is_refused(batch):
+    """A sub-block's operands are decayed from its middle row: 8 tokens at
+    the floor are an exponent float32 must hold."""
+    from raydp_tpu.ops import delta_rule
+
+    assert delta_rule.LOG_DECAY_FLOOR == -10.0
+    m = model(kda_decay_floor=-12.0)
+    with pytest.raises(ValueError, match="kda_decay_floor -12.0"):
+        jax.eval_shape(lambda: m.init(jax.random.PRNGKey(0), batch, None,
+                                      method="loss"))
+
+
 def test_the_familys_fields_follow_the_config():
     m = model()
     assert (m.num_heads, m.head_dim, m.rope_head_dim, m.value_width,
@@ -169,6 +181,7 @@ def test_fit_facts_say_what_a_row_holds(batch):
     assert facts["layer_kinds"] == "kda,kda,kda,kda,mla,kda"
     want = {
         "layer_kinds.kda": 5, "layer_kinds.mla": 1, "delta.decay": "channel",
+        "delta.scan": "kernel",
         "delta.heads_held": 2, "delta.heads_total": 2, "delta.chunk": 32,
         "delta.state_bytes_per_row": 5 * 4 * 2 * 16 * 16,
         "attention.latent_rank": 32, "attention.key_width": 24,

@@ -1,7 +1,8 @@
 """The kernels' small compiles for a described TPU v5e: the flash kernels at
 every LM cell's widths (the forward and the one-call backward, bf16 and the
 matched check's float32), heads of 64, the grouped products of the routed
-cells (``tpu_compile_helpers`` says how and why)."""
+cells, the channel-decay delta rule's two kernels (``tpu_compile_helpers``
+says how and why)."""
 
 import importlib
 import re
@@ -135,3 +136,69 @@ def test_grouped_products_compile_at_lfm2_widths(
     calls = re.findall(rf"(%[\w.\-]*{kernel}[\w.\-]*) = \S+ custom-call", text)
     assert len([c for c in calls if "metadata" not in c]) == 2, calls
     assert all(moe_costs.GMM.search(c) for c in calls)
+
+
+@pytest.mark.parametrize("dtype, precision", [
+    (jnp.bfloat16, None), (jnp.float32, "highest")],
+    ids=["bf16", "float32_matched"])
+def test_channel_delta_rule_kernels_compile_at_the_ling_cell_shape(
+        one_chip, no_compile_cache, kernels_compile, dtype, precision):
+    """ISSUE 50: [1, 8192 tokens, 32 heads, 128] keys and values, the decay
+    [.., 128] and beta float32: the forward call and the backward call of
+    ``ops.delta_rule.channel_gated_delta_rule`` go through Mosaic in the
+    timed step's bf16 and in the matched check's float32 (products at
+    ``highest``), ONE call each (a gradient alone holds no forward call:
+    the backward keeps only the operands), within the VMEM the flash
+    kernels may ask for, on the model's own [T, H * 128] layout: no
+    ``[.., c, d]`` head-major copy, no triangular solve, no loop."""
+    fa = importlib.import_module("raydp_tpu.ops.flash_attention")
+    from raydp_tpu.ops import delta_rule
+
+    t, h, d = 8192, 32, 128
+    assert (delta_rule.vmem_bytes(t, d, d, backward=False)
+            < delta_rule.vmem_bytes(t, d, d) <= fa.VMEM_ASK_BOUND_BYTES)
+    x = jax.ShapeDtypeStruct((1, t, h, d), dtype, sharding=one_chip)
+    decay = jax.ShapeDtypeStruct((1, t, h, d), jnp.float32, sharding=one_chip)
+    beta = jax.ShapeDtypeStruct((1, t, h), jnp.float32, sharding=one_chip)
+
+    def forward(q, k, v, log_alpha, beta):
+        with jax.default_matmul_precision(precision):
+            return delta_rule.channel_gated_delta_rule(
+                q, k, v, log_alpha, beta)
+
+    def grads(*operands):
+        return jax.grad(lambda *a: forward(*a).astype(jnp.float32).sum(),
+                        argnums=(0, 1, 2, 3, 4))(*operands)
+
+    for f, here, gone in ((forward, "delta_rule_fwd", "delta_rule_bwd"),
+                          (grads, "delta_rule_bwd", "delta_rule_fwd")):
+        text = jax.jit(f).lower(x, x, x, decay, beta).compile().as_text()
+        assert len(re.findall(rf"%[\w.\-]*{here}[\w.\-]* = ", text)) == 1
+        assert not re.search(rf"%[\w.\-]*{gone}[\w.\-]* = ", text)
+        assert text.count("tpu_custom_call") == 1
+        assert "triangular" not in text and " while(" not in text
+    shapes = [(leaf.shape, leaf.dtype) for leaf in jax.tree.leaves(
+        jax.eval_shape(grads, x, x, x, decay, beta))]
+    assert shapes == [((1, t, h, d), dtype)] * 3 + [
+        ((1, t, h, d), jnp.float32), ((1, t, h), jnp.float32)]
+
+
+@pytest.mark.parametrize("t, d, devices, why", [
+    (8192, 96, 1, "whole 128-lane tiles"), (8192, 128, 4, "no mesh"),
+    (32768, 128, 1, "every chunk's inverse")],
+    ids=["heads_of_96", "devices_and_no_mesh", "vmem_at_32k"])
+def test_channel_delta_rule_refuses_what_mosaic_cannot_take(
+        kernels_compile, monkeypatch, t, d, devices, why):
+    """What the interpreter bears and the chip does not is refused by the op
+    with its cause, not inside Mosaic or XLA's partitioner: heads that are
+    not whole lane tiles, several devices with no mesh, a sequence whose
+    kept inverses and states pass the VMEM a call may ask for."""
+    from raydp_tpu.ops import delta_rule
+
+    monkeypatch.setattr(jax, "device_count", lambda *_: devices)
+    x = jax.ShapeDtypeStruct((1, t, 4, d), jnp.bfloat16)
+    decay = jax.ShapeDtypeStruct((1, t, 4, d), jnp.float32)
+    beta = jax.ShapeDtypeStruct((1, t, 4), jnp.float32)
+    with pytest.raises(ValueError, match=why):
+        jax.eval_shape(delta_rule.channel_gated_delta_rule,
+                       x, x, x, decay, beta)
