@@ -190,6 +190,7 @@ from raydp_tpu.models.looplm import (
 from raydp_tpu.models.transformer import _attend, attention_backward_facts
 from raydp_tpu.ops import experts as experts_op
 from raydp_tpu.ops import delta_rule
+from raydp_tpu.ops import kda_mixer
 from raydp_tpu.ops.flash_attention import SAVED_RESIDUALS
 from raydp_tpu.ops.ssd import ssd_chunk_scan
 
@@ -218,8 +219,13 @@ EXPERT_KEEPS = (experts_op.KEPT,)
 # heads of 128): the scan's backward kernel keeps nothing of the forward
 # call but its operands (``ops.delta_rule``), so with ``o`` kept the block's
 # recomputation has no use for a second forward call and the scan runs once
-# a step, not twice
-KDA_KEEPS = (delta_rule.SAVED_OUTPUT,)
+# a step, not twice; where the mixer's chain around the scan runs as
+# ``ops.kda_mixer``'s kernels it keeps their results too (the scan's operands:
+# q, k, v at 67 MB each and the float32 log-decay at 134; ``W_o``'s input at
+# 67), so that the recomputed block needs no second call of either forward
+# kernel: 470 MB a layer in all, and the element-wise passes run twice a step
+# and not three times (the plain chain names nothing, and keeps ``o`` alone)
+KDA_KEEPS = (delta_rule.SAVED_OUTPUT, kda_mixer.OPERANDS, kda_mixer.READ_OUT)
 
 
 def _inverse_softplus(x):
@@ -965,6 +971,19 @@ class HybridLM(nn.Module):
                 # from token to token
                 "delta.state_bytes_per_row": delta * 4 * self.delta_heads
                 * self.delta_key_dim * self.delta_value_dim})
+            # what runs the chain AROUND the scan: a ``kda`` mixer's is
+            # ``ops.kda_mixer``'s fused kernels wherever they take the sizes;
+            # a ``delta`` mixer's scan is plain ``jnp`` and has no layout of
+            # its own to share
+            mixers = {kind: self._kda_mixer(t) if kind == KDA else (
+                "xla", "a decay a head: no kernel runs its scan")
+                for kind in by_kind}
+            whys = [why_not for _, why_not in mixers.values() if why_not]
+            facts["delta.mixer"] = ",".join(m for m, _ in mixers.values())
+            if whys:
+                facts["delta.mixer_why_not"] = "; ".join(whys)
+            if mixers.get(KDA, ("",))[0] == "kernel":
+                facts["delta.mixer_fused_layers"] = by_kind[KDA]
         attention = [w for kind, w in zip(self.layer_types, self.layer_windows)
                      if kind == ATTENTION]
         kinds = {"global": attention.count(0),
@@ -1060,9 +1079,17 @@ class HybridLM(nn.Module):
             kept[experts_op.KEPT] = self.expert_layers * 4 * (
                 5 * t * self.experts_per_token + t)
         if KDA in self.layer_types:
-            kept[delta_rule.SAVED_OUTPUT] = (
-                self.layer_types.count(KDA) * t * self.delta_heads
-                * self.delta_value_dim * itemsize)
+            rows = self.layer_types.count(KDA) * t * self.delta_heads
+            keys, values = rows * self.delta_key_dim, rows * self.delta_value_dim
+            kept[delta_rule.SAVED_OUTPUT] = values * itemsize
+            if self._kda_mixer(t)[0] == "kernel":
+                # what the fused chain hands the scan (q, k, v in the compute
+                # dtype, the log-decay float32: the operands its backward
+                # call reads) and W_o's input: kept, no call of the chain
+                # runs twice a step
+                kept[kda_mixer.OPERANDS] = (
+                    (2 * keys + values) * itemsize + 4 * keys)
+                kept[kda_mixer.READ_OUT] = values * itemsize
         return kept
 
     def epoch_facts(self, report: dict, steps: int) -> dict:
@@ -1193,26 +1220,47 @@ class HybridLM(nn.Module):
         ``beta`` in (0, 1), a log-decay A CHANNEL bounded below
         (``kda_decay_floor`` x a sigmoid), ``ops.delta_rule.
         channel_gated_delta_rule``, the read-out normed a head and gated by
-        ONE sigmoid a head before ``W_o``."""
+        ONE sigmoid a head before ``W_o``. Around the scan the chain runs as
+        ``ops.kda_mixer``'s fused kernels on the scan's own ``[T, H x d]``
+        layout wherever they take the sizes (``_kda_mixer`` says), and as
+        plain ``jnp`` where not: the same arithmetic."""
         with obs.device_scope("hybridlm.delta"):
             b, t, _ = a.shape
             heads, dk, dv = (self.delta_heads, self.delta_key_dim,
                              self.delta_value_dim)
             f32 = jnp.float32
-            q, k, v = self._delta_qkv(w, a)
+            fused = self._kda_mixer(t)[0] == "kernel"
+            if fused:  # [B, T, H x d] from here to W_o: the scan's layout
+                q, k, v, log_alpha = kda_mixer.operands(
+                    *(self._dot(a, w[name])
+                      for name in ("wq", "wk", "wv", "wf")),
+                    w["conv_w"], w["A_log"], w["dt_bias"],
+                    self.kda_decay_floor)
+            else:
+                q, k, v = (x.astype(self.dtype)
+                           for x in self._delta_qkv(w, a))
+                log_alpha = self.kda_decay_floor * jax.nn.sigmoid(
+                    jnp.exp(w["A_log"])[:, None] * (
+                        self._dot(a, w["wf"]).astype(f32) + w["dt_bias"]
+                    ).reshape(b, t, heads, dk))
             beta = jax.nn.sigmoid(self._dot(a, w["wb"]).astype(f32))
-            log_alpha = self.kda_decay_floor * jax.nn.sigmoid(
-                jnp.exp(w["A_log"])[:, None] * (
-                    self._dot(a, w["wf"]).astype(f32) + w["dt_bias"]
-                ).reshape(b, t, heads, dk))
-            o = delta_rule.channel_gated_delta_rule(
-                q.astype(self.dtype), k.astype(self.dtype),
-                v.astype(self.dtype), log_alpha, beta)
+            o = delta_rule.channel_gated_delta_rule(q, k, v, log_alpha, beta)
             gate = jax.nn.sigmoid(self._dot(a, w["wg"]).astype(f32))
+            if fused:
+                return self._dot(kda_mixer.read_out(
+                    o, gate, w["gate_norm"], self.rms_eps), w["wo"])
             o = rms_norm(o.astype(f32), w["gate_norm"], self.rms_eps)
             return self._dot(
                 (o * gate[..., None]).reshape(b, t, heads * dv).astype(
                     self.dtype), w["wo"])
+
+    def _kda_mixer(self, t: int) -> tuple:
+        """(``kernel`` | ``xla``, why not the kernels: "" where they run):
+        what runs a ``kda`` mixer's chain around its scan on rows of ``t``
+        tokens (``ops.kda_mixer.refused``: from the sizes alone)."""
+        why_not = kda_mixer.refused(t, self.delta_conv, self.delta_key_dim,
+                                    self.delta_value_dim)
+        return ("xla" if why_not else "kernel"), why_not or ""
 
     def _latent_attention(self, w, y):
         """The ``mla`` mixer's training side on ``y`` [B, T, D]: K's

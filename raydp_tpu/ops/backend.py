@@ -60,3 +60,12 @@ def per_shard(fn, mesh, specs, batch_axes=BATCH_AXES):
     # varying operands; numerics are test-validated against the plain forms
     return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
+
+
+def per_shard_under_mesh(fn, specs):
+    """``per_shard(fn, <the active mesh>, specs)`` where a mesh is active and
+    splits anything; ``fn`` itself on one device or with no mesh."""
+    mesh = active_mesh()
+    if mesh is not None and any(n > 1 for n in mesh.shape.values()):
+        return per_shard(fn, mesh, specs)
+    return fn

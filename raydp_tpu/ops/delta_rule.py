@@ -77,7 +77,9 @@ WHERE THE CHANNEL FORM'S DATA LIVES. The two calls (``delta_rule_fwd``,
 ``delta_rule_bwd``) read ``q``, ``k``, ``v``, ``log_alpha`` as ``[b, t, h *
 d]`` with a head's channels one block column, and ``beta`` as ``[b, t, h]``
 (a step picks its head's column): a reshape of what the mixer holds, no
-head-major copy. The grid is (batch, heads by ``HEADS_A_STEP``, token tile),
+head-major copy; a caller that holds that flat form already
+(``ops/kda_mixer.py``'s kernels write it) hands it over as it is and gets
+``o`` back flat. The grid is (batch, heads by ``HEADS_A_STEP``, token tile),
 the tiles of ``CHUNKS_A_STEP`` chunks in order. Of a tile, ``_chunk_matrices``
 first works every chunk of the step's heads at once (the running sums,
 ``QK``, ``KK``, the inverse, ``U0 = T beta V`` and ``W = T beta K exp(G)``:
@@ -142,7 +144,7 @@ from jax.sharding import PartitionSpec as P
 
 from raydp_tpu import obs
 from raydp_tpu.ops.backend import (
-    VMEM_ASK_BOUND_BYTES, active_mesh, pallas_interpret, per_shard,
+    VMEM_ASK_BOUND_BYTES, pallas_interpret, per_shard_under_mesh,
     unpartitioned)
 
 SCOPE = "delta_rule"
@@ -732,14 +734,20 @@ def _backward_kernel(q_ref, k_ref, v_ref, la_ref, beta_ref, do_ref,
                 chunks, c)
 
 
-def _layout(q, v, c: int):
-    """The calls' view of the operands: ``[b, t, h * d]``, a head's channels
-    one block column, in grid steps of ``tile`` tokens and ``heads`` heads."""
-    b, t, h, dk = q.shape
+def grid_step(t: int, h: int, c: int):
+    """(tokens, heads) a grid step of the two calls takes of ``t`` tokens in
+    chunks of ``c`` and ``h`` heads."""
     chunks = t // c
-    tile = c * (CHUNKS_A_STEP if chunks % CHUNKS_A_STEP == 0 else chunks)
-    heads = HEADS_A_STEP if h % HEADS_A_STEP == 0 else 1
-    return b, t, h, dk, v.shape[-1], tile, heads
+    return (c * (CHUNKS_A_STEP if chunks % CHUNKS_A_STEP == 0 else chunks),
+            HEADS_A_STEP if h % HEADS_A_STEP == 0 else 1)
+
+
+def _layout(q, v, beta, c: int):
+    """The calls' view of the operands: ``q`` [b, t, h * dk] and ``v`` [b,
+    t, h * dv], a head's channels one block column (``beta`` [b, t, h] says
+    how many heads), in grid steps of ``tile`` tokens and ``heads`` heads."""
+    b, t, h = beta.shape
+    return (b, t, h, q.shape[2] // h, v.shape[2] // h) + grid_step(t, h, c)
 
 
 def _lanes(width: int) -> int:
@@ -762,13 +770,13 @@ def _params(semantics, vmem: int):
                                 vmem_limit_bytes=vmem)
 
 
-def _cost(q, v, passes: int, gradients: bool):
+def _cost(q, v, beta, passes: int, gradients: bool):
     """The RECURRENCE's operations and bytes (``recurrence_flops`` once
     forward, twice more backward; every operand read and every result
     written once), not the chunked form's: what XLA's count of the program
     then holds for the call."""
-    b, t, h, dk = q.shape
-    dv, size = v.shape[-1], q.dtype.itemsize
+    b, t, h = beta.shape
+    dk, dv, size = q.shape[2] // h, v.shape[2] // h, q.dtype.itemsize
     rows = b * t * h
     read = rows * ((2 * dk + dv) * size + (dk + 1) * 4)
     wrote = rows * ((2 * dk + dv) * size + (dk + 1) * 4 if gradients
@@ -779,15 +787,24 @@ def _cost(q, v, passes: int, gradients: bool):
         bytes_accessed=read + wrote + (rows * dv * size if gradients else 0))
 
 
-def _forward_call(q, k, v, log_alpha, beta, c: int, s: int):
-    b, t, h, dk, dv, tile, heads = _layout(q, v, c)
+# THE TWO CALLS' ENTRIES ARE EACH ONE ``jax.jit`` of the module: a model's
+# layers of one shape share a trace of the kernel's body and one lowered
+# function (a Mosaic call is traced and lowered once a layer and pass
+# otherwise: 0.9 s a layer for this pair, ``PERF.md`` §6, PR 52). What a trace
+# reads of the process (``pallas_interpret``) is an argument, so that the
+# cache keeps the two answers apart; the scope and the ``checkpoint_name``
+# stay with the callers, outside
+@functools.partial(jax.jit, static_argnames=("c", "s", "interpret"))
+def _forward_call(q, k, v, log_alpha, beta, *, c: int, s: int,
+                  interpret: bool):
+    b, t, h, dk, dv, tile, heads = _layout(q, v, beta, c)
     chunks = heads * tile // c
 
     def at(d):
         return pl.BlockSpec((1, tile, heads * d),
                             lambda bi, hi, ti: (bi, ti, hi))
 
-    o = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_forward_kernel, c=c, s=s, heads=heads),
         grid=(b, h // heads, t // tile),
         in_specs=[at(dk), at(dk), at(dv), at(dk),
@@ -803,16 +820,16 @@ def _forward_call(q, k, v, log_alpha, beta, c: int, s: int):
             pltpu.VMEM((chunks, c, c), q.dtype)],
         compiler_params=_params(("parallel", "parallel", "arbitrary"),
                                 vmem_bytes(t, dk, dv, c, False)),
-        cost_estimate=_cost(q, v, 1, False),
-        interpret=pallas_interpret(None),
+        cost_estimate=_cost(q, v, beta, 1, False),
+        interpret=interpret,
         name="delta_rule_fwd",
-    )(q.reshape(b, t, h * dk), k.reshape(b, t, h * dk),
-      v.reshape(b, t, h * dv), log_alpha.reshape(b, t, h * dk), beta)
-    return o.reshape(b, t, h, dv)
+    )(q, k, v, log_alpha, beta)
 
 
-def _backward_call(q, k, v, log_alpha, beta, do, c: int, s: int):
-    b, t, h, dk, dv, tile, heads = _layout(q, v, c)
+@functools.partial(jax.jit, static_argnames=("c", "s", "interpret"))
+def _backward_call(q, k, v, log_alpha, beta, do, *, c: int, s: int,
+                   interpret: bool):
+    b, t, h, dk, dv, tile, heads = _layout(q, v, beta, c)
     tiles, chunks = t // tile, heads * tile // c
 
     # pass 0 walks the tiles up and pass 1 down; what only pass 1 touches
@@ -830,7 +847,7 @@ def _backward_call(q, k, v, log_alpha, beta, do, c: int, s: int):
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype)
 
-    grads = pl.pallas_call(
+    dq, dk, dv, dla, dbeta = pl.pallas_call(
         functools.partial(_backward_kernel, c=c, s=s, heads=heads),
         grid=(b, h // heads, 2, tiles),
         in_specs=[walked(dk_), walked(dk_), walked(dv_), walked(dk_),
@@ -859,33 +876,29 @@ def _backward_call(q, k, v, log_alpha, beta, do, c: int, s: int):
         compiler_params=_params(
             ("parallel", "parallel", "arbitrary", "arbitrary"),
             vmem_bytes(t, dk, dv, c)),
-        cost_estimate=_cost(q, v, 2, True),
-        interpret=pallas_interpret(None),
+        cost_estimate=_cost(q, v, beta, 2, True),
+        interpret=interpret,
         name="delta_rule_bwd",
-    )(q.reshape(b, t, h * dk), k.reshape(b, t, h * dk),
-      v.reshape(b, t, h * dv), log_alpha.reshape(b, t, h * dk), beta,
-      do.reshape(b, t, h * dv))
-    dq, dk, dv, dla, dbeta = grads
-    return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape),
-            dla.reshape(log_alpha.shape),
-            jnp.moveaxis(dbeta.reshape(b, h, t), 1, 2))
+    )(q, k, v, log_alpha, beta, do)
+    return dq, dk, dv, dla, jnp.moveaxis(dbeta.reshape(b, h, t), 1, 2)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _channel_rule(q, k, v, log_alpha, beta, c: int, s: int):
-    return _forward_call(q, k, v, log_alpha, beta, c, s)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _channel_rule(q, k, v, log_alpha, beta, c: int, s: int, interpret: bool):
+    return _forward_call(q, k, v, log_alpha, beta, c=c, s=s,
+                         interpret=interpret)
 
 
-def _channel_rule_fwd(q, k, v, log_alpha, beta, c, s):
+def _channel_rule_fwd(q, k, v, log_alpha, beta, c, s, interpret):
     # nothing of the forward call is kept: the backward call recomputes the
     # states from the operands
-    return (_forward_call(q, k, v, log_alpha, beta, c, s),
-            (q, k, v, log_alpha, beta))
+    return (_forward_call(q, k, v, log_alpha, beta, c=c, s=s,
+                          interpret=interpret), (q, k, v, log_alpha, beta))
 
 
-def _channel_rule_bwd(c, s, operands, do):
+def _channel_rule_bwd(c, s, interpret, operands, do):
     with obs.device_scope(SCOPE):
-        return _backward_call(*operands, do, c, s)
+        return _backward_call(*operands, do, c=c, s=s, interpret=interpret)
 
 
 _channel_rule.defvjp(_channel_rule_fwd, _channel_rule_bwd)
@@ -895,29 +908,41 @@ def channel_gated_delta_rule(q, k, v, log_alpha, beta, chunk: int = CHUNK,
                              sub: int = SUB):
     """``gated_delta_rule`` with A DECAY A CHANNEL: ``log_alpha``
     [b, t, h, dk] (<= 0), float32; the other operands and the result as
-    there. ``sub`` must divide the chunk (a chunk shorter than a sub-block
-    is one) a power of two times."""
-    t = q.shape[1]
+    there. Or FLAT, as the two calls read and write them: ``q``, ``k``,
+    ``log_alpha`` [b, t, h x dk] and ``v`` [b, t, h x dv], a head's channels
+    side by side (``beta`` [b, t, h] says how many heads); ``o`` is then
+    [b, t, h x dv] and no ``[t, h, d]`` view is formed anywhere. ``sub``
+    must divide the chunk (a chunk shorter than a sub-block is one) a power
+    of two times."""
+    b, t, h = beta.shape
+    flat = q.ndim == 3
+    if not flat:
+        q, k, v, log_alpha = (x.reshape(b, t, -1)
+                              for x in (q, k, v, log_alpha))
     c = min(int(chunk), t)
     s = min(int(sub), c)
     if t % c or c % s or (c // s) & (c // s - 1):
         raise ValueError(f"chunk {c} does not divide the sequence length {t}"
                          f", or sub-block {s} the chunk a power of two times")
-    why_not = None if pallas_interpret(None) else _refused(q, v, c)
+    why_not = None if pallas_interpret(None) else _refused(
+        t, q.shape[2] // h, v.shape[2] // h, c)
     if why_not:
         raise ValueError(f"channel_gated_delta_rule on a TPU: {why_not}")
-    rule, mesh = functools.partial(_channel_rule, c=c, s=s), active_mesh()
-    if mesh is not None and any(n > 1 for n in mesh.shape.values()):
-        rule = per_shard(rule, mesh, lambda batch: ((P(batch),) * 5, P(batch)))
+    rule = per_shard_under_mesh(
+        functools.partial(_channel_rule, c=c, s=s,
+                          interpret=pallas_interpret(None)),
+        lambda batch: ((P(batch),) * 5, P(batch)))
     with obs.device_scope(SCOPE):
-        o = rule(q, k, v, log_alpha.astype(F32), beta.astype(F32))
-        return checkpoint_name(o, SAVED_OUTPUT)
+        o = checkpoint_name(
+            rule(q, k, v, log_alpha.astype(F32), beta.astype(F32)),
+            SAVED_OUTPUT)
+        return o if flat else o.reshape(b, t, h, -1)
 
 
-def _refused(q, v, c: int):
-    """Why the two Mosaic calls cannot take these operands (None: they can):
-    what the interpreter bears and the chip does not."""
-    t, dk, dv = q.shape[1], q.shape[3], v.shape[3]
+def _refused(t: int, dk: int, dv: int, c: int):
+    """Why the two Mosaic calls cannot take a sequence of ``t`` tokens in
+    chunks of ``c`` at heads of ``dk`` x ``dv`` (None: they can): what the
+    interpreter bears and the chip does not."""
     if dk % 128 or dv % 128:
         return (f"heads of {dk} x {dv}: a head's channels are a block column "
                 "of whole 128-lane tiles")

@@ -83,7 +83,9 @@ def close(got_o, got_g, want_o, want_g, value=1e-5, gradient=2e-4):
 def test_the_chunked_form_is_the_recurrence(operands, decay, chunk, tokens):
     log_alpha = operands["decays"][decay]
     *_, tile, heads = delta_rule._layout(
-        operands["q"][:, :tokens], operands["v"][:, :tokens], chunk)
+        operands["q"][:, :tokens].reshape(B, tokens, H * DK),
+        operands["v"][:, :tokens].reshape(B, tokens, H * DV),
+        operands["beta"][:, :tokens], chunk)
     assert heads == 2  # the four heads go two a grid step, side by side
     assert tokens // tile == (2 if (chunk, tokens) == (16, 256) else 1)
     want_o, want_g = both(recurrence, operands, log_alpha, tokens)

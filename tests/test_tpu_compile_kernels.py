@@ -202,3 +202,49 @@ def test_channel_delta_rule_refuses_what_mosaic_cannot_take(
     with pytest.raises(ValueError, match=why):
         jax.eval_shape(delta_rule.channel_gated_delta_rule,
                        x, x, x, decay, beta)
+
+
+@pytest.mark.parametrize("dtype, precision", [
+    (jnp.bfloat16, None), (jnp.float32, "highest")],
+    ids=["bf16", "float32_matched"])
+def test_kda_mixer_kernels_compile_at_the_ling_cell_shape(
+        one_chip, no_compile_cache, kernels_compile, dtype, precision):
+    """ISSUE 52: the four calls of ``ops.kda_mixer`` at [1, 8192 tokens, 32
+    heads x 128] in the timed step's bf16 and the matched check's float32,
+    the scan's two calls between them on the same flat layout: FIVE Mosaic
+    calls in a gradient (the read-out's forward call makes nothing a
+    backward pass reads) and no ``[T, H, 128]`` view between any two (no
+    copy, no transpose of a mixer's channels); what the kernels refuse at
+    such sizes is what the scan refuses."""
+    from raydp_tpu.ops import delta_rule, kda_mixer
+
+    t, h, d = 8192, 32, 128
+    assert kda_mixer.refused(t, 4, d, d) is None
+    assert "whole 128-lane tiles" in kda_mixer.refused(t, 4, 96, 192)
+    wide = jax.ShapeDtypeStruct((1, t, h * d), dtype, sharding=one_chip)
+
+    def f32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def mixer(pq, pk, pv, pf, conv_w, a_log, dt_bias, beta, gate, gain):
+        with jax.default_matmul_precision(precision):
+            o = delta_rule.channel_gated_delta_rule(*kda_mixer.operands(
+                pq, pk, pv, pf, conv_w, a_log, dt_bias, -5.0), beta)
+            return kda_mixer.read_out(o, gate, gain, 1e-6)
+
+    def grads(*given):
+        return jax.grad(lambda *a: mixer(*a).astype(jnp.float32).sum(),
+                        argnums=tuple(range(10)))(*given)
+
+    given = (wide, wide, wide, wide, f32(4, 3 * h * d), f32(h), f32(h * d),
+             f32(1, t, h), f32(1, t, h), f32(d))
+    text = jax.jit(grads).lower(*given).compile().as_text()
+    for name in ("kda_operands_fwd", "kda_operands_bwd", "delta_rule_fwd",
+                 "delta_rule_bwd", "kda_read_out_bwd"):
+        assert len(re.findall(rf"%[\w.\-]*{name}[\w.\-]* = ", text)) == 1, name
+    assert text.count("tpu_custom_call") == 5
+    assert f"[{t},{h},{d}]" not in text and f"[{t // 8},8,{h},{d}]" not in text
+    assert " transpose(" not in text
+    got = jax.eval_shape(grads, *given)
+    assert [(g.shape, g.dtype) for g in got] == [
+        (x.shape, x.dtype) for x in given]
