@@ -2,7 +2,8 @@
 
 from raydp_tpu.models.dlrm import DLRM, dlrm_optimizer, dlrm_sharding_rules
 from raydp_tpu.models.hybridlm import (
-    DeltaHybridLM, HybridLM, LatentDeltaHybridLM, RoutedHybridLM,
+    DeltaHybridLM, HybridLM, LatentDeltaHybridLM, LatentMTPHybridLM,
+    RoutedHybridLM,
     hybridlm_optimizer)
 from raydp_tpu.models.looplm import LoopLM, looplm_optimizer
 from raydp_tpu.models.mlp import MLPClassifier, MLPRegressor
@@ -13,6 +14,7 @@ __all__ = [
     "DeltaHybridLM",
     "HybridLM",
     "LatentDeltaHybridLM",
+    "LatentMTPHybridLM",
     "LoopLM",
     "MLPClassifier",
     "MLPRegressor",

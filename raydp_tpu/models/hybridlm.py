@@ -1,6 +1,6 @@
 """Hybrid decoder LM: a MIXER KIND (``mamba``, ``attention``, ``conv``,
 ``delta``, ``kda``, ``mla``) and an FFN KIND (``dense``, ``experts``) per
-layer, in the order ``layer_types`` and ``ffn_types`` give. Five published
+layer, in the order ``layer_types`` and ``ffn_types`` give. Six published
 families are built from
 their ``config.json`` (``from_config`` reads ``model_type``): ``granitemoehybrid``
 with no experts (Mamba-2 and grouped-query attention without positions, a
@@ -19,7 +19,11 @@ sub-layer's OUTPUT is normed; this chip holds a share of every mixer's HEADS;
 an untied head) and ``bailing_hybrid`` (``layer_group_size`` - 1 ``kda``
 layers to one ``mla`` layer, both under a head-wise sigmoid output gate;
 leading dense SwiGLUs, then routed experts under a GROUP-LIMITED selection
-beside a SHARED expert every token passes; an untied head).
+beside a SHARED expert every token passes; an untied head) and
+``glm4_moe_lite`` (DeepSeek-V3's block: ``mla`` in EVERY layer with a
+LOW-RANK QUERY and no output gate, one leading dense SwiGLU, then routed
+experts beside a shared one, no group limit; an untied head; and one
+MULTI-TOKEN-PREDICTION module whose loss counts, below).
 
 ::
 
@@ -135,6 +139,7 @@ multi-head attention whose keys are wider than its values; the cache's
 latent form is the serving side and is not built)::
 
     q = W_q a = [q_nope | q_rope] a head          (head_dim = nope + rope_head_dim)
+      # ``query_rank`` > 0: q = W_qb RMSNorm(W_qa a; q_norm), a low-rank query
     [c | k_rope] = W_kva a                        (latent_rank | rope_head_dim)
     [k_nope | v] = W_kvb RMSNorm(c; kv_norm)      (nope | value_head_dim a head)
     k = [k_nope | RoPE(k_rope)], the ONE k_rope shared by all the heads;
@@ -142,8 +147,27 @@ latent form is the serving side and is not built)::
     pairs apart and rotates halves: q.k is the same)
     out = W_o [ softmax_causal(q.k / sqrt(head_dim)) v * sigmoid(W_g a)[head] ]
 
-scope ``hybridlm.attention`` with ``.latent`` around the two latent products;
-the flash kernels take v, o and do at ``value_head_dim``.
+(``latent_gate`` False: no gate, ``out = W_o [...]`` as it is); scope
+``hybridlm.attention`` with ``.query`` around the query's products and
+``.latent`` around the two latent products; the flash kernels take v, o and
+do at ``value_head_dim``.
+
+MULTI-TOKEN PREDICTION (``mtp_weight`` > 0: one module; DeepSeek-V3,
+arXiv:2412.19437 section 2.2, depth 1), on a row ``t_0 .. t_T``::
+
+    u_i = W_eh [ RMSNorm(E[t_{i+1}]; enorm) ; RMSNorm(h_L,i; hnorm) ]
+    g   = Block_mtp(u)                   # one more layer of the LAST layer's kinds
+    L   = L_main + mtp_weight x mean_{i <= T-2} CE(RMSNorm(g_i; final_norm) W_head, t_{i+2})
+
+``E`` and ``W_head`` are the MAIN model's (their gradients are the sum of
+both uses), ``h_L`` the stream the final norm READS, the block's positions
+the main model's; every array keeps its T rows and row T - 1, which has no
+target, weighs 0. The module's leaves are ``mtp_0``: the block's own, with
+``eh_proj`` [2 D, D], ``enorm``, ``hnorm`` and ``final_norm`` beside them;
+its router's ``expert_bias`` keeps that name, so ``hybridlm_optimizer``'s
+balancing rule moves it. Scopes: ``hybridlm.mtp`` around all of it,
+``.mtp.combine`` (two norms, the gather, ``eh_proj``), ``.mtp.loss``; the
+block under the scopes every block has. ``mtp_weight`` 0 builds nothing.
 
 ``mamba`` (Mamba-2; H heads of P, one group, state N)::
 
@@ -302,11 +326,18 @@ class HybridLM(nn.Module):
     expert_groups: int = 0  # group-limited selection: the experts' groups
     expert_groups_kept: int = 0  # and how many a token's choice may lie in
     expert_weight_eps: float = 1e-6  # beside the selected scores' sum
+    # -- what the sixth family adds; the defaults build the first five -------
+    query_rank: int = 0  # mla: q comes up from a normed latent this wide; 0: W_q
+    latent_gate: bool = True  # mla: one sigmoid a head gates the read-out
+    mtp_weight: float = 0.0  # > 0: ONE multi-token-prediction module, its loss x this
 
-    # what ``loss`` reports of a TRAINING step beside its loss, by name in
-    # its ``aux``: the estimator sums these over an epoch's steps inside the
-    # epoch program and gives the sums to ``epoch_facts``
-    train_report = ("expert_load", "pairs_dropped", "layers_at_full_bound")
+    @property
+    def train_report(self) -> tuple:
+        """What ``loss`` reports of a TRAINING step beside its loss, by name
+        in its ``aux``: the estimator sums these over an epoch's steps inside
+        the epoch program and gives the sums to ``epoch_facts``."""
+        return ("expert_load", "pairs_dropped", "layers_at_full_bound") + (
+            ("mtp_loss",) if self.mtp_built else ())
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -321,8 +352,8 @@ class HybridLM(nn.Module):
     def from_config(cls, config: dict, **kw):
         """From a published ``config.json``'s keys, by ``model_type``
         (``granitemoehybrid``, the default, ``lfm2_moe``, ``smallthinker``,
-        ``olmo_hybrid`` or ``bailing_hybrid``). What the model does not build
-        is refused, not ignored."""
+        ``olmo_hybrid``, ``bailing_hybrid`` or ``glm4_moe_lite``). What the
+        model does not build is refused, not ignored."""
         family = config.get("model_type", "granitemoehybrid")
         if family == "granitemoehybrid":
             fields = cls._granite_fields(config)
@@ -334,11 +365,20 @@ class HybridLM(nn.Module):
             fields = cls._olmo_hybrid_fields(config)
         elif family == "bailing_hybrid":
             fields = cls._bailing_hybrid_fields(config)
+        elif family == "glm4_moe_lite":
+            fields = cls._glm4_moe_lite_fields(config)
         else:
             raise ValueError(f"HybridLM builds model_type granitemoehybrid, "
-                             f"lfm2_moe, smallthinker, olmo_hybrid and "
-                             f"bailing_hybrid, not {family!r}")
+                             f"lfm2_moe, smallthinker, olmo_hybrid, "
+                             f"bailing_hybrid and glm4_moe_lite, not "
+                             f"{family!r}")
         fields.update(kw)  # attn_impl, dtype, remat, loss_chunk; overrides
+        if (fields.get("mtp_weight", 0) > 0
+                and not config.get("num_nextn_predict_layers")):
+            raise ValueError(
+                f"mtp_weight {fields['mtp_weight']} weighs a multi-token-"
+                "prediction module the configuration does not have "
+                "(num_nextn_predict_layers)")
         fields["dtype"] = jnp.dtype(fields.get("dtype", cls.dtype))
         return cls(**fields)
 
@@ -619,7 +659,95 @@ class HybridLM(nn.Module):
             attention_multiplier=(nope + rope) ** -0.5, logits_scaling=1.0,
             rms_eps=float(config["rms_norm_eps"]))
 
+    @classmethod
+    def _glm4_moe_lite_fields(cls, config: dict) -> dict:
+        """``num_hidden_layers`` layers from ``share["first_layer"]`` on (a
+        pipeline stage's), every one ``mla`` with a query that comes up from a
+        normed latent of ``q_lora_rank`` and no output gate; published layer
+        l (from 0) carries a dense SwiGLU of ``intermediate_size`` where l <
+        ``first_k_dense_replace`` and elsewhere ``n_routed_experts`` held
+        experts, ``share["first_expert"]`` the first, of the
+        ``share["experts_total"]`` the router scores (both default to the
+        whole), beside ``n_shared_experts`` shared ones of the same width;
+        ``n_group`` 1 is no group limit. ``num_nextn_predict_layers`` 1: one
+        multi-token-prediction module, BUILT WHERE THE CALLER WEIGHS ITS LOSS
+        (``mtp_weight``: ``config.json`` has no key for lambda, so a
+        configuration as run states it; without one nothing of the module is
+        built, and a weight without a module is refused by ``from_config``).
+        Refused: positions scaled, a bias, another selection rule than
+        ``noaux_tc``, unnormalised weights, a tied head, more than one
+        module, a partial rotary factor, fewer key/value heads than query
+        heads."""
+        cls._refuse(config, {
+            "attention_bias": False, "hidden_act": "silu",
+            "rope_scaling": None, "topk_method": "noaux_tc",
+            "norm_topk_prob": True, "tie_word_embeddings": False,
+            "partial_rotary_factor": 1,
+            "num_key_value_heads": config["num_attention_heads"]},
+            {"partial_rotary_factor": (
+                ": RoPE turns all of qk_rope_head_dim, and nothing else")})
+        modules = config.get("num_nextn_predict_layers", 0)
+        if modules > 1:
+            raise ValueError(
+                "HybridLM builds one multi-token-prediction module, not "
+                f"num_nextn_predict_layers={modules!r}")
+        if not config.get("q_lora_rank"):
+            raise ValueError(
+                "HybridLM builds glm4_moe_lite's query through a latent: "
+                f"q_lora_rank={config.get('q_lora_rank')!r}")
+        share = config.get("share", {})
+        first, depth = share.get("first_layer", 0), config["num_hidden_layers"]
+        dense = config["first_k_dense_replace"]
+        nope, rope = config["qk_nope_head_dim"], config["qk_rope_head_dim"]
+        held = config["n_routed_experts"]
+        groups = config.get("n_group", 1)
+        return dict(
+            vocab_size=config["vocab_size"],
+            layer_types=(MLA,) * depth,
+            ffn_types=tuple(DENSE if layer < dense else EXPERTS
+                            for layer in range(first, first + depth)),
+            hidden_size=config["hidden_size"],
+            num_heads=config["num_attention_heads"],
+            num_kv_heads=config["num_key_value_heads"],
+            head_dim=nope + rope, rope_head_dim=rope,
+            value_head_dim=config["v_head_dim"],
+            latent_rank=config["kv_lora_rank"],
+            query_rank=config["q_lora_rank"], latent_gate=False,
+            rope_theta=float(config["rope_theta"]),
+            intermediate_size=config["intermediate_size"],
+            expert_width=config["moe_intermediate_size"],
+            experts_held=held,
+            experts_total=share.get("experts_total", held),
+            first_expert=share.get("first_expert", 0),
+            experts_per_token=config["num_experts_per_tok"],
+            routed_scaling=float(config["routed_scaling_factor"]),
+            shared_experts=config["n_shared_experts"],
+            expert_groups=groups if groups > 1 else 0,
+            expert_groups_kept=config.get("topk_group", 1) if groups > 1 else 0,
+            expert_weight_eps=1e-20, tied_head=False,
+            embedding_multiplier=1.0, residual_multiplier=1.0,
+            attention_multiplier=(nope + rope) ** -0.5, logits_scaling=1.0,
+            rms_eps=float(config["rms_norm_eps"]))
+
     # -- shapes ----------------------------------------------------------------
+    @property
+    def mtp_built(self) -> bool:
+        """Whether the multi-token-prediction module is there: a module
+        whose loss weighs nothing takes no gradient and is left out."""
+        return self.mtp_weight > 0
+
+    @property
+    def mtp_kinds(self) -> tuple:
+        """The module's block, (mixer kind, FFN kind): the last layer's."""
+        return (self.layer_types[-1], self.ffn_kinds[-1])
+
+    @property
+    def blocks(self) -> tuple:
+        """(mixer kind, FFN kind, window) of every block a step runs: the
+        layers, then the multi-token-prediction module's where it is built."""
+        main = tuple(zip(self.layer_types, self.ffn_kinds, self.layer_windows))
+        return main + ((self.mtp_kinds + (0,),) if self.mtp_built else ())
+
     @property
     def value_width(self) -> int:
         """An attention head's v and o: ``head_dim`` but in an ``mla``
@@ -650,8 +778,8 @@ class HybridLM(nn.Module):
             w = min(window, t) if window else t
             return w * (w + 1) // 2 + (t - w) * w
 
-        return sum(pairs(window) for kind, window in zip(
-            self.layer_types, self.layer_windows) if kind in (ATTENTION, MLA))
+        return sum(pairs(window) for kind, _, window in self.blocks
+                   if kind in (ATTENTION, MLA))
 
     @property
     def mamba_inner(self) -> int:
@@ -663,7 +791,8 @@ class HybridLM(nn.Module):
 
     @property
     def expert_layers(self) -> int:
-        return self.ffn_kinds.count(EXPERTS)
+        """Expert layers a step runs, the module's among them."""
+        return sum(ffn == EXPERTS for _, ffn, _ in self.blocks)
 
     def matrix_shapes(self, kind: str, ffn: str = DENSE) -> dict:
         """{name: (in, out)} of one layer's matrices, a held expert's as one
@@ -698,11 +827,15 @@ class HybridLM(nn.Module):
                     "wo": (values, d), **after}
         if kind == MLA:
             heads, nope = self.num_heads, self.head_dim - self.rope_head_dim
-            return {"wq": (d, self.attention_width),
+            query = {"wq": (d, self.attention_width)} if not self.query_rank else {
+                "wqa": (d, self.query_rank),
+                "wqb": (self.query_rank, self.attention_width)}
+            gate = {"wg": (d, heads)} if self.latent_gate else {}
+            return {**query,
                     "wkva": (d, self.latent_rank + self.rope_head_dim),
                     "wkvb": (self.latent_rank,
                              heads * (nope + self.value_width)),
-                    "wg": (d, heads), "wo": (heads * self.value_width, d),
+                    **gate, "wo": (heads * self.value_width, d),
                     **after}
         return {"in_proj": (d, 2 * inner + 2 * self.mamba_state
                             + self.mamba_heads),
@@ -742,6 +875,13 @@ class HybridLM(nn.Module):
                 f"a latent of {self.latent_rank}, RoPE (theta "
                 f"{self.rope_theta}) on {self.rope_head_dim} of a key's "
                 f"{self.head_dim}: not a latent-attention layer")
+        if self.mtp_weight < 0 or self.query_rank < 0:
+            raise ValueError(
+                f"mtp_weight {self.mtp_weight}, query_rank "
+                f"{self.query_rank}: no negative weight or rank")
+        if self.mtp_built and self.expert_placement:
+            raise ValueError("expert_placement places the layers' experts; "
+                             "the multi-token-prediction module's are not")
         if (DELTA in kinds or KDA in kinds) and not (
                 0 < self.delta_heads <= (self.delta_heads_total
                                          or self.delta_heads)
@@ -829,6 +969,9 @@ class HybridLM(nn.Module):
                 elif kind == MLA:
                     out["kv_norm"] = jnp.ones((self.latent_rank,),
                                               jnp.float32)
+                    if self.query_rank:
+                        out["q_norm"] = jnp.ones((self.query_rank,),
+                                                 jnp.float32)
                 elif self.qk_norm:
                     whole = self.qk_norm_over == "projection"
                     out.update(
@@ -858,6 +1001,15 @@ class HybridLM(nn.Module):
         if not self.tied_head:
             self.head_w = self.param("head", matrix, (d, self.vocab_size),
                                      jnp.float32)
+        if self.mtp_built:
+            def module(rng):
+                block, combine = jax.random.split(rng)
+                return {**layer(*self.mtp_kinds)(block),
+                        "eh_proj": matrix(combine, (2 * d, d), jnp.float32),
+                        **{name: jnp.ones((d,), jnp.float32)
+                           for name in ("enorm", "hnorm", "final_norm")}}
+
+            self.mtp = self.param("mtp_0", module)
 
     @staticmethod
     def _decay_vectors(keys, heads: int, taps: int, channels: int,
@@ -988,16 +1140,24 @@ class HybridLM(nn.Module):
                      if kind == ATTENTION]
         kinds = {"global": attention.count(0),
                  "window": sum(1 for w in attention if w),
-                 "latent": self.layer_types.count(MLA)}
+                 "latent": sum(kind == MLA for kind, _, _ in self.blocks)}
         facts.update(attention_backward_facts(
             self.attn_impl, t, self.head_dim, self.dtype,
             {kind: n for kind, n in kinds.items() if n}, self.value_width))
         if kinds["latent"]:
             facts.update({
-                "layer_kinds.mla": kinds["latent"],
+                "layer_kinds.mla": self.layer_types.count(MLA),
                 "attention.latent_rank": self.latent_rank,
                 "attention.key_width": self.head_dim,
                 "attention.value_width": self.value_width})
+            if self.query_rank:
+                facts["attention.query_rank"] = self.query_rank
+        if self.mtp_built:
+            # a block more than ``layer_kinds`` lists, and a second pass of
+            # the head: both are in ``flops_per_row``, the expert layers'
+            # counts and ``attention.backward_fused_layers``
+            facts.update({"mtp.weight": self.mtp_weight,
+                          "mtp.block": ",".join(self.mtp_kinds)})
         if any(self.layer_windows):
             facts.update({
                 "layer_kinds.window": kinds["window"],
@@ -1018,14 +1178,21 @@ class HybridLM(nn.Module):
         (the recurrence's own 6 Dk Dv a token and held head:
         ``ops.delta_rule.recurrence_flops``) and, with expert layers,
         ``experts`` (6 x one expert's parameters x the uniform share of the
-        pairs)."""
+        pairs). A multi-token-prediction module's block counts as a layer
+        (``blocks``), its ``eh_proj`` among the matrices, and the head
+        twice (row T - 1 of the second pass weighs 0 and is run all the
+        same: counted)."""
         d, n = self.hidden_size, self.mamba_state
         heads, p = self.mamba_heads, self.mamba_head_dim
         mamba = self.layer_types.count(MAMBA)
         matrices = sum(
-            a * b for kind, ffn in zip(self.layer_types, self.ffn_kinds)
+            a * b for kind, ffn, _ in self.blocks
             for name, (a, b) in self.matrix_shapes(kind, ffn).items()
             if name not in ("w13", "w2"))
+        heads_run = 1
+        if self.mtp_built:  # ``eh_proj``, and the shared head a second time
+            matrices += 2 * d * d
+            heads_run = 2
         delta = self.layer_types.count(DELTA) + self.layer_types.count(KDA)
         conv = (mamba * self.mamba_conv * (self.mamba_inner + 2 * n)
                 + self.layer_types.count(CONV) * self.conv_kernel * d
@@ -1041,7 +1208,7 @@ class HybridLM(nn.Module):
             # q.k over head_dim and p.v over the values' width, a kept pair
             "attention": 6 * self.num_heads * (
                 self.head_dim + self.value_width) * self.attention_pairs(t),
-            "head": 6 * d * self.vocab_size * t}
+            "head": heads_run * 6 * d * self.vocab_size * t}
         if delta:
             # what the RECURRENCE needs, whatever implements it
             parts["delta"] = 3 * delta * delta_rule.recurrence_flops(
@@ -1063,12 +1230,11 @@ class HybridLM(nn.Module):
             return {}
         itemsize = jnp.dtype(self.dtype).itemsize
         wide = t * self.hidden_size * itemsize
-        attention = (self.layer_types.count(ATTENTION)
-                     + self.layer_types.count(MLA))
+        attention = sum(kind in (ATTENTION, MLA) for kind, _, _ in self.blocks)
         sizes = {"attn_out": attention * t * self.num_heads
                  * self.value_width * itemsize,
                  "attn_lse": attention * 4 * self.num_heads * t,
-                 "mlp_out": len(self.layer_types) * wide}
+                 "mlp_out": len(self.blocks) * wide}
         flash = self.attn_impl in ("flash", "ulysses_flash")
         kept = {name: sizes[name] for name in REMAT_KEEPS
                 if flash or name not in SAVED_RESIDUALS}
@@ -1098,9 +1264,20 @@ class HybridLM(nn.Module):
         [expert layers, held] pairs routed to each held expert,
         ``report["pairs_dropped"]`` and ``report["layers_at_full_bound"]``
         (expert layers x steps whose load overflowed the likely rows' bound
-        and ran at the worst-case one), over ``steps`` steps."""
-        if not self.expert_layers or not steps:
+        and ran at the worst-case one), over ``steps`` steps; with a
+        multi-token-prediction module, ``report["mtp_loss"]``: its gauge
+        ``mtp.loss`` is the epoch's mean of the module's own cross-entropy,
+        which the fit's ``train_loss`` holds ``mtp_weight`` times beside the
+        main one."""
+        if not steps:
             return {}
+        said = self._expert_facts(report, steps) if self.expert_layers else {}
+        if self.mtp_built and "mtp_loss" in report:
+            said.setdefault("gauges", {})["mtp.loss"] = float(
+                np.sum(report["mtp_loss"])) / steps
+        return said
+
+    def _expert_facts(self, report: dict, steps: int) -> dict:
         load = np.asarray(report["expert_load"], np.float64)
         dropped = float(np.sum(report["pairs_dropped"]))
         overflows = float(np.sum(report["layers_at_full_bound"]))
@@ -1266,13 +1443,18 @@ class HybridLM(nn.Module):
         """The ``mla`` mixer's training side on ``y`` [B, T, D]: K's
         position-free part and V come up from one normed latent, every head
         shares the one RoPE key, keys of ``head_dim`` stand over values of
-        ``value_head_dim``; one sigmoid a head gates the read-out."""
+        ``value_head_dim``; ``query_rank`` > 0: the query comes up from a
+        normed latent too; ``latent_gate``: one sigmoid a head gates the
+        read-out."""
         with obs.device_scope("hybridlm.attention"):
             b, t, _ = y.shape
             heads, rope, dv = self.num_heads, self.rope_head_dim, self.value_width
             nope = self.head_dim - rope
-            q = self._dot(y, w["wq"]).reshape(
-                b, t, heads, nope + rope).transpose(0, 2, 1, 3)
+            with obs.device_scope("hybridlm.attention.query"):
+                q = self._dot(y, w["wq"]) if not self.query_rank else self._dot(
+                    rms_norm(self._dot(y, w["wqa"]), w["q_norm"],
+                             self.rms_eps), w["wqb"])
+            q = q.reshape(b, t, heads, nope + rope).transpose(0, 2, 1, 3)
             with obs.device_scope("hybridlm.attention.latent"):
                 latent, k_rope = jnp.split(
                     self._dot(y, w["wkva"]), [self.latent_rank], axis=-1)
@@ -1298,9 +1480,11 @@ class HybridLM(nn.Module):
             k = jnp.concatenate([k_nope, jnp.broadcast_to(
                 turned(k_rope[:, None]), (b, heads, t, rope))], axis=-1)
             o = _attend(q, k, v, impl=self.attn_impl, axis="sp", causal=True)
-            gate = jax.nn.sigmoid(
-                self._dot(y, w["wg"]).astype(jnp.float32))
-            o = o.transpose(0, 2, 1, 3).astype(jnp.float32) * gate[..., None]
+            o = o.transpose(0, 2, 1, 3)
+            if self.latent_gate:
+                gate = jax.nn.sigmoid(
+                    self._dot(y, w["wg"]).astype(jnp.float32))
+                o = o.astype(jnp.float32) * gate[..., None]
             return self._dot(o.reshape(b, t, heads * dv).astype(self.dtype),
                              w["wo"])
 
@@ -1403,6 +1587,11 @@ class HybridLM(nn.Module):
             self._mlp(w, y), {})
         return self._residual(h, normed(out, w["norm2"], post)), report
 
+    def _head_operand(self) -> tuple:
+        """(the head's float32 matrix, its axis that meets D): the tied
+        embedding [V, D], or the model's own head [D, V]."""
+        return (self.embed, 1) if self.tied_head else (self.head_w, 0)
+
     def head(self, h):
         """Logits, float32, from the final norm's output (the tied head, or
         the model's own)."""
@@ -1412,16 +1601,21 @@ class HybridLM(nn.Module):
                        ) / self.logits_scaling
 
     # -- surfaces ------------------------------------------------------------
-    def _states(self, tokens):
-        """(the final norm's output [B, T, D], the expert layers' reports
-        stacked: {} without expert layers)."""
-        h = (self.embedding_multiplier * self.embed[tokens]).astype(self.dtype)
+    def _recomputed_block(self):
+        """``_block``, recomputed in the backward pass but for what the
+        model's layers keep (``remat``)."""
         keeps = (REMAT_KEEPS + (EXPERT_KEEPS if self.expert_layers else ())
                  + (KDA_KEEPS if KDA in self.layer_types else ()))
-        block = jax.checkpoint(
+        return jax.checkpoint(
             self._block, static_argnums=(0, 1, 4, 5),
             policy=jax.checkpoint_policies.save_only_these_names(*keeps),
         ) if self.remat else self._block
+
+    def _stream(self, tokens):
+        """(the stream the final norm READS [B, T, D], the expert layers'
+        reports, a list)."""
+        h = (self.embedding_multiplier * self.embed[tokens]).astype(self.dtype)
+        block = self._recomputed_block()
         reports = []
         for kind, ffn, w, window, rope in zip(
                 self.layer_types, self.ffn_kinds, self.layers,
@@ -1429,9 +1623,48 @@ class HybridLM(nn.Module):
             h, report = block(kind, ffn, w, h, window, rope)
             if report:
                 reports.append(report)
-        stacked = {key: jnp.stack([r[key] for r in reports])
-                   for key in (reports[0] if reports else ())}
-        return rms_norm(h, self.final_norm, self.rms_eps), stacked
+        return h, reports
+
+    @staticmethod
+    def _stacked(reports: list) -> dict:
+        return {key: jnp.stack([r[key] for r in reports])
+                for key in (reports[0] if reports else ())}
+
+    def _states(self, tokens):
+        """(the final norm's output [B, T, D], the expert layers' reports
+        stacked: {} without expert layers)."""
+        h, reports = self._stream(tokens)
+        return (rms_norm(h, self.final_norm, self.rms_eps),
+                self._stacked(reports))
+
+    def _mtp(self, h, x):
+        """The multi-token-prediction module on the stream ``h`` [B, T, D]
+        the final norm reads and the row ``x`` [B, T + 1]: (the mean
+        cross-entropy of token i + 2 from row i over the rows that have one,
+        the state its head read [B, T, D], its block's report). Row T - 1
+        has no target: it keeps its place, so no kernel's grid changes, and
+        weighs 0."""
+        w = self.mtp
+        b, t = x.shape[0], x.shape[1] - 1
+        with obs.device_scope("hybridlm.mtp"):
+            with obs.device_scope("hybridlm.mtp.combine"):
+                ahead = (self.embedding_multiplier
+                         * self.embed[x[:, 1:]]).astype(self.dtype)
+                u = self._dot(jnp.concatenate(
+                    [rms_norm(ahead, w["enorm"], self.rms_eps),
+                     rms_norm(h, w["hnorm"], self.rms_eps)], axis=-1),
+                    w["eh_proj"])
+            g, report = self._recomputed_block()(*self.mtp_kinds, w, u, 0, True)
+            g = rms_norm(g, w["final_norm"], self.rms_eps)
+            targets = jnp.pad(x[:, 2:], ((0, 0), (0, 1)))
+            weight = jnp.broadcast_to(
+                (jnp.arange(t) < t - 1) / (b * max(t - 1, 1)), (b, t)
+            ).astype(jnp.float32)
+            loss, _ = chunked_cross_entropy(
+                g, *self._head_operand(), targets, self.loss_chunk,
+                "hybridlm.mtp.loss", scale=1.0 / self.logits_scaling,
+                weight=weight)
+        return loss, g, report
 
     def hidden_states(self, tokens):
         """The final norm's output [B, T, D]: what the head reads."""
@@ -1498,20 +1731,31 @@ class HybridLM(nn.Module):
         ``with_states`` adds ``hidden`` [B, T, D], the state the head read,
         and ``routing`` int32 [expert layers, B, T, k], every token's
         choice (for a comparison; not for a fit, whose evaluation would
-        average them)."""
-        h, reports = self._states(x[:, :-1])
-        head, contract = (self.embed, 1) if self.tied_head else (
-            self.head_w, 0)
+        average them). With a multi-token-prediction module the loss is the
+        main one + ``mtp_weight`` x the module's, ``aux["mtp_loss"]`` is the
+        module's alone (``train_report``'s fourth), its block's report is
+        the LAST of the expert layers', and ``with_states`` adds
+        ``mtp_hidden``, the state the module's head read."""
+        stream, reports = self._stream(x[:, :-1])
+        h = rms_norm(stream, self.final_norm, self.rms_eps)
         loss, _ = chunked_cross_entropy(
-            h, head, contract, x[:, 1:], self.loss_chunk, "hybridlm.loss",
-            scale=1.0 / self.logits_scaling)
+            h, *self._head_operand(), x[:, 1:], self.loss_chunk,
+            "hybridlm.loss", scale=1.0 / self.logits_scaling)
         aux = {}
+        if self.mtp_built:
+            aux["mtp_loss"], ahead, report = self._mtp(stream, x)
+            loss = loss + self.mtp_weight * aux["mtp_loss"]
+            if report:
+                reports.append(report)
+        reports = self._stacked(reports)
         if reports:
             aux.update(expert_load=reports["load"],
                        pairs_dropped=reports["dropped"].sum(),
                        layers_at_full_bound=reports["full_bound"].sum())
         if with_states:
             aux["hidden"] = h
+            if self.mtp_built:
+                aux["mtp_hidden"] = ahead
             if reports:
                 aux["routing"] = reports["sel"]
         return loss, aux
@@ -1529,6 +1773,14 @@ class LatentDeltaHybridLM(HybridLM):
     layers asks for (``config["model"]["class"]``), as ``RoutedHybridLM``
     and for its reason: a program from before those mixer kinds has no such
     name, and a benchmark that asks for it there leaves at once."""
+
+
+class LatentMTPHybridLM(HybridLM):
+    """``HybridLM`` under the name a configuration with a low-rank-query
+    ``mla`` mixer in every layer and a multi-token-prediction module asks for
+    (``config["model"]["class"]``), as ``RoutedHybridLM`` and for its reason:
+    a program from before them has no such name, and a benchmark that asks
+    for it there leaves at once."""
 
 
 class DeltaHybridLM(HybridLM):

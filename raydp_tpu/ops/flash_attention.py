@@ -697,12 +697,23 @@ def fused_vmem_bytes(t: int, head_dim: int, block: int, itemsize: int,
     for a described v5e the call needed 2.5 of them beside the rest at bf16
     1024-row tiles (16.6 MB + dq) and 6.3 at float32 512-row ones (11.3 MB
     + dq), and what is asked for and not used costs nothing
-    (``tests/test_tpu_compile_kernels.py`` compiles the cells' shapes)."""
+    (``tests/test_tpu_compile_kernels.py`` compiles the cells' shapes).
+    FLOAT32 operands add the three bfloat16 parts a product at ``highest``
+    splits each operand tile into: at heads of 256 over 256 in 256-row
+    tiles the call needs 16.65 MB where the rest of this count is 15.2,
+    and the 16 MiB of ``VMEM_DEFAULT_BYTES`` covered it by 0.7 %. The ask
+    matters beyond covering the need: XLA keeps an operand in VMEM across
+    the call where the two fit its 96 MiB, and at 20 heads the row
+    statistics' ``[20, 8192, 1]`` float32 (lane-padded: 80 MiB exactly)
+    beside an ask of exactly 16 MiB passed that test and failed the
+    allocation by 136 KB (my chip run, PR 53); an ask that says what the
+    call needs lies off that edge."""
     row = block * _lanes(head_dim)
     row_v = block * _lanes(value_dim or head_dim)
+    split = 3 * (4 * row + 3 * row_v) * 2 if itemsize >= 4 else 0
     return (dq_resident_bytes(t, head_dim) + (row + row_v) * 4
             + 2 * ((4 * row + 3 * row_v) * itemsize + 2 * block * 128 * 4)
-            + 8 * block * block * 4)
+            + 8 * block * block * 4 + split)
 
 
 def _blocks(t, tk, head_dim, itemsize, block_q, block_k, value_dim=None):
