@@ -364,6 +364,15 @@ def phase_kernels(ph: Phase) -> None:
     ph.say(f"flash bwd form at static | runtime offsets: {' | '.join(forms)}")
     if forms != ("fused", "two_call"):
         failures.append("backward_form")
+    # ... and over the RECTANGULAR grid: the fused call (and the forward
+    # above) step over the tiles under the diagonal alone
+    tiles = fa.pick_blocks(t, t, d)
+    grids = (fa.causal_grid(t, t, *tiles),
+             fa.causal_grid(t, t, *tiles, q_offset=zero))
+    ph.say(f"flash grid at static | runtime offsets: {' | '.join(grids)} "
+           f"(steps a head, live tiles: {fa.causal_steps(t, *tiles)})")
+    if grids != ("live", "rectangular:runtime offsets"):
+        failures.append("causal_grid")
     two_call = ph.first_call("flash bwd two-call", lambda: blocks(zero, zero))
     fused = ph.first_call("flash bwd fused", lambda: jax.jit(
         lambda: fa.flash_backward_blocks(q, k, v, lse, dsum, w, 0, 0, True))())

@@ -1070,7 +1070,8 @@ class HybridLM(nn.Module):
         (``ops.experts.token_rows_gathered``: bound + tokens token-ordered,
         tokens x k per choice), for a trace's reader to hold the gathers it
         sees against. ``attention_backward`` and its two numbers: the form
-        the attention layers' backward pass takes by layer kind
+        the attention layers' backward pass takes by layer kind, and
+        ``attention_grid`` with its share: the grid a kind's calls step over
         (``transformer.attention_backward_facts``)."""
         t = x.shape[1] - 1
         parts = self.flops_per_row_parts(t)
@@ -1143,7 +1144,8 @@ class HybridLM(nn.Module):
                  "latent": sum(kind == MLA for kind, _, _ in self.blocks)}
         facts.update(attention_backward_facts(
             self.attn_impl, t, self.head_dim, self.dtype,
-            {kind: n for kind, n in kinds.items() if n}, self.value_width))
+            {kind: n for kind, n in kinds.items() if n}, self.value_width,
+            max(attention, default=0) or None))
         if kinds["latent"]:
             facts.update({
                 "layer_kinds.mla": self.layer_types.count(MLA),

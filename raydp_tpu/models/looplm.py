@@ -253,7 +253,9 @@ class LoopLM(nn.Module):
         does not count. XLA's count of the step program cannot stand in: it
         counts a scanned loop's body once and no Mosaic call.
         ``attention_backward`` and its two numbers: the form the blocks'
-        attention backward takes (``transformer.attention_backward_facts``)."""
+        attention backward takes; ``attention_grid`` and
+        ``attention.causal_grid_live_share``: the grid its calls step over
+        (``transformer.attention_backward_facts``)."""
         t = x.shape[1] - 1
         d, applications = self.hidden_size, self.loop_steps * self.num_layers
         layer = 4 * d * d + 3 * d * self.intermediate_size

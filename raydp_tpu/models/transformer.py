@@ -64,33 +64,56 @@ def _attend(q, k, v, *, impl: str, axis: str, causal: bool,
 
 
 def attention_backward_facts(impl: str, t: int, head_dim: int, dtype,
-                             layers: dict, value_dim: int = None) -> dict:
+                             layers: dict, value_dim: int = None,
+                             window: int = None) -> dict:
     """What a model whose attention goes through ``_attend`` says in its
-    ``fit_facts`` of the attention's backward pass over rows of ``t``
-    tokens. ``layers``: {layer kind (``global``, ``window``, ``latent``): its
+    ``fit_facts`` of the attention's kernels over rows of ``t`` tokens.
+    ``layers``: {layer kind (``global``, ``window``, ``latent``): its
     layer applications a step}; ``value_dim``: v's and o's width where it is
-    not ``head_dim``. ``attention_backward``: the form a kind's backward
+    not ``head_dim``; ``window``: the ``window`` kind's.
+    ``attention_backward``: the form a kind's backward
     pass takes, from the shapes (``ops.flash_attention.backward_form``:
     ``fused``, one call that computes every live tile once, or
     ``two_call``; a ring's step has runtime offsets and is ``two_call``;
     ``xla`` where no flash kernel runs). ``attention.backward_fused_layers``:
     the layer applications a step whose backward is the fused call.
     ``attention.dq_resident_bytes``: what that call keeps in VMEM for a
-    head's float32 dq."""
-    from raydp_tpu.ops.flash_attention import backward_form, dq_resident_bytes
+    head's float32 dq. ``attention_grid``: the grid a kind's calls, forward
+    and backward, step over (``ops.flash_attention.causal_grid``: ``live``,
+    the tiles under the diagonal alone; ``window``, bounded by the window;
+    ``rectangular`` and why: a ring's runtime offsets). Where a causal flash
+    call runs, ``attention.causal_grid_live_share``: the tiles with an
+    unmasked entry over the steps those calls' grids take a head, in % (100
+    on the live grid; 53.1 on a 16 x 16 rectangle: a ring's calls counted
+    as one device's over the whole row)."""
+    from raydp_tpu.ops.flash_attention import (
+        backward_form, causal_grid, causal_steps, dq_resident_bytes,
+        pick_blocks)
 
-    form = "xla"
-    if impl in ("flash", "ulysses_flash"):
-        form = backward_form(t, t, head_dim, jnp.dtype(dtype).itemsize,
-                             value_dim=value_dim)
-    elif impl == "ring_flash":
-        form = "two_call"
+    form, grids, itemsize = "xla", {}, jnp.dtype(dtype).itemsize
+    if impl in ("flash", "ulysses_flash", "ring_flash"):
+        ring = impl == "ring_flash"
+        form = "two_call" if ring else backward_form(
+            t, t, head_dim, itemsize, value_dim=value_dim)
+        blocks = pick_blocks(t, t, head_dim, itemsize, value_dim)
+        # a ring step's offsets are traced values: any but the static 0
+        grids = {kind: causal_grid(
+            t, t, *blocks, window=window if kind == "window" else None,
+            q_offset=None if ring else 0) for kind in layers}
     fused = sum(layers.values()) if form == "fused" else 0
-    return {
+    facts = {
         "attention_backward": ",".join(f"{kind}={form}" for kind in layers),
         "attention.backward_fused_layers": fused,
         "attention.dq_resident_bytes":
-            dq_resident_bytes(t, head_dim) if fused else 0}
+            dq_resident_bytes(t, head_dim) if fused else 0,
+        "attention_grid": ",".join(
+            f"{kind}={grids.get(kind, 'xla')}" for kind in layers)}
+    causal = {grid for grid in grids.values() if grid != "window"}
+    if causal:
+        steps, live_tiles = causal_steps(t, *blocks)
+        facts["attention.causal_grid_live_share"] = 100.0 * live_tiles / (
+            live_tiles if causal == {"live"} else steps)
+    return facts
 
 
 def _scatter_rows(cache, new, starts):

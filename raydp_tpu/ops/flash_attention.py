@@ -6,15 +6,32 @@ innermost, so the per-q-block statistics (running max m, denominator l,
 unnormalized output o) persist across k iterations and the full [T, T] score
 matrix never materializes — O(T) memory instead of O(T²). Scores run on the
 MXU (`preferred_element_type=f32`); masking and the softmax update run on the
-VPU. Causal masking uses global positions (runtime offsets from SMEM), and
-k-blocks entirely in the future are skipped outright (~2x causal throughput).
-A causal WINDOW (``flash_attention(..., window=W)``: a query sees its own
-position and the W - 1 before it) bounds the GRID of every kernel: the
-inner axis has only the steps a block's window can touch and the index maps
-start at the block's first live partner, so the blocks a window hides are
-neither stepped over nor fetched (``window_steps``); those calls carry names
-of their own (``flash_attention_window_fwd`` / ``_bwd_dq_dkv``, and
-``_bwd_dq`` / ``_bwd_dkv`` where the two-call pass runs).
+VPU. Causal masking uses global positions (runtime offsets from SMEM); a
+tile wholly in the future is never computed, and what it still costs
+depends on the surface:
+- causal self-attention from position 0 (``flash_attention`` with no
+  window, forward and backward: static offsets 0, Tq = Tk) steps over the
+  LIVE tiles only: the grid is flattened to (batch·head, tiles under the
+  diagonal), 136 steps a head where the 16 x 16 rectangle has 256, and
+  scalar-prefetched tables say which blocks a step reads
+  (``causal_grid``, ``causal_steps``), so a dead tile costs no grid step
+  and no DMA;
+- a causal WINDOW (``flash_attention(..., window=W)``: a query sees its own
+  position and the W - 1 before it) bounds the inner axis of a rectangular
+  grid: it has only the steps a block's window can touch and the index
+  maps start at the block's first live partner, so the blocks a window
+  hides are neither stepped over nor fetched (``window_steps``), and the
+  few dead tiles at a window's two edges are stepped over, fetched and
+  predicated off; those calls carry names of their own
+  (``flash_attention_window_fwd`` / ``_bwd_dq_dkv``, and ``_bwd_dq`` /
+  ``_bwd_dkv`` where the two-call pass runs);
+- with RUNTIME offsets (``flash_attention_stats`` and
+  ``flash_backward_blocks`` under a ring) or Tq != Tk the grid is the full
+  rectangle: a traced offset cannot shape a grid, so a dead tile's step is
+  taken and its blocks are fetched, and only its arithmetic is predicated
+  off (``_causal_block_live``);
+- ``flash_decode`` steps over the whole cache and predicates off the
+  blocks past a sequence's length.
 
 One kernel family serves three surfaces:
 - ``flash_attention``: normalized output, offsets 0 — the single-device /
@@ -78,7 +95,9 @@ def _causal_block_live(q_off_ref, k_off_ref, qi, ki, block_q, block_k, causal,
                        window=None):
     """Whether a (q-block, k-block) pair has any unmasked entry. Causal: a
     k-block entirely in the future contributes nothing — skip its matmul +
-    update outright (~2x causal throughput). Offsets are runtime values
+    update outright (on a rectangular grid its step and its fetch remain;
+    the live grid has no such step, so there this is always true).
+    Offsets are runtime values
     (SMEM), so the predicate is computed at runtime too. ``window``: a
     k-block entirely past the window (every key ``window`` or more
     positions behind the block's first query) is dead as well."""
@@ -151,20 +170,151 @@ def window_steps(t: int, block_q: int, block_k: int, window: int) -> tuple:
     return k_steps, q_steps
 
 
+# -- a causal call steps over its LIVE tiles only -----------------------------
+# Causal self-attention from position 0 (no window, both offsets the static
+# 0, Tq = Tk) knows at trace time which tiles lie under the diagonal, so its
+# grid is FLATTENED to (batch·head, live tiles): step -> (outer block, inner
+# block) tables, built with numpy and scalar-prefetched into SMEM, drive the
+# index maps, and a tile beyond the diagonal costs no grid step and no DMA.
+# The kernel bodies are the rectangular grid's (``_tile_of_step`` hands them
+# their place either way), so are the tiles and their order: the same bits.
+
+# the tables' most steps: two int32 a step, so half of a v5e's 1 MiB of SMEM
+# at most (compiled for a described one: 65,341 steps go through, 131,328
+# run out of SMEM). A row of 362 blocks or more keeps the rectangular grid.
+LIVE_GRID_MAX_STEPS = 1 << 16
+
+
+def causal_steps(t: int, block_q: int, block_k: int) -> tuple:
+    """(steps a head of the RECTANGULAR grid over ``t`` causal positions,
+    the tiles of it with an unmasked entry: the steps of the live grid).
+    136 of 256 at 16 x 16 equal blocks."""
+    nq, nk = t // block_q, t // block_k
+    return nq * nk, sum(
+        ((i + 1) * block_q - 1) // block_k + 1 for i in range(nq))
+
+
+def causal_grid(t: int, tk: int, block_q: int, block_k: int, *,
+                causal: bool = True, window: int | None = None,
+                q_offset=0, k_offset=0) -> str:
+    """Which grid the calls of these shapes and arguments take: ``"live"``
+    (flattened over the tiles under the diagonal), ``"window"`` (bounded by
+    the window: ``window_steps``) or ``"rectangular"`` with why, after a
+    colon: a traced offset cannot shape a grid, ``Tq != Tk`` and a
+    non-causal call are not built, tables past ``LIVE_GRID_MAX_STEPS`` do
+    not fit. Decided from what the call sees, by no flag."""
+    if not causal:
+        return "rectangular:not causal"
+    if not all(isinstance(o, int) and o == 0 for o in (q_offset, k_offset)):
+        return "rectangular:runtime offsets"
+    if t != tk:
+        return "rectangular:queries and keys differ in length"
+    if window is not None and window < tk:
+        return "window"
+    if causal_steps(t, block_q, block_k)[1] > LIVE_GRID_MAX_STEPS:
+        return "rectangular:more live tiles than the tables hold"
+    return "live"
+
+
+def _live_tiles(t: int, block_q: int, block_k: int, outer: str):
+    """int32 [2, live tiles]: the (outer block, inner block) of every step
+    of the flattened grid, an outer block's inner ones ascending. ``outer``
+    ``"q"``: the forward's and the dq call's order (a q-block, then its
+    k-blocks up to the diagonal's); ``"k"``: the dk/dv and the fused call's
+    (a k-block, then the q-blocks from ``_first_q_block`` on)."""
+    import numpy as np
+
+    nq, nk = t // block_q, t // block_k
+    if outer == "q":
+        pairs = [(i, j) for i in range(nq)
+                 for j in range(((i + 1) * block_q - 1) // block_k + 1)]
+    else:
+        pairs = [(j, i) for j in range(nk)
+                 for i in range(_first_q_block(j, block_q, block_k), nq)]
+    return np.asarray(pairs, np.int32).T
+
+
+def _on_live_tiles(kernel):
+    """``kernel`` on the flattened grid: the two tables come first (scalar
+    prefetch), and the kernel is told its tile and whether the step is its
+    outer block's first and last (the neighbouring steps' outer blocks
+    differ) in place of reading them off a rectangular grid."""
+    from jax.experimental import pallas as pl
+
+    def body(outer_ref, inner_ref, *refs):
+        step, steps = pl.program_id(1), pl.num_programs(1)
+        outer = outer_ref[step]
+        first = jnp.logical_or(
+            step == 0, outer_ref[jnp.maximum(step - 1, 0)] != outer)
+
+        def last():
+            return jnp.logical_or(
+                step == steps - 1,
+                outer_ref[jnp.minimum(step + 1, steps - 1)] != outer)
+
+        kernel(*refs, tile=(outer, inner_ref[step], first, last))
+
+    return body
+
+
+def _tile_of_step(tile, first_inner=None):
+    """(outer block, inner block, whether this is the outer block's first
+    step, a function that says whether it is its last) of a grid step:
+    ``tile`` as ``_on_live_tiles`` gives it, or read off the rectangular
+    grid, whose inner axis starts at block ``first_inner(outer)`` where a
+    window bounds it. The last is a function so that a rectangular call
+    lowers to the operations it always had, in their order."""
+    from jax.experimental import pallas as pl
+
+    if tile is not None:
+        return tile
+    outer, step = pl.program_id(1), pl.program_id(2)
+    inner = step if first_inner is None else step + first_inner(outer)
+    return outer, inner, step == 0, lambda: step == pl.num_programs(2) - 1
+
+
+def _grid_plan(kernel, live, outer, bh, t, block_q, block_k, steps,
+               inner_block):
+    """A call's (kernel, grid, scalar-prefetched tables, index map of a
+    block that follows the grid's OUTER blocks, index map of one that
+    follows its inner blocks). ``outer``: ``"q"`` (a q-block, then its
+    k-blocks: the forward and the dq call) or ``"k"`` (a k-block, then its
+    q-blocks: the dk/dv and the fused call). ``live``: the flattened grid
+    over the tiles under the diagonal, both blocks of a step read from the
+    tables; else the rectangle (bh, outer blocks, inner steps) of ``steps``,
+    step j of outer block o at inner block ``inner_block(o, j)``."""
+    if live:
+        tables = _live_tiles(t, block_q, block_k, outer)
+
+        def outer_at(b_, s, outers, inners):
+            return (b_, outers[s], 0)
+
+        def inner_at(b_, s, outers, inners):
+            return (b_, inners[s], 0)
+
+        return (_on_live_tiles(kernel), (bh, tables.shape[1]), tuple(tables),
+                outer_at, inner_at)
+
+    def outer_at(b_, o, j):
+        return (b_, o, 0)
+
+    def inner_at(b_, o, j):
+        return (b_, inner_block(o, j), 0)
+
+    return kernel, (bh, *steps), (), outer_at, inner_at
+
+
 def _flash_kernel(
     q_off_ref, k_off_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
     o_acc, m_acc, l_acc, *, scale, causal, block_q, block_k, normalize,
-    window=None, inner_blocks=None,
+    window=None, inner_blocks=None, tile=None,
 ):
     from jax.experimental import pallas as pl
 
-    qi = pl.program_id(1)
-    step = pl.program_id(2)
-    num_k = pl.num_programs(2)
-    ki = step if window is None else step + _first_k_block(
-        qi, block_q, block_k, window)
+    qi, ki, first, last = _tile_of_step(tile, window and functools.partial(
+        _first_k_block, block_q=block_q, block_k=block_k, window=window))
 
-    @pl.when(step == 0)
+    @pl.when(first)
     def _init():
         o_acc[:] = jnp.zeros_like(o_acc)
         m_acc[:] = jnp.full_like(m_acc, NEG_INF)
@@ -208,7 +358,7 @@ def _flash_kernel(
         m_acc[:] = jnp.broadcast_to(m_new, m_acc.shape)
         l_acc[:] = jnp.broadcast_to(l_new, l_acc.shape)
 
-    @pl.when(step == num_k - 1)
+    @pl.when(last())
     def _finalize():
         if normalize:
             o_ref[0] = (
@@ -223,7 +373,7 @@ def _flash_kernel(
 def _flash_kernel_onepass(
     q_off_ref, k_off_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
     o_acc, m_acc, l_acc, *, scale, causal, block_q, block_k, normalize,
-    window=None, inner_blocks=None,
+    window=None, inner_blocks=None, tile=None,
 ):
     """One-pass online softmax with the accumulator rescale deferred.
 
@@ -237,13 +387,10 @@ def _flash_kernel_onepass(
     (the gated multiplies are exactly ×1.0 when skipped)."""
     from jax.experimental import pallas as pl
 
-    qi = pl.program_id(1)
-    step = pl.program_id(2)
-    num_k = pl.num_programs(2)
-    ki = step if window is None else step + _first_k_block(
-        qi, block_q, block_k, window)
+    qi, ki, first, last = _tile_of_step(tile, window and functools.partial(
+        _first_k_block, block_q=block_q, block_k=block_k, window=window))
 
-    @pl.when(step == 0)
+    @pl.when(first)
     def _init():
         o_acc[:] = jnp.zeros_like(o_acc)
         m_acc[:] = jnp.full_like(m_acc, NEG_INF)
@@ -301,7 +448,7 @@ def _flash_kernel_onepass(
 
         m_acc[:] = jnp.broadcast_to(m_new, m_acc.shape)
 
-    @pl.when(step == num_k - 1)
+    @pl.when(last())
     def _finalize():
         if normalize:
             o_ref[0] = (
@@ -365,6 +512,8 @@ def _flash_call(
     vf = v.reshape(bh, tk, dv)
 
     window = _window_for(window, causal, t, tk, q_offset, k_offset)
+    live = causal_grid(t, tk, block_q, block_k, causal=causal, window=window,
+                       q_offset=q_offset, k_offset=k_offset) == "live"
     k_steps, k_block = tk // block_k, (lambda i, j: j)
     if window is not None:
         # the grid follows the window: only the k-blocks it can touch are
@@ -391,6 +540,10 @@ def _flash_call(
     q_off = _vary_like(jnp.asarray([q_offset], jnp.int32), union)
     k_off = _vary_like(jnp.asarray([k_offset], jnp.int32), union)
 
+    kernel, grid, tables, q_at, k_at = _grid_plan(
+        kernel, live, "q", bh, t, block_q, block_k,
+        (t // block_q, k_steps), k_block)
+
     out_dtype = q.dtype if normalize else jnp.float32
     o, m, l = pl.pallas_call(  # noqa: E741
         kernel,
@@ -399,33 +552,35 @@ def _flash_call(
             sds((bh, t, 1), jnp.float32),
             sds((bh, t, 1), jnp.float32),
         ),
-        grid=(bh, t // block_q, k_steps),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0)),
-            pl.BlockSpec((1, block_k, d),
-                         lambda b_, i, j: (b_, k_block(i, j), 0)),
-            pl.BlockSpec((1, block_k, dv),
-                         lambda b_, i, j: (b_, k_block(i, j), 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, block_q, dv), lambda b_, i, j: (b_, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b_, i, j: (b_, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b_, i, j: (b_, i, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(tables),
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+                pl.BlockSpec((1, block_q, d), q_at),
+                pl.BlockSpec((1, block_k, d), k_at),
+                pl.BlockSpec((1, block_k, dv), k_at),
+            ],
+            out_specs=(
+                pl.BlockSpec((1, block_q, dv), q_at),
+                pl.BlockSpec((1, block_q, 1), q_at),
+                pl.BlockSpec((1, block_q, 1), q_at),
+            ),
+            scratch_shapes=[
+                pltpu.VMEM((block_q, dv), jnp.float32),
+                pltpu.VMEM((block_q, 128), jnp.float32),
+                pltpu.VMEM((block_q, 128), jnp.float32),
+            ],
         ),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, dv), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-        ],
         interpret=interpret,
         # a window call under a name of its own: a reader that counts every
         # ``flash_attention_fwd`` call as causal would credit it with the
         # pairs the window hides
         name="flash_attention_fwd" if window is None
         else "flash_attention_window_fwd",
-    )(q_off, k_off, qf, kf, vf)
+    )(*(_vary_like(jnp.asarray(table), union) for table in tables),
+      q_off, k_off, qf, kf, vf)
     return (
         o.reshape(b, h, t, dv),
         m.reshape(b, h, t),
@@ -514,17 +669,14 @@ def _bwd_tile(
 def _bwd_dq_kernel(
     q_off_ref, k_off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref,
     dq_ref, dq_acc, *, scale, causal, block_q, block_k, window=None,
-    inner_blocks=None,
+    inner_blocks=None, tile=None,
 ):
     from jax.experimental import pallas as pl
 
-    qi = pl.program_id(1)
-    step = pl.program_id(2)
-    num_k = pl.num_programs(2)
-    ki = step if window is None else step + _first_k_block(
-        qi, block_q, block_k, window)
+    qi, ki, first, last = _tile_of_step(tile, window and functools.partial(
+        _first_k_block, block_q=block_q, block_k=block_k, window=window))
 
-    @pl.when(step == 0)
+    @pl.when(first)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
@@ -544,7 +696,7 @@ def _bwd_dq_kernel(
             ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
-    @pl.when(step == num_k - 1)
+    @pl.when(last())
     def _finalize():
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
@@ -552,17 +704,14 @@ def _bwd_dq_kernel(
 def _bwd_dkv_kernel(
     q_off_ref, k_off_ref, k_ref, v_ref, q_ref, do_ref, lse_ref, dsum_ref,
     dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal, block_q, block_k,
-    window=None, inner_blocks=None,
+    window=None, inner_blocks=None, tile=None,
 ):
     from jax.experimental import pallas as pl
 
-    kj = pl.program_id(1)
-    step = pl.program_id(2)
-    num_q = pl.num_programs(2)
-    qi = step if window is None else step + _first_q_block(
-        kj, block_q, block_k)
+    kj, qi, first, last = _tile_of_step(tile, window and functools.partial(
+        _first_q_block, block_q=block_q, block_k=block_k))
 
-    @pl.when(step == 0)
+    @pl.when(first)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -586,7 +735,7 @@ def _bwd_dkv_kernel(
             ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
-    @pl.when(step == num_q - 1)
+    @pl.when(last())
     def _finalize():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
@@ -595,7 +744,7 @@ def _bwd_dkv_kernel(
 def _bwd_fused_kernel(
     q_off_ref, k_off_ref, k_ref, v_ref, q_ref, do_ref, lse_ref, dsum_ref,
     dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *, scale, block,
-    window=None, inner_blocks=None,
+    window=None, inner_blocks=None, tile=None,
 ):
     """The whole backward pass of causal self-attention in the dk/dv
     kernel's grid (k-block ``kj`` outer, q-block inner, equal blocks): a
@@ -608,12 +757,10 @@ def _bwd_fused_kernel(
     follows the outer axis."""
     from jax.experimental import pallas as pl
 
-    kj = pl.program_id(1)
-    step = pl.program_id(2)
-    num_q = pl.num_programs(2)
-    qi = step if window is None else step + _first_q_block(kj, block, block)
+    kj, qi, first, last = _tile_of_step(tile, window and functools.partial(
+        _first_q_block, block_q=block, block_k=block))
 
-    @pl.when(step == 0)
+    @pl.when(first)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -652,7 +799,7 @@ def _bwd_fused_kernel(
         def _diagonal():
             dq_ref[0] = dq_acc[qi].astype(dq_ref.dtype)
 
-    @pl.when(step == num_q - 1)
+    @pl.when(last())
     def _finalize():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
@@ -797,6 +944,8 @@ def flash_backward_blocks(
     k_off = _vary_like(jnp.asarray([k_offset], jnp.int32).reshape(1), union)
 
     window = _window_for(window, causal, t, tk, q_offset, k_offset)
+    live = causal_grid(t, tk, block_q, block_k, causal=causal, window=window,
+                       q_offset=q_offset, k_offset=k_offset) == "live"
     k_steps, q_steps = tk // block_k, t // block_q
     k_block = q_block = (lambda outer, j: j)
     name = "flash_attention_bwd_"
@@ -816,25 +965,39 @@ def flash_backward_blocks(
             return jnp.minimum(
                 _first_q_block(kj, block_q, block_k) + j, last_q)
 
-    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    # k-block outer, q-block inner: the dk/dv call's grid and the fused one's
-    k_spec = pl.BlockSpec((1, block_k, d), lambda b_, j, i: (b_, j, 0))
-    v_spec = pl.BlockSpec((1, block_k, dv), lambda b_, j, i: (b_, j, 0))
-    q_spec_kv = pl.BlockSpec(
-        (1, block_q, d), lambda b_, j, i: (b_, q_block(j, i), 0))
-    do_spec_kv = pl.BlockSpec(
-        (1, block_q, dv), lambda b_, j, i: (b_, q_block(j, i), 0))
-    stat_spec_kv = pl.BlockSpec(
-        (1, block_q, 1), lambda b_, j, i: (b_, q_block(j, i), 0))
-    kv_grid = dict(
-        grid=(bh, tk // block_k, q_steps),
-        in_specs=[
-            smem, smem, k_spec, v_spec, q_spec_kv, do_spec_kv,
-            stat_spec_kv, stat_spec_kv,
-        ],
-        interpret=interpret,
-    )
-    kv_operands = (q_off, k_off, kf, vf, qf, dof, lsef, dsumf)
+    def call(kernel, outer, operands, blocks, outs, scratch, **params):
+        """One backward call on the grid its shapes take. ``outer``: the
+        outer blocks' axis (``"q"``: q-block, then its k-blocks, the dq
+        call; ``"k"``: k-block, then its q-blocks, the dk/dv and the fused
+        call). ``blocks``: per operand (then per result in ``outs``) its
+        block's (rows, width, whether it follows the OUTER axis)."""
+        kernel, grid, tables, outer_at, inner_at = _grid_plan(
+            kernel, live, outer, bh, t, block_q, block_k,
+            *(((t // block_q, k_steps), k_block) if outer == "q"
+              else ((tk // block_k, q_steps), q_block)))
+
+        def specs(blocks):
+            return [pl.BlockSpec((1, rows, width),
+                                 outer_at if follows_outer else inner_at)
+                    for rows, width, follows_outer in blocks]
+
+        smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+        return pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(tables), grid=grid,
+                in_specs=[smem, smem, *specs(blocks)],
+                out_specs=tuple(specs(outs)), scratch_shapes=scratch),
+            interpret=interpret, **params,
+        )(*(_vary_like(jnp.asarray(table), union) for table in tables),
+          q_off, k_off, *operands)
+
+    # (rows, width, follows the outer axis) of a block in the k-outer grids
+    # (the dk/dv call's and the fused one's) and in the dq call's q-outer one
+    k_kv, v_kv = (block_k, d, True), (block_k, dv, True)
+    kv_blocks = [k_kv, v_kv, (block_q, d, False), (block_q, dv, False),
+                 (block_q, 1, False), (block_q, 1, False)]
+    kv_operands = (kf, vf, qf, dof, lsef, dsumf)
     kv_acc = [
         pltpu.VMEM((block_k, d), jnp.float32),
         pltpu.VMEM((block_k, dv), jnp.float32),
@@ -844,63 +1007,48 @@ def flash_backward_blocks(
         # the names begin as the dq call's do: a reader that counts passes
         # by ``flash_attention[_window]_bwd_dq`` counts this call once, with
         # the whole pass's time
-        dq, dk, dv_ = pl.pallas_call(
+        dq, dk, dv_ = call(
             functools.partial(
                 _bwd_fused_kernel, scale=scale, block=block_k, **dkv_window),
-            out_shape=(sds((bh, t, d), q.dtype), sds((bh, tk, d), k.dtype),
-                       sds((bh, tk, dv), v.dtype)),
+            "k", kv_operands, kv_blocks,
             # dq's block follows the OUTER axis: q-block kj is complete, and
             # written, at the first live step of k-block kj
-            out_specs=(k_spec, k_spec, v_spec),
-            scratch_shapes=[
-                pltpu.VMEM((t // block_q, block_q, d), jnp.float32), *kv_acc],
+            [k_kv, k_kv, v_kv],
+            [pltpu.VMEM((t // block_q, block_q, d), jnp.float32), *kv_acc],
+            out_shape=(sds((bh, t, d), q.dtype), sds((bh, tk, d), k.dtype),
+                       sds((bh, tk, dv), v.dtype)),
             compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=max(
                     fused_vmem_bytes(t, d, block_k, q.dtype.itemsize, dv),
                     VMEM_DEFAULT_BYTES)),
             name=name + "dq_dkv",
-            **kv_grid,
-        )(*kv_operands)
+        )
     else:
-        q_spec = pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0))
-        do_spec = pl.BlockSpec((1, block_q, dv), lambda b_, i, j: (b_, i, 0))
-        k_spec_dq = pl.BlockSpec(
-            (1, block_k, d), lambda b_, i, j: (b_, k_block(i, j), 0))
-        v_spec_dq = pl.BlockSpec(
-            (1, block_k, dv), lambda b_, i, j: (b_, k_block(i, j), 0))
-        stat_spec_dq = pl.BlockSpec(
-            (1, block_q, 1), lambda b_, i, j: (b_, i, 0))
-
-        dq = pl.pallas_call(
+        q_dq = (block_q, d, True)
+        dq, = call(
             functools.partial(
                 _bwd_dq_kernel,
                 scale=scale, causal=causal, block_q=block_q, block_k=block_k,
                 **dq_window,
             ),
-            out_shape=sds((bh, t, d), q.dtype),
-            grid=(bh, t // block_q, k_steps),
-            in_specs=[
-                smem, smem, q_spec, k_spec_dq, v_spec_dq, do_spec,
-                stat_spec_dq, stat_spec_dq,
-            ],
-            out_specs=q_spec,
-            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-            interpret=interpret,
+            "q", (qf, kf, vf, dof, lsef, dsumf),
+            [q_dq, (block_k, d, False), (block_k, dv, False),
+             (block_q, dv, True), (block_q, 1, True), (block_q, 1, True)],
+            [q_dq], [pltpu.VMEM((block_q, d), jnp.float32)],
+            out_shape=(sds((bh, t, d), q.dtype),),
             name=name + "dq",
-        )(q_off, k_off, qf, kf, vf, dof, lsef, dsumf)
+        )
 
-        dk, dv_ = pl.pallas_call(
+        dk, dv_ = call(
             functools.partial(
                 _bwd_dkv_kernel,
                 scale=scale, causal=causal, block_q=block_q, block_k=block_k,
                 **dkv_window,
             ),
+            "k", kv_operands, kv_blocks, [k_kv, v_kv], kv_acc,
             out_shape=(sds((bh, tk, d), k.dtype), sds((bh, tk, dv), v.dtype)),
-            out_specs=(k_spec, v_spec),
-            scratch_shapes=kv_acc,
             name=name + "dkv",
-            **kv_grid,
-        )(*kv_operands)
+        )
 
     return (
         dq.reshape(b, h, t, d),

@@ -467,3 +467,115 @@ def test_a_window_is_refused_where_the_kernels_do_not_build_it():
 
     with pytest.raises(ValueError, match="builds no window"):
         _attend(q, k, v, impl="ring", axis="sp", causal=True, window=32)
+
+
+# -- ISSUE 54: the causal calls step over the tiles under the diagonal --------
+
+
+# a cover of: T of 1, 2, 4, 8, 16 blocks x equal / wider q / wider k tiles x
+# the head x the dtype (every pair of them in one of the three files' cases;
+# the whole product is the interpreter's minutes)
+@pytest.mark.parametrize("d, blocks, block_q, block_k, dtype", [
+    (64, 1, 16, 16, jnp.float32), (64, 2, 32, 16, jnp.bfloat16),
+    (64, 4, 16, 32, jnp.float32), (64, 8, 16, 16, jnp.bfloat16),
+    (64, 16, 16, 16, jnp.float32), (64, 16, 32, 16, jnp.bfloat16),
+    (128, 1, 16, 32, jnp.bfloat16), (128, 2, 16, 16, jnp.float32),
+    (128, 4, 32, 16, jnp.float32), (128, 8, 16, 32, jnp.bfloat16),
+    (128, 16, 16, 16, jnp.bfloat16)])
+def test_the_live_grid_gives_the_rectangular_grids_bits(
+        d, blocks, block_q, block_k, dtype):
+    """Heads of 64 and 128 (256: ``test_flash_head_256.py``; 192 over 128:
+    ``test_flash_value_width.py``)."""
+    from flash_grid_cases import live_grid_against_rectangular
+
+    live_grid_against_rectangular(blocks, block_q, block_k, d, d, dtype)
+
+
+@pytest.mark.parametrize("t, block_q, block_k, want", [
+    (16 * 8, 8, 8, (256, 136)), (8 * 16, 16, 16, (64, 36)),
+    (4 * 16, 16, 16, (16, 10)), (64, 64, 64, (1, 1)),
+    (256, 64, 32, (32, 20)), (256, 32, 64, (32, 20)), (384, 128, 32, (36, 24)),
+    (32 * 8, 8, 8, (1024, 528))])
+def test_causal_steps_against_a_brute_force_count(t, block_q, block_k, want):
+    """A tile is live where any of its queries sees any of its keys: counted
+    from the whole mask, tile by tile; the tables list exactly those tiles,
+    an outer block's inner ones ascending, in both orders."""
+    fa = _flash_module()
+    mask = np.tril(np.ones((t, t), bool)).reshape(
+        t // block_q, block_q, t // block_k, block_k).any(axis=(1, 3))
+    assert fa.causal_steps(t, block_q, block_k) == (mask.size, mask.sum()) == want
+    by_q = fa._live_tiles(t, block_q, block_k, "q")
+    by_k = fa._live_tiles(t, block_q, block_k, "k")
+    assert by_q.dtype == by_k.dtype == np.int32
+    assert by_q.T.tolist() == [list(ij) for ij in zip(*np.nonzero(mask))]
+    assert by_k.T.tolist() == [list(ji) for ji in zip(*np.nonzero(mask.T))]
+    for j in range(t // block_k):
+        assert by_k[1][by_k[0] == j][0] == fa._first_q_block(
+            j, block_q, block_k)
+
+
+@pytest.mark.parametrize("case", [
+    "traced_offset", "tq_is_not_tk", "window", "non_causal",
+    "tables_past_smem"])
+def test_what_is_not_causal_from_zero_keeps_its_grid(monkeypatch, case):
+    """The live grid is taken from what the call sees (causal, no window,
+    both offsets the static 0, Tq = Tk) and by no flag; everything else
+    steps over the grid it had."""
+    from flash_grid_cases import pallas_grids
+
+    fa = _flash_module()
+    t, block = 128, 32
+    q = jnp.zeros((1, 2, t, 32))
+    stat = jnp.zeros((1, 2, t))
+    rect = (2, 4, 4)
+    if case == "traced_offset":
+        assert fa.causal_grid(t, t, block, block, k_offset=jnp.int32(0)) == (
+            "rectangular:runtime offsets")
+        assert fa.causal_grid(t, t, block, block, q_offset=32).startswith(
+            "rectangular")
+        got = pallas_grids(lambda off: fa.flash_attention_stats(
+            q, q, q, off, 0, True, block, block), jnp.int32(0))
+        got += pallas_grids(lambda off: fa.flash_backward_blocks(
+            q, q, q, stat, stat, q, 0, off, True, block, block), jnp.int32(0))
+        want = [("flash_attention_fwd", rect), ("flash_attention_bwd_dq", rect),
+                ("flash_attention_bwd_dkv", rect)]
+    elif case == "tq_is_not_tk":
+        k = jnp.zeros((1, 2, 2 * t, 32))
+        assert fa.causal_grid(t, 2 * t, block, block).startswith("rectangular")
+        got = pallas_grids(jax.grad(lambda q: fa.flash_attention(
+            q, k, k, True, block, block).sum()), q)
+        want = [("flash_attention_fwd", (2, 4, 8)),
+                ("flash_attention_bwd_dq", (2, 4, 8)),
+                ("flash_attention_bwd_dkv", (2, 8, 4))]
+    elif case == "window":
+        assert fa.causal_grid(t, t, block, block, window=40) == "window"
+        # a window of the whole row or more IS the causal call
+        assert fa.causal_grid(t, t, block, block, window=t) == "live"
+        steps = fa.window_steps(t, block, block, 40)
+        got = pallas_grids(jax.grad(lambda q: fa.flash_attention(
+            q, q, q, True, block, block, None, 40).sum()), q)
+        want = [("flash_attention_window_fwd", (2, 4, steps[0])),
+                ("flash_attention_window_bwd_dq_dkv", (2, 4, steps[1]))]
+    elif case == "non_causal":
+        assert fa.causal_grid(t, t, block, block, causal=False) == (
+            "rectangular:not causal")
+        got = pallas_grids(jax.grad(lambda q: fa.flash_attention(
+            q, q, q, False, block, block).sum()), q)
+        want = [("flash_attention_fwd", rect), ("flash_attention_bwd_dq", rect),
+                ("flash_attention_bwd_dkv", rect)]
+    else:
+        # two int32 a step in SMEM: past the tables' bound the rectangle stays
+        assert fa.causal_grid(362 * 1024, 362 * 1024, 1024, 1024).startswith(
+            "rectangular:more live tiles")
+        assert fa.causal_grid(361 * 1024, 361 * 1024, 1024, 1024) == "live"
+        monkeypatch.setattr(fa, "LIVE_GRID_MAX_STEPS", 9)
+        got = pallas_grids(jax.grad(lambda q: fa.flash_attention(
+            q, q, q, True, block, block).sum()), q)
+        want = [("flash_attention_fwd", rect),
+                ("flash_attention_bwd_dq_dkv", rect)]
+    assert got == want
+    live = pallas_grids(jax.grad(lambda q: fa.flash_attention(
+        q, q, q, True, block, block).sum()), q)
+    if case != "tables_past_smem":
+        assert live == [("flash_attention_fwd", (2, 10)),
+                        ("flash_attention_bwd_dq_dkv", (2, 10))]

@@ -151,3 +151,17 @@ def test_where_the_float32_form_turns_at_heads_of_128():
     assert fa.backward_form(172_032, 172_032, 128, 4) == "two_call"
     assert fa.backward_form(65_536, 65_536, 128, 2) == "fused"
     assert fa.backward_form(131_072, 131_072, 128, 2) == "two_call"
+
+
+@pytest.mark.parametrize("blocks, block_q, block_k, dtype", [
+    (1, 32, 16, jnp.float32), (2, 16, 32, jnp.bfloat16),
+    (4, 16, 16, jnp.bfloat16), (8, 32, 16, jnp.float32),
+    (8, 16, 16, jnp.bfloat16), (16, 16, 16, jnp.float32)])
+def test_the_live_grid_gives_the_rectangular_grids_bits_at_256(
+        blocks, block_q, block_k, dtype):
+    """ISSUE 54 at the GLM cell's widths: the causal calls step over the
+    tiles under the diagonal alone, and give the rectangular grid's bits
+    (the cover of the cases: ``test_flash_attention.py``)."""
+    from flash_grid_cases import live_grid_against_rectangular
+
+    live_grid_against_rectangular(blocks, block_q, block_k, D, D, dtype)

@@ -116,3 +116,18 @@ def test_the_shape_rules_reckon_with_both_widths():
     assert fa.dq_resident_bytes(8192, 192) < narrow < fa.fused_vmem_bytes(
         8192, 192, 512, 2)
     assert fa.backward_form(8192, 8192, 192, 2, value_dim=128) == "fused"
+
+
+@pytest.mark.parametrize("blocks, block_q, block_k, dtype", [
+    (1, 16, 16, jnp.bfloat16), (2, 16, 16, jnp.float32),
+    (4, 32, 16, jnp.bfloat16), (8, 16, 32, jnp.float32),
+    (16, 16, 16, jnp.bfloat16), (16, 16, 32, jnp.float32)])
+def test_the_live_grid_gives_the_rectangular_grids_bits_at_192_over_128(
+        blocks, block_q, block_k, dtype):
+    """ISSUE 54 at the Ling cell's widths (keys of 192 over values of 128):
+    the causal calls step over the tiles under the diagonal alone, and give
+    the rectangular grid's bits (the cover of the cases:
+    ``test_flash_attention.py``)."""
+    from flash_grid_cases import live_grid_against_rectangular
+
+    live_grid_against_rectangular(blocks, block_q, block_k, 192, 128, dtype)

@@ -307,6 +307,27 @@ def test_fit_facts_say_which_form_the_attention_backward_takes(
     assert tuple(facts[name] for name in ATTENTION_FACTS) == want
 
 
+@pytest.mark.parametrize("impl, tokens, want", [
+    # ISSUE 54: a head's causal calls step over the 10 tiles under the
+    # diagonal of 4 x 4 (1024-row tiles), not over the 16 of the rectangle
+    ("flash", 4096, ("global=live", 100.0)),
+    ("ulysses_flash", 4096, ("global=live", 100.0)),
+    # a ring step's offsets are values of the program: no grid follows them
+    ("ring_flash", 4096, ("global=rectangular:runtime offsets", 62.5)),
+    ("ring_flash", 16384, ("global=rectangular:runtime offsets", 53.125)),
+    # no flash kernel, no grid
+    ("full", 4096, ("global=xla", None))])
+def test_fit_facts_say_which_grid_the_attention_calls_step_over(
+        impl, tokens, want):
+    """ISSUE 54: from ``attn_impl`` and the shapes, as the kernel decides it
+    (``ops.flash_attention.causal_grid``); the share becomes the gauge
+    ``model.attention.causal_grid_live_share``."""
+    big = LoopLM(vocab_size=49152, attn_impl=impl)
+    facts = big.fit_facts(np.zeros((2, tokens + 1), np.int32))
+    assert (facts["attention_grid"],
+            facts.get("attention.causal_grid_live_share")) == want
+
+
 # -- the sequence column ------------------------------------------------------
 
 

@@ -11,18 +11,21 @@ import jax.numpy as jnp
 import pytest
 from tpu_compile_helpers import (  # noqa: F401 - fixtures by name
     BWD_DKV, calls, cell_config, epoch_program, instructions,
-    kernels_compile, loss_products, no_compile_cache, one_chip)
+    kernels_compile, loss_products, mosaic_grids, no_compile_cache, one_chip)
 
 
-@pytest.mark.parametrize("dtype, precision, tile", [
-    (jnp.bfloat16, None, 512), (jnp.float32, "highest", 256)])
+@pytest.mark.parametrize("dtype, precision, tile, live", [
+    (jnp.bfloat16, None, 512, 136), (jnp.float32, "highest", 256, 528)])
 def test_flash_kernels_compile_at_keys_of_256_over_values_of_256(
-        one_chip, no_compile_cache, dtype, precision, tile):
+        one_chip, no_compile_cache, mosaic_grids, dtype, precision, tile,
+        live):
     """[20 heads, T 8192], q, k, v, o and do of 256 lanes: the timed bf16
     step's tiles and the float32 ones of the matched check. A tile's bytes go
     by the two widths' lanes, 256 + 256: half the rows of heads of 128; the
     backward is the one fused call, whose dq [8192, 256] float32 (8.4 MB)
-    fits the VMEM it may ask for beside the tiles."""
+    fits the VMEM it may ask for beside the tiles. ISSUE 54: both calls step
+    over the 136 tiles under the diagonal of 16 x 16 (528 of 32 x 32 in
+    float32), not over the rectangle."""
     fa = importlib.import_module("raydp_tpu.ops.flash_attention")
     t, itemsize = 8192, jnp.dtype(dtype).itemsize
     assert fa.pick_blocks(t, t, head_dim=256, itemsize=itemsize,
@@ -44,10 +47,12 @@ def test_flash_kernels_compile_at_keys_of_256_over_values_of_256(
     for name in ("flash_attention_fwd", "flash_attention_bwd_dq_dkv"):
         assert calls(text, name) == 1, name
     assert not re.search(BWD_DKV, text)
+    assert mosaic_grids == [("flash_attention_fwd", (20, live)),
+                            ("flash_attention_bwd_dq_dkv", (20, live))]
 
 
 def test_latent_mtp_hybridlm_epoch_program_fits_the_chip(
-        one_chip, no_compile_cache, kernels_compile):
+        one_chip, no_compile_cache, kernels_compile, mosaic_grids):
     """ISSUE 53: the benchmark's epoch program of
     ``glm-4.7-flash.pretrain-8k-mtp`` (706,518,848 float32 parameters counted
     from the built tree: the stage of the published model, name by name;
@@ -104,6 +109,10 @@ def test_latent_mtp_hybridlm_epoch_program_fits_the_chip(
     text = compiled.as_text()
     for name in ("flash_attention_fwd", "flash_attention_bwd_dq_dkv"):
         assert calls(text, name) == 6, (name, calls(text, name))
+    # ISSUE 54: every causal call of the program steps over the 136 tiles
+    # under the diagonal of a head's 16 x 16, where the rectangle had 256
+    flash = [grid for name, grid in mosaic_grids if "flash_attention" in name]
+    assert flash and set(flash) == {(20, 136)}, flash
     assert not re.search(BWD_DKV, text)
     assert loss_products(text, "hybridlm.loss") == 3
     assert loss_products(text, "hybridlm.mtp.loss") == 3

@@ -11,16 +11,19 @@ import jax
 import jax.numpy as jnp
 import pytest
 from tpu_compile_helpers import (  # noqa: F401 - fixtures by name
-    BWD_DKV, kernels_compile, no_compile_cache, one_chip)
+    BWD_DKV, kernels_compile, mosaic_grids, no_compile_cache, one_chip)
 
 
-@pytest.mark.parametrize("dtype, precision, tile", [
-    (jnp.bfloat16, None, 1024), (jnp.float32, "highest", 512)])
+@pytest.mark.parametrize("dtype, precision, tile, live", [
+    (jnp.bfloat16, None, 1024, 10), (jnp.float32, "highest", 512, 36)])
 def test_flash_forward_and_backward_compile_at_ouro_widths(
-        one_chip, no_compile_cache, dtype, precision, tile):
+        one_chip, no_compile_cache, mosaic_grids, dtype, precision, tile,
+        live):
     """[2 x 16 heads, T 4096, head 128], causal: the timed bf16 step's tiles,
     and the float32 ones of the benchmark's matched check (1024-row float32
-    tiles ask the backward kernel for 19.5 MB of its 16 MB of VMEM)."""
+    tiles ask the backward kernel for 19.5 MB of its 16 MB of VMEM). ISSUE
+    54: both calls step over the tiles under the diagonal alone (10 of 4 x
+    4, 36 of 8 x 8)."""
     fa = importlib.import_module("raydp_tpu.ops.flash_attention")
     assert fa.pick_blocks(4096, 4096, head_dim=128,
                           itemsize=jnp.dtype(dtype).itemsize) == (tile, tile)
@@ -40,24 +43,36 @@ def test_flash_forward_and_backward_compile_at_ouro_widths(
     # PR 43: the backward pass is ONE call
     assert not re.search(BWD_DKV, text)
     assert text.count("tpu_custom_call") == 2
+    assert mosaic_grids == [("flash_attention_fwd", (32, live)),
+                            ("flash_attention_bwd_dq_dkv", (32, live))]
 
 
-@pytest.mark.parametrize("heads, t, head, window", [
-    (32, 4096, 128, None), (32, 8192, 64, None), (128, 8192, 64, None),
-    (56, 16384, 128, None), (56, 16384, 128, 4096)],
+# the fused backward's grid a head: (bf16, float32) tiles under the diagonal,
+# or the window's (k-blocks, q-steps)
+@pytest.mark.parametrize("heads, t, head, window, steps", [
+    (32, 4096, 128, None, (10, 36)), (32, 8192, 64, None, (36, 136)),
+    (128, 8192, 64, None, (36, 136)), (56, 16384, 128, None, (136, 528)),
+    (56, 16384, 128, 4096, ((16, 5), (32, 9))),
+    (15, 8192, 128, None, (36, 136))],
     ids=["ouro", "granite", "routed", "window_cell_global",
-         "window_cell_window"])
+         "window_cell_window", "olmo"])
 @pytest.mark.parametrize("dtype, precision", [
     (jnp.bfloat16, None), (jnp.float32, "highest")],
     ids=["bf16", "float32_matched"])
 def test_fused_flash_backward_compiles_at_the_cells_shapes(
-        one_chip, no_compile_cache, heads, t, head, window, dtype, precision):
+        one_chip, no_compile_cache, mosaic_grids, heads, t, head, window,
+        steps, dtype, precision):
     """PR 43: the ONE-call backward pass alone, [batch x heads, T, head] of
-    the four LM cells (the timed bf16 step and the matched check's float32
-    tiles): a head's float32 dq lives in VMEM (2-8 MB) beside the tile, so
+    the LM cells (the timed bf16 step and the matched check's float32
+    tiles; the Ling and GLM cells' widths: their own files): a head's
+    float32 dq lives in VMEM (2-8 MB) beside the tile, so
     the call asks for more than a Mosaic call's 16 MB by
     ``vmem_limit_bytes`` (``fused_vmem_bytes``, from the shapes): what it
-    asks for must cover what the compiler needs, here and not on the chip."""
+    asks for must cover what the compiler needs, here and not on the chip.
+    ISSUE 54: the grid Mosaic is handed is (heads, tiles under the
+    diagonal), 136 where the 16 x 16 rectangle had 256; a window's stays
+    (heads, k-blocks, the q-blocks a k-block's window touches). A dead step
+    coming back fails here."""
     fa = importlib.import_module("raydp_tpu.ops.flash_attention")
     itemsize = jnp.dtype(dtype).itemsize
     block, _ = fa.pick_blocks(t, t, head_dim=head, itemsize=itemsize)
@@ -82,10 +97,45 @@ def test_fused_flash_backward_compiles_at_the_cells_shapes(
     # array anywhere in the program when the operands are bf16
     if dtype == jnp.bfloat16:
         assert f"f32[{heads},{t},{head}]" not in text
+    live = steps[dtype == jnp.float32]
+    assert mosaic_grids == [
+        (name, (heads, *live) if window else (heads, live))]
+    if not window:
+        assert (fa.causal_grid(t, t, block, block),
+                fa.causal_steps(t, block, block)) == (
+                    "live", ((t // block) ** 2, live))
+
+
+@pytest.mark.parametrize("heads, t, head, steps", [
+    (128, 8192, 64, (36, 136)), (56, 16384, 128, (136, 528)),
+    (15, 8192, 128, (36, 136))],
+    ids=["routed", "window_cell_global", "olmo"])
+@pytest.mark.parametrize("dtype, precision", [
+    (jnp.bfloat16, None), (jnp.float32, "highest")],
+    ids=["bf16", "float32_matched"])
+def test_causal_flash_forward_steps_over_live_tiles_at_the_cells_shapes(
+        one_chip, no_compile_cache, mosaic_grids, heads, t, head, steps,
+        dtype, precision):
+    """ISSUE 54: the forward call alone at the cells' shapes no other test
+    compiles it at (Ouro and Granite: above and below; Ling and GLM: their
+    own files), for the described v5e, on the grid of the tiles under the
+    diagonal, its two tables scalar-prefetched."""
+    fa = importlib.import_module("raydp_tpu.ops.flash_attention")
+    q = jax.ShapeDtypeStruct((1, heads, t, head), dtype, sharding=one_chip)
+
+    def forward(q, k, v):
+        with jax.default_matmul_precision(precision):
+            return fa.flash_attention(q, k, v, True, None, None, False)
+
+    text = jax.jit(forward).lower(q, q, q).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert mosaic_grids == [
+        ("flash_attention_fwd", (heads, steps[dtype == jnp.float32]))]
 
 
 @pytest.mark.parametrize("matched", [False, True], ids=["bf16", "float32_matched"])
-def test_flash_kernels_compile_at_head_dim_64(one_chip, no_compile_cache, matched):
+def test_flash_kernels_compile_at_head_dim_64(
+        one_chip, no_compile_cache, mosaic_grids, matched):
     """[1 x 32 heads, T 8192, head 64], causal: the grouped-query layer's
     kernels after K and V are repeated to the query heads (the flash
     kernels had only ever run heads of 128), in the timed step's bf16
@@ -110,6 +160,10 @@ def test_flash_kernels_compile_at_head_dim_64(one_chip, no_compile_cache, matche
     for name in ("flash_attention_fwd", "flash_attention_bwd_dq_dkv"):
         assert re.search(rf"%[\w.\-]*{name}[\w.\-]* = ", text), name
     assert not re.search(BWD_DKV, text)
+    # ISSUE 54: the tiles under the diagonal of 8 x 8 (16 x 16 in float32)
+    live = 136 if matched else 36
+    assert mosaic_grids == [("flash_attention_fwd", (32, live)),
+                            ("flash_attention_bwd_dq_dkv", (32, live))]
 
 
 @pytest.mark.parametrize("impl, kernel", [

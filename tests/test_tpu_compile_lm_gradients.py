@@ -58,9 +58,10 @@ def test_looplm_gradient_keeps_what_the_flash_forward_gave(
     assert kept.memory_analysis().temp_size_in_bytes <= temp_limit
     assert loss_products(kept.as_text(), "looplm.exit_loss") == 3
     # what a change that leaves the model's options alone must not move
-    # (12,395 until PR 43 made the flash backward one call of two)
+    # (12,395 until PR 43 made the flash backward one call of two; 12,341
+    # until ISSUE 54 handed the flash calls their tables)
     if not matched:
-        assert instructions(kept.as_text()) == 12_341
+        assert instructions(kept.as_text()) == 12_400
 
 
 @pytest.mark.parametrize("remat, calls_a_layer", [(False, 2), (True, 3)],

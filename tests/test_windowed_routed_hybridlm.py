@@ -414,6 +414,30 @@ def test_fit_facts_say_which_form_the_attention_backward_takes(
                 narrow["attention.dq_resident_bytes"]) == (1, 8192 * 128 * 4)
 
 
+@pytest.mark.parametrize("impl, tokens, want", [
+    # the cell: the global layer's calls step over the 136 tiles under the
+    # diagonal of 16 x 16, the window layers' over the window's bounded grid
+    ("flash", 16384, ("global=live,window=window", 100.0)),
+    # a window of the whole row hides nothing: the causal call, live
+    ("flash", 4096, ("global=live,window=live", 100.0)),
+    # a ring step's offsets are values of the program: 136 of 256 steps live
+    ("ring_flash", 16384, (
+        "global=rectangular:runtime offsets,"
+        "window=rectangular:runtime offsets", 53.125)),
+    ("full", 16384, ("global=xla,window=xla", None))])
+def test_fit_facts_say_which_grid_the_attention_calls_step_over(
+        impl, tokens, want):
+    """ISSUE 54: by layer kind, from ``attn_impl``, the window and the
+    shapes, as the kernel decides it (``ops.flash_attention.causal_grid``);
+    at the published widths."""
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "smallthinker-21b-a3b.json")) as f:
+        module = HybridLM.from_config(json.load(f), attn_impl=impl)
+    facts = module.fit_facts(np.zeros((2, tokens + 1), np.int32))
+    assert (facts["attention_grid"],
+            facts.get("attention.causal_grid_live_share")) == want
+
+
 def test_a_model_without_window_layers_says_nothing_of_them():
     facts = HybridLM(vocab_size=64, hidden_size=32, num_heads=4,
                      num_kv_heads=2, intermediate_size=48,

@@ -56,6 +56,30 @@ def kernels_compile(monkeypatch):
     monkeypatch.setattr(jax, "device_count", lambda *_: 1)
 
 
+@pytest.fixture()
+def mosaic_grids(monkeypatch):
+    """[(kernel's name, its grid)] of every Mosaic call lowered while the
+    fixture lives, read from the module Mosaic is handed
+    (``iteration_bounds``): what the chip steps over, not what the caller
+    meant to ask for."""
+    from jax._src.pallas.mosaic import pallas_call_registration as registration
+
+    mosaic = getattr(registration, "mosaic", None)
+    if not hasattr(mosaic, "lower_module_to_custom_call"):
+        pytest.skip("this jax hands Mosaic its module some other way")
+    lower, lowered = mosaic.lower_module_to_custom_call, []
+
+    def record(*args, module, kernel_name, **kwargs):
+        bounds = re.search(r"iteration_bounds = array<i64: ([\d, ]+)>",
+                           str(module))
+        lowered.append((kernel_name, tuple(
+            int(n) for n in bounds.group(1).split(",")) if bounds else ()))
+        return lower(*args, module=module, kernel_name=kernel_name, **kwargs)
+
+    monkeypatch.setattr(mosaic, "lower_module_to_custom_call", record)
+    return lowered
+
+
 def instructions(text):
     """The instructions of a compiled program's text: what a change that
     leaves a model's options as they were must not move."""
