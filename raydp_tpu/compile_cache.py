@@ -25,9 +25,14 @@ def default_cache_dir() -> str:
 
 def enable_compile_cache() -> str:
     """Make sure this process compiles against the persistent cache; returns
-    the directory in use. Idempotent."""
+    the directory in use. Idempotent. Every compiling process passes here
+    before its first compile, so this is also where the compile account's
+    listeners are registered (``obs.profiler``: once a process)."""
     import jax
 
+    from raydp_tpu.obs import profiler
+
+    profiler.install_compile_listeners()
     configured = jax.config.jax_compilation_cache_dir
     if configured:
         return configured

@@ -621,20 +621,36 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
         thing it does: noted under ``what``, by weak reference, for whoever
         asks what its instructions belong to
         (``obs.profiler.device_scopes``). The FLOPs probe's program is never
-        run and is not noted."""
+        run and is not noted.
+
+        While the span is open the compile account (``obs.profiler``) books
+        what jax reports of compiles on this thread to this site; at its end
+        the span carries the split (``trace_s``, ``lower_s``, ``backend_s``,
+        ``cache_load_s``, ``programs``, ``cache_hits``, ``cache_misses``; its
+        twin in a profiler's trace too) and ``rest_s``, its duration less the
+        four: at ``init`` the init program's RUN and the twin's text, at
+        ``flops_probe`` the cost analysis, at a step site ``fit_facts`` and
+        ``note_program``. The same go to the counters
+        ``estimator.compile.*``."""
         from raydp_tpu.obs import profiler
 
         with obs.span("estimator.compile", what=str(what)) as span:
-            yield functools.partial(profiler.note_program, what)
-            if getattr(self, "_fit_facts", None):
-                span.set(**self._fit_facts)
-            if self._row_plan is not None:  # a program of this fit's step
-                span.set(
-                    row_update_params=len(self._row_plan.paths),
-                    row_update_bytes_skipped=self._row_plan.bytes_skipped,
-                    row_update_dma_leaves=self._row_plan.kernel_leaves,
-                    row_update_gather_leaves=self._row_plan.gather_leaves,
-                )
+            site = profiler.open_compile_site(str(what), self._fit_seq)
+            try:
+                yield functools.partial(profiler.note_program, what)
+                if getattr(self, "_fit_facts", None):
+                    span.set(**self._fit_facts)
+                if self._row_plan is not None:  # a program of this fit's step
+                    span.set(
+                        row_update_params=len(self._row_plan.paths),
+                        row_update_bytes_skipped=self._row_plan.bytes_skipped,
+                        row_update_dma_leaves=self._row_plan.kernel_leaves,
+                        row_update_gather_leaves=self._row_plan.gather_leaves,
+                    )
+            finally:
+                span.set_traced(
+                    what=str(what), **profiler.close_compile_site(site))
+        span.set(rest_s=profiler.settle_compile_site(site, span.duration))
         self.compile_seconds_ += span.duration
         obs.metrics.counter("estimator.compile_seconds").inc(span.duration)
 
@@ -680,6 +696,8 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
     def fit(self, train_ds, evaluate_ds=None, max_retries: int = 0) -> List[Dict[str, float]]:
         import jax
 
+        from raydp_tpu.obs import profiler
+
         attempts = 0
         # Snapshot the pre-existing newest checkpoint so retries only resume
         # from epochs saved by THIS run — a stale checkpoint from a prior fit
@@ -716,10 +734,18 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                                 epochs=self.num_epochs,
                                 streaming=str(self.streaming),
                                 attempt=attempts,
-                            ):
+                            ) as fit_span:
+                                # what the first fence reads the fit's
+                                # residue from (_note_first_fence)
+                                self._fit_live = (fit_span, fit_records)
                                 return self._fit_once(train_ds, evaluate_ds)
                         finally:
                             self.last_fit_records_ = fit_records
+                            self._fit_live = None
+                            # a fit that has returned or raised compiles
+                            # nothing late any more
+                            profiler.close_late_window(
+                                getattr(self, "_fit_seq", 0))
                 except Exception:
                     attempts += 1
                     if attempts > max_retries:
@@ -812,6 +838,9 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
         # MFU gauge — all resolved once per fit
         recorder = self._step_recorder = _profiler.step_recorder()
         self._fit_capture = _profiler.armed_capture()
+        # what this fit's compile sites are listed under (compile_account)
+        self._fit_seq = _profiler.next_fit()
+        self._fits = (*getattr(self, "_fits", ())[-63:], self._fit_seq)
         self._flops_per_step = None
         self._mfu_mark = self._mfu_origin = None
         self._peak_info = _costmodel.device_peak_flops()
@@ -1086,12 +1115,17 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
                     recorder.open_restart(epoch)
                 self._update_live_mfu()
                 self._history.append(record)
+                if len(self._history) == 1:
+                    self._note_first_fence(start_epoch)
                 # EVERY process calls save: orbax's Checkpointer runs
                 # cross-process barriers and writes from the primary host
                 # only — a lone process-0 save deadlocks on those barriers
                 if self.checkpoint_dir:
                     self._save_checkpoint(params, epoch, opt_state)
                     self._gc_step_checkpoints(epoch)
+            # the last epoch is fenced: what the fit compiles from here on
+            # (the history's fetch below) delays no step
+            _profiler.close_late_window(self._fit_seq)
 
         if self._history:
             # ONE host fetch for every epoch's loss: a per-record float()
@@ -1133,6 +1167,7 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
             "row_update": {
                 **row_plan.stats(), "probe_seconds": probe_span.duration,
             },
+            "compile": _profiler.compile_account(fits=(self._fit_seq,)),
         }
         if mfps:
             obs.metrics.gauge("estimator.model_flops_per_sec").set(mfps)
@@ -1150,6 +1185,47 @@ class JaxEstimator(EstimatorInterface, EtlEstimatorInterface):
     # per-fit compute-observatory summary (obs/profiler.py + obs/costmodel):
     # step-phase totals, FLOPs accounting, live MFU — docs/estimators.md
     fit_stats_: Dict[str, Any]
+
+    def _note_first_fence(self, first_epoch: int) -> None:
+        """The fit's first epoch has been fenced and recorded. From here
+        until its last epoch is (or the fit raises) a compile anywhere in
+        the process is LATE (``estimator.compile.late_*``: a fit whose
+        shapes are steady reads 0). And the fit's own residue, one subtraction at this one
+        fence: ``estimator.fit.first_fence_seconds`` (fit start to here) and
+        ``estimator.fit.unaccounted_seconds``, that less the fit span's
+        children closed so far on this thread: what of a fit's start is
+        under no span at all (mesh and model resolution, ``device_put`` of
+        the parameters, the optimizer's re-init, checkpoint look-ups)."""
+        import threading
+
+        from raydp_tpu.obs import profiler
+
+        if first_epoch + 1 < self.num_epochs:
+            profiler.open_late_window(
+                self._fit_seq, self._history, first_epoch)
+        fit_span, records = self._fit_live
+        wall = fit_span.elapsed()
+        # the producer thread's spans parent under the fit's too, and run
+        # beside this thread's
+        tid = threading.get_ident() % 1_000_000
+        held = sum(
+            r["dur"] for r in records
+            if r.get("parent") == fit_span.id and r.get("tid") == tid
+        ) / 1e6
+        obs.metrics.counter("estimator.fit.first_fence_seconds").inc(wall)
+        obs.metrics.counter("estimator.fit.unaccounted_seconds").inc(
+            max(0.0, wall - held))
+
+    def compile_account(self) -> dict:
+        """``obs.profiler.compile_account()`` cut to this estimator's fits:
+        every ``estimator.compile`` site with its seconds split into trace /
+        lower / XLA compile / cache load / rest and its programs, cache hits
+        and misses; the process's compiles that no site held, by name; the
+        compiles that came after a fit's first fence. From any thread, while
+        a fit runs."""
+        from raydp_tpu.obs import profiler
+
+        return profiler.compile_account(fits=getattr(self, "_fits", ()))
 
     def explain_last_fit(self, top_k: int = 5) -> dict:
         """Critical-path wall-time attribution of the last ``fit()`` (the

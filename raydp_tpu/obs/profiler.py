@@ -34,6 +34,12 @@ Dapper-style complement to request tracing). Three pieces:
   instruction of the compiled programs still alive belongs to. A device
   trace's events carry an instruction's HLO line and no scope; joined to
   this map by instruction name they give device time by scope.
+- **Compile account** (:func:`install_compile_listeners` /
+  :func:`compile_account`): what ``jax.monitoring`` reports of every compile
+  in the process (trace, lowering, XLA's compile or the cache's load), kept
+  by the ``estimator.compile`` site open on the reporting thread, by the
+  function's name where no site is open, and once more where it comes after
+  a fit's first fence.
 
 Stdlib-only at import (jax strictly on demand, and NEVER imported by the
 memory sampler — a ``python -S`` worker without jax must flush cleanly).
@@ -667,6 +673,284 @@ def scopes_in_text(text: str, names=None) -> Dict[str, dict]:
                     said["mixed"] = True
             out[instruction.name] = said
     return out
+
+
+# ---------------------------------------------------------------------------
+# compile account (every compile of the process, by where it happened)
+# ---------------------------------------------------------------------------
+
+# what jax reports of a compile through ``jax.monitoring`` (0.9.0): three
+# durations with the function's name, and the persistent cache's request and
+# hit INSIDE the backend interval of the thread that asked
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_REQUEST_EVENT = "/jax/compilation_cache/compile_requests_use_cache"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_DURATION_PARTS = {
+    _TRACE_EVENT: "trace_s", _LOWER_EVENT: "lower_s",
+    _BACKEND_EVENT: "backend_s",
+}
+
+# a site's seconds by what jax did in them; a backend interval with a cache
+# hit inside it is a load, one without is XLA compiling anew
+SITE_PARTS = ("trace_s", "lower_s", "backend_s", "cache_load_s")
+_SITES_KEPT = 512  # the newest sites of the process
+# how long a thread remembers an interval it claimed: a later event can
+# only hold what began after it did, and no trace or lowering lasts so long.
+# Inside one open trace the list grows by its nested traces (thousands for
+# a large model) and falls to one entry when the trace ends
+_INTERVALS_KEPT_S = 1800.0
+_OUTSIDE_ROWS = 64  # names kept outside any site; the 16 largest are shown
+NAMED_ROWS_SHOWN = 16
+_OTHER = "other"
+
+_compile_tls = threading.local()
+_listeners_installed = False
+_fit_seq = itertools.count(1)
+_sites: "collections.deque" = collections.deque(maxlen=_SITES_KEPT)
+_outside: Dict[str, dict] = {}  # fun_name -> {seconds, programs, under}
+_late: Dict[str, dict] = {}  # fun_name -> {seconds, programs, under, epoch, fit}
+# fits past their first fence: fit -> (its history list, its first epoch)
+_late_windows: Dict[int, Tuple[list, int]] = {}
+
+
+def install_compile_listeners() -> None:
+    """Register the account's two ``jax.monitoring`` listeners, once a
+    process (``compile_cache.enable_compile_cache`` calls this, which every
+    compiling process calls before its first compile)."""
+    global _listeners_installed
+    if _listeners_installed:
+        return
+    _listeners_installed = True
+    import jax
+
+    jax.monitoring.register_event_listener(_on_compile_event)
+    jax.monitoring.register_event_duration_secs_listener(_on_compile_duration)
+
+
+def next_fit() -> int:
+    """A number for a fit that begins, unique in the process: what its
+    compile sites are listed under."""
+    return next(_fit_seq)
+
+
+def open_compile_site(what: str, fit: int) -> dict:
+    """An ``estimator.compile`` span has opened on this thread: until
+    :func:`close_compile_site`, what jax reports of compiles here belongs to
+    the site ``what`` of fit ``fit``."""
+    site = {
+        "what": what, "fit": fit, "wall_s": None, "trace_s": 0.0,
+        "lower_s": 0.0, "backend_s": 0.0, "cache_load_s": 0.0,
+        "rest_s": None, "programs": 0, "cache_hits": 0, "cache_misses": 0,
+        "_outer": getattr(_compile_tls, "site", None),
+    }
+    _compile_tls.site = site
+    _sites.append(site)
+    return site
+
+
+def close_compile_site(site: dict) -> dict:
+    """The span is about to end: nothing more belongs to the site. Returns
+    what the span carries: the four parts, ``programs``, ``cache_hits`` and
+    ``cache_misses`` (requests of the cache that it did not serve; a program
+    that is neither had no cache key)."""
+    _compile_tls.site = site.pop("_outer", None)
+    return {key: site[key] for key in
+            (*SITE_PARTS, "programs", "cache_hits", "cache_misses")}
+
+
+def settle_compile_site(site: dict, wall: float) -> float:
+    """The span has ended after ``wall`` seconds: the site's ``rest_s`` is
+    what of them jax reported nothing of (returned), and the parts go to the
+    process's counters ``estimator.compile.*``."""
+    rest = max(0.0, wall - sum(site[part] for part in SITE_PARTS))
+    site["wall_s"], site["rest_s"] = wall, rest
+    metrics.counter("estimator.compile.trace_seconds").inc(site["trace_s"])
+    metrics.counter("estimator.compile.lower_seconds").inc(site["lower_s"])
+    metrics.counter("estimator.compile.backend_seconds").inc(site["backend_s"])
+    metrics.counter("estimator.compile.cache_load_seconds").inc(
+        site["cache_load_s"])
+    metrics.counter("estimator.compile.rest_seconds").inc(rest)
+    metrics.counter("estimator.compile.programs").inc(site["programs"])
+    metrics.counter("estimator.compile.cache_hits").inc(site["cache_hits"])
+    metrics.counter("estimator.compile.cache_misses").inc(
+        site["cache_misses"])
+    return rest
+
+
+def open_late_window(fit: int, history: list, first_epoch: int) -> None:
+    """Fit ``fit`` has fenced its first epoch: until
+    :func:`close_late_window` every compile of the process is late too.
+    ``history`` is the fit's live list of epoch records: its length says,
+    when a late compile is named, in which epoch."""
+    _late_windows[fit] = (history, first_epoch)
+    # there from here on, at 0: "nothing compiled late" is a reading
+    metrics.counter("estimator.compile.late_seconds")
+    metrics.counter("estimator.compile.late_programs")
+
+
+def close_late_window(fit: int) -> None:
+    _late_windows.pop(fit, None)
+
+
+def _book(table: Dict[str, dict], cap: int, nested, at: int, name: str,
+          seconds: float, programs: int, **said):
+    """``seconds`` and ``programs`` to the row ``name`` of a bounded table
+    (past ``cap`` names: to the row ``other``; a name already met under
+    another span: to a row ``name [span]``), and with them the trace
+    seconds the ``nested`` intervals had booked there (their key and seconds
+    at ``[at]``, ``[at + 1]``): a nested function's seconds move to the
+    function whose trace held its trace, one row a program. Returns the
+    row's key and what it was given."""
+    for held in nested:
+        row = table.get(held[at])
+        if row is not None:
+            row["seconds"] -= held[at + 1]
+            seconds += held[at + 1]
+            if row["seconds"] <= 1e-9 and not row["programs"]:
+                table.pop(held[at], None)
+    row = table.get(name)
+    if row is not None and row.get("under") != said.get("under"):
+        # a name met under another span (two ``<lambda>``s): a row of its own
+        name = f"{name} [{said.get('under')}]"
+        row = table.get(name)
+    if row is None:
+        if len(table) >= cap:
+            name = _OTHER
+        row = table.setdefault(name, {"seconds": 0.0, "programs": 0})
+    row["seconds"] += seconds
+    row["programs"] += programs
+    row.update(said)
+    return name, seconds
+
+
+def _on_compile_event(event: str, **kwargs) -> None:
+    if event == _CACHE_HIT_EVENT:
+        # inside the backend interval that ends next on this thread
+        _compile_tls.hit = True
+    elif event == _CACHE_REQUEST_EVENT:
+        site = getattr(_compile_tls, "site", None)
+        if site is not None:
+            site["cache_misses"] += 1  # until a hit says otherwise
+
+
+def _on_compile_duration(event: str, duration: float, **kwargs) -> None:
+    """One stage of one compile has ended on this thread, ``duration``
+    seconds ago it began. Events fire at their END, so whatever lies inside
+    this interval was claimed before it: the stage's own seconds are its
+    duration less what the thread's claimed intervals hold of it (an inner
+    ``jit``'s trace inside the outer's adds nothing twice; a backend compile
+    inside a trace comes off the trace)."""
+    part = _DURATION_PARTS.get(event)
+    if part is None:
+        return
+    end = time.time()  # jax's own clock for these durations
+    start = end - duration
+    tls = _compile_tls
+    claimed = getattr(tls, "claimed", None)
+    if claimed is None:
+        claimed = tls.claimed = collections.deque()
+    name = str(kwargs.get("fun_name", "?"))
+    if name.startswith("jit(") and name.endswith(")"):
+        name = name[4:-1]  # lower and backend say jit(f), the trace f
+    inside, first, nested = 0.0, start, []
+    while claimed and claimed[-1][1] > start:
+        held = claimed.pop()
+        inside += held[2]
+        first = min(first, held[0])
+        nested.append(held)
+    own = max(0.0, duration - inside)
+    programs, traced = 0, part != "backend_s"
+    if not traced:
+        # a program: its seconds are its own, nothing moves into its row
+        # or out of it
+        programs, nested = 1, ()
+        if getattr(tls, "hit", False):
+            tls.hit, part = False, "cache_load_s"
+    site = getattr(tls, "site", None)
+    # the rows the interval is booked to by name, (key, seconds): outside
+    # any site, and late
+    outside = late = (None, 0.0)
+    if site is not None:
+        site[part] += own
+        site["programs"] += programs
+        if part == "cache_load_s":
+            site["cache_hits"] += 1
+            site["cache_misses"] -= 1
+        under = site["what"]
+    else:
+        from raydp_tpu.obs import tracing
+
+        under = tracing.current_span_name()
+        outside = _book(_outside, _OUTSIDE_ROWS, nested, 4, name, own,
+                        programs, under=under)
+        metrics.counter("jax.compile.outside_seconds").inc(own)
+        metrics.counter("jax.compile.outside_programs").inc(programs)
+    if _late_windows:
+        fit, (history, first_epoch) = next(iter(_late_windows.items()))
+        late = _book(_late, NAMED_ROWS_SHOWN, nested, 6, name, own, programs,
+                     under=under, epoch=first_epoch + len(history), fit=fit)
+        metrics.counter("estimator.compile.late_seconds").inc(own)
+        metrics.counter("estimator.compile.late_programs").inc(programs)
+    if not traced:
+        outside = late = (None, 0.0)
+    claimed.append((first, end, own + inside, name, *outside, *late))
+    while claimed[0][1] < end - _INTERVALS_KEPT_S:
+        claimed.popleft()
+
+
+def compile_account(fits=None) -> dict:
+    """Every compile of the process, by where it happened::
+
+        {"sites": [{what, fit, wall_s, trace_s, lower_s, backend_s,
+                    cache_load_s, rest_s, programs, cache_hits,
+                    cache_misses}],            # the estimator.compile spans
+         "outside": {fun_name: {seconds, programs, under}},
+         "late": [{fun_name, seconds, programs, under, epoch, fit}],
+         "totals": {...}}
+
+    ``sites``: in the order opened, the newest 512 (``wall_s`` and ``rest_s``
+    None while a site is open); ``fits``: only the sites and late compiles of
+    these fits (``JaxEstimator.compile_account``). ``outside``: what no site
+    held, by the function's name, the 16 largest by seconds and the rest
+    under ``other``; ``under`` is the innermost obs span open on the thread
+    (None: none). ``late``: compiles after a fit's first fence, in a site or
+    not, 16 names at most. Readable from any thread, while fits run."""
+    sites = []
+    for site in list(_sites):
+        if fits is not None and site["fit"] not in fits:
+            continue
+        sites.append({k: v for k, v in site.items() if not k.startswith("_")})
+    rows = sorted(
+        ((name, dict(row)) for name, row in list(_outside.items())),
+        key=lambda item: (item[0] == _OTHER, -item[1]["seconds"]))
+    outside = dict(rows[:NAMED_ROWS_SHOWN])
+    for _, row in rows[NAMED_ROWS_SHOWN:]:
+        other = outside.setdefault(
+            _OTHER, {"seconds": 0.0, "programs": 0, "under": None})
+        other["seconds"] += row["seconds"]
+        other["programs"] += row["programs"]
+    late = [
+        {"fun_name": name, **row} for name, row in list(_late.items())
+        if fits is None or row.get("fit") in fits
+    ]
+    totals = {
+        part: sum(site[part] for site in sites) for part in SITE_PARTS
+    }
+    totals.update(
+        wall_s=sum(site["wall_s"] or 0.0 for site in sites),
+        rest_s=sum(site["rest_s"] or 0.0 for site in sites),
+        programs=sum(site["programs"] for site in sites),
+        cache_hits=sum(site["cache_hits"] for site in sites),
+        cache_misses=sum(site["cache_misses"] for site in sites),
+        outside_s=sum(row["seconds"] for row in outside.values()),
+        outside_programs=sum(row["programs"] for row in outside.values()),
+        late_s=sum(row["seconds"] for row in late),
+        late_programs=sum(row["programs"] for row in late),
+    )
+    return {"sites": sites, "outside": outside, "late": late,
+            "totals": totals}
 
 
 # ---------------------------------------------------------------------------

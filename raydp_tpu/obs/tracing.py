@@ -26,7 +26,9 @@ Context: ``(trace_id, span_id)`` pairs travel thread-locally; ``span()``
 parents under the current context and installs itself for its body. RPC
 clients attach the current context to outgoing frames (common.rpc /
 ActorHandle) and servers adopt it around the handled call, so causality
-crosses process boundaries without any span caring.
+crosses process boundaries without any span caring. Beside the pair a real
+span that is entered leaves its NAME for its body (``current_span_name``):
+it stays on the thread and rides no frame.
 """
 
 from __future__ import annotations
@@ -116,6 +118,14 @@ def _set_context(ctx: Optional[Tuple[str, str]]) -> None:
     _tls.ctx = ctx
 
 
+def current_span_name() -> Optional[str]:
+    """The name of the innermost real span open on this thread (entered
+    with ``with``), or None: what a compile that no ``estimator.compile``
+    span holds is listed under (obs/profiler.py, "compile account"). The
+    shared no-op span sets nothing."""
+    return getattr(_tls, "span_name", None)
+
+
 class use_context:
     """Adopt a remote caller's (trace_id, span_id) for a code region — the
     server half of cross-process propagation."""
@@ -156,6 +166,9 @@ class _NoopSpan:
     def set(self, **attrs):
         return self
 
+    def set_traced(self, **attrs):
+        return self
+
     def start(self):
         return self
 
@@ -189,7 +202,8 @@ def _open_annotation(name: str):
 
 class Span:
     __slots__ = ("name", "args", "trace", "id", "parent", "_t0", "_ts",
-                 "duration", "_saved_ctx", "_ship", "_nested", "_annotation")
+                 "duration", "_saved_ctx", "_saved_name", "_ship", "_nested",
+                 "_annotation")
 
     def __init__(self, name: str, args: Dict[str, Any], ship: bool):
         self.name = name
@@ -203,6 +217,7 @@ class Span:
         self.id = uuid.uuid4().hex[:16]
         self._ship = ship
         self._saved_ctx = ctx
+        self._saved_name = None
         self._nested = False
         self._annotation = None
         self.duration = 0.0
@@ -213,8 +228,23 @@ class Span:
         self.args.update(attrs)
         return self
 
+    def set_traced(self, **attrs) -> "Span":
+        """``set``, and the same attributes on the span's twin in the
+        profiler's trace where it has one (numbers and strings): for what
+        is known only at the span's end and belongs on the timeline."""
+        self.args.update(attrs)
+        if self._annotation is not None:
+            self._annotation.set_metadata(**attrs)
+        return self
+
+    def elapsed(self) -> float:
+        """Seconds since the span was created, while it is open."""
+        return time.perf_counter() - self._t0
+
     def __enter__(self) -> "Span":
         _set_context((self.trace, self.id))
+        self._saved_name = getattr(_tls, "span_name", None)
+        _tls.span_name = self.name
         self._nested = True
         self._annotation = _open_annotation(self.name)
         return self
@@ -236,6 +266,7 @@ class Span:
             self._annotation.__exit__(exc_type, exc, tb)
         if self._nested:
             _set_context(self._saved_ctx)
+            _tls.span_name = self._saved_name
         if exc_type is not None:
             self.args.setdefault("error", exc_type.__name__)
         record = {

@@ -315,11 +315,17 @@ def test_restart_histogram_one_observation_per_epoch_boundary(host_ds):
 def test_compile_and_stage_counters_readable(host_ds):
     compile_c = obs.metrics.counter("estimator.compile_seconds")
     stage_c = obs.metrics.counter("exchange.stage_seconds")
-    before = compile_c.value, stage_c.value
+    # the compile account's split of the same seconds (ISSUE 55)
+    split = [obs.metrics.counter(f"estimator.compile.{part}_seconds")
+             for part in ("trace", "lower", "backend", "cache_load", "rest")]
+    before = compile_c.value, stage_c.value, sum(c.value for c in split)
     est = _make_est(num_epochs=1)
     est._stage_cache = {}
     est.fit(_HostDs(host_ds._f.copy(), host_ds._l.copy()))
     assert compile_c.value - before[0] == pytest.approx(est.compile_seconds_)
+    assert sum(c.value for c in split) - before[2] == pytest.approx(
+        est.compile_seconds_)
+    assert all(c.value >= 0.0 for c in split)
     whats = {r["args"]["what"] for r in est.last_fit_records_
              if r["name"] == "estimator.compile"}
     assert {"init", "flops_probe"} <= whats
